@@ -8,7 +8,7 @@ planner's ladder (keys→no-keys→shape-only) would actually land on — the
 registry-name guarantee is only as real as the tier that compiles.
 
 Host-only (grammar construction never touches the device); one JSON line
-per (vocab, size) so the ladder table in BASELINE.md is a paste of stdout.
+per (vocab, size), so a results table is a paste of stdout.
 
 Usage: [SIZES=1000,10000] python benchmarks/grammar_scale.py
 """

@@ -417,6 +417,13 @@ class EnginePool:
     def pallas_paths(self) -> dict:
         return self._replicas[0].engine.pallas_paths()
 
+    async def warm_grammar(self, grammar) -> None:
+        """Every routable replica compiles for ``grammar``: any of them may
+        be routed the first request that carries it."""
+        await asyncio.gather(
+            *(r.engine.warm_grammar(grammar) for r in self._replicas if r.routable)
+        )
+
     async def pin_prefix(self, prompt_ids) -> Optional[ClusterPin]:
         r = self._affinity_replica(prompt_ids)
         if r is None:

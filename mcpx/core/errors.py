@@ -1,6 +1,6 @@
 """Framework exception hierarchy.
 
-The reference has no error taxonomy — it raises bare ``HTTPException(502)``
+The reference has no error hierarchy — it raises bare ``HTTPException(502)``
 mid-walk and discards partial results (reference ``control_plane.py:130``,
 SURVEY.md bug B5). Here every error carries structure so the API layer can
 return partial-failure responses instead of aborting.

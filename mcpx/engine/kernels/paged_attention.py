@@ -155,10 +155,10 @@ def _ragged_kernel(
     q_lens_ref,  # [B] SMEM — live queries per row (ragged; 0 = idle row)
     layer_ref,  # [1] SMEM — which layer's pool slice to stream
     # blocks
-    q_ref,  # [1, S, 1, G, hd] VMEM
+    q_ref,  # [1, Sq, 1, G, hd] VMEM — one query block of the window
     k_pages_ref,  # [K, L, N, Psz, hd] ANY (stays in HBM)
     v_pages_ref,
-    out_ref,  # [1, S, 1, G, hd] VMEM
+    out_ref,  # [1, Sq, 1, G, hd] VMEM
     # scratch
     k_buf,  # [NBUF, Psz, hd] VMEM
     v_buf,
@@ -172,13 +172,19 @@ def _ragged_kernel(
     kh = pl.program_id(1)
     layer = layer_ref[0]
     S, G, hd = q_ref.shape[1], q_ref.shape[3], q_ref.shape[4]
-    start = start_pos_ref[b]
-    qn = q_lens_ref[b]
-    # The row's LAST LIVE query attends through position start+qn-1, so
+    # Query block j of the row's window is itself a ragged window: it
+    # starts S*j positions further into the cache and holds whatever part
+    # of the row's live queries falls inside it. Everything below is the
+    # whole-window kernel applied to that sub-window, so the carries and
+    # the q/out blocks stay [S*G, hd] however long a prefill window is.
+    q0 = pl.program_id(2) * S
+    start = start_pos_ref[b] + q0
+    qn = jnp.clip(q_lens_ref[b] - q0, 0, S)
+    # The block's LAST LIVE query attends through position start+qn-1, so
     # only pages up to that position stream in — a decode row (qn=1) next
     # to a prefill row (qn=S) pays decode-sized page traffic, and an idle
-    # row (qn=0) streams nothing (see _ragged_n_pages) and falls through
-    # to the zero output.
+    # row or an all-pad block (qn=0) streams nothing (see _ragged_n_pages)
+    # and falls through to the zero output.
     n_pages = _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1])
 
     q = q_ref[0, :, 0].reshape(S * G, hd).astype(jnp.float32)
@@ -257,6 +263,15 @@ def _ragged_kernel(
     out_ref[0, :, 0] = out.reshape(S, G, hd).astype(out_ref.dtype)
 
 
+# Queries per kernel program. The q/out blocks and the three f32 carries
+# are [Q_BLOCK * G, hd]; at the 2B head layout (G = 8, hd = 256) that is
+# 1 MiB per carry, which with the double-buffered blocks and the score
+# tiles stays inside Mosaic's default scoped-VMEM limit. A whole 1024-token
+# prefill window in one program does not. Each query block re-streams the
+# row's pages up to its own last query (PERF.md, open questions).
+Q_BLOCK = 128
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "n_buf"))
 def ragged_paged_attention(
     q: jax.Array,  # [B, S, K, G, hd] — padded query windows
@@ -270,10 +285,11 @@ def ragged_paged_attention(
     interpret: bool = False,
     n_buf: int = 4,
 ) -> jax.Array:
-    """The ragged mixed-phase kernel: grid (B, K); ONE program streams a
-    row's pages once for all of its live queries ([S*G, hd] MXU rows/page
-    vs [G, hd] for a single-query kernel folded over B*S programs — S
-    times fewer DMA issues, S*G-row matmuls instead of G-row). Row
+    """The ragged mixed-phase kernel: grid (B, K, cdiv(S, Sq)); ONE program
+    streams a row's pages once for a block of Sq <= Q_BLOCK of its queries
+    ([Sq*G, hd] MXU rows/page vs [G, hd] for a single-query kernel folded
+    over B*S programs — Sq times fewer DMA issues, Sq*G-row matmuls instead
+    of G-row). Decode, draft and verify windows are one block. Row
     raggedness (``q_lens``) is scalar-prefetched DATA like the start
     offsets and page tables, so suffix-prefill, plain-decode and
     spec-verify rows share ONE launch of ONE executable per padded window
@@ -283,19 +299,27 @@ def ragged_paged_attention(
     host-side would materialise a per-layer copy."""
     B, S, K, G, hd = q.shape
     _, _, _, page_size, _ = k_pages.shape
+    # A window up to Q_BLOCK is one block of its own width. A wider one runs
+    # as Q_BLOCK-query blocks; the engine's prefill buckets past 128 are all
+    # multiples of it, and any other width is padded up with dead queries
+    # (past every row's q_len, so they stream nothing and are sliced off).
+    sq = min(S, Q_BLOCK)
+    s_pad = pl.cdiv(S, sq) * sq
+    if s_pad != S:
+        q = jnp.pad(q, ((0, 0), (0, s_pad - S), (0, 0), (0, 0), (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, K),
+        grid=(B, K, s_pad // sq),
         in_specs=[
             pl.BlockSpec(
-                (1, S, 1, G, hd), lambda b, k, *_: (b, 0, k, 0, 0), memory_space=pltpu.VMEM
+                (1, sq, 1, G, hd), lambda b, k, j, *_: (b, j, k, 0, 0), memory_space=pltpu.VMEM
             ),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (1, S, 1, G, hd), lambda b, k, *_: (b, 0, k, 0, 0), memory_space=pltpu.VMEM
+            (1, sq, 1, G, hd), lambda b, k, j, *_: (b, j, k, 0, 0), memory_space=pltpu.VMEM
         ),
         scratch_shapes=[
             pltpu.VMEM((n_buf, page_size, hd), k_pages.dtype),
@@ -305,7 +329,7 @@ def ragged_paged_attention(
         ],
     )
     kernel = functools.partial(_ragged_kernel, page_size=page_size, n_buf=n_buf)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -319,6 +343,7 @@ def ragged_paged_attention(
         k_pages,
         v_pages,
     )
+    return out[:, :S]
 
 
 def paged_attention_chunk(

@@ -10,12 +10,14 @@ reference (SURVEY.md §4.2) and the paged path owns its layout decisions.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
 
 from mcpx.engine.kernels.paged_attention import (
     paged_attention_chunk,
@@ -25,6 +27,43 @@ from mcpx.engine.kernels.paged_attention import (
 )
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import apply_rope, rms_norm
+from mcpx.parallel.mesh import DATA_AXIS, MODEL_AXIS, _axis
+
+
+def _ragged_kernel_on_mesh(
+    mesh: Mesh,
+    qg: jax.Array,  # [B, S, K, G, hd]
+    k_all: jax.Array,  # [K, L, N, Psz, hd]
+    v_all: jax.Array,
+    page_table: jax.Array,  # [B, Pmax]
+    positions: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: jax.Array,
+    *,
+    interpret: bool,
+) -> jax.Array:
+    """The ragged kernel under ``jax.shard_map`` over the engine mesh: XLA
+    will not partition a Mosaic call by itself, so each device runs the
+    kernel on its own block. Rows split over ``data``; KV heads over
+    ``model`` where they divide — the pools' own sharding
+    (``InferenceEngine._init_pools``) — else the query-group axis with the
+    pools replicated (MQA). An axis that does not divide stays whole on
+    every device, so a one-device mesh is the same code with nothing split.
+    No collective is needed: a (row, head) pair's attention is complete on
+    the device that holds it."""
+    B, _, K, G, _ = qg.shape
+    rows = _axis(mesh, DATA_AXIS, B)
+    heads = _axis(mesh, MODEL_AXIS, K)
+    groups = None if heads else _axis(mesh, MODEL_AXIS, G)
+    q_spec = P(rows, None, heads, groups, None)
+    pool_spec = P(heads, None, None, None, None)
+    return jax.shard_map(
+        functools.partial(ragged_paged_attention, interpret=interpret),
+        mesh=mesh,
+        in_specs=(q_spec, pool_spec, pool_spec, P(rows, None), P(rows), P(rows), P()),
+        out_specs=q_spec,
+        check_vma=False,
+    )(qg, k_all, v_all, page_table, positions, q_lens, jnp.asarray(layer, jnp.int32))
 
 
 def decode_chunk_paged(
@@ -40,6 +79,7 @@ def decode_chunk_paged(
     logits_at: "jax.Array | None" = None,  # [B] chunk slot per row, or None
     active_cols: "jax.Array | None" = None,  # [C] token ids: compact unembed
     q_lens: "jax.Array | None" = None,  # [B] live window slots (ragged rows)
+    mesh: Optional[Mesh] = None,  # engine mesh; required with q_lens + use_pallas
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Multi-token decode step: S new tokens per sequence in ONE forward.
 
@@ -70,6 +110,11 @@ def decode_chunk_paged(
     """
     B, S = tokens.shape
     K, L, N, psz, hd = paged_kv["k"].shape
+    if use_pallas and q_lens is not None and mesh is None:
+        # The ragged kernel only runs under shard_map (a one-device mesh is
+        # its trivial case): a bare Mosaic call cannot lower on >1 chip, and
+        # the CPU interpreter would not show that.
+        raise ValueError("decode_chunk_paged: the ragged kernel route (q_lens) needs mesh=")
     from mcpx.models.gemma.quant import dequant_layer, embed_lookup, unembed
 
     # Weight-only int8 serving mode (models/gemma/quant.py): identity
@@ -97,8 +142,8 @@ def decode_chunk_paged(
         qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
         if use_pallas:
             if q_lens is not None:
-                out = ragged_paged_attention(
-                    qg, k_all, v_all, page_table, positions, q_lens, layer,
+                out = _ragged_kernel_on_mesh(
+                    mesh, qg, k_all, v_all, page_table, positions, q_lens, layer,
                     interpret=interpret,
                 )
             else:

@@ -26,13 +26,9 @@ def _check_shapes(params: Params, cfg: GemmaConfig, path: str) -> None:
     mismatch (e.g. a checkpoint trained on a different vocab) would either
     crash deep inside jit or, worse, broadcast."""
     expected = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    # tree_util spelling: jax.tree.leaves_with_path only exists on jax
-    # >= 0.4.40ish, and this must load checkpoints on the oldest jax the
-    # image family ships.
-    flat_e = jax.tree_util.tree_leaves_with_path(expected)
+    flat_e = jax.tree.leaves_with_path(expected)
     flat_p = {
-        jax.tree_util.keystr(k): v
-        for k, v in jax.tree_util.tree_leaves_with_path(params)
+        jax.tree_util.keystr(k): v for k, v in jax.tree.leaves_with_path(params)
     }
     problems = []
     expected_keys = set()

@@ -73,9 +73,7 @@ def _loss_fn(
 
 def _decay_mask(params: Params):
     # No weight decay on norm scales (Gemma RMSNorm scales sit at 0 = 1x).
-    # tree_util spelling: jax.tree.map_with_path needs a newer jax than the
-    # oldest image this must train on.
-    return jax.tree_util.tree_map_with_path(
+    return jax.tree.map_with_path(
         lambda path, _: not any("norm" in str(k) for k in path), params
     )
 

@@ -125,15 +125,7 @@ def ring_attention(
     B = q.shape[0]
     b_ax = _axis(mesh, DATA_AXIS, B)
     m_ax = _axis(mesh, MODEL_AXIS, q.shape[2])
-    if hasattr(jax, "shard_map"):
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:
-        # jax < 0.5: experimental spelling, and the replication check is
-        # named check_rep there. Same semantics either way.
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        smap = functools.partial(_shard_map, check_rep=False)
-    fn = smap(
+    fn = jax.shard_map(
         functools.partial(
             _ring_block_attend, n_shards=n, block_len=T // n
         ),
@@ -145,6 +137,7 @@ def ring_attention(
             P(b_ax),
         ),
         out_specs=P(b_ax, SEQ_AXIS, m_ax, None, None),
+        check_vma=False,
     )
     # No upcast of q: the QK^T einsum requests f32 accumulation via
     # preferred_element_type, same numerics contract as the dense _attend —

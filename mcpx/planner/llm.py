@@ -40,7 +40,7 @@ log = logging.getLogger("mcpx.planner.llm")
 
 # Cache sentinel for "this registry version compiles to shape-only": the
 # grammar cache must remember FAILED builds as well (they cost minutes at
-# the registry sizes where they fail — BASELINE.md grammar-scale table).
+# the registry sizes where they fail — benchmarks/grammar_scale.py).
 _SHAPE_ONLY = object()
 
 # Fixed prompt header — byte-identical for every request against any
@@ -186,13 +186,13 @@ class LLMPlanner:
 
     async def warm(self, registry) -> None:
         """Compile the serving path for the CURRENT registry grammar: build
-        the trie grammar for the latest snapshot and push one minimal
-        generate through it, so the admit/segment executables for its pad
-        bucket exist before the first real request (the engine's own warmup
-        covers only the generic grammar — on big subword vocabs a registry
-        trie lands in a different column bucket). Called by
-        ControlPlane.startup; failures are non-fatal (first request then
-        pays the compile instead)."""
+        the trie grammar for the latest snapshot and have the engine compile
+        every executable shaped by its tables (``engine.warm_grammar``: the
+        segment and each cohort bucket's admit — the engine's own warmup
+        covers only the generic grammar, and on big subword vocabs a
+        registry trie lands in a different column bucket). Called by
+        ControlPlane.startup; a failure is not fatal (the first request
+        then pays the compile) but is reported by GET /healthz."""
         await self.ensure_ready()
         if self.config.constrain_names == "shortlist":
             # Per-shortlist grammars are keyed by the shortlist itself — the
@@ -207,10 +207,7 @@ class LLMPlanner:
         grammar = await self._grammar(context, version, all_services)
         if grammar is None:
             return
-        prompt_ids = self.engine.tokenizer.encode("warm")
-        await self.engine.generate(
-            prompt_ids, max_new_tokens=1, constrained=True, grammar=grammar
-        )
+        await self.engine.warm_grammar(grammar)
 
     # ------------------------------------------------------------------ plan
     async def plan(self, intent: str, context: PlanContext) -> Plan:
@@ -408,7 +405,7 @@ class LLMPlanner:
             )
             # A failed (shape-only) outcome is cached too: at the registry
             # sizes where the build fails, the failing attempts themselves
-            # cost minutes (BASELINE.md r5 grammar-scale table) — re-running
+            # cost minutes (benchmarks/grammar_scale.py) — re-running
             # them per request behind this lock would serialize serving to
             # one plan per failure, and the grammar_fallbacks counter would
             # count requests instead of builds.

@@ -49,13 +49,12 @@ def _jax_version() -> str:
 
 
 def _backend_label(config: MCPXConfig) -> str:
-    """The accelerator backend this build SERVES with, as configured —
-    resolved cheaply (env/planner kind), never by initialising jax."""
-    import os
-
-    if config.planner.kind != "llm":
-        return "none"
-    return os.environ.get("JAX_PLATFORMS", "") or "auto"
+    """The build-identity backend label at CONSTRUCTION: "none" without an
+    engine, else "starting". The label is what jax reports, not a guess
+    from the environment, and an engine may not have asked jax yet (only a
+    ``use_pallas`` engine checks the backend when it is built): startup()
+    re-stamps it once the engine is ready and can say."""
+    return "starting" if config.planner.kind == "llm" else "none"
 
 
 class ControlPlane:
@@ -161,6 +160,12 @@ class ControlPlane:
         # counters stay the scrape surface; an operator endpoint should
         # not have to parse the exposition text for a hit rate).
         self.plan_cache_stats = {"hits": 0, "redis_hits": 0, "misses": 0}
+        # startup() progress for GET /healthz: ``started`` flips once the
+        # engine is ready AND the registry grammar is warm (or its warm
+        # failed, recorded in ``warm_error``) — "engine: ready" alone is
+        # earlier than "no compile left on the serving path".
+        self.started = False
+        self.warm_error: Optional[BaseException] = None
 
     # ------------------------------------------------------------- lifecycle
     async def startup(self) -> None:
@@ -171,14 +176,26 @@ class ControlPlane:
         ensure = getattr(self.planner, "ensure_ready", None)
         if ensure is not None:
             await ensure()
+            import jax  # the engine already initialised the backend
+
+            self.metrics.build_info.clear()
+            self.metrics.set_build_info(
+                version=_mcpx_version(),
+                jax=_jax_version(),
+                backend=jax.default_backend(),
+            )
         warm = getattr(self.planner, "warm", None)
         if warm is not None:
             try:
                 await warm(self.registry)
-            except Exception:  # broad: warm is best-effort, and logged
+            except Exception as e:  # broad: serving continues, /healthz says why
+                # Not fatal — the first plan then pays the compile — but
+                # never quiet: GET /healthz reports it as warm_error.
+                self.warm_error = e
                 log.exception(
                     "registry-grammar warmup failed; first plan pays the compile"
                 )
+        self.started = True
 
     # ------------------------------------------------------------------ plan
     async def plan(

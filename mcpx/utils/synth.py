@@ -2,7 +2,7 @@
 
 Generates deterministic N-service registries whose schemas chain (each
 service's outputs feed plausible downstream inputs), mirroring the baseline
-ladder's 3/10/100/1k-service registries (BASELINE.md configs).
+ladder's 3/10/100/1k-service registries (BASELINE.json configs).
 """
 
 from __future__ import annotations

@@ -1,10 +1,10 @@
-"""bench.py config plumbing: the honesty-critical knobs that steer a TPU
-session (smoke ladder -> env -> engine config) and the fallback-kind scrape
-that surfaces grammar degradations in the one JSON line the operator reads.
+"""bench.py config plumbing: the honesty-critical knobs that steer a run
+(env -> engine config), the removed fallbacks (no platform switch, no
+model-size retry, no invented peak) and the fallback-kind scrape that
+surfaces grammar degradations in the one JSON line the operator reads.
 
 These are host-side pure functions — no engine, no device."""
 
-import importlib.util
 import os
 import sys
 
@@ -16,21 +16,49 @@ sys.path.insert(0, REPO)
 import bench  # noqa: E402  (stdlib-only module level; jax untouched)
 
 
-def _smoke():
-    spec = importlib.util.spec_from_file_location(
-        "startup_smoke", os.path.join(REPO, "benchmarks", "startup_smoke.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def test_batch_default_comes_from_env_or_constant_only(monkeypatch):
+    """The served batch is MCPX_BENCH_BATCH or a constant — never a value
+    read back from an earlier run's artifact."""
+    monkeypatch.delenv("MCPX_BENCH_BATCH", raising=False)
+    assert bench._bench_batch("2b") == 32
+    assert bench._bench_batch("test") == 64
+    monkeypatch.setenv("MCPX_BENCH_BATCH", "16")
+    assert bench._bench_batch("2b") == 16
 
 
-def test_smoke_spec_parse():
-    sm = _smoke()
-    assert sm._parse_spec("64") == (64, True)
-    assert sm._parse_spec("32np") == (32, False)
-    with pytest.raises(ValueError):
-        sm._parse_spec("banana")
+def test_main_fails_instead_of_falling_back(monkeypatch):
+    """A run that cannot start fails: main() makes ONE attempt at the model
+    asked for on the device JAX gives it — no device guard that re-arms a
+    virtual CPU, no retry at model=test, no measured-matmul MFU peak."""
+    for gone in ("_device_guard", "_measured_peak_flops", "_peak_flops_per_chip"):
+        assert not hasattr(bench, gone), gone
+    seen = []
+
+    async def boom(model, *a):
+        seen.append(model)
+        raise RuntimeError("engine did not start")
+
+    monkeypatch.setattr(bench, "_run", boom)
+    monkeypatch.setenv("MCPX_BENCH_MODEL", "2b")
+    with pytest.raises(RuntimeError, match="engine did not start"):
+        bench.main()
+    assert seen == ["2b"]
+
+
+def test_quality_phase_failure_fails_the_run(monkeypatch, capsys):
+    async def ok_run(*a):
+        return {}
+
+    async def broken_quality(**kw):
+        raise RuntimeError("quality phase broke")
+
+    monkeypatch.setattr(bench, "_run", ok_run)
+    monkeypatch.setattr(bench, "_run_quality_trained", broken_quality)
+    monkeypatch.setenv("MCPX_BENCH_MODEL", "test")
+    monkeypatch.delenv("MCPX_BENCH_SKIP_QUALITY", raising=False)
+    with pytest.raises(RuntimeError, match="quality phase broke"):
+        bench.main()
+    assert capsys.readouterr().out == ""  # no result line
 
 
 def test_pallas_gate_forces_fused_jnp(monkeypatch):

@@ -94,6 +94,9 @@ class FakeClusterEngine:
     def pallas_paths(self):
         return {"decode": {"engaged": False}}
 
+    async def warm_grammar(self, grammar):
+        self.warmed = grammar
+
     async def pin_prefix(self, prompt_ids):
         self.pinned.append(tuple(prompt_ids))
         return ("pin", self.index)
@@ -369,6 +372,18 @@ def test_pool_pin_lands_on_affinity_replica():
     asyncio.run(go())
 
 
+def test_pool_warms_a_grammar_on_every_routable_replica():
+    async def go():
+        pool, engines = _pool(3)
+        await pool.start()
+        await pool.kill(1)
+        await pool.warm_grammar("g")
+        warmed = [getattr(engines[i][-1], "warmed", None) for i in range(3)]
+        assert warmed == ["g", None, "g"]
+
+    asyncio.run(go())
+
+
 def test_replica_skew_and_gauges():
     async def go():
         pool, _ = _pool(3)
@@ -502,7 +517,7 @@ def test_pool_is_engine_shaped():
         await pool.start()
         for attr in (
             "generate", "queue_stats", "state", "start", "aclose", "tokenizer",
-            "pin_prefix", "unpin_prefix", "prefix_cache_stats",
+            "pin_prefix", "unpin_prefix", "prefix_cache_stats", "warm_grammar",
             "prompt_capacity", "pallas_paths", "metrics", "costs",
         ):
             assert hasattr(pool, attr), attr

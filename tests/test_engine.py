@@ -868,3 +868,51 @@ def test_hetero_grammar_slots_recycle_and_defer():
             await eng.aclose()
 
     asyncio.run(go())
+
+
+def test_warm_grammar_compiles_every_cohort_bucket_before_traffic():
+    """``warm_grammar`` is the engine's own walk over its cohort buckets: a
+    grammar whose tables land in another pad bucket than the generic one
+    gets one admit per bucket and one segment compiled up front, by direct
+    dispatch, and then no cohort size — whatever way a burst is gathered —
+    compiles anything while serving. A resident row decoding under another
+    grammar while the warm runs is left alone."""
+    from mcpx.planner.grammar import build_plan_grammar
+
+    def compiles(eng):
+        snap = eng.costs.snapshot(materialize=False)["executables"]
+        return {n: e["compiles"] for n, e in snap.items()}
+
+    async def go():
+        eng = make_engine(
+            max_decode_len=32, warmup_compile=True, warmup_max_len=64,
+            grammar_state_budget=64,
+        )
+        await eng.start()
+        try:
+            tok = eng.tokenizer
+            g = build_plan_grammar(tok, [f"service-number-{i:03d}" for i in range(40)])
+            p = tok.encode("plan: JSON:")
+            c0 = compiles(eng)
+            solo = await eng.generate(p, max_new_tokens=24)
+            resident = asyncio.ensure_future(eng.generate(p, max_new_tokens=24))
+            await asyncio.sleep(0.02)  # admitted (or about to be) when the warm lands
+            await eng.warm_grammar(g)
+            assert (await resident).text == solo.text
+            c1 = compiles(eng)
+            grew = {n: c1[n] - c0[n] for n in c1 if c1[n] != c0[n]}
+            assert grew == {"admit": len(eng._batch_buckets), "segment": 1}, grew
+            for n in (1, 2, 4, 3):
+                outs = await asyncio.gather(
+                    *(
+                        eng.generate(p + [65 + i], max_new_tokens=8, grammar=g)
+                        for i in range(n)
+                    )
+                )
+                assert all(o.text.startswith('{"steps"') for o in outs)
+            assert compiles(eng) == c1
+            assert eng.metrics.engine_resets._value.get() == 0
+        finally:
+            await eng.aclose()
+
+    asyncio.run(go())

@@ -20,13 +20,6 @@ def test_dryrun_multichip_8():
 def test_dryrun_multichip_2():
     # dryrun self-arms a 2-device platform (a real re-arm, exercising the
     # clear-backends path); restore the suite's 8-device mesh afterwards.
-    # Re-arming an already-latched backend needs jax_num_cpu_devices
-    # (config-time, re-read on client creation) — older jax only honours
-    # XLA_FLAGS, which is parsed once per process.
-    import pytest
-
-    if not hasattr(jax.config, "jax_num_cpu_devices"):
-        pytest.skip("jax too old to re-arm a latched backend (no jax_num_cpu_devices)")
     try:
         graft.dryrun_multichip(2)
     finally:

@@ -141,11 +141,14 @@ def test_tenant_rollups_exactly_sum_member_bills():
                 tenant, key,
             )
         # Float items: the ledger folds += in completion order, the exact
-        # order this sum replays — raw equality is EXACT, bit for bit.
+        # order this loop replays — raw equality is EXACT, bit for bit.
+        # (Not builtin sum(): Python 3.12 compensates float sums, so it no
+        # longer equals a plain += fold in the last bits.)
         for key in ("flops", "hbm_bytes", "decode_ms", "kv_page_seconds"):
-            assert acct[key] == sum(getattr(b, key) for b in member), (
-                tenant, key,
-            )
+            folded = 0.0
+            for b in member:
+                folded += getattr(b, key)
+            assert acct[key] == folded, (tenant, key)
     # Grand totals equal the tenant sums.
     for key in ("requests", "decode_tokens"):
         assert snap["totals"][key] == sum(

@@ -22,9 +22,13 @@ class FakeEngine:
         self.tokenizer = ByteTokenizer()
         self.state = "ready"
         self.prompts = []
+        self.warmed = []
 
     async def start(self):
         self.state = "ready"
+
+    async def warm_grammar(self, grammar):
+        self.warmed.append(grammar)
 
     async def generate(self, prompt_ids, **kw):
         import dataclasses
@@ -308,18 +312,20 @@ def test_exclude_builds_grammar_without_excluded_name():
     asyncio.run(go())
 
 
-def test_warm_runs_one_generate_through_registry_grammar():
+def test_warm_hands_the_registry_grammar_to_the_engine():
     async def go():
         reg = await _registry()
         eng = FakeEngine(["x"])
         p = LLMPlanner(eng, PlannerConfig(kind="llm"))
         await p.warm(reg)
-        # One generate went through with the registry grammar attached.
-        assert len(eng.prompts) == 1
+        # The engine compiles for the registry grammar itself; the planner
+        # serves no request to get there.
+        assert len(eng.warmed) == 1 and eng.prompts == []
+        assert eng.warmed[0].walk('{"steps":[{"s":"fetch"') != eng.warmed[0].dead_state
         # Empty registry: warm is a no-op, not an error.
         empty = InMemoryRegistry()
         await p.warm(empty)
-        assert len(eng.prompts) == 1
+        assert len(eng.warmed) == 1
 
     asyncio.run(go())
 

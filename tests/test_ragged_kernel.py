@@ -106,6 +106,129 @@ def test_ragged_idle_rows_stream_zero_pages_and_output_zeros():
     assert np.all(np.asarray(out[1]) == 0.0)
 
 
+def test_ragged_kernel_blocks_long_windows_over_queries():
+    """A window wider than Q_BLOCK runs as Q_BLOCK-query blocks per row (a
+    whole 1024-token prefill window does not fit Mosaic's scoped VMEM in
+    one program); a width that is not a multiple is padded with dead
+    queries, never cut into slivers. Rows whose live length ends inside a
+    block, exactly on a block edge, before the first block ends and at zero
+    must all agree with the reference, pads included."""
+    from mcpx.engine.kernels.paged_attention import Q_BLOCK
+
+    S = 2 * Q_BLOCK + Q_BLOCK // 2  # 320: three blocks, the last half dead
+    B, K, G, hd, psz, p_max = 5, 2, 2, 16, 8, 48
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    n_pages = B * p_max + 1
+    q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (K, 1, n_pages, psz, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 1, n_pages, psz, hd), jnp.float32)
+    table = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(B, p_max)
+    q_lens = jnp.asarray([S, 90, Q_BLOCK, 0, 3], jnp.int32)
+    starts = jnp.asarray([p_max * psz - S, 11, 0, 40, 5], jnp.int32)
+    out = ragged_paged_attention(q, kp, vp, table, starts, q_lens, 0, interpret=True)
+    ref = ragged_paged_attention_reference(q, kp, vp, table, starts, q_lens, 0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    for b in range(B):
+        assert np.all(np.asarray(out[b, int(q_lens[b]):]) == 0.0), b
+
+
+# ----------------------------------------- lowering for the chip, from CPU
+_HEAD_LAYOUTS = {"2b": (1, 8, 256), "7b": (16, 1, 256)}  # (K, G, head_dim)
+
+
+def _kernel_arg_shapes(S, K, G, hd, B=4, psz=16, p_max=128):
+    sd = jax.ShapeDtypeStruct
+    pool = sd((K, 2, B * p_max + 1, psz, hd), jnp.bfloat16)
+    return (
+        sd((B, S, K, G, hd), jnp.bfloat16), pool, pool,
+        sd((B, p_max), jnp.int32), sd((B,), jnp.int32), sd((B,), jnp.int32),
+        sd((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("layout", sorted(_HEAD_LAYOUTS))
+@pytest.mark.parametrize("S", [1, 8, 9, 128])
+def test_kernel_lowers_for_tpu_on_one_device_and_under_shard_map(layout, S):
+    """Cross-platform lowering (jaxpr -> Mosaic MLIR, no chip needed) at the
+    published head layouts for every window class the engine dispatches:
+    the bare kernel on one device, and the engine's call — the kernel under
+    ``shard_map`` — on a 2x2 (data x model) mesh. Interpret mode lowers to
+    plain HLO, so without this neither a jaxpr->Mosaic error nor "Mosaic
+    kernels cannot be automatically partitioned" can fail on CPU."""
+    from mcpx.engine.paged_decode import _ragged_kernel_on_mesh
+    from mcpx.parallel.mesh import make_mesh
+
+    args = _kernel_arg_shapes(S, *_HEAD_LAYOUTS[layout])
+    jax.jit(ragged_paged_attention).trace(*args).lower(lowering_platforms=("tpu",))
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    jax.jit(
+        lambda *a: _ragged_kernel_on_mesh(mesh, *a, interpret=False)
+    ).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def test_bare_kernel_on_a_sharded_mesh_is_what_the_shard_map_prevents():
+    """The failure the shard_map exists for, pinned so the lowering test
+    above is known to see it: the same kernel called bare with inputs
+    sharded over a 2x2 mesh cannot lower for TPU."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mcpx.parallel.mesh import make_mesh
+
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    specs = (
+        P("data", None, None, "model", None), P(), P(),
+        P("data", None), P("data"), P("data"), P(),
+    )
+    args = tuple(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, s))
+        for a, s in zip(_kernel_arg_shapes(8, *_HEAD_LAYOUTS["2b"]), specs)
+    )
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(ragged_paged_attention).trace(*args).lower(
+            lowering_platforms=("tpu",)
+        )
+
+
+def test_ragged_kernel_route_refuses_to_run_without_a_mesh():
+    """There is no bare-kernel arm in the forward: a caller that forgets
+    ``mesh=`` fails at trace time on CPU instead of at lowering on >1 chip."""
+    from mcpx.engine.paged_decode import decode_chunk_paged
+
+    z = jnp.zeros((1, 1), jnp.int32)
+    with pytest.raises(ValueError, match="mesh="):
+        decode_chunk_paged(
+            {}, None, z, z[0], z, {"k": jnp.zeros((1, 1, 1, 1, 1))},
+            interpret=True, q_lens=z[0],
+        )
+
+
+def test_sharded_kernel_matches_reference_on_virtual_mesh():
+    """The shard_map'd call computes what the reference computes on a 2x2
+    mesh, for both head splits: KV heads over ``model`` (GQA, K divides)
+    and the query-group axis with the pools whole (MQA)."""
+    from mcpx.engine.paged_decode import _ragged_kernel_on_mesh
+    from mcpx.parallel.mesh import make_mesh
+
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    B, S, hd, psz, p_max = 4, 3, 16, 4, 6
+    for K, G in ((2, 2), (1, 4)):
+        ks = jax.random.split(jax.random.PRNGKey(K), 3)
+        n_pages = B * p_max + 1
+        q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+        kp = jax.random.normal(ks[1], (K, 2, n_pages, psz, hd), jnp.float32)
+        vp = jax.random.normal(ks[2], (K, 2, n_pages, psz, hd), jnp.float32)
+        table = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(B, p_max)
+        starts = jnp.asarray([5, 0, 17, 9], jnp.int32)
+        q_lens = jnp.asarray([S, 1, 0, 2], jnp.int32)
+        out = jax.jit(
+            lambda *a: _ragged_kernel_on_mesh(mesh, *a, interpret=True)
+        )(q, kp, vp, table, starts, q_lens, jnp.asarray(1, jnp.int32))
+        ref = ragged_paged_attention_reference(q, kp, vp, table, starts, q_lens, 1)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+        )
+
+
 # ------------------------------------------------------------ engine-level
 def _engine_cfg(**overrides):
     eng = {

@@ -11,7 +11,6 @@ from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import _attend, init_params, prefill, init_kv_cache
 from mcpx.parallel.mesh import make_mesh
 from mcpx.parallel.ring_attention import ring_attention, ring_prefill
-from mcpx.utils.backend import mesh_context
 
 
 def dense_reference(q, k, v, seq_lens):
@@ -48,7 +47,7 @@ def test_ring_matches_dense(mesh_kw, B, T, K, G):
     )
 
     ref = dense_reference(q, k, v, seq_lens)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda *a: ring_attention(*a, mesh))(q, k, v, seq_lens)
 
     # Compare only valid query positions (padded queries are don't-care).
@@ -69,7 +68,7 @@ def test_ring_prefill_matches_dense_prefill():
     ref_logits, ref_cache = jax.jit(prefill, static_argnums=1)(
         params, cfg, tokens, seq_lens, init_kv_cache(cfg, B, T)
     )
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         logits, cache = jax.jit(
             lambda p, t, sl: ring_prefill(p, cfg, t, sl, mesh)
         )(params, tokens, seq_lens)

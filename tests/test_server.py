@@ -240,6 +240,49 @@ def test_cache_endpoint_surfaces_tier_and_governor_stats():
     assert st_off["tier"] is None and st_off["governor"] is None
 
 
+def test_failed_grammar_warm_is_visible_in_healthz_and_serving_continues():
+    """ControlPlane.startup survives a failed registry-grammar warm, but
+    never quietly: /healthz reaches ``started`` with the cause under
+    ``warm_error`` (not ``engine_error`` — the engine is fine), and /plan
+    keeps answering."""
+    from mcpx.core.errors import PlannerError
+    from mcpx.planner.heuristic import HeuristicPlanner
+    from mcpx.registry import ServiceRecord
+
+    class WarmFails(HeuristicPlanner):
+        async def warm(self, registry):
+            raise PlannerError("no column bucket for this trie")
+
+    async def go():
+        cp, app = make_app(planner=WarmFails())
+        assert cp.started is False and cp.warm_error is None
+        await cp.registry.put(
+            ServiceRecord(
+                name="search",
+                endpoint="local://search",
+                description="search documents by query",
+                input_schema={"query": "str"},
+                output_schema={"document": "str"},
+            )
+        )
+
+        async def drive(client):
+            for _ in range(100):  # startup() is the app's background task
+                body = await (await client.get("/healthz")).json()
+                if body["started"]:
+                    break
+                await asyncio.sleep(0.01)
+            assert body["started"] is True and body["status"] == "ok"
+            assert body["warm_error"] == "PlannerError: no column bucket for this trie"
+            assert "engine_error" not in body
+            r = await client.post("/plan", json={"intent": "search documents"})
+            assert r.status == 200 and "graph" in await r.json()
+
+        await with_client(app, drive)
+
+    asyncio.run(go())
+
+
 def test_missing_registration_returns_400():
     async def go():
         cp, app = make_app()
