@@ -344,9 +344,10 @@ class FlightConfig:
     # phase timers — admit / locality-sort / prefix-match / dispatch /
     # poll / harvest / spill-copy drain / host-bookkeeping / idle —
     # aggregated into streaming histograms and surfaced in
-    # ``queue_stats()["worker_profile"]``, engine.decode span attrs, the
-    # bench ``worker_profile`` block and the flight ring. Off = the
-    # worker loop takes no clock reads at all (pass-through).
+    # ``queue_stats()["worker_profile"]``, the per-segment attributes of
+    # engine.segment spans, the bench ``worker_profile`` block and the
+    # flight ring. ``tracing.enabled`` brings the profiler along; with
+    # both off the worker loop takes no clock reads for it (pass-through).
     profile_worker: bool = False
     # Run the SPC detectors over the sampled series (enabled only).
     detectors: bool = True

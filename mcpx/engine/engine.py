@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mcpx.core.config import MCPXConfig
@@ -85,7 +86,7 @@ from mcpx.scheduler.locality import locality_order
 from mcpx.telemetry import ledger as ledger_mod
 from mcpx.telemetry import tracing
 from mcpx.telemetry.costs import CostRegistry, device_peaks, rounded_roofline
-from mcpx.telemetry.flight import WorkerProfiler
+from mcpx.telemetry.flight import SEGMENT_PARTS, WorkerProfiler
 from mcpx.telemetry.metrics import Metrics
 from mcpx.utils.ownership import owned_by
 
@@ -129,6 +130,10 @@ class GenerateRequest:  # mcpx: request-payload
     # no contextvar crosses the thread boundary. None (tracing disabled or
     # request unsampled) keeps the decode hot path entirely span-free.
     span: Optional[Any] = None
+    # When the worker's _drain_queue moved this request into its pending
+    # line (time.monotonic; stamped for requests that carry a span only):
+    # enqueued_at..seen_at is the engine.queue_wait span's ``unseen_ms``.
+    seen_at: float = 0.0
 
     def prefix_key(self, page_size: int) -> Optional[tuple]:
         """Page-aligned shared prefix as the cache key (None = no sharing).
@@ -271,11 +276,6 @@ class _Slab:
         # (engine.decode span attrs). Written only when a span rides the
         # request, so the untraced hot path never touches it.
         self.cost0 = np.zeros((B, 3), np.float64)
-        # Per-row snapshot of the worker profiler's phase totals at
-        # admission (traced rows with an attached profiler only): the
-        # retirement delta is the worker-loop breakdown during the row's
-        # residency (engine.decode span worker_* attrs). None = untouched.
-        self.prof0: list[Optional[dict]] = [None] * B
         # Per-row cost-ledger accumulators (telemetry/ledger.py), written
         # ONLY while telemetry.ledger is enabled (engine._ledger_on) —
         # ledger-off leaves every array untouched, the pass-through
@@ -374,7 +374,6 @@ class _Slab:
             node.refs -= 1
         self.prefix[i] = ()
         self.prefix_toks[i] = 0
-        self.prof0[i] = None
         self.bill_flops[i] = 0.0
         self.bill_bytes[i] = 0.0
         self.bill_fwd[i] = 0
@@ -496,6 +495,21 @@ class InferenceEngine:
         # the poll that first sees the chain finished (≤1 tick late).
         self._pending_admissions: list[tuple] = []  # mcpx: owner[engine-worker]
         self._seg_counter = 0  # mcpx: owner[engine-worker]
+        # Decode segments dispatched so far: the ``seq`` of engine.segment
+        # spans and the step_num of the segment's ``mcpx.segment`` event in
+        # a profiler trace (_seg_counter also counts admissions: it seeds
+        # the PRNG).
+        self._dispatch_seq = 0  # mcpx: owner[engine-worker]
+        # Rows admitted since the previous segment dispatch: their prefills
+        # are chained in front of the next segment on the device, so its
+        # engine.segment spans carry the count as ``prefill_rows``.
+        self._rows_admitted = 0  # mcpx: owner[engine-worker]
+        # Whether every slab row is taken, by the worker's own books, and
+        # the last transitions of that with their times (kept while a
+        # profiler is on): admission integrates them into the
+        # engine.queue_wait span's ``free_row_ms``.
+        self._slab_full = False  # mcpx: owner[engine-worker]
+        self._occupancy: "deque[tuple[float, bool]]" = deque(maxlen=64)  # mcpx: owner[engine-worker]
         self._seq_counter = 0  # mcpx: owner[engine-worker]
         self._last_admit_t = 0.0  # mcpx: owner[engine-worker]
         # EWMA of per-request engine service time (prefill + decode wall
@@ -643,14 +657,17 @@ class InferenceEngine:
         self._seg_cost_totals = {"flops": 0.0, "bytes": 0.0, "wall_s": 0.0}  # mcpx: owner[engine-worker]
         # Decode-loop host profiler (telemetry/flight.py): per-iteration
         # phase timers tiling the worker loop's wall time into named
-        # phases, surfaced via queue_stats()["worker_profile"], decode
-        # span attrs and the bench worker_profile block. None (default) =
-        # zero clock reads on the hot path; the bench's flight phase
+        # phases on the spans' clock, surfaced via
+        # queue_stats()["worker_profile"], the per-segment attributes of
+        # engine.segment spans and the bench worker_profile block. On
+        # with tracing (whose spans carry it) or profile_worker; None =
+        # zero clock reads on the hot path. The bench's flight phase
         # attaches one to a LIVE engine (the worker re-reads the field
         # each iteration, so an attach/detach lands at the next tick).
         self._profiler: Optional[WorkerProfiler] = (  # mcpx: owner[engine-worker, atomic]
             WorkerProfiler()
-            if self.config.telemetry.flight.profile_worker
+            if self.config.tracing.enabled
+            or self.config.telemetry.flight.profile_worker
             else None
         )
         # Per-request cost ledger (telemetry/ledger.py): while on, the
@@ -3499,15 +3516,19 @@ class InferenceEngine:
             # Decode-loop host profiler (telemetry/flight.py): lap() marks
             # tile the iteration's wall time into named phases; prof is
             # re-read each iteration so a live attach/detach (bench flight
-            # phase) lands at the next tick. None (default) = no clock
-            # reads anywhere on this path.
+            # phase) lands at the next tick. None = no clock reads anywhere
+            # on this path. The TraceAnnotations put the same phases, as
+            # ``mcpx.worker.<phase>`` events, on this thread's line of a
+            # profiler trace (POST /profile/start), the device ops' clock;
+            # with no session open each costs an atomic load.
             prof = self._profiler
             if prof is not None:
                 prof.loop_tick()
-            self._drain_queue(
-                pending,
-                block=(not pending and slab.n_active == 0 and not self._inflight),
-            )
+            with TraceAnnotation("mcpx.worker.drain"):
+                self._drain_queue(
+                    pending,
+                    block=(not pending and slab.n_active == 0 and not self._inflight),
+                )
             if prof is not None:
                 prof.lap("drain")
             if self._stop:
@@ -3529,7 +3550,8 @@ class InferenceEngine:
                 prof.lap("host_bookkeeping")
             if pending and slab.n_active < slab.B:
                 try:
-                    self._admit(slab, pending)
+                    with TraceAnnotation("mcpx.worker.admit"):
+                        self._admit(slab, pending)
                 except BaseException as e:  # noqa: BLE001 - keep worker alive
                     log.exception("admission failed; failing resident rows")
                     self._fail_rows(slab, e)
@@ -3541,7 +3563,8 @@ class InferenceEngine:
                     # Dispatch first, THEN fetch a lagged segment's flags:
                     # the fetch's round trip rides on top of the segment the
                     # device is already computing.
-                    self._dispatch_segment(slab)
+                    with TraceAnnotation("mcpx.worker.dispatch_submit"):
+                        self._dispatch_segment(slab)
                     if prof is not None:
                         # Submit only — the async XLA enqueue's host cost.
                         # Blocking device waits show up as the "sync"
@@ -3549,10 +3572,11 @@ class InferenceEngine:
                         # (submit down) is attributable separately from
                         # "the device is now the bottleneck" (sync up).
                         prof.lap("dispatch_submit")
-                    self._harvest(
-                        slab,
-                        keep_inflight=max(0, self.config.engine.pipeline_depth - 1),
-                    )
+                    with TraceAnnotation("mcpx.worker.harvest"):
+                        self._harvest(
+                            slab,
+                            keep_inflight=max(0, self.config.engine.pipeline_depth - 1),
+                        )
                     if prof is not None:
                         prof.lap("harvest")
                 except BaseException as e:  # noqa: BLE001 - keep worker alive
@@ -3563,7 +3587,8 @@ class InferenceEngine:
                 # Nothing active by the host's (lagged) view but segments
                 # still in flight: drain them so idle blocking is safe.
                 try:
-                    self._harvest(slab, keep_inflight=0)
+                    with TraceAnnotation("mcpx.worker.harvest"):
+                        self._harvest(slab, keep_inflight=0)
                 except BaseException as e:  # noqa: BLE001 - keep worker alive
                     log.exception("segment harvest failed; failing resident rows")
                     self._fail_rows(slab, e)
@@ -3667,7 +3692,8 @@ class InferenceEngine:
                 # for work" from "moving work".
                 t_idle = prof.mark() if prof is not None else 0.0
                 try:
-                    item = self._queue.get(timeout=0.05)
+                    with TraceAnnotation("mcpx.worker.idle"):
+                        item = self._queue.get(timeout=0.05)
                 finally:
                     if prof is not None:
                         prof.carve("idle", t_idle)
@@ -3681,7 +3707,7 @@ class InferenceEngine:
                 self._stop = True
                 return
             if not self._apply_control_op(item):
-                pending.append(item)
+                self._to_pending(pending, item)
             try:
                 item = self._queue.get_nowait()
             except queue.Empty:
@@ -3694,7 +3720,8 @@ class InferenceEngine:
                     return
                 t_idle = prof.mark() if prof is not None else 0.0
                 try:
-                    item = self._queue.get(timeout=remaining)
+                    with TraceAnnotation("mcpx.worker.idle"):
+                        item = self._queue.get(timeout=remaining)
                 except queue.Empty:
                     return
                 finally:
@@ -3704,7 +3731,15 @@ class InferenceEngine:
                     self._stop = True
                     return
                 if not self._apply_control_op(item):
-                    pending.append(item)
+                    self._to_pending(pending, item)
+
+    @staticmethod
+    def _to_pending(pending: "deque[GenerateRequest]", r: GenerateRequest) -> None:
+        if r.span is not None:
+            # The worker has now SEEN the request: until here it sat in
+            # queue.Queue (engine.queue_wait's unseen_ms).
+            r.seen_at = time.monotonic()
+        pending.append(r)
 
     def _apply_control_op(self, item: Any) -> bool:
         """Apply a control op riding the request queue (prefix pin / unpin,
@@ -4392,19 +4427,13 @@ class InferenceEngine:
                 slab.n_traced += 1
                 tot = self._seg_cost_totals
                 slab.cost0[i] = (tot["flops"], tot["bytes"], tot["wall_s"])
-                prof = self._profiler  # one read: a live detach between
-                if prof is not None:   # check and use must not raise here
-                    # Worker-loop attribution for this row's residency:
-                    # retirement deltas these totals (engine.decode span
-                    # worker_phases_ms attr). Traced rows only — the
-                    # untraced path pays nothing.
-                    slab.prof0[i] = prof.totals_copy()
                 r.span.child(
                     "engine.queue_wait",
                     t0=r.enqueued_at,
                     t1=t0,
                     cls="constrained" if r.constrained else "free",
                     row=i,
+                    **self._queue_wait_why(r, t0),
                 )
             # The radix nodes this row references were pinned at stage-3
             # commit (match +1, insert born-pinned); the row now OWNS those
@@ -4420,6 +4449,10 @@ class InferenceEngine:
                 slab.bill_pages[i] = len(row_pages[j])
                 slab.bill_copy[i] = int(prefixes[j][3])
                 slab.admit_t[i] = t1
+        self._rows_admitted += len(cohort)
+        # Stamped at the admission's start: from there on its rows were
+        # taken, not free for a request still waiting.
+        self._note_occupancy(slab, t0)
         if hetero:
             self.metrics.resident_grammars.set(
                 sum(1 for n in self._dfa_slot_refs[1:] if n > 0)
@@ -4495,8 +4528,52 @@ class InferenceEngine:
         self._drop_row_grammar(slab, i)
         slab.clear_row(i)
         self._dirty_rows.add(i)
+        self._note_occupancy(slab)
         self.metrics.kv_page_utilization.set(self._allocator.stats().utilization)
         self.metrics.batch_occupancy.set(slab.n_active)
+
+    def _note_occupancy(self, slab: "_Slab", t: float = 0.0) -> None:
+        """Record a change of "every slab row is taken" with its time
+        (``t`` where the caller has already read the clock). Only while a
+        profiler is on: otherwise no clock read, and the stale log goes."""
+        full = slab.n_active >= slab.B
+        if full == self._slab_full:
+            return
+        self._slab_full = full
+        if self._profiler is not None:
+            self._occupancy.append((t or time.monotonic(), full))
+        else:
+            self._occupancy.clear()
+
+    def _queue_wait_why(self, r: GenerateRequest, t_admit: float) -> dict:
+        """Why the request waited, as engine.queue_wait span attributes:
+        ``unseen_ms``, enqueue to the drain pass that moved it into the
+        pending line (it sat in queue.Queue while the worker was not
+        looking), and ``free_row_ms``, the part of the wait during which
+        the slab had a free row by the worker's books. Both <= the span's
+        duration. Empty without a profiler."""
+        if self._profiler is None:
+            return {}
+        t_enq = r.enqueued_at
+        # Transitions alternate, so before the oldest one kept the state
+        # was its opposite; with none kept it is the current state.
+        transitions = self._occupancy
+        full = (not transitions[0][1]) if transitions else self._slab_full
+        free_s, t_prev = 0.0, t_enq
+        for t, now_full in transitions:
+            if t > t_prev:
+                t = min(t, t_admit)
+                if not full:
+                    free_s += t - t_prev
+                t_prev = t
+            full = now_full
+        if not full and t_admit > t_prev:
+            free_s += t_admit - t_prev
+        seen = min(max(r.seen_at, t_enq), t_admit)
+        return {
+            "unseen_ms": round((seen - t_enq) * 1e3, 3),
+            "free_row_ms": round(free_s * 1e3, 3),
+        }
 
     def _reap_cancelled(self, slab: "_Slab") -> None:
         """Free rows whose request future was cancelled (client disconnect,
@@ -4546,84 +4623,90 @@ class InferenceEngine:
         ) = self._dev_state(slab)
         prng = jax.random.PRNGKey((self._rng_base + self._seg_counter) & 0x7FFFFFFF)
         dr_d = ac_d = cons_snap = None
-        if hetero and slab.spec:
-            out = self._jit_hetero_segment_spec(
-                self._params,
-                *self._stacked_dfa(),
-                cur_d,
-                pos_d,
-                st_d,
-                e_d,
-                done_d,
-                budgets_d,
-                pt_d,
-                self._paged_kv["k"],
-                self._paged_kv["v"],
-                buf_in,
-                temp_d,
-                cons_d,
-                dfa_d,
-                hst_d,
-                prng,
-                iters=iters,
-                K=slab.spec_k,
-                draft=slab.spec_draft,
-            )
-            (
-                cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, hst_d,
-                dr_d, ac_d, n_fwd,
-            ) = out
-            # Class snapshot at dispatch: the drafted/accepted vectors the
-            # lagged harvest fetches belong to the rows resident NOW.
-            cons_snap = slab.cons.copy()
-        elif hetero:
-            out = self._jit_hetero_segment(
-                self._params,
-                *self._stacked_dfa()[:5],
-                cur_d,
-                pos_d,
-                st_d,
-                e_d,
-                done_d,
-                budgets_d,
-                pt_d,
-                self._paged_kv["k"],
-                self._paged_kv["v"],
-                buf_in,
-                temp_d,
-                cons_d,
-                dfa_d,
-                prng,
-                iters=iters,
-                chunk=chunk,
-            )
-            cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, n_fwd = out
-        else:
-            dfa = self._dfa_for(slab.grammar or self.grammar)
-            out = self._jit_segment(  # mcpx: ignore[jit-contract] - homogeneous-mode debt: per-request temperature/constrained ARE trace statics here, bounded by the slab-wide compat triple (one config per occupancy, drain-to-switch); hetero_batch moves both into per-row device state
-                self._params,
-                *dfa,
-                cur_d,
-                pos_d,
-                st_d,
-                e_d,
-                done_d,
-                budgets_d,
-                pt_d,
-                self._paged_kv["k"],
-                self._paged_kv["v"],
-                buf_in,
-                ptoks_d,
-                plens_d,
-                prev_d,
-                prng,
-                iters=iters,
-                chunk=chunk,
-                temperature=slab.temperature,
-                constrained=slab.constrained,
-                draft=ecfg.draft_mode == "prompt",
-            )
-            cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, prev_d, n_fwd = out
+        self._dispatch_seq += 1
+        seq = self._dispatch_seq
+        prefill_rows, self._rows_admitted = self._rows_admitted, 0
+        # A step event in a profiler trace: the segment's device ops carry
+        # its step_num, which is the engine.segment spans' ``seq``.
+        with StepTraceAnnotation("mcpx.segment", step_num=seq):
+            if hetero and slab.spec:
+                out = self._jit_hetero_segment_spec(
+                    self._params,
+                    *self._stacked_dfa(),
+                    cur_d,
+                    pos_d,
+                    st_d,
+                    e_d,
+                    done_d,
+                    budgets_d,
+                    pt_d,
+                    self._paged_kv["k"],
+                    self._paged_kv["v"],
+                    buf_in,
+                    temp_d,
+                    cons_d,
+                    dfa_d,
+                    hst_d,
+                    prng,
+                    iters=iters,
+                    K=slab.spec_k,
+                    draft=slab.spec_draft,
+                )
+                (
+                    cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, hst_d,
+                    dr_d, ac_d, n_fwd,
+                ) = out
+                # Class snapshot at dispatch: the drafted/accepted vectors the
+                # lagged harvest fetches belong to the rows resident NOW.
+                cons_snap = slab.cons.copy()
+            elif hetero:
+                out = self._jit_hetero_segment(
+                    self._params,
+                    *self._stacked_dfa()[:5],
+                    cur_d,
+                    pos_d,
+                    st_d,
+                    e_d,
+                    done_d,
+                    budgets_d,
+                    pt_d,
+                    self._paged_kv["k"],
+                    self._paged_kv["v"],
+                    buf_in,
+                    temp_d,
+                    cons_d,
+                    dfa_d,
+                    prng,
+                    iters=iters,
+                    chunk=chunk,
+                )
+                cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, n_fwd = out
+            else:
+                dfa = self._dfa_for(slab.grammar or self.grammar)
+                out = self._jit_segment(  # mcpx: ignore[jit-contract] - homogeneous-mode debt: per-request temperature/constrained ARE trace statics here, bounded by the slab-wide compat triple (one config per occupancy, drain-to-switch); hetero_batch moves both into per-row device state
+                    self._params,
+                    *dfa,
+                    cur_d,
+                    pos_d,
+                    st_d,
+                    e_d,
+                    done_d,
+                    budgets_d,
+                    pt_d,
+                    self._paged_kv["k"],
+                    self._paged_kv["v"],
+                    buf_in,
+                    ptoks_d,
+                    plens_d,
+                    prev_d,
+                    prng,
+                    iters=iters,
+                    chunk=chunk,
+                    temperature=slab.temperature,
+                    constrained=slab.constrained,
+                    draft=ecfg.draft_mode == "prompt",
+                )
+                cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, prev_d, n_fwd = out
         self._paged_kv = {"k": k_p, "v": v_p}
         slab.dev = (
             cur_d, pos_d, st_d, e_d, done_d, budgets_d, pt_d, buf_d,
@@ -4655,6 +4738,9 @@ class InferenceEngine:
                 # traced spans and request bills with it.
                 getattr(seg_exec, "last_entry", None),
                 getattr(seg_exec, "name", "segment"),
+                # The engine.segment spans' seq and prefill_rows.
+                seq,
+                prefill_rows,
             )
         )
 
@@ -4713,7 +4799,7 @@ class InferenceEngine:
         while len(self._inflight) > keep_inflight:
             (
                 done_d, e_d, buf_d, nfwd_d, gen_snap, t_disp, spec_h, cons_snap,
-                seg_cost, seg_name,
+                seg_cost, seg_name, seq, prefill_rows,
             ) = self._inflight.popleft()
             # ONE combined fetch (flags + out_buf): a blocking fetch costs
             # its round trip, not the ~24KB of buffer — splitting into
@@ -4726,14 +4812,27 @@ class InferenceEngine:
             prof = self._profiler
             t_sync = prof.mark() if prof is not None else 0.0
             dr = ac = None
-            if spec_h is not None:
-                done, e, buf, n_fwd, dr, ac = jax.device_get(
-                    (done_d, e_d, buf_d, nfwd_d) + spec_h
-                )
-            else:
-                done, e, buf, n_fwd = jax.device_get((done_d, e_d, buf_d, nfwd_d))
+            with TraceAnnotation("mcpx.worker.sync"):
+                if spec_h is not None:
+                    done, e, buf, n_fwd, dr, ac = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d) + spec_h
+                    )
+                else:
+                    done, e, buf, n_fwd = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d)
+                    )
+            timeline = {}
             if prof is not None:
-                prof.carve("sync", t_sync)
+                # The segment's ready stamp: the fetch has just returned.
+                # The profiler's window between two ready stamps is what
+                # the worker did meanwhile; kept for every segment so the
+                # windows tile, written only on traced ones (t_disp).
+                t_ready = prof.carve("sync", t_sync)
+                t_prev, phases = prof.window("harvest", t_ready)
+                if t_disp:
+                    timeline = self._segment_timeline(
+                        seq, prefill_rows, t_disp, t_ready, t_prev, phases
+                    )
             if dr is not None:
                 self._account_speculation(dr, ac, cons_snap)
             # The blocking fetch above implies every earlier admission chain
@@ -4787,8 +4886,10 @@ class InferenceEngine:
                         forwards=int(n_fwd),
                         # Whole-slab segment roofline (XLA cost over the
                         # dispatch->harvest window) — identical across the
-                        # segment's rows by construction.
+                        # segment's rows by construction, as is its
+                        # timeline (_segment_timeline).
                         **seg_attrs,
+                        **timeline,
                     )
                     if dr is not None:
                         # Speculation attribution per traced row: how many
@@ -4877,22 +4978,12 @@ class InferenceEngine:
                     # the row's residency (cost0 is per-row, the work is
                     # the slab's).
                     tot = self._seg_cost_totals
-                    prof_attrs = {}
-                    prof = self._profiler  # single read (live detach safety)
-                    if prof is not None and slab.prof0[i] is not None:
-                        # Worker-loop phase breakdown over this row's
-                        # residency (telemetry/flight.py): where the HOST
-                        # side of the decode wall went, per named phase.
-                        prof_attrs["worker_phases_ms"] = WorkerProfiler.delta_ms(
-                            slab.prof0[i], prof.totals
-                        )
                     r.span.child(
                         "engine.decode",
                         t0=slab.t_decode0[i],
                         t1=t1,
                         tokens=len(ids),
                         row=i,
-                        **prof_attrs,
                         **self._span_roofline(
                             tot["flops"] - slab.cost0[i, 0] or None,
                             tot["bytes"] - slab.cost0[i, 1] or None,
@@ -4911,6 +5002,39 @@ class InferenceEngine:
                 )
                 self._release_row(slab, i)
                 r.loop.call_soon_threadsafe(_resolve, r.future, res, None)
+
+    @staticmethod
+    def _segment_timeline(
+        seq: int,
+        prefill_rows: int,
+        t_disp: float,
+        t_ready: float,
+        t_prev: float,
+        phases: dict[str, float],
+    ) -> dict:
+        """When a harvested segment ran and what the worker did meanwhile,
+        as flat engine.segment span attributes. ``period_ms``: from when
+        the device could start it (its dispatch, or the previous segment's
+        ready stamp ``t_prev`` if later) to when the blocked fetch saw it
+        done; the ``prefill_rows`` admission prefills chained in front of
+        it are inside. ``sync_ms`` is how long that fetch blocked: near
+        zero, the device had finished before the host asked and the
+        period is the host's lateness. ``idle_ms`` (blocked waiting for
+        requests) and ``host_ms`` (every other phase, with its named
+        parts) are the worker's phases since ``t_prev``: with ``sync_ms``
+        they sum to ``t_ready - t_prev``."""
+        ms = {p: v * 1e3 for p, v in phases.items()}
+        out = {
+            "seq": seq,
+            "prefill_rows": prefill_rows,
+            "period_ms": round((t_ready - max(t_disp, t_prev)) * 1e3, 3),
+            "sync_ms": round(ms["sync"], 3),
+            "idle_ms": round(ms["idle"], 3),
+            "host_ms": round(sum(ms.values()) - ms["sync"] - ms["idle"], 3),
+        }
+        for attr, parts in SEGMENT_PARTS.items():
+            out[attr] = round(sum(ms[p] for p in parts), 3)
+        return out
 
     def _init_pools(self) -> dict:
         """Fresh zeroed KV page pools, sharded over the mesh: KV heads on
@@ -4966,6 +5090,7 @@ class InferenceEngine:
             self._drop_row_grammar(slab, i)
             slab.clear_row(i)
             r.loop.call_soon_threadsafe(_resolve, r.future, None, error)
+        self._note_occupancy(slab)
         self.metrics.kv_page_utilization.set(self._allocator.stats().utilization)
         self.metrics.batch_occupancy.set(0)
 

@@ -79,7 +79,13 @@ def build_control_plane(
             config.planner.plan_cache_redis_url,
             ttl_s=config.planner.plan_cache_redis_ttl_s,
         )
-    metrics = Metrics()
+    # An injected planner's engine (or pool) made its own registry at
+    # construction; the from_config path below hands ONE registry to both.
+    # Adopt it, so the engine's series (mcpx_engine_compiles_total,
+    # mcpx_engine_resets_total, ...) show on the served GET /metrics.
+    metrics = getattr(getattr(planner, "engine", None), "metrics", None)
+    if not isinstance(metrics, Metrics):
+        metrics = Metrics()
     chaos_profile = None
     if config.resilience.chaos_profile:
         # Chaos injection (`mcpx serve --chaos profile.json`): every

@@ -40,8 +40,11 @@ poll / harvest / spill-copy drain / host-bookkeeping / idle) with
 ``lap()`` timestamps between loop sections and ``carve()`` for nested
 sub-phases, aggregated into streaming log-bucketed histograms. Because
 laps tile the loop, attribution is ~100% by construction — the bench's
-``worker_profile`` block gates on >= 95%. Disabled (the default) the
-worker loop takes no clock reads at all.
+``worker_profile`` block gates on >= 95%. It reads ``time.monotonic``, the
+spans' clock, so ``window()`` can hand the engine the phases between two
+segments' ready stamps as ``engine.segment`` span attributes. The engine
+builds one when ``tracing.enabled`` or ``telemetry.flight.profile_worker``;
+with both off the worker loop takes no clock reads at all.
 """
 
 from __future__ import annotations
@@ -73,8 +76,10 @@ __all__ = [
 
 # ===================================================================== profiler
 # Worker-loop phases. Names are the contract surfaced in queue_stats(),
-# span attrs and the bench worker_profile block — keep docs/observability.md
-# in sync when touching this tuple.
+# the engine.segment span attrs (SEGMENT_PARTS below), the worker's
+# ``mcpx.worker.<phase>`` events in a profiler trace and the bench
+# worker_profile block — keep docs/observability.md in sync when touching
+# this tuple.
 PROFILE_PHASES = (
     "idle",              # blocking waits for work (queue.get / gather window)
     "drain",             # moving queued requests into the pending line
@@ -95,6 +100,17 @@ PROFILE_PHASES = (
     "sync",              # blocking device_get waits (carved out of harvest)
     "harvest",           # lagged flag/out_buf fetch + retirement bookkeeping
 )
+
+# The named parts of an engine.segment span's ``host_ms`` (the worker busy
+# on the host between two segments' ready stamps): attribute -> the phases
+# it sums. ``sync`` and ``idle`` are attributes of their own; the phases in
+# neither (drain, host_bookkeeping, poll, spill_copy) count in ``host_ms``
+# only.
+SEGMENT_PARTS = {
+    "admit_ms": ("admit", "locality_sort", "prefix_match"),
+    "dispatch_ms": ("dispatch_submit",),
+    "harvest_ms": ("harvest",),
+}
 
 # Log-ish bucket edges (seconds) for the per-phase streaming histograms:
 # 10 us .. 10 s, roughly x3 per step — enough resolution to split "clock
@@ -118,7 +134,7 @@ class WorkerProfiler:
     Because consecutive laps tile the loop, total attributed time equals
     wall time between the first and last lap."""
 
-    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+    def __init__(self, clock: Callable[[], float] = time.monotonic) -> None:
         self._clock = clock
         self.totals = {p: 0.0 for p in PROFILE_PHASES}
         self.counts = {p: 0 for p in PROFILE_PHASES}
@@ -128,6 +144,9 @@ class WorkerProfiler:
         self.t_start: Optional[float] = None
         self.t_end = 0.0
         self.iterations = 0
+        # window(): the totals and the stamp at the previous call.
+        self._win_totals = dict(self.totals)
+        self._win_t: Optional[float] = None
 
     # ------------------------------------------------------- worker thread
     def loop_tick(self) -> None:
@@ -137,7 +156,11 @@ class WorkerProfiler:
         self.iterations += 1
 
     def lap(self, phase: str) -> None:
-        now = self._clock()
+        self._lap_at(phase, self._clock())
+
+    def _lap_at(self, phase: str, now: float) -> None:
+        if self._t_last is None:  # attached mid-iteration: no tick yet
+            self._t_last = self.t_start = now
         d = now - self._t_last - self._carved
         self._carved = 0.0
         self._t_last = now
@@ -148,33 +171,33 @@ class WorkerProfiler:
     def mark(self) -> float:
         return self._clock()
 
-    def carve(self, phase: str, t0: float) -> None:
-        d = self._clock() - t0
+    def carve(self, phase: str, t0: float) -> float:
+        """Attribute ``t0``..now to ``phase``; returns the now it read."""
+        now = self._clock()
+        d = now - t0
         if d > 0:
             self._add(phase, d)
             self._carved += d
+        return now
+
+    def window(self, phase: str, now: float) -> tuple[float, dict[str, float]]:
+        """Close the open lap into ``phase`` at ``now`` (the caller's stamp:
+        no clock read) and return (start, seconds per phase) of the window
+        since the previous call; the first window starts at the first
+        tick. Laps tile the loop, so the seconds sum to ``now - start``:
+        the engine calls this at each segment's ready stamp."""
+        self._lap_at(phase, now)
+        start = self._win_t if self._win_t is not None else self.t_start
+        prev, self._win_totals = self._win_totals, dict(self.totals)
+        self._win_t = now
+        return start, {p: v - prev[p] for p, v in self._win_totals.items()}
 
     def _add(self, phase: str, d: float) -> None:
         self.totals[phase] += d
         self.counts[phase] += 1
         self._hist[phase][bisect.bisect_right(_HIST_EDGES, d)] += 1
 
-    def totals_copy(self) -> dict:
-        return dict(self.totals)
-
     # --------------------------------------------------------- any thread
-    @staticmethod
-    def delta_ms(before: dict, after: dict) -> dict:
-        """Per-phase milliseconds between two ``totals_copy`` snapshots
-        (span attribution: the worker-loop breakdown during one request's
-        residency). Zero phases are dropped."""
-        out = {}
-        for p, v in after.items():
-            d = (v - before.get(p, 0.0)) * 1e3
-            if d > 0.005:
-                out[p] = round(d, 3)
-        return out
-
     def _phase_p50_us(self, phase: str) -> Optional[float]:
         h = self._hist[phase]
         n = sum(h)

@@ -114,9 +114,42 @@ def test_profiler_laps_tile_and_carves_subtract():
     # Laps tile the loop: everything between first and last lap is named.
     assert snap["attributed_frac"] == pytest.approx(1.0)
     assert snap["wall_s"] == pytest.approx(2.0)
-    d = WorkerProfiler.delta_ms({"admit": 0.0}, prof.totals)
-    assert d["admit"] == pytest.approx(600.0)
-    assert d["drain"] == pytest.approx(1000.0)
+    # window(): the phases since the previous call (the first: since the
+    # first tick), closed at the caller's stamp; they sum to its length.
+    t_sync = prof.mark()
+    t["now"] = 2.5
+    t_ready = prof.carve("sync", t_sync)  # returns the now it read
+    assert t_ready == 2.5
+    start, w = prof.window("harvest", t_ready)
+    assert start == 0.0
+    assert w["drain"] == pytest.approx(1.0) and w["admit"] == pytest.approx(0.6)
+    assert w["sync"] == pytest.approx(0.5) and w["harvest"] == 0.0
+    assert sum(w.values()) == pytest.approx(t_ready - start)
+    t["now"] = 2.75
+    prof.lap("harvest")                  # bookkeeping after the ready stamp
+    t0 = prof.mark()
+    t["now"] = 3.0
+    prof.carve("idle", t0)
+    t["now"] = 3.5
+    start, w = prof.window("harvest", 3.5)
+    assert start == 2.5
+    assert w["harvest"] == pytest.approx(0.75) and w["idle"] == pytest.approx(0.25)
+    assert w["sync"] == 0.0 and w["drain"] == 0.0
+    assert sum(w.values()) == pytest.approx(1.0)
+
+
+def test_profiler_reads_the_spans_clock_and_survives_a_late_attach():
+    """The profiler's default clock is the spans' (time.monotonic), and a
+    profiler attached to a live worker mid-iteration (window before any
+    loop_tick) starts its first window there instead of raising."""
+    import time
+
+    prof = WorkerProfiler()
+    assert prof._clock is time.monotonic
+    t_sync = prof.mark()
+    t_ready = prof.carve("sync", t_sync)
+    start, w = prof.window("harvest", t_ready)
+    assert start == t_ready and sum(w.values()) == pytest.approx(0.0, abs=1e-3)
 
 
 # ---------------------------------------------------------- recorder mechanics
@@ -257,44 +290,62 @@ def test_recorder_cooldown_suppresses_and_retention_prunes(tmp_path):
 
 # ------------------------------------------------------ engine worker profiler
 def test_engine_worker_profile_attribution_and_parity():
-    """ISSUE 13 acceptance (engine side): with the profiler attached the
-    worker thread's wall time is >=95% attributed to named phases and
-    surfaced in queue_stats + engine.decode span attrs; without it (the
-    default) queue_stats carries no worker_profile key and greedy token
-    outputs are byte-identical."""
+    """ISSUE 13 acceptance (engine side), on ISSUE 24's terms: with the
+    profiler on the worker thread's wall time is >=95% attributed to named
+    phases, surfaced in queue_stats and, per harvested segment, as flat
+    attributes of the engine.segment spans. With tracing.enabled false AND
+    profile_worker false the engine has no profiler: no worker_profile
+    key, none of the new attributes even on a request that carries a span,
+    and greedy token outputs byte-identical."""
     from mcpx.engine.engine import InferenceEngine
     from mcpx.telemetry import tracing
-    from mcpx.telemetry.flight import PROFILE_PHASES
+    from mcpx.telemetry.flight import PROFILE_PHASES, SEGMENT_PARTS
     from mcpx.telemetry.tracing import Tracer
 
-    def cfg(profile):
+    timeline = {"seq", "prefill_rows", "period_ms", "sync_ms", "idle_ms",
+                "host_ms", *SEGMENT_PARTS}
+
+    def cfg(on):
         return MCPXConfig.from_dict(
             {
                 "model": {"size": "test", "max_seq_len": 256},
                 "engine": {"max_batch_size": 4, "max_decode_len": 12},
-                "telemetry": {"flight": {"profile_worker": profile}},
+                "tracing": {"enabled": on},
+                "telemetry": {"flight": {"profile_worker": on}},
             }
         )
+
+    async def traced(eng, tracer, ids):
+        root = tracer.start_request("/plan")
+        with tracing.activate(root):
+            res = await eng.generate(
+                ids, max_new_tokens=8, constrained=False, temperature=0.0
+            )
+        tracer.finish(root)
+        return res, tracer.get(root.record.trace_id).spans
 
     async def go():
         eng_on = InferenceEngine(cfg(True))
         eng_off = InferenceEngine(cfg(False))
+        assert eng_on._profiler is not None and eng_off._profiler is None
+        # Either switch alone builds one (tracing is on by default).
+        assert InferenceEngine(MCPXConfig.from_dict(
+            {"model": {"size": "test"}, "tracing": {"enabled": False},
+             "telemetry": {"flight": {"profile_worker": True}}}
+        ))._profiler is not None
+        assert InferenceEngine(MCPXConfig.from_dict(
+            {"model": {"size": "test"}}
+        ))._profiler is not None
         await eng_on.start()
         await eng_off.start()
         try:
             ids = eng_on.tokenizer.encode("profile this plan please")
             tracer = Tracer(None, enabled=True, sample_rate=1.0)
-            root = tracer.start_request("/plan")
-            with tracing.activate(root):
-                r_on = await eng_on.generate(
-                    ids, max_new_tokens=8, constrained=False, temperature=0.0
-                )
-            tracer.finish(root)
-            r_off = await eng_off.generate(
-                ids, max_new_tokens=8, constrained=False, temperature=0.0
-            )
+            r_on, spans_on = await traced(eng_on, tracer, ids)
+            r_off, spans_off = await traced(eng_off, tracer, ids)
             # Parity: profiling only observes.
             assert r_on.token_ids == r_off.token_ids
+            assert eng_off._profiler is None
             assert "worker_profile" not in eng_off.queue_stats()
             wp = eng_on.queue_stats()["worker_profile"]
             assert set(wp["phases"]) == set(PROFILE_PHASES)
@@ -307,17 +358,31 @@ def test_engine_worker_profile_attribution_and_parity():
             assert wp["phases"]["dispatch_submit"]["total_s"] > 0
             assert wp["phases"]["sync"]["count"] >= 1
             assert wp["phases"]["harvest"]["count"] >= 1
-            # Residency attribution rode the trace: engine.decode carries
-            # the per-phase worker breakdown for the traced request.
-            rec = tracer.get(root.record.trace_id)
-            decode = [s for s in rec.spans if s.name == "engine.decode"]
-            assert decode and "worker_phases_ms" in decode[0].attrs
-            assert decode[0].attrs["worker_phases_ms"]  # non-empty
+            # The phases rode the trace per harvested segment, as flat
+            # numbers; engine.decode carries no per-request residency dict
+            # of whole-loop totals any more.
+            by_name = lambda spans, n: [s for s in spans if s.name == n]  # noqa: E731
+            segs = by_name(spans_on, "engine.segment")
+            assert segs and all(timeline <= set(s.attrs) for s in segs)
+            assert all(
+                isinstance(s.attrs[k], (int, float)) for s in segs for k in timeline
+            )
+            qw = by_name(spans_on, "engine.queue_wait")[0]
+            assert {"unseen_ms", "free_row_ms"} <= set(qw.attrs)
+            decode = by_name(spans_on, "engine.decode")[0]
+            assert not any(isinstance(v, dict) for v in decode.attrs.values())
+            # Off: the same spans, none of the new attributes.
+            segs_off = by_name(spans_off, "engine.segment")
+            assert segs_off and [s.attrs["tokens"] for s in segs_off] == [
+                s.attrs["tokens"] for s in segs
+            ]
+            for s in spans_off:
+                assert not (timeline | {"unseen_ms", "free_row_ms"}) & set(s.attrs)
         finally:
             await eng_on.aclose()
             await eng_off.aclose()
 
-    asyncio.run(go())
+    asyncio.run(asyncio.wait_for(go(), 240))
 
 
 # ------------------------------------------------------------- e2e chaos trip

@@ -336,6 +336,11 @@ def test_engine_queue_stats_surface():
     )
     eng = InferenceEngine(cfg)
     st = eng.queue_stats()
+    # Tracing is on by default and brings the worker profiler with it
+    # (ISSUE 24); a cold engine's profile is empty. With tracing and
+    # telemetry.flight.profile_worker both off the key is absent
+    # (test_flight.test_engine_worker_profile_attribution_and_parity).
+    assert st.pop("worker_profile")["iterations"] == 0
     assert st == {
         # Per-path ragged-kernel engagement (ISSUE 15): resolved at
         # construction (config + head-dim probe) so a COLD engine already

@@ -283,6 +283,55 @@ def test_failed_grammar_warm_is_visible_in_healthz_and_serving_continues():
     asyncio.run(go())
 
 
+def test_injected_engine_counters_show_on_served_metrics():
+    """build_control_plane(config, planner=...) adopts the injected
+    planner's engine Metrics as the control plane's registry: the engine's
+    series (the compile sentinel, the pool-reset counter) are on the SERVED
+    GET /metrics, next to the server's own, not on a registry nothing
+    scrapes."""
+    from mcpx.engine.engine import InferenceEngine
+    from mcpx.planner.heuristic import HeuristicPlanner
+    from mcpx.planner.llm import LLMPlanner
+
+    cfg = MCPXConfig.from_dict(
+        {
+            "model": {"size": "test", "max_seq_len": 256},
+            "engine": {"use_pallas": False, "max_batch_size": 2, "max_decode_len": 16},
+            "planner": {"kind": "llm"},
+        }
+    )
+    eng = InferenceEngine(cfg)
+
+    async def go():
+        cp, app = make_app(config=cfg, planner=LLMPlanner(eng, cfg.planner))
+        assert cp.metrics is eng.metrics
+        # A planner with no engine still gets a registry of its own.
+        other, _ = make_app(planner=HeuristicPlanner())
+        assert other.metrics is not eng.metrics
+
+        async def drive(client):
+            for _ in range(600):  # startup() starts the engine in the background
+                body = await (await client.get("/healthz")).json()
+                if body["started"]:
+                    break
+                await asyncio.sleep(0.1)
+            assert body["started"] is True and "engine_error" not in body
+            # One generate compiles its executables in the serving path.
+            await eng.generate(
+                eng.tokenizer.encode("count my compiles"), max_new_tokens=4,
+                constrained=False,
+            )
+            eng.metrics.engine_resets.inc()
+            text = await (await client.get("/metrics")).text()
+            assert 'mcpx_engine_compiles_total{executable="' in text
+            assert "mcpx_engine_resets_total 1.0" in text
+            assert 'mcpx_requests_total{endpoint="/healthz"' in text
+
+        await with_client(app, drive)
+
+    asyncio.run(asyncio.wait_for(go(), 240))
+
+
 def test_missing_registration_returns_400():
     async def go():
         cp, app = make_app()
