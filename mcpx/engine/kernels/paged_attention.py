@@ -296,7 +296,11 @@ def ragged_paged_attention(
     shape — compile count is independent of the phase mix. The pools hold
     every layer ([K, L, ...]) so the decode loop can carry them through
     lax.scan and the kernel streams just ``layer``'s slice — slicing
-    host-side would materialise a per-layer copy."""
+    host-side would materialise a per-layer copy. The kernel only READS
+    the pools: the window's own K/V rows are already in them when it is
+    called, written by ``paged_decode._write_kv_window`` as whole pages in
+    this same shape, so the pools reach the Mosaic call in the layout they
+    are kept in and XLA copies nothing to reconcile the two."""
     B, S, K, G, hd = q.shape
     _, _, _, page_size, _ = k_pages.shape
     # A window up to Q_BLOCK is one block of its own width. A wider one runs

@@ -6,12 +6,13 @@ Attention ... for TPU"): the device holds K/V page pools laid out
 head_dim]`` — so (a) the decode kernel's per-(batch, kv-head) grid step
 DMAs one contiguous ``[page_size, head_dim]`` tile per page with no
 in-kernel transposes, and (b) the decode loop can thread the pools through
-``lax.scan`` as a CARRY and write each layer's chunk with ONE
-single-advanced-index scatter into the flattened token-slot view
-(``[K, L, N*psz, hd]``) — measured ~3x cheaper on v5e than scattering
-per-layer slices through scan xs/ys, which forces whole-slice copies. The
-``K`` axis shards over the mesh's ``model`` axis when divisible (GQA); MQA
-replicates KV, the standard MQA-TP layout.
+``lax.scan`` as a CARRY and write each layer's chunk in place, whole pages
+at a time, in this same shape (``paged_decode._write_kv_window``; like
+``commit_prefill_to_pages`` below, every write through XLA is a scatter of
+whole ``[page_size, head_dim]`` pages, so the pools never leave the layout
+the kernel reads — a row-granular write made XLA relayout the whole pool
+around it, PERF.md PR 25). The ``K`` axis shards over the mesh's ``model``
+axis when divisible (GQA); MQA replicates KV, the standard MQA-TP layout.
 
 The allocator is deliberately host-side, synchronous, single-writer (the
 scheduler owns it): allocation is bookkeeping, not compute, and a single
@@ -196,28 +197,3 @@ def commit_prefill_to_pages(
         return pool.at[:, :, dest].set(chunks, mode="drop")
 
     return {"k": scatter(paged["k"], dense["k"]), "v": scatter(paged["v"], dense["v"])}
-
-
-def write_decode_kv(
-    paged: dict[str, jax.Array],
-    k_new: jax.Array,
-    v_new: jax.Array,
-    page_table: jax.Array,
-    positions: jax.Array,
-) -> dict[str, jax.Array]:
-    """Write one decode step's K/V ``[L, B, K, hd]`` at ``positions`` [B].
-
-    The target page is ``page_table[b, pos // page_size]``, slot
-    ``pos % page_size``.
-    """
-    page_size = paged["k"].shape[3]
-    chunk = positions // page_size  # [B]
-    slot = positions % page_size  # [B]
-    b_idx = jnp.arange(positions.shape[0])
-    pages = page_table[b_idx, chunk]  # [B]
-    # [L, B, K, hd] -> pool [K, L, n_pages, page_size, hd]
-    k_t = k_new.transpose(2, 0, 1, 3)  # [K, L, B, hd]
-    v_t = v_new.transpose(2, 0, 1, 3)
-    out_k = paged["k"].at[:, :, pages, slot].set(k_t, mode="drop")
-    out_v = paged["v"].at[:, :, pages, slot].set(v_t, mode="drop")
-    return {"k": out_k, "v": out_v}

@@ -66,6 +66,72 @@ def _ragged_kernel_on_mesh(
     )(qg, k_all, v_all, page_table, positions, q_lens, jnp.asarray(layer, jnp.int32))
 
 
+def _kv_window(
+    positions: jax.Array,  # [B] slot of each row's window slot 0
+    page_table: jax.Array,  # [B, Pmax]
+    S: int,
+    psz: int,
+    n_pool_pages: int,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Where a forward's ``[B, S]`` window of new K/V rows lands in the page
+    pool, at page granularity. A window of S slots starting anywhere in a
+    page touches at most ``P = cdiv(S - 1, psz) + 1`` pages of its row
+    (static per executable: 2 at a decode segment's S = 8, 9 at a 128-wide
+    suffix prefill). Returns
+
+      - ``pages`` ``[B, P]``: the pool page behind each touched table column;
+        a column past the table's width gets ``n_pool_pages``, out of range,
+        so the scatter drops it (a window that overhangs the row's last
+        column writes nothing there),
+      - ``slot`` ``[B, P * psz]``: the window slot that page slot ``t`` of
+        the gathered run holds, ``t - positions % psz``, clipped into
+        ``[0, S)``,
+      - ``live`` ``[B, P, psz]``: whether that page slot is inside the
+        window at all; the others keep what the page held.
+
+    Computed once per forward, outside the layer scan."""
+    n_win = -(-(S - 1) // psz) + 1
+    p_max = page_table.shape[1]
+    cols = (positions // psz)[:, None] + jnp.arange(n_win, dtype=positions.dtype)
+    pages = jnp.where(
+        cols < p_max,
+        jnp.take_along_axis(page_table, jnp.minimum(cols, p_max - 1), axis=1),
+        n_pool_pages,
+    )
+    slot = jnp.arange(n_win * psz, dtype=positions.dtype) - (positions % psz)[:, None]
+    live = ((slot >= 0) & (slot < S)).reshape(-1, n_win, psz)
+    return pages, jnp.clip(slot, 0, S - 1), live
+
+
+def _write_kv_window(
+    pool: jax.Array,  # [K, L, N, psz, hd], one of the two pools
+    layer: jax.Array,
+    new: jax.Array,  # [B, S, K, hd] this layer's new K or V rows
+    window: tuple[jax.Array, jax.Array, jax.Array],  # _kv_window(...)
+) -> jax.Array:
+    """Write one layer's window of new rows into the pool IN PLACE, page by
+    page, in the pool's own shape: gather the touched pages of ``layer``
+    (``[K, B, P, psz, hd]``, 1 MB at the benchmark's slab), merge the new
+    rows in by the slot mask, scatter whole pages back. The pool is never
+    reshaped and never written through XLA at less than a whole
+    ``[psz, hd]`` page, which is a whole device tile, so XLA keeps it in
+    the layout the Mosaic call reads. (A row-granular scatter through a
+    flat ``[K, L, N*psz, hd]`` view made XLA relayout the whole all-layers
+    pool before and after it, for K and for V, in every layer of every
+    forward: 66-88% of device time on the v5e, PERF.md PR 25.) Rows own
+    the pages they write (shared radix pages lie wholly before a row's
+    ``positions``), so two rows meet only on null page 0, where either's
+    garbage may win."""
+    pages, slot, live = window
+    K, _, _, psz, hd = pool.shape
+    B, n_win = pages.shape
+    old = pool[:, layer, pages]  # [K, B, P, psz, hd]
+    rows = jnp.take_along_axis(new, slot[:, :, None, None], axis=1)  # [B, P*psz, K, hd]
+    rows = rows.transpose(2, 0, 1, 3).reshape(K, B, n_win, psz, hd)
+    merged = jnp.where(live[None, ..., None], rows.astype(pool.dtype), old)
+    return pool.at[:, layer, pages].set(merged, mode="drop")
+
+
 def decode_chunk_paged(
     params: dict[str, Any],
     cfg: GemmaConfig,
@@ -89,9 +155,11 @@ def decode_chunk_paged(
     at the chain end — so S sequential decode steps collapse into one
     forward whose per-token cost is amortised over the weight loads that
     dominate decode on TPU. The pools ([K, L, N, Psz, hd], all layers) are
-    carried through the layer scan; each layer writes its chunk K/V with
-    one flat scatter, then the chunk kernel streams that layer's pages
-    once for all S queries (query i sees cache through ``positions+i``).
+    carried through the layer scan; each layer writes its chunk K/V into
+    the pages its window touches, whole pages at a time
+    (``_write_kv_window``), then the chunk kernel streams that layer's
+    pages once for all S queries (query i sees cache through
+    ``positions+i``).
 
     Tokens past a sequence's valid chain are pads; their K/V slots hold
     garbage that the next chunk (which starts at the first invalid
@@ -109,7 +177,7 @@ def decode_chunk_paged(
     row to unembed.
     """
     B, S = tokens.shape
-    K, L, N, psz, hd = paged_kv["k"].shape
+    _, _, N, psz, _ = paged_kv["k"].shape
     if use_pallas and q_lens is not None and mesh is None:
         # The ragged kernel only runs under shard_map (a one-device mesh is
         # its trivial case): a bare Mosaic call cannot lower on >1 chip, and
@@ -126,11 +194,7 @@ def decode_chunk_paged(
     x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
-    # Flat token-slot index into the [K, L, N*psz, hd] pool view: ONE
-    # single-advanced-index scatter per layer into the scan CARRY (measured
-    # ~3x cheaper on v5e than scattering per-layer slices through scan
-    # xs/ys, which copies whole pool slices).
-    flat_idx = jnp.take_along_axis(page_table, pos_mat // psz, axis=1) * psz + pos_mat % psz
+    window = _kv_window(positions, page_table, S, psz, N)
 
     def attend(q, k_all, v_all, layer):
         # Both paths stream/gather each sequence's pages ONCE for all S
@@ -170,18 +234,8 @@ def decode_chunk_paged(
         v = jnp.einsum("bsd,dkh->bskh", h, lp["wv"])
         q = apply_rope(q, pos_mat, cfg.rope_theta)
         k = apply_rope(k, pos_mat, cfg.rope_theta)
-        k_all = (
-            k_all.reshape(K, L, N * psz, hd)
-            .at[:, layer, flat_idx]
-            .set(k.transpose(2, 0, 1, 3).astype(k_all.dtype))
-            .reshape(K, L, N, psz, hd)
-        )
-        v_all = (
-            v_all.reshape(K, L, N * psz, hd)
-            .at[:, layer, flat_idx]
-            .set(v.transpose(2, 0, 1, 3).astype(v_all.dtype))
-            .reshape(K, L, N, psz, hd)
-        )
+        k_all = _write_kv_window(k_all, layer, k, window)
+        v_all = _write_kv_window(v_all, layer, v, window)
         attn = attend(q, k_all, v_all, layer)
         wo = lp["wo"].reshape(cfg.n_heads * cfg.head_dim, cfg.d_model)
         x = x + jnp.einsum("bsf,fd->bsd", attn, wo)

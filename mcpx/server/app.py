@@ -707,7 +707,7 @@ def build_app(cp: ControlPlane) -> web.Application:
     # active trace directory.
     _STARTING = "<starting>"
     _STOPPING = "<stopping>"
-    profile = {"dir": None}
+    profile = {"dir": None, "session": None}
 
     async def profile_start(request: web.Request) -> web.Response:
         body = await _body(request) if request.can_read_body else {}
@@ -717,9 +717,10 @@ def build_app(cp: ControlPlane) -> web.Application:
         if not isinstance(trace_dir, str) or not trace_dir:
             return _json_error(400, "'dir' must be a non-empty string")
         try:
-            import jax
+            import jax  # noqa: F401 - absent = 501, before any state changes
         except ImportError:
             return _json_error(501, "jax unavailable; device profiling disabled")
+        from mcpx.telemetry import device_trace
         # Reserve BEFORE the await: a concurrent start arriving while this
         # one is mid-await must hit the already-active 409 above, and a
         # concurrent STOP must see the _STARTING sentinel and back off —
@@ -727,7 +728,7 @@ def build_app(cp: ControlPlane) -> web.Application:
         profile["dir"] = _STARTING
         started = False
         try:
-            await asyncio.to_thread(jax.profiler.start_trace, trace_dir)
+            profile["session"] = await asyncio.to_thread(device_trace.start)
             started = True
         except Exception as e:  # mcpx: ignore[broad-except] - profiler state errors -> client as 409
             return _json_error(409, f"could not start trace: {e}")
@@ -743,19 +744,19 @@ def build_app(cp: ControlPlane) -> web.Application:
             return _json_error(409, "profiling not active")
         if profile["dir"] in (_STARTING, _STOPPING):
             # A start or stop is still in flight in a worker thread:
-            # dispatching stop_trace now would race it inside jax's
-            # single-session profiler state.
+            # dispatching a stop now would race it inside the profiler's
+            # single-session state.
             return _json_error(409, "profiler transition in progress; retry")
-        import jax
+        from mcpx.telemetry import device_trace
 
         # Reserve: concurrent stops (and starts) 409 on the sentinel above
-        # instead of racing the in-flight stop_trace below.
+        # instead of racing the in-flight stop below.
         trace_dir, profile["dir"] = profile["dir"], _STOPPING
         stopped = False
         try:
-            # Off the event loop: stop_trace serializes the whole capture to
-            # disk, which can take seconds under real decode traffic.
-            await asyncio.to_thread(jax.profiler.stop_trace)
+            # Off the event loop: collecting the capture costs ~25 us a
+            # device event, tens of seconds under real decode traffic.
+            await asyncio.to_thread(device_trace.stop, profile["session"], trace_dir)
             stopped = True
         except Exception as e:  # mcpx: ignore[broad-except] - error -> client as 500
             return _json_error(500, f"could not stop trace: {e}")
@@ -764,6 +765,8 @@ def build_app(cp: ControlPlane) -> web.Application:
             # failure restore the active state: jax's session is unknown,
             # and dropping it would wedge both endpoints behind 409s.
             profile["dir"] = None if stopped else trace_dir  # mcpx: ignore[async-shared-mutation] - resolving this handler's own reservation; racers were 409'd by it
+            if stopped:
+                profile["session"] = None
         return web.json_response({"profiling": "stopped", "dir": trace_dir})
 
     app.router.add_post("/plan", plan)
@@ -892,12 +895,14 @@ def build_app(cp: ControlPlane) -> web.Application:
             log.warning("shutdown during profiler transition; skipping flush")
             profile["dir"] = None
         if profile["dir"] is not None:
-            # stop_trace is what flushes the capture to disk; without this a
+            # stop is what flushes the capture to disk; without this a
             # trace active at shutdown would vanish silently.
-            import jax
+            from mcpx.telemetry import device_trace
 
             try:
-                await asyncio.to_thread(jax.profiler.stop_trace)
+                await asyncio.to_thread(
+                    device_trace.stop, profile["session"], profile["dir"]
+                )
             except Exception:  # broad: best-effort at shutdown, and logged
                 log.exception("failed to flush active profiler trace")
             profile["dir"] = None  # mcpx: ignore[async-shared-mutation] - shutdown path; no handler can race on_cleanup

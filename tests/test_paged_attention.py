@@ -15,7 +15,6 @@ from mcpx.engine.kv_cache import (
     PageAllocator,
     commit_prefill_to_pages,
     init_paged_kv,
-    write_decode_kv,
 )
 from mcpx.models.gemma.config import GemmaConfig
 
@@ -98,9 +97,23 @@ def test_commit_and_decode_write_roundtrip():
     # Decode write at position 5 for seq1 -> page 4 slot 1.
     k_new = jax.random.normal(jax.random.PRNGKey(3), (2, B, 2, 16))
     v_new = jax.random.normal(jax.random.PRNGKey(4), (2, B, 2, 16))
-    paged = write_decode_kv(paged, k_new, v_new, table, jnp.array([8 % (psz * 4), 5]))
+    from mcpx.engine.paged_decode import _kv_window, _write_kv_window
+
+    window = _kv_window(jnp.array([8, 5]), table, 1, psz, n_pages)
+    for layer in range(2):
+        paged = {
+            "k": _write_kv_window(paged["k"], layer, k_new[layer][:, None], window),
+            "v": _write_kv_window(paged["v"], layer, v_new[layer][:, None], window),
+        }
     np.testing.assert_allclose(
         np.asarray(paged["k"][:, 0, 4, 1]), np.asarray(k_new[0, 1])
+    )
+    np.testing.assert_allclose(
+        np.asarray(paged["v"][:, 1, 4, 1]), np.asarray(v_new[1, 1])
+    )
+    # seq1's prefill rows in the same page are still there
+    np.testing.assert_allclose(
+        np.asarray(paged["k"][:, 1, 4, 0]), np.asarray(dense["k"][1, 1, psz])
     )
 
 

@@ -1,0 +1,336 @@
+"""The paged forward's K/V write (``paged_decode._write_kv_window``): whole
+pages, in place, in the pool's own shape.
+
+Three guards:
+  - the write leaves the pools bit-identical to the row-granular flat
+    scatter it replaced (kept HERE as the oracle, nowhere in ``mcpx/``),
+    through the whole forward on both attention routes and directly on
+    random pools;
+  - the traced forward never shows the pool in a flat ``[K, L, N*psz, hd]``
+    view and only ever scatters whole ``[psz, hd]`` pages into it;
+  - compiled for a described v5e (no chip attached), the layer loop holds
+    no ``copy`` of the pool: the relayout the flat scatter cost on the
+    chip (PERF.md, PR 25) cannot come back unnoticed.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mcpx.engine import paged_decode
+from mcpx.engine.kv_cache import init_paged_kv
+from mcpx.engine.paged_decode import _kv_window, _write_kv_window, decode_chunk_paged
+from mcpx.models.gemma.config import GemmaConfig
+from mcpx.models.gemma.model import init_params
+from mcpx.parallel.mesh import make_mesh
+
+
+# ------------------------------------------------------------------ oracle
+def _flat_scatter_oracle(positions, page_table, S):
+    """The write this PR removed, verbatim: one row-granular scatter per
+    layer through the flat ``[K, L, N*psz, hd]`` view of the pool."""
+
+    def write(pool, layer, new, _window):
+        K, L, N, psz, hd = pool.shape
+        pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)
+        flat_idx = (
+            jnp.take_along_axis(page_table, pos_mat // psz, axis=1) * psz + pos_mat % psz
+        )
+        return (
+            pool.reshape(K, L, N * psz, hd)
+            .at[:, layer, flat_idx]
+            .set(new.transpose(2, 0, 1, 3).astype(pool.dtype))
+            .reshape(K, L, N, psz, hd)
+        )
+
+    return write
+
+
+def _assert_pools_identical(got, want):
+    """Bit-identical on every page but null page 0 (never read; pads, done
+    rows and dropped columns may leave either write's garbage there)."""
+    for name in ("k", "v"):
+        g, w = np.asarray(got[name]), np.asarray(want[name])
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g[:, :, 1:], w[:, :, 1:], err_msg=name)
+
+
+# --------------------------------------------------- through the forward
+_PSZ, _PMAX = 16, 10
+
+# head layout: (n_heads, n_kv_heads, head_dim)
+_LAYOUTS = {
+    "mha16": (16, 16, 128),  # olmo2-1b: 16/16
+    "gqa8of32": (32, 8, 128),  # mistral-7b: 8/32
+    "mqa-hd256": (8, 1, 256),  # chip_smoke's Gemma-2B shape
+}
+
+
+def _window_case(name):
+    """(S, positions, q_lens, table edit) of one window class; four rows,
+    each with ``_PMAX`` private pages unless the edit says otherwise."""
+    B = 4
+    table = np.arange(1, B * _PMAX + 1, dtype=np.int32).reshape(B, _PMAX)
+    q_lens = None
+    if name == "S1":
+        S, pos = 1, [0, 15, 16, 37]
+    elif name == "S8-straddle":
+        # windows that end on, start on and cross a page edge
+        S, pos = 8, [8, 16, 12, 30]
+        q_lens = [8, 3, 8, 1]
+    elif name == "S128-ragged-prefill":
+        # a 128-wide suffix prefill at page-aligned matched depths, one row
+        # starting mid-page (a decode row riding a prefill-width window)
+        S, pos = 128, [0, 16, 32, 5]
+        q_lens = [128, 77, 1, 40]
+    elif name == "done-rows":
+        # retired rows: page table zeroed, position frozen, zero live slots
+        S, pos = 8, [20, 44, 3, 90]
+        q_lens = [8, 0, 5, 0]
+        table[1] = 0
+        table[3] = 0
+    elif name == "overhang":
+        # row 0's window runs past its last table column (dropped); row 1
+        # ends exactly on it; row 2 has only three pages allocated
+        S, pos = 8, [_PMAX * _PSZ - 3, _PMAX * _PSZ - 8, 44, 0]
+        q_lens = [2, 8, 4, 8]
+        table[2, 3:] = 0
+    else:  # pragma: no cover
+        raise AssertionError(name)
+    return S, np.asarray(pos, np.int32), q_lens, table
+
+
+_FORWARD_CASES = [
+    (w, "mha16") for w in
+    ("S1", "S8-straddle", "S128-ragged-prefill", "done-rows", "overhang")
+] + [("S8-straddle", "gqa8of32"), ("S8-straddle", "mqa-hd256")]
+
+
+@pytest.mark.parametrize("route", ["kernel-interpret", "jnp"])
+@pytest.mark.parametrize("window,layout", _FORWARD_CASES)
+def test_forward_leaves_the_pools_as_the_flat_scatter_did(window, layout, route, monkeypatch):
+    """The whole forward, new write against the oracle: logits and BOTH
+    pools bit-identical (every page but null page 0), for every window
+    class the engine dispatches, on the kernel route (interpreted) and the
+    jnp reference route."""
+    n_heads, n_kv, hd = _LAYOUTS[layout]
+    cfg = GemmaConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=n_heads, n_kv_heads=n_kv,
+        head_dim=hd, d_ff=64, dtype="float32",
+    )
+    S, pos, q_lens, table = _window_case(window)
+    B = pos.shape[0]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    pools = init_paged_kv(cfg, B * _PMAX + 1, _PSZ)
+    # a resident history: every slot holds something the write must keep
+    pools = {
+        n: jax.random.normal(jax.random.PRNGKey(i), p.shape, p.dtype)
+        for i, (n, p) in enumerate(pools.items())
+    }
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (B, S), 0, cfg.vocab_size)
+    positions, page_table = jnp.asarray(pos), jnp.asarray(table)
+    kw = dict(use_pallas=route != "jnp", interpret=True)
+    if q_lens is not None:
+        kw.update(
+            q_lens=jnp.asarray(q_lens, jnp.int32),
+            mesh=make_mesh(data=1, model=1, devices=jax.devices()[:1]),
+        )
+
+    def forward():
+        return jax.jit(
+            lambda p, t, po, tb, kv: decode_chunk_paged(p, cfg, t, po, tb, kv, **kw)
+        )(params, tokens, positions, page_table, pools)
+
+    got_logits, got = forward()
+    monkeypatch.setattr(
+        paged_decode, "_write_kv_window", _flat_scatter_oracle(positions, page_table, S)
+    )
+    want_logits, want = forward()
+    _assert_pools_identical(got, want)
+    np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(want_logits))
+    # not vacuous: the write changed pages other than page 0
+    assert not np.array_equal(np.asarray(got["k"][:, :, 1:]), np.asarray(pools["k"][:, :, 1:]))
+
+
+# ------------------------------------------------------ the write alone
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("S", [1, 2, 8, 16, 17, 33, 128])
+def test_write_alone_matches_the_flat_scatter_at_every_offset(S, dtype):
+    """The write function on random pools, every start offset within a
+    page at once (16 rows, offset = row), a window past the table's end
+    among them, in the served dtype too (a bf16 page is one packed tile)."""
+    K, L, psz, hd = 2, 3, 16, 128
+    B = psz
+    p_max = -(-(S + psz) // psz)
+    N = B * p_max + 1
+    pool = jax.random.normal(jax.random.PRNGKey(S), (K, L, N, psz, hd), jnp.dtype(dtype))
+    new = jax.random.normal(jax.random.PRNGKey(S + 1), (B, S, K, hd), jnp.float32)
+    table = jnp.arange(1, N, dtype=jnp.int32).reshape(B, p_max)
+    # rows 0-7 start in their first page; rows 8-15 in their second, where
+    # the later ones overhang the table's last column
+    positions = jnp.arange(B, dtype=jnp.int32) + jnp.where(jnp.arange(B) >= 8, psz, 0)
+    layer = jnp.asarray(1, jnp.int32)
+    window = _kv_window(positions, table, S, psz, N)
+    got = jax.jit(_write_kv_window)(pool, layer, new, window)
+    want = jax.jit(_flat_scatter_oracle(positions, table, S))(pool, layer, new, None)
+    np.testing.assert_array_equal(
+        np.asarray(got[:, :, 1:].astype(jnp.float32)),
+        np.asarray(want[:, :, 1:].astype(jnp.float32)),
+    )
+    # the other layers are untouched, bit for bit
+    np.testing.assert_array_equal(
+        np.asarray(got[:, 0].astype(jnp.float32)), np.asarray(pool[:, 0].astype(jnp.float32))
+    )
+
+
+def test_window_pages_are_static_in_the_window_width():
+    """P = cdiv(S - 1, psz) + 1: 2 at the segment's S = 8, 9 at a 128-wide
+    suffix prefill; a column past the table is routed out of range."""
+    table = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    for S, n_win in ((1, 1), (8, 2), (16, 2), (17, 2), (18, 3), (128, 9)):
+        pages, slot, live = _kv_window(jnp.asarray([0, 63], jnp.int32), table, S, 16, 99)
+        assert pages.shape == (2, n_win) and live.shape == (2, n_win, 16)
+        assert slot.shape == (2, n_win * 16)
+        assert int(live.sum()) == 2 * S  # the table's end is the pages' business, not the mask's
+        assert int(pages[0, 0]) == 1 and int(pages[1, 0]) == 8
+        assert all(int(p) == 99 for p in pages[1, 1:])  # past the table: dropped
+
+
+# --------------------------------------------------------- structure
+# The benchmark's slab (benchmarks/chip/configs/olmo2-1b.json): 8 rows, a
+# page table 32 wide over a 257-page pool, MHA 16/16 at head_dim 128.
+_SLAB = dict(B=8, p_max=32, psz=16)
+_OLMO = dict(
+    vocab_size=3072, d_model=2048, n_layers=16, n_heads=16, n_kv_heads=16,
+    head_dim=128, d_ff=8192, rope_theta=500000.0, dtype="bfloat16",
+)
+
+
+def _slab_args(cfg, S, sharding=None):
+    B, p_max, psz = _SLAB["B"], _SLAB["p_max"], _SLAB["psz"]
+
+    def sds(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+        )
+
+    params = sds(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pools = sds(jax.eval_shape(lambda: init_paged_kv(cfg, B * p_max + 1, psz)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)  # noqa: E731
+    return params, i32(B, S), i32(B), i32(B, p_max), pools, i32(B)
+
+
+def _slab_step(cfg, mesh):
+    def step(params, tokens, positions, table, pools, q_lens):
+        return decode_chunk_paged(
+            params, cfg, tokens, positions, table, pools, use_pallas=True,
+            interpret=False, mesh=mesh, logits_at=jnp.maximum(q_lens - 1, 0),
+            q_lens=q_lens,
+        )
+
+    return step
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk(inner)
+
+
+@pytest.mark.parametrize("S", [1, 8, 128])
+def test_scan_body_never_sees_a_flat_pool_and_scatters_whole_pages(S):
+    """Traced at the slab's shape: no operation in the layer scan has an
+    operand or result of the pool's flat ``[K, L, N*psz, hd]`` shape, and
+    every scatter into the pool writes whole ``[psz, hd]`` windows (two
+    pools x one scatter a layer). A row-granular write, whichever view it
+    goes through, fails here before it costs a relayout on the chip."""
+    cfg = GemmaConfig(**_OLMO)
+    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    args = _slab_args(cfg, S)
+    K, L, N, psz, hd = args[4]["k"].shape
+    jaxpr = jax.make_jaxpr(_slab_step(cfg, mesh))(*args).jaxpr
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1, [e.primitive.name for e in jaxpr.eqns]
+    pool_scatters = 0
+    for eqn in _walk(scans[0].params["jaxpr"].jaxpr):
+        shapes = [
+            tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars) if hasattr(v, "aval")
+        ]
+        assert (K, L, N * psz, hd) not in shapes, f"flat pool view in the scan body: {eqn}"
+        if eqn.primitive.name.startswith("scatter") and shapes[0] == (K, L, N, psz, hd):
+            pool_scatters += 1
+            dn = eqn.params["dimension_numbers"]
+            updates = eqn.invars[2].aval.shape
+            # the page's two dimensions are window dimensions, at full size
+            assert 3 not in dn.inserted_window_dims and 4 not in dn.inserted_window_dims, dn
+            assert 3 not in dn.scatter_dims_to_operand_dims, dn
+            assert 4 not in dn.scatter_dims_to_operand_dims, dn
+            assert tuple(updates[d] for d in dn.update_window_dims)[-2:] == (psz, hd), updates
+        if eqn.primitive.name == "dynamic_update_slice":
+            assert shapes[0] != (K, L, N, psz, hd) or shapes[1][-2:] == (psz, hd), eqn
+    assert pool_scatters == 2, pool_scatters
+
+
+# ------------------------------------------- compiled for a described v5e
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One chip of a DESCRIBED v5e:2x2 (nothing attached, nothing runs):
+    the TPU compiler is installed beside jax, so the layout it assigns the
+    pool can be read on the CPU. Built here, not at import: only this
+    test's process may load the TPU library."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    return mesh, NamedSharding(mesh, PartitionSpec())
+
+
+@pytest.mark.parametrize("S", [8, 128])
+def test_compiled_for_v5e_the_layer_loop_copies_no_pool(one_v5e, S):
+    """The optimised HLO of the forward at the slab's shape, compiled for
+    the v5e: no ``copy`` (or copy-start) anywhere in the module produces an
+    array of the pool's shape in either view, and the program needs no
+    pool-sized temporary. With the flat scatter this module held four such
+    copies in the while body and 271 MB of temporaries."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    mesh, replicated = one_v5e
+    cfg = GemmaConfig(**_OLMO)
+    args = _slab_args(cfg, S, sharding=replicated)
+    K, L, N, psz, hd = args[4]["k"].shape
+    # a described device's executable cannot be read back from the
+    # persistent cache; keep this compile out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(_slab_step(cfg, mesh), donate_argnums=(4,)).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the Mosaic kernel is in the program
+    views = (f"[{K},{L},{N},{psz},{hd}]", f"[{K},{L},{N * psz},{hd}]")
+    copies = [
+        line.strip()[:160]
+        for line in text.splitlines()
+        if re.search(r"= \S+ copy(-start)?\(", line) and any(v in line.split(" copy")[0] for v in views)
+    ]
+    assert not copies, copies
+    pool_bytes = K * L * N * psz * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8

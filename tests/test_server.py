@@ -347,30 +347,32 @@ def test_missing_registration_returns_400():
 
 def test_profile_transition_in_progress_409(monkeypatch):
     """The concurrency contract of /profile/start|stop (ISSUE 7 satellite):
-    while a start's ``start_trace`` is still in flight in a worker thread,
+    while a start's ``device_trace.start`` is still in flight in a worker thread,
     a concurrent stop must 409 on the _STARTING sentinel ("transition in
     progress") and a concurrent start must 409 on the reservation — neither
     may race jax's single-session profiler state."""
     import threading
 
-    import jax
+    from mcpx.telemetry import device_trace
 
     release = threading.Event()
     entered = threading.Event()
     calls = {"start": 0, "stop": 0}
 
-    def fake_start(trace_dir):
+    def fake_start():
         calls["start"] += 1
         entered.set()
         release.wait(10)
+        return "session"
 
-    def fake_stop():
+    def fake_stop(session, trace_dir):
+        assert session == "session"
         calls["stop"] += 1
 
     async def go():
         cp, app = make_app()
-        monkeypatch.setattr(jax.profiler, "start_trace", fake_start)
-        monkeypatch.setattr(jax.profiler, "stop_trace", fake_stop)
+        monkeypatch.setattr(device_trace, "start", fake_start)
+        monkeypatch.setattr(device_trace, "stop", fake_stop)
 
         async def drive(client):
             task = asyncio.create_task(
@@ -402,24 +404,24 @@ def test_shutdown_during_profiler_transition_skips_flush(monkeypatch):
     comment, now pinned."""
     import threading
 
-    import jax
+    from mcpx.telemetry import device_trace
 
     release = threading.Event()
     entered = threading.Event()
     calls = {"start": 0, "stop": 0}
 
-    def fake_start(trace_dir):
+    def fake_start():
         calls["start"] += 1
 
-    def fake_stop():
+    def fake_stop(session, trace_dir):
         calls["stop"] += 1
         entered.set()
         release.wait(10)
 
     async def go():
         cp, app = make_app()
-        monkeypatch.setattr(jax.profiler, "start_trace", fake_start)
-        monkeypatch.setattr(jax.profiler, "stop_trace", fake_stop)
+        monkeypatch.setattr(device_trace, "start", fake_start)
+        monkeypatch.setattr(device_trace, "stop", fake_stop)
 
         async def drive(client):
             r = await client.post("/profile/start", json={"dir": "/tmp/mcpx-prof-s"})
@@ -428,7 +430,7 @@ def test_shutdown_during_profiler_transition_skips_flush(monkeypatch):
             assert await asyncio.to_thread(entered.wait, 10)
             # Stop is mid-flight (_STOPPING). Run the app's cleanup NOW —
             # the shutdown-during-transition path: it must not dispatch a
-            # second stop_trace (the flush) and must clear the sentinel.
+            # second stop (the flush) and must clear the sentinel.
             before = calls["stop"]
             for cb in app.on_cleanup:
                 await cb(app)
@@ -447,8 +449,11 @@ def test_shutdown_during_profiler_transition_skips_flush(monkeypatch):
 
 
 def test_profile_endpoints(tmp_path):
-    """POST /profile/start captures a jax.profiler trace of device work done
-    while active; double-start and stop-without-start are 409s."""
+    """POST /profile/start captures a profiler trace of device work done
+    while active; double-start and stop-without-start are 409s. The capture
+    is ONE .xplane.pb that jax's own reader opens, and no Chrome-trace JSON
+    beside it (the export that made a flush outlast the request timeout on
+    the chip: telemetry/device_trace.py)."""
 
     async def go():
         cp, app = make_app()
@@ -470,8 +475,17 @@ def test_profile_endpoints(tmp_path):
             assert (await r3.json())["dir"] == trace_dir
             import pathlib
 
-            files = list(pathlib.Path(trace_dir).rglob("*"))
-            assert any(f.is_file() for f in files), "no trace artifacts written"
+            files = [f for f in pathlib.Path(trace_dir).rglob("*") if f.is_file()]
+            assert [f.suffixes[-2:] for f in files] == [[".xplane", ".pb"]], files
+            assert files[0].parent.parent == pathlib.Path(trace_dir) / "plugins" / "profile"
+            from jax.profiler import ProfileData
+
+            planes = [p.name for p in ProfileData.from_file(str(files[0])).planes]
+            assert any(p.startswith("/host:") for p in planes), planes
+            # the session is released: a second capture can start
+            r4 = await client.post("/profile/start", json={"dir": trace_dir})
+            assert r4.status == 200, await r4.text()
+            assert (await client.post("/profile/stop")).status == 200
 
         await with_client(app, drive)
 
