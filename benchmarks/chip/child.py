@@ -4,10 +4,12 @@ configuration's dimensions, on loopback.
 ``mcpx serve`` can only build the presets of ``GemmaConfig.named``; the
 benchmark's configurations are other models' published widths. So this
 process assembles the same server from the seams that exist:
-``InferenceEngine(config, model_cfg=GemmaConfig(**dims))`` ->
-``LLMPlanner(engine, config.planner)`` -> ``build_control_plane(config,
-planner=...)`` -> ``build_app`` — the aiohttp app ``mcpx serve`` runs,
-unchanged. Nothing in ``mcpx/`` is patched.
+``InferenceEngine(config, model_cfg=...)`` -> ``LLMPlanner(engine,
+config.planner)`` -> ``build_control_plane(config, planner=...)`` ->
+``build_app`` — the aiohttp app ``mcpx serve`` runs, unchanged. Nothing in
+``mcpx/`` is patched. The model config comes from the configuration's block
+module (``models/<module>.py``, named by the configuration file): this file
+knows no model.
 
 One benchmark-only route is added to the app: ``POST /bench/reference``
 runs the program's model step, at the slab's shape, against the plain
@@ -30,28 +32,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
-def gemma_dims(config: dict, vocab_size: int) -> dict:
-    """The configuration file's published keys -> ``GemmaConfig`` fields."""
-    if config["vocab_size"] != vocab_size:
-        raise SystemExit(
-            f"config says vocab_size {config['vocab_size']}, the repo's "
-            f"tokenizer has {vocab_size}"
-        )
-    return dict(
-        vocab_size=vocab_size,
-        d_model=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        max_seq_len=config["max_position_embeddings"],
-        dtype=config["dtype"],
-    )
-
-
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config-file", required=True, help="configs/<config>.json")
@@ -62,11 +42,11 @@ def main(argv: list[str] | None = None) -> int:
     t_start = time.time()
     sys.path.insert(0, ROOT)
 
+    import spec
     from aiohttp import web
 
     from mcpx.core.config import MCPXConfig
     from mcpx.engine.engine import InferenceEngine
-    from mcpx.models.gemma.config import GemmaConfig
     from mcpx.planner.llm import LLMPlanner
     from mcpx.server.app import build_app
     from mcpx.server.factory import build_control_plane
@@ -75,6 +55,7 @@ def main(argv: list[str] | None = None) -> int:
     configure_logging()
     with open(args.config_file) as f:
         config = json.load(f)
+    block = spec.load_block(config.get("module"), HERE)
     cfg = MCPXConfig.from_file(args.mcpx_config)
     t_imported = time.time()
 
@@ -91,12 +72,11 @@ def main(argv: list[str] | None = None) -> int:
     from mcpx.models.tokenizer import make_tokenizer
 
     vocab = make_tokenizer(cfg.model.vocab).vocab_size
+    # The published keys are checked in a rehearsal too; only the size differs.
+    model_cfg = block.model_config(spec.model_keys(config), vocab)
     if args.rehearse_cpu:
-        # Rehearsal only: the CPU-sized model, the same code paths.
-        model_cfg = GemmaConfig.named("test", vocab_size=vocab)
-    else:
-        model_cfg = GemmaConfig(**gemma_dims(config, vocab))
-    dims = dataclasses.asdict(model_cfg)  # what reference.py reads
+        model_cfg = block.rehearsal_config(vocab)  # the same block at CPU size
+    dims = dataclasses.asdict(model_cfg)  # what the block's reference reads
     engine = InferenceEngine(cfg, model_cfg=model_cfg)
     planner = LLMPlanner(engine, cfg.planner)
     cp = build_control_plane(cfg, planner=planner)
@@ -109,12 +89,11 @@ def main(argv: list[str] | None = None) -> int:
         body = await request.json()
         if engine.state != "ready":
             return web.json_response({"error": f"engine {engine.state}"}, status=409)
-        sys.path.insert(0, HERE)
         from reference import compare_with_engine_step
 
         def _run():
             return compare_with_engine_step(
-                engine._params,  # mcpx: ignore[thread-ownership] - read-only use after 'ready': the worker binds _params once, in _setup
+                block, engine._params,  # mcpx: ignore[thread-ownership] - read-only use after 'ready': the worker binds _params once, in _setup
                 model_cfg, dims, engine._mesh,
                 seed=int(body.get("seed", 0)),
                 interpret=bool(cfg.engine.interpret),
@@ -133,6 +112,7 @@ def main(argv: list[str] | None = None) -> int:
     async def marks_handler(request: web.Request) -> web.Response:
         return web.json_response(
             {"t_start": t_start, "t_imported": t_imported, "t_app_built": t_built,
+             "kernel_paths": block.kernel_paths,
              "engine_metrics": engine.metrics.render().decode()}
         )
 

@@ -1,9 +1,12 @@
-"""The per-layer metric readers: a small fixed vocabulary.
+"""The per-layer metric readers: a small vocabulary that files extend.
 
 A metric file (``metrics/<metric>.json``) names one reader and its
 arguments. Every reader takes the run's collected evidence (``Evidence``)
 and returns a number, or None when it finds nothing to read — the harness
-then leaves that metric out of the line.
+then leaves that metric out of the line. A reader is looked up among the
+nine below first, then among the public functions that the files of
+``reader_files/*.py`` define, each ``(ev, **args)`` (``vocabulary``); a new
+reader is a new file there, which may import this module and ``stats``.
 
   client_quantile{q}                 quantile of the load generator's own
                                      lateness samples (closed: reply-to-next-
@@ -21,9 +24,14 @@ then leaves that metric out of the line.
   trace_attr_mean{terms}             per retained trace, the signed sum of
                                      span attributes ``[[span, attr, sign]]``;
                                      mean over the traces that have the first
-  counter_delta_ratio{endpoint,      (after - before) of JSON counters read
-      num,den,scale}                 from ``endpoint`` around the window:
-                                     sum of ``num`` paths over sum of ``den``
+  counter_delta_ratio{endpoint,      (after - before) of counters read from
+      num,den,scale}                 ``endpoint`` around the window: sum of
+                                     ``num`` paths over sum of ``den``. The
+                                     harness fetches every ``endpoint`` that
+                                     a metric of the cell names in its args;
+                                     a path is dotted into a JSON body, or one
+                                     whole sample of ``/metrics``, labels and
+                                     all (``run.prom_samples``)
   device_op_share{regex,scale}       device seconds of the ops whose label
                                      matches ``regex`` over the traced window
   device_idle_share{scale}           1 - busy / window of the device trace
@@ -34,12 +42,16 @@ then leaves that metric out of the line.
 from __future__ import annotations
 
 import dataclasses
+import inspect
+import os
 import re
 from typing import Callable, Optional
 
+from spec import import_file
 from stats import cluster_by_start, quantile, span_self_ms
 
 SEGMENT_GAP_MS = 20.0  # per-trace clocks agree to ~1 ms; segments last >> 20 ms
+READER_FILES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "reader_files")
 
 
 @dataclasses.dataclass
@@ -52,6 +64,10 @@ class Evidence:
     counters_after: dict  # endpoint -> JSON body after the window
     device: Optional[dict]  # xplane.reduce_device(...) of the traced slice
     memory_in_use_bytes: Optional[int]  # after the window, fullest device
+    # For a reader that computes a kernel's operations and bytes: the
+    # configuration file as run, and the chip's kind (``peaks.peaks_for``).
+    config: Optional[dict] = None
+    device_kind: Optional[str] = None
 
 
 def _spans(ev: Evidence, name: str):
@@ -148,7 +164,9 @@ def histogram(ev: Evidence, name: str, attr: str) -> dict:
 
 
 def _path(obj, dotted: str) -> Optional[float]:
-    for part in dotted.split("."):
+    if isinstance(obj, dict) and dotted in obj:  # one sample of /metrics, whole
+        obj, dotted = obj[dotted], ""
+    for part in filter(None, dotted.split(".")):
         if not isinstance(obj, dict) or part not in obj:
             return None
         obj = obj[part]
@@ -207,7 +225,32 @@ READERS: dict[str, Callable[..., Optional[float]]] = {
 }
 
 
-def read_metric(ev: Evidence, reader: str, args: dict) -> Optional[float]:
-    if reader not in READERS:
-        raise KeyError(f"unknown reader {reader!r} (vocabulary: {sorted(READERS)})")
-    return READERS[reader](ev, **args)
+def vocabulary(reader_dir: str = READER_FILES) -> dict[str, Callable[..., Optional[float]]]:
+    """Every reader by name: ``READERS`` and the public functions defined in
+    the files of ``reader_dir``. A name defined twice is an error."""
+    found = dict(READERS)
+    where = {name: "readers.py" for name in READERS}
+    files = sorted(f for f in os.listdir(reader_dir) if f.endswith(".py")) if os.path.isdir(reader_dir) else []
+    for fname in files:
+        module = import_file(os.path.join(reader_dir, fname), "chip_reader_")
+        for name, fn in inspect.getmembers(module, inspect.isfunction):
+            if name.startswith("_") or fn.__module__ != module.__name__:
+                continue  # a helper, or a function the file only imported
+            if name in found:
+                raise ValueError(f"reader {name!r} is defined in {where[name]} and in {fname}")
+            found[name], where[name] = fn, fname
+    return found
+
+
+def reader_named(reader: str, found: dict) -> Callable[..., Optional[float]]:
+    if reader not in found:
+        raise KeyError(
+            f"unknown reader {reader!r} (vocabulary: {sorted(found)}; looked in readers.py "
+            "and reader_files/*.py)"
+        )
+    return found[reader]
+
+
+def read_metric(ev: Evidence, reader: str, args: dict, found: Optional[dict] = None) -> Optional[float]:
+    """``found`` is a ``vocabulary(...)`` made once; None looks the default up."""
+    return reader_named(reader, vocabulary() if found is None else found)(ev, **args)

@@ -48,8 +48,6 @@ STARTUP_DEADLINE_S = 1100.0  # of the 1,200 s a first (compiling) run may take
 WARM_DEADLINE_S = 240.0
 SCRAPE_TIMEOUT_S = 120.0
 TRACE_START_S = 2.0  # the profiled slice starts this far into the window
-# EngineConfig sizes a configuration file states at its top level.
-ENGINE_SIZES = ("max_batch_size", "max_pages_per_seq", "max_decode_len", "warmup_max_len")
 
 
 class BenchFailure(Exception):
@@ -66,7 +64,8 @@ class Client:
 
     def request(self, method: str, path: str, body: dict | None = None):
         """(status, parsed JSON, headers); status 0 on a transport error or
-        client timeout."""
+        client timeout. A body that is not JSON (``/metrics``) comes back as
+        ``{"text": ...}``."""
         data = None if body is None else json.dumps(body).encode()
         headers = {"content-type": "application/json"} if data is not None else {}
         for attempt in (0, 1):
@@ -81,7 +80,8 @@ class Client:
                 try:
                     parsed = json.loads(raw.decode()) if raw else {}
                 except json.JSONDecodeError:
-                    parsed = {"error": raw[:300].decode(errors="replace")}
+                    text = raw.decode(errors="replace")
+                    parsed = {"error": text[:300], "text": text}
                 return resp.status, parsed, resp.headers
             except (http.client.HTTPException, OSError) as e:
                 self.close()
@@ -148,11 +148,42 @@ def plan_problem(status: int, body: dict, names: set, want_origin: str) -> str:
 
 def prom_total(text: str, name: str) -> float:
     """Sum of every sample of counter ``name`` in a Prometheus exposition."""
-    total = 0.0
+    return sum(v for key, v in prom_samples(text).items() if key == name or key.startswith(name + "{"))
+
+
+def prom_samples(text: str) -> dict[str, float]:
+    """A Prometheus exposition as ``{sample name with its label set: value}``,
+    e.g. ``'mcpx_engine_compiles_total{executable="admit"}'``: a counter
+    path of a metric file can then address one labelled sample."""
+    samples: dict[str, float] = {}
     for line in text.splitlines():
-        if line.startswith(name) and line[len(name): len(name) + 1] in ("{", " "):
-            total += float(line.rsplit(" ", 1)[1])
-    return total
+        line = line.strip()
+        if not line or line.startswith("#"):
+            continue
+        key, _, value = line.rpartition(" ")
+        try:
+            samples[key.strip()] = float(value)
+        except ValueError:
+            continue  # not a sample line
+    return samples
+
+
+def counter_endpoints(cell: spec.Cell) -> list[str]:
+    """Every ``endpoint`` a per-layer metric of the cell names in its args."""
+    return sorted({str(m.args["endpoint"]) for m in cell.per_layer if "endpoint" in m.args})
+
+
+def fetch_counters(ctl: "Client", endpoints: list[str]) -> dict[str, dict]:
+    """The counters as they stand: each endpoint's JSON body as it is, a text
+    body (``/metrics``) parsed by ``prom_samples``. An endpoint that does not
+    answer 200 is left out, and its metrics then find nothing to read."""
+    out: dict[str, dict] = {}
+    for endpoint in endpoints:
+        status, body, headers = ctl.request("GET", endpoint)
+        if status == 200:
+            is_json = "json" in (headers.get("Content-Type") or "")
+            out[endpoint] = body if is_json else prom_samples(body.get("text", ""))
+    return out
 
 
 # --------------------------------------------------------------------- child
@@ -163,7 +194,7 @@ def mcpx_config(cell: spec.Cell, run_dir: str, port: int, trace: bool, rehearsal
     file, and tracing on (rate 1) only in a traced run."""
     cfg = json.loads(json.dumps(cell.config.get("mcpx", {})))
     engine = cfg.setdefault("engine", {})
-    for key in ENGINE_SIZES:
+    for key in spec.ENGINE_SIZES:
         engine[key] = cell.config[key]
     if rehearsal:
         engine["interpret"] = True
@@ -234,8 +265,9 @@ def wait_started(child: subprocess.Popen, ctl: "Client", t_child: float, marks: 
 
 
 def correctness_problems(*, failed, n_good, drained, edges, ref, resets, compiles, platform,
-                         pallas, rehearsal) -> list[str]:
-    """Every reason the run's result is not ``correct`` (empty = correct)."""
+                         pallas, kernel_paths, rehearsal) -> list[str]:
+    """Every reason the run's result is not ``correct`` (empty = correct).
+    ``kernel_paths`` is the block module's: kernel path -> fewest dispatches."""
     problems: list[str] = []
     if failed:
         problems.append(f"{len(failed)} failed plan(s), e.g. {failed[0].why}")
@@ -263,13 +295,15 @@ def correctness_problems(*, failed, n_good, drained, edges, ref, resets, compile
     if pallas.get("enabled") is not True or bool(pallas.get("interpret")) != rehearsal:
         problems.append(f"ragged kernel: enabled={pallas.get('enabled')!r} "
                         f"interpret={pallas.get('interpret')!r}")
-    for path in ("decode", "prefill"):
-        if not (paths.get(path) or {}).get("engaged"):
+    for path, fewest in kernel_paths.items():
+        seen = paths.get(path) or {}
+        if not seen.get("engaged"):
+            problems.append(f"kernel path {path!r} not engaged: {seen.get('reason')}")
+        if int(seen.get("dispatches") or 0) < fewest:
             problems.append(
-                f"kernel path {path!r} not engaged: {(paths.get(path) or {}).get('reason')}"
+                f"kernel path {path!r}: {int(seen.get('dispatches') or 0)} dispatch(es) went "
+                f"through the kernel, fewer than {fewest}"
             )
-    if int((paths.get("decode") or {}).get("dispatches") or 0) <= 0:
-        problems.append("no decode dispatch went through the kernel")
     if n_good < 2:
         problems.append(f"only {n_good} plan(s) completed in the window")
     return problems
@@ -280,6 +314,13 @@ def run(args: argparse.Namespace) -> dict:
     rehearsal = bool(args.rehearse_cpu)
     trace = bool(args.trace)
     cell = spec.load_cell(args.workload)
+    found = readers.vocabulary()
+    try:  # an unknown reader fails before the child starts, not after the window
+        for m in cell.per_layer:
+            readers.reader_named(m.reader, found)
+    except KeyError as e:
+        raise BenchFailure(e.args[0]) from e
+    endpoints = counter_endpoints(cell)
     traffic = loadgen.load_traffic(cell.traffic)
     gen = loadgen.Generator(cell.traffic, args.seed)
     names = {r["name"] for r in gen.registry}
@@ -365,7 +406,7 @@ def run(args: argparse.Namespace) -> dict:
         t0 = time.monotonic()
         setup_s = t0 - t_child
         marks["warm_plans_done"] = setup_s
-        _, cache0, _ = ctl.request("GET", "/cache")
+        counters0 = fetch_counters(ctl, endpoints)
 
         # --- the measured window; traced runs profile a slice of it.
         profile_dir = os.path.join(run_dir, "profile")
@@ -391,7 +432,7 @@ def run(args: argparse.Namespace) -> dict:
         marks["drained"] = time.monotonic() - t_child
 
         # --- what served it.
-        _, cache1, _ = ctl.request("GET", "/cache")
+        counters1 = fetch_counters(ctl, endpoints)
         _, health, _ = ctl.request("GET", "/healthz")
         status, m1, _ = ctl.request("GET", "/bench/marks")
         if status != 200:
@@ -421,7 +462,7 @@ def run(args: argparse.Namespace) -> dict:
         problems = correctness_problems(
             failed=failed, n_good=len(good), drained=drained, edges=edges, ref=ref, resets=resets,
             compiles=(compiles0, compiles1), platform=platform, pallas=pallas,
-            rehearsal=rehearsal,
+            kernel_paths=m1["kernel_paths"], rehearsal=rehearsal,
         )
 
         hbm = dev.get("hbm") or []
@@ -497,17 +538,19 @@ def run(args: argparse.Namespace) -> dict:
             ev = readers.Evidence(
                 gen_late_ms=[s.gen_late_ms for s in samples],
                 traces=traces,
-                counters_before={"/cache": cache0},
-                counters_after={"/cache": cache1},
+                counters_before=counters0,
+                counters_after=counters1,
                 # A CPU rehearsal has no device trace: nothing is ever
                 # written under a device metric's name from it.
                 device=None if rehearsal else reduced,
                 memory_in_use_bytes=None if rehearsal else in_use_bytes,
+                config=cell.config,
+                device_kind=device["kind"],
             )
             info["traces_read"] = len(traces)
             info["plan_decode_tokens"] = readers.histogram(ev, "engine.decode", "tokens")
             for m in cell.per_layer:
-                v = readers.read_metric(ev, m.reader, m.args)
+                v = readers.read_metric(ev, m.reader, m.args, found)
                 if v is not None:
                     out_metrics[m.name] = {"value": v, "unit": m.unit}
         print("bench-info " + json.dumps(info), flush=True)

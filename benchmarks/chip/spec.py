@@ -1,16 +1,18 @@
 """Loads ``BENCHMARK.json`` and the data files it names, and checks them.
 
 The harness is driven by data: a configuration is ``configs/<config>.json``
-(the path ``BENCHMARK.json`` gives), a traffic mix ``traffic/<traffic>.json``,
-a per-layer metric ``metrics/<metric>.json`` (a reader from ``readers.py``'s
-vocabulary with its arguments), and a cell one ``workloads`` entry naming a
-configuration and a traffic mix. Adding any of them is adding files and
-entries; nothing here is edited.
+(the path ``BENCHMARK.json`` gives) naming its block module
+``models/<module>.py``, a traffic mix ``traffic/<traffic>.json``, a per-layer
+metric ``metrics/<metric>.json`` (a reader of ``readers.py`` or of a file under
+``reader_files/``, with its arguments), and a cell one ``workloads`` entry
+naming a configuration and a traffic mix. Adding any of them is adding files
+and entries; nothing here is edited.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
 import re
@@ -21,6 +23,16 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# EngineConfig sizes a configuration file states at its top level.
+ENGINE_SIZES = ("max_batch_size", "max_pages_per_seq", "max_decode_len", "warmup_max_len")
+# What a configuration file says to the harness. Every OTHER top-level key is
+# the model's own, and the configuration's block module has to account for it.
+HARNESS_KEYS = frozenset(ENGINE_SIZES) | {
+    "name", "source", "module", "chips", "mesh", "slab_rows", "mcpx",
+    "reduced", "assumed", "departures", "params",
+}
+# What a block module gives (``load_block``); ``step_functions`` is optional.
+BLOCK_PARTS = ("model_config", "rehearsal_config", "reference_logits", "kernel_paths")
 
 
 class SpecError(ValueError):
@@ -53,6 +65,52 @@ def _read_json(path: str) -> dict:
     if not isinstance(obj, dict):
         raise SpecError(f"{path} must hold one JSON object")
     return obj
+
+
+def model_keys(config: dict) -> dict:
+    """The configuration file without the harness's own keys: the published
+    keys its block module maps to the program's model config."""
+    return {k: v for k, v in config.items() if k not in HARNESS_KEYS}
+
+
+def block_file(name: object, bench_dir: str = HERE) -> str:
+    """``models/<name>.py`` of a configuration's ``"module": "<name>"``: a
+    name, never a path or a dotted import."""
+    if name is None:
+        raise SpecError("the configuration names no 'module' (models/<module>.py); there is no default")
+    path = os.path.join(bench_dir, "models", check_name(name, "config module") + ".py")
+    if not os.path.isfile(path):
+        models = os.path.dirname(path)
+        have = sorted(f[:-3] for f in os.listdir(models) if f.endswith(".py")) if os.path.isdir(models) else []
+        raise SpecError(f"config module {name!r}: no {path} (has: {have})")
+    return path
+
+
+def import_file(path: str, prefix: str):
+    """The module of one ``.py`` file found by name (a block module, a reader
+    file), imported under a name of its own and not through ``sys.path``."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    mod_spec = importlib.util.spec_from_file_location(prefix + re.sub(r"\W", "_", stem), path)
+    module = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(module)
+    return module
+
+
+def load_block(name: object, bench_dir: str = HERE):
+    """Import a configuration's block module and check its parts. Only the
+    process that runs the model calls this: a block module may import jax."""
+    path = block_file(name, bench_dir)
+    module = import_file(path, "chip_block_")
+    missing = [part for part in BLOCK_PARTS if not hasattr(module, part)]
+    if missing:
+        raise SpecError(f"block module {path} lacks {missing} (a block module gives {list(BLOCK_PARTS)})")
+    paths = module.kernel_paths
+    if not isinstance(paths, dict) or not paths or not all(
+        isinstance(k, str) and isinstance(v, int) and v >= 0 for k, v in paths.items()
+    ):
+        raise SpecError(f"block module {path}: kernel_paths maps each kernel path that 'correct' "
+                        "requires (at least one) to its fewest dispatches")
+    return module
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,6 +206,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         check_name(key, f"config {cfg_entry['name']} reduced key")
         if key not in config:
             raise SpecError(f"config {cfg_entry['name']}: reduced key {key!r} is not in its file")
+    block_file(config.get("module"), bench_dir)
     traffic = _read_json(os.path.join(bench_dir, "traffic", entry["traffic"] + ".json"))
 
     def in_cell(m: dict) -> bool:

@@ -90,5 +90,61 @@ def test_every_committed_metric_file_names_a_known_reader():
 
     for w in spec.load_benchmark(REPO)["workloads"]:
         for m in spec.load_cell(w["name"], REPO).per_layer:
-            assert m.reader in readers.READERS
+            assert m.reader in readers.vocabulary()
             readers.read_metric(evidence(), m.reader, m.args)  # arguments fit the reader
+
+
+# ------------------------------------------------- readers are found, not listed
+NEW_READER = ("import readers\n"
+              "from stats import quantile\n"
+              "def _helper(ev): return list(ev.gen_late_ms)\n"
+              "def late_spread(ev, lo, hi):\n"
+              "    xs = _helper(ev)\n"
+              "    return quantile(xs, hi) - quantile(xs, lo) if xs else None\n")
+
+
+def test_a_reader_file_is_found_by_name_and_read_like_the_built_in_ones(tmp_path):
+    (tmp_path / "spread.py").write_text(NEW_READER)
+    (tmp_path / "notes.txt").write_text("not a reader file")
+    found = readers.vocabulary(str(tmp_path))
+    assert set(found) == set(readers.READERS) | {"late_spread"}  # no helper, no imported function
+    assert readers.read_metric(evidence(), "late_spread", {"lo": 0.0, "hi": 1.0}, found) == pytest.approx(4.9)
+    assert readers.read_metric(readers.Evidence([], [], {}, {}, None, None), "late_spread",
+                               {"lo": 0.0, "hi": 1.0}, found) is None
+    assert readers.read_metric(evidence(), "client_quantile", {"q": 0.5}, found) == pytest.approx(0.25)
+    assert set(readers.vocabulary(str(tmp_path / "absent"))) == set(readers.READERS)
+
+
+def test_a_reader_defined_twice_and_an_unknown_reader_are_errors(tmp_path):
+    (tmp_path / "a.py").write_text("def late_spread(ev): return 1.0\n")
+    (tmp_path / "b.py").write_text("def late_spread(ev): return 2.0\n")
+    with pytest.raises(ValueError, match="late_spread.*a.py.*b.py"):
+        readers.vocabulary(str(tmp_path))
+    (tmp_path / "b.py").write_text("def client_quantile(ev, q): return 2.0\n")
+    with pytest.raises(ValueError, match="client_quantile.*readers.py.*b.py"):
+        readers.vocabulary(str(tmp_path))
+    (tmp_path / "b.py").unlink()
+    with pytest.raises(KeyError, match="no_such.*late_spread.*reader_files"):
+        readers.read_metric(evidence(), "no_such", {}, readers.vocabulary(str(tmp_path)))
+
+
+def test_a_counter_path_reads_one_labelled_sample_of_the_metrics_endpoint():
+    ev = readers.Evidence(
+        [], [],
+        {"/metrics": {'compiles_total{executable="admit"}': 4.0, 'compiles_total{executable="seg"}': 2.0,
+                      "requests_total": 10.0},
+         "/healthz": {"engine_queue": {"admitted": 5, "dispatched": 3}}},
+        {"/metrics": {'compiles_total{executable="admit"}': 7.0, 'compiles_total{executable="seg"}': 3.0,
+                      "requests_total": 30.0},
+         "/healthz": {"engine_queue": {"admitted": 45, "dispatched": 13}}},
+        None, None)
+    assert readers.read_metric(ev, "counter_delta_ratio", {
+        "endpoint": "/metrics", "num": ['compiles_total{executable="admit"}'],
+        "den": ["requests_total"]}) == pytest.approx(3 / 20)
+    assert readers.read_metric(ev, "counter_delta_ratio", {
+        "endpoint": "/healthz", "num": ["engine_queue.admitted"],
+        "den": ["engine_queue.dispatched"]}) == pytest.approx(4.0)
+    assert readers.read_metric(ev, "counter_delta_ratio", {
+        "endpoint": "/metrics", "num": ['compiles_total{executable="other"}'], "den": ["requests_total"]}) is None
+    assert readers.read_metric(ev, "counter_delta_ratio", {
+        "endpoint": "/costs", "num": ["a"], "den": ["a"]}) is None

@@ -20,16 +20,17 @@ def compare():
     from jax.sharding import Mesh
 
     import reference
-    from mcpx.models.gemma.config import GemmaConfig
+    import spec
     from mcpx.models.gemma.params import init_params
 
-    cfg = GemmaConfig.named("test", vocab_size=3072)
+    block = spec.load_block("gemma")
+    cfg = block.rehearsal_config(3072)
     params = init_params(cfg, jax.random.PRNGKey(0))
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
 
     def run(p=params, **kw):
         return reference.compare_with_engine_step(
-            p, cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 5, interpret=True,
+            block, p, cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 5, interpret=True,
             page_size=16, rows=2, pages_per_row=32, prefill_len=128, n_decode=2, **kw)
 
     return run, params

@@ -82,3 +82,22 @@ def test_four_chip_configuration_on_four_virtual_devices(tree):
     )
     line = rehearse(root, "mistral-7b.distinct-closed", trace=0)
     assert line["device"]["count"] == 4
+
+
+def test_a_new_block_arrives_as_files(tree):
+    """A configuration naming another block module runs through child.py and
+    reference.py with no edit to either: the probe block (tests/models/probe.py:
+    its own reference and its own program step) dropped into a checkout."""
+    import shutil
+
+    base = json.load(open(os.path.join(CHIP_DIR, "configs", "olmo2-1b.json")))
+    root = tree(
+        cell={"name": "probe.distinct-closed", "config": "probe", "traffic": "distinct-closed",
+              "chips": 1, "why": "rehearsal"},
+        config=("probe", {**base, "name": "probe", "module": "probe"}),
+    )
+    shutil.copy(os.path.join(CHIP_DIR, "tests", "models", "probe.py"),
+                os.path.join(root, "benchmarks", "chip", "models", "probe.py"))
+    rehearse(root, "probe.distinct-closed", trace=0)
+    with open(os.path.join(root, ".chip", "bench", "probe.distinct-closed", "server.log")) as f:
+        assert "Traceback" not in f.read()
