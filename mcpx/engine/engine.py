@@ -72,7 +72,7 @@ from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window
 from mcpx.engine.speculative import advance_drafter_state, draft_window
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import init_kv_cache, prefill
-from mcpx.models.gemma.params import load_or_init
+from mcpx.models.gemma.params import bytes_per_device, load_or_init
 from mcpx.models.tokenizer import make_tokenizer
 from mcpx.planner.grammar import (
     PlanGrammar,
@@ -477,6 +477,9 @@ class InferenceEngine:
         self._pending_stats: dict = {  # mcpx: owner[engine-worker, atomic]
             "constrained": 0, "free": 0, "hol_wait_ms": 0.0,
         }
+        # Mesh axes and weight placement, swapped in whole by _setup once
+        # the tree is placed; queue_stats() merges it (empty while cold).
+        self._placement: dict = {}  # mcpx: owner[engine-worker, atomic]
         # Pipelined segment outputs awaiting their (lagged) flag fetch:
         # entries are (done, emitted, out_buf, n_fwd device handles,
         # gen snapshot); decode wall time is taken at harvest. Worker
@@ -1047,6 +1050,9 @@ class InferenceEngine:
         extra = {"worker_profile": prof.snapshot()} if prof is not None else {}
         return {
             **extra,
+            # Mesh axes and weight placement (source, wall of the draw,
+            # bytes per device); absent until _setup has placed the tree.
+            **self._placement,
             # Per-path ragged-kernel engagement (decode / suffix-prefill /
             # spec-verify): route + dispatch counts + blocking reason, so
             # the scheduler, /healthz watchers and the bench headline all
@@ -1149,12 +1155,29 @@ class InferenceEngine:
         # quantizes each leaf at creation so the full-precision tree never
         # exists (7B-int8 on one 16 GB chip); checkpoints quantize after
         # restore — see load_or_init's documented limitation.
+        t_weights = time.monotonic()
         self._params, source = load_or_init(
             self.model_cfg,
             self.config.model.checkpoint_path,
             self._mesh,
             quantize=self.config.model.quantize,
         )
+        jax.block_until_ready(self._params)
+        init_s = time.monotonic() - t_weights
+        # How the weights were placed, for /healthz and /metrics: the wall
+        # of the draw (or restore) and what each device now holds.
+        held = bytes_per_device(self._params)
+        self.metrics.weights_init_seconds.set(init_s)
+        for dev, n_bytes in held.items():
+            self.metrics.weights_bytes.labels(device=dev).set(n_bytes)
+        self._placement = {
+            "mesh": {k: int(v) for k, v in self._mesh.shape.items()},
+            "weights": {
+                "source": source,
+                "init_s": round(init_s, 3),
+                "bytes_per_device": held,
+            },
+        }
         self._paged_kv = self._init_pools()
         # Long-prompt routing (ring prefill): the serving mesh's data
         # devices double as a seq axis — same device order, so the ring's
@@ -5051,12 +5074,13 @@ class InferenceEngine:
             None,
             None,
         )
-        return jax.device_put(
-            init_paged_kv(
+        # Created sharded, not built whole on one device and moved.
+        return jax.jit(
+            lambda: init_paged_kv(
                 self.model_cfg, self._allocator.n_pages, self.config.engine.kv_page_size
             ),
-            self._named(kv_spec),
-        )
+            out_shardings=self._named(kv_spec),
+        )()
 
     def _reset_pools(self) -> None:
         """Recreate the KV page pools after a failed jit call. Prefill and

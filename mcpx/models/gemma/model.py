@@ -26,6 +26,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from mcpx.models.gemma.config import GemmaConfig
@@ -35,14 +36,28 @@ KVCache = dict[str, jax.Array]
 
 
 # --------------------------------------------------------------------- init
-def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None) -> Params:
+def _draw_normal(key: jax.Array, divisor: jax.Array, shape, dtype) -> jax.Array:
+    return (jax.random.normal(key, shape, jnp.float32) / divisor).astype(dtype)
+
+
+def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None) -> Params:
     """Random-init parameters (bfloat16 by default), layer-stacked.
 
+    Every leaf is CREATED with its sharding: one jitted draw per leaf whose
+    ``out_shardings`` is the leaf's ``param_pspecs`` entry on ``mesh`` (None:
+    the default device), so no device holds a whole leaf that the specs
+    split, nor the whole tree — what lets a 14 GB tree start on four 16 GB
+    chips. The bits do not depend on the mesh (threefry is partitionable: an
+    element's bits are a function of the key and its index), nor on the jit:
+    the divisor is a runtime operand, because XLA rewrites a divide by a
+    compile-time constant into a multiply by its reciprocal, which rounds
+    differently wherever sqrt(fan_in) is not a power of two.
+
     ``leaf_transform(name, array)`` is applied to each tensor AT CREATION
-    (e.g. ``quant.leaf_quantizer`` for int8 serving): intermediates are
-    freed as each transformed leaf replaces them, so the full-precision
-    tree never needs to exist at once — the property that lets 7B-int8
-    initialise on a 16 GB chip."""
+    (e.g. ``quant.leaf_quantizer`` for int8 serving), on the sharded leaf:
+    intermediates are freed as each transformed leaf replaces them, so the
+    full-precision tree never needs to exist at once — the property that
+    lets 7B-int8 initialise on a 16 GB chip."""
     dtype = jnp.dtype(cfg.dtype)
     t = leaf_transform or (lambda _name, w: w)
     k_embed, k_q, k_k, k_v, k_o, k_gate, k_up, k_down = jax.random.split(key, 8)
@@ -55,18 +70,31 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None) -> Params
         cfg.d_ff,
         cfg.vocab_size,
     )
+    if mesh is None:
+        sharding = lambda _name: None
+    else:
+        from jax.sharding import NamedSharding
+
+        from mcpx.parallel.mesh import param_pspecs
+
+        specs = param_pspecs(cfg, mesh)
+        by_name = {**specs["layers"], "embed": specs["embed"], "final_norm": specs["final_norm"]}
+        sharding = lambda name: NamedSharding(mesh, by_name[name])
 
     def normal(name, key, shape, fan_in):
-        return t(
-            name,
-            (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype),
+        draw = jax.jit(
+            _draw_normal, static_argnames=("shape", "dtype"), out_shardings=sharding(name)
         )
+        return t(name, draw(key, np.float32(math.sqrt(fan_in)), shape=shape, dtype=dtype))
+
+    def zeros(name, shape):
+        return t(name, jnp.zeros(shape, dtype, device=sharding(name)))
 
     return {
         "embed": normal("embed", k_embed, (V, D), D),
         "layers": {
-            "pre_attn_norm": t("pre_attn_norm", jnp.zeros((L, D), dtype)),
-            "pre_mlp_norm": t("pre_mlp_norm", jnp.zeros((L, D), dtype)),
+            "pre_attn_norm": zeros("pre_attn_norm", (L, D)),
+            "pre_mlp_norm": zeros("pre_mlp_norm", (L, D)),
             "wq": normal("wq", k_q, (L, D, H, hd), D),
             "wk": normal("wk", k_k, (L, D, K, hd), D),
             "wv": normal("wv", k_v, (L, D, K, hd), D),
@@ -75,7 +103,7 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None) -> Params
             "w_up": normal("w_up", k_up, (L, D, F), D),
             "w_down": normal("w_down", k_down, (L, F, D), F),
         },
-        "final_norm": t("final_norm", jnp.zeros((D,), dtype)),
+        "final_norm": zeros("final_norm", (D,)),
     }
 
 

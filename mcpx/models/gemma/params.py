@@ -100,9 +100,11 @@ def load_or_init(
     """Load a checkpoint if configured, else random-init (optionally onto the
     mesh). Returns (params, source) where source is "checkpoint" | "random".
 
-    ``quantize="int8"`` (models/gemma/quant.py): the random path quantizes
-    each leaf AT CREATION (full-precision tree never exists at once — the
-    property that lets the 7B geometry initialise int8 on one 16 GB chip).
+    The random path draws every leaf already sharded over ``mesh`` (bits
+    independent of the mesh). ``quantize="int8"`` (models/gemma/quant.py):
+    the random path quantizes each leaf AT CREATION (full-precision tree
+    never exists at once — the property that lets the 7B geometry
+    initialise int8 on one 16 GB chip).
     The checkpoint path quantizes after restore, which transiently needs
     the full-precision footprint on the restoring topology; a single chip
     that can't hold it needs either a sharded restore across a mesh or an
@@ -128,14 +130,28 @@ def load_or_init(
         from mcpx.models.gemma.quant import leaf_quantizer
 
         leaf_transform = leaf_quantizer
-    params = init_params(cfg, jax.random.PRNGKey(seed), leaf_transform=leaf_transform)
-    if mesh is not None:
+    # Every leaf is drawn already sharded per param_pspecs (init_params): no
+    # device holds the whole tree, nor a whole leaf that the specs split.
+    params = init_params(
+        cfg, jax.random.PRNGKey(seed), leaf_transform=leaf_transform, mesh=mesh
+    )
+    if mesh is not None and quantize == "int8":
+        # The quantizer ran on the sharded leaf, so the int8 weights and
+        # their scales already sit where GSPMD put them; pinning them to
+        # quant_pspecs moves at most the small scale leaves.
+        from mcpx.models.gemma.quant import quant_pspecs
         from mcpx.parallel.mesh import shard_pytree
 
-        if quantize == "int8":
-            from mcpx.models.gemma.quant import quant_pspecs
-
-            params = shard_pytree(params, quant_pspecs(cfg, mesh), mesh)
-        else:
-            params = shard_pytree(params, param_pspecs(cfg, mesh), mesh)
+        params = shard_pytree(params, quant_pspecs(cfg, mesh), mesh)
     return params, "random"
+
+
+def bytes_per_device(params: Params) -> dict[str, int]:
+    """Bytes of ``params`` that each device holds, from the placed tree's
+    addressable shards (a replicated leaf counts once on every device)."""
+    held: dict[str, int] = {}
+    for leaf in jax.tree.leaves(params):
+        for shard in leaf.addressable_shards:
+            dev = str(shard.device)
+            held[dev] = held.get(dev, 0) + shard.data.nbytes
+    return held

@@ -349,6 +349,19 @@ class Metrics:
             ["device"],
             registry=self.registry,
         )
+        self.weights_init_seconds = Gauge(
+            "mcpx_engine_weights_init_seconds",
+            "Wall seconds of the weights' random draw or checkpoint restore "
+            "at engine start, to block_until_ready",
+            registry=self.registry,
+        )
+        self.weights_bytes = Gauge(
+            "mcpx_engine_weights_bytes",
+            "Bytes of the placed weight tree held by each local device (from "
+            "its addressable shards; a replicated leaf counts on every device)",
+            ["device"],
+            registry=self.registry,
+        )
         self.resident_grammars = Gauge(
             "mcpx_engine_resident_grammars",
             "Distinct constrained grammars resident in the decode slab "

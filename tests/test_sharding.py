@@ -218,3 +218,20 @@ def test_hybrid_mesh_grad_allreduce_spans_dcn_axis():
         if any(i < 4 for i in g) and any(i >= 4 for i in g)
     ]
     assert crossing, "no gradient reduction spans the dcn_data axis"
+
+
+@pytest.mark.parametrize("axes", [(1, 1), (2, 4), (1, 8)], ids=["1x1", "2x4", "1x8"])
+def test_init_params_on_a_mesh_is_the_unsharded_draw_placed(cfg, params, axes):
+    """``init_params(mesh=...)`` creates each leaf with its ``param_pspecs``
+    sharding; values and placement are what ``shard_pytree`` of the
+    unsharded draw gave, without a whole leaf ever on one device."""
+    data, model = axes
+    mesh = make_mesh(data=data, model=model, devices=jax.devices()[: data * model])
+    drawn = init_params(cfg, jax.random.PRNGKey(0), mesh=mesh)
+    placed = shard_pytree(params, param_pspecs(cfg, mesh), mesh)
+    for (path, got), want in zip(jax.tree.leaves_with_path(drawn), jax.tree.leaves(placed)):
+        name = jax.tree_util.keystr(path)
+        assert got.sharding == want.sharding, name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=name)
+        if "model" in got.sharding.spec:
+            assert {s.data.size for s in got.addressable_shards} == {got.size // model}, name
