@@ -16,9 +16,15 @@ replacement: an in-process serving stack where
   - the worker is **pipelined** (``pipeline_depth``): it dispatches the
     next segment BEFORE fetching the previous one's done-flags, so the
     blocking host←device fetch rides on top of compute the device is
-    already doing. Slab-row mutation happens on device via a jitted merge
-    scatter; the host never materialises full state. Per-row generation
-    counters keep lagged done-flags from retiring a re-admitted row;
+    already doing. The dispatch is **just in time** (``engine/pacing.py``):
+    with a segment in flight and a slab row free, the worker holds the next
+    segment, waits on its queue and admits each arrival, and dispatches
+    shortly before the predicted ready time of the one in flight, so a
+    request that arrives during a segment joins the next one instead of
+    the one after, and the device's queue still never empties. Slab-row
+    mutation happens on device via a jitted merge scatter; the host never
+    materialises full state. Per-row generation counters keep lagged
+    done-flags from retiring a re-admitted row;
   - within a segment, grammar masking, speculation fast-forward, sampling
     and KV writes all happen on-device with zero host round-trips per
     token; pools are donated so decode updates in place;
@@ -66,6 +72,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from mcpx.core.config import MCPXConfig
 from mcpx.core.errors import ConfigError, EngineError
 from mcpx.engine.kv_cache import PageAllocator, commit_prefill_to_pages, init_paged_kv
+from mcpx.engine.pacing import SegmentPacer, hold_until
 from mcpx.engine.paged_decode import decode_chunk_paged
 from mcpx.engine.prefix_cache import PrefixNode, RadixPrefixCache
 from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window_rows
@@ -507,6 +514,16 @@ class InferenceEngine:
         # are chained in front of the next segment on the device, so its
         # engine.segment spans carry the count as ``prefill_rows``.
         self._rows_admitted = 0  # mcpx: owner[engine-worker]
+        # Those of them admitted while the segment was being held for
+        # arrivals (``hold_joined_rows``), and their lifetime total for
+        # queue_stats()'s worker_profile.
+        self._hold_joined = 0  # mcpx: owner[engine-worker]
+        self._hold_joined_total = 0  # mcpx: owner[engine-worker, atomic]
+        # Just-in-time dispatch of the next segment (engine/pacing.py): the
+        # device's queue as the worker knows it and the running estimates
+        # its hold deadline comes from. One clock read per admission,
+        # dispatch and ready stamp, with or without a profiler.
+        self._pacer = SegmentPacer()  # mcpx: owner[engine-worker]
         # Whether every slab row is taken, by the worker's own books, and
         # the last transitions of that with their times (kept while a
         # profiler is on): admission integrates them into the
@@ -1047,7 +1064,18 @@ class InferenceEngine:
         # while a profiler is attached, so the disabled-mode queue_stats
         # payload stays byte-identical (recorder-off parity contract).
         prof = self._profiler
-        extra = {"worker_profile": prof.snapshot()} if prof is not None else {}
+        extra = (
+            {
+                "worker_profile": {
+                    **prof.snapshot(),
+                    # Rows admitted while a segment was held for them (the
+                    # "hold" phase's seconds are among the phases).
+                    "hold_joined_rows": self._hold_joined_total,
+                }
+            }
+            if prof is not None
+            else {}
+        )
         return {
             **extra,
             # Mesh axes and weight placement (source, wall of the draw,
@@ -3535,12 +3563,21 @@ class InferenceEngine:
         self._started.set()
         slab = self._slab
         pending: "deque[GenerateRequest]" = deque()
+        # Just-in-time dispatch (engine/pacing.py): until when the next
+        # segment is being HELD for arrivals; None = not holding. Decided
+        # after each pass's admission, so that what a harvest left pending
+        # is admitted first; while it stands, the drain waits for arrivals
+        # and the loop comes round again without dispatching, so a
+        # caller's re-send joins the next segment and not the one after.
+        hold: Optional[float] = None
         while True:
             # Decode-loop host profiler (telemetry/flight.py): lap() marks
             # tile the iteration's wall time into named phases; prof is
             # re-read each iteration so a live attach/detach (bench flight
-            # phase) lands at the next tick. None = no clock reads anywhere
-            # on this path. The TraceAnnotations put the same phases, as
+            # phase) lands at the next tick. None = no phase is timed (the
+            # pacer's stamps, one per admission, dispatch and ready stamp,
+            # are all the clock this path reads). The TraceAnnotations put
+            # the same phases, as
             # ``mcpx.worker.<phase>`` events, on this thread's line of a
             # profiler trace (POST /profile/start), the device ops' clock;
             # with no session open each costs an atomic load.
@@ -3551,6 +3588,7 @@ class InferenceEngine:
                 self._drain_queue(
                     pending,
                     block=(not pending and slab.n_active == 0 and not self._inflight),
+                    hold=hold,
                 )
             if prof is not None:
                 prof.lap("drain")
@@ -3571,21 +3609,50 @@ class InferenceEngine:
             self._reap_cancelled(slab)
             if prof is not None:
                 prof.lap("host_bookkeeping")
+            admitted = 0
             if pending and slab.n_active < slab.B:
+                admitted, t_admit = self._rows_admitted, self._pacer.clock()
                 try:
                     with TraceAnnotation("mcpx.worker.admit"):
-                        self._admit(slab, pending)
+                        self._admit(slab, pending, holding=hold is not None)
                 except BaseException as e:  # noqa: BLE001 - keep worker alive
                     log.exception("admission failed; failing resident rows")
                     self._fail_rows(slab, e)
                     self._reset_pools()
+                admitted = self._rows_admitted - admitted
+                if admitted > 0:
+                    # A prefill chain went onto the device's queue, at this
+                    # much host time: what the hold's deadline is made of.
+                    self._pacer.admitted(t_admit, self._pacer.clock())
+                    if hold is not None:
+                        self._hold_joined += admitted
                 if prof is not None:
                     prof.lap("admit")
+            hold = self._hold_until(slab, pending)
+            if hold is not None:
+                # Time left before the segment in flight is ready, a row
+                # free and nothing left behind: wait for company.
+                continue
+            if (
+                admitted > 0
+                and not self._inflight
+                and slab.n_active < slab.B
+                and not self._queue.empty()
+            ):
+                # From idle, a burst is still arriving (its later members
+                # landed while the first were admitted): admit them too
+                # before the first dispatch, so that the burst decodes as
+                # one cohort and not as a sliver with the rest a segment
+                # behind. Ends with the inbox or the free rows.
+                continue
             if slab.n_active:
                 try:
                     # Dispatch first, THEN fetch a lagged segment's flags:
                     # the fetch's round trip rides on top of the segment the
-                    # device is already computing.
+                    # device is already computing. After a hold that ran to
+                    # its deadline the segment in flight is about to end:
+                    # the dispatch lands behind it just in time, and the
+                    # fetch blocks only briefly.
                     with TraceAnnotation("mcpx.worker.dispatch_submit"):
                         self._dispatch_segment(slab)
                     if prof is not None:
@@ -3702,29 +3769,83 @@ class InferenceEngine:
                     tenant=tenant
                 ).set(tokens)
 
-    def _drain_queue(self, pending: "deque[GenerateRequest]", block: bool) -> None:
+    def _hold_until(
+        self, slab: "_Slab", pending: "deque[GenerateRequest]"
+    ) -> Optional[float]:
+        """Until when the next segment is held for arrivals (the decision
+        is ``pacing.hold_until``); None = dispatch now. Worker thread only.
+        The hold refers to the NEWEST segment in flight, and only while it
+        still decodes a resident row and the device has not finished it: a
+        segment of retired rows ends at once, whatever its forwards."""
+        busy = False
+        if self._inflight:
+            done_d, gen_snap = self._inflight[-1][0], self._inflight[-1][4]
+            busy = not done_d.is_ready() and any(
+                r is not None and gen_snap[i] == slab.gen[i]
+                for i, r in enumerate(slab.req)
+            )
+        pacer = self._pacer
+        return hold_until(
+            pacer.clock(),
+            in_flight=busy,
+            free_rows=slab.B - slab.n_active,
+            backlog=len(pending),
+            ready_at=pacer.ready_at(),
+            margin=pacer.margin_s,
+        )
+
+    def _hold_wait(self, until: float) -> Any:
+        """Block on the queue until an item arrives (returned) or the hold
+        ends (``queue.Empty``): at ``until``, or as soon as the device has
+        finished the segment in flight, which is looked at once a
+        predicted forward so that a segment whose rows all finished early
+        is not found out a whole period late."""
+        pacer = self._pacer
+        newest = self._inflight[-1][0]
+        while True:
+            remaining = until - pacer.clock()
+            if remaining <= 0 or newest.is_ready():
+                raise queue.Empty
+            try:
+                return self._queue.get(timeout=min(remaining, pacer.forward_s))
+            except queue.Empty:
+                continue
+
+    def _drain_queue(
+        self,
+        pending: "deque[GenerateRequest]",
+        block: bool,
+        hold: Optional[float] = None,
+    ) -> None:
         """Move queued requests into ``pending``. When idle (``block``), wait
         briefly for the first arrival, then hold a short gather window so a
         burst forms one large admission cohort instead of a size-1 prefill
-        followed by stragglers."""
+        followed by stragglers. While the next segment is held (``hold``,
+        the time the hold ends) wait until then instead, with the same
+        gather window: the callers a harvest answered re-send together."""
         prof = self._profiler
+        # Blocking waits are carved out of the enclosing drain lap so the
+        # profile separates waiting from moving work: "idle" waits for
+        # work, "hold" for company while the device is busy.
+        waiting = "hold" if hold is not None else "idle"
         try:
-            if block:
-                # Blocking waits are the worker's IDLE time — carved out of
-                # the enclosing drain lap so the profile separates "waiting
-                # for work" from "moving work".
-                t_idle = prof.mark() if prof is not None else 0.0
+            if block or hold is not None:
+                t_wait = prof.mark() if prof is not None else 0.0
                 try:
-                    with TraceAnnotation("mcpx.worker.idle"):
-                        item = self._queue.get(timeout=0.05)
+                    with TraceAnnotation(f"mcpx.worker.{waiting}"):
+                        item = (
+                            self._hold_wait(hold)
+                            if hold is not None
+                            else self._queue.get(timeout=0.05)
+                        )
                 finally:
                     if prof is not None:
-                        prof.carve("idle", t_idle)
+                        prof.carve(waiting, t_wait)
             else:
                 item = self._queue.get_nowait()
         except queue.Empty:
             return
-        first_arrival = item is not None and block
+        first_arrival = item is not None and (block or hold is not None)
         while True:
             if item is None:
                 self._stop = True
@@ -3737,19 +3858,21 @@ class InferenceEngine:
                 break
         if first_arrival:
             deadline = time.monotonic() + 0.003
+            if hold is not None:
+                deadline = min(deadline, hold)
             while True:
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     return
-                t_idle = prof.mark() if prof is not None else 0.0
+                t_wait = prof.mark() if prof is not None else 0.0
                 try:
-                    with TraceAnnotation("mcpx.worker.idle"):
+                    with TraceAnnotation(f"mcpx.worker.{waiting}"):
                         item = self._queue.get(timeout=remaining)
                 except queue.Empty:
                     return
                 finally:
                     if prof is not None:
-                        prof.carve("idle", t_idle)
+                        prof.carve(waiting, t_wait)
                 if item is None:
                     self._stop = True
                     return
@@ -3795,7 +3918,12 @@ class InferenceEngine:
             return True
         return False
 
-    def _admit(self, slab: "_Slab", pending: "deque[GenerateRequest]") -> None:
+    def _admit(
+        self,
+        slab: "_Slab",
+        pending: "deque[GenerateRequest]",
+        holding: bool = False,
+    ) -> None:
         """Admit pending requests into free slab rows: prefill the cohort,
         commit its KV to pages, first-sample, merge row state.
 
@@ -3851,7 +3979,7 @@ class InferenceEngine:
             time.monotonic() - pending[0].enqueued_at > ecfg.fairness_timeout_s
         ):
             return  # drain the slab so the head of the line can run
-        elif slab.n_active and len(free) < (
+        elif not holding and slab.n_active and len(free) < (
             ecfg.admit_min_free or max(1, slab.B // 4)
         ) and (
             time.monotonic() - self._last_admit_t < ecfg.admit_max_wait_s
@@ -3863,6 +3991,10 @@ class InferenceEngine:
             # saturation every queued request is "old", which would disable
             # the guard exactly when it matters): small cohorts are rate-
             # limited to one per admit_max_wait_s, full ones go immediately.
+            # Not while the next segment is HELD (``holding``): then there
+            # is nothing to keep decoding instead, no retirement can come
+            # before the hold ends, and the request would wait a whole
+            # period for a row that is free now.
             return
 
     # --- prefix locality + declared-head pre-build ------------------------
@@ -4649,6 +4781,9 @@ class InferenceEngine:
         self._dispatch_seq += 1
         seq = self._dispatch_seq
         prefill_rows, self._rows_admitted = self._rows_admitted, 0
+        hold_joined, self._hold_joined = self._hold_joined, 0
+        self._hold_joined_total += hold_joined
+        t_submit = self._pacer.clock()
         # A step event in a profiler trace: the segment's device ops carry
         # its step_num, which is the engine.segment spans' ``seq``.
         with StepTraceAnnotation("mcpx.segment", step_num=seq):
@@ -4735,12 +4870,13 @@ class InferenceEngine:
             cur_d, pos_d, st_d, e_d, done_d, budgets_d, pt_d, buf_d,
             ptoks_d, plens_d, prev_d, temp_d, cons_d, dfa_d, hst_d,
         )
-        # Dispatch timestamp only when some resident request is traced (or
-        # the cost ledger is billing): the disabled/unsampled hot path must
-        # not even pay the clock read.
-        t_disp = (
-            time.monotonic() if (slab.n_traced or self._ledger_on) else 0.0
-        )
+        # The pacer's stamp of the enqueue (always: the next hold's
+        # deadline is predicted from it); the spans' dispatch timestamp
+        # only when some resident request is traced (or the cost ledger is
+        # billing).
+        now = self._pacer.clock()
+        self._pacer.dispatched(t_submit, now, iters)
+        t_disp = now if (slab.n_traced or self._ledger_on) else 0.0
         seg_exec = (
             self._jit_hetero_segment_spec
             if hetero and slab.spec
@@ -4761,9 +4897,11 @@ class InferenceEngine:
                 # traced spans and request bills with it.
                 getattr(seg_exec, "last_entry", None),
                 getattr(seg_exec, "name", "segment"),
-                # The engine.segment spans' seq and prefill_rows.
+                # The engine.segment spans' seq, prefill_rows and
+                # hold_joined_rows.
                 seq,
                 prefill_rows,
+                hold_joined,
             )
         )
 
@@ -4822,7 +4960,7 @@ class InferenceEngine:
         while len(self._inflight) > keep_inflight:
             (
                 done_d, e_d, buf_d, nfwd_d, gen_snap, t_disp, spec_h, cons_snap,
-                seg_cost, seg_name, seq, prefill_rows,
+                seg_cost, seg_name, seq, prefill_rows, hold_joined,
             ) = self._inflight.popleft()
             # ONE combined fetch (flags + out_buf): a blocking fetch costs
             # its round trip, not the ~24KB of buffer — splitting into
@@ -4845,8 +4983,9 @@ class InferenceEngine:
                         (done_d, e_d, buf_d, nfwd_d)
                     )
             timeline = {}
+            # The segment's ready stamp: the fetch has just returned. The
+            # pacer learns the period from it, profiler or none.
             if prof is not None:
-                # The segment's ready stamp: the fetch has just returned.
                 # The profiler's window between two ready stamps is what
                 # the worker did meanwhile; kept for every segment so the
                 # windows tile, written only on traced ones (t_disp).
@@ -4854,8 +4993,12 @@ class InferenceEngine:
                 t_prev, phases = prof.window("harvest", t_ready)
                 if t_disp:
                     timeline = self._segment_timeline(
-                        seq, prefill_rows, t_disp, t_ready, t_prev, phases
+                        seq, prefill_rows, hold_joined, t_disp, t_ready,
+                        t_prev, phases,
                     )
+            else:
+                t_ready = self._pacer.clock()
+            self._pacer.ready(t_ready, int(n_fwd))
             if dr is not None:
                 self._account_speculation(dr, ac, cons_snap)
             # The blocking fetch above implies every earlier admission chain
@@ -5030,6 +5173,7 @@ class InferenceEngine:
     def _segment_timeline(
         seq: int,
         prefill_rows: int,
+        hold_joined_rows: int,
         t_disp: float,
         t_ready: float,
         t_prev: float,
@@ -5042,18 +5186,24 @@ class InferenceEngine:
         done; the ``prefill_rows`` admission prefills chained in front of
         it are inside. ``sync_ms`` is how long that fetch blocked: near
         zero, the device had finished before the host asked and the
-        period is the host's lateness. ``idle_ms`` (blocked waiting for
-        requests) and ``host_ms`` (every other phase, with its named
-        parts) are the worker's phases since ``t_prev``: with ``sync_ms``
-        they sum to ``t_ready - t_prev``."""
+        period is the host's lateness: the dispatch came late. ``idle_ms``
+        (blocked waiting for requests), ``hold_ms`` (blocked waiting for
+        arrivals to join the segment being held, while the device was
+        busy) and ``host_ms`` (every other phase, with its named parts)
+        are the worker's phases since ``t_prev``: with ``sync_ms`` they
+        sum to ``t_ready - t_prev``. ``hold_joined_rows`` of the
+        ``prefill_rows`` were admitted while the segment was held."""
         ms = {p: v * 1e3 for p, v in phases.items()}
+        waits = ms["sync"] + ms["idle"] + ms["hold"]
         out = {
             "seq": seq,
             "prefill_rows": prefill_rows,
+            "hold_joined_rows": hold_joined_rows,
             "period_ms": round((t_ready - max(t_disp, t_prev)) * 1e3, 3),
             "sync_ms": round(ms["sync"], 3),
             "idle_ms": round(ms["idle"], 3),
-            "host_ms": round(sum(ms.values()) - ms["sync"] - ms["idle"], 3),
+            "hold_ms": round(ms["hold"], 3),
+            "host_ms": round(sum(ms.values()) - waits, 3),
         }
         for attr, parts in SEGMENT_PARTS.items():
             out[attr] = round(sum(ms[p] for p in parts), 3)
@@ -5103,6 +5253,7 @@ class InferenceEngine:
         # rows are failed right here, nothing left to harvest).
         slab.dev = None
         self._inflight.clear()
+        self._pacer.reset()
         self._dirty_rows.clear()
         self._pending_admissions.clear()
         for i in range(slab.B):

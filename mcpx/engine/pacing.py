@@ -1,0 +1,180 @@
+"""When to dispatch the next decode segment: just in time, not at once.
+
+The engine worker is pipelined: while segment N+1 computes, it prepares
+N+2. Dispatching N+2 the moment N's results are delivered leaves out every
+request that arrives during N+1's period: a caller's re-send lands
+milliseconds after the harvest that answered it, misses N+2 and waits a
+whole period in ``queue.Queue`` for N+3, while a slab row rides along
+empty. So the worker HOLDS N+2: it waits on its queue, admitting arrivals,
+until N+1 is nearly ready, and dispatches N+2 just before that, so the
+device's queue never empties and nothing starts later than it could have.
+
+This module is the part of that with no device in it: ``SegmentPacer``
+models the device's FIFO from what the worker observes (what it enqueued
+and when, and each segment's ready stamp), keeps running estimates of a
+forward's period, an admission's prefill chain and the worker's own costs
+of one admission and one dispatch, and predicts when the newest segment in
+flight will be ready; ``hold_until`` is the decision. The worker loop that
+acts on it is ``InferenceEngine._worker``.
+"""
+
+from __future__ import annotations
+
+import statistics
+import time
+from collections import deque
+from typing import Callable, Optional
+
+__all__ = ["SegmentPacer", "hold_until"]
+
+
+def hold_until(
+    now: float,
+    *,
+    in_flight: bool,
+    free_rows: int,
+    backlog: int,
+    ready_at: Optional[float],
+    margin: Optional[float],
+) -> Optional[float]:
+    """Until when the worker may wait for arrivals before it dispatches
+    the next segment; None = dispatch now (the order before ISSUE 29).
+
+    A hold needs a segment ``in_flight`` that still decodes live rows (the
+    device has work, and the next segment could not start before it ends
+    anyway), a free slab row (an arrival could join), no ``backlog`` of
+    pending requests that admission just left behind (want of pages, a
+    full grammar table, an incompatible slab: waiting admits none of
+    them), and an estimate: ``ready_at``, when the newest segment in
+    flight is predicted ready, and ``margin``, the worker's own cost of
+    one admission plus one dispatch. The wait ends ``margin`` before
+    ``ready_at``: an arrival seen by then is admitted and the segment
+    still reaches the device before the one ahead of it ends; a later one
+    joins the next segment, as every request did before."""
+    if not in_flight or free_rows <= 0 or backlog > 0:
+        return None
+    if ready_at is None or margin is None:
+        return None
+    until = ready_at - margin
+    return until if until > now else None
+
+
+class _Recent:
+    """Median of the last few samples: a running estimate that one
+    outlier (a compile, a stalled host) does not move."""
+
+    def __init__(self, keep: int = 5) -> None:
+        self._xs: "deque[float]" = deque(maxlen=keep)
+
+    def add(self, x: float) -> None:
+        self._xs.append(x)
+
+    @property
+    def value(self) -> Optional[float]:
+        return statistics.median(self._xs) if self._xs else None
+
+
+class SegmentPacer:
+    """The device's queue as the worker knows it, and the estimates that
+    turn it into a predicted ready time. Single writer: the engine worker.
+
+    The worker reports what it enqueues, ``admitted`` (an admission's
+    prefill chain) and ``dispatched`` (a segment of so many forwards), each
+    with the host wall it took, and ``ready`` when a segment's blocking
+    fetch returns. Between two ready stamps the device ran the prefills
+    chained in front of the segment and the segment's forwards, so a
+    segment with no prefill in front gives a clean sample of the period
+    per forward, and one with prefills gives, less its forwards, a sample
+    of a prefill chain. A sample with prefills inside is an upper bound on
+    the period per forward: it stands in until a clean one exists, and
+    caps a clean estimate that has gone stale above it."""
+
+    def __init__(self, clock: Callable[[], float] = time.monotonic) -> None:
+        self.clock = clock
+        self._forward = _Recent()  # clean: no prefill inside the period
+        self._forward_bound = _Recent()  # with prefills inside: upper bounds
+        self._prefill = _Recent()
+        self._admit = _Recent()
+        self._dispatch = _Recent()
+        # Device work enqueued since the last ready stamp, oldest first:
+        # (host time enqueued, forwards), forwards 0 = a prefill chain.
+        self._queued: "deque[tuple[float, int]]" = deque()
+        self._t_ready: Optional[float] = None
+
+    # ------------------------------------------------------------ estimates
+    @property
+    def forward_s(self) -> Optional[float]:
+        clean, bound = self._forward.value, self._forward_bound.value
+        if clean is None:
+            return bound
+        return clean if bound is None else min(clean, bound)
+
+    @property
+    def prefill_s(self) -> float:
+        return self._prefill.value or 0.0
+
+    @property
+    def margin_s(self) -> Optional[float]:
+        """One admission plus one dispatch, on the host."""
+        admit, dispatch = self._admit.value, self._dispatch.value
+        if admit is None or dispatch is None:
+            return None
+        return admit + dispatch
+
+    def ready_at(self) -> Optional[float]:
+        """Predicted ready time of the newest segment in flight: the queue
+        replayed from the last ready stamp, each item starting when the
+        one before it ends or when it was enqueued, whichever is later."""
+        forward = self.forward_s
+        if forward is None:
+            return None
+        finish, newest = self._t_ready, None
+        for t, forwards in self._queued:
+            start = t if finish is None else max(finish, t)
+            finish = start + (forward * forwards if forwards else self.prefill_s)
+            if forwards:
+                newest = finish
+        return newest
+
+    # ------------------------------------------------------------- reports
+    def admitted(self, t0: float, t1: float) -> None:
+        self._admit.add(t1 - t0)
+        self._queued.append((t1, 0))
+
+    def dispatched(self, t0: float, t1: float, forwards: int) -> None:
+        self._dispatch.add(t1 - t0)
+        self._queued.append((t1, max(1, forwards)))
+
+    def ready(self, t_ready: float, forwards: int) -> None:
+        """The oldest segment in flight was fetched at ``t_ready`` after
+        ``forwards`` forwards (fewer than dispatched when every row
+        finished early)."""
+        prefills, t_disp = 0, None
+        while self._queued:
+            t, fw = self._queued.popleft()
+            if fw:
+                t_disp = t
+                break
+            prefills += 1
+        t_prev, self._t_ready = self._t_ready, t_ready
+        if t_disp is None or forwards <= 0:
+            return
+        # Pipelined = dispatched before the segment ahead of it was ready:
+        # only then did the device run back to back, so that the wall
+        # between the two stamps is the work between them.
+        pipelined = t_prev is not None and t_disp <= t_prev
+        period = t_ready - (t_prev if pipelined else t_disp)
+        if period <= 0:
+            return
+        if not prefills:
+            self._forward.add(period / forwards)
+        elif pipelined:
+            self._forward_bound.add(period / forwards)
+            clean = self._forward.value
+            if clean is not None:
+                self._prefill.add(max(0.0, period - clean * forwards) / prefills)
+
+    def reset(self) -> None:
+        """The in-flight segments were dropped (a failed dispatch)."""
+        self._queued.clear()
+        self._t_ready = None

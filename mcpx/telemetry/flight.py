@@ -82,6 +82,10 @@ __all__ = [
 # this tuple.
 PROFILE_PHASES = (
     "idle",              # blocking waits for work (queue.get / gather window)
+    # Blocking waits for arrivals to JOIN the next segment, which is held
+    # until the one in flight is nearly ready (engine/pacing.py): the
+    # device is busy meanwhile, so this is neither idle nor host work.
+    "hold",
     "drain",             # moving queued requests into the pending line
     "host_bookkeeping",  # gauge publish, counter folds, cancelled-row reaping
     "poll",              # admission-chain completion polls (is_ready scans)
@@ -103,9 +107,9 @@ PROFILE_PHASES = (
 
 # The named parts of an engine.segment span's ``host_ms`` (the worker busy
 # on the host between two segments' ready stamps): attribute -> the phases
-# it sums. ``sync`` and ``idle`` are attributes of their own; the phases in
-# neither (drain, host_bookkeeping, poll, spill_copy) count in ``host_ms``
-# only.
+# it sums. ``sync``, ``idle`` and ``hold`` are attributes of their own; the
+# phases in none of these (drain, host_bookkeeping, poll, spill_copy) count
+# in ``host_ms`` only.
 SEGMENT_PARTS = {
     "admit_ms": ("admit", "locality_sort", "prefix_match"),
     "dispatch_ms": ("dispatch_submit",),

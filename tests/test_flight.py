@@ -136,6 +136,22 @@ def test_profiler_laps_tile_and_carves_subtract():
     assert w["harvest"] == pytest.approx(0.75) and w["idle"] == pytest.approx(0.25)
     assert w["sync"] == 0.0 and w["drain"] == 0.0
     assert sum(w.values()) == pytest.approx(1.0)
+    # "hold" (ISSUE 29: waiting for arrivals to join the segment being
+    # held) is carved out of the drain lap like "idle", twice in one lap
+    # when an arrival came in between; the window still tiles.
+    for t_from, t_to in ((3.5, 3.8), (3.9, 4.3)):
+        t["now"] = t_from
+        t0 = prof.mark()
+        t["now"] = t_to
+        prof.carve("hold", t0)
+    t["now"] = 4.4
+    prof.lap("drain")
+    start, w = prof.window("harvest", 4.5)
+    assert start == 3.5
+    assert w["hold"] == pytest.approx(0.7) and w["drain"] == pytest.approx(0.2)
+    assert w["harvest"] == pytest.approx(0.1) and w["idle"] == 0.0
+    assert sum(w.values()) == pytest.approx(1.0)
+    assert prof.snapshot()["phases"]["hold"]["count"] == 2
 
 
 def test_profiler_reads_the_spans_clock_and_survives_a_late_attach():
@@ -302,8 +318,8 @@ def test_engine_worker_profile_attribution_and_parity():
     from mcpx.telemetry.flight import PROFILE_PHASES, SEGMENT_PARTS
     from mcpx.telemetry.tracing import Tracer
 
-    timeline = {"seq", "prefill_rows", "period_ms", "sync_ms", "idle_ms",
-                "host_ms", *SEGMENT_PARTS}
+    timeline = {"seq", "prefill_rows", "hold_joined_rows", "period_ms",
+                "sync_ms", "idle_ms", "hold_ms", "host_ms", *SEGMENT_PARTS}
 
     def cfg(on):
         return MCPXConfig.from_dict(
@@ -349,6 +365,7 @@ def test_engine_worker_profile_attribution_and_parity():
             assert "worker_profile" not in eng_off.queue_stats()
             wp = eng_on.queue_stats()["worker_profile"]
             assert set(wp["phases"]) == set(PROFILE_PHASES)
+            assert "hold" in wp["phases"] and wp["hold_joined_rows"] >= 0
             assert wp["iterations"] >= 1
             assert wp["attributed_frac"] >= 0.95
             # The decode-heavy phases actually saw time (dispatch split

@@ -1,0 +1,147 @@
+"""Just-in-time dispatch of the next decode segment (ISSUE 29), the part
+with no device in it: the hold's decision as a pure function, and the
+pacer's model of the device queue on an injected clock. Nothing here
+depends on how fast anything decodes."""
+
+import pytest
+
+from mcpx.engine.pacing import SegmentPacer, hold_until
+
+OK = dict(in_flight=True, free_rows=3, backlog=0, ready_at=10.0, margin=0.025)
+
+
+@pytest.mark.parametrize(
+    "change, want",
+    [
+        ({}, 9.975),  # ready_at - margin
+        ({"in_flight": False}, None),  # idle engine, first request, depth 1
+        ({"free_rows": 0}, None),  # full slab: nobody could join
+        ({"backlog": 2}, None),  # admission left requests behind (pages)
+        ({"ready_at": None}, None),  # no period estimate yet
+        ({"margin": None}, None),  # no admission or dispatch timed yet
+        ({"ready_at": 5.02}, None),  # less than the margin left
+        ({"ready_at": 4.0}, None),  # predicted ready in the past: late already
+        ({"margin": 0.0}, 10.0),
+        ({"free_rows": 1}, 9.975),
+    ],
+)
+def test_hold_decision(change, want):
+    got = hold_until(5.0, **{**OK, **change})
+    assert got == (pytest.approx(want) if want is not None else None)
+
+
+class Clock:
+    def __init__(self) -> None:
+        self.now = 0.0
+
+    def __call__(self) -> float:
+        return self.now
+
+
+def steady(pacer: SegmentPacer, t: float, segments: int, period: float, fw: int) -> float:
+    """Dispatch ``segments`` pipelined segments of ``fw`` forwards, one
+    ready every ``period`` from ``t``; two in flight throughout. Returns
+    the last ready stamp."""
+    pacer.dispatched(t - 0.002, t, fw)
+    for _ in range(segments):
+        pacer.dispatched(t + 0.001, t + 0.003, fw)
+        t += period
+        pacer.ready(t, fw)
+    return t
+
+
+def test_no_estimate_no_prediction_then_a_clean_period_gives_one():
+    pacer = SegmentPacer(Clock())
+    assert pacer.ready_at() is None and pacer.margin_s is None
+    # From idle: an admission, then its segment. Its period holds the
+    # prefill and starts at the dispatch: nothing is learnt from it.
+    pacer.admitted(0.000, 0.020)
+    pacer.dispatched(0.020, 0.023, 16)
+    assert pacer.ready_at() is None
+    pacer.dispatched(0.024, 0.026, 16)  # the second, behind the first
+    pacer.ready(0.200, 16)
+    assert pacer.forward_s is None and pacer.ready_at() is None
+    # The second ran back to back with no prefill in front: clean.
+    pacer.ready(0.360, 16)
+    assert pacer.forward_s == pytest.approx(0.010)
+    assert pacer.margin_s == pytest.approx(0.020 + 0.0025)
+    # A third dispatched now is predicted from the last ready stamp, or
+    # from its own enqueue where the device had already run dry.
+    pacer.dispatched(0.400, 0.402, 16)
+    assert pacer.ready_at() == pytest.approx(0.402 + 0.160)
+
+
+def test_prediction_replays_the_queue_with_prefills_in_front():
+    pacer = SegmentPacer(Clock())
+    t = steady(pacer, 1.0, 4, 0.160, 16)  # 10 ms a forward, clean
+    assert pacer.forward_s == pytest.approx(0.010)
+    # One segment is in flight (dispatched before the last ready stamp):
+    # ready one period after that stamp.
+    assert pacer.ready_at() == pytest.approx(t + 0.160)
+    # Two admissions during the hold chain behind it, in front of the NEXT
+    # segment: the prediction for the one in flight does not move...
+    pacer.admitted(t + 0.004, t + 0.024)
+    pacer.admitted(t + 0.030, t + 0.050)
+    assert pacer.ready_at() == pytest.approx(t + 0.160)
+    # ...and the next one's holds them, at no cost until one was measured.
+    pacer.dispatched(t + 0.135, t + 0.138, 16)
+    assert pacer.ready_at() == pytest.approx(t + 0.320)
+    pacer.ready(t + 0.160, 16)
+    # Its period holds two prefill chains of 12 ms each.
+    pacer.ready(t + 0.160 + 0.184, 16)
+    assert pacer.prefill_s == pytest.approx(0.012)
+    assert pacer.forward_s == pytest.approx(0.010)  # 11.5 with prefills: no cap
+    t += 0.344
+    pacer.dispatched(t - 0.010, t - 0.008, 16)  # was in flight at that stamp
+    pacer.admitted(t + 0.002, t + 0.020)
+    pacer.dispatched(t + 0.130, t + 0.132, 8)
+    # In flight: 16 forwards from the stamp; behind it a prefill and 8.
+    assert pacer.ready_at() == pytest.approx(t + 0.160 + 0.012 + 0.080)
+
+
+def test_deeper_pipeline_predicts_the_newest_in_flight():
+    pacer = SegmentPacer(Clock())
+    t = steady(pacer, 1.0, 3, 0.080, 8)
+    pacer.dispatched(t + 0.001, t + 0.002, 8)  # two in flight now
+    assert pacer.ready_at() == pytest.approx(t + 0.160)
+
+
+def test_one_outlier_does_not_move_the_estimates_and_reset_forgets_the_queue():
+    pacer = SegmentPacer(Clock())
+    t = steady(pacer, 1.0, 4, 0.160, 16)
+    pacer.dispatched(t + 0.001, t + 0.003, 16)
+    t += 2.0  # a stalled host: one period of seconds
+    pacer.ready(t, 16)
+    assert pacer.forward_s == pytest.approx(0.010)
+    pacer.admitted(t, t + 0.020)
+    pacer.admitted(t + 0.02, t + 0.040)
+    pacer.admitted(t + 0.04, t + 1.5)  # one slow admission
+    assert pacer.margin_s == pytest.approx(0.020 + 0.002)
+    pacer.reset()  # a failed dispatch dropped what was in flight
+    assert pacer.ready_at() is None
+    assert pacer.forward_s == pytest.approx(0.010)  # what was learnt stays
+    pacer.dispatched(t + 2.0, t + 2.002, 16)
+    assert pacer.ready_at() == pytest.approx(t + 2.002 + 0.160)
+
+
+def test_periods_with_prefills_inside_bound_the_estimate_until_a_clean_one():
+    pacer = SegmentPacer(Clock())
+    pacer.dispatched(0.0, 0.002, 16)
+    pacer.ready(0.100, 16)  # not pipelined (no stamp before it): clean, 98 ms
+    t = 0.100
+    for _ in range(3):  # every later period has an admission inside
+        pacer.admitted(t - 0.060, t - 0.050)
+        pacer.dispatched(t - 0.050, t - 0.048, 16)
+    for k in range(3):
+        pacer.ready(t + 0.080 * (k + 1), 16)
+    # 80 ms over 16 forwards with a prefill inside: 5 ms bounds the stale
+    # clean 6.125 from above, and no prefill cost is read off a period
+    # shorter than the clean estimate says its forwards take.
+    assert pacer._forward.value == pytest.approx(0.006125)
+    assert pacer.forward_s == pytest.approx(0.005)
+    assert pacer.prefill_s == 0.0
+    # An early exit (every row finished) reports fewer forwards than
+    # dispatched; zero forwards teaches nothing.
+    pacer.dispatched(t, t + 0.001, 16)
+    pacer.ready(t + 0.300, 0)
+    assert pacer.forward_s == pytest.approx(0.005)

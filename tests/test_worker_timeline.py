@@ -14,9 +14,10 @@ from mcpx.engine.engine import InferenceEngine
 from mcpx.telemetry import tracing
 from mcpx.telemetry.flight import PROFILE_PHASES, SEGMENT_PARTS
 from mcpx.telemetry.tracing import Tracer
+from tests.test_hold_dispatch import HoldsTillDone
 
-TIMELINE = ("seq", "prefill_rows", "period_ms", "sync_ms", "idle_ms", "host_ms",
-            *SEGMENT_PARTS)
+TIMELINE = ("seq", "prefill_rows", "hold_joined_rows", "period_ms", "sync_ms",
+            "idle_ms", "hold_ms", "host_ms", *SEGMENT_PARTS)
 
 
 def make_engine(rows: int) -> InferenceEngine:
@@ -53,12 +54,15 @@ def named(spans: list, name: str) -> list:
     return [s for s in spans if s.name == name]
 
 
-def test_segment_spans_carry_the_segments_timeline_and_it_tiles():
+@pytest.mark.parametrize("held", [False, True], ids=["own-estimate", "held"])
+def test_segment_spans_carry_the_segments_timeline_and_it_tiles(held):
     """Every engine.segment span of one harvest carries the same timeline
-    (seq, prefill_rows, period_ms, sync_ms, idle_ms, host_ms and its
-    parts), and host_ms + sync_ms + idle_ms is the wall between two
-    consecutive ready stamps: checked against the spans' own ends, which
-    the worker stamps a few statements after the ready stamp."""
+    (seq, prefill_rows, hold_joined_rows, period_ms, sync_ms, idle_ms,
+    hold_ms, host_ms and its parts), and host_ms + sync_ms + idle_ms +
+    hold_ms is the wall between two consecutive ready stamps: checked
+    against the spans' own ends, which the worker stamps a few statements
+    after the ready stamp. Once with the pacer's own estimate (on a CPU
+    that may hold or not), once with every segment held (ISSUE 29)."""
 
     async def go():
         eng = make_engine(rows=4)
@@ -68,6 +72,8 @@ def test_segment_spans_carry_the_segments_timeline_and_it_tiles():
             # Warm the executables first: a compile inside a segment's
             # window is host time too, but makes the windows lopsided.
             await traced(eng, tracer, "warm the shapes", 40)
+            if held:
+                eng._pacer = HoldsTillDone()
             per_request = await asyncio.gather(
                 traced(eng, tracer, "first request of three", 56),
                 traced(eng, tracer, "the second request", 56),
@@ -101,7 +107,9 @@ def test_segment_spans_carry_the_segments_timeline_and_it_tiles():
             parts = sum(a[k] for k in SEGMENT_PARTS)
             assert 0.0 <= parts <= a["host_ms"] + 0.01
             assert 0.0 <= a["sync_ms"] and 0.0 <= a["idle_ms"]
-            window = a["host_ms"] + a["sync_ms"] + a["idle_ms"]
+            assert 0.0 <= a["hold_ms"]
+            assert 0 <= a["hold_joined_rows"] <= a["prefill_rows"]
+            window = a["host_ms"] + a["sync_ms"] + a["idle_ms"] + a["hold_ms"]
             # Dispatched before the previous segment was ready or after:
             # either way the period ends at this ready stamp and starts no
             # earlier than the previous one.
@@ -111,8 +119,53 @@ def test_segment_spans_carry_the_segments_timeline_and_it_tiles():
             tiled += window
             wall += ends
         assert tiled == pytest.approx(wall, rel=0.02)
+        if held:
+            # Three rows of four taken: segments behind the first were
+            # held, so the worker waited in ``hold`` while the device
+            # decoded, and the tiling above counted that wait.
+            assert sum(by_seq[q][0].attrs["hold_ms"] for q in seqs[1:]) > 0.0
+            assert eng.queue_stats()["worker_profile"]["phases"]["hold"]["count"] > 0
 
     asyncio.run(asyncio.wait_for(go(), 240))
+
+
+@pytest.mark.parametrize(
+    "metric, num, den, moves",
+    [
+        ("engine.hold_ms_per_forward", "hold_ms", "forwards", "plans_per_s"),
+        ("engine.hold_joined_share", "hold_joined_rows", "prefill_rows", "plan_p50_ms"),
+    ],
+)
+def test_the_hold_metrics_read_attributes_the_engine_writes(metric, num, den, moves):
+    """The benchmark's two hold metrics (ISSUE 29) are data files over the
+    harness's ``span_attr_ratio``: each names engine.segment attributes
+    that ``_segment_timeline`` and ``_harvest`` really write, once a
+    segment, and ``BENCHMARK.json`` lists it for every cell."""
+    import json
+    import os
+
+    from mcpx.engine.engine import InferenceEngine as E
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "metrics", metric + ".json")) as f:
+        spec = json.load(f)
+    assert (spec["name"], spec["reader"], spec["layer"], spec["moves"]) == (
+        metric, "span_attr_ratio", "engine", moves
+    )
+    assert spec["args"] == {
+        "name": "engine.segment", "num": num, "den": den,
+        "num_per": "segment", "den_per": "segment",
+    }
+    written = set(
+        E._segment_timeline(1, 2, 1, 0.0, 1.0, 0.5, dict.fromkeys(PROFILE_PHASES, 0.0))
+    ) | {"forwards", "tokens"}
+    assert {num, den} <= written
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"] if m["name"] == metric)
+    assert entry == {
+        "name": metric, "unit": spec["unit"], "better": "higher",
+        "source": "program_span", "layer": "engine", "moves": moves,
+    }
 
 
 def test_queue_wait_says_whether_a_row_was_free_and_the_worker_looking():
