@@ -346,8 +346,7 @@ def cmd_train_planner(args: argparse.Namespace) -> int:
 def cmd_eval_planner(args: argparse.Namespace) -> int:
     """Serve a planner checkpoint through the real stack (engine +
     grammar-constrained decode + retrieval shortlist) and print its
-    plan-quality metrics as one JSON line. Protocol shared with bench.py
-    via ``planner/evaluate.py``."""
+    plan-quality metrics as one JSON line (``planner/evaluate.py``)."""
     if args.platform == "cpu":
         from mcpx.utils.backend import force_virtual_cpu
 
@@ -370,20 +369,6 @@ def cmd_eval_planner(args: argparse.Namespace) -> int:
     )
     print(json.dumps({k: round(v, 4) if isinstance(v, float) else v for k, v in out.items()}))
     return 0
-
-
-def cmd_bench_report(args: argparse.Namespace) -> int:
-    """Regression report over the BENCH_r*.json series (mcpx/cli/
-    bench_report.py): scenario-keyed per-metric deltas with noise bands and
-    a machine-readable verdict — the same block bench.py embeds into each
-    new run's output JSON."""
-    from mcpx.cli.bench_report import run_report
-
-    return run_report(
-        args.paths,
-        fmt=args.format,
-        fail_on_regression=args.fail_on_regression,
-    )
 
 
 def cmd_lint(args: argparse.Namespace) -> int:
@@ -559,28 +544,6 @@ def main(argv: list[str] | None = None) -> int:
                         help="cpu: pin to host CPU (never takes the "
                         "chip); auto (default): whatever jax picks")
     p_eval.set_defaults(func=cmd_eval_planner)
-
-    p_bench = sub.add_parser(
-        "bench", help="bench artifact tooling (regression tracking)"
-    )
-    bench_sub = p_bench.add_subparsers(dest="bench_command", required=True)
-    p_breport = bench_sub.add_parser(
-        "report",
-        help="per-metric regression verdict over the BENCH_r*.json series",
-    )
-    p_breport.add_argument(
-        "paths", nargs="*",
-        help="bench artifacts in series order (default: ./BENCH_r*.json sorted)",
-    )
-    p_breport.add_argument(
-        "--format", choices=["text", "json"], default="text",
-        help="report format (json is the same block bench.py embeds)",
-    )
-    p_breport.add_argument(
-        "--fail-on-regression", action="store_true",
-        help="exit 1 when any tracked metric regressed beyond its noise band",
-    )
-    p_breport.set_defaults(func=cmd_bench_report)
 
     p_lint = sub.add_parser(
         "lint", help="static analysis (mcpxlint): async-safety + TPU hot-path rules"

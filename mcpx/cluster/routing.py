@@ -23,8 +23,8 @@ Three production policies compose the default pipeline:
   routable replica (deepest queue / worst error rate), protecting the
   healthy replicas for budget-healthy traffic before queues feel it.
 
-``RoundRobinPolicy`` exists for the bench A/B (routed vs round-robin
-prefix hit rate) and as a null hypothesis in tests.
+``RoundRobinPolicy`` is the null hypothesis (routed vs round-robin prefix
+hit rate); only tests use it.
 """
 
 from __future__ import annotations
@@ -220,7 +220,7 @@ class CostBurnPolicy:
 
 @owned_by("event_loop")
 class RoundRobinPolicy:
-    """Null-hypothesis router for the bench A/B: ignores everything and
+    """Null-hypothesis router: ignores everything and
     rotates. Strong enough (weight >> baseline) to dominate the pipeline
     when used alone with QueueDepthPolicy absent."""
 

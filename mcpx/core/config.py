@@ -133,7 +133,7 @@ class KVTierConfig:
     # Seeded fault profile for the spill tier (JSON file or inline JSON):
     # {"seed": 7, "host_alloc_fail_p": 0.1, "copy_delay_p": 0.2,
     #  "copy_delay_s": 0.05, "snapshot_corrupt": false} — exercised by
-    # bench phase 9 and the resilience tests; "" disables.
+    # the KV-tier tests; "" disables.
     chaos_profile: str = ""
 
 
@@ -166,7 +166,7 @@ class EngineConfig:
     # the engine worker's wall: host-side bookkeeping (harvest, admission,
     # gauge publish) then runs once per fused window instead of once per
     # tick, amortising exactly that line. 1 = per-step-window legacy
-    # cadence (bench phase 12's baseline arm). Tradeoff: a new arrival
+    # cadence. Tradeoff: a new arrival
     # waits up to one fused window for admission, and retirement lags by
     # pipeline_depth-1 windows — size the product against your admission-
     # latency budget (docs/engine.md "Ragged kernel & fused decode
@@ -345,7 +345,7 @@ class FlightConfig:
     # poll / harvest / spill-copy drain / host-bookkeeping / idle —
     # aggregated into streaming histograms and surfaced in
     # ``queue_stats()["worker_profile"]``, the per-segment attributes of
-    # engine.segment spans, the bench ``worker_profile`` block and the
+    # engine.segment spans (which the chip benchmark reads) and the
     # flight ring. ``tracing.enabled`` brings the profiler along; with
     # both off the worker loop takes no clock reads for it (pass-through).
     profile_worker: bool = False
@@ -661,8 +661,8 @@ class ResilienceConfig:
     # --- chaos injection -------------------------------------------------
     # JSON fault profile (docs/resilience.md schema); when set the factory
     # wraps the transport in a seeded ChaosTransport (`mcpx serve --chaos`).
-    # Independent of `enabled`, so the bench can measure the SAME fault
-    # profile with resilience on vs off.
+    # Independent of `enabled`, so the SAME fault profile can be served
+    # with resilience on and off.
     chaos_profile: str = ""
 
 

@@ -94,8 +94,9 @@ class Plan:
     explanation: str = ""
     # Which planner actually produced this plan: "llm" | "heuristic" | "mock"
     # | "" (unknown, e.g. /execute-supplied graphs). An LLM plan that fell
-    # back reads "heuristic" — this is what the bench's accept-rate and the
-    # ladder's llm_share report on (VERDICT r1 weak #1).
+    # back reads "heuristic" — what `mcpx eval-planner`'s llm_share reports
+    # on, and what the chip benchmark requires to be "llm" for a plan to
+    # count.
     origin: str = ""
     # LLM-planner provenance, NEVER serialized (to_wire omits both): the
     # exact prompt token ids this plan was decoded from, and the service

@@ -14,11 +14,10 @@ two enforcement points are
     victims come from tenants over their fair share first, LRU within a
     bucket — so an adversarial cache-thrash tenant (unbounded unique
     prompts at volume) can displace only its own share, and a victim
-    tenant's token hit rate keeps its fair-share floor (tested, and bench
-    phase 9's thrash scenario measures it end to end).
+    tenant's token hit rate keeps its fair-share floor (tested).
 
 Per-tenant lookup accounting (hits / matched vs prefilled tokens) rides
-along so ``GET /cache`` and the bench can report the per-tenant hit-rate
+along so ``GET /cache`` can report the per-tenant hit-rate
 spread — isolation as a number, not a claim. Tenant cardinality is capped:
 past ``max_tenants`` distinct names, new tenants fold into ``"other"`` so
 an adversarial tenant-id stream cannot grow this table or the

@@ -678,11 +678,10 @@ class InferenceEngine:
         # Decode-loop host profiler (telemetry/flight.py): per-iteration
         # phase timers tiling the worker loop's wall time into named
         # phases on the spans' clock, surfaced via
-        # queue_stats()["worker_profile"], the per-segment attributes of
-        # engine.segment spans and the bench worker_profile block. On
-        # with tracing (whose spans carry it) or profile_worker; None =
-        # zero clock reads on the hot path. The bench's flight phase
-        # attaches one to a LIVE engine (the worker re-reads the field
+        # queue_stats()["worker_profile"] and the per-segment attributes
+        # of engine.segment spans. On with tracing (whose spans carry it)
+        # or profile_worker; None = zero clock reads on the hot path. One
+        # can be attached to a LIVE engine (the worker re-reads the field
         # each iteration, so an attach/detach lands at the next tick).
         self._profiler: Optional[WorkerProfiler] = (  # mcpx: owner[engine-worker, atomic]
             WorkerProfiler()
@@ -695,7 +694,7 @@ class InferenceEngine:
         # an itemized bill dict to every GenerateResult. Off (default) no
         # accumulator is ever written and GenerateResult.bill stays None
         # (pass-through parity). Re-read from config each worker decision
-        # point so bench can flip it on a LIVE engine like the profiler.
+        # point so it can be flipped on a LIVE engine like the profiler.
         self._ledger_totals = {  # mcpx: owner[engine-worker, atomic]
             "flops": 0.0, "bytes": 0.0, "by_executable": {},
         }
@@ -1083,7 +1082,7 @@ class InferenceEngine:
             **self._placement,
             # Per-path ragged-kernel engagement (decode / suffix-prefill /
             # spec-verify): route + dispatch counts + blocking reason, so
-            # the scheduler, /healthz watchers and the bench headline all
+            # the scheduler, /healthz watchers and the chip benchmark all
             # read the SAME per-path truth (ISSUE 15 satellite — a single
             # boolean used to mask the suffix-prefill jnp fork).
             "pallas": self.pallas_paths(),
@@ -1925,8 +1924,7 @@ class InferenceEngine:
         binds. Empty when XLA published no costs (labeled absence beats a
         guessed number). With pipeline_depth > 1 consecutive segment spans
         overlap, so per-span achieved rates are upper-bounded approximations
-        of the interval — the bench's phase rooflines (cumulative totals /
-        phase wall) are the exact ones."""
+        of the interval."""
         rl = rounded_roofline(
             flops,
             bytes_accessed,
@@ -2765,8 +2763,8 @@ class InferenceEngine:
             cache.rollback(node)
             raise
         # The build counts as prefill work (amortised once per resident
-        # prefix, not per request) — the bench's prefill-tokens-per-request
-        # accounting must see it or reuse would overstate itself.
+        # prefix, not per request) — prefill-tokens-per-request accounting
+        # must see it or reuse would overstate itself.
         self.metrics.prefill_tokens.inc(R)
         cache.seal()  # dispatched: later cohorts may read these pages
         node.refs -= 1  # drop the insert's born-pin; callers re-pin
@@ -3573,8 +3571,8 @@ class InferenceEngine:
         while True:
             # Decode-loop host profiler (telemetry/flight.py): lap() marks
             # tile the iteration's wall time into named phases; prof is
-            # re-read each iteration so a live attach/detach (bench flight
-            # phase) lands at the next tick. None = no phase is timed (the
+            # re-read each iteration so a live attach/detach lands at the
+            # next tick. None = no phase is timed (the
             # pacer's stamps, one per admission, dispatch and ready stamp,
             # are all the clock this path reads). The TraceAnnotations put
             # the same phases, as
@@ -3966,7 +3964,7 @@ class InferenceEngine:
             # are still decoding: their page-slack geometry belongs to that
             # mode, so pause admission and let them drain — the flip lands
             # at the next empty-slab admission. This is what makes a
-            # runtime flip (bench mixed/spec phases, operator rollback)
+            # runtime flip (operator rollback)
             # safe rather than merely documented-safe.
             return
         hetero = slab.hetero
@@ -4285,7 +4283,7 @@ class InferenceEngine:
                 # record=False: hit/miss accounting happens AFTER the
                 # degrade decision below — a match the row cannot use
                 # (tree shrank, geometry infeasible) must not inflate the
-                # reuse counters bench phase 8 gates on.
+                # reuse counters.
                 P2, mpages, mnode = cache.match(
                     r.prompt_ids,
                     min(capacity - T, cache.match_cap(len(r.prompt_ids))),
@@ -4349,7 +4347,7 @@ class InferenceEngine:
                 if self._governor is not None:
                     # Per-tenant reuse accounting: matched vs prefilled
                     # tokens — the per-tenant hit-rate spread GET /cache
-                    # and bench phase 9's isolation gate read.
+                    # serves.
                     self._governor.on_lookup(r.tenant, P, len(ids))
             cohort.append(r)
             prompts.append(ids)
@@ -4576,9 +4574,9 @@ class InferenceEngine:
             slab.t_decode0[i] = t1
             if r.span is not None:
                 # Queue-wait (enqueue -> admission-prefill start): the
-                # HoL/admit-wait attribution the hetero-batching bench
-                # phases care about, now per request instead of only as a
-                # histogram.
+                # HoL/admit-wait attribution, per request instead of only
+                # as a histogram (the chip benchmark's engine.queue_*
+                # metrics read this span).
                 slab.n_traced += 1
                 tot = self._seg_cost_totals
                 slab.cost0[i] = (tot["flops"], tot["bytes"], tot["wall_s"])

@@ -485,9 +485,8 @@ class RadixPrefixCache:
         # never blocks on the cache. The pre-tier build only evicted when
         # already strictly over budget, so a tree that FILLED with
         # refcount-0 entries froze: every later insert was refused and
-        # the hit rate pinned at whatever happened to be resident — the
-        # PR 11 full-bench run caught it (phase-8 hit rate 0.0 after the
-        # headline phases saturated the node cap).
+        # the hit rate pinned at whatever happened to be resident (hit
+        # rate 0.0 once earlier traffic had saturated the node cap).
         if (
             self.resident_tokens + n_tokens > self.max_tokens
             or self.n_device_nodes + 1 > self.max_nodes

@@ -76,9 +76,9 @@ DRAFT_DECAY = 0.5
 
 def drafter_flops_per_token(d_model: int, vocab_size: int) -> float:
     """Analytic FLOPs attributed to one recurrent-drafter proposal: the
-    per-step ``h @ embed.T`` scoring matmul (2·D·V). Used by the bench's
-    MFU accounting so speculated runs bill the drafter's compute honestly
-    alongside the model's own 2·params·tokens."""
+    per-step ``h @ embed.T`` scoring matmul (2·D·V), so that a speculated
+    run's accounting can bill the drafter's compute alongside the model's
+    own 2·params·tokens. No caller since PR 30 (ROADMAP, Design debts)."""
     return 2.0 * d_model * vocab_size
 
 

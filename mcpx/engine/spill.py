@@ -29,9 +29,8 @@ Design constraints this module encodes:
     mutation under mcpxlint's thread-ownership pass. Cross-thread readers
     (``GET /cache``, ``queue_stats``) see GIL-atomic counter snapshots.
   - **Chaos-ready.** A seeded ``SpillChaos`` profile injects host-alloc
-    failures, copy-latency spikes and snapshot corruption so bench phase 9
-    and the resilience tests can prove the degradation paths, not just the
-    happy one.
+    failures, copy-latency spikes and snapshot corruption so the KV-tier
+    tests can prove the degradation paths, not just the happy one.
 
 ``evict-without-refcount-consult`` (mcpx/analysis/rules/cache_rules.py)
 polices the bug class the host tier must not reintroduce: every eviction
@@ -55,7 +54,7 @@ log = logging.getLogger("mcpx.engine.spill")
 class SpillChaos:
     """Seeded fault injector for the spill tier (ChaosTransport's design
     applied to the cache layer): deterministic per seed, rewindable via
-    ``reseed()`` so a bench can replay the exact fault sequence against
+    ``reseed()`` so the exact fault sequence can be replayed against
     tier configurations under comparison.
 
     Profile keys (all optional):

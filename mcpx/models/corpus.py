@@ -50,7 +50,7 @@ class CorpusConfig:
     # registry is a deployment artifact (the model serves THIS registry),
     # while fresh intent draws extend coverage without changing it.
     intent_seed: "int | None" = None
-    # Serving-parity knobs (bench.py's planner/engine geometry): 6-way
+    # Serving-parity knobs (the served planner/engine geometry): 6-way
     # shortlist, 128-token prompt budget (the BPE prefill bucket).
     shortlist_top_k: int = 6
     prompt_budget: int = 128

@@ -1,9 +1,8 @@
 """Offline planner evaluation: serve a checkpoint, score plan quality.
 
-One protocol shared by ``bench.py`` (``plan_quality_trained``), the
-``mcpx eval-planner`` CLI, and tests — the eval geometry (decode budget,
-shortlist width, registry seed) must not drift between them, or they
-silently measure different things."""
+One protocol shared by the ``mcpx eval-planner`` CLI and tests — the eval
+geometry (decode budget, shortlist width, registry seed) must not drift
+between them, or they silently measure different things."""
 
 from __future__ import annotations
 
@@ -132,7 +131,7 @@ async def evaluate_planner(
     out["llm_share"] = origins.get("llm", 0) / max(1, sum(origins.values()))
     out["node_f1"] = sum(f1s) / len(f1s) if f1s else 0.0
     out["node_f1_n"] = len(f1s)
-    # How the weights were actually served — callers (bench.py, the CLI)
-    # echo this instead of re-deriving it from their own knobs.
+    # How the weights were actually served — the CLI echoes this instead
+    # of re-deriving it from its own knobs.
     out["quantize"] = quantize
     return out

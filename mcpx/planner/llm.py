@@ -40,7 +40,7 @@ log = logging.getLogger("mcpx.planner.llm")
 
 # Cache sentinel for "this registry version compiles to shape-only": the
 # grammar cache must remember FAILED builds as well (they cost minutes at
-# the registry sizes where they fail — benchmarks/grammar_scale.py).
+# the registry sizes where they fail).
 _SHAPE_ONLY = object()
 
 # Fixed prompt header — byte-identical for every request against any
@@ -405,8 +405,7 @@ class LLMPlanner:
             )
             # A failed (shape-only) outcome is cached too: at the registry
             # sizes where the build fails, the failing attempts themselves
-            # cost minutes (benchmarks/grammar_scale.py) — re-running
-            # them per request behind this lock would serialize serving to
+            # cost minutes — re-running them per request behind this lock would serialize serving to
             # one plan per failure, and the grammar_fallbacks counter would
             # count requests instead of builds.
             self._grammar_cache[key] = _SHAPE_ONLY if grammar is None else grammar

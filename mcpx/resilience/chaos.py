@@ -1,10 +1,9 @@
 """ChaosTransport: a seeded, scriptable fault-injecting ``Transport`` wrapper.
 
 Wraps any transport and injects faults per endpoint according to a profile
-(JSON file or dict). Usable three ways: directly from tests, via
-``mcpx serve --chaos profile.json`` (the factory wraps the real transport),
-and by the bench's resilience scenario (same fault profile served with
-resilience on vs off).
+(JSON file or dict). Usable two ways: directly from tests, and via
+``mcpx serve --chaos profile.json`` (the factory wraps the real transport;
+the same fault profile can be served with resilience on and off).
 
 Profile schema (docs/resilience.md):
 
@@ -36,7 +35,7 @@ Determinism: all draws come from one seeded RNG consumed in a fixed order
 (flap check is clock-based, draws are error → timeout → spike), so a
 SEQUENTIAL call sequence replays exactly under the same seed. Concurrent
 callers interleave their draws nondeterministically — the marginal fault
-rates still hold, which is what the bench's A/B comparison needs.
+rates still hold, which is what an on-against-off comparison needs.
 """
 
 from __future__ import annotations
@@ -180,8 +179,8 @@ class ChaosTransport(Transport):
 
     def reseed(self) -> None:
         """Rewind the fault stream (fresh RNG from the profile seed, flap
-        phase restarted) — the bench's A/B rounds call this so both modes
-        face the same fault profile from the same starting state."""
+        phase restarted), so that two modes under comparison face the same
+        fault profile from the same starting state."""
         self._rng = random.Random(self._profile.seed)
         self._t0 = self._clock()
 

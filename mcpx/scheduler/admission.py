@@ -3,9 +3,9 @@ the shed decision carried back to the HTTP layer.
 
 Everything here is host-side bookkeeping measured in microseconds — the
 point of the subsystem is to spend THIS instead of engine queue slots when
-the answer would arrive after the caller stopped caring (BENCH_r05: the
-queue phase dominates /plan p50 at saturation; a request whose queue ETA
-already blows its deadline is pure wasted decode).
+the answer would arrive after the caller stopped caring (at saturation the
+queue phase dominates /plan p50; a request whose queue ETA already blows
+its deadline is pure wasted decode).
 """
 
 from __future__ import annotations

@@ -14,9 +14,9 @@ boundary (degrading instantly empties the queue, which would instantly
 The tier this degrades to is the model-free schema-chaining shortlist
 planner (``planner/heuristic.py``) — the TEACHER algorithm the trained
 checkpoint imitates (``models/corpus.py``), so degraded service is
-teacher-grade plans at microsecond cost, not garbage. (The trained LLM's
-own shortlist-typed score, BENCH_r05 ``shortlist_typed`` 0.956, measures
-the checkpoint under that grammar — not this heuristic tier.)
+teacher-grade plans at microsecond cost, not garbage. (What ``mcpx
+eval-planner --constrain-names shortlist`` scores is the trained checkpoint
+under that grammar — not this heuristic tier.)
 """
 
 from __future__ import annotations

@@ -118,7 +118,7 @@ def build_app(cp: ControlPlane) -> web.Application:
         resource = getattr(request.match_info.route, "resource", None)
         endpoint = resource.canonical if resource is not None else "unmatched"
         # Read per-request so a tracer can be attached/detached on a LIVE
-        # server (bench.py's latency-attribution phase does exactly that).
+        # server.
         tracer = cp.tracer
         root = (
             tracer.start_request(
@@ -134,8 +134,8 @@ def build_app(cp: ControlPlane) -> web.Application:
         t0 = time.monotonic()
         limited_path = request.path in _LIMITED
         # Cost ledger (mcpx/telemetry/ledger.py): one bill per serving-path
-        # request while the ledger is attached (read per-request so bench
-        # can attach/detach it live, like the tracer). The bill rides a
+        # request while the ledger is attached (read per-request so it
+        # can be attached/detached live, like the tracer). The bill rides a
         # contextvar through the handler's task; scheduler/engine/executor
         # items fold in along the way, and the finalize below rolls it
         # into the per-tenant usage ledger + the root span.
@@ -258,8 +258,8 @@ def build_app(cp: ControlPlane) -> web.Application:
         if not isinstance(intent, str) or not intent.strip():
             return _json_error(400, "'intent' must be a non-empty string")
         # SLO-aware admission scheduler (mcpx/scheduler/): read per-request
-        # so it can be attached/detached on a live server (bench overload
-        # phase). None = the pre-scheduler pass-through path, byte-identical
+        # so it can be attached/detached on a live server.
+        # None = the pre-scheduler pass-through path, byte-identical
         # responses included (no "planner" field).
         sched = cp.scheduler
         slot = None
@@ -593,7 +593,7 @@ def build_app(cp: ControlPlane) -> web.Application:
                 # Per-path ragged-kernel engagement + dispatch counts
                 # (decode / suffix-prefill / spec-verify) with the blocking
                 # reason when a path is not kernel-routed — the /costs
-                # twin of the bench's per-path pallas block.
+                # twin of /healthz's engine_queue.pallas block.
                 "pallas": engine.pallas_paths(),
                 "device": device,
             }
@@ -686,8 +686,8 @@ def build_app(cp: ControlPlane) -> web.Application:
                 )
                 for k, v in engine.queue_stats().items()
             }
-        # Surface the startup failure cause: a remote operator (or the bench
-        # session log) must be able to see WHY the engine is down without
+        # Surface the startup failure cause: a remote operator (or the chip
+        # benchmark's failure line) must be able to see WHY the engine is down without
         # shell access to the server's stderr — e.g. a device OOM string.
         err = getattr(engine, "_startup_error", None) if engine is not None else None
         if err is not None:

@@ -85,12 +85,11 @@ class ControlPlane:
         self.telemetry_mirror = telemetry_mirror
         self.redis_plan_cache = redis_plan_cache
         # SLO-aware admission scheduler (mcpx/scheduler/). Read per-request
-        # by the /plan handler, so it can be attached/detached at runtime
-        # (bench.py's overload phase enables it against a live server).
+        # by the /plan handler, so it can be attached/detached at runtime.
         self.scheduler = scheduler
         # Request-tracing spine (mcpx/telemetry/tracing.py). Read per-request
         # by the server middleware so it can be attached/detached on a live
-        # server (bench.py's attribution phase does exactly that).
+        # server.
         if tracer is None:
             from mcpx.telemetry.tracing import Tracer
 
@@ -100,7 +99,7 @@ class ControlPlane:
         # (mcpx/telemetry/ledger.py) and the SLO error-budget engine
         # (mcpx/telemetry/slo.py). Both None while disabled — the serving
         # path then carries no bill and no SLO observe. Read per-request
-        # by the middleware so bench can attach/detach them on a live
+        # by the middleware so they can be attached/detached on a live
         # server, like the tracer and the scheduler.
         from mcpx.telemetry.ledger import build_ledger
         from mcpx.telemetry.slo import build_slo_tracker

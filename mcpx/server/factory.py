@@ -90,8 +90,8 @@ def build_control_plane(
     if config.resilience.chaos_profile:
         # Chaos injection (`mcpx serve --chaos profile.json`): every
         # microservice call crosses the seeded fault injector. Wrapped
-        # OUTSIDE the resilience gate on purpose — the bench measures the
-        # same fault profile with resilience on vs off. The profile's
+        # OUTSIDE the resilience gate on purpose — the same fault profile
+        # can then be served with resilience on and off. The profile's
         # optional "cluster" section is NOT a transport fault — the engine
         # pool consumes it below (kill-a-replica / rejoin schedule).
         from mcpx.resilience.chaos import ChaosProfile, ChaosTransport

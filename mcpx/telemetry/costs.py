@@ -1,7 +1,7 @@
 """Per-executable XLA cost accounting + retrace sentinel (the roofline
 cost observatory's data plane).
 
-Motivation (ROADMAP item 2): the bench's MFU was an *analytic* estimate
+Motivation: an *analytic* MFU estimate
 (2·params·tokens) over a datasheet or measured peak — it moves when the
 model changes, not when the kernels do. XLA already knows exactly what
 every compiled executable costs (``compiled.cost_analysis()``: flops,
@@ -50,9 +50,9 @@ flops/bytes + wall time into achieved FLOP/s, achieved bytes/s, arithmetic
 intensity and a roofline position against the chip's datasheet peaks;
 :func:`hbm_stats`/:func:`update_hbm_gauges` expose per-device
 ``memory_stats()`` as HBM-pressure gauges. Consumers: the engine's
-``engine.prefill``/``engine.segment``/``engine.decode`` spans, the
-``GET /costs`` endpoint, and bench.py's per-phase roofline block
-(docs/observability.md §Roofline & cost accounting).
+``engine.prefill``/``engine.segment``/``engine.decode`` spans and the
+``GET /costs`` endpoint (docs/observability.md §Roofline & cost
+accounting).
 """
 
 from __future__ import annotations
@@ -76,8 +76,9 @@ __all__ = [
 
 # bf16 FLOP/s and HBM bytes/s per chip, by jax device_kind substring —
 # datasheet numbers (v5e: Google Cloud documentation, "TPU v5e"). The one
-# peaks table: the engine's span roofline, GET /costs and bench.py all read
-# it through device_peaks(). A hard-coded peak on an unknown chip would
+# peaks table: the engine's span roofline and GET /costs read it through
+# device_peaks() (benchmarks/chip/peaks.py is the chip benchmark's own
+# copy). A hard-coded peak on an unknown chip would
 # print a confidently-wrong roofline, so an accelerator that is not listed
 # is an error, and the CPU backend has no peak at all.
 _TPU_PEAKS: tuple[tuple[str, float, float], ...] = (
@@ -199,8 +200,7 @@ def roofline(
     return out
 
 
-# Report precision per roofline key — ONE contract shared by the engine's
-# span attrs and bench.py's phase block (they used to round independently).
+# Report precision per roofline key, as the engine's span attrs carry it.
 _ROOFLINE_ROUNDING = {
     "achieved_flops_s": 1,
     "achieved_bytes_s": 1,
@@ -444,7 +444,7 @@ class TrackedExecutable:
 class CostRegistry:
     """Registry of cost-tracked engine executables: the compile sentinel,
     the per-executable cost table, and the cumulative executed-work totals
-    the bench's roofline phases delta against."""
+    (``GET /costs``)."""
 
     def __init__(
         self, metrics: Any = None, *, enabled: bool = True, name: str = "engine"
@@ -527,7 +527,7 @@ class CostRegistry:
 
     # ------------------------------------------------------------- readers
     def snapshot(self, materialize: bool = True) -> dict:
-        """Cross-thread snapshot for GET /costs and the bench: per-
+        """Cross-thread snapshot for GET /costs: per-
         executable compile counts + per-signature costs, plus cumulative
         executed-work totals (Σ cost × calls) whose deltas give a timed
         phase's XLA-derived flops/bytes. ``materialize`` ensures pending

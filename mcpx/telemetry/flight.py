@@ -39,8 +39,8 @@ time into named phases (admit / locality-sort / prefix-match / dispatch /
 poll / harvest / spill-copy drain / host-bookkeeping / idle) with
 ``lap()`` timestamps between loop sections and ``carve()`` for nested
 sub-phases, aggregated into streaming log-bucketed histograms. Because
-laps tile the loop, attribution is ~100% by construction — the bench's
-``worker_profile`` block gates on >= 95%. It reads ``time.monotonic``, the
+laps tile the loop, attribution is ~100% by construction (tests hold it
+to >= 95%). It reads ``time.monotonic``, the
 spans' clock, so ``window()`` can hand the engine the phases between two
 segments' ready stamps as ``engine.segment`` span attributes. The engine
 builds one when ``tracing.enabled`` or ``telemetry.flight.profile_worker``;
@@ -77,9 +77,8 @@ __all__ = [
 # ===================================================================== profiler
 # Worker-loop phases. Names are the contract surfaced in queue_stats(),
 # the engine.segment span attrs (SEGMENT_PARTS below), the worker's
-# ``mcpx.worker.<phase>`` events in a profiler trace and the bench
-# worker_profile block — keep docs/observability.md in sync when touching
-# this tuple.
+# ``mcpx.worker.<phase>`` events in a profiler trace — keep
+# docs/observability.md in sync when touching this tuple.
 PROFILE_PHASES = (
     "idle",              # blocking waits for work (queue.get / gather window)
     # Blocking waits for arrivals to JOIN the next segment, which is held
@@ -217,8 +216,8 @@ class WorkerProfiler:
 
     def snapshot(self) -> dict:
         """Cross-thread profile snapshot: per-phase totals/shares/counts +
-        a histogram-derived p50 lap, and the attribution fraction the
-        bench acceptance gates on (attributed / wall between first and
+        a histogram-derived p50 lap, and the attribution fraction
+        (attributed / wall between first and
         last lap — ~1.0 by construction because laps tile the loop)."""
         t0, t1 = self.t_start, self.t_end
         wall = max(0.0, (t1 - t0)) if t0 is not None else 0.0
@@ -430,9 +429,9 @@ class _LogTail(logging.Handler):
 def _quantile_from_buckets(
     edges: list[float], counts: list[float], q: float
 ) -> Optional[float]:
-    """q-quantile (seconds) from cumulative histogram bucket counts —
-    the same upper-edge estimate bench.py's ``_hist_quantile`` uses; None
-    when the window saw no observations."""
+    """q-quantile (seconds) from cumulative histogram bucket counts: the
+    upper edge of the bucket that holds it; None when the window saw no
+    observations."""
     total = counts[-1] if counts else 0.0
     if total <= 0:
         return None

@@ -558,8 +558,7 @@ class Metrics:
             registry=self.registry,
         )
         # Per-request engine phase latencies, observed at retirement: where a
-        # request's wall time went (admission queue wait vs prefill vs decode)
-        # — the split VERDICT r2 demanded in the bench artifacts.
+        # request's wall time went (admission queue wait vs prefill vs decode).
         self.engine_queue_seconds = Histogram(
             "mcpx_engine_queue_seconds",
             "Time from enqueue to admission prefill start",

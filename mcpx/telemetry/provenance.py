@@ -24,7 +24,8 @@ per-request trail on a contextvar while ``telemetry.provenance.enabled``;
 is current. Off (the default) no trail ever exists — token outputs,
 queue_stats and span trees are byte-identical pass-through
 (parity-tested). Emission is host-side dict writes on the event loop —
-noise next to a model forward; the bench gates the overhead < 3%.
+noise next to a model forward (not measured on the chip: the benchmark's
+cells run with it off).
 
 Canonical layers (the ``mcpx_provenance_records_total{layer}`` label set
 — keep docs/observability.md in sync):

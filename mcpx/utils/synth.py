@@ -50,8 +50,8 @@ def _build_registry(
 ) -> list[ServiceRecord]:
     """One record-construction loop for every naming universe: the in- and
     out-of-distribution registries must keep IDENTICAL chaining structure
-    (key-sample sizes, cost ranges, fallback rate) or the OOD bench row
-    stops isolating tokenizer fit from workload shape.
+    (key-sample sizes, cost ranges, fallback rate) or a comparison on
+    the OOD registry stops isolating tokenizer fit from workload shape.
 
     RNG draw order is a compatibility surface: the committed BPE vocab,
     checkpoint, and every pinned "registry seed N" protocol artifact depend
@@ -111,8 +111,7 @@ def synth_registry_ood(n: int, seed: int = 0, local: bool = True) -> list[Servic
     a token universe disjoint from ``synth_registry``'s — the workload the
     committed BPE vocab was NOT fitted to (its ~6-8x compression is
     registry-fitted; `tests/test_bpe.py` pins the 1.6-2.1x OOD floor).
-    Bench rows on this registry keep the headline honest (VERDICT r4
-    weak #3). Same chaining structure as ``synth_registry`` (shared
+    No caller since PR 30 (ROADMAP, Design debts). Same chaining structure as ``synth_registry`` (shared
     ``_build_registry`` loop — the structural parity is by construction)."""
     return _build_registry(
         n,

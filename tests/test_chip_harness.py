@@ -1,19 +1,32 @@
 """The thin bridge from tier-1 to the chip harness (PERF.md Open question 12).
 
-The comparison that decides a benchmark run's ``correct``
-(``benchmarks/chip/reference.py::compare_with_engine_step``) driven from
-here, through the ``gemma`` block module, at ``model=test`` size with 4 KV
-heads, so that on the 2 x 2 mesh KV heads split over ``model`` and rows over
-``data``: the arm of ``_ragged_kernel_on_mesh`` and ``_write_kv_window`` that
-the four-chip cell ``mistral-7b.distinct-closed`` takes. The harness is
-imported by path, never copied. CPU, interpreted kernel: a correctness
-reading, not a device number.
+Two contracts between the program and ``benchmarks/chip``, each driven from
+here with the harness imported by path, never copied. CPU, interpreted
+kernel: correctness readings, not device numbers.
+
+1. The comparison that decides a benchmark run's ``correct``
+   (``reference.py::compare_with_engine_step``), through the ``gemma`` block
+   module, at ``model=test`` size with 4 KV heads, so that on the 2 x 2 mesh KV
+   heads split over ``model`` and rows over ``data``: the arm of
+   ``_ragged_kernel_on_mesh`` and ``_write_kv_window`` that the four-chip cell
+   ``mistral-7b.distinct-closed`` takes.
+2. What the harness READS from the served program (``served``, below): the
+   spans, span attributes, counters and health fields behind every per-layer
+   metric and behind ``correct``. A span, an attribute or a counter renamed in
+   the program leaves a ``null`` in the ledger's ``per_layer`` column, which
+   only a ``benchmark`` PR can repair; here it fails a test first.
 """
 
 import dataclasses
+import glob
 import importlib.util
+import json
+import math
 import os
+import re
+import subprocess
 import sys
+import time
 
 import jax
 import pytest
@@ -21,8 +34,8 @@ import pytest
 from mcpx.models.gemma.params import load_or_init
 from mcpx.parallel.mesh import make_mesh
 
-CHIP_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "benchmarks", "chip")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
 
 
 def _by_path(name):
@@ -65,3 +78,242 @@ def test_paged_step_with_kv_heads_over_model_agrees_with_the_plain_reference(har
 def test_int8_weights_control_fails_on_the_mesh(harness):
     plain, control = _compare(harness, (2, 2)), _compare(harness, (2, 2), control="int8-weights")
     assert not control["ok"] and control["rms_rel_err"] > 3 * plain["rms_rel_err"]
+
+
+# ------------------------------------------- what the harness reads, served
+CELL = "olmo2-1b.distinct-closed"
+# Readers that need a device profile, allocator statistics or the load
+# generator's own clock: nothing a served program on the CPU can feed.
+NOT_FED_HERE = {"device_op_share", "device_idle_share", "memory_in_use", "endpoint_spread",
+                "client_quantile"}
+LABELLED_SAMPLE = 'mcpx_engine_compiles_total{executable="admit"}'
+
+
+METRICS = [json.load(open(f)) for f in sorted(glob.glob(os.path.join(CHIP_DIR, "metrics", "*.json")))]
+FED = [m for m in METRICS if m["reader"] not in NOT_FED_HERE]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One rehearsal child of the harness (``child.py --rehearse-cpu``: the
+    served app at ``model=test``, LLM planner, interpreted kernel, tracing at
+    rate 1), five fresh ``/plan`` requests and one re-send, and around them
+    everything ``run.py`` fetches, through ``run.py``'s own functions."""
+    run = _by_path("run")  # imports its siblings by bare name, from CHIP_DIR
+    sys.path.remove(CHIP_DIR)
+    readers, spec, loadgen = (sys.modules[n] for n in ("readers", "spec", "loadgen"))
+    cell = spec.load_cell(CELL)
+    gen = loadgen.Generator({**cell.traffic, "registry_services": 120}, seed=30)
+    names = {r["name"] for r in gen.registry}
+    endpoints = sorted({m["args"]["endpoint"] for m in FED if "endpoint" in m["args"]})
+
+    run_dir = str(tmp_path_factory.mktemp("served"))
+    with open(os.path.join(run_dir, "registry.json"), "w") as f:
+        json.dump(gen.registry, f)
+    port = run.free_port()
+    cfg_path = os.path.join(run_dir, "mcpx_config.json")
+    with open(cfg_path, "w") as f:
+        json.dump(run.mcpx_config(cell, run_dir, port, trace=True, rehearsal=True), f)
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if "xla_force_host_platform_device_count" not in f]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=" ".join(flags))
+    log_path = os.path.join(run_dir, "server.log")
+    with open(log_path, "wb") as log:
+        child = subprocess.Popen(
+            [sys.executable, os.path.join(CHIP_DIR, "child.py"), "--config-file", cell.config_file,
+             "--mcpx-config", cfg_path, "--port", str(port), "--rehearse-cpu"],
+            cwd=REPO, env=env, stdout=log, stderr=subprocess.STDOUT, start_new_session=True,
+        )
+    ctl = run.Client(port, run.SCRAPE_TIMEOUT_S)
+    loop = None
+    try:
+        run.wait_started(child, ctl, time.monotonic(), {})
+        marks0 = ctl.request("GET", "/bench/marks")[1]
+        counters0 = run.fetch_counters(ctl, endpoints)
+
+        def post_factory():
+            c = run.Client(port, float(cell.traffic["request_timeout_s"]))
+
+            def post(intent):
+                status, body, headers = c.request("POST", "/plan", {"intent": intent})
+                why = run.plan_problem(status, body, names, cell.traffic["origin"])
+                return (not why), why, headers.get("X-Trace-Id", "") if headers else ""
+
+            return post
+
+        loop = loadgen.Loop(gen, post_factory, clients=2)
+        loop.start()
+        deadline = time.monotonic() + run.WARM_DEADLINE_S
+        while loop.fresh_done < 5 and time.monotonic() < deadline and child.poll() is None:
+            time.sleep(0.05)
+        drained = loop.stop(float(cell.traffic["request_timeout_s"]))
+        samples = loop.snapshot()
+        ok, why, trace_id = post_factory()(samples[0].intent)  # the re-send: a plan-cache hit
+        samples.append(loadgen.Sample(0.0, 0.0, 0.0, ok, False, why, trace_id, intent=samples[0].intent))
+
+        counters1 = run.fetch_counters(ctl, endpoints)
+        health = ctl.request("GET", "/healthz")[1]
+        marks1 = ctl.request("GET", "/bench/marks")[1]
+        costs = ctl.request("GET", "/costs")[1]
+        traces = []
+        for s in samples:
+            status, body, _ = ctl.request("GET", f"/traces/{s.trace_id}")
+            if status == 200:
+                traces.append(body)
+    except BaseException:
+        print(run.tail(log_path), file=sys.stderr)
+        raise
+    finally:
+        if loop is not None:
+            loop.stop(0.0)
+        ctl.close()
+        run.stop_child(child)
+    ev = readers.Evidence(  # as run.py builds it in a rehearsal
+        gen_late_ms=[s.gen_late_ms for s in samples], traces=traces,
+        counters_before=counters0, counters_after=counters1, device=None,
+        memory_in_use_bytes=None, config=cell.config, device_kind="cpu",
+    )
+    pallas = (health.get("engine_queue") or {}).get("pallas") or {}
+    found = readers.vocabulary()  # made once, as run.py does
+    return dict(
+        run=run, ev=ev, found=found, histogram=readers.histogram,
+        read=lambda reader, args: readers.read_metric(ev, reader, args, found), samples=samples, drained=drained, health=health,
+        pallas=pallas, paths=pallas.get("paths") or {}, costs=costs,
+        kernel_paths=marks1["kernel_paths"],
+        engine_metrics=(marks0["engine_metrics"], marks1["engine_metrics"]),
+    )
+
+
+def test_the_requests_were_answered(served):
+    assert served["drained"] and len(served["samples"]) >= 6
+    assert [s.why for s in served["samples"] if not s.ok] == []
+    assert len(served["ev"].traces) == len(served["samples"])
+    # the re-send is the plan cache's hit, the fresh intents its misses
+    hit_share = next(m for m in FED if m["name"] == "planner.cache_hit_share")
+    assert 0 < served["read"](hit_share["reader"], hit_share["args"]) < 100
+
+
+@pytest.mark.parametrize("metric", FED, ids=[m["name"] for m in FED])
+def test_the_program_feeds_the_metric(served, metric):
+    v = served["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v), (
+        f"{metric['name']}: reader {metric['reader']}{metric['args']} found nothing in what the "
+        "served program emits (a span, an attribute or a counter it reads was renamed?)"
+    )
+
+
+def test_every_metric_is_fed_here_or_left_out_by_its_readers_name(served):
+    assert all(m["reader"] in served["found"] for m in METRICS)
+    assert len(FED) >= 17 and NOT_FED_HERE <= set(served["found"])
+
+
+def _compiles(served):
+    total = served["run"].prom_total
+    return tuple(total(text, "mcpx_engine_compiles_total") for text in served["engine_metrics"])
+
+
+# What run.py reads from the server to decide ``correct`` (run.py, "what
+# served it" and ``correctness_problems``): field -> what a sound rehearsal shows.
+CORRECT_READS = {
+    "healthz.engine": lambda s: s["health"]["engine"] == "ready",
+    "healthz.started": lambda s: s["health"]["started"] is True,
+    "pallas.enabled": lambda s: s["pallas"]["enabled"] is True,
+    "pallas.interpret": lambda s: s["pallas"]["interpret"] is True,  # False on the chip
+    "pallas.paths.decode": lambda s: s["paths"]["decode"]["engaged"] is True
+    and s["paths"]["decode"]["dispatches"] > 0,
+    "pallas.paths.prefill": lambda s: s["paths"]["prefill"]["engaged"] is True
+    and s["paths"]["prefill"]["dispatches"] >= 0,
+    "kernel_paths": lambda s: set(s["kernel_paths"]) <= set(s["paths"]),
+    "engine_queue.mesh": lambda s: s["health"]["engine_queue"]["mesh"] == {"data": 1, "model": 1},
+    "mcpx_engine_resets_total": lambda s: "mcpx_engine_resets_total" in s["engine_metrics"][1]
+    and s["run"].prom_total(s["engine_metrics"][1], "mcpx_engine_resets_total") == 0,
+    "mcpx_engine_compiles_total": lambda s: _compiles(s)[0] > 0,
+    "no_compile_after_started": lambda s: _compiles(s)[1] == _compiles(s)[0],
+    "costs.device.peaks.platform": lambda s: s["costs"]["device"]["peaks"]["platform"] == "cpu",
+    "costs.device.peaks.n_devices": lambda s: s["costs"]["device"]["peaks"]["n_devices"] == 1
+    and isinstance(s["costs"]["device"]["peaks"]["device_kind"], str),
+    "costs.device.hbm": lambda s: isinstance(s["costs"]["device"]["hbm"], list),
+}
+
+
+@pytest.mark.parametrize("field", list(CORRECT_READS))
+def test_the_program_reports_what_correct_reads(served, field):
+    assert CORRECT_READS[field](served)
+
+
+def test_the_harness_finds_the_rehearsal_correct(served):
+    problems = served["run"].correctness_problems(
+        failed=[s for s in served["samples"] if not s.ok], n_good=len(served["samples"]),
+        drained=served["drained"], edges=None, ref={"ok": True},
+        resets=served["run"].prom_total(served["engine_metrics"][1], "mcpx_engine_resets_total"),
+        compiles=_compiles(served), platform=served["costs"]["device"]["peaks"]["platform"],
+        pallas=served["pallas"], kernel_paths=served["kernel_paths"], rehearsal=True,
+    )
+    assert problems == []
+
+
+def test_a_trace_body_has_the_keys_the_readers_index(served):
+    for tr in served["ev"].traces:
+        assert isinstance(tr["started_at"], float) and tr["tree"]
+        assert sum(sp["parent_id"] is None for sp in tr["tree"]) == 1
+        for sp in tr["tree"]:
+            assert {"name", "parent_id", "start_ms", "duration_ms"} <= set(sp)
+            assert isinstance(sp.get("attrs", {}), dict)  # left out of a span that has none
+        assert any(sp.get("attrs") for sp in tr["tree"])
+
+
+def test_the_metrics_parser_addresses_a_labelled_sample_of_the_live_server(served):
+    after = served["ev"].counters_after["/metrics"]
+    assert after[LABELLED_SAMPLE] >= 1
+    assert served["read"](
+        "endpoint_value", {"endpoint": "/metrics", "path": LABELLED_SAMPLE}
+    ) == after[LABELLED_SAMPLE]
+
+
+def test_the_info_lines_histogram_of_plan_lengths_is_not_empty(served):
+    hist = served["histogram"](served["ev"], "engine.decode", "tokens")
+    fresh = sum(s.fresh for s in served["samples"])  # the re-send decodes nothing
+    assert sum(hist.values()) == fresh >= 5 and all(0 < tokens <= 48 for tokens in hist)
+
+
+# ------------------------------------------------ what the documents name
+_DOCS = ["README.md"] + sorted(
+    os.path.relpath(p, REPO) for p in glob.glob(os.path.join(REPO, "docs", "*.md"))
+)
+_TOP = set(os.listdir(REPO))
+_BASENAMES = {"control_plane.py"}  # the reference system's file, cited as such
+for _d, _dirs, _files in os.walk(REPO):
+    _dirs[:] = [d for d in _dirs if not d.startswith(".") and d != "chiprun_out"]
+    _BASENAMES.update(_files)
+
+
+def _named_paths(text):
+    """Repository paths and ``python <script>`` / ``python -m <module>`` targets
+    that a document names in backticks or code blocks."""
+    out = set()
+    for token in re.findall(r"`([^`\n]+)`", text) + re.findall(r"^\s*(python3? .+)$", text, re.M):
+        words = token.split()
+        if words[0] in ("python", "python3") and len(words) > 1:
+            if words[1] == "-m" and len(words) > 2:
+                out.add(words[2].replace(".", "/"))
+            elif words[1].endswith(".py"):
+                out.add(words[1])
+            continue
+        word = re.split(r"::|:\d|#", words[0])[0].rstrip("/.,")
+        if re.search(r"[*<>{}$|()\[\]]", word) or word.startswith(("/", "http", ".")):
+            continue
+        if "/" in word and word.split("/")[0] in _TOP:
+            out.add(word)
+        elif re.fullmatch(r"\w+\.(py|md|jsonl)|[A-Z]\w*\.json", word) and word not in _BASENAMES:
+            out.add(word)  # a bare file name that no file of the tree has
+    return out
+
+
+@pytest.mark.parametrize("doc", _DOCS)
+def test_every_path_and_command_a_document_names_exists(doc):
+    named = _named_paths(open(os.path.join(REPO, doc)).read())
+    missing = sorted(
+        p for p in named
+        if not (os.path.exists(os.path.join(REPO, p)) or os.path.exists(os.path.join(REPO, p + ".py")))
+    )
+    assert not missing, f"{doc} names {missing}, which the repository does not have"

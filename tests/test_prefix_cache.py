@@ -326,8 +326,7 @@ def test_engine_reuse_compile_invariance_and_pin_api():
             for c, w in zip(cold, warm):
                 assert w.text == c.text
             # (1) reuse observable: matched tokens grew, and each repeat
-            # prefilled at most its final partial page (the >=5x collapse
-            # the bench phase measures at registry scale).
+            # prefilled at most its final partial page.
             assert eng._prefix_cache.matched_tokens > m0
             assert pf_repeats <= len(prompts) * psz, (pf_repeats, pf_cold)
             st = eng.prefix_cache_stats()

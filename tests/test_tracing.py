@@ -6,8 +6,6 @@ exemplar linkage, and disabled-mode no-op equivalence on engine outputs."""
 import asyncio
 import json
 import logging
-import os
-import sys
 
 import pytest
 
@@ -25,8 +23,6 @@ from mcpx.telemetry.tracing import (
 )
 
 from tests.helpers import FakeService, make_transport
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ------------------------------------------------------------------ span tree
@@ -467,32 +463,6 @@ def test_json_log_lines_carry_trace_ids():
     assert inside["span_id"] == root.span_id
     assert inside["msg"] == "inside request"
     assert "trace_id" not in outside
-
-
-# ----------------------------------------------------------- bench attribution
-def test_bench_attribution_from_traces():
-    sys.path.insert(0, REPO)
-    import bench
-
-    tr = Tracer(enabled=True)
-    recs = []
-    for i in range(4):
-        root = tr.start_request("/plan")
-        t0 = root.t0
-        root.child("sched.acquire", t0=t0, t1=t0 + 0.004)
-        root.child("engine.queue_wait", t0=t0 + 0.004, t1=t0 + 0.010)
-        root.child("engine.prefill", t0=t0 + 0.010, t1=t0 + 0.030)
-        root.child("engine.decode", t0=t0 + 0.030, t1=t0 + 0.090)
-        root.end(t0 + 0.100)
-        tr.finish(root)
-        recs.append(tr.get(root.trace_id))
-    out = bench._attribution_from_traces(recs)
-    assert out["traces"] == 4
-    assert abs(out["p50_ms"]["decode"] - 60.0) < 1.0
-    assert abs(out["p50_ms"]["total"] - 100.0) < 1.0
-    assert abs(out["share_p50"]["decode"] - 0.6) < 0.02
-    assert out["p99_ms"]["prefill"] >= out["p50_ms"]["prefill"]
-    assert bench._attribution_from_traces([]) is None
 
 
 # ------------------------------------------------- engine no-op + attribution
