@@ -149,27 +149,38 @@ class EngineConfig:
     max_pages_per_seq: int = 128
     max_batch_size: int = 32
     max_prefill_tokens: int = 4096
-    # Model forwards per decode segment. Between segments the worker admits
-    # newly-arrived requests into free slab rows (continuous batching), so
-    # this bounds admission latency: smaller = lower p50 under load, larger
-    # = fewer host round-trips per token. With speculation each forward
-    # covers up to speculate_k tokens. Sized so a segment's compute (~4
-    # weight-bound forwards) roughly covers one host<->device round trip:
-    # the pipelined worker overlaps the flag fetch with the next segment.
+    # Model forwards per decode TICK: the unit a decode segment's length
+    # is counted in (a segment is a whole number of ticks, at least one;
+    # the speculative segment is always exactly one). Between segments the
+    # worker admits newly-arrived requests into free slab rows (continuous
+    # batching) and answers finished ones, so a shorter segment means a
+    # lower p50 under load and a longer one fewer host round-trips per
+    # token. With speculation each forward covers up to speculate_k
+    # tokens.
     decode_steps_per_tick: int = 4
-    # Fused multi-step decode dispatch (ISSUE 15): how many decode
-    # iterations fold into ONE jitted dispatch — the dispatched window
-    # runs decode_steps_per_tick * steps_per_dispatch model forwards
-    # in-graph (one executable; per-row done masks are DATA, so finished
+    # Fused multi-step decode dispatch (ISSUE 15): how many decode ticks
+    # may fold into ONE jitted dispatch. decode_steps_per_tick *
+    # steps_per_dispatch model forwards is the CEILING of a segment's
+    # length (one executable; per-row done masks are DATA, so finished
     # rows idle safely and the loop still exits early when the whole slab
-    # drains). The r07 worker profile measured XLA dispatch at ~80% of
-    # the engine worker's wall: host-side bookkeeping (harvest, admission,
-    # gauge publish) then runs once per fused window instead of once per
-    # tick, amortising exactly that line. 1 = per-step-window legacy
-    # cadence. Tradeoff: a new arrival
-    # waits up to one fused window for admission, and retirement lags by
-    # pipeline_depth-1 windows — size the product against your admission-
-    # latency budget (docs/engine.md "Ragged kernel & fused decode
+    # drains); host-side bookkeeping (harvest, admission, gauge publish)
+    # runs once per segment. The length SERVED is chosen at each dispatch
+    # by the pacer (ISSUE 31, engine/pacing.py::segment_forwards) and
+    # passed to that one executable as an operand: the fewest whole ticks
+    # whose device time covers the worker's own work for a segment three
+    # times over and the prefill chain in front of it, this ceiling until the
+    # worker has measured a forward's period and its own costs. Why not
+    # always the ceiling: a plan is charged whole segments (one waiting
+    # behind the segment in flight, then as many as its tokens need, its
+    # reply leaving only at a segment's end), and on the chip the worker
+    # needs 22-67 ms of host work a segment where 16 forwards last
+    # 138-305 ms (ledger, PR 30: engine.host_ms_per_forward 1.91 / 1.40 /
+    # 4.19 ms, step.forward_period_ms 8.65 / 16.06 / 19.07 ms), so the
+    # full window was four times longer than the host needs.
+    # 1 = per-tick cadence always. Tradeoff of a high ceiling: while no
+    # estimate exists a new arrival waits up to one full window for
+    # admission, and retirement lags by pipeline_depth-1 segments
+    # (docs/engine.md "Ragged kernel & fused decode
     # dispatch"). The speculative segment is NOT multiplied: its
     # iterations are unrolled without early exit (pool-aliasing
     # constraint) and each already amortises dispatch over a [rows, K+1]

@@ -6,8 +6,13 @@ replacement: an in-process serving stack where
 
   - requests funnel through a thread-safe queue into a dedicated worker
     thread that owns a persistent **slab** of ``max_batch_size`` decode rows;
-  - decode runs in bounded **segments** (``decode_steps_per_tick`` model
-    forwards per segment, one jitted ``lax.while_loop`` each); between
+  - decode runs in bounded **segments** (one jitted ``lax.while_loop``
+    each, of whole ticks of ``decode_steps_per_tick`` model forwards: as
+    many as the pacer asks for at that dispatch, at most the configured
+    window ``decode_steps_per_tick x steps_per_dispatch``; the length is
+    an operand of ONE executable, ``engine/pacing.py::segment_forwards``
+    chooses it from the forward period, prefill chain and host costs the
+    worker measures); between
     segments the worker admits newly-arrived requests into free rows
     (prefill → commit-to-pages → first sample → merge) and retires finished
     rows — *continuous batching*: a request never waits for a previous
@@ -519,6 +524,10 @@ class InferenceEngine:
         # queue_stats()'s worker_profile.
         self._hold_joined = 0  # mcpx: owner[engine-worker]
         self._hold_joined_total = 0  # mcpx: owner[engine-worker, atomic]
+        # Lifetime sums of the decode segments' lengths as dispatched and
+        # of the configured ceiling (worker_profile, like the total above).
+        self._window_total = 0  # mcpx: owner[engine-worker, atomic]
+        self._window_max_total = 0  # mcpx: owner[engine-worker, atomic]
         # Just-in-time dispatch of the next segment (engine/pacing.py): the
         # device's queue as the worker knows it and the running estimates
         # its hold deadline comes from. One clock read per admission,
@@ -1070,6 +1079,11 @@ class InferenceEngine:
                     # Rows admitted while a segment was held for them (the
                     # "hold" phase's seconds are among the phases).
                     "hold_joined_rows": self._hold_joined_total,
+                    # Forwards asked for over all dispatches and the
+                    # ceilings they were chosen under: their ratio is how
+                    # far the pacer shortens the segment.
+                    "window_forwards": self._window_total,
+                    "window_max_forwards": self._window_max_total,
                 }
             }
             if prof is not None
@@ -1256,17 +1270,18 @@ class InferenceEngine:
         # out_buf is NOT donated: the pipelined worker reads a LAGGED
         # segment's out_buf after newer segments were already dispatched —
         # donation would invalidate the handle it still has to fetch. The
-        # copy is [B, steps] int32, noise next to the KV pools.
+        # copy is [B, steps] int32, noise next to the KV pools. ``iters``,
+        # the segment's length, is a device operand (an int32 scalar in
+        # the while_loop's condition), not a static: the pacer chooses it
+        # at every dispatch and ONE executable serves every length.
         self._jit_segment = wrap(
             "segment",
             jax.jit(
                 self._segment_impl,
-                static_argnames=(
-                    "iters", "chunk", "temperature", "constrained", "draft",
-                ),
+                static_argnames=("chunk", "temperature", "constrained", "draft"),
                 donate_argnames=("paged_k", "paged_v"),
             ),
-            static_argnames=("iters", "chunk", "temperature", "constrained", "draft"),
+            static_argnames=("chunk", "temperature", "constrained", "draft"),
         )
         # Merges donate NOTHING: their inputs are the newest segment's
         # output handles, which the newest in-flight entry still needs
@@ -1287,10 +1302,10 @@ class InferenceEngine:
             "hetero_segment",
             jax.jit(
                 self._hetero_segment_impl,
-                static_argnames=("iters", "chunk"),
+                static_argnames=("chunk",),
                 donate_argnames=("paged_k", "paged_v"),
             ),
-            static_argnames=("iters", "chunk"),
+            static_argnames=("chunk",),
         )
         # Grammar-aware speculative decoding (engine/speculative.py): the
         # drafter-propose + one-forward-verify segment. K and the draft
@@ -1645,7 +1660,8 @@ class InferenceEngine:
         ecfg = self.config.engine
         key = jax.random.PRNGKey(0)
         chunk = self._spec_chunk(True)
-        iters = self._decode_iters(spec=False)
+        # The length is an operand: the ceiling here, any length served.
+        iters = np.int32(self._decode_iters(spec=False))
         rs_b = self._row_spec(slab.B)
         rs_b2 = self._row_spec(slab.B, 1)
         if ecfg.hetero_batch:
@@ -2117,25 +2133,40 @@ class InferenceEngine:
         return budget
 
     def _decode_iters(self, spec: bool) -> int:
-        """Model-forward iterations per dispatched decode executable — the
-        FUSED MULTI-STEP WINDOW: ``decode_steps_per_tick`` (the legacy
+        """The most model forwards one dispatched decode segment may run —
+        the FUSED MULTI-STEP WINDOW: ``decode_steps_per_tick`` (the legacy
         tick) times ``steps_per_dispatch`` folded into one jitted
         ``lax.while_loop`` whose per-row done masks are data, so one host
-        dispatch + one harvest serve the whole window (the r07 profiler's
-        ~80%-dispatch line, amortised). The while loop exits early when
-        every row drains, so a long window never burns device compute —
-        only admission latency, which is the knob's documented tradeoff.
+        dispatch + one harvest serve the whole window. It is a CEILING:
+        the length a segment is dispatched with is chosen by the pacer
+        (``_segment_window``) and reaches the executable as an operand, so
+        warmup (which passes this ceiling) and every served length share
+        one executable. The while loop exits early when every row drains,
+        so a long window never burns device compute — only the time a
+        finished row and a fresh arrival wait for the segment's end.
         The SPECULATIVE segment is excluded: its iterations are unrolled
         without early exit (pool-aliasing constraint, see
         ``_hetero_segment_spec_impl``) and each already covers a
         [rows, K+1] window, so multiplying it would pay full verify
-        compute on the drain tail. Shared by warmup and dispatch so the
-        warmed executable is exactly the served one."""
+        compute on the drain tail; its count stays a static, one tick."""
         ecfg = self.config.engine
         base = max(1, ecfg.decode_steps_per_tick)
         if spec:
             return base
         return base * max(1, ecfg.steps_per_dispatch)
+
+    def _segment_window(self, spec: bool) -> tuple[int, int]:
+        """(forwards the next segment may run, the configured ceiling).
+        Worker thread only. The pacer sizes the segment from what it has
+        measured (``pacing.segment_forwards``): whole ticks, long enough
+        to cover the worker's own work and the prefill chain in front,
+        the ceiling until it has an estimate. The speculative segment is
+        always its one tick."""
+        ceiling = self._decode_iters(spec)
+        if spec:
+            return ceiling, ceiling
+        tick = self.config.engine.decode_steps_per_tick
+        return self._pacer.window(tick, ceiling), ceiling
 
     def _spec_chunk(self, constrained: bool) -> int:
         """Static speculation chunk width — config-derived only (it is a jit
@@ -4756,11 +4787,14 @@ class InferenceEngine:
         ecfg = self.config.engine
         hetero = slab.hetero
         chunk = self._spec_chunk(True if hetero else slab.constrained)
-        # Fused multi-step window: one dispatch covers steps_per_dispatch
-        # ticks of decode (host bookkeeping runs once per window); the
+        # Fused multi-step window: one dispatch covers up to
+        # steps_per_dispatch ticks of decode (host bookkeeping runs once
+        # per window), as many as the pacer asks for at this dispatch; the
         # spec segment keeps its own per-tick iteration count (see
         # _decode_iters for both rationales).
-        iters = self._decode_iters(spec=hetero and slab.spec)
+        window, window_max = self._segment_window(spec=hetero and slab.spec)
+        self._window_total += window
+        self._window_max_total += window_max
         self.metrics.segments.inc()
         self.metrics.segment_active_rows.inc(slab.n_active)
         # Per-path kernel accounting (pallas_paths): every segment is a
@@ -4804,7 +4838,7 @@ class InferenceEngine:
                     dfa_d,
                     hst_d,
                     prng,
-                    iters=iters,
+                    iters=window,
                     K=slab.spec_k,
                     draft=slab.spec_draft,
                 )
@@ -4833,7 +4867,7 @@ class InferenceEngine:
                     cons_d,
                     dfa_d,
                     prng,
-                    iters=iters,
+                    iters=np.int32(window),
                     chunk=chunk,
                 )
                 cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, n_fwd = out
@@ -4856,7 +4890,7 @@ class InferenceEngine:
                     plens_d,
                     prev_d,
                     prng,
-                    iters=iters,
+                    iters=np.int32(window),
                     chunk=chunk,
                     temperature=slab.temperature,
                     constrained=slab.constrained,
@@ -4873,7 +4907,7 @@ class InferenceEngine:
         # only when some resident request is traced (or the cost ledger is
         # billing).
         now = self._pacer.clock()
-        self._pacer.dispatched(t_submit, now, iters)
+        self._pacer.dispatched(t_submit, now, window)
         t_disp = now if (slab.n_traced or self._ledger_on) else 0.0
         seg_exec = (
             self._jit_hetero_segment_spec
@@ -4895,11 +4929,13 @@ class InferenceEngine:
                 # traced spans and request bills with it.
                 getattr(seg_exec, "last_entry", None),
                 getattr(seg_exec, "name", "segment"),
-                # The engine.segment spans' seq, prefill_rows and
-                # hold_joined_rows.
+                # The engine.segment spans' seq, prefill_rows,
+                # hold_joined_rows, window and window_max.
                 seq,
                 prefill_rows,
                 hold_joined,
+                window,
+                window_max,
             )
         )
 
@@ -4959,6 +4995,7 @@ class InferenceEngine:
             (
                 done_d, e_d, buf_d, nfwd_d, gen_snap, t_disp, spec_h, cons_snap,
                 seg_cost, seg_name, seq, prefill_rows, hold_joined,
+                window, window_max,
             ) = self._inflight.popleft()
             # ONE combined fetch (flags + out_buf): a blocking fetch costs
             # its round trip, not the ~24KB of buffer — splitting into
@@ -5048,6 +5085,10 @@ class InferenceEngine:
                         dfa_id=int(slab.dfa[i]),
                         cls="constrained" if slab.cons[i] else "free",
                         forwards=int(n_fwd),
+                        # What the pacer asked for at the dispatch, and
+                        # the configured ceiling it chose under.
+                        window=window,
+                        window_max=window_max,
                         # Whole-slab segment roofline (XLA cost over the
                         # dispatch->harvest window) — identical across the
                         # segment's rows by construction, as is its
@@ -5166,6 +5207,9 @@ class InferenceEngine:
                 )
                 self._release_row(slab, i)
                 r.loop.call_soon_threadsafe(_resolve, r.future, res, None)
+            # The host's share of this harvest: what the pacer sizes the
+            # next segment against, with an admission and a dispatch.
+            self._pacer.harvested(t_ready, self._pacer.clock())
 
     @staticmethod
     def _segment_timeline(

@@ -9,23 +9,35 @@ empty. So the worker HOLDS N+2: it waits on its queue, admitting arrivals,
 until N+1 is nearly ready, and dispatches N+2 just before that, so the
 device's queue never empties and nothing starts later than it could have.
 
+How LONG the segment is belongs to the same bookkeeping. A plan is charged
+whole segments: one waiting behind the segment in flight, then as many as
+its tokens need, and its reply leaves only at a segment's end. A short
+segment wastes less of a row's time; what bounds it from below is the
+worker itself, which has to admit, dispatch and harvest once a segment
+without ever letting the device's queue empty, and the prefill chain that
+an admission puts in front of each one. ``segment_forwards`` chooses the
+length at each dispatch from the same estimates; the configured window
+(``decode_steps_per_tick x steps_per_dispatch``) is its ceiling.
+
 This module is the part of that with no device in it: ``SegmentPacer``
 models the device's FIFO from what the worker observes (what it enqueued
 and when, and each segment's ready stamp), keeps running estimates of a
 forward's period, an admission's prefill chain and the worker's own costs
-of one admission and one dispatch, and predicts when the newest segment in
-flight will be ready; ``hold_until`` is the decision. The worker loop that
-acts on it is ``InferenceEngine._worker``.
+of one admission, one dispatch and one harvest, and predicts when the
+newest segment in flight will be ready; ``hold_until`` and
+``segment_forwards`` are the decisions. The worker loop that acts on them
+is ``InferenceEngine._worker``.
 """
 
 from __future__ import annotations
 
+import math
 import statistics
 import time
 from collections import deque
 from typing import Callable, Optional
 
-__all__ = ["SegmentPacer", "hold_until"]
+__all__ = ["SegmentPacer", "hold_until", "segment_forwards"]
 
 
 def hold_until(
@@ -57,6 +69,49 @@ def hold_until(
         return None
     until = ready_at - margin
     return until if until > now else None
+
+
+# How short a decode segment may get, as multiples of what the pacer
+# measures (PERF.md, PR 31, has the chip runs that chose them). The
+# segment's own forwards must last HOST_COVER times the worker's host work
+# for one segment (the medians of an admission, a dispatch, a harvest):
+# three times, because the work comes in lumps (a second admission in one
+# segment, one admission in fifty at several times the median, everything
+# at twice its cost while a profiler session is open) and the device's
+# queue is only one segment deep behind the one that runs. At 2.0 every
+# benchmark cell ran one tick of 4, the four-chip one on the edge of two
+# with its device waiting 7 ms at a time for an admission's copies; at 3.0
+# olmo2-1b runs 8 forwards with the need at 1.5 ticks, well short of the 12
+# that read worse than no change there. And they must last
+# PREFILL_COVER times the prefill chain an admission puts in front of
+# them: a period that is mostly prefill pads its cohort bucket once a
+# segment and decodes nobody meanwhile.
+HOST_COVER = 3.0
+PREFILL_COVER = 1.0
+
+
+def segment_forwards(
+    *,
+    tick: int,
+    ceiling: int,
+    forward_s: Optional[float],
+    prefill_s: float,
+    host_s: Optional[float],
+) -> int:
+    """How many forwards the next decode segment may run: the fewest whole
+    ticks whose device time covers the worker's host work for a segment
+    ``HOST_COVER`` times and the prefill chain in front ``PREFILL_COVER``
+    times, never more than ``ceiling`` (the configured window, a whole
+    number of ``tick``s itself) and never less than one tick. With no
+    estimate of a forward's period or of the host's costs yet, the
+    ceiling: the length every segment had before ISSUE 31."""
+    tick = max(1, tick)
+    ceiling = max(tick, ceiling)
+    if forward_s is None or forward_s <= 0 or host_s is None:
+        return ceiling
+    need_s = max(HOST_COVER * host_s, PREFILL_COVER * prefill_s)
+    ticks = max(1, math.ceil(need_s / (forward_s * tick)))
+    return min(ceiling, ticks * tick)
 
 
 class _Recent:
@@ -96,6 +151,7 @@ class SegmentPacer:
         self._prefill = _Recent()
         self._admit = _Recent()
         self._dispatch = _Recent()
+        self._harvest = _Recent()
         # Device work enqueued since the last ready stamp, oldest first:
         # (host time enqueued, forwards), forwards 0 = a prefill chain.
         self._queued: "deque[tuple[float, int]]" = deque()
@@ -121,6 +177,24 @@ class SegmentPacer:
             return None
         return admit + dispatch
 
+    @property
+    def host_s(self) -> Optional[float]:
+        """The worker's own work for one segment: an admission, a dispatch
+        and a harvest's bookkeeping (nothing until one was timed)."""
+        margin = self.margin_s
+        return None if margin is None else margin + (self._harvest.value or 0.0)
+
+    def window(self, tick: int, ceiling: int) -> int:
+        """Forwards the next segment may run (``segment_forwards`` on the
+        current estimates)."""
+        return segment_forwards(
+            tick=tick,
+            ceiling=ceiling,
+            forward_s=self.forward_s,
+            prefill_s=self.prefill_s,
+            host_s=self.host_s,
+        )
+
     def ready_at(self) -> Optional[float]:
         """Predicted ready time of the newest segment in flight: the queue
         replayed from the last ready stamp, each item starting when the
@@ -144,6 +218,10 @@ class SegmentPacer:
     def dispatched(self, t0: float, t1: float, forwards: int) -> None:
         self._dispatch.add(t1 - t0)
         self._queued.append((t1, max(1, forwards)))
+
+    def harvested(self, t0: float, t1: float) -> None:
+        """The host's bookkeeping after a segment's fetch returned."""
+        self._harvest.add(t1 - t0)
 
     def ready(self, t_ready: float, forwards: int) -> None:
         """The oldest segment in flight was fetched at ``t_ready`` after

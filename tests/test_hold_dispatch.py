@@ -19,7 +19,16 @@ from mcpx.telemetry import tracing
 from mcpx.telemetry.tracing import Tracer
 
 
-class HoldsTillDone(SegmentPacer):
+class WholeWindow(SegmentPacer):
+    """A pacer that never shortens a segment (ISSUE 31): every dispatch
+    runs the configured window, so that ``LONG`` below stays long whatever
+    this CPU measures."""
+
+    def window(self, tick, ceiling):
+        return ceiling
+
+
+class HoldsTillDone(WholeWindow):
     """Every segment in flight is predicted ready far ahead, at no margin:
     the worker holds whenever the decision's other conditions allow it,
     until its look at the device finds the segment done."""
@@ -31,7 +40,7 @@ class HoldsTillDone(SegmentPacer):
         return self.clock() + 30.0
 
 
-class NoEstimate(SegmentPacer):
+class NoEstimate(WholeWindow):
     """A worker that has not seen a clean period yet."""
 
     def ready_at(self):
@@ -303,5 +312,59 @@ def test_without_a_reason_to_hold_the_worker_never_waits(rows, engine, pacer):
         held_tokens, held = await run(make_engine(4), HoldsTillDone)
         assert held["phases"]["hold"]["count"] > 0
         assert tokens == held_tokens and all(len(t) > 0 for t in tokens)
+
+    asyncio.run(asyncio.wait_for(go(), 240))
+
+
+def test_the_worker_dispatches_and_reports_the_length_the_pacer_chose():
+    """ISSUE 31: the pacer is asked at every dispatch how many forwards
+    the segment may run, under the configured window (4 ticks of 4) as its
+    ceiling; the segment runs no more than that, the worker reports that
+    length back (``ready_at()`` predicts the segment that was sent, not
+    the configured one), and the engine.segment spans and the worker's
+    profile carry what was asked and the ceiling it was asked under."""
+
+    class TwoTicks(SegmentPacer):
+        forward_s = 0.010  # the estimate the prediction is made with
+
+        def __init__(self):
+            super().__init__()
+            self.asked, self.told = [], []
+
+        def window(self, tick, ceiling):
+            self.asked.append((tick, ceiling))
+            return 2 * tick
+
+        def dispatched(self, t0, t1, forwards):
+            super().dispatched(t0, t1, forwards)
+            self.told.append((forwards, self.ready_at() - t1))
+
+    async def go():
+        eng = make_engine(rows=2)  # decode_steps_per_tick 4 x steps_per_dispatch 4
+        await eng.start()
+        try:
+            tracer = Tracer(None, enabled=True, sample_rate=1.0)
+            await traced(eng, tracer, "warm the shapes", 8)
+            before = dict(eng.queue_stats()["worker_profile"])
+            pacer = eng._pacer = TwoTicks()
+            spans = await traced(eng, tracer, "a request of some length", 60)
+            after = eng.queue_stats()["worker_profile"]
+        finally:
+            await eng.aclose()
+        assert pacer.asked and set(pacer.asked) == {(4, 16)}
+        assert [f for f, _ in pacer.told] == [8] * len(pacer.asked)
+        # From idle the device's queue held nothing in front: the segment
+        # is predicted ready 8 forwards after its dispatch, not 16.
+        assert pacer.told[0][1] == pytest.approx(8 * 0.010, abs=1e-6)
+        segs = segments(spans)
+        assert len(segs) >= 3
+        for s in segs:
+            assert (s.attrs["window"], s.attrs["window_max"]) == (8, 16)
+            assert 1 <= s.attrs["forwards"] <= 8
+        assert any(s.attrs["forwards"] == 8 for s in segs)
+        n = len(pacer.asked)
+        assert after["window_forwards"] - before["window_forwards"] == 8 * n
+        assert after["window_max_forwards"] - before["window_max_forwards"] == 16 * n
+        assert before["window_forwards"] <= before["window_max_forwards"]
 
     asyncio.run(asyncio.wait_for(go(), 240))

@@ -1,11 +1,20 @@
-"""Just-in-time dispatch of the next decode segment (ISSUE 29), the part
-with no device in it: the hold's decision as a pure function, and the
-pacer's model of the device queue on an injected clock. Nothing here
-depends on how fast anything decodes."""
+"""Just-in-time dispatch of the next decode segment (ISSUE 29) and its
+length (ISSUE 31), the part with no device in it: the hold's decision and
+the segment's length as pure functions, and the pacer's model of the
+device queue on an injected clock. Nothing here depends on how fast
+anything decodes."""
+
+import math
 
 import pytest
 
-from mcpx.engine.pacing import SegmentPacer, hold_until
+from mcpx.engine.pacing import (
+    HOST_COVER,
+    PREFILL_COVER,
+    SegmentPacer,
+    hold_until,
+    segment_forwards,
+)
 
 OK = dict(in_flight=True, free_rows=3, backlog=0, ready_at=10.0, margin=0.025)
 
@@ -145,3 +154,84 @@ def test_periods_with_prefills_inside_bound_the_estimate_until_a_clean_one():
     pacer.dispatched(t, t + 0.001, 16)
     pacer.ready(t + 0.300, 0)
     assert pacer.forward_s == pytest.approx(0.005)
+
+
+# ------------------------------------------------- the segment's length
+# A tick of 4 forwards under a window of 16; a clean forward of 8 ms, so a
+# tick is 32 ms of device time.
+SIZED = dict(tick=4, ceiling=16, forward_s=0.008, prefill_s=0.0, host_s=0.0)
+
+
+def _host(ticks: float) -> float:
+    """Host work a segment that ``HOST_COVER`` turns into ``ticks`` ticks."""
+    return ticks * 0.032 / HOST_COVER
+
+
+def _chain(ticks: float) -> float:
+    return ticks * 0.032 / PREFILL_COVER
+
+
+@pytest.mark.parametrize(
+    "change, want",
+    [
+        ({"forward_s": None}, 16),  # no period seen yet: the configured window
+        ({"host_s": None}, 16),  # no admission or dispatch timed yet
+        ({"forward_s": None, "host_s": None, "ceiling": 64}, 64),
+        ({"host_s": _host(1.6)}, 8),  # the host's work sets the floor
+        ({"host_s": _host(2.0)}, 8),  # exactly covered: no tick more
+        ({"host_s": _host(2.1)}, 12),
+        ({"prefill_s": _chain(1.3)}, 8),  # the prefill chain sets the floor
+        ({"host_s": _host(0.5), "prefill_s": _chain(2.5)}, 12),  # the larger
+        ({"host_s": _host(2.5), "prefill_s": _chain(0.5)}, 12),  # of the two
+        ({"host_s": _host(9.0)}, 16),  # never above the ceiling
+        ({"prefill_s": _chain(40.0)}, 16),
+        ({}, 4),  # nothing to cover: never under one tick
+        ({"host_s": _host(0.2), "prefill_s": _chain(0.9)}, 4),
+        ({"forward_s": 0.016, "host_s": _host(1.6)}, 4),  # a slower forward covers sooner
+        ({"forward_s": 0.004, "host_s": _host(1.6)}, 16),
+        ({"ceiling": 4, "host_s": _host(3.0)}, 4),  # steps_per_dispatch 1: the tick
+        ({"tick": 1, "ceiling": 16, "host_s": _host(1.6)}, 7),  # ticks of one forward
+        ({"tick": 3, "ceiling": 12, "host_s": _host(1.6)}, 9),  # whole ticks of three
+    ],
+)
+def test_segment_length_decision(change, want):
+    assert segment_forwards(**{**SIZED, **change}) == want
+
+
+@pytest.mark.parametrize("tick, steps", [(1, 1), (1, 64), (4, 4), (4, 16), (3, 5)])
+def test_segment_length_is_whole_ticks_between_one_tick_and_the_window(tick, steps):
+    ceiling = tick * steps
+    seen = set()
+    for forward_ms in (0.5, 2, 6.35, 12.76, 15.4, 40):
+        for host_ms in (0, 1, 10, 26, 60, 400):
+            for prefill_ms in (0, 5, 15, 50, 99):
+                n = segment_forwards(
+                    tick=tick, ceiling=ceiling, forward_s=forward_ms / 1e3,
+                    prefill_s=prefill_ms / 1e3, host_s=host_ms / 1e3,
+                )
+                assert tick <= n <= ceiling and n % tick == 0
+                seen.add(n)
+    assert tick in seen and ceiling in seen  # both ends are reached
+
+
+def test_the_pacer_sizes_the_segment_from_what_the_worker_reported():
+    pacer = SegmentPacer(Clock())
+    assert pacer.host_s is None and pacer.window(4, 16) == 16  # nothing known
+    t = steady(pacer, 1.0, 4, 0.160, 16)  # 10 ms a forward; a dispatch 2 ms
+    assert pacer.host_s is None and pacer.window(4, 16) == 16  # no admission yet
+    pacer.admitted(t + 0.004, t + 0.024)
+    # 20 ms + 2 ms of host work a segment against ticks of 40 ms.
+    assert pacer.host_s == pytest.approx(0.022)
+    want = 4 * math.ceil(HOST_COVER * 0.022 / 0.040)
+    assert pacer.window(4, 64) == want == segment_forwards(
+        tick=4, ceiling=64, forward_s=0.010, prefill_s=0.0, host_s=0.022
+    )
+    pacer.harvested(t + 0.030, t + 0.050)  # the harvest's bookkeeping counts
+    assert pacer.host_s == pytest.approx(0.042)
+    longer = 4 * math.ceil(HOST_COVER * 0.042 / 0.040)
+    assert pacer.window(4, 64) == longer > want
+    assert pacer.window(4, 8) == 8  # under a lower ceiling
+    assert pacer.window(1, 64) == math.ceil(HOST_COVER * 0.042 / 0.010)
+    # The length it was told is the length it predicts with.
+    pacer.dispatched(t + 0.051, t + 0.053, longer)
+    assert pacer.ready_at() == pytest.approx(t + 0.160 + longer * 0.010)

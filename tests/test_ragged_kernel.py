@@ -2,7 +2,8 @@
 seeded kernel-vs-reference property coverage over mixed row batches,
 compile-count invariance across ragged phase mixes via the cost-registry
 sentinel, and fused-vs-per-step greedy byte parity through the live
-engine (mid-window retirement, replan pin, spill/readmit interleave)."""
+engine (mid-window retirement, replan pin, spill/readmit interleave), and
+every window length through ONE segment executable (ISSUE 31)."""
 
 import asyncio
 import random
@@ -13,6 +14,7 @@ import numpy as np
 import pytest
 
 from mcpx.core.config import MCPXConfig
+from mcpx.engine.pacing import SegmentPacer
 from mcpx.engine.kernels.paged_attention import (
     ragged_paged_attention,
     ragged_paged_attention_reference,
@@ -355,6 +357,96 @@ def test_fused_vs_per_step_greedy_byte_parity_with_mid_window_retirement():
         finally:
             await per_step.aclose()
             await fused.aclose()
+
+    asyncio.run(go())
+
+
+class _FixedWindow(SegmentPacer):
+    """Every segment is asked for ``n`` forwards, and what the worker then
+    reports as dispatched is kept."""
+
+    def __init__(self, n: int) -> None:
+        super().__init__()
+        self.n, self.lengths = n, []
+
+    def window(self, tick, ceiling):
+        return min(ceiling, self.n)
+
+    def dispatched(self, t0, t1, forwards):
+        self.lengths.append(forwards)
+        super().dispatched(t0, t1, forwards)
+
+
+@pytest.mark.parametrize("hetero", [False, True], ids=["homogeneous", "hetero"])
+def test_one_executable_serves_every_window_with_the_same_greedy_tokens(hetero):
+    """The segment's length is an OPERAND (ISSUE 31): the same greedy
+    requests, staggered so rows retire mid-window, decode byte-identical
+    tokens at windows of 4, 8 and 16 forwards on one engine, and the
+    compile sentinel does not move between them: one executable, whatever
+    length the pacer asks for. The per-step engine of the case above is
+    the reference at a fourth length."""
+
+    async def go():
+        eng = _mk(
+            steps_per_dispatch=4, hetero_batch=hetero, prefix_cache=False,
+            max_decode_len=64,
+            warmup_compile=True, warmup_max_len=64,  # as served: all warm at start
+        )
+        ref = _mk(
+            steps_per_dispatch=1, hetero_batch=hetero, prefix_cache=False,
+            max_decode_len=64,
+        )
+        await eng.start()
+        await ref.start()
+        try:
+            tok = eng.tokenizer
+            prompts = [
+                tok.encode(f"Window parity header.\nintent {i}: compose. JSON:")
+                for i in range(6)
+            ]
+            budgets = [2, 60, 7, 41, 1, 12]  # retire at different forwards
+
+            async def serve(e):
+                rs = await asyncio.gather(
+                    *(
+                        e.generate(
+                            p, max_new_tokens=b, constrained=True, temperature=0.0
+                        )
+                        for p, b in zip(prompts, budgets)
+                    )
+                )
+                return [r.token_ids for r in rs]
+
+            def compiles():
+                return {
+                    name: e["compiles"]
+                    for name, e in eng.costs.snapshot(materialize=False)[
+                        "executables"
+                    ].items()
+                }
+
+            assert all(len(p) <= 64 for p in prompts)
+            want = await serve(ref)
+            assert all(want)
+            # What the benchmark's ``correct`` reads: the compile count at
+            # ``started`` must not move, whatever lengths are served.
+            snap, dispatches = compiles(), {}
+            assert snap["hetero_segment" if hetero else "segment"] == 1
+            for n in (4, 8, 16):
+                pacer = eng._pacer = _FixedWindow(n)
+                before = eng.pallas_paths()["paths"]["decode"]["dispatches"]
+                assert await serve(eng) == want, n
+                assert set(pacer.lengths) == {n}  # asked for, and reported
+                dispatches[n] = (
+                    eng.pallas_paths()["paths"]["decode"]["dispatches"] - before
+                )
+                assert compiles() == snap, (n, snap, compiles())
+            # The length did change the cadence: more, shorter segments.
+            assert dispatches[4] >= dispatches[8] >= dispatches[16] >= 2
+            assert dispatches[4] > dispatches[16]
+        finally:
+            await eng.aclose()
+            await ref.aclose()
 
     asyncio.run(go())
 
