@@ -79,6 +79,7 @@ from mcpx.core.errors import ConfigError, EngineError
 from mcpx.engine.kv_cache import PageAllocator, commit_prefill_to_pages, init_paged_kv
 from mcpx.engine.pacing import SegmentPacer, hold_until
 from mcpx.engine.paged_decode import decode_chunk_paged
+from mcpx.models.gemma.moe import moe_stats_init
 from mcpx.engine.prefix_cache import PrefixNode, RadixPrefixCache
 from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window_rows
 from mcpx.engine.speculative import advance_drafter_state, draft_window
@@ -424,6 +425,27 @@ class InferenceEngine:
             max_seq_len=self.config.model.max_seq_len,
             vocab_size=self.tokenizer.vocab_size,
         )
+        mc = self.model_cfg
+        if not mc.is_default_block:
+            # What was written against the default block and has not been
+            # carried over to another: an error at construction, not a wrong
+            # answer later.
+            unsupported = {
+                "model.quantize=int8 (no quantizer for router, expert or head leaves)":
+                    self.config.model.quantize != "none",
+                "engine.speculative (the drafter scores against a tied embedding)":
+                    ecfg.speculative.enabled,
+                "engine.ring_prefill_min_tokens (ring attention has one rope and no window)":
+                    ecfg.ring_prefill_min_tokens > 0,
+            }
+            asked = [what for what, on in unsupported.items() if on]
+            if asked:
+                raise ConfigError(
+                    f"this model's block departs from the default one, which {asked} requires"
+                )
+        # Which counters the segment returns beside its state (sparse
+        # feed-forward, windowed attention); a default block returns none.
+        self._segment_stats = (bool(mc.n_experts), mc.layer_windows() is not None)
         self.grammar: PlanGrammar = build_plan_grammar(self.tokenizer)
         self.metrics = metrics or Metrics()
         # Resolved kernel route, decided at construction so a COLD engine
@@ -528,6 +550,10 @@ class InferenceEngine:
         # of the configured ceiling (worker_profile, like the total above).
         self._window_total = 0  # mcpx: owner[engine-worker, atomic]
         self._window_max_total = 0  # mcpx: owner[engine-worker, atomic]
+        # Lifetime sums of the engine.segment spans' layer-kind counters
+        # (_layer_kind_attrs), swapped in whole for queue_stats(); stays
+        # empty for a model whose layers are all dense and full.
+        self._layer_kind_totals: dict[str, int] = {}  # mcpx: owner[engine-worker, atomic]
         # Just-in-time dispatch of the next segment (engine/pacing.py): the
         # device's queue as the worker knows it and the running estimates
         # its hold deadline comes from. One clock read per admission,
@@ -1084,6 +1110,7 @@ class InferenceEngine:
                     # far the pacer shortens the segment.
                     "window_forwards": self._window_total,
                     "window_max_forwards": self._window_max_total,
+                    **self._layer_kind_totals,
                 }
             }
             if prof is not None
@@ -2872,7 +2899,8 @@ class InferenceEngine:
         Emissions are written at absolute slots ``out_buf[b, emitted..]`` so
         rows admitted at different segment boundaries coexist in one slab.
         Returns (cur, pos, st, emitted, done, pools_k, pools_v, out_buf,
-        prev, n_forwards).
+        prev, n_forwards) and, for a model with sparse or windowed layers,
+        their counters (``_segment_stats``) as one more int32 vector.
         """
         cfg = self.model_cfg
         tok = self.tokenizer
@@ -2884,15 +2912,16 @@ class InferenceEngine:
         pad, eos = tok.pad_id, tok.eos_id
         b_idx = jnp.arange(B)
         use_draft = draft and constrained and chunk > 1 and temperature <= 0.0
+        sparse, windowed = self._segment_stats
 
         def cond(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = c
             return (it < iters) & jnp.any(~done)
 
         def draft_body(c):
             from mcpx.engine.sampling import NEG_INF
 
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = c
             J = chunk - 1
             Lp = prompt_toks.shape[1]
             j_ar = jnp.arange(J)
@@ -2954,7 +2983,7 @@ class InferenceEngine:
             # (p_use is a prefix mask), so the kernel streams pages for
             # what the row actually proposed, not the static chunk width.
             chunk_toks = jnp.concatenate([cur[:, None], p_toks], axis=1)
-            logits_c, kv = decode_chunk_paged(
+            logits_c, kv, *fwd_ms = decode_chunk_paged(
                 params,
                 cfg,
                 chunk_toks,
@@ -2968,6 +2997,7 @@ class InferenceEngine:
                 q_lens=jnp.where(
                     done, 0, 1 + jnp.sum(p_use, axis=1).astype(jnp.int32)
                 ),
+                moe_stats=sparse,
             )  # [B, chunk, C] float32
 
             # --- verify: accepted prefix = positions where the proposal IS
@@ -3031,10 +3061,11 @@ class InferenceEngine:
                 buf,
                 prev2,
                 key,
+                ms + fwd_ms[0] if sparse else ms,
             )
 
         def body(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = c
 
             if chunk > 1 and constrained:
                 # Fast-forward: chain of forced tokens after `cur`. Emission
@@ -3083,7 +3114,7 @@ class InferenceEngine:
             # consumed chain (0 for done rows — they idle through the
             # fused window at zero attention cost).
             adv = jnp.where(done, 0, 1) + adv_extra  # tokens consumed
-            last_logits, kv = decode_chunk_paged(
+            last_logits, kv, *fwd_ms = decode_chunk_paged(
                 params,
                 cfg,
                 chunk_toks,
@@ -3095,6 +3126,7 @@ class InferenceEngine:
                 mesh=self._mesh,
                 logits_at=jnp.maximum(adv - 1, 0),  # [B, V]: chain-end only
                 q_lens=adv,
+                moe_stats=sparse,
             )
 
             key, sub = jax.random.split(key)
@@ -3141,8 +3173,13 @@ class InferenceEngine:
                 buf,
                 prev2,
                 key,
+                ms + fwd_ms[0] if sparse else ms,
             )
 
+        rows_at_dispatch = []
+        if windowed:
+            past = ~done & (pos >= cfg.sliding_window)
+            rows_at_dispatch = [jnp.stack([jnp.sum(past), jnp.sum(~done)]).astype(jnp.int32)]
         init = (
             jnp.asarray(0, jnp.int32),
             cur,
@@ -3155,11 +3192,18 @@ class InferenceEngine:
             out_buf,
             prev,
             key,
+            moe_stats_init(cfg) if sparse else None,
         )
-        it, cur, pos, st, e, done, k_p, v_p, buf, prev, key = lax.while_loop(
+        it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = lax.while_loop(
             cond, draft_body if use_draft else body, init
         )
-        return cur, pos, st, e, done, k_p, v_p, buf, prev, it
+        out = (cur, pos, st, e, done, k_p, v_p, buf, prev, it)
+        if not any(self._segment_stats):
+            return out
+        # What the layer kinds did, for the lagged harvest's one fetch: the
+        # expert counters of the segment's forwards, then the rows that were
+        # live at dispatch and those of them at or past the window.
+        return out + (jnp.concatenate(([ms] if sparse else []) + rows_at_dispatch),)
 
     def _hetero_segment_impl(
         self,
@@ -4809,7 +4853,7 @@ class InferenceEngine:
             ptoks_d, plens_d, prev_d, temp_d, cons_d, dfa_d, hst_d,
         ) = self._dev_state(slab)
         prng = jax.random.PRNGKey((self._rng_base + self._seg_counter) & 0x7FFFFFFF)
-        dr_d = ac_d = cons_snap = None
+        dr_d = ac_d = cons_snap = kinds_d = None
         self._dispatch_seq += 1
         seq = self._dispatch_seq
         prefill_rows, self._rows_admitted = self._rows_admitted, 0
@@ -4896,7 +4940,9 @@ class InferenceEngine:
                     constrained=slab.constrained,
                     draft=ecfg.draft_mode == "prompt",
                 )
-                cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, prev_d, n_fwd = out
+                (cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, prev_d, n_fwd,
+                 *kind_stats) = out
+                kinds_d = kind_stats[0] if kind_stats else None
         self._paged_kv = {"k": k_p, "v": v_p}
         slab.dev = (
             cur_d, pos_d, st_d, e_d, done_d, budgets_d, pt_d, buf_d,
@@ -4936,8 +4982,42 @@ class InferenceEngine:
                 hold_joined,
                 window,
                 window_max,
+                # The layer kinds' counters of this segment (None for a
+                # model of dense, full layers): _layer_kind_attrs.
+                kinds_d,
             )
         )
+
+    def _layer_kind_attrs(self, counts: np.ndarray, n_fwd: int) -> dict[str, int]:
+        """One harvested segment's layer-kind counters (the vector
+        ``_segment_impl`` appends) as engine.segment attributes, identical
+        on the segment's rows, and into the lifetime sums and the per-expert
+        counter. Sparse feed-forward: ``moe_assignments`` (live tokens times
+        the experts each chose here, over the segment's forwards and sparse
+        layers), ``moe_experts_touched`` ((forward, layer, expert) triples
+        with at least one live token: the experts whose weights were read)
+        and ``moe_expert_slots`` (forwards x sparse layers x experts held:
+        what reading every expert would come to). Windowed attention:
+        ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
+        whose position had reached the window."""
+        mc = self.model_cfg
+        sparse, windowed = self._segment_stats
+        attrs: dict[str, int] = {}
+        if sparse:
+            E = mc.n_experts_held
+            per_expert = counts[:E]
+            attrs["moe_assignments"] = int(per_expert.sum())
+            attrs["moe_experts_touched"] = int(counts[E])
+            attrs["moe_expert_slots"] = n_fwd * mc.n_layers * E
+            for i in np.flatnonzero(per_expert):
+                self.metrics.moe_expert_tokens.labels(expert=str(mc.expert_first + int(i))).inc(
+                    int(per_expert[i])
+                )
+        if windowed:
+            attrs["rows_past_window"], attrs["rows_live"] = int(counts[-2]), int(counts[-1])
+        totals = self._layer_kind_totals
+        self._layer_kind_totals = {k: totals.get(k, 0) + v for k, v in attrs.items()}
+        return attrs
 
     def _account_speculation(
         self, dr: np.ndarray, ac: np.ndarray, cons_snap: np.ndarray
@@ -4995,7 +5075,7 @@ class InferenceEngine:
             (
                 done_d, e_d, buf_d, nfwd_d, gen_snap, t_disp, spec_h, cons_snap,
                 seg_cost, seg_name, seq, prefill_rows, hold_joined,
-                window, window_max,
+                window, window_max, kinds_d,
             ) = self._inflight.popleft()
             # ONE combined fetch (flags + out_buf): a blocking fetch costs
             # its round trip, not the ~24KB of buffer — splitting into
@@ -5013,10 +5093,17 @@ class InferenceEngine:
                     done, e, buf, n_fwd, dr, ac = jax.device_get(
                         (done_d, e_d, buf_d, nfwd_d) + spec_h
                     )
+                elif kinds_d is not None:
+                    done, e, buf, n_fwd, kind_counts = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d, kinds_d)
+                    )
                 else:
                     done, e, buf, n_fwd = jax.device_get(
                         (done_d, e_d, buf_d, nfwd_d)
                     )
+            kind_attrs = (
+                self._layer_kind_attrs(kind_counts, int(n_fwd)) if kinds_d is not None else {}
+            )
             timeline = {}
             # The segment's ready stamp: the fetch has just returned. The
             # pacer learns the period from it, profiler or none.
@@ -5095,6 +5182,7 @@ class InferenceEngine:
                         # timeline (_segment_timeline).
                         **seg_attrs,
                         **timeline,
+                        **kind_attrs,
                     )
                     if dr is not None:
                         # Speculation attribution per traced row: how many

@@ -83,14 +83,16 @@ def paged_attention_chunk_reference(
     page_table: jax.Array,  # [B, Pmax] int32
     start_pos: jax.Array,  # [B] int32 — cache position of query 0
     layer: jax.Array | int = 0,
+    window: "jax.Array | int | None" = None,
 ) -> jax.Array:
     """Chunked decode attention, pure jnp: query i of sequence b attends
     through cache position ``start_pos[b]+i`` (itself + earlier chunk
-    tokens, already written to the pools). Gathers each sequence's pages
-    ONCE for all S queries — folding the chunk into the batch dim instead
-    would re-gather the same pages S times, which at chunk width 8 is 8x
-    the HBM traffic of this formulation (the dominant cost of jnp-path
-    decode). Returns [B, S, K, G, hd] in q.dtype."""
+    tokens, already written to the pools), and under a ``window`` no
+    further back than the ``window - 1`` positions before itself. Gathers
+    each sequence's pages ONCE for all S queries — folding the chunk into
+    the batch dim instead would re-gather the same pages S times, which at
+    chunk width 8 is 8x the HBM traffic of this formulation (the dominant
+    cost of jnp-path decode). Returns [B, S, K, G, hd] in q.dtype."""
     B, S, K, G, hd = q.shape
     _, _, _, psz, _ = k_pages.shape
     p_max = page_table.shape[1]
@@ -102,6 +104,8 @@ def paged_attention_chunk_reference(
     logits = logits * scale
     vis = start_pos[:, None] + jnp.arange(S) + 1  # [B, S]
     mask = jnp.arange(L)[None, None, :] < vis[:, :, None]  # [B, S, L]
+    if window is not None:
+        mask &= jnp.arange(L)[None, None, :] >= vis[:, :, None] - window
     logits = jnp.where(mask[:, :, None, None, :], logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bskgl,bklh->bskgh", weights.astype(v.dtype), v)
@@ -116,6 +120,7 @@ def ragged_paged_attention_reference(
     start_pos: jax.Array,  # [B] int32 — cache position of query 0
     q_lens: jax.Array,  # [B] int32 — live queries per row (0 = idle row)
     layer: jax.Array | int = 0,
+    window: "jax.Array | int | None" = None,
 ) -> jax.Array:
     """Ragged mixed-phase semantics, pure jnp: row ``b``'s queries at
     window index ``i < q_lens[b]`` attend through cache position
@@ -124,7 +129,7 @@ def ragged_paged_attention_reference(
     pinned here so the interpret-parity tests cover pads too, not just the
     positions the callers happen to read. Returns [B, S, K, G, hd]."""
     out = paged_attention_chunk_reference(
-        q, k_pages, v_pages, page_table, start_pos, layer
+        q, k_pages, v_pages, page_table, start_pos, layer, window
     )
     valid = jnp.arange(q.shape[1])[None, :] < q_lens[:, None]  # [B, S]
     return jnp.where(valid[:, :, None, None, None], out, 0).astype(q.dtype)
@@ -148,26 +153,19 @@ def _ragged_n_pages(start, qn, page_size: int, p_max: int):
     return jnp.where(qn > 0, n, 0)
 
 
-def _ragged_kernel(
-    # scalar prefetch
-    page_table_ref,  # [B, Pmax] SMEM
-    start_pos_ref,  # [B] SMEM
-    q_lens_ref,  # [B] SMEM — live queries per row (ragged; 0 = idle row)
-    layer_ref,  # [1] SMEM — which layer's pool slice to stream
-    # blocks
-    q_ref,  # [1, Sq, 1, G, hd] VMEM — one query block of the window
-    k_pages_ref,  # [K, L, N, Psz, hd] ANY (stays in HBM)
-    v_pages_ref,
-    out_ref,  # [1, Sq, 1, G, hd] VMEM
-    # scratch
-    k_buf,  # [NBUF, Psz, hd] VMEM
-    v_buf,
-    sem_k,  # DMA sems [NBUF]
-    sem_v,
-    *,
-    page_size: int,
-    n_buf: int,
-):
+def _ragged_kernel(*refs, page_size: int, n_buf: int, windowed: bool):
+    """``refs``: the scalar prefetch — page_table [B, Pmax], start_pos [B],
+    q_lens [B] (live queries per row; 0 = idle row), layer [1] (which
+    layer's pool slice to stream) and, ``windowed``, window [1] (this
+    call's attention window) — all SMEM; then the blocks q [1, Sq, 1, G, hd]
+    VMEM (one query block of the window), k_pages / v_pages [K, L, N, Psz,
+    hd] ANY (they stay in HBM), out [1, Sq, 1, G, hd] VMEM; then the scratch
+    k_buf / v_buf [NBUF, Psz, hd] VMEM and their DMA semaphores [NBUF]."""
+    page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
+    window_ref = refs[4] if windowed else None
+    q_ref, k_pages_ref, v_pages_ref, out_ref, k_buf, v_buf, sem_k, sem_v = refs[
+        4 + windowed :
+    ]
     b = pl.program_id(0)
     kh = pl.program_id(1)
     layer = layer_ref[0]
@@ -186,6 +184,14 @@ def _ragged_kernel(
     # row or an all-pad block (qn=0) streams nothing (see _ragged_n_pages)
     # and falls through to the zero output.
     n_pages = _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1])
+    # Under a window the block's FIRST query sees no key before position
+    # start - window + 1, and a later query sees none earlier still: pages
+    # wholly before it are never streamed. (No window: every page from 0.)
+    n_stream, page_at = n_pages, lambda i: i
+    if windowed:
+        window = window_ref[0]
+        first = jnp.minimum(jnp.maximum(start - window + 1, 0) // page_size, n_pages)
+        n_stream, page_at = n_pages - first, lambda i: first + i
 
     q = q_ref[0, :, 0].reshape(S * G, hd).astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
@@ -213,17 +219,17 @@ def _ragged_kernel(
     # latency (the decode-attention bottleneck at small page sizes).
     for j in range(n_buf):
 
-        @pl.when(j < n_pages)
+        @pl.when(j < n_stream)
         def _():
-            dma_k(j, j).start()
-            dma_v(j, j).start()
+            dma_k(j, page_at(j)).start()
+            dma_v(j, page_at(j)).start()
 
     def body(i, carry):
         m, l, acc = carry  # [S*G, 1], [S*G, 1], [S*G, hd] fp32
         slot = lax.rem(i, n_buf)
 
-        dma_k(slot, i).wait()
-        dma_v(slot, i).wait()
+        dma_k(slot, page_at(i)).wait()
+        dma_v(slot, page_at(i)).wait()
         k_tile = k_buf[slot].astype(jnp.float32)  # [Psz, hd]
         v_tile = v_buf[slot].astype(jnp.float32)
 
@@ -231,16 +237,21 @@ def _ragged_kernel(
             q, k_tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [S*G, Psz]
         s = s * scale
-        pos = i * page_size + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        s = jnp.where(q_valid & (pos < vis), s, NEG_INF)
+        pos = page_at(i) * page_size + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        seen = q_valid & (pos < vis)
+        if windowed:
+            seen &= pos >= vis - window
+        s = jnp.where(seen, s, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         # Fully-masked rows (pad queries of a live row) keep m_new at
         # NEG_INF, where exp(s - m_new) would be exp(0) = 1 — guard so
         # their weights stay exactly 0 and the l == 0 fallthrough below
-        # emits the reference's zeros (live queries always see page 0's
-        # position 0, so the guard never fires for them).
+        # emits the reference's zeros (with no window live queries always
+        # see page 0's position 0, so the guard never fires for them; under
+        # a window a block's later queries may see nothing of its first
+        # pages, and the guard keeps those tiles at weight 0 for them).
         p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))  # [S*G, Psz]
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + lax.dot_general(
@@ -248,17 +259,17 @@ def _ragged_kernel(
         )
 
         # Refill the slot we just drained with the page n_buf ahead.
-        @pl.when(i + n_buf < n_pages)
+        @pl.when(i + n_buf < n_stream)
         def _():
-            dma_k(slot, i + n_buf).start()
-            dma_v(slot, i + n_buf).start()
+            dma_k(slot, page_at(i + n_buf)).start()
+            dma_v(slot, page_at(i + n_buf)).start()
 
         return m_new, l_new, acc_new
 
     m0 = jnp.full((S * G, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((S * G, 1), jnp.float32)
     acc0 = jnp.zeros((S * G, hd), jnp.float32)
-    m, l, acc = lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
+    m, l, acc = lax.fori_loop(0, n_stream, body, (m0, l0, acc0))
     out = jnp.where(l > 0.0, acc / jnp.maximum(l, 1e-30), 0.0)
     out_ref[0, :, 0] = out.reshape(S, G, hd).astype(out_ref.dtype)
 
@@ -281,6 +292,7 @@ def ragged_paged_attention(
     start_pos: jax.Array,  # [B] — cache position of query 0
     q_lens: jax.Array,  # [B] — live queries per row (0 = idle row)
     layer: jax.Array | int = 0,
+    window: "jax.Array | int | None" = None,
     *,
     interpret: bool = False,
     n_buf: int = 4,
@@ -300,8 +312,15 @@ def ragged_paged_attention(
     the pools: the window's own K/V rows are already in them when it is
     called, written by ``paged_decode._write_kv_window`` as whole pages in
     this same shape, so the pools reach the Mosaic call in the layout they
-    are kept in and XLA copies nothing to reconcile the two."""
+    are kept in and XLA copies nothing to reconcile the two.
+
+    ``window`` (None: no window, and the program this always was) is this
+    CALL's attention window, one more prefetched scalar beside ``layer``,
+    so the layers of one scan may differ in it: a query at position p sees
+    keys in (p - window, p]. The page loop starts at the first page the
+    block's first query can see."""
     B, S, K, G, hd = q.shape
+    windowed = window is not None
     _, _, _, page_size, _ = k_pages.shape
     # A window up to Q_BLOCK is one block of its own width. A wider one runs
     # as Q_BLOCK-query blocks; the engine's prefill buckets past 128 are all
@@ -313,7 +332,7 @@ def ragged_paged_attention(
         q = jnp.pad(q, ((0, 0), (0, s_pad - S), (0, 0), (0, 0), (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 + windowed,
         grid=(B, K, s_pad // sq),
         in_specs=[
             pl.BlockSpec(
@@ -332,7 +351,12 @@ def ragged_paged_attention(
             pltpu.SemaphoreType.DMA((n_buf,)),
         ],
     )
-    kernel = functools.partial(_ragged_kernel, page_size=page_size, n_buf=n_buf)
+    kernel = functools.partial(
+        _ragged_kernel, page_size=page_size, n_buf=n_buf, windowed=windowed
+    )
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1),)
+    if windowed:
+        scalars += (jnp.asarray(window, jnp.int32).reshape(1),)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -342,7 +366,7 @@ def ragged_paged_attention(
         page_table.astype(jnp.int32),
         start_pos.astype(jnp.int32),
         q_lens.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1),
+        *scalars,
         q,
         k_pages,
         v_pages,
@@ -357,6 +381,7 @@ def paged_attention_chunk(
     page_table: jax.Array,  # [B, Pmax]
     start_pos: jax.Array,  # [B] — cache position of query 0
     layer: jax.Array | int = 0,
+    window: "jax.Array | int | None" = None,
     *,
     interpret: bool = False,
     n_buf: int = 4,
@@ -373,6 +398,7 @@ def paged_attention_chunk(
         start_pos,
         jnp.full((B,), S, jnp.int32),
         layer,
+        window,
         interpret=interpret,
         n_buf=n_buf,
     )

@@ -11,7 +11,6 @@ reference (SURVEY.md §4.2) and the paged path owns its layout decisions.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Optional
 
 import jax
@@ -26,7 +25,14 @@ from mcpx.engine.kernels.paged_attention import (
     ragged_paged_attention_reference,
 )
 from mcpx.models.gemma.config import GemmaConfig
-from mcpx.models.gemma.model import apply_rope, rms_norm
+from mcpx.models.gemma.model import (
+    apply_rope,
+    embed_tokens,
+    layer_kinds,
+    output_logits,
+    rms_norm,
+)
+from mcpx.models.gemma.moe import activation, moe_forward, moe_stats_init, split_layers
 from mcpx.parallel.mesh import DATA_AXIS, MODEL_AXIS, _axis
 
 
@@ -39,6 +45,7 @@ def _ragged_kernel_on_mesh(
     positions: jax.Array,  # [B]
     q_lens: jax.Array,  # [B]
     layer: jax.Array,
+    window: "jax.Array | None" = None,
     *,
     interpret: bool,
 ) -> jax.Array:
@@ -57,13 +64,19 @@ def _ragged_kernel_on_mesh(
     groups = None if heads else _axis(mesh, MODEL_AXIS, G)
     q_spec = P(rows, None, heads, groups, None)
     pool_spec = P(heads, None, None, None, None)
+    # A layer's window, where the model has any, is one more replicated
+    # scalar; a model without keeps the call it always made.
+    scalars = (jnp.asarray(layer, jnp.int32),)
+    if window is not None:
+        scalars += (jnp.asarray(window, jnp.int32),)
     return jax.shard_map(
         functools.partial(ragged_paged_attention, interpret=interpret),
         mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, P(rows, None), P(rows), P(rows), P()),
+        in_specs=(q_spec, pool_spec, pool_spec, P(rows, None), P(rows), P(rows))
+        + (P(),) * len(scalars),
         out_specs=q_spec,
         check_vma=False,
-    )(qg, k_all, v_all, page_table, positions, q_lens, jnp.asarray(layer, jnp.int32))
+    )(qg, k_all, v_all, page_table, positions, q_lens, *scalars)
 
 
 def _kv_window(
@@ -146,7 +159,9 @@ def decode_chunk_paged(
     active_cols: "jax.Array | None" = None,  # [C] token ids: compact unembed
     q_lens: "jax.Array | None" = None,  # [B] live window slots (ragged rows)
     mesh: Optional[Mesh] = None,  # engine mesh; required with q_lens + use_pallas
-) -> tuple[jax.Array, dict[str, jax.Array]]:
+    moe_stats: bool = False,  # sparse models: also the forward's expert counters
+    routing: bool = False,  # sparse models: also the experts chosen [L, B, S, k]
+) -> tuple:
     """Multi-token decode step: S new tokens per sequence in ONE forward.
 
     This is the verify/extend pass for grammar fast-forward speculation
@@ -183,74 +198,91 @@ def decode_chunk_paged(
         # its trivial case): a bare Mosaic call cannot lower on >1 chip, and
         # the CPU interpreter would not show that.
         raise ValueError("decode_chunk_paged: the ragged kernel route (q_lens) needs mesh=")
-    from mcpx.models.gemma.quant import dequant_layer, embed_lookup, unembed
+    from mcpx.models.gemma.quant import dequant_layer
 
     # Weight-only int8 serving mode (models/gemma/quant.py): identity
     # plumbing on plain params; the second of the two param choke points.
     # Quantized leaves stay the HBM-resident buffers — embed rows gather
     # as int8 + per-row scales, layers dequantize per layer INSIDE the
     # scan body (see dequant_layer), unembeds scale on the output.
-    x = embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))  # [B, S, D]
-    x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    x = embed_tokens(params, cfg, tokens)  # [B, S, D]
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
-    window = _kv_window(positions, page_table, S, psz, N)
+    kv_window = _kv_window(positions, page_table, S, psz, N)
+    scanned, experts = split_layers(cfg, params["layers"])
+    # A sparse feed-forward routes only the window's live slots: a pad slot
+    # or an idle row chooses no expert, reads none and is counted nowhere.
+    live = None if q_lens is None else jnp.arange(S)[None, :] < q_lens[:, None]
 
-    def attend(q, k_all, v_all, layer):
+    def attend(q, k_all, v_all, layer, window):
         # Both paths stream/gather each sequence's pages ONCE for all S
         # chunk queries (folding the chunk into the batch dim instead would
         # multiply page traffic by S — the dominant decode cost), and the
         # kernel and jnp reference stay in LOCKSTEP on the ragged contract
         # (q_lens) so tier-1's interpret/jnp runs exercise the same
-        # semantics TPUs serve.
+        # semantics TPUs serve. ``window``: this layer's (None: the model
+        # has none).
         qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
         if use_pallas:
             if q_lens is not None:
                 out = _ragged_kernel_on_mesh(
-                    mesh, qg, k_all, v_all, page_table, positions, q_lens, layer,
+                    mesh, qg, k_all, v_all, page_table, positions, q_lens, layer, window,
                     interpret=interpret,
                 )
             else:
                 out = paged_attention_chunk(
-                    qg, k_all, v_all, page_table, positions, layer,
+                    qg, k_all, v_all, page_table, positions, layer, window,
                     interpret=interpret,
                 )
         elif q_lens is not None:
             out = ragged_paged_attention_reference(
-                qg, k_all, v_all, page_table, positions, q_lens, layer
+                qg, k_all, v_all, page_table, positions, q_lens, layer, window
             )
         else:
             out = paged_attention_chunk_reference(
-                qg, k_all, v_all, page_table, positions, layer
+                qg, k_all, v_all, page_table, positions, layer, window
             )
         return out.reshape(B, S, cfg.n_heads * cfg.head_dim)
 
-    def body(carry, lp):
-        x, k_all, v_all, layer = carry  # pools: [K, L, N, Psz, hd]
+    def body(carry, scanned):
+        x, k_all, v_all, layer, stats = carry  # pools: [K, L, N, Psz, hd]
+        lp, kind = scanned
         lp = dequant_layer(lp, jnp.dtype(cfg.dtype))
-        h = rms_norm(x, lp["pre_attn_norm"], cfg.norm_eps)
+        h = rms_norm(x, lp["pre_attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
         q = jnp.einsum("bsd,dkh->bskh", h, lp["wq"])  # [B, S, H, hd]
         k = jnp.einsum("bsd,dkh->bskh", h, lp["wk"])  # [B, S, K, hd]
         v = jnp.einsum("bsd,dkh->bskh", h, lp["wv"])
-        q = apply_rope(q, pos_mat, cfg.rope_theta)
-        k = apply_rope(k, pos_mat, cfg.rope_theta)
-        k_all = _write_kv_window(k_all, layer, k, window)
-        v_all = _write_kv_window(v_all, layer, v, window)
-        attn = attend(q, k_all, v_all, layer)
+        q = apply_rope(q, pos_mat, cfg.rope_theta, kind)
+        k = apply_rope(k, pos_mat, cfg.rope_theta, kind)
+        k_all = _write_kv_window(k_all, layer, k, kv_window)
+        v_all = _write_kv_window(v_all, layer, v, kv_window)
+        attn = attend(q, k_all, v_all, layer, kind.get("window"))
         wo = lp["wo"].reshape(cfg.n_heads * cfg.head_dim, cfg.d_model)
         x = x + jnp.einsum("bsf,fd->bsd", attn, wo)
-        h = rms_norm(x, lp["pre_mlp_norm"], cfg.norm_eps)
-        ff = jax.nn.gelu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]), approximate=True)
-        ff = ff * jnp.einsum("bsd,df->bsf", h, lp["w_up"])
-        x = x + jnp.einsum("bsf,fd->bsd", ff, lp["w_down"])
-        return (x, k_all, v_all, layer + 1), None
+        h = rms_norm(x, lp["pre_mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
+        chosen = None
+        if cfg.n_experts:
+            ff, layer_stats, chosen = moe_forward(h, lp["router"], experts, layer, cfg, live)
+            x, stats = x + ff, stats + layer_stats
+        else:
+            ff = activation(cfg, jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
+            ff = ff * jnp.einsum("bsd,df->bsf", h, lp["w_up"])
+            x = x + jnp.einsum("bsf,fd->bsd", ff, lp["w_down"])
+        return (x, k_all, v_all, layer + 1, stats), chosen
 
-    (x, k_new, v_new, _), _ = lax.scan(
+    (x, k_new, v_new, _, stats), chosen = lax.scan(
         body,
-        (x, paged_kv["k"], paged_kv["v"], jnp.asarray(0, jnp.int32)),
-        params["layers"],
+        (
+            x, paged_kv["k"], paged_kv["v"], jnp.asarray(0, jnp.int32),
+            moe_stats_init(cfg) if cfg.n_experts else None,
+        ),
+        (scanned, layer_kinds(cfg)),
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
+    pools = {"k": k_new, "v": v_new}
+    # What a sparse model's callers may ask for beside the logits: the
+    # forward's expert counters (``moe_stats_init``) and the experts chosen.
+    extra = ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
     if active_cols is not None:
         # Draft verification needs logits at EVERY chunk position, but only
         # over the grammar's C active columns: gather those unembed rows
@@ -260,19 +292,15 @@ def decode_chunk_paged(
         # logits, which is what makes per-position verification affordable
         # at all (the "last-only unembed" optimisation stays intact for the
         # non-draft path below).
-        return unembed(x, params["embed"], subset=active_cols), {
-            "k": k_new,
-            "v": v_new,
-        }
+        return (output_logits(params, cfg, x, subset=active_cols), pools) + extra
     if logits_at is not None:
         # Serving only reads ONE position's logits per row (the last valid
         # chunk slot): gather the hidden state BEFORE the unembed so the
         # [B, S, V] logits buffer never exists and the unembed matmul costs
         # 1/S of the all-positions version — at subword vocab sizes that
         # buffer and those FLOPs rival a whole transformer layer.
-        x1 = x[jnp.arange(B), logits_at]  # [B, D]
-        return unembed(x1, params["embed"]), {"k": k_new, "v": v_new}
-    return unembed(x, params["embed"]), {"k": k_new, "v": v_new}
+        x = x[jnp.arange(B), logits_at]  # [B, D]
+    return (output_logits(params, cfg, x), pools) + extra
 
 
 def decode_step_paged(

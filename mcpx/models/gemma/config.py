@@ -13,13 +13,29 @@ checkpoints (256128) both fit the same code path.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
+import numpy as np
+
 from mcpx.core.errors import ConfigError
+
+# A full layer's window in the layer scan's data: past any context.
+NO_WINDOW = 2**30
+
+
+FULL, SLIDING = "full_attention", "sliding_attention"
 
 
 @dataclass(frozen=True)
 class GemmaConfig:
+    """What a decoder layer IS. The defaults are the Gemma block (tanh-GELU
+    gate, tied embeddings scaled by sqrt(d_model), a (1 + scale) norm gain,
+    full causal attention in every layer, one rope, a dense feed-forward);
+    the further fields say where another model's block departs from it, so
+    one forward serves both and a configuration at the defaults traces to
+    the program it always did."""
+
     vocab_size: int = 384
     d_model: int = 128
     n_layers: int = 2
@@ -31,22 +47,150 @@ class GemmaConfig:
     norm_eps: float = 1e-6
     max_seq_len: int = 2048
     dtype: str = "bfloat16"
+    # --- the block's pointwise choices
+    activation: str = "gelu_tanh"  # or "silu" (SwiGLU)
+    tie_embeddings: bool = True  # False: the output head is its own [D, V] leaf
+    scale_embeddings: bool = True  # embeddings times sqrt(d_model)
+    norm_plus_one: bool = True  # gain (1 + scale), scale drawn 0; False: gain g, drawn 1
+    # --- attention kinds: one entry per layer, () = every layer full.
+    # A sliding layer's query sees itself and the ``sliding_window - 1``
+    # keys before it, and rotates with ``rope_theta`` as it stands; a full
+    # layer's rope is YaRN-stretched where ``yarn_factor`` > 0.
+    layer_types: tuple[str, ...] = ()
+    sliding_window: int = 0
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
+    # --- sparse feed-forward: ``n_experts`` > 0 replaces the dense MLP in
+    # every layer by a router ``n_experts`` wide choosing
+    # ``n_experts_per_tok`` experts of width ``d_expert``. This device holds
+    # ``experts_held`` of them from ``expert_first`` on (0 = all): it routes
+    # over all and computes its own experts' part of the result.
+    n_experts: int = 0
+    n_experts_per_tok: int = 0
+    d_expert: int = 0
+    expert_first: int = 0
+    experts_held: int = 0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
             raise ConfigError("n_heads must be divisible by n_kv_heads")
+        if self.activation not in ("gelu_tanh", "silu"):
+            raise ConfigError(f"activation {self.activation!r}: gelu_tanh or silu")
+        # A JSON round trip (dataclasses.asdict -> GemmaConfig(**d)) hands a list.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers:
+                raise ConfigError(
+                    f"layer_types has {len(self.layer_types)} entries for {self.n_layers} layers"
+                )
+            unknown = set(self.layer_types) - {FULL, SLIDING}
+            if unknown:
+                raise ConfigError(f"layer_types: unknown kind(s) {sorted(unknown)}")
+            if SLIDING in self.layer_types and self.sliding_window < 1:
+                raise ConfigError("sliding_attention layers need sliding_window >= 1")
+        if self.yarn_factor and self.yarn_original_max_pos < 1:
+            raise ConfigError("yarn_factor needs yarn_original_max_pos")
+        if self.n_experts:
+            if not 1 <= self.n_experts_per_tok <= self.n_experts or self.d_expert < 1:
+                raise ConfigError(
+                    "n_experts needs 1 <= n_experts_per_tok <= n_experts and d_expert >= 1"
+                )
+            if self.expert_first < 0 or self.expert_first + self.n_experts_held > self.n_experts:
+                raise ConfigError(
+                    f"experts {self.expert_first}..{self.expert_first + self.n_experts_held} "
+                    f"are not among the router's {self.n_experts}"
+                )
 
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
     @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def is_default_block(self) -> bool:
+        """True for the block every default describes: what the int8 path,
+        the drafter, ring prefill and the trainer were written against."""
+        d = GemmaConfig()
+        return all(
+            getattr(self, f) == getattr(d, f)
+            for f in (
+                "activation", "tie_embeddings", "scale_embeddings", "norm_plus_one",
+                "layer_types", "sliding_window", "yarn_factor", "n_experts",
+            )
+        )
+
+    def rope_tables(self) -> "tuple[np.ndarray, np.ndarray] | None":
+        """Per-layer rope as the layer scan's data: inverse frequencies
+        ``[L, head_dim / 2]`` and the factor on cos and sin ``[L]``. None
+        where every layer rotates alike with no stretch: the scan then
+        carries nothing and the rope is the constant it always was.
+
+        Sliding layers: ``theta^(-2k/dim)``, factor 1. Full layers under
+        YaRN: with ``corr(r) = dim * ln(orig / (2 pi r)) / (2 ln theta)``,
+        ``low = floor(corr(beta_fast))``, ``high = ceil(corr(beta_slow))``
+        (clipped to [0, dim - 1]) and ``ramp_k = clip((k - low) / (high -
+        low), 0, 1)``, the frequency is ``(1 - ramp_k)`` of the plain one
+        plus ``ramp_k`` of the plain one over ``yarn_factor``; factor
+        ``yarn_attention_factor``."""
+        if not self.yarn_factor:
+            return None
+        dim = self.head_dim
+        k = np.arange(dim // 2, dtype=np.float64)
+        plain = self.rope_theta ** (-2.0 * k / dim)
+
+        def corr(r: float) -> float:
+            return dim * math.log(self.yarn_original_max_pos / (2 * math.pi * r)) / (
+                2 * math.log(self.rope_theta)
+            )
+
+        low = max(math.floor(corr(self.yarn_beta_fast)), 0)
+        high = min(math.ceil(corr(self.yarn_beta_slow)), dim - 1)
+        ramp = np.clip((k - low) / max(high - low, 1e-3), 0.0, 1.0)
+        stretched = (1.0 - ramp) * plain + ramp * plain / self.yarn_factor
+        full = np.asarray([t != SLIDING for t in self.layer_types or (FULL,) * self.n_layers])
+        inv_freq = np.where(full[:, None], stretched[None, :], plain[None, :])
+        factor = np.where(full, self.yarn_attention_factor, 1.0)
+        return inv_freq.astype(np.float32), factor.astype(np.float32)
+
+    def layer_windows(self) -> "np.ndarray | None":
+        """Per-layer attention window ``[L]`` as the layer scan's data (a
+        full layer's is past any context), or None where no layer has one."""
+        if SLIDING not in self.layer_types:
+            return None
+        return np.asarray(
+            [self.sliding_window if t == SLIDING else NO_WINDOW for t in self.layer_types],
+            np.int32,
+        )
+
+    @property
     def n_params(self) -> int:
-        """Parameter count (tied embeddings counted once) — the basis for
-        model-FLOPs/token ≈ 2*n_params in MFU accounting."""
+        """Parameters HELD (tied embeddings counted once; the experts this
+        device holds): what the weights' bytes follow."""
+        return self._count(self.n_experts_held)
+
+    @property
+    def n_active_params(self) -> int:
+        """Parameters a token READS: with a sparse feed-forward only its
+        ``n_experts_per_tok`` experts — the basis for model-FLOPs/token ≈
+        2 * n_active_params in MFU accounting. Equals ``n_params`` for a
+        dense block."""
+        return self._count(self.n_experts_per_tok)
+
+    def _count(self, experts: int) -> int:
         D, H, K, hd, F = self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.d_ff
-        per_layer = D * H * hd + 2 * D * K * hd + H * hd * D + 3 * D * F + 2 * D
-        return self.vocab_size * D + self.n_layers * per_layer + D
+        attn = D * H * hd + 2 * D * K * hd + H * hd * D + 2 * D
+        if self.n_experts:
+            ff = D * self.n_experts + experts * 3 * D * self.d_expert
+        else:
+            ff = 3 * D * F
+        head = 0 if self.tie_embeddings else D * self.vocab_size
+        return self.vocab_size * D + self.n_layers * (attn + ff) + D + head
 
     @classmethod
     def named(cls, name: str, *, vocab_size: int = 384, max_seq_len: int = 2048) -> "GemmaConfig":

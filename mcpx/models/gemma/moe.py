@@ -1,0 +1,106 @@
+"""Sparse feed-forward: a router over ``n_experts`` choosing
+``n_experts_per_tok`` experts a token, computed for the experts this device
+holds (``GemmaConfig.expert_first`` / ``experts_held``; all of them on one
+chip that holds the layer, a share under expert parallelism).
+
+``p = softmax(h @ router)`` over ALL experts in float32; the k largest with
+their indices; ``w = p_top / sum(p_top)``; the layer's output is ``sum_k w_k
+* down_e(act(gate_e h) * (up_e h))`` over the chosen experts held here. What
+the absent experts would have added is left out: under expert parallelism
+the exchange adds the shares, and on one chip that holds every expert there
+is nothing to add.
+
+The expert matmuls run as a loop over the experts that at least one LIVE
+token chose, each step slicing that expert's three matrices out of the
+all-layers stacks ``[L, E, D, F]`` / ``[L, E, F, D]`` (which therefore stay
+OUT of the layer scan's ``xs``: scanned, every layer's whole expert stack
+would be copied as the inner loop's operand). An expert no live token chose
+is never read; a pad slot or an idle row is routed nowhere, reads nothing
+and counts in no counter. Each step multiplies every slot of the window by
+its expert (a slot that did not choose it has weight 0): at decode widths
+the step is bound by the expert's 12 MB of weights, not by the slots.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mcpx.models.gemma.config import GemmaConfig
+
+# Leaves of params["layers"] that hold the experts: closed over by the layer
+# scan and sliced by (layer, expert), never scanned.
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def activation(cfg: GemmaConfig, x: jax.Array) -> jax.Array:
+    if cfg.activation == "silu":
+        return jax.nn.silu(x)
+    return jax.nn.gelu(x, approximate=True)
+
+
+def split_layers(cfg: GemmaConfig, layers: dict) -> tuple[dict, dict]:
+    """(what the layer scan scans, what it closes over)."""
+    if not cfg.n_experts:
+        return layers, {}
+    return (
+        {k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
+        {k: layers[k] for k in EXPERT_LEAVES},
+    )
+
+
+def route(x: jax.Array, router: jax.Array, cfg: GemmaConfig) -> tuple[jax.Array, jax.Array]:
+    """x [T, D], router [D, E] -> (chosen [T, k] int32, weights [T, k]
+    float32, summing to 1 a token): the softmax over every expert, in
+    float32, comes BEFORE the choice."""
+    logits = jnp.einsum("td,de->te", x, router, preferred_element_type=jnp.float32)
+    p_top, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
+    return chosen.astype(jnp.int32), p_top / jnp.sum(p_top, axis=-1, keepdims=True)
+
+
+def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
+    """The counters a forward adds to: tokens per held expert ``[E_held]``
+    (summed over layers), then the number of (layer, expert) pairs with at
+    least one live token."""
+    return jnp.zeros((cfg.n_experts_held + 1,), jnp.int32)
+
+
+def moe_forward(
+    h: jax.Array,  # [B, S, D]
+    router: jax.Array,  # [D, E] this layer's
+    experts: dict,  # w_gate / w_up [L, E_held, D, F], w_down [L, E_held, F, D]
+    layer: jax.Array,
+    cfg: GemmaConfig,
+    live: "jax.Array | None" = None,  # [B, S] bool; None = every slot
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """-> (this device's part of the layer's output [B, S, D], the counters
+    of ``moe_stats_init`` for this layer, the experts chosen [B, S, k])."""
+    B, S, D = h.shape
+    T, E, k = B * S, cfg.n_experts_held, cfg.n_experts_per_tok
+    x = h.reshape(T, D)
+    chosen, w = route(x, router, cfg)
+    local = chosen - cfg.expert_first
+    here = (local >= 0) & (local < E)
+    if live is not None:
+        here &= live.reshape(T, 1)
+    onehot = here[:, :, None] & (local[:, :, None] == jnp.arange(E, dtype=jnp.int32))
+    combine = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1).T  # [E, T]
+    counts = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [E]
+    touched = counts > 0
+    n_touched = jnp.sum(touched, dtype=jnp.int32)
+    order = jnp.argsort(~touched, stable=True).astype(jnp.int32)  # touched first
+    F = cfg.d_expert
+
+    def one_expert(i, acc):
+        e = order[i]
+        w_gate = lax.dynamic_slice(experts["w_gate"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
+        w_up = lax.dynamic_slice(experts["w_up"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
+        w_down = lax.dynamic_slice(experts["w_down"], (layer, e, 0, 0), (1, 1, F, D))[0, 0]
+        a = activation(cfg, jnp.einsum("td,df->tf", x, w_gate)) * jnp.einsum("td,df->tf", x, w_up)
+        y = jnp.einsum("tf,fd->td", a, w_down, preferred_element_type=jnp.float32)
+        return acc + y * lax.dynamic_index_in_dim(combine, e, 0, keepdims=False)[:, None]
+
+    out = lax.fori_loop(0, n_touched, one_expert, jnp.zeros((T, D), jnp.float32))
+    stats = jnp.concatenate([counts, n_touched[None]])
+    return out.astype(h.dtype).reshape(B, S, D), stats, chosen.reshape(B, S, k)

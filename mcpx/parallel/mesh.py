@@ -105,7 +105,7 @@ def _axis(mesh: Mesh, axis: str, dim: int) -> Optional[str]:
 def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
     """PartitionSpec pytree matching ``init_params`` output."""
     m = lambda dim: _axis(mesh, MODEL_AXIS, dim)
-    return {
+    specs = {
         "embed": P(m(cfg.vocab_size), None),
         "layers": {
             "pre_attn_norm": P(None, None),
@@ -120,6 +120,20 @@ def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
         },
         "final_norm": P(None),
     }
+    if cfg.n_experts:
+        # The experts this device holds stay whole on every device of the
+        # mesh: which experts a device holds is the configuration's
+        # (expert_first / experts_held), not yet an axis of the mesh
+        # (ROADMAP M4 keeps the expert axis and its exchange).
+        specs["layers"].update(
+            router=P(None, None, None),
+            w_gate=P(None, None, None, None),
+            w_up=P(None, None, None, None),
+            w_down=P(None, None, None, None),
+        )
+    if not cfg.tie_embeddings:
+        specs["head"] = P(None, m(cfg.vocab_size))
+    return specs
 
 
 def kv_cache_pspecs(cfg: GemmaConfig, mesh: Mesh, batch: int) -> dict[str, Any]:

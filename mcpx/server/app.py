@@ -565,7 +565,7 @@ def build_app(cp: ControlPlane) -> web.Application:
                     "reason": "engine not ready; device stats deferred",
                 }
             )
-        from mcpx.telemetry.costs import device_peaks, hbm_stats, update_hbm_gauges
+        from mcpx.telemetry.costs import device_peaks, hbm_stats, model_cost, update_hbm_gauges
 
         # Off the event loop: materialising pending cost entries lazily
         # AOT-compiles (seconds per signature, first scrape only), and the
@@ -596,6 +596,9 @@ def build_app(cp: ControlPlane) -> web.Application:
                 # twin of /healthz's engine_queue.pallas block.
                 "pallas": engine.pallas_paths(),
                 "device": device,
+                # Parameters held against parameters a token reads (an
+                # engine pool's replicas each answer for their own).
+                "model": model_cost(engine.model_cfg) if hasattr(engine, "model_cfg") else None,
             }
         )
 

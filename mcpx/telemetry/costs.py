@@ -128,6 +128,19 @@ def device_peaks() -> dict:
     )
 
 
+def model_cost(model_cfg) -> dict:
+    """What a token costs by the model's shape, for ``/costs``: parameters
+    HELD by this engine against parameters a token READS (a sparse
+    feed-forward reads ``n_experts_per_tok`` of its experts a layer, not all
+    it holds), and model FLOPs a token ~ 2 x the latter: the basis an MFU is
+    to be taken on, never 2 x the parameters held."""
+    return {
+        "params_held": model_cfg.n_params,
+        "params_active_per_token": model_cfg.n_active_params,
+        "flops_per_token": 2 * model_cfg.n_active_params,
+    }
+
+
 def hbm_stats() -> list[dict]:
     """Per-device ``memory_stats()`` snapshot (bytes in use / limit / peak).
     Backends without allocator stats (XLA:CPU) report ``available: false``

@@ -349,6 +349,14 @@ class Metrics:
             ["device"],
             registry=self.registry,
         )
+        self.moe_expert_tokens = Counter(
+            "mcpx_engine_moe_expert_tokens_total",
+            "Live tokens routed to each expert this engine holds, summed over "
+            "the sparse layers of every decode forward (pad slots and idle "
+            "rows are routed nowhere); a dense model writes no sample",
+            ["expert"],
+            registry=self.registry,
+        )
         self.weights_init_seconds = Gauge(
             "mcpx_engine_weights_init_seconds",
             "Wall seconds of the weights' random draw or checkpoint restore "

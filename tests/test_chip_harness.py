@@ -89,23 +89,49 @@ NOT_FED_HERE = {"device_op_share", "device_idle_share", "memory_in_use", "endpoi
 LABELLED_SAMPLE = 'mcpx_engine_compiles_total{executable="admit"}'
 
 
+# The cell whose block has sparse experts and windowed layers: the
+# engine.segment attributes that only such a block writes (PR 33).
+SPARSE_CELL = "mellum2-12b-a2.5b.distinct-closed"
 METRICS = [json.load(open(f)) for f in sorted(glob.glob(os.path.join(CHIP_DIR, "metrics", "*.json")))]
-FED = [m for m in METRICS if m["reader"] not in NOT_FED_HERE]
+_CELLS_OF = {m["name"]: m.get("workloads")
+             for m in json.load(open(os.path.join(REPO, "BENCHMARK.json")))["per_layer"]}
+
+
+def _fed_in(cell):
+    """The metrics of ``cell`` that a served program on the CPU can feed."""
+    return [m for m in METRICS if m["reader"] not in NOT_FED_HERE
+            and (_CELLS_OF[m["name"]] is None or cell in _CELLS_OF[m["name"]])]
+
+
+FED = _fed_in(CELL)
+FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
 
 
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
+    return _serve(CELL, tmp_path_factory)
+
+
+@pytest.fixture(scope="module")
+def served_sparse(tmp_path_factory):
+    return _serve(SPARSE_CELL, tmp_path_factory)
+
+
+def _serve(cell_name, tmp_path_factory):
     """One rehearsal child of the harness (``child.py --rehearse-cpu``: the
-    served app at ``model=test``, LLM planner, interpreted kernel, tracing at
-    rate 1), five fresh ``/plan`` requests and one re-send, and around them
-    everything ``run.py`` fetches, through ``run.py``'s own functions."""
-    run = _by_path("run")  # imports its siblings by bare name, from CHIP_DIR
-    sys.path.remove(CHIP_DIR)
+    served app at the cell's block's rehearsal size, LLM planner, interpreted
+    kernel, tracing at rate 1), five fresh ``/plan`` requests and one re-send,
+    and around them everything ``run.py`` fetches, through ``run.py``'s own
+    functions."""
+    fed = _fed_in(cell_name)
+    run = sys.modules.get("chip_harness_run") or _by_path("run")  # imports its siblings by bare name
+    if CHIP_DIR in sys.path:
+        sys.path.remove(CHIP_DIR)
     readers, spec, loadgen = (sys.modules[n] for n in ("readers", "spec", "loadgen"))
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(cell_name)
     gen = loadgen.Generator({**cell.traffic, "registry_services": 120}, seed=30)
     names = {r["name"] for r in gen.registry}
-    endpoints = sorted({m["args"]["endpoint"] for m in FED if "endpoint" in m["args"]})
+    endpoints = sorted({m["args"]["endpoint"] for m in fed if "endpoint" in m["args"]} | {"/metrics"})
 
     run_dir = str(tmp_path_factory.mktemp("served"))
     with open(os.path.join(run_dir, "registry.json"), "w") as f:
@@ -205,6 +231,71 @@ def test_the_program_feeds_the_metric(served, metric):
 def test_every_metric_is_fed_here_or_left_out_by_its_readers_name(served):
     assert all(m["reader"] in served["found"] for m in METRICS)
     assert len(FED) >= 17 and NOT_FED_HERE <= set(served["found"])
+    assert {m["name"] for m in FED_SPARSE} == {
+        "moe.experts_touched_share", "moe.tok_per_touched_expert", "attn.rows_past_window_share"}
+
+
+# The engine.segment attributes that only a block with sparse experts or
+# windowed layers writes; a dense block writes none of them.
+LAYER_KIND_ATTRS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
+                    "rows_past_window", "rows_live")
+
+
+def _segments(served):
+    return [sp for tr in served["ev"].traces for sp in tr["tree"] if sp["name"] == "engine.segment"]
+
+
+def test_costs_counts_the_params_a_token_reads(served, served_sparse):
+    dense, sparse = served["costs"]["model"], served_sparse["costs"]["model"]
+    assert dense["params_active_per_token"] == dense["params_held"] > 0
+    # rehearsal size: 2 of 8 experts a layer of 3 x 128 x 64 parameters, 4 layers
+    assert sparse["params_held"] - sparse["params_active_per_token"] == 4 * 6 * 3 * 128 * 64
+    assert sparse["flops_per_token"] == 2 * sparse["params_active_per_token"]
+
+
+def test_a_dense_block_writes_no_layer_kind_attribute(served):
+    assert _segments(served)
+    assert not any(a in sp["attrs"] for sp in _segments(served) for a in LAYER_KIND_ATTRS)
+    assert "mcpx_engine_moe_expert_tokens_total{" not in served["engine_metrics"][1]
+    profile = served["health"]["engine_queue"]["worker_profile"]
+    assert not any(a in profile for a in LAYER_KIND_ATTRS)
+
+
+@pytest.mark.parametrize("metric", FED_SPARSE, ids=[m["name"] for m in FED_SPARSE])
+def test_the_sparse_block_feeds_its_metrics(served_sparse, metric):
+    v = served_sparse["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v) and 0 <= v
+    if metric["name"] == "moe.experts_touched_share":
+        assert 0 < v <= 1
+    if metric["name"] == "moe.tok_per_touched_expert":
+        assert v >= 1  # a touched expert has at least one token
+
+
+@pytest.mark.parametrize("attr", LAYER_KIND_ATTRS)
+def test_the_sparse_blocks_segments_carry_the_attribute(served_sparse, attr):
+    segments = _segments(served_sparse)
+    assert segments and all(isinstance(sp["attrs"].get(attr), int) for sp in segments)
+
+
+def test_the_layer_kind_attributes_add_up(served_sparse):
+    """At the rehearsal size: 4 sparse layers, 8 experts held, 2 a token, a
+    window of 8 that every prompt has passed."""
+    layers, experts, k = 4, 8, 2
+    for sp in _segments(served_sparse):
+        a = sp["attrs"]
+        assert a["moe_expert_slots"] == a["forwards"] * layers * experts
+        assert 0 < a["moe_experts_touched"] <= min(a["moe_expert_slots"], a["moe_assignments"])
+        # every live token of every forward chose k experts in each layer, all held here
+        assert a["moe_assignments"] % (k * layers) == 0
+        assert a["moe_assignments"] // (k * layers) >= a["tokens"]
+        assert 0 < a["rows_live"] <= 8 and a["rows_past_window"] == a["rows_live"]
+    # lifetime sums, and the per-expert counter beside them
+    profile = served_sparse["health"]["engine_queue"]["worker_profile"]
+    per_expert = {key: v for key, v in served_sparse["ev"].counters_after["/metrics"].items()
+                  if key.startswith("mcpx_engine_moe_expert_tokens_total{")}
+    assert len(per_expert) == experts
+    assert sum(per_expert.values()) <= profile["moe_assignments"]  # the scrape came first
+    assert profile["moe_expert_slots"] >= profile["moe_experts_touched"] > 0
 
 
 def _compiles(served):

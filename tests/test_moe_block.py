@@ -1,0 +1,308 @@
+"""The block beyond the default one: sparse feed-forward (router + experts
+held), per-layer attention kinds (window, YaRN rope), untied head, plain
+gain. CPU, small sizes; the plain reference is the benchmark's block module
+(``benchmarks/chip/models/mellum.py``), imported by path."""
+
+import dataclasses
+import importlib.util
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mcpx.core.config import MCPXConfig
+from mcpx.core.errors import ConfigError
+from mcpx.engine.kv_cache import commit_prefill_to_pages, init_paged_kv
+from mcpx.engine.paged_decode import decode_chunk_paged
+from mcpx.models.gemma.config import GemmaConfig
+from mcpx.models.gemma.model import init_kv_cache, init_params, prefill
+from mcpx.models.gemma.moe import moe_forward, route
+from mcpx.parallel.mesh import make_mesh, param_pspecs
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
+PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+def _by_path(name, path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def block():
+    return _by_path("chip_block_mellum_t", os.path.join(CHIP_DIR, "models", "mellum.py"))
+
+
+def small(**kw):
+    base = dict(
+        vocab_size=384, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=32, d_ff=128,
+        rope_theta=500000.0, layer_types=PERIOD, sliding_window=8, yarn_factor=16.0,
+        yarn_original_max_pos=64, yarn_attention_factor=1.2772588722239782,
+        n_experts=8, n_experts_per_tok=2, d_expert=32, activation="silu",
+        tie_embeddings=False, scale_embeddings=False, norm_plus_one=False, dtype="float32",
+    )
+    return GemmaConfig(**{**base, **kw})
+
+
+# ------------------------------------------------------------ configuration
+def test_defaults_are_todays_block_and_carry_no_layer_data():
+    cfg = GemmaConfig()
+    assert cfg.is_default_block and cfg.rope_tables() is None and cfg.layer_windows() is None
+    assert cfg.n_active_params == cfg.n_params
+    assert set(init_params(cfg, jax.random.PRNGKey(0))) == {"embed", "layers", "final_norm"}
+    assert not small().is_default_block
+
+
+def test_published_yarn_frequencies_and_factor():
+    """Mellum 2's numbers: dim 128, base 500000, factor 16, original 8192,
+    beta_fast 32, beta_slow 1: corr(32) = 18.08, corr(1) = 34.98, so the ramp
+    runs over k = 18..35."""
+    cfg = small(head_dim=128, n_heads=2, n_kv_heads=1, yarn_original_max_pos=8192)
+    inv_freq, factor = cfg.rope_tables()
+    plain = np.asarray([500000.0 ** (-2 * k / 128) for k in range(64)])
+    assert inv_freq.shape == (4, 64) and factor.tolist() == pytest.approx([1, 1, 1, 1.2772588722239782])
+    assert 1.2772588722239782 == pytest.approx(0.1 * math.log(16) + 1)
+    for layer in range(3):  # sliding layers: the plain rope
+        np.testing.assert_allclose(inv_freq[layer], plain, rtol=1e-6)
+    ratio = inv_freq[3] / plain
+    np.testing.assert_allclose(ratio[:19], 1.0, rtol=1e-6)  # k <= low = 18: untouched
+    np.testing.assert_allclose(ratio[35:], 1 / 16, rtol=1e-6)  # k >= high = 35: stretched
+    np.testing.assert_allclose(ratio[18:36], 1 - (np.arange(18, 36) - 18) / 17 * (15 / 16), rtol=1e-5)
+    assert cfg.layer_windows().tolist() == [8, 8, 8, 2**30]
+
+
+def test_the_benchmarks_reference_derives_the_same_rope(block):
+    cfg = small(head_dim=128, n_heads=2, n_kv_heads=1, yarn_original_max_pos=8192)
+    ours, theirs = cfg.rope_tables(), block._rope_tables(dataclasses.asdict(cfg))
+    np.testing.assert_allclose(ours[0], theirs[0], rtol=1e-6)
+    np.testing.assert_allclose(ours[1], theirs[1], rtol=1e-6)
+
+
+@pytest.mark.parametrize("bad", [
+    dict(layer_types=PERIOD[:3]),
+    dict(layer_types=("windowed",) * 4),
+    dict(sliding_window=0),
+    dict(n_experts_per_tok=9),
+    dict(expert_first=6, experts_held=4),
+    dict(activation="relu"),
+], ids=lambda d: next(iter(d)))
+def test_a_configuration_that_cannot_be_is_refused(bad):
+    with pytest.raises(ConfigError):
+        small(**bad)
+
+
+def test_params_read_a_token_are_not_all_params_held():
+    cfg = small()
+    D, F = cfg.d_model, cfg.d_expert
+    assert cfg.n_params - cfg.n_active_params == cfg.n_layers * (8 - 2) * 3 * D * F
+    held = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(init_params(cfg, jax.random.PRNGKey(0))))
+    assert held == cfg.n_params
+    share = dataclasses.replace(cfg, expert_first=2, experts_held=2)
+    assert cfg.n_params - share.n_params == cfg.n_layers * 6 * 3 * D * F
+
+
+@pytest.mark.parametrize("feature, cfg_json", [
+    ("quantize", {"model": {"quantize": "int8"}}),
+    ("speculative", {"engine": {"speculative": {"enabled": True}, "hetero_batch": True}}),
+    ("ring_prefill", {"engine": {"ring_prefill_min_tokens": 512}}),
+])
+def test_what_the_block_does_not_do_yet_is_an_error_at_construction(feature, cfg_json):
+    from mcpx.engine.engine import InferenceEngine
+
+    cfg = MCPXConfig.from_dict(cfg_json)
+    with pytest.raises(ConfigError, match="departs from the default"):
+        InferenceEngine(cfg, model_cfg=small(vocab_size=384))
+    InferenceEngine(MCPXConfig(), model_cfg=small(vocab_size=384))  # without it: built
+
+
+# ------------------------------------------------------------ expert layer
+def _layer_inputs(cfg, seed=0, B=3, S=5):
+    params = init_params(cfg, jax.random.PRNGKey(seed))["layers"]
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, S, cfg.d_model), jnp.float32)
+    experts = {k: params[k] for k in ("w_gate", "w_up", "w_down")}
+    return h, params["router"], experts
+
+
+def _plain_moe(h, router, experts, layer, chosen, w, cfg):
+    """Every expert densely, weighted by the given routing."""
+    x = np.asarray(h, np.float64).reshape(-1, cfg.d_model)
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for e, wt in zip(np.asarray(chosen)[t], np.asarray(w, np.float64)[t]):
+            loc = e - cfg.expert_first
+            if not 0 <= loc < cfg.n_experts_held:
+                continue
+            g = x[t] @ np.asarray(experts["w_gate"][layer, loc], np.float64)
+            u = x[t] @ np.asarray(experts["w_up"][layer, loc], np.float64)
+            out[t] += wt * ((g / (1 + np.exp(-g)) * u) @ np.asarray(experts["w_down"][layer, loc], np.float64))
+    return out.reshape(h.shape)
+
+
+@pytest.mark.parametrize("layer", [0, 3])
+def test_expert_layer_matches_the_plain_one_under_the_same_routing(layer):
+    cfg = small()
+    h, router, experts = _layer_inputs(cfg)
+    out, stats, chosen = moe_forward(h, router[layer], experts, jnp.int32(layer), cfg)
+    _, w = route(h.reshape(-1, cfg.d_model), router[layer], cfg)
+    want = _plain_moe(h, router, experts, layer, chosen.reshape(-1, 2), w, cfg)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
+    assert int(stats[:8].sum()) == 3 * 5 * 2 and int(stats[8]) == int((stats[:8] > 0).sum())
+
+
+def test_routing_is_the_softmaxs_top_k_outside_a_margin():
+    """The chosen set is the float64 softmax's top-k wherever the k-th and
+    (k+1)-th probabilities lie more than 1e-5 of the k-th apart, and the
+    weights are the chosen probabilities renormalised."""
+    cfg = small()
+    h, router, _ = _layer_inputs(cfg, seed=3, B=8, S=16)
+    x = h.reshape(-1, cfg.d_model)
+    chosen, w = route(x, router[1], cfg)
+    logits = np.asarray(x, np.float64) @ np.asarray(router[1], np.float64)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    order = np.argsort(-p, axis=-1)
+    clear = (np.take_along_axis(p, order[:, 1:2], 1) - np.take_along_axis(p, order[:, 2:3], 1))[:, 0] > 1e-5 * p.max(-1)
+    assert clear.mean() > 0.9
+    assert (np.sort(np.asarray(chosen), -1) == np.sort(order[:, :2], -1))[clear].all()
+    top = np.take_along_axis(p, np.asarray(chosen), 1)
+    np.testing.assert_allclose(np.asarray(w), top / top.sum(-1, keepdims=True), rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, rtol=1e-5)
+
+
+def test_the_shares_add_up():
+    """model-configs section 4: the 8 experts held 2 + 2 + 4 by three shares,
+    each routing over all 8: the three partial results sum to the uncut
+    layer's, and so do their counters."""
+    cfg = small()
+    h, router, experts = _layer_inputs(cfg, seed=5)
+    whole, stats, chosen = moe_forward(h, router[2], experts, jnp.int32(2), cfg)
+    parts, counts = [], []
+    for first, held in ((0, 2), (2, 2), (4, 4)):
+        share = dataclasses.replace(cfg, expert_first=first, experts_held=held)
+        mine = {k: v[:, first : first + held] for k, v in experts.items()}
+        out, st, ch = moe_forward(h, router[2], mine, jnp.int32(2), share)
+        assert (np.asarray(ch) == np.asarray(chosen)).all()  # every share routes over all 8
+        parts.append(np.asarray(out))
+        counts.append(np.asarray(st[:held]))
+    np.testing.assert_allclose(sum(parts), np.asarray(whole), rtol=1e-5, atol=1e-6)
+    assert np.concatenate(counts).tolist() == np.asarray(stats[:8]).tolist()
+    assert all(np.abs(p).max() > 0 for p in parts)
+
+
+def test_pad_slots_and_idle_rows_are_routed_nowhere():
+    cfg = small()
+    h, router, experts = _layer_inputs(cfg, seed=7, B=4, S=6)
+    q_lens = jnp.asarray([6, 2, 0, 1])
+    live = jnp.arange(6)[None, :] < q_lens[:, None]
+    out, stats, _ = moe_forward(h, router[0], experts, jnp.int32(0), cfg, live)
+    assert int(stats[:8].sum()) == 2 * int(q_lens.sum())  # pads count in no counter
+    assert (np.asarray(out)[~np.asarray(live)] == 0).all()  # and get nothing
+    noise = jnp.where(live[..., None], h, 100.0 * jax.random.normal(jax.random.PRNGKey(9), h.shape))
+    out2, stats2, _ = moe_forward(noise, router[0], experts, jnp.int32(0), cfg, live)
+    np.testing.assert_array_equal(np.asarray(out2)[np.asarray(live)], np.asarray(out)[np.asarray(live)])
+    assert np.asarray(stats2).tolist() == np.asarray(stats).tolist()
+    nothing, stats0, _ = moe_forward(h, router[0], experts, jnp.int32(0), cfg, jnp.zeros((4, 6), bool))
+    assert not np.asarray(nothing).any() and not np.asarray(stats0).any()  # no expert is read
+
+
+# -------------------------------------------------------- the whole forward
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "kernel"])
+def test_pads_and_idle_rows_change_no_live_rows_logits(use_pallas):
+    """A decode window of S = 8 slots with ragged q_lens: what the pad slots
+    and the idle row hold moves neither a live row's logits nor a counter."""
+    cfg = small()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    B, T, lens = 3, 32, jnp.asarray([20, 9, 14])
+    n_pages = 1 + B * 4  # four pages of 16 a row, page 0 the null page
+    table = jnp.asarray(1 + np.arange(B * 4, dtype=np.int32).reshape(B, 4))
+    rng = np.random.default_rng(0)
+    toks = jnp.asarray(rng.integers(0, 384, (B, T)), jnp.int32)
+    _, dense = prefill(params, cfg, toks, lens, init_kv_cache(cfg, B, T), last_only=True)
+    pools = commit_prefill_to_pages(init_paged_kv(cfg, n_pages, 16), dense, table, lens, 16)
+    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    q_lens = jnp.asarray([3, 0, 8])
+
+    def run(window_toks):
+        return decode_chunk_paged(
+            params, cfg, window_toks, lens, table, pools, use_pallas=use_pallas, interpret=True,
+            mesh=mesh, logits_at=jnp.maximum(q_lens - 1, 0), q_lens=q_lens, moe_stats=True, routing=True,
+        )
+
+    w1 = jnp.asarray(rng.integers(0, 384, (B, 8)), jnp.int32)
+    live = np.arange(8)[None, :] < np.asarray(q_lens)[:, None]
+    w2 = jnp.where(live, w1, jnp.asarray(rng.integers(0, 384, (B, 8)), jnp.int32))
+    (l1, _, s1, c1), (l2, _, s2, c2) = run(w1), run(w2)
+    np.testing.assert_array_equal(np.asarray(l1)[[0, 2]], np.asarray(l2)[[0, 2]])
+    assert np.asarray(s1).tolist() == np.asarray(s2).tolist()
+    assert int(s1[:8].sum()) == 4 * 2 * int(q_lens.sum())  # layers x top-k x live tokens
+    assert c1.shape == (4, B, 8, 2)
+    assert (np.asarray(c1)[:, live] == np.asarray(c2)[:, live]).all()
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_prefill_then_paged_decode_through_one_period_matches_the_reference(block, mesh_shape):
+    """Dense prefill committed to pages, then paged decode one token at a time
+    through the windowed kernel (interpreted), over one period of the layer
+    pattern (3 window layers of 8 + 1 full with YaRN), contexts 9-47: logits
+    against the block's plain float32 reference under the step's routing. On
+    the 2 x 2 mesh the expert leaves stay whole on every device."""
+    reference = _by_path("chip_harness_reference_t", os.path.join(CHIP_DIR, "reference.py"))
+    from mcpx.models.gemma.params import load_or_init
+
+    data, model = mesh_shape
+    mesh = make_mesh(data=data, model=model, devices=jax.devices()[: data * model])
+    cfg = dataclasses.replace(block.rehearsal_config(3072), n_kv_heads=4 if model > 1 else 2)
+    params, _ = load_or_init(cfg, "", mesh)
+    specs = param_pspecs(cfg, mesh)
+    assert all(ax is None for k in ("router", "w_gate", "w_up", "w_down") for ax in specs["layers"][k])
+    out = reference.compare_with_engine_step(
+        block, params, cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 33, interpret=True,
+        page_size=16, rows=4, pages_per_row=4, prefill_len=48, n_decode=3,
+    )
+    assert out["ok"] and out["positions"] == 16, out
+    assert min(out["prompt_lens"]) >= 9 and max(out["prompt_lens"]) + 3 > 8  # past the window of 8
+    read = block.routing_readings(params, dataclasses.asdict(cfg))
+    assert len(read) == 4 and max(r["distance"] for r in read) < block.DELTA
+    assert sum(r["checked"] for r in read) == 4 * (sum(out["prompt_lens"]) + 4 * 3)
+
+
+def test_the_window_is_really_applied_in_prefill_and_decode(block):
+    """The same step with the window taken out of the program's config alone
+    (the reference keeps it) fails the comparison: contexts pass 8."""
+    reference = _by_path("chip_harness_reference_t2", os.path.join(CHIP_DIR, "reference.py"))
+    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    cfg = block.rehearsal_config(3072)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    no_window = dataclasses.replace(cfg, layer_types=(), sliding_window=0)
+    out = reference.compare_with_engine_step(
+        block, params, no_window, dataclasses.asdict(cfg), mesh, seed=2**31 + 33, interpret=True,
+        page_size=16, rows=2, pages_per_row=4, prefill_len=48, n_decode=2,
+    )
+    assert not out["ok"]
+
+
+def test_the_configuration_file_keeps_every_published_width():
+    with open(os.path.join(CHIP_DIR, "configs", "mellum2-12b-a2.5b.json")) as f:
+        config = json.load(f)
+    row = None
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        row = next(json.loads(l) for l in open(catalog) if '"Mellum2-12B-A2.5B-Instruct"' in l)
+    published = row["config"] if row else {
+        "hidden_size": 2304, "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
+        "num_experts": 64, "num_experts_per_tok": 8, "moe_intermediate_size": 896, "sliding_window": 1024,
+    }
+    changed = {k for k, v in published.items() if config.get(k) != v}
+    assert changed == ({"num_hidden_layers", "vocab_size"} if row else set())
+    assert set(config["reduced"]) == {"num_hidden_layers", "vocab_size", "max_batch_size",
+                                      "max_pages_per_seq", "max_decode_len", "warmup_max_len"}
+    assert config["num_hidden_layers"] == 12 and config["num_hidden_layers"] % 4 == 0
