@@ -902,6 +902,10 @@ class MCPXConfig:
                 "engine.hetero_grammar_slots must be >= 2 (slot 0 is the "
                 "trivial DFA; at least one constrained grammar must fit)"
             )
+        if self.engine.max_decode_len > 32767:
+            # planner/grammar.py DIST_SUCC_MAX: the budget mask compares the
+            # remaining budget with an int16 table that saturates there.
+            problems.append("engine.max_decode_len must be <= 32767")
         if self.engine.decode_steps_per_tick < 1:
             problems.append("engine.decode_steps_per_tick must be >= 1")
         if not 1 <= self.engine.steps_per_dispatch <= 64:

@@ -1447,11 +1447,12 @@ class InferenceEngine:
             self._warmup()
 
     def _dfa_for(self, grammar: PlanGrammar) -> tuple:
-        """Device copies of a grammar's (trans, mask, dist) tables, padded to
-        the engine's state bucket and replicated over the mesh. Cached per
-        (grammar, pad) so every segment using this grammar shares one HBM
-        copy; the cache holds the grammar object so an id() can't be reused
-        by a new grammar while its tables are still cached."""
+        """Device copies of a grammar's tables (``PlanGrammar.device_tables``:
+        trans, mask, dist_succ, active ids, eos columns, inverse columns),
+        padded to the engine's state bucket and replicated over the mesh.
+        Cached per (grammar, pad) so every segment using this grammar shares
+        one HBM copy; the cache holds the grammar object so an id() can't be
+        reused by a new grammar while its tables are still cached."""
         pad = self._grammar_pad()
         key = (id(grammar), pad)
         hit = self._dfa_cache.get(key)
@@ -2246,12 +2247,16 @@ class InferenceEngine:
         forces the JSON closed before the budget runs out. When the budget
         can't fit any completion at all (caller asked for fewer tokens than
         the shortest valid plan), degrade to the plain grammar mask: the
-        output is then a legal prefix, never garbage. ``dfa`` = the 5-tuple
-        from ``PlanGrammar.device_tables()``; masks live in COMPACT column
-        space [B, C]."""
-        trans, mask_tab, dist, _active, eos_cols = dfa
+        output is then a legal prefix, never garbage. ``dfa`` = the first
+        five of ``PlanGrammar.device_tables()``; its third member is
+        ``dist_succ [S, C]``, the successor's distance-to-finish, read by
+        ROW at the visited state like the mask (a ``dist [S]`` vector read
+        through ``trans`` is one scalar gather per row and column: 0.8 ms
+        of every forward on a v5e, PERF.md PR 34). Masks live in COMPACT
+        column space [B, C]."""
+        _trans, mask_tab, dist_succ, _active, eos_cols = dfa
         legal = mask_tab[st]
-        finishable = legal & (eos_cols[None, :] | (dist[trans[st]] <= rem[:, None]))
+        finishable = legal & (eos_cols[None, :] | (dist_succ[st] <= rem[:, None]))
         feasible = jnp.any(finishable, axis=-1, keepdims=True)
         return jnp.where(feasible, finishable, legal)
 
@@ -2259,7 +2264,7 @@ class InferenceEngine:
         self,
         dfa_trans,
         dfa_mask,
-        dfa_dist,
+        dfa_dist_succ,
         dfa_active,
         dfa_eos,
         dfa_inv,  # unused here; *dfa call sites pass the full 6-tuple
@@ -2278,7 +2283,7 @@ class InferenceEngine:
         active columns of the logits, mask, sample a column, map back to a
         token id via active_ids."""
         tok = self.tokenizer
-        dfa = (dfa_trans, dfa_mask, dfa_dist, dfa_active, dfa_eos)
+        dfa = (dfa_trans, dfa_mask, dfa_dist_succ, dfa_active, dfa_eos)
         A = budgets.shape[0]
         start_state = jnp.zeros((A,), jnp.int32)
         if constrained:
@@ -2843,7 +2848,7 @@ class InferenceEngine:
         params,
         dfa_trans,
         dfa_mask,
-        dfa_dist,
+        dfa_dist_succ,
         dfa_active,
         dfa_eos,
         dfa_inv,
@@ -2906,7 +2911,7 @@ class InferenceEngine:
         tok = self.tokenizer
         B = cur.shape[0]
         W = out_buf.shape[1]
-        dfa = (dfa_trans, dfa_mask, dfa_dist, dfa_active, dfa_eos)
+        dfa = (dfa_trans, dfa_mask, dfa_dist_succ, dfa_active, dfa_eos)
         trans, mask_tab = dfa_trans, dfa_mask
         budget_mask = self._budget_mask
         pad, eos = tok.pad_id, tok.eos_id
@@ -3002,12 +3007,13 @@ class InferenceEngine:
 
             # --- verify: accepted prefix = positions where the proposal IS
             # the budget-masked greedy argmax (the same mask formula as
-            # _budget_mask, vectorised over chunk positions).
+            # _budget_mask, vectorised over chunk positions: two row
+            # gathers at the J states the chain visited).
             rem_j = budgets[:, None] - e[:, None] - j_ar[None, :] - 1
             legal_j = mask_tab[s_before]  # [B, J, C]
             finish_j = legal_j & (
                 dfa_eos[None, None, :]
-                | (dfa_dist[trans[s_before]] <= rem_j[..., None])
+                | (dfa_dist_succ[s_before] <= rem_j[..., None])
             )
             feas_j = jnp.any(finish_j, axis=-1, keepdims=True)
             m_j = jnp.where(feas_j, finish_j, legal_j)

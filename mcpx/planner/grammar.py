@@ -221,17 +221,23 @@ class PlanGrammar:
         return self.active_ids.shape[0]
 
     def device_tables(self, pad_multiple: int = 512):
-        """(ctrans, cmask, dist, active_ids, eos_cols, inv_cols) as device
-        arrays, state dim padded to a multiple of ``pad_multiple`` and
-        columns padded to ``_col_bucket``. The decode loop takes these as
-        ARGUMENTS (not closure constants), so grammars with the same padded
-        shape share one compiled executable — a registry update swaps tables
-        without recompiling, and recompiles happen only when a pad bucket
-        changes. Padding rows/columns are inert: mask False, transitions to
-        the dead state, active id PAD (whose logit is masked anyway).
-        ``inv_cols`` [V] maps token id → compact column (or -1 when the
-        token is active in no state) — how prompt-lookup draft tokens enter
-        compact column space (engine draft speculation)."""
+        """(ctrans, cmask, dist_succ, active_ids, eos_cols, inv_cols) as
+        device arrays, state dim padded to a multiple of ``pad_multiple``
+        and columns padded to ``_col_bucket``. The decode loop takes these
+        as ARGUMENTS (not closure constants), so grammars with the same
+        padded shape share one compiled executable — a registry update swaps
+        tables without recompiling, and recompiles happen only when a pad
+        bucket changes. Padding rows/columns are inert: mask False,
+        transitions to the dead state, active id PAD (whose logit is masked
+        anyway). ``dist_succ`` [S, C] int16 is ``successor_distance``: the
+        device's only use of ``dist`` is the budget mask's "can the
+        successor still finish", and read through ``trans`` that is one
+        scalar gather per (row, position, column) in every forward, while a
+        table indexed like ``cmask`` is one row per visited state. The host
+        keeps ``self.dist`` (``min_len``). ``inv_cols`` [V] maps token id →
+        compact column (or -1 when the token is active in no state) — how
+        prompt-lookup draft tokens enter compact column space (engine draft
+        speculation)."""
         if self._device is None or self._device_pad != pad_multiple:
             import jax.numpy as jnp
 
@@ -242,8 +248,6 @@ class PlanGrammar:
             trans[:n, :c] = self.ctrans
             mask = np.zeros((S, C), bool)
             mask[:n, :c] = self.cmask
-            dist = np.full((S,), _DIST_INF, np.int32)
-            dist[:n] = self.dist
             ids = np.full((C,), self.tokenizer.pad_id, np.int32)
             ids[:c] = self.active_ids
             eos = np.zeros((C,), bool)
@@ -253,7 +257,7 @@ class PlanGrammar:
             self._device = (
                 jnp.asarray(trans),
                 jnp.asarray(mask),
-                jnp.asarray(dist),
+                jnp.asarray(successor_distance(self, S, C)),
                 jnp.asarray(ids),
                 jnp.asarray(eos),
                 jnp.asarray(inv),
@@ -277,6 +281,24 @@ class PlanGrammar:
         for b in text.encode("utf-8"):
             s = int(self.byte_transitions[s, b])
         return s
+
+
+def successor_distance(g: PlanGrammar, S: int, C: int) -> np.ndarray:
+    """``dist_succ [S, C]`` int16: the fewest samples (EOS included) to
+    finish AFTER taking column c from state s, i.e. ``dist[trans[s, c]]``
+    over ``g``'s tables padded to ``[S, C]``, saturated at
+    ``DIST_SUCC_MAX``. Pad rows and pad columns lead to the dead state, so
+    they read its distance (``_DIST_INF``, saturated). Its one use is the
+    budget mask's ``dist_succ <= rem`` with ``rem < max_decode_len``, which
+    the saturation leaves exact while ``max_decode_len <= DIST_SUCC_MAX``
+    (``MCPXConfig.validate``); half the bytes of an int32 table and read no
+    slower on a v5e (PERF.md, PR 34). The one builder of both
+    ``device_tables`` and ``stacked_spec_tables``: the homogeneous and the
+    heterogeneous budget masks cannot drift."""
+    n, c = g.ctrans.shape
+    out = np.full((S, C), min(int(g.dist[g.cdead]), DIST_SUCC_MAX), np.int16)
+    out[:n, :c] = np.minimum(g.dist[g.ctrans], DIST_SUCC_MAX)
+    return out
 
 
 def build_trivial_grammar(tokenizer=None) -> PlanGrammar:
@@ -361,11 +383,11 @@ def stacked_spec_tables(
     stack order and pad geometry (state/column buckets MUST match — the
     engine builds both from one slot snapshot):
 
-      - ``dist_succ [G, S, C]`` int32 — min samples to finish AFTER taking
-        column c from state s (``dist[g, trans[g, s, c]]`` precomputed at
-        stack build), so the hot path's budget-finishability check costs
-        ONE gather instead of the chained transition-then-distance pair —
-        per draft step AND per verify window position;
+      - ``dist_succ [G, S, C]`` int16 — ``successor_distance`` of each
+        slot (the table ``device_tables`` returns for one grammar), so the
+        hot path's budget-finishability check costs ONE gather instead of
+        the chained transition-then-distance pair — per draft step AND per
+        verify window position;
       - ``inv_cols [G, V]`` int32 — token id → compact column, ``-1``
         where the token is not active in that grammar (the stacked
         counterpart of ``device_tables``'s ``inv_cols``). Lets the verify
@@ -385,16 +407,11 @@ def stacked_spec_tables(
     C = max(_col_bucket(g.n_active) for g in grammars)
     G = len(grammars)
     V = grammars[0].tokenizer.vocab_size
-    dist_succ = np.full((G, S, C), _DIST_INF, np.int32)
+    dist_succ = np.empty((G, S, C), np.int16)
     inv = np.full((G, V), -1, np.int32)
     for gi, g in enumerate(grammars):
-        n, c = g.ctrans.shape
-        d = np.full((S,), _DIST_INF, np.int32)
-        d[:n] = g.dist
-        tr = np.full((S, C), g.cdead, np.int32)
-        tr[:n, :c] = g.ctrans
-        dist_succ[gi] = d[tr]
-        inv[gi, g.active_ids] = np.arange(c, dtype=np.int32)
+        dist_succ[gi] = successor_distance(g, S, C)
+        inv[gi, g.active_ids] = np.arange(g.n_active, dtype=np.int32)
     return dist_succ, inv
 
 
@@ -743,6 +760,9 @@ def _sparse_token_tables(byte_trans, byte_dead, eos_ok, tok):
 
 
 _DIST_INF = np.iinfo(np.int32).max // 2
+# Where the device's int16 successor-distance table saturates; also the
+# largest ``engine.max_decode_len`` the budget mask stays exact for.
+DIST_SUCC_MAX = int(np.iinfo(np.int16).max)
 
 
 def _distance_to_accept_compact(

@@ -35,6 +35,16 @@ def test_invalid_page_size_rejected():
         MCPXConfig.from_dict({"engine": {"kv_page_size": 13}})
 
 
+def test_decode_budget_bounded_by_the_distance_table():
+    """The budget mask compares the remaining budget with an int16 table
+    saturated at ``DIST_SUCC_MAX``: exact only for budgets up to it."""
+    from mcpx.planner.grammar import DIST_SUCC_MAX
+
+    MCPXConfig.from_dict({"engine": {"max_decode_len": DIST_SUCC_MAX}})
+    with pytest.raises(ConfigError, match=f"max_decode_len must be <= {DIST_SUCC_MAX}"):
+        MCPXConfig.from_dict({"engine": {"max_decode_len": DIST_SUCC_MAX + 1}})
+
+
 def test_invalid_planner_kind_rejected():
     with pytest.raises(ConfigError, match="planner.kind"):
         MCPXConfig.from_dict({"planner": {"kind": "oracle"}})

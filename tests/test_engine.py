@@ -2,6 +2,8 @@
 allocator hygiene (SURVEY.md §4.5 model-in-the-loop)."""
 
 import asyncio
+import functools
+import math
 
 import pytest
 
@@ -912,6 +914,82 @@ def test_warm_grammar_compiles_every_cohort_bucket_before_traffic():
                 assert all(o.text.startswith('{"steps"') for o in outs)
             assert compiles(eng) == c1
             assert eng.metrics.engine_resets._value.get() == 0
+        finally:
+            await eng.aclose()
+
+    asyncio.run(go())
+
+
+def _gathers(jaxpr):
+    """Every ``gather`` equation of a jaxpr, those of its sub-jaxprs (while,
+    scan, cond, pjit bodies) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _gathers(inner)
+
+
+def _scalar_gathers_from_state_vector(jaxpr, n_states: int, at_least: int):
+    """Gathers whose operand is a 1-D table over the grammar's states and
+    whose result has ``at_least`` elements or more: the chained
+    ``dist[trans[...]]`` shape, one scalar fetched per (row, column)."""
+    return [
+        e
+        for e in _gathers(jaxpr)
+        if e.invars[0].aval.shape == (n_states,)
+        and math.prod(e.outvars[0].aval.shape) >= at_least
+    ]
+
+
+def test_segment_program_has_no_scalar_gather_over_states():
+    """The served decode segment and the first-sample admit, traced as the
+    engine dispatched them, hold no gather from a 1-D table over the DFA's
+    states with B x C (a budget mask) or B x J x C (the draft verify)
+    results: the successor's distance is read by row from ``dist_succ
+    [S, C]``. The chain ``dist[trans[s]]`` it replaced was B x J x C =
+    114,688 scalar gathers and 0.8 ms of every forward on a v5e (PERF.md,
+    PR 34); the detector is shown to see that chain first."""
+    import jax
+    import jax.numpy as jnp
+
+    S, C, B, J = 1024, 512, 4, 7
+    chain = jax.make_jaxpr(lambda dist, trans, s: dist[trans[s]])(
+        jax.ShapeDtypeStruct((S,), jnp.int32),
+        jax.ShapeDtypeStruct((S, C), jnp.int32),
+        jax.ShapeDtypeStruct((B, J), jnp.int32),
+    )
+    assert len(_scalar_gathers_from_state_vector(chain.jaxpr, S, B * C)) == 1
+
+    async def go():
+        eng = make_engine()
+        await eng.start()
+        try:
+            p = eng.tokenizer.encode("plan: compose the services. JSON:")
+            await eng.generate(p, max_new_tokens=24)
+            tracked = {t.name: t for t in eng.costs._tracked}
+            dfa = eng._dfa_for(eng.grammar)
+            S, C = dfa[0].shape
+            assert dfa[2].shape == (S, C)
+            seen = {}
+            for name, impl in (("segment", eng._segment_impl), ("admit", eng._admit_impl)):
+                static = tracked[name]._static
+                for entry in tracked[name]._entries.values():
+                    args, kwargs = entry.lower_spec
+                    statics = {k: v for k, v in kwargs.items() if k in static}
+                    operands = {k: v for k, v in kwargs.items() if k not in static}
+                    jaxpr = jax.make_jaxpr(functools.partial(impl, **statics))(
+                        *args, **operands
+                    ).jaxpr
+                    rows = args[7].shape[0]  # cur [B] / budgets [A]
+                    assert not _scalar_gathers_from_state_vector(jaxpr, S, rows * C)
+                    seen[name] = statics
+            # the path the cells run: the prompt draft's verify, chunk 8
+            assert seen["segment"]["draft"] and seen["segment"]["chunk"] == 8
+            assert "admit" in seen
         finally:
             await eng.aclose()
 

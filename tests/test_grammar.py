@@ -2,10 +2,11 @@
 consistency, and device-side constrained sampling (SURVEY.md §4.1)."""
 
 import numpy as np
+import pytest
 
 from mcpx.core.dag import Plan
 from mcpx.models.tokenizer import ByteTokenizer
-from mcpx.planner.grammar import build_plan_grammar
+from mcpx.planner.grammar import DIST_SUCC_MAX, build_plan_grammar
 
 
 def test_accepts_valid_plans():
@@ -431,8 +432,6 @@ def test_trie_random_legal_walk_names_only_registry_services():
 
 
 def test_trie_rejects_unencodable_names():
-    import pytest
-
     with pytest.raises(ValueError):
         build_plan_grammar(ByteTokenizer(), ['has"quote'])
     with pytest.raises(ValueError):
@@ -442,15 +441,15 @@ def test_trie_rejects_unencodable_names():
 def test_device_tables_pad_and_share():
     tok = ByteTokenizer()
     g = build_plan_grammar(tok, ["a-svc", "b-svc"])
-    trans, mask, dist, active_ids, eos_cols, inv_cols = g.device_tables()
+    trans, mask, dist_succ, active_ids, eos_cols, inv_cols = g.device_tables()
     n, c = g.ctrans.shape
     assert trans.shape[0] % 512 == 0 and trans.shape[0] >= n
     assert trans.shape[1] >= c and trans.shape == mask.shape
-    assert dist.shape[0] == trans.shape[0]
+    assert dist_succ.shape == trans.shape
     assert active_ids.shape == eos_cols.shape == (trans.shape[1],)
     # same objects on second call (one HBM copy per grammar)
     t2 = g.device_tables()
-    assert t2[0] is trans and t2[1] is mask and t2[2] is dist
+    assert t2[0] is trans and t2[1] is mask and t2[2] is dist_succ
     # padded rows/cols: unreachable, all-False mask, dead transitions
     assert not bool(np.asarray(mask)[n:].any())
     assert not bool(np.asarray(mask)[:, c:].any())
@@ -459,7 +458,9 @@ def test_device_tables_pad_and_share():
     # active columns (dense path keeps both forms coherent)
     np.testing.assert_array_equal(np.asarray(trans)[:n, :c], g.ctrans)
     np.testing.assert_array_equal(np.asarray(mask)[:n, :c], g.cmask)
-    np.testing.assert_array_equal(np.asarray(dist)[:n], g.dist)
+    np.testing.assert_array_equal(
+        np.asarray(dist_succ)[:n, :c], np.minimum(g.dist[g.ctrans], DIST_SUCC_MAX)
+    )
     np.testing.assert_array_equal(g.ctrans, g.transitions[:, g.active_ids])
     np.testing.assert_array_equal(g.cmask, g.mask[:, g.active_ids])
     # EOS is an active column; PAD never is
@@ -471,6 +472,102 @@ def test_device_tables_pad_and_share():
     assert inv_np.shape == (tok.vocab_size,)
     np.testing.assert_array_equal(inv_np[g.active_ids], np.arange(c))
     assert inv_np[tok.pad_id] == -1
+
+
+def _table_grammar(kind: str, vocab: str):
+    """One grammar of each construction the planner serves, over either
+    in-tree tokenizer."""
+    from mcpx.models.tokenizer import make_tokenizer
+    from mcpx.registry.base import ServiceRecord
+
+    tok = make_tokenizer(vocab)
+    if kind == "generic":
+        return build_plan_grammar(tok)
+    if kind == "trie":
+        names = [f"svc-{k}-{i:02d}" for k in ("fetch", "rank") for i in range(6)]
+        return build_plan_grammar(tok, names, input_keys=["query", "user_id"])
+    recs = [
+        ServiceRecord(name="fetch", endpoint="local://f",
+                      input_schema={"query": "str"}, output_schema={"data": "str"}),
+        ServiceRecord(name="summarize", endpoint="local://s",
+                      input_schema={"data": "str"}, output_schema={"summary": "str"}),
+        ServiceRecord(name="audit", endpoint="local://a",
+                      input_schema={"summary": "str"}, output_schema={}),
+    ]
+    return build_plan_grammar(tok, services=recs)
+
+
+def _reachable_states(g) -> np.ndarray:
+    seen, frontier = {g.start_state}, [g.start_state]
+    while frontier:
+        s = frontier.pop()
+        for t in np.unique(g.ctrans[s][g.cmask[s] & ~g.eos_cols]):
+            if int(t) not in seen:
+                seen.add(int(t))
+                frontier.append(int(t))
+    return np.asarray(sorted(seen), np.int32)
+
+
+@pytest.mark.parametrize("vocab", ["byte", "bpe"])
+@pytest.mark.parametrize("kind", ["generic", "trie", "typed"])
+def test_dist_succ_table_is_the_chained_distance(kind, vocab):
+    """``device_tables()``'s third member is ``dist[trans]`` on EVERY padded
+    cell (saturated at int16's maximum, which no budget reaches), and the
+    engine's budget mask read from it by row equals the chained
+    transition-then-distance formula it replaced, at every reachable state
+    and every remaining budget the engine can pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from mcpx.core.config import EngineConfig
+    from mcpx.engine.engine import InferenceEngine
+    from mcpx.planner.grammar import _DIST_INF
+
+    g = _table_grammar(kind, vocab)
+    dfa = g.device_tables(512)
+    trans, mask, dist_succ, _ids, eos = (np.asarray(t) for t in dfa[:5])
+    S, C = trans.shape
+    n, c = g.ctrans.shape
+    dist = np.full((S,), _DIST_INF, np.int32)
+    dist[:n] = g.dist
+    assert dist_succ.dtype == np.int16 and dist_succ.shape == (S, C)
+    np.testing.assert_array_equal(dist_succ, np.minimum(dist[trans], DIST_SUCC_MAX))
+    # pad rows and pad columns lead to the dead state
+    assert (dist_succ[n:] == DIST_SUCC_MAX).all() and (dist_succ[:, c:] == DIST_SUCC_MAX).all()
+    assert EngineConfig().max_decode_len <= DIST_SUCC_MAX  # validate() holds every config to it
+
+    st = _reachable_states(g)
+    assert len(st) > 10 and int(g.dist[st].max()) < _DIST_INF
+    legal = mask[st]
+    chained = dist[trans[st]]  # what the device gathered scalar by scalar
+    new = jax.jit(
+        lambda rem: InferenceEngine._budget_mask(
+            None, dfa[:5], jnp.asarray(st), jnp.full(st.shape, rem, jnp.int32)
+        )
+    )
+    # ... and the largest a valid config can: max_decode_len - 1 at its cap
+    for rem in (*range(-1, EngineConfig().max_decode_len + 1), DIST_SUCC_MAX - 1):
+        fin = legal & (eos[None, :] | (chained <= rem))
+        old = np.where(fin.any(-1, keepdims=True), fin, legal)
+        np.testing.assert_array_equal(np.asarray(new(rem)), old, err_msg=f"rem={rem}")
+
+
+def test_stacked_spec_table_is_the_device_table():
+    """One builder serves both paths: a grammar's slot of the heterogeneous
+    speculative stack is the very table ``device_tables`` gives the
+    homogeneous path."""
+    from mcpx.planner.grammar import build_trivial_grammar, stacked_spec_tables
+
+    g = _table_grammar("trie", "byte")
+    triv = build_trivial_grammar(g.tokenizer)
+    own = np.asarray(g.device_tables(512)[2])
+    sdist_succ, _inv = stacked_spec_tables([triv, g], 512)
+    np.testing.assert_array_equal(sdist_succ[1], own)
+    # the trivial slot at the stack's common shape: its live state's two
+    # self-loops finish in one sample, everything else leads to its dead state
+    want = np.full(own.shape, DIST_SUCC_MAX, np.int16)
+    want[0, :2] = 1
+    np.testing.assert_array_equal(sdist_succ[0], want)
 
 
 def test_engine_pad_makes_registry_grammar_share_warmup_shape():
