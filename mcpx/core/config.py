@@ -168,7 +168,7 @@ class EngineConfig:
     # by the pacer (ISSUE 31, engine/pacing.py::segment_forwards) and
     # passed to that one executable as an operand: the fewest whole ticks
     # whose device time covers the worker's own work for a segment three
-    # times over and the prefill chain in front of it, this ceiling until the
+    # times over, this ceiling until the
     # worker has measured a forward's period and its own costs. Why not
     # always the ceiling: a plan is charged whole segments (one waiting
     # behind the segment in flight, then as many as its tokens need, its

@@ -79,7 +79,7 @@ from mcpx.core.errors import ConfigError, EngineError
 from mcpx.engine.kv_cache import PageAllocator, commit_prefill_to_pages, init_paged_kv
 from mcpx.engine.pacing import SegmentPacer, hold_until
 from mcpx.engine.paged_decode import decode_chunk_paged
-from mcpx.models.gemma.moe import moe_stats_init
+from mcpx.models.gemma.moe import forward_weight_bytes, moe_stats_init
 from mcpx.engine.prefix_cache import PrefixNode, RadixPrefixCache
 from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window_rows
 from mcpx.engine.speculative import advance_drafter_state, draft_window
@@ -554,6 +554,10 @@ class InferenceEngine:
         # (_layer_kind_attrs), swapped in whole for queue_stats(); stays
         # empty for a model whose layers are all dense and full.
         self._layer_kind_totals: dict[str, int] = {}  # mcpx: owner[engine-worker, atomic]
+        # A sparse model's weight bytes by kind (moe.forward_weight_bytes),
+        # bound once with the weights: one routed expert's, and the rest of
+        # what a forward reads.
+        self._weight_bytes = (0, 0)  # mcpx: owner[engine-worker]
         # Just-in-time dispatch of the next segment (engine/pacing.py): the
         # device's queue as the worker knows it and the running estimates
         # its hold deadline comes from. One clock read per admission,
@@ -1232,6 +1236,13 @@ class InferenceEngine:
         )
         jax.block_until_ready(self._params)
         init_s = time.monotonic() - t_weights
+        if self.model_cfg.n_experts:
+            self._weight_bytes = forward_weight_bytes(self.model_cfg, self._params)
+            # One sample an expert held, from 0: an expert no token ever
+            # chooses still counts in a reader's mean.
+            first = self.model_cfg.expert_first
+            for e in range(first, first + self.model_cfg.n_experts_held):
+                self.metrics.moe_expert_tokens.labels(expert=str(e))
         # How the weights were placed, for /healthz and /metrics: the wall
         # of the draw (or restore) and what each device now holds.
         held = bytes_per_device(self._params)
@@ -2187,8 +2198,8 @@ class InferenceEngine:
         """(forwards the next segment may run, the configured ceiling).
         Worker thread only. The pacer sizes the segment from what it has
         measured (``pacing.segment_forwards``): whole ticks, long enough
-        to cover the worker's own work and the prefill chain in front,
-        the ceiling until it has an estimate. The speculative segment is
+        to cover the worker's own work, the ceiling until it has an
+        estimate. The speculative segment is
         always its one tick."""
         ceiling = self._decode_iters(spec)
         if spec:
@@ -5003,7 +5014,11 @@ class InferenceEngine:
         layers), ``moe_experts_touched`` ((forward, layer, expert) triples
         with at least one live token: the experts whose weights were read)
         and ``moe_expert_slots`` (forwards x sparse layers x experts held:
-        what reading every expert would come to). Windowed attention:
+        what reading every expert would come to), ``moe_layer_forwards``
+        (forwards x sparse layers), ``weight_bytes_routed`` (touched experts
+        x one expert's bytes) and ``weight_bytes_read`` (that plus
+        everything else a forward reads, a constant x forwards). Windowed
+        attention:
         ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
         whose position had reached the window."""
         mc = self.model_cfg
@@ -5014,7 +5029,11 @@ class InferenceEngine:
             per_expert = counts[:E]
             attrs["moe_assignments"] = int(per_expert.sum())
             attrs["moe_experts_touched"] = int(counts[E])
-            attrs["moe_expert_slots"] = n_fwd * mc.n_layers * E
+            attrs["moe_layer_forwards"] = n_fwd * mc.n_sparse_layers
+            attrs["moe_expert_slots"] = attrs["moe_layer_forwards"] * E
+            expert_bytes, rest_bytes = self._weight_bytes
+            attrs["weight_bytes_routed"] = attrs["moe_experts_touched"] * expert_bytes
+            attrs["weight_bytes_read"] = attrs["weight_bytes_routed"] + n_fwd * rest_bytes
             for i in np.flatnonzero(per_expert):
                 self.metrics.moe_expert_tokens.labels(expert=str(mc.expert_first + int(i))).inc(
                     int(per_expert[i])

@@ -14,10 +14,10 @@ whole segments: one waiting behind the segment in flight, then as many as
 its tokens need, and its reply leaves only at a segment's end. A short
 segment wastes less of a row's time; what bounds it from below is the
 worker itself, which has to admit, dispatch and harvest once a segment
-without ever letting the device's queue empty, and the prefill chain that
-an admission puts in front of each one. ``segment_forwards`` chooses the
-length at each dispatch from the same estimates; the configured window
-(``decode_steps_per_tick x steps_per_dispatch``) is its ceiling.
+without ever letting the device's queue empty. ``segment_forwards``
+chooses the length at each dispatch from the same estimates; the
+configured window (``decode_steps_per_tick x steps_per_dispatch``) is its
+ceiling.
 
 This module is the part of that with no device in it: ``SegmentPacer``
 models the device's FIFO from what the worker observes (what it enqueued
@@ -71,8 +71,8 @@ def hold_until(
     return until if until > now else None
 
 
-# How short a decode segment may get, as multiples of what the pacer
-# measures (PERF.md, PR 31, has the chip runs that chose them). The
+# How short a decode segment may get, as a multiple of what the pacer
+# measures (PERF.md, PR 31, has the chip runs that chose it). The
 # segment's own forwards must last HOST_COVER times the worker's host work
 # for one segment (the medians of an admission, a dispatch, a harvest):
 # three times, because the work comes in lumps (a second admission in one
@@ -82,12 +82,17 @@ def hold_until(
 # benchmark cell ran one tick of 4, the four-chip one on the edge of two
 # with its device waiting 7 ms at a time for an admission's copies; at 3.0
 # olmo2-1b runs 8 forwards with the need at 1.5 ticks, well short of the 12
-# that read worse than no change there. And they must last
-# PREFILL_COVER times the prefill chain an admission puts in front of
-# them: a period that is mostly prefill pads its cohort bucket once a
-# segment and decodes nobody meanwhile.
+# that read worse than no change there.
+#
+# The prefill chain in front of a segment does NOT lengthen it. Until PR 36
+# a segment also had to outlast that chain; the rule never set a length
+# in the cells it was chosen on (PR 31), and a longer segment does not make
+# the chain in front of it shorter: it retires more rows, whose one
+# admission pads to a larger cohort bucket, whose chain then asks for a
+# long segment again. In the first cell where the rule bound, 8 forwards
+# pinned beat 16 on the rate and the median and beat the rule's own mix
+# (PERF.md, PR 36).
 HOST_COVER = 3.0
-PREFILL_COVER = 1.0
 
 
 def segment_forwards(
@@ -95,22 +100,19 @@ def segment_forwards(
     tick: int,
     ceiling: int,
     forward_s: Optional[float],
-    prefill_s: float,
     host_s: Optional[float],
 ) -> int:
     """How many forwards the next decode segment may run: the fewest whole
     ticks whose device time covers the worker's host work for a segment
-    ``HOST_COVER`` times and the prefill chain in front ``PREFILL_COVER``
-    times, never more than ``ceiling`` (the configured window, a whole
-    number of ``tick``s itself) and never less than one tick. With no
-    estimate of a forward's period or of the host's costs yet, the
-    ceiling: the length every segment had before ISSUE 31."""
+    ``HOST_COVER`` times, never more than ``ceiling`` (the configured
+    window, a whole number of ``tick``s itself) and never less than one
+    tick. With no estimate of a forward's period or of the host's costs
+    yet, the ceiling: the length every segment had before ISSUE 31."""
     tick = max(1, tick)
     ceiling = max(tick, ceiling)
     if forward_s is None or forward_s <= 0 or host_s is None:
         return ceiling
-    need_s = max(HOST_COVER * host_s, PREFILL_COVER * prefill_s)
-    ticks = max(1, math.ceil(need_s / (forward_s * tick)))
+    ticks = max(1, math.ceil(HOST_COVER * host_s / (forward_s * tick)))
     return min(ceiling, ticks * tick)
 
 
@@ -128,6 +130,12 @@ class _Recent:
     def value(self) -> Optional[float]:
         return statistics.median(self._xs) if self._xs else None
 
+    @property
+    def low(self) -> Optional[float]:
+        """The smallest of them: of samples that are each an upper bound,
+        the tightest."""
+        return min(self._xs) if self._xs else None
+
 
 class SegmentPacer:
     """The device's queue as the worker knows it, and the estimates that
@@ -137,17 +145,25 @@ class SegmentPacer:
     prefill chain) and ``dispatched`` (a segment of so many forwards), each
     with the host wall it took, and ``ready`` when a segment's blocking
     fetch returns. Between two ready stamps the device ran the prefills
-    chained in front of the segment and the segment's forwards, so a
-    segment with no prefill in front gives a clean sample of the period
-    per forward, and one with prefills gives, less its forwards, a sample
-    of a prefill chain. A sample with prefills inside is an upper bound on
-    the period per forward: it stands in until a clean one exists, and
-    caps a clean estimate that has gone stale above it."""
+    chained in front of the segment and the segment's forwards, so the
+    period over the forwards is an upper bound on a forward's period,
+    exact when no prefill was in front, and the period less its forwards
+    is a sample of a prefill chain.
+
+    A forward's period is the SMALLEST of the last few such bounds, clean
+    or not: what the device does at the load it carries now. Until PR 36 a
+    clean sample outranked every other however old it was; under callers
+    who never leave a row idle every segment has an admission in front, no
+    period is clean, and the estimate stayed where the last lull left it.
+    Where a forward costs what its live tokens touch (a sparse-expert
+    block: 3.7 ms with one row live, 5-6 with a full slab; PERF.md, PR 36)
+    that was a third under the truth, the segment came out half as long
+    again as the worker needs, and which lull a run happened to see set
+    the length it served."""
 
     def __init__(self, clock: Callable[[], float] = time.monotonic) -> None:
         self.clock = clock
-        self._forward = _Recent()  # clean: no prefill inside the period
-        self._forward_bound = _Recent()  # with prefills inside: upper bounds
+        self._forward = _Recent()  # period / forwards, prefills inside or not
         self._prefill = _Recent()
         self._admit = _Recent()
         self._dispatch = _Recent()
@@ -160,10 +176,7 @@ class SegmentPacer:
     # ------------------------------------------------------------ estimates
     @property
     def forward_s(self) -> Optional[float]:
-        clean, bound = self._forward.value, self._forward_bound.value
-        if clean is None:
-            return bound
-        return clean if bound is None else min(clean, bound)
+        return self._forward.low
 
     @property
     def prefill_s(self) -> float:
@@ -191,7 +204,6 @@ class SegmentPacer:
             tick=tick,
             ceiling=ceiling,
             forward_s=self.forward_s,
-            prefill_s=self.prefill_s,
             host_s=self.host_s,
         )
 
@@ -244,13 +256,12 @@ class SegmentPacer:
         period = t_ready - (t_prev if pipelined else t_disp)
         if period <= 0:
             return
-        if not prefills:
-            self._forward.add(period / forwards)
-        elif pipelined:
-            self._forward_bound.add(period / forwards)
-            clean = self._forward.value
-            if clean is not None:
-                self._prefill.add(max(0.0, period - clean * forwards) / prefills)
+        if prefills and not pipelined:
+            return  # from idle: the period holds the host's admission too
+        forward = self.forward_s  # before this period moves it
+        self._forward.add(period / forwards)
+        if prefills and forward is not None:
+            self._prefill.add(max(0.0, period - forward * forwards) / prefills)
 
     def reset(self) -> None:
         """The in-flight segments were dropped (a failed dispatch)."""

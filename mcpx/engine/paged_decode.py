@@ -26,13 +26,17 @@ from mcpx.engine.kernels.paged_attention import (
 )
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import (
-    apply_rope,
+    attention_inputs,
+    attention_residual,
     embed_tokens,
+    feed_forward_residual,
     layer_kinds,
+    layer_stacks,
     output_logits,
     rms_norm,
+    sparse_index,
 )
-from mcpx.models.gemma.moe import activation, moe_forward, moe_stats_init, split_layers
+from mcpx.models.gemma.moe import moe_stats_init
 from mcpx.parallel.mesh import DATA_AXIS, MODEL_AXIS, _axis
 
 
@@ -160,7 +164,7 @@ def decode_chunk_paged(
     q_lens: "jax.Array | None" = None,  # [B] live window slots (ragged rows)
     mesh: Optional[Mesh] = None,  # engine mesh; required with q_lens + use_pallas
     moe_stats: bool = False,  # sparse models: also the forward's expert counters
-    routing: bool = False,  # sparse models: also the experts chosen [L, B, S, k]
+    routing: bool = False,  # sparse models: also the experts chosen [Ls, B, S, k]
 ) -> tuple:
     """Multi-token decode step: S new tokens per sequence in ONE forward.
 
@@ -209,7 +213,7 @@ def decode_chunk_paged(
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
     kv_window = _kv_window(positions, page_table, S, psz, N)
-    scanned, experts = split_layers(cfg, params["layers"])
+    stacks, experts = layer_stacks(cfg, params)
     # A sparse feed-forward routes only the window's live slots: a pad slot
     # or an idle row chooses no expert, reads none and is counted nowhere.
     live = None if q_lens is None else jnp.arange(S)[None, :] < q_lens[:, None]
@@ -249,35 +253,27 @@ def decode_chunk_paged(
         lp, kind = scanned
         lp = dequant_layer(lp, jnp.dtype(cfg.dtype))
         h = rms_norm(x, lp["pre_attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
-        q = jnp.einsum("bsd,dkh->bskh", h, lp["wq"])  # [B, S, H, hd]
-        k = jnp.einsum("bsd,dkh->bskh", h, lp["wk"])  # [B, S, K, hd]
-        v = jnp.einsum("bsd,dkh->bskh", h, lp["wv"])
-        q = apply_rope(q, pos_mat, cfg.rope_theta, kind)
-        k = apply_rope(k, pos_mat, cfg.rope_theta, kind)
+        q, k, v = attention_inputs(h, lp, cfg, pos_mat, kind)  # the pages hold k as attended
         k_all = _write_kv_window(k_all, layer, k, kv_window)
         v_all = _write_kv_window(v_all, layer, v, kv_window)
         attn = attend(q, k_all, v_all, layer, kind.get("window"))
-        wo = lp["wo"].reshape(cfg.n_heads * cfg.head_dim, cfg.d_model)
-        x = x + jnp.einsum("bsf,fd->bsd", attn, wo)
-        h = rms_norm(x, lp["pre_mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
-        chosen = None
-        if cfg.n_experts:
-            ff, layer_stats, chosen = moe_forward(h, lp["router"], experts, layer, cfg, live)
-            x, stats = x + ff, stats + layer_stats
-        else:
-            ff = activation(cfg, jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
-            ff = ff * jnp.einsum("bsd,df->bsf", h, lp["w_up"])
-            x = x + jnp.einsum("bsf,fd->bsd", ff, lp["w_down"])
+        x = attention_residual(x, h, attn, lp, cfg)
+        x, layer_stats, chosen = feed_forward_residual(
+            x, lp, cfg, moe=(experts, sparse_index(cfg, layer), live)
+        )
+        if layer_stats is not None:
+            stats = stats + layer_stats
         return (x, k_all, v_all, layer + 1, stats), chosen
 
-    (x, k_new, v_new, _, stats), chosen = lax.scan(
-        body,
-        (
-            x, paged_kv["k"], paged_kv["v"], jnp.asarray(0, jnp.int32),
-            moe_stats_init(cfg) if cfg.n_experts else None,
-        ),
-        (scanned, layer_kinds(cfg)),
+    # One scan a stack (one, but for leading dense layers before sparse
+    # ones): the global layer counter, the pools and the counters cross.
+    carry = (
+        x, paged_kv["k"], paged_kv["v"], jnp.asarray(0, jnp.int32),
+        moe_stats_init(cfg) if cfg.n_experts else None,
     )
+    for scanned, lo, hi in stacks:
+        carry, chosen = lax.scan(body, carry, (scanned, layer_kinds(cfg, lo, hi)))
+    x, k_new, v_new, _, stats = carry
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     pools = {"k": k_new, "v": v_new}
     # What a sparse model's callers may ask for beside the logits: the

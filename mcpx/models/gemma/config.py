@@ -63,16 +63,39 @@ class GemmaConfig:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     yarn_attention_factor: float = 1.0
-    # --- sparse feed-forward: ``n_experts`` > 0 replaces the dense MLP in
-    # every layer by a router ``n_experts`` wide choosing
-    # ``n_experts_per_tok`` experts of width ``d_expert``. This device holds
-    # ``experts_held`` of them from ``expert_first`` on (0 = all): it routes
-    # over all and computes its own experts' part of the result.
+    # ``rope_full_layers`` False: a full layer's q and k are not rotated (its
+    # row of ``rope_tables`` is zeros, the identity rotation).
+    rope_full_layers: bool = True
+    # --- attention's further parts: an RMSNorm over head_dim of q and of k
+    # (one gain of head_dim each) before the rope and the KV write; an
+    # output gate, ``sigmoid(Wg n1)`` on the attention's result before Wo;
+    # a norm on each branch's OUTPUT before it joins the residual.
+    qk_norm: bool = False
+    attn_gate: bool = False
+    post_norms: bool = False
+    # --- sparse feed-forward: ``n_experts`` > 0 replaces the dense MLP, in
+    # every layer after the ``n_dense_layers`` leading ones, by a router
+    # ``n_experts`` wide choosing ``n_experts_per_tok`` experts of width
+    # ``d_expert``. This device holds ``experts_held`` of them from
+    # ``expert_first`` on (0 = all): it routes over all and computes its own
+    # experts' part of the result.
     n_experts: int = 0
     n_experts_per_tok: int = 0
     d_expert: int = 0
     expert_first: int = 0
     experts_held: int = 0
+    n_dense_layers: int = 0
+    # A shared expert of this width beside the routed ones (0: none): every
+    # token reads it, weight 1.
+    d_shared_expert: int = 0
+    # The router's scoring: ``softmax`` over all experts before the choice,
+    # the chosen renormalised; or ``sigmoid`` scores, chosen by score plus a
+    # per-expert bias where ``router_bias_scale`` > 0 (the bias enters the
+    # CHOICE, never the weights; a random init draws it N(0, scale^2)), the
+    # chosen scores renormalised, times ``router_scale``.
+    router_scoring: str = "softmax"
+    router_bias_scale: float = 0.0
+    router_scale: float = 1.0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -103,6 +126,16 @@ class GemmaConfig:
                     f"experts {self.expert_first}..{self.expert_first + self.n_experts_held} "
                     f"are not among the router's {self.n_experts}"
                 )
+            if not 0 <= self.n_dense_layers < self.n_layers:
+                raise ConfigError("n_dense_layers leaves no sparse layer (or is negative)")
+            if self.router_scoring not in ("softmax", "sigmoid"):
+                raise ConfigError(f"router_scoring {self.router_scoring!r}: softmax or sigmoid")
+            if self.router_scoring == "softmax" and (
+                self.router_bias_scale or self.router_scale != 1.0
+            ):
+                raise ConfigError("router bias and scale belong to sigmoid scoring")
+        elif self.n_dense_layers or self.d_shared_expert:
+            raise ConfigError("n_dense_layers / d_shared_expert need n_experts")
 
     @property
     def q_per_kv(self) -> int:
@@ -111,6 +144,10 @@ class GemmaConfig:
     @property
     def n_experts_held(self) -> int:
         return self.experts_held or self.n_experts
+
+    @property
+    def n_sparse_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.n_experts else 0
 
     @property
     def is_default_block(self) -> bool:
@@ -122,6 +159,7 @@ class GemmaConfig:
             for f in (
                 "activation", "tie_embeddings", "scale_embeddings", "norm_plus_one",
                 "layer_types", "sliding_window", "yarn_factor", "n_experts",
+                "rope_full_layers", "qk_norm", "attn_gate", "post_norms",
             )
         )
 
@@ -129,7 +167,9 @@ class GemmaConfig:
         """Per-layer rope as the layer scan's data: inverse frequencies
         ``[L, head_dim / 2]`` and the factor on cos and sin ``[L]``. None
         where every layer rotates alike with no stretch: the scan then
-        carries nothing and the rope is the constant it always was.
+        carries nothing and the rope is the constant it always was. A full
+        layer that does not rotate (``rope_full_layers`` off) gets a row of
+        zeros: angle 0 at every position, the identity.
 
         Sliding layers: ``theta^(-2k/dim)``, factor 1. Full layers under
         YaRN: with ``corr(r) = dim * ln(orig / (2 pi r)) / (2 ln theta)``,
@@ -138,11 +178,15 @@ class GemmaConfig:
         low), 0, 1)``, the frequency is ``(1 - ramp_k)`` of the plain one
         plus ``ramp_k`` of the plain one over ``yarn_factor``; factor
         ``yarn_attention_factor``."""
-        if not self.yarn_factor:
+        if not self.yarn_factor and self.rope_full_layers:
             return None
         dim = self.head_dim
         k = np.arange(dim // 2, dtype=np.float64)
         plain = self.rope_theta ** (-2.0 * k / dim)
+        full = np.asarray([t != SLIDING for t in self.layer_types or (FULL,) * self.n_layers])
+        if not self.rope_full_layers:
+            inv_freq = np.where(full[:, None], 0.0, plain[None, :])
+            return inv_freq.astype(np.float32), np.ones(self.n_layers, np.float32)
 
         def corr(r: float) -> float:
             return dim * math.log(self.yarn_original_max_pos / (2 * math.pi * r)) / (
@@ -153,7 +197,6 @@ class GemmaConfig:
         high = min(math.ceil(corr(self.yarn_beta_slow)), dim - 1)
         ramp = np.clip((k - low) / max(high - low, 1e-3), 0.0, 1.0)
         stretched = (1.0 - ramp) * plain + ramp * plain / self.yarn_factor
-        full = np.asarray([t != SLIDING for t in self.layer_types or (FULL,) * self.n_layers])
         inv_freq = np.where(full[:, None], stretched[None, :], plain[None, :])
         factor = np.where(full, self.yarn_attention_factor, 1.0)
         return inv_freq.astype(np.float32), factor.astype(np.float32)
@@ -185,12 +228,16 @@ class GemmaConfig:
     def _count(self, experts: int) -> int:
         D, H, K, hd, F = self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.d_ff
         attn = D * H * hd + 2 * D * K * hd + H * hd * D + 2 * D
-        if self.n_experts:
-            ff = D * self.n_experts + experts * 3 * D * self.d_expert
-        else:
-            ff = 3 * D * F
+        attn += self.attn_gate * D * H * hd + self.qk_norm * 2 * hd + self.post_norms * 2 * D
+        sparse_ff = (
+            (D + bool(self.router_bias_scale)) * self.n_experts
+            + experts * 3 * D * self.d_expert
+            + 3 * D * self.d_shared_expert
+        )
+        n_sparse = self.n_sparse_layers
+        layers = (self.n_layers - n_sparse) * (attn + 3 * D * F) + n_sparse * (attn + sparse_ff)
         head = 0 if self.tie_embeddings else D * self.vocab_size
-        return self.vocab_size * D + self.n_layers * (attn + ff) + D + head
+        return self.vocab_size * D + layers + D + head
 
     @classmethod
     def named(cls, name: str, *, vocab_size: int = 384, max_seq_len: int = 2048) -> "GemmaConfig":

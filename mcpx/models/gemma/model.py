@@ -86,47 +86,91 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         from mcpx.parallel.mesh import param_pspecs
 
         specs = param_pspecs(cfg, mesh)
-        by_name = {**specs["layers"], **{k: v for k, v in specs.items() if k != "layers"}}
+        by_name = {
+            **specs["layers"],
+            **{k: v for k, v in specs.items() if k not in ("layers", "dense_layers")},
+            **{"dense_layers." + k: v for k, v in specs.get("dense_layers", {}).items()},
+        }
         sharding = lambda name: NamedSharding(mesh, by_name[name])
 
-    def normal(name, key, shape, fan_in, by_layer=False):
+    def normal(name, key, shape, fan_in, by_layer=False, stack="", as_type=dtype):
+        # ``stack``: "" or "dense_layers.", the leading dense layers' own
+        # stack, whose keys are folded apart from the other stack's.
         draw = jax.jit(
             _draw_by_layer if by_layer else _draw_normal,
             static_argnames=("shape", "dtype"),
-            out_shardings=sharding(name),
+            out_shardings=sharding(stack + name),
         )
-        return t(name, draw(key, np.float32(math.sqrt(fan_in)), shape=shape, dtype=dtype))
+        key = jax.random.fold_in(key, 20) if stack else key
+        return t(name, draw(key, np.float32(math.sqrt(fan_in)), shape=shape, dtype=as_type))
 
-    def gain(name, shape):
+    def gain(name, shape, stack=""):
         # The norm's scale at its identity: 0 under a (1 + scale) gain, else 1.
         fill = jnp.zeros if cfg.norm_plus_one else jnp.ones
-        return t(name, fill(shape, dtype, device=sharding(name)))
+        return t(name, fill(shape, dtype, device=sharding(stack + name)))
 
-    layers = {
-        "pre_attn_norm": gain("pre_attn_norm", (L, D)),
-        "pre_mlp_norm": gain("pre_mlp_norm", (L, D)),
-        "wq": normal("wq", k_q, (L, D, H, hd), D),
-        "wk": normal("wk", k_k, (L, D, K, hd), D),
-        "wv": normal("wv", k_v, (L, D, K, hd), D),
-        "wo": normal("wo", k_o, (L, H, hd, D), H * hd),
-    }
+    def attention(n, stack=""):
+        """The leaves every layer has, for a stack of ``n`` layers."""
+        leaves = {
+            "pre_attn_norm": gain("pre_attn_norm", (n, D), stack),
+            "pre_mlp_norm": gain("pre_mlp_norm", (n, D), stack),
+            "wq": normal("wq", k_q, (n, D, H, hd), D, stack=stack),
+            "wk": normal("wk", k_k, (n, D, K, hd), D, stack=stack),
+            "wv": normal("wv", k_v, (n, D, K, hd), D, stack=stack),
+            "wo": normal("wo", k_o, (n, H, hd, D), H * hd, stack=stack),
+        }
+        if cfg.qk_norm:
+            leaves["q_norm"] = gain("q_norm", (n, hd), stack)
+            leaves["k_norm"] = gain("k_norm", (n, hd), stack)
+        if cfg.attn_gate:
+            leaves["w_attn_gate"] = normal(
+                "w_attn_gate", jax.random.fold_in(key, 10), (n, D, H, hd), D, stack=stack
+            )
+        if cfg.post_norms:
+            leaves["post_attn_norm"] = gain("post_attn_norm", (n, D), stack)
+            leaves["post_mlp_norm"] = gain("post_mlp_norm", (n, D), stack)
+        return leaves
+
+    def dense_ff(n, stack=""):
+        return {
+            "w_gate": normal("w_gate", k_gate, (n, D, F), D, stack=stack),
+            "w_up": normal("w_up", k_up, (n, D, F), D, stack=stack),
+            "w_down": normal("w_down", k_down, (n, F, D), F, stack=stack),
+        }
+
+    Ls = cfg.n_sparse_layers
+    layers = attention(Ls or L)
     if cfg.n_experts:
-        E, Fe = cfg.n_experts_held, cfg.d_expert
-        layers["router"] = normal("router", jax.random.fold_in(key, 8), (L, D, cfg.n_experts), D)
+        E, Fe, Fs = cfg.n_experts_held, cfg.d_expert, cfg.d_shared_expert
+        layers["router"] = normal("router", jax.random.fold_in(key, 8), (Ls, D, cfg.n_experts), D)
+        if cfg.router_bias_scale:
+            # float32 whatever the weights' type: it is added to float32 scores.
+            layers["router_bias"] = normal(
+                "router_bias", jax.random.fold_in(key, 11), (Ls, cfg.n_experts),
+                cfg.router_bias_scale**-2, as_type=jnp.float32,
+            )
+        if Fs:
+            layers["shared_gate"] = normal("shared_gate", jax.random.fold_in(key, 12), (Ls, D, Fs), D)
+            layers["shared_up"] = normal("shared_up", jax.random.fold_in(key, 13), (Ls, D, Fs), D)
+            layers["shared_down"] = normal("shared_down", jax.random.fold_in(key, 14), (Ls, Fs, D), Fs)
         # The expert stacks are most of the tree: drawn a layer at a time, so
         # the float32 transient is one layer's and not the leaf's.
-        layers["w_gate"] = normal("w_gate", k_gate, (L, E, D, Fe), D, by_layer=True)
-        layers["w_up"] = normal("w_up", k_up, (L, E, D, Fe), D, by_layer=True)
-        layers["w_down"] = normal("w_down", k_down, (L, E, Fe, D), Fe, by_layer=True)
+        layers["w_gate"] = normal("w_gate", k_gate, (Ls, E, D, Fe), D, by_layer=True)
+        layers["w_up"] = normal("w_up", k_up, (Ls, E, D, Fe), D, by_layer=True)
+        layers["w_down"] = normal("w_down", k_down, (Ls, E, Fe, D), Fe, by_layer=True)
     else:
-        layers["w_gate"] = normal("w_gate", k_gate, (L, D, F), D)
-        layers["w_up"] = normal("w_up", k_up, (L, D, F), D)
-        layers["w_down"] = normal("w_down", k_down, (L, F, D), F)
+        layers.update(dense_ff(L))
     params = {
         "embed": normal("embed", k_embed, (V, D), D),
         "layers": layers,
         "final_norm": gain("final_norm", (D,)),
     }
+    if cfg.n_dense_layers:
+        # The leading dense layers are a stack of their own: a dense
+        # feed-forward [Ld, D, F] and an expert stack [Ls, E, D, Fe] cannot
+        # share a leaf.
+        Ld = cfg.n_dense_layers
+        params["dense_layers"] = {**attention(Ld, "dense_layers."), **dense_ff(Ld, "dense_layers.")}
     if not cfg.tie_embeddings:
         params["head"] = normal("head", jax.random.fold_in(key, 9), (D, V), D)
     return params
@@ -139,28 +183,33 @@ def init_kv_cache(cfg: GemmaConfig, batch: int, max_len: int, dtype: str | None 
 
 
 # ------------------------------------------------------------------- pieces
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float, plus_one: bool = True) -> jax.Array:
+def rms_norm(
+    x: jax.Array, scale: jax.Array, eps: float, plus_one: bool = True, out_dtype=None
+) -> jax.Array:
+    """``out_dtype``: x's own unless given (float32 where what follows is
+    elementwise too, so that the chain rounds once, at its end)."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     normed = x32 * lax.rsqrt(var + eps)
-    if not plus_one:  # a plain gain
-        return (normed * scale.astype(jnp.float32)).astype(x.dtype)
-    # Gemma convention: scale is a residual around 1.
-    return (normed * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
+    gain = scale.astype(jnp.float32)
+    if plus_one:  # Gemma convention: scale is a residual around 1; else a plain gain
+        gain = 1.0 + gain
+    return (normed * gain).astype(out_dtype or x.dtype)
 
 
-def layer_kinds(cfg: GemmaConfig) -> dict[str, jax.Array]:
-    """What differs from layer to layer, as data the ONE layer scan scans
-    beside the weights: ``inv_freq`` [L, hd/2] and ``rope_factor`` [L]
-    (``GemmaConfig.rope_tables``), ``window`` [L] (``layer_windows``). Empty
-    where every layer is alike: the scan then carries what it always did."""
+def layer_kinds(cfg: GemmaConfig, lo: int = 0, hi: "int | None" = None) -> dict[str, jax.Array]:
+    """What differs from layer to layer, as data the layer scan scans
+    beside the weights, for layers ``lo`` to ``hi``: ``inv_freq`` [L, hd/2]
+    and ``rope_factor`` [L] (``GemmaConfig.rope_tables``), ``window`` [L]
+    (``layer_windows``). Empty where every layer is alike: the scan then
+    carries what it always did."""
     kinds = {}
     rope = cfg.rope_tables()
     if rope is not None:
-        kinds["inv_freq"], kinds["rope_factor"] = jnp.asarray(rope[0]), jnp.asarray(rope[1])
+        kinds["inv_freq"], kinds["rope_factor"] = (jnp.asarray(a[lo:hi]) for a in rope)
     windows = cfg.layer_windows()
     if windows is not None:
-        kinds["window"] = jnp.asarray(windows)
+        kinds["window"] = jnp.asarray(windows[lo:hi])
     return kinds
 
 
@@ -208,6 +257,104 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array) -> jax.Ar
     return out
 
 
+# What a block computes around its attention op, written once for the dense
+# forward below and the paged one (``engine/paged_decode.py``): the two differ
+# only in where K/V are written and what attends.
+def attention_inputs(
+    h: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig, positions: jax.Array, kind
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Normed input h [B, T, D] -> q [B, T, H, hd], k and v [B, T, K, hd] as
+    the cache holds them: q and k normed per head where the block has a q/k
+    norm, then rotated by this layer's rope."""
+    q = jnp.einsum("btd,dkh->btkh", h, lp["wq"])
+    k = jnp.einsum("btd,dkh->btkh", h, lp["wk"])
+    v = jnp.einsum("btd,dkh->btkh", h, lp["wv"])
+    if cfg.qk_norm:
+        # norm and rope are one elementwise chain in float32, rounded once
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_plus_one, jnp.float32)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_plus_one, jnp.float32)
+    q = apply_rope(q, positions, cfg.rope_theta, kind).astype(h.dtype)
+    k = apply_rope(k, positions, cfg.rope_theta, kind).astype(h.dtype)
+    return q, k, v
+
+
+def attention_residual(
+    x: jax.Array, h: jax.Array, attn: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig
+) -> jax.Array:
+    """x + the attention branch: ``attn`` [B, T, H * hd] gated by
+    ``sigmoid(Wg h)`` where the block has an output gate, through Wo, normed
+    where the block norms its branches' outputs."""
+    F = cfg.n_heads * cfg.head_dim
+    if cfg.attn_gate:
+        gate = jnp.einsum("btd,df->btf", h, lp["w_attn_gate"].reshape(cfg.d_model, F))
+        attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
+    wo = lp["wo"].reshape(F, cfg.d_model)
+    if not cfg.post_norms:
+        return x + jnp.einsum("btf,fd->btd", attn, wo)
+    out = jnp.einsum("btf,fd->btd", attn, wo, preferred_element_type=jnp.float32)
+    return _add_normed(x, out, lp["post_attn_norm"], cfg)
+
+
+def _add_normed(x: jax.Array, branch32: jax.Array, gain: jax.Array, cfg: GemmaConfig) -> jax.Array:
+    """x + RMSNorm(branch) g, the branch's float32 output normed and added in
+    float32: the sum is rounded to x's type once."""
+    normed = rms_norm(branch32, gain, cfg.norm_eps, cfg.norm_plus_one)
+    return (x.astype(jnp.float32) + normed).astype(x.dtype)
+
+
+def gated_mlp(h: jax.Array, w_gate, w_up, w_down, cfg: GemmaConfig, out_dtype=None) -> jax.Array:
+    """The dense feed-forward: a layer's own, or a sparse layer's shared expert."""
+    ff = activation(cfg, jnp.einsum("btd,df->btf", h, w_gate)) * jnp.einsum("btd,df->btf", h, w_up)
+    return jnp.einsum("btf,fd->btd", ff, w_down, preferred_element_type=out_dtype)
+
+
+def feed_forward_residual(
+    x: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig, moe: "tuple | None" = None
+) -> tuple:
+    """x + the feed-forward branch, by the layer's KIND, which its leaves
+    say: with a ``router`` the routed experts held here (``moe``: expert
+    stacks, SPARSE layer index, live [B, T]) plus the shared expert, else the
+    dense MLP. -> (x, the layer's expert counters, the experts chosen), the
+    last two None for a dense layer."""
+    h = rms_norm(x, lp["pre_mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
+    stats = chosen = None
+    # A branch that is normed before it joins stays float32 until it has.
+    branch_dtype = jnp.float32 if cfg.post_norms else h.dtype
+    if "router" in lp:
+        experts, layer, live = moe
+        ff, stats, chosen = moe_forward(
+            h, lp["router"], experts, layer, cfg, live, lp.get("router_bias")
+        )
+        ff = ff.astype(branch_dtype)
+        if cfg.d_shared_expert:
+            shared = (lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+            ff = ff + gated_mlp(h, *shared, cfg, out_dtype=branch_dtype)
+    else:
+        ff = gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg, out_dtype=branch_dtype)
+    if cfg.post_norms:
+        return _add_normed(x, ff, lp["post_mlp_norm"], cfg), stats, chosen
+    return x + ff, stats, chosen
+
+
+def layer_stacks(cfg: GemmaConfig, params: Params) -> tuple[list, dict]:
+    """The layer scan's runs in layer order, ``[(scanned leaves, first
+    layer, one past the last)]``, and the expert stacks the scan closes
+    over. One run, but for a sparse model with leading dense layers: those
+    are a stack of their own (``params["dense_layers"]``), so the same body
+    runs over two stacks with its carry handed across."""
+    scanned, experts = split_layers(cfg, params["layers"])
+    Ld, L = cfg.n_dense_layers, cfg.n_layers
+    if not Ld:
+        return [(scanned, 0, L)], experts
+    return [(params["dense_layers"], 0, Ld), (scanned, Ld, L)], experts
+
+
+def sparse_index(cfg: GemmaConfig, layer: jax.Array) -> jax.Array:
+    """A layer's row in the sparse layers' stacks (what a leading dense
+    layer gets names no row, and no dense layer reads it)."""
+    return layer - cfg.n_dense_layers if cfg.n_dense_layers else layer
+
+
 def _layer(
     x: jax.Array,
     lp: dict[str, jax.Array],
@@ -225,17 +372,13 @@ def _layer(
 
     x: [B, T, D]; k_cache/v_cache: [B, S, K, hd]; positions: [B, T];
     mask: [B, T, S]; write_idx: [B, T] absolute cache slots for this chunk.
-    ``kind``: this layer's slice of ``layer_kinds``. ``moe``: (expert
-    stacks, layer index, live [B, T]) under a sparse feed-forward; the
-    return then ends with the layer's counters and chosen experts.
+    ``kind``: this layer's slice of ``layer_kinds``. ``moe``: see
+    ``feed_forward_residual``. -> (x, k_cache, v_cache, the layer's expert
+    counters, the experts chosen), the last two None for a dense layer.
     """
     B, T, D = x.shape
     h = rms_norm(x, lp["pre_attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
-    q = jnp.einsum("btd,dkh->btkh", h, lp["wq"])
-    k = jnp.einsum("btd,dkh->btkh", h, lp["wk"])
-    v = jnp.einsum("btd,dkh->btkh", h, lp["wv"])
-    q = apply_rope(q, positions, cfg.rope_theta, kind)
-    k = apply_rope(k, positions, cfg.rope_theta, kind)
+    q, k, v = attention_inputs(h, lp, cfg, positions, kind)
 
     b_idx = jnp.arange(B)[:, None]  # [B, 1] broadcast with write_idx [B, T]
     k_cache = k_cache.at[b_idx, write_idx].set(k.astype(k_cache.dtype))
@@ -247,20 +390,9 @@ def _layer(
         mask = mask & (s_idx[None, None, :] > positions[:, :, None] - kind["window"])
     qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
     attn = (attend_fn or _attend)(qg, k_cache, v_cache, mask)
-    attn = attn.reshape(B, T, cfg.n_heads * cfg.head_dim)
-    wo = lp["wo"].reshape(cfg.n_heads * cfg.head_dim, D)
-    x = x + jnp.einsum("btf,fd->btd", attn, wo)
-
-    h = rms_norm(x, lp["pre_mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
-    if moe is not None:
-        experts, layer, live = moe
-        ff, stats, chosen = moe_forward(h, lp["router"], experts, layer, cfg, live)
-        return x + ff, k_cache, v_cache, stats, chosen
-    gate = jnp.einsum("btd,df->btf", h, lp["w_gate"])
-    up = jnp.einsum("btd,df->btf", h, lp["w_up"])
-    ff = activation(cfg, gate) * up
-    x = x + jnp.einsum("btf,fd->btd", ff, lp["w_down"])
-    return x, k_cache, v_cache
+    x = attention_residual(x, h, attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp, cfg)
+    x, stats, chosen = feed_forward_residual(x, lp, cfg, moe)
+    return x, k_cache, v_cache, stats, chosen
 
 
 def embed_tokens(params: Params, cfg: GemmaConfig, tokens: jax.Array) -> jax.Array:
@@ -305,7 +437,7 @@ def forward(
     ``logits_at`` [B]: unembed only that position per row -> [B, V].
     ``live`` [B, T]: the slots that are tokens and not padding; a sparse
     feed-forward routes the others nowhere. ``routing``: also return the
-    experts chosen, [L, B, T, k]."""
+    experts chosen in the sparse layers, [Ls, B, T, k]."""
     from mcpx.models.gemma.quant import dequant_layer
 
     # Weight-only int8 serving mode (quant.py): identity plumbing on plain
@@ -315,27 +447,34 @@ def forward(
     # position matters).
     dtype = jnp.dtype(cfg.dtype)
     x = embed_tokens(params, cfg, tokens)
-    scanned, experts = split_layers(cfg, params["layers"])
+    stacks, experts = layer_stacks(cfg, params)
 
     def body(carry, scanned):
         lp, kind, k_c, v_c = scanned
         lp = dequant_layer(lp, dtype)
-        if cfg.n_experts:
-            x, layer = carry
-            x, k_c, v_c, _stats, chosen = _layer(
-                x, lp, k_c, v_c, positions, mask, positions, cfg, attend_fn, kind,
-                moe=(experts, layer, live),
-            )
-            return (x, layer + 1), (k_c, v_c, chosen)
-        x, k_c, v_c = _layer(carry, lp, k_c, v_c, positions, mask, positions, cfg, attend_fn, kind)
-        return x, (k_c, v_c)
+        # A sparse model's carry counts the layers: the expert stacks are
+        # sliced by it. A dense layer's ``chosen`` is None.
+        x, layer = carry if cfg.n_experts else (carry, None)
+        moe = (experts, sparse_index(cfg, layer), live) if cfg.n_experts else None
+        x, k_c, v_c, _stats, chosen = _layer(
+            x, lp, k_c, v_c, positions, mask, positions, cfg, attend_fn, kind, moe
+        )
+        return ((x, layer + 1) if cfg.n_experts else x), (k_c, v_c, chosen)
 
     carry = (x, jnp.asarray(0, jnp.int32)) if cfg.n_experts else x
-    carry, ys = lax.scan(
-        body, carry, (scanned, layer_kinds(cfg), kv_cache["k"], kv_cache["v"])
-    )
+    runs = []  # one scan a stack: (k [n, ...], v [n, ...], chosen or None)
+    for scanned, lo, hi in stacks:
+        k_rows, v_rows = kv_cache["k"], kv_cache["v"]
+        if len(stacks) > 1:
+            k_rows, v_rows = k_rows[lo:hi], v_rows[lo:hi]
+        carry, ys = lax.scan(body, carry, (scanned, layer_kinds(cfg, lo, hi), k_rows, v_rows))
+        runs.append(ys)
     x = carry[0] if cfg.n_experts else carry
-    k_new, v_new = ys[0], ys[1]
+    if len(runs) == 1:
+        k_new, v_new, chosen = runs[0]
+    else:
+        k_new, v_new = (jnp.concatenate([ys[i] for ys in runs]) for i in (0, 1))
+        chosen = runs[-1][2]  # [Ls, B, T, k]: the sparse run's
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     if logits_at is not None:
         # Single-position unembed (serving prefill reads only each row's
@@ -346,7 +485,7 @@ def forward(
         B = tokens.shape[0]
         x = x[jnp.arange(B), logits_at]  # [B, D]
     out = output_logits(params, cfg, x), {"k": k_new, "v": v_new}
-    return out + (ys[2],) if routing else out
+    return out + (chosen,) if routing else out
 
 
 # -------------------------------------------------------------- entrypoints
@@ -363,7 +502,7 @@ def prefill(
 
     Returns logits [B, T, V] and the filled cache — or [B, V] (each row's
     last valid position only) with ``last_only``, the serving path's shape;
-    with ``routing`` also the experts each slot chose, [L, B, T, k].
+    with ``routing`` also the experts each slot chose, [Ls, B, T, k].
     """
     B, T = tokens.shape
     S = kv_cache["k"].shape[2]

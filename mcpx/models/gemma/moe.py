@@ -10,9 +10,17 @@ the absent experts would have added is left out: under expert parallelism
 the exchange adds the shares, and on one chip that holds every expert there
 is nothing to add.
 
+Under ``router_scoring == "sigmoid"``: ``s = sigmoid(h @ router)`` in float32
+over all experts; chosen = the k largest of ``s + bias`` (a per-expert bias
+that enters the CHOICE alone); ``w = s[chosen]``, over ``sum w + 1e-20``,
+times ``router_scale``. A shared expert
+(``d_shared_expert``) is not this file's: every token reads it, so it is a
+dense feed-forward among the scanned leaves (``model.feed_forward``), which a
+share computes beside its own routed experts' part.
+
 The expert matmuls run as a loop over the experts that at least one LIVE
 token chose, each step slicing that expert's three matrices out of the
-all-layers stacks ``[L, E, D, F]`` / ``[L, E, F, D]`` (which therefore stay
+sparse layers' stacks ``[Ls, E, D, F]`` / ``[Ls, E, F, D]`` (which therefore stay
 OUT of the layer scan's ``xs``: scanned, every layer's whole expert stack
 would be copied as the inner loop's operand). An expert no live token chose
 is never read; a pad slot or an idle row is routed nowhere, reads nothing
@@ -50,13 +58,36 @@ def split_layers(cfg: GemmaConfig, layers: dict) -> tuple[dict, dict]:
     )
 
 
-def route(x: jax.Array, router: jax.Array, cfg: GemmaConfig) -> tuple[jax.Array, jax.Array]:
-    """x [T, D], router [D, E] -> (chosen [T, k] int32, weights [T, k]
-    float32, summing to 1 a token): the softmax over every expert, in
-    float32, comes BEFORE the choice."""
+def forward_weight_bytes(cfg: GemmaConfig, params: dict) -> tuple[int, int]:
+    """(the bytes of ONE routed expert's three matrices, the bytes of every
+    other leaf a forward reads whole: attention, routers, shared experts,
+    dense layers, gains, the head). The embedding table is among them only
+    where it is the head too; untied, a forward gathers a few of its rows."""
+    stacks = [params["layers"][k] for k in EXPERT_LEAVES]
+    n_experts = stacks[0].shape[0] * stacks[0].shape[1]
+    routed = sum(a.nbytes for a in stacks)
+    rest = sum(a.nbytes for a in jax.tree.leaves(params)) - routed
+    if not cfg.tie_embeddings:
+        rest -= params["embed"].nbytes
+    return routed // n_experts, rest
+
+
+def route(
+    x: jax.Array, router: jax.Array, cfg: GemmaConfig, bias: "jax.Array | None" = None
+) -> tuple[jax.Array, jax.Array]:
+    """x [T, D], router [D, E], bias [E] float32 (sigmoid scoring with a
+    bias) -> (chosen [T, k] int32, weights [T, k] float32). The scores over
+    every expert, in float32, come BEFORE the choice; softmax weights sum to
+    1 a token, sigmoid ones to ``router_scale``."""
     logits = jnp.einsum("td,de->te", x, router, preferred_element_type=jnp.float32)
-    p_top, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
-    return chosen.astype(jnp.int32), p_top / jnp.sum(p_top, axis=-1, keepdims=True)
+    if cfg.router_scoring == "softmax":
+        p_top, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
+        return chosen.astype(jnp.int32), p_top / jnp.sum(p_top, axis=-1, keepdims=True)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = lax.top_k(scores if bias is None else scores + bias, cfg.n_experts_per_tok)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)  # the bias chose; it weighs nothing
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), w * cfg.router_scale
 
 
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
@@ -73,13 +104,16 @@ def moe_forward(
     layer: jax.Array,
     cfg: GemmaConfig,
     live: "jax.Array | None" = None,  # [B, S] bool; None = every slot
+    bias: "jax.Array | None" = None,  # [E] this layer's, into the choice
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """-> (this device's part of the layer's output [B, S, D], the counters
-    of ``moe_stats_init`` for this layer, the experts chosen [B, S, k])."""
+    """-> (this device's routed experts' part of the layer's output [B, S,
+    D] as accumulated, float32, the counters of ``moe_stats_init`` for this layer, the experts
+    chosen [B, S, k]). ``layer`` indexes the expert stacks: the SPARSE
+    layer's number."""
     B, S, D = h.shape
     T, E, k = B * S, cfg.n_experts_held, cfg.n_experts_per_tok
     x = h.reshape(T, D)
-    chosen, w = route(x, router, cfg)
+    chosen, w = route(x, router, cfg, bias)
     local = chosen - cfg.expert_first
     here = (local >= 0) & (local < E)
     if live is not None:
@@ -103,4 +137,4 @@ def moe_forward(
 
     out = lax.fori_loop(0, n_touched, one_expert, jnp.zeros((T, D), jnp.float32))
     stats = jnp.concatenate([counts, n_touched[None]])
-    return out.astype(h.dtype).reshape(B, S, D), stats, chosen.reshape(B, S, k)
+    return out.reshape(B, S, D), stats, chosen.reshape(B, S, k)

@@ -105,19 +105,30 @@ def _axis(mesh: Mesh, axis: str, dim: int) -> Optional[str]:
 def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
     """PartitionSpec pytree matching ``init_params`` output."""
     m = lambda dim: _axis(mesh, MODEL_AXIS, dim)
+    whole = lambda rank: P(*(None,) * rank)
+    attention = {
+        "pre_attn_norm": P(None, None),
+        "pre_mlp_norm": P(None, None),
+        "wq": P(None, None, m(cfg.n_heads), None),
+        "wk": P(None, None, m(cfg.n_kv_heads), None),
+        "wv": P(None, None, m(cfg.n_kv_heads), None),
+        "wo": P(None, m(cfg.n_heads), None, None),
+    }
+    # What a block beyond the default adds stays whole on every device.
+    if cfg.qk_norm:
+        attention.update(q_norm=whole(2), k_norm=whole(2))
+    if cfg.attn_gate:
+        attention["w_attn_gate"] = whole(4)
+    if cfg.post_norms:
+        attention.update(post_attn_norm=whole(2), post_mlp_norm=whole(2))
+    dense_ff = {
+        "w_gate": P(None, None, m(cfg.d_ff)),
+        "w_up": P(None, None, m(cfg.d_ff)),
+        "w_down": P(None, m(cfg.d_ff), None),
+    }
     specs = {
         "embed": P(m(cfg.vocab_size), None),
-        "layers": {
-            "pre_attn_norm": P(None, None),
-            "pre_mlp_norm": P(None, None),
-            "wq": P(None, None, m(cfg.n_heads), None),
-            "wk": P(None, None, m(cfg.n_kv_heads), None),
-            "wv": P(None, None, m(cfg.n_kv_heads), None),
-            "wo": P(None, m(cfg.n_heads), None, None),
-            "w_gate": P(None, None, m(cfg.d_ff)),
-            "w_up": P(None, None, m(cfg.d_ff)),
-            "w_down": P(None, m(cfg.d_ff), None),
-        },
+        "layers": {**attention, **dense_ff},
         "final_norm": P(None),
     }
     if cfg.n_experts:
@@ -125,12 +136,13 @@ def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
         # mesh: which experts a device holds is the configuration's
         # (expert_first / experts_held), not yet an axis of the mesh
         # (ROADMAP M4 keeps the expert axis and its exchange).
-        specs["layers"].update(
-            router=P(None, None, None),
-            w_gate=P(None, None, None, None),
-            w_up=P(None, None, None, None),
-            w_down=P(None, None, None, None),
-        )
+        specs["layers"].update(router=whole(3), w_gate=whole(4), w_up=whole(4), w_down=whole(4))
+        if cfg.router_bias_scale:
+            specs["layers"]["router_bias"] = whole(2)
+        if cfg.d_shared_expert:
+            specs["layers"].update(shared_gate=whole(3), shared_up=whole(3), shared_down=whole(3))
+        if cfg.n_dense_layers:
+            specs["dense_layers"] = {**attention, **dense_ff}
     if not cfg.tie_embeddings:
         specs["head"] = P(None, m(cfg.vocab_size))
     return specs

@@ -353,7 +353,8 @@ class Metrics:
             "mcpx_engine_moe_expert_tokens_total",
             "Live tokens routed to each expert this engine holds, summed over "
             "the sparse layers of every decode forward (pad slots and idle "
-            "rows are routed nowhere); a dense model writes no sample",
+            "rows are routed nowhere); one sample an expert held from the "
+            "weights' binding on, a dense model writes none",
             ["expert"],
             registry=self.registry,
         )

@@ -103,8 +103,13 @@ def _fed_in(cell):
             and (_CELLS_OF[m["name"]] is None or cell in _CELLS_OF[m["name"]])]
 
 
+# The cell whose sparse layers follow leading dense ones, beside a shared
+# expert: the attributes and the per-expert counter its metrics read (PR 36).
+MIXED_CELL = "trinity-mini.distinct-closed"
+
 FED = _fed_in(CELL)
 FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
+FED_MIXED = [m for m in _fed_in(MIXED_CELL) if m not in FED]
 
 
 @pytest.fixture(scope="module")
@@ -115,6 +120,11 @@ def served(tmp_path_factory):
 @pytest.fixture(scope="module")
 def served_sparse(tmp_path_factory):
     return _serve(SPARSE_CELL, tmp_path_factory)
+
+
+@pytest.fixture(scope="module")
+def served_mixed(tmp_path_factory):
+    return _serve(MIXED_CELL, tmp_path_factory)
 
 
 def _serve(cell_name, tmp_path_factory):
@@ -296,6 +306,68 @@ def test_the_layer_kind_attributes_add_up(served_sparse):
     assert len(per_expert) == experts
     assert sum(per_expert.values()) <= profile["moe_assignments"]  # the scrape came first
     assert profile["moe_expert_slots"] >= profile["moe_experts_touched"] > 0
+
+
+@pytest.mark.parametrize("metric", FED_MIXED, ids=[m["name"] for m in FED_MIXED])
+def test_the_mixed_block_feeds_its_metrics(served_mixed, metric):
+    assert {m["name"] for m in FED_MIXED} == {
+        "moe.routed_bytes_share", "moe.touched_per_sparse_layer", "moe.load_max_over_mean"}
+    v = served_mixed["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v)
+    if metric["name"] == "moe.routed_bytes_share":
+        assert 0 < v < 1
+    if metric["name"] == "moe.touched_per_sparse_layer":
+        assert 2 <= v <= 8  # a live token touches its 2 experts; a layer has 8
+    if metric["name"] == "moe.load_max_over_mean":
+        assert 1 <= v <= 8  # even routing reads 1, one expert taking all reads 8
+
+
+def test_the_mixed_blocks_attributes_count_sparse_layers_and_bytes(served_mixed):
+    """At the rehearsal size: 2 dense layers, then 6 sparse ones of 8 experts
+    held, 2 a token, beside a shared expert. The bytes are those of the
+    leaves a forward reads, reckoned here from the tree's shapes."""
+    sys.path.insert(0, REPO)
+    import jax
+    import numpy as np
+
+    spec = sys.modules["spec"]
+    cfg = spec.load_block("afmoe", CHIP_DIR).rehearsal_config(3072)
+    from mcpx.models.gemma.model import init_params
+
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    nbytes = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(tree))
+    stacks = {k: shapes["layers"][k] for k in ("w_gate", "w_up", "w_down")}
+    expert = nbytes(stacks) // (6 * 8)
+    assert expert == 3 * cfg.d_model * cfg.d_expert * 2
+    rest = nbytes(shapes) - nbytes(stacks) - nbytes(shapes["embed"])
+    segments = _segments(served_mixed)
+    assert segments
+    for sp in segments:
+        a = sp["attrs"]
+        assert a["moe_layer_forwards"] == a["forwards"] * 6
+        assert a["moe_expert_slots"] == a["forwards"] * 6 * 8
+        assert 0 < a["moe_experts_touched"] <= min(a["moe_expert_slots"], a["moe_assignments"])
+        assert a["moe_assignments"] % (2 * 6) == 0  # 2 experts a live token in each SPARSE layer
+        assert a["weight_bytes_routed"] == a["moe_experts_touched"] * expert
+        assert a["weight_bytes_read"] == a["weight_bytes_routed"] + a["forwards"] * rest
+    profile = served_mixed["health"]["engine_queue"]["worker_profile"]
+    for attr in ("moe_layer_forwards", "moe_expert_slots", "weight_bytes_routed", "weight_bytes_read"):
+        assert profile[attr] >= sum(sp["attrs"][attr] for sp in _segments_once(served_mixed)) > 0
+    per_expert = {key: v for key, v in served_mixed["ev"].counters_after["/metrics"].items()
+                  if key.startswith("mcpx_engine_moe_expert_tokens_total{")}
+    assert len(per_expert) == 8 and sum(per_expert.values()) <= profile["moe_assignments"]
+    # /costs: a token reads 2 + 1 of the 8 + 1 experts of a sparse layer, and all of a dense one
+    model = served_mixed["costs"]["model"]
+    assert model["params_held"] == cfg.n_params
+    assert model["params_held"] - model["params_active_per_token"] == 6 * 6 * 3 * cfg.d_model * cfg.d_expert
+
+
+def _segments_once(served):
+    """One engine.segment span a dispatched segment (its rows' spans agree)."""
+    seen = {}
+    for sp in _segments(served):
+        seen.setdefault(sp["attrs"].get("seq"), sp)
+    return list(seen.values())
 
 
 def _compiles(served):

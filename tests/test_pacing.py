@@ -10,7 +10,6 @@ import pytest
 
 from mcpx.engine.pacing import (
     HOST_COVER,
-    PREFILL_COVER,
     SegmentPacer,
     hold_until,
     segment_forwards,
@@ -133,7 +132,7 @@ def test_one_outlier_does_not_move_the_estimates_and_reset_forgets_the_queue():
     assert pacer.ready_at() == pytest.approx(t + 2.002 + 0.160)
 
 
-def test_periods_with_prefills_inside_bound_the_estimate_until_a_clean_one():
+def test_periods_with_prefills_inside_bound_the_estimate_like_clean_ones():
     pacer = SegmentPacer(Clock())
     pacer.dispatched(0.0, 0.002, 16)
     pacer.ready(0.100, 16)  # not pipelined (no stamp before it): clean, 98 ms
@@ -145,8 +144,7 @@ def test_periods_with_prefills_inside_bound_the_estimate_until_a_clean_one():
         pacer.ready(t + 0.080 * (k + 1), 16)
     # 80 ms over 16 forwards with a prefill inside: 5 ms bounds the stale
     # clean 6.125 from above, and no prefill cost is read off a period
-    # shorter than the clean estimate says its forwards take.
-    assert pacer._forward.value == pytest.approx(0.006125)
+    # shorter than the estimate says its forwards take.
     assert pacer.forward_s == pytest.approx(0.005)
     assert pacer.prefill_s == 0.0
     # An early exit (every row finished) reports fewer forwards than
@@ -156,19 +154,38 @@ def test_periods_with_prefills_inside_bound_the_estimate_until_a_clean_one():
     assert pacer.forward_s == pytest.approx(0.005)
 
 
+@pytest.mark.parametrize("loaded_s, want", [(0.0065, 8), (0.0037, 12)])
+def test_a_lull_is_forgotten_once_the_load_is_back(loaded_s, want):
+    """One row live reads a forward far under a full slab's (a sparse
+    block reads what its tokens touch). The estimate follows the periods
+    the device runs NOW, prefills inside or not: the lull's clean sample
+    leaves with the fifth loaded period after it, and the segment's length
+    with it (until PR 36 it stayed, and the lull a run met set the length
+    it served: 12 forwards where the worker needs 8)."""
+    pacer = SegmentPacer(Clock())
+    t = steady(pacer, 1.0, 5, 16 * 0.0037, 16)  # a lull: 3.7 ms a forward
+    assert pacer.forward_s == pytest.approx(0.0037)
+    for _ in range(5):  # callers back: an admission in front of every segment
+        pacer.admitted(t + 0.004, t + 0.015)  # 11 ms + 2 ms of host work
+        pacer.dispatched(t + 0.015, t + 0.017, 16)
+        t += 16 * loaded_s + 0.008  # its forwards and an 8 ms chain
+        pacer.ready(t, 16)
+    assert pacer.forward_s == pytest.approx(loaded_s + 0.0005)
+    assert pacer.host_s == pytest.approx(0.013)
+    assert pacer.window(4, 16) == want == 4 * math.ceil(
+        HOST_COVER * 0.013 / (4 * (loaded_s + 0.0005))
+    )
+
+
 # ------------------------------------------------- the segment's length
 # A tick of 4 forwards under a window of 16; a clean forward of 8 ms, so a
 # tick is 32 ms of device time.
-SIZED = dict(tick=4, ceiling=16, forward_s=0.008, prefill_s=0.0, host_s=0.0)
+SIZED = dict(tick=4, ceiling=16, forward_s=0.008, host_s=0.0)
 
 
 def _host(ticks: float) -> float:
     """Host work a segment that ``HOST_COVER`` turns into ``ticks`` ticks."""
     return ticks * 0.032 / HOST_COVER
-
-
-def _chain(ticks: float) -> float:
-    return ticks * 0.032 / PREFILL_COVER
 
 
 @pytest.mark.parametrize(
@@ -180,13 +197,13 @@ def _chain(ticks: float) -> float:
         ({"host_s": _host(1.6)}, 8),  # the host's work sets the floor
         ({"host_s": _host(2.0)}, 8),  # exactly covered: no tick more
         ({"host_s": _host(2.1)}, 12),
-        ({"prefill_s": _chain(1.3)}, 8),  # the prefill chain sets the floor
-        ({"host_s": _host(0.5), "prefill_s": _chain(2.5)}, 12),  # the larger
-        ({"host_s": _host(2.5), "prefill_s": _chain(0.5)}, 12),  # of the two
+        ({"host_s": _host(1.3)}, 8),
+        ({"host_s": _host(2.5)}, 12),
+        ({"host_s": _host(3.0)}, 12),
         ({"host_s": _host(9.0)}, 16),  # never above the ceiling
-        ({"prefill_s": _chain(40.0)}, 16),
+        ({"host_s": _host(40.0)}, 16),
         ({}, 4),  # nothing to cover: never under one tick
-        ({"host_s": _host(0.2), "prefill_s": _chain(0.9)}, 4),
+        ({"host_s": _host(0.2)}, 4),
         ({"forward_s": 0.016, "host_s": _host(1.6)}, 4),  # a slower forward covers sooner
         ({"forward_s": 0.004, "host_s": _host(1.6)}, 16),
         ({"ceiling": 4, "host_s": _host(3.0)}, 4),  # steps_per_dispatch 1: the tick
@@ -204,13 +221,12 @@ def test_segment_length_is_whole_ticks_between_one_tick_and_the_window(tick, ste
     seen = set()
     for forward_ms in (0.5, 2, 6.35, 12.76, 15.4, 40):
         for host_ms in (0, 1, 10, 26, 60, 400):
-            for prefill_ms in (0, 5, 15, 50, 99):
-                n = segment_forwards(
-                    tick=tick, ceiling=ceiling, forward_s=forward_ms / 1e3,
-                    prefill_s=prefill_ms / 1e3, host_s=host_ms / 1e3,
-                )
-                assert tick <= n <= ceiling and n % tick == 0
-                seen.add(n)
+            n = segment_forwards(
+                tick=tick, ceiling=ceiling, forward_s=forward_ms / 1e3,
+                host_s=host_ms / 1e3,
+            )
+            assert tick <= n <= ceiling and n % tick == 0
+            seen.add(n)
     assert tick in seen and ceiling in seen  # both ends are reached
 
 
@@ -224,7 +240,7 @@ def test_the_pacer_sizes_the_segment_from_what_the_worker_reported():
     assert pacer.host_s == pytest.approx(0.022)
     want = 4 * math.ceil(HOST_COVER * 0.022 / 0.040)
     assert pacer.window(4, 64) == want == segment_forwards(
-        tick=4, ceiling=64, forward_s=0.010, prefill_s=0.0, host_s=0.022
+        tick=4, ceiling=64, forward_s=0.010, host_s=0.022
     )
     pacer.harvested(t + 0.030, t + 0.050)  # the harvest's bookkeeping counts
     assert pacer.host_s == pytest.approx(0.042)
@@ -235,3 +251,24 @@ def test_the_pacer_sizes_the_segment_from_what_the_worker_reported():
     # The length it was told is the length it predicts with.
     pacer.dispatched(t + 0.051, t + 0.053, longer)
     assert pacer.ready_at() == pytest.approx(t + 0.160 + longer * 0.010)
+
+
+@pytest.mark.parametrize("chain_s", [0.012, 0.100, 0.400])
+def test_a_prefill_chain_enters_the_prediction_and_not_the_length(chain_s):
+    """The chain in front of a segment delays its predicted ready time; it
+    does not lengthen the segment (until PR 36 a segment had to outlast the
+    chain, and a chain that grows with the rows a long segment retires
+    then kept the segment long)."""
+    pacer = SegmentPacer(Clock())
+    t = steady(pacer, 1.0, 4, 0.080, 8)  # 10 ms a forward; a dispatch 2 ms
+    pacer.admitted(t + 0.004, t + 0.014)  # 10 ms + 2 ms of host work
+    pacer.dispatched(t + 0.014, t + 0.016, 8)
+    pacer.ready(t + 0.080, 8)
+    pacer.ready(t + 0.160 + chain_s, 8)  # its period held the chain
+    assert pacer.prefill_s == pytest.approx(chain_s)
+    assert pacer.window(4, 16) == 4 == 4 * math.ceil(HOST_COVER * 0.012 / 0.040)
+    t += 0.160 + chain_s
+    pacer.dispatched(t - 0.010, t - 0.008, 8)  # in flight at that stamp
+    pacer.admitted(t + 0.002, t + 0.012)
+    pacer.dispatched(t + 0.012, t + 0.014, 4)
+    assert pacer.ready_at() == pytest.approx(t + 0.080 + chain_s + 0.040)
