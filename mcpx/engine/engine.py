@@ -546,6 +546,10 @@ class InferenceEngine:
         # queue_stats()'s worker_profile.
         self._hold_joined = 0  # mcpx: owner[engine-worker]
         self._hold_joined_total = 0  # mcpx: owner[engine-worker, atomic]
+        # The expert counters of their prefills (a sparse model's), still
+        # on the device: the next segment's lagged harvest fetches them
+        # with its own flags, in the same device_get.
+        self._prefill_moe: list = []  # mcpx: owner[engine-worker]
         # Lifetime sums of the decode segments' lengths as dispatched and
         # of the configured ceiling (worker_profile, like the total above).
         self._window_total = 0  # mcpx: owner[engine-worker, atomic]
@@ -885,6 +889,7 @@ class InferenceEngine:
             self.costs.release()
             self._stack_cache = None  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
             self._inflight.clear()  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
+            self._prefill_moe.clear()  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
             self._pending_admissions.clear()  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
             self._dfa_cache.clear()  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
             self._prefix_cache.drop_all()  # mcpx: ignore[thread-ownership] - worker joined (guard above); cached KV dies with the pools
@@ -1633,7 +1638,7 @@ class InferenceEngine:
         # Compile the executable serving will dispatch for this
         # bucket: ring buckets warm the ring route, not a dense
         # executable serving would never run.
-        last, k_p, v_p = self._jit_prefill(
+        last, k_p, v_p, _ = self._jit_prefill(
             self._params,
             self._put(tokens, self._row_spec(A, 1)),
             self._put(seq_lens, self._row_spec(A)),
@@ -1647,7 +1652,7 @@ class InferenceEngine:
         if ecfg.prefix_cache:
             # Shared-prefix serving prefills SUFFIXES through the
             # chunked path; compile it for the same buckets.
-            last, k_p, v_p = self._jit_suffix_prefill(
+            last, k_p, v_p, _ = self._jit_suffix_prefill(
                 self._params,
                 self._put(tokens, self._row_spec(A, 1)),
                 self._put(seq_lens, self._row_spec(A)),
@@ -2410,8 +2415,13 @@ class InferenceEngine:
             last, dense = ring_prefill(
                 params, cfg, tokens, seq_lens, self._seq_mesh, dense, last_only=True
             )
+            moe = None
         else:
-            last, dense = prefill(params, cfg, tokens, seq_lens, dense, last_only=True)
+            # A sparse model's expert counters (moe_stats_init); None from a
+            # dense one, which adds no output to its executable.
+            last, dense, moe = prefill(
+                params, cfg, tokens, seq_lens, dense, last_only=True, moe_stats=True
+            )
         paged = commit_prefill_to_pages(
             {"k": paged_k, "v": paged_v},
             dense,
@@ -2419,7 +2429,7 @@ class InferenceEngine:
             seq_lens,
             self.config.engine.kv_page_size,
         )
-        return last, paged["k"], paged["v"]
+        return last, paged["k"], paged["v"], moe
 
     def _ring_ok(self, T: int) -> bool:
         """True when a ``T``-token full prefill should take the ring route:
@@ -2449,7 +2459,7 @@ class InferenceEngine:
         ``q_lens``, so short-suffix rows (warm replans prefilling ~1 page)
         stream pages for their own width, not the cohort bucket's."""
         cfg = self.model_cfg
-        last, kv = decode_chunk_paged(
+        last, kv, moe = decode_chunk_paged(
             params,
             cfg,
             tokens,
@@ -2461,8 +2471,9 @@ class InferenceEngine:
             mesh=self._mesh,
             logits_at=seq_lens - 1,  # [A, V]: suffix-final logits only
             q_lens=seq_lens,
+            moe_stats=True,  # as _prefill_impl: None from a dense model
         )
-        return last, kv["k"], kv["v"]
+        return last, kv["k"], kv["v"], moe
 
     # --- tiered KV cache: device<->host page-run copies -------------------
     def _spill_gather_impl(self, paged_k, paged_v, pages):
@@ -2799,7 +2810,7 @@ class InferenceEngine:
         try:
             if n > 0:
                 # Continue from the resident head: prefill only [n, P).
-                last, k_p, v_p = self._jit_suffix_prefill(
+                last, k_p, v_p, _ = self._jit_suffix_prefill(
                     self._params,
                     self._put(tokens, self._row_spec(1, 1)),
                     self._put(np.asarray([R], np.int32), self._row_spec(1)),
@@ -2821,7 +2832,7 @@ class InferenceEngine:
                 use_ring = self._ring_ok(T)
                 if use_ring:
                     self.metrics.ring_prefills.inc()
-                last, k_p, v_p = self._jit_prefill(
+                last, k_p, v_p, _ = self._jit_prefill(
                     self._params,
                     self._put(tokens, self._row_spec(1, 1)),
                     self._put(np.asarray([R], np.int32), self._row_spec(1)),
@@ -4539,7 +4550,7 @@ class InferenceEngine:
                 # (decode_chunk_paged's contract) — a matched prefix's
                 # FLOPs are paid once per resident tree path, not per
                 # request.
-                last_logits, k_p, v_p = self._jit_suffix_prefill(
+                last_logits, k_p, v_p, moe_d = self._jit_suffix_prefill(
                     self._params,
                     tokens_d,
                     lens_d,
@@ -4568,7 +4579,7 @@ class InferenceEngine:
                 use_ring = self._ring_ok(T)
                 if use_ring:
                     self.metrics.ring_prefills.inc()
-                last_logits, k_p, v_p = self._jit_prefill(
+                last_logits, k_p, v_p, moe_d = self._jit_prefill(
                     self._params,
                     tokens_d,
                     lens_d,
@@ -4583,6 +4594,8 @@ class InferenceEngine:
             # Pools were donated to prefill: point at the live buffers
             # immediately so an exception below can't leave stale handles.
             self._paged_kv = {"k": k_p, "v": v_p}
+            if moe_d is not None:
+                self._prefill_moe.append(moe_d)
             # The cohort prefill that writes this admission's inserted
             # radix nodes is dispatched: seal them — later dispatches are
             # device-ordered behind the writes, so they may now match.
@@ -4876,6 +4889,7 @@ class InferenceEngine:
         prefill_rows, self._rows_admitted = self._rows_admitted, 0
         hold_joined, self._hold_joined = self._hold_joined, 0
         self._hold_joined_total += hold_joined
+        prefill_moe, self._prefill_moe = self._prefill_moe, []
         t_submit = self._pacer.clock()
         # A step event in a profiler trace: the segment's device ops carry
         # its step_num, which is the engine.segment spans' ``seq``.
@@ -5000,16 +5014,25 @@ class InferenceEngine:
                 window,
                 window_max,
                 # The layer kinds' counters of this segment (None for a
-                # model of dense, full layers): _layer_kind_attrs.
+                # model of dense, full layers) and the expert counters of
+                # the prefills in front of it: _layer_kind_attrs.
                 kinds_d,
+                prefill_moe,
             )
         )
 
-    def _layer_kind_attrs(self, counts: np.ndarray, n_fwd: int) -> dict[str, int]:
+    def _layer_kind_attrs(
+        self, counts: np.ndarray, n_fwd: int, prefills: "list[np.ndarray]"
+    ) -> dict[str, int]:
         """One harvested segment's layer-kind counters (the vector
         ``_segment_impl`` appends) as engine.segment attributes, identical
         on the segment's rows, and into the lifetime sums and the per-expert
-        counter. Sparse feed-forward: ``moe_assignments`` (live tokens times
+        counter. ``prefills``: the expert counters of the admission prefills
+        dispatched in front of the segment (``moe_stats_init``, one vector
+        each), as ``moe_prefill_assignments`` (their live tokens times the
+        experts each chose here, over the sparse layers) and
+        ``moe_prefill_rows`` (the rows those layers multiplied by an
+        expert's matrices for them). Sparse feed-forward: ``moe_assignments`` (live tokens times
         the experts each chose here, over the segment's forwards and sparse
         layers), ``moe_experts_touched`` ((forward, layer, expert) triples
         with at least one live token: the experts whose weights were read)
@@ -5034,6 +5057,8 @@ class InferenceEngine:
             expert_bytes, rest_bytes = self._weight_bytes
             attrs["weight_bytes_routed"] = attrs["moe_experts_touched"] * expert_bytes
             attrs["weight_bytes_read"] = attrs["weight_bytes_routed"] + n_fwd * rest_bytes
+            attrs["moe_prefill_assignments"] = sum(int(c[:E].sum()) for c in prefills)
+            attrs["moe_prefill_rows"] = sum(int(c[E + 1]) for c in prefills)
             for i in np.flatnonzero(per_expert):
                 self.metrics.moe_expert_tokens.labels(expert=str(mc.expert_first + int(i))).inc(
                     int(per_expert[i])
@@ -5100,7 +5125,7 @@ class InferenceEngine:
             (
                 done_d, e_d, buf_d, nfwd_d, gen_snap, t_disp, spec_h, cons_snap,
                 seg_cost, seg_name, seq, prefill_rows, hold_joined,
-                window, window_max, kinds_d,
+                window, window_max, kinds_d, prefill_moe,
             ) = self._inflight.popleft()
             # ONE combined fetch (flags + out_buf): a blocking fetch costs
             # its round trip, not the ~24KB of buffer — splitting into
@@ -5119,15 +5144,17 @@ class InferenceEngine:
                         (done_d, e_d, buf_d, nfwd_d) + spec_h
                     )
                 elif kinds_d is not None:
-                    done, e, buf, n_fwd, kind_counts = jax.device_get(
-                        (done_d, e_d, buf_d, nfwd_d, kinds_d)
+                    done, e, buf, n_fwd, kind_counts, prefill_counts = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d, kinds_d, prefill_moe)
                     )
                 else:
                     done, e, buf, n_fwd = jax.device_get(
                         (done_d, e_d, buf_d, nfwd_d)
                     )
             kind_attrs = (
-                self._layer_kind_attrs(kind_counts, int(n_fwd)) if kinds_d is not None else {}
+                self._layer_kind_attrs(kind_counts, int(n_fwd), prefill_counts)
+                if kinds_d is not None
+                else {}
             )
             timeline = {}
             # The segment's ready stamp: the fetch has just returned. The
@@ -5408,6 +5435,7 @@ class InferenceEngine:
         # rows are failed right here, nothing left to harvest).
         slab.dev = None
         self._inflight.clear()
+        self._prefill_moe.clear()
         self._pacer.reset()
         self._dirty_rows.clear()
         self._pending_admissions.clear()

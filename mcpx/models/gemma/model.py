@@ -30,7 +30,7 @@ import numpy as np
 from jax import lax
 
 from mcpx.models.gemma.config import GemmaConfig
-from mcpx.models.gemma.moe import activation, moe_forward, split_layers
+from mcpx.models.gemma.moe import activation, moe_forward, moe_stats_init, split_layers
 
 Params = dict[str, Any]
 KVCache = dict[str, jax.Array]
@@ -429,6 +429,7 @@ def forward(
     logits_at: "jax.Array | None" = None,
     live: "jax.Array | None" = None,
     routing: bool = False,
+    moe_stats: bool = False,
 ) -> tuple:
     """Core forward over a [B, T] token chunk against a [L, B, S, K, hd]
     cache. ``positions`` are absolute (double as cache write slots);
@@ -437,7 +438,8 @@ def forward(
     ``logits_at`` [B]: unembed only that position per row -> [B, V].
     ``live`` [B, T]: the slots that are tokens and not padding; a sparse
     feed-forward routes the others nowhere. ``routing``: also return the
-    experts chosen in the sparse layers, [Ls, B, T, k]."""
+    experts chosen in the sparse layers, [Ls, B, T, k]. ``moe_stats``: also
+    the forward's expert counters (``moe_stats_init``), before ``routing``'s."""
     from mcpx.models.gemma.quant import dequant_layer
 
     # Weight-only int8 serving mode (quant.py): identity plumbing on plain
@@ -452,16 +454,19 @@ def forward(
     def body(carry, scanned):
         lp, kind, k_c, v_c = scanned
         lp = dequant_layer(lp, dtype)
-        # A sparse model's carry counts the layers: the expert stacks are
-        # sliced by it. A dense layer's ``chosen`` is None.
-        x, layer = carry if cfg.n_experts else (carry, None)
+        # A sparse model's carry counts the layers (the expert stacks are
+        # sliced by it) and sums the expert counters. A dense layer's
+        # ``layer_stats`` and ``chosen`` are None.
+        x, layer, stats = carry if cfg.n_experts else (carry, None, None)
         moe = (experts, sparse_index(cfg, layer), live) if cfg.n_experts else None
-        x, k_c, v_c, _stats, chosen = _layer(
+        x, k_c, v_c, layer_stats, chosen = _layer(
             x, lp, k_c, v_c, positions, mask, positions, cfg, attend_fn, kind, moe
         )
-        return ((x, layer + 1) if cfg.n_experts else x), (k_c, v_c, chosen)
+        if layer_stats is not None:
+            stats = stats + layer_stats
+        return ((x, layer + 1, stats) if cfg.n_experts else x), (k_c, v_c, chosen)
 
-    carry = (x, jnp.asarray(0, jnp.int32)) if cfg.n_experts else x
+    carry = (x, jnp.asarray(0, jnp.int32), moe_stats_init(cfg)) if cfg.n_experts else x
     runs = []  # one scan a stack: (k [n, ...], v [n, ...], chosen or None)
     for scanned, lo, hi in stacks:
         k_rows, v_rows = kv_cache["k"], kv_cache["v"]
@@ -485,7 +490,8 @@ def forward(
         B = tokens.shape[0]
         x = x[jnp.arange(B), logits_at]  # [B, D]
     out = output_logits(params, cfg, x), {"k": k_new, "v": v_new}
-    return out + (chosen,) if routing else out
+    stats = carry[2] if cfg.n_experts else None  # as decode_chunk_paged: None from a dense model
+    return out + ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
 
 
 # -------------------------------------------------------------- entrypoints
@@ -497,12 +503,14 @@ def prefill(
     kv_cache: KVCache,
     last_only: bool = False,
     routing: bool = False,
+    moe_stats: bool = False,
 ) -> tuple:
     """Prefill a padded [B, T] batch. ``seq_lens`` [B] masks right-padding.
 
     Returns logits [B, T, V] and the filled cache — or [B, V] (each row's
     last valid position only) with ``last_only``, the serving path's shape;
-    with ``routing`` also the experts each slot chose, [Ls, B, T, k].
+    with ``moe_stats`` also the forward's expert counters, with ``routing``
+    also the experts each slot chose, [Ls, B, T, k].
     """
     B, T = tokens.shape
     S = kv_cache["k"].shape[2]
@@ -516,6 +524,7 @@ def prefill(
         logits_at=seq_lens - 1 if last_only else None,
         live=positions < seq_lens[:, None],
         routing=routing,
+        moe_stats=moe_stats,
     )
 
 

@@ -24,9 +24,25 @@ sparse layers' stacks ``[Ls, E, D, F]`` / ``[Ls, E, F, D]`` (which therefore sta
 OUT of the layer scan's ``xs``: scanned, every layer's whole expert stack
 would be copied as the inner loop's operand). An expert no live token chose
 is never read; a pad slot or an idle row is routed nowhere, reads nothing
-and counts in no counter. Each step multiplies every slot of the window by
-its expert (a slot that did not choose it has weight 0): at decode widths
-the step is bound by the expert's 12 MB of weights, not by the slots.
+and counts in no counter.
+
+Which rows a step multiplies is read off the window's width ``T = B x S``,
+static at trace time, against the chip's ridge: peak FLOP/s over HBM bytes/s,
+about 240 bf16 rows on a v5e (197e12 / 819e9, ``telemetry/costs.py``), the
+width at which multiplying a row by an expert costs what fetching the
+expert's 12 MB does. Up to ``RIDGE_SLOTS`` (a decode segment's 64 slots, a
+cohort of one's 128) a step multiplies every slot of the window by its
+expert, a slot that did not choose it with weight 0: the step is bound by
+the expert's bytes and the slots ride free. Past it (the admission cohort's
+8 x 128 = 1,024 slots, the suffix prefill at that width) that product is
+compute-bound and mostly zeros, so the live (token, expert) assignments are
+sorted by expert, their rows of ``x`` gathered in that order, and a step
+multiplies one tile of ``GROUP_TILE`` sorted rows by the expert that owns
+them: ``sum_e ceil(count_e / GROUP_TILE)`` steps, every touched expert read
+once a tile, no row multiplied by an expert it did not choose but the tail
+of an expert's last tile. What is computed is the same, term for term; only
+the order of the float32 sum over a token's experts may differ. No expert
+has a capacity: a crowded one takes more tiles.
 """
 
 from __future__ import annotations
@@ -93,8 +109,68 @@ def route(
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     """The counters a forward adds to: tokens per held expert ``[E_held]``
     (summed over layers), then the number of (layer, expert) pairs with at
-    least one live token."""
-    return jnp.zeros((cfg.n_experts_held + 1,), jnp.int32)
+    least one live token, then the rows multiplied by an expert's matrices
+    (the window's slots a step of the loop, a tile's a grouped step)."""
+    return jnp.zeros((cfg.n_experts_held + 2,), jnp.int32)
+
+
+# The widest window whose expert steps multiply every slot: the v5e's ridge
+# (~240 rows, the module docstring), rounded to the bucket above it.
+RIDGE_SLOTS = 256
+# Sorted rows a grouped step multiplies. A tile wider than the usual group
+# pays for rows it masks, and a narrower one reads a crowded expert's
+# weights once more a tile: on a v5e at 1,024 slots 64 read fastest at the
+# cohorts the cells admit (about two prompts of 80-90 tokens in an 8 x 128
+# window) and no slower than the loop with every slot live (PERF.md, PR 38).
+GROUP_TILE = 64
+
+
+def _expert_rows(cfg: GemmaConfig, rows: jax.Array, experts: dict, layer, e) -> jax.Array:
+    """rows [R, D] through expert ``e`` of sparse layer ``layer``, float32
+    [R, D]: gate and up in the rows' type, down accumulated in float32."""
+    D, F = rows.shape[1], cfg.d_expert
+    w_gate = lax.dynamic_slice(experts["w_gate"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
+    w_up = lax.dynamic_slice(experts["w_up"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
+    w_down = lax.dynamic_slice(experts["w_down"], (layer, e, 0, 0), (1, 1, F, D))[0, 0]
+    a = activation(cfg, jnp.einsum("td,df->tf", rows, w_gate)) * jnp.einsum("td,df->tf", rows, w_up)
+    return jnp.einsum("tf,fd->td", a, w_down, preferred_element_type=jnp.float32)
+
+
+def _grouped_experts(
+    cfg: GemmaConfig, x, experts: dict, layer, local, here, w, counts
+) -> tuple[jax.Array, jax.Array]:
+    """The routed experts' output for a window past the ridge -> (float32
+    [T, D], rows multiplied). ``local`` [T, k] the chosen experts as held
+    here, ``here`` [T, k] the assignments that count, ``w`` their weights,
+    ``counts`` [E] assignments an expert."""
+    (T, D), k, E, C = x.shape, cfg.n_experts_per_tok, cfg.n_experts_held, GROUP_TILE
+    N = T * k
+    # Assignments by expert, those that count nowhere last; ``place`` is
+    # where an assignment went.
+    order = jnp.argsort(jnp.where(here, local, E).reshape(N), stable=True).astype(jnp.int32)
+    place = jnp.zeros((N,), jnp.int32).at[order].set(jnp.arange(N, dtype=jnp.int32))
+    # A group's last tile runs up to C rows past it: into the next group's
+    # rows, or this padding.
+    xs = jnp.concatenate([x[order // k], jnp.zeros((C, D), x.dtype)])
+    # step -> (expert, first sorted row), for sum_e ceil(count_e / C) steps.
+    first = jnp.cumsum(counts) - counts
+    tiles = (counts + C - 1) // C
+    tile_end = jnp.cumsum(tiles)
+    steps = jnp.arange(E + N // C, dtype=jnp.int32)  # more than any routing takes
+    step_e = jnp.minimum(jnp.sum(steps[:, None] >= tile_end, axis=1, dtype=jnp.int32), E - 1)
+    step_row = first[step_e] + (steps - (tile_end[step_e] - tiles[step_e])) * C
+
+    def one_tile(i, ys):
+        # Steps run in the order of their rows, so what a tile writes past
+        # its group is overwritten by the group that owns those rows.
+        rows = lax.dynamic_slice(xs, (step_row[i], 0), (C, D))
+        y = _expert_rows(cfg, rows, experts, layer, step_e[i])
+        return lax.dynamic_update_slice(ys, y, (step_row[i], 0))
+
+    n_steps = tile_end[-1]
+    ys = lax.fori_loop(0, n_steps, one_tile, jnp.zeros((N + C, D), jnp.float32))
+    weighed = ys[place].reshape(T, k, D) * jnp.where(here, w, 0.0)[:, :, None]
+    return jnp.sum(weighed, axis=1), n_steps * C
 
 
 def moe_forward(
@@ -119,22 +195,21 @@ def moe_forward(
     if live is not None:
         here &= live.reshape(T, 1)
     onehot = here[:, :, None] & (local[:, :, None] == jnp.arange(E, dtype=jnp.int32))
-    combine = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1).T  # [E, T]
     counts = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [E]
     touched = counts > 0
     n_touched = jnp.sum(touched, dtype=jnp.int32)
-    order = jnp.argsort(~touched, stable=True).astype(jnp.int32)  # touched first
-    F = cfg.d_expert
+    if T > RIDGE_SLOTS:
+        out, rows = _grouped_experts(cfg, x, experts, layer, local, here, w, counts)
+    else:
+        combine = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1).T  # [E, T]
+        order = jnp.argsort(~touched, stable=True).astype(jnp.int32)  # touched first
 
-    def one_expert(i, acc):
-        e = order[i]
-        w_gate = lax.dynamic_slice(experts["w_gate"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
-        w_up = lax.dynamic_slice(experts["w_up"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
-        w_down = lax.dynamic_slice(experts["w_down"], (layer, e, 0, 0), (1, 1, F, D))[0, 0]
-        a = activation(cfg, jnp.einsum("td,df->tf", x, w_gate)) * jnp.einsum("td,df->tf", x, w_up)
-        y = jnp.einsum("tf,fd->td", a, w_down, preferred_element_type=jnp.float32)
-        return acc + y * lax.dynamic_index_in_dim(combine, e, 0, keepdims=False)[:, None]
+        def one_expert(i, acc):
+            e = order[i]
+            y = _expert_rows(cfg, x, experts, layer, e)
+            return acc + y * lax.dynamic_index_in_dim(combine, e, 0, keepdims=False)[:, None]
 
-    out = lax.fori_loop(0, n_touched, one_expert, jnp.zeros((T, D), jnp.float32))
-    stats = jnp.concatenate([counts, n_touched[None]])
+        out = lax.fori_loop(0, n_touched, one_expert, jnp.zeros((T, D), jnp.float32))
+        rows = n_touched * T
+    stats = jnp.concatenate([counts, n_touched[None], rows[None]])
     return out.reshape(B, S, D), stats, chosen.reshape(B, S, k)

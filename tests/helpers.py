@@ -104,3 +104,36 @@ def count_compiles(substring: str):
         jax.config.update("jax_log_compiles", old_flag)
         logger.removeHandler(handler)
         logger.setLevel(old_level)
+
+
+def grouped_against_loop(cfg, h, router, experts, layer, live=None, bias=None):
+    """``moe_forward`` at a window past the ridge, by the grouped form it
+    takes there and by the loop it would take below (the ridge lifted out
+    of the way): outputs within the float32 sum's tolerance, every counter
+    but the rows and the experts chosen identical, pad slots exactly 0.
+    -> (the grouped form's stats, the loop's), for what the case adds."""
+    import numpy as np
+    import pytest
+
+    from mcpx.models.gemma import moe
+
+    B, S, _ = h.shape
+    T, E = B * S, cfg.n_experts_held
+    assert T > moe.RIDGE_SLOTS
+    out_g, stats_g, chosen_g = moe.moe_forward(h, router, experts, layer, cfg, live, bias)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "RIDGE_SLOTS", T)
+        out_l, stats_l, chosen_l = moe.moe_forward(h, router, experts, layer, cfg, live, bias)
+    out_g, out_l, stats_g, stats_l = (np.asarray(a) for a in (out_g, out_l, stats_g, stats_l))
+    assert np.abs(out_l).max() > 0
+    np.testing.assert_allclose(out_g, out_l, rtol=1e-5, atol=1e-5 * np.abs(out_l).max())
+    assert stats_g[: E + 1].tolist() == stats_l[: E + 1].tolist()  # counts, touched
+    assert (np.asarray(chosen_g) == np.asarray(chosen_l)).all()
+    if live is not None:
+        assert (out_g[~np.asarray(live)] == 0).all()
+    # the loop multiplied every slot by every touched expert; the grouped
+    # form whole tiles, no fewer rows than assignments and far fewer than that
+    assert stats_l[E + 1] == T * stats_l[E]
+    assert stats_g[E + 1] % moe.GROUP_TILE == 0 and stats_g[:E].sum() <= stats_g[E + 1]
+    assert stats_g[E + 1] < stats_g[:E].sum() + moe.GROUP_TILE * stats_g[E]
+    return stats_g, stats_l

@@ -28,6 +28,7 @@ from mcpx.models.gemma.model import (
     apply_rope, feed_forward_residual, gated_mlp, init_kv_cache, init_params, layer_kinds, prefill,
 )
 from mcpx.parallel.mesh import make_mesh, param_pspecs
+from tests.helpers import grouped_against_loop
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
@@ -192,6 +193,41 @@ def test_the_bias_chooses_and_weighs_nothing():
     assert 0.15 < changed < 0.7, changed
 
 
+@pytest.mark.parametrize("case", [
+    "every_slot_live", "pads_and_idle_rows", "the_bias_crowds_one_expert",
+    "the_bias_keeps_every_token_off_one", "a_strict_share_held",
+])
+def test_past_the_ridge_the_grouped_form_computes_what_the_loop_does(case):
+    """4 x 96 = 384 slots, past the ridge of 256: sigmoid scoring, the bias
+    in the choice alone, so a bias of +-10 steers every token's choice and
+    weighs nothing."""
+    cfg = small()
+    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    experts = {k: layers[k] for k in moe.EXPERT_LEAVES}
+    router, bias = layers["router"][2], layers["router_bias"][2]
+    h = jax.random.normal(jax.random.PRNGKey(12), (4, 96, 64), jnp.float32)
+    live, E = None, 8
+    if case == "pads_and_idle_rows":
+        live = jnp.arange(96)[None, :] < jnp.asarray([90, 3, 0, 96])[:, None]
+    if case == "the_bias_crowds_one_expert":
+        bias = bias.at[3].set(10.0)
+    if case == "the_bias_keeps_every_token_off_one":
+        bias = bias.at[5].set(-10.0)
+    if case == "a_strict_share_held":
+        cfg, E = dataclasses.replace(cfg, expert_first=2, experts_held=4), 4
+        experts = {k: v[:, 2:6] for k, v in experts.items()}
+    grouped, _ = grouped_against_loop(cfg, h, router, experts, jnp.int32(2), live, bias)
+    n_live = 384 if live is None else 189
+    if case == "a_strict_share_held":
+        assert 0 < grouped[:E].sum() < 2 * n_live
+    else:
+        assert grouped[:E].sum() == 2 * n_live
+    if case == "the_bias_crowds_one_expert":
+        assert grouped[3] == 384 and grouped[E + 1] >= 384 + moe.GROUP_TILE
+    if case == "the_bias_keeps_every_token_off_one":
+        assert grouped[5] == 0 and grouped[E] == 7
+
+
 def _ff_branch(cfg, lp, experts, h):
     """The feed-forward branch of sparse layer 1 at ``h`` (no norm on it)."""
     x = jnp.zeros_like(h)
@@ -325,8 +361,11 @@ def test_the_engine_serves_the_same_tokens_at_every_segment_length():
     budgets that retire rows mid-segment, decode byte-identical tokens at
     each length on one engine of this block, and nothing compiles between
     them. Every expert held has a sample on the per-expert counter from the
-    weights' binding on, before a token is routed."""
+    weights' binding on, before a token is routed. The admission prefills'
+    expert counters come back in the harvest's own fetch: the worker makes
+    one ``device_get`` a harvested segment, as before them."""
     import asyncio
+    import threading
 
     from mcpx.engine.engine import InferenceEngine
     from mcpx.engine.pacing import SegmentPacer
@@ -350,9 +389,16 @@ def test_the_engine_serves_the_same_tokens_at_every_segment_length():
                    "warmup_compile": True, "warmup_max_len": 64},
     })
 
+    fetches, device_get = [], jax.device_get
+
+    def counted(tree):
+        fetches.append(threading.current_thread().name)
+        return device_get(tree)
+
     async def go():
         eng = InferenceEngine(config, model_cfg=small(max_seq_len=256))
         await eng.start()
+        jax.device_get = counted
         try:
             samples = eng.metrics.moe_expert_tokens._metrics
             assert len(samples) == 8 and all(c._value.get() == 0 for c in samples.values())
@@ -374,7 +420,20 @@ def test_the_engine_serves_the_same_tokens_at_every_segment_length():
                 assert set(pacer.lengths) == {n} and compiles() == snap, (n, pacer.lengths)
             assert all(got[4]) and got[4] == got[8] == got[12] == got[16]
             assert sum(c._value.get() for c in samples.values()) > 0
+            # 20 prompts were admitted: 2 experts a live token in each of 6
+            # sparse layers, and under the ridge every slot of a cohort's
+            # window by every touched expert.
+            totals = eng._layer_kind_totals
+            assert totals["moe_prefill_assignments"] == 2 * 6 * 4 * sum(map(len, prompts))
+            assert totals["moe_prefill_rows"] > totals["moe_prefill_assignments"]
+            for _ in range(200):  # the worker harvests the last segment in its own time
+                if not eng._inflight:
+                    break
+                await asyncio.sleep(0.05)
+            assert not eng._inflight and not eng._prefill_moe
+            assert fetches.count("mcpx-engine") == eng._dispatch_seq > 0
         finally:
+            jax.device_get = device_get
             await eng.aclose()
 
     asyncio.run(go())

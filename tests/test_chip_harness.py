@@ -242,13 +242,14 @@ def test_every_metric_is_fed_here_or_left_out_by_its_readers_name(served):
     assert all(m["reader"] in served["found"] for m in METRICS)
     assert len(FED) >= 17 and NOT_FED_HERE <= set(served["found"])
     assert {m["name"] for m in FED_SPARSE} == {
-        "moe.experts_touched_share", "moe.tok_per_touched_expert", "attn.rows_past_window_share"}
+        "moe.experts_touched_share", "moe.tok_per_touched_expert", "attn.rows_past_window_share",
+        "moe.prefill_rows_per_assignment"}
 
 
 # The engine.segment attributes that only a block with sparse experts or
 # windowed layers writes; a dense block writes none of them.
 LAYER_KIND_ATTRS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
-                    "rows_past_window", "rows_live")
+                    "rows_past_window", "rows_live", "moe_prefill_assignments", "moe_prefill_rows")
 
 
 def _segments(served):
@@ -299,8 +300,20 @@ def test_the_layer_kind_attributes_add_up(served_sparse):
         assert a["moe_assignments"] % (k * layers) == 0
         assert a["moe_assignments"] // (k * layers) >= a["tokens"]
         assert 0 < a["rows_live"] <= 8 and a["rows_past_window"] == a["rows_live"]
+        # the admission prefills in front of the segment: none, or k experts a
+        # prompt token in each layer, multiplied in whole tiles of 64 rows
+        assert a["moe_prefill_assignments"] % (k * layers) == 0
+        assert a["moe_prefill_rows"] % 64 == 0 and a["moe_prefill_rows"] >= a["moe_prefill_assignments"]
+        assert (a["moe_prefill_assignments"] > 0) == (a["prefill_rows"] > 0)
     # lifetime sums, and the per-expert counter beside them
     profile = served_sparse["health"]["engine_queue"]["worker_profile"]
+    once = _segments_once(served_sparse)
+    for attr in ("moe_prefill_assignments", "moe_prefill_rows"):
+        assert profile[attr] >= sum(sp["attrs"][attr] for sp in once) > 0
+    # they came back in the harvest's own fetch: the worker blocked on the
+    # device once a dispatched segment (the last may still be in flight)
+    phases = profile["phases"]
+    assert 0 <= phases["dispatch_submit"]["count"] - phases["sync"]["count"] <= 2
     per_expert = {key: v for key, v in served_sparse["ev"].counters_after["/metrics"].items()
                   if key.startswith("mcpx_engine_moe_expert_tokens_total{")}
     assert len(per_expert) == experts
@@ -311,7 +324,8 @@ def test_the_layer_kind_attributes_add_up(served_sparse):
 @pytest.mark.parametrize("metric", FED_MIXED, ids=[m["name"] for m in FED_MIXED])
 def test_the_mixed_block_feeds_its_metrics(served_mixed, metric):
     assert {m["name"] for m in FED_MIXED} == {
-        "moe.routed_bytes_share", "moe.touched_per_sparse_layer", "moe.load_max_over_mean"}
+        "moe.routed_bytes_share", "moe.touched_per_sparse_layer", "moe.load_max_over_mean",
+        "moe.prefill_rows_per_assignment"}
     v = served_mixed["read"](metric["reader"], metric["args"])
     assert v is not None and math.isfinite(v)
     if metric["name"] == "moe.routed_bytes_share":
@@ -320,6 +334,11 @@ def test_the_mixed_block_feeds_its_metrics(served_mixed, metric):
         assert 2 <= v <= 8  # a live token touches its 2 experts; a layer has 8
     if metric["name"] == "moe.load_max_over_mean":
         assert 1 <= v <= 8  # even routing reads 1, one expert taking all reads 8
+    if metric["name"] == "moe.prefill_rows_per_assignment":
+        # grouped (the rehearsal's cohort prefill is 8 x 128 slots, past the
+        # ridge): whole tiles of 64 rows, so at least 1; the loop over its 8
+        # experts would read 1,024 x 8 rows for a cohort's few hundred assignments
+        assert 1 <= v < 64
 
 
 def test_the_mixed_blocks_attributes_count_sparse_layers_and_bytes(served_mixed):

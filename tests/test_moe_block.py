@@ -21,8 +21,10 @@ from mcpx.engine.kv_cache import commit_prefill_to_pages, init_paged_kv
 from mcpx.engine.paged_decode import decode_chunk_paged
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import init_kv_cache, init_params, prefill
+from mcpx.models.gemma import moe
 from mcpx.models.gemma.moe import moe_forward, route
 from mcpx.parallel.mesh import make_mesh, param_pspecs
+from tests.helpers import grouped_against_loop
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
@@ -212,6 +214,69 @@ def test_pad_slots_and_idle_rows_are_routed_nowhere():
     assert np.asarray(stats2).tolist() == np.asarray(stats).tolist()
     nothing, stats0, _ = moe_forward(h, router[0], experts, jnp.int32(0), cfg, jnp.zeros((4, 6), bool))
     assert not np.asarray(nothing).any() and not np.asarray(stats0).any()  # no expert is read
+
+
+# ----------------------------------- past the ridge: rows grouped by expert
+def _steered(h, router, onto=None, away=None):
+    """One feature held at 5 in every slot, and the router's row for it: a
+    logit of +50 for the expert every token is to choose, -50 for the one
+    none is to."""
+    h = h.at[..., 0].set(5.0)
+    router = router.at[0].set(0.0)
+    if onto is not None:
+        router = router.at[0, onto].set(10.0)
+    if away is not None:
+        router = router.at[0, away].set(-10.0)
+    return h, router
+
+
+@pytest.mark.parametrize("case", [
+    "every_slot_live", "pads_and_idle_rows", "every_token_crowds_one_expert",
+    "an_expert_nobody_chose", "a_strict_share_held",
+])
+def test_past_the_ridge_the_grouped_form_computes_what_the_loop_does(case):
+    """4 x 96 = 384 slots, past the ridge of 256: softmax scoring."""
+    cfg = small()
+    h, router, experts = _layer_inputs(cfg, seed=11, B=4, S=96)
+    router, live, E = router[1], None, 8
+    if case == "pads_and_idle_rows":
+        live = jnp.arange(96)[None, :] < jnp.asarray([90, 3, 0, 96])[:, None]
+    if case == "every_token_crowds_one_expert":
+        h, router = _steered(h, router, onto=3)
+    if case == "an_expert_nobody_chose":
+        h, router = _steered(h, router, away=5)
+    if case == "a_strict_share_held":
+        cfg, E = dataclasses.replace(cfg, expert_first=2, experts_held=4), 4
+        experts = {k: v[:, 2:6] for k, v in experts.items()}
+    grouped, loop = grouped_against_loop(cfg, h, router, experts, jnp.int32(1), live)
+    n_live = 384 if live is None else 189
+    if case == "a_strict_share_held":
+        assert 0 < grouped[:E].sum() < 2 * n_live  # an assignment held elsewhere is in no group
+    else:
+        assert grouped[:E].sum() == 2 * n_live
+    if case == "every_token_crowds_one_expert":
+        assert grouped[3] == 384  # no capacity: six tiles of 64, and the other choice's
+        assert grouped[E + 1] >= 384 + moe.GROUP_TILE
+    if case == "an_expert_nobody_chose":
+        assert grouped[5] == 0 and grouped[E] == 7
+
+
+@pytest.mark.parametrize("slots, grouped", [
+    ((8, 8), False), ((1, 128), False), ((2, 128), False), ((3, 96), True), ((8, 128), True),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else ("grouped" if v else "loop"))
+def test_the_form_is_read_off_the_windows_width_alone(slots, grouped, monkeypatch):
+    """A decode segment's 64 slots, a cohort of one's 128 and anything up to
+    the ridge trace to the loop; past it, and so the cohort prefill's 1,024,
+    to the grouped form. Nothing but B x S decides."""
+    cfg = small()
+    _, router, experts = _layer_inputs(cfg, B=1, S=1)
+    took, real = [], moe._grouped_experts
+    monkeypatch.setattr(moe, "_grouped_experts", lambda *a: took.append(a[1].shape) or real(*a))
+    h = jax.ShapeDtypeStruct(slots + (cfg.d_model,), jnp.float32)
+    out, stats, _ = jax.eval_shape(
+        lambda h: moe_forward(h, router[0], experts, jnp.int32(0), cfg), h)
+    assert took == ([(slots[0] * slots[1], cfg.d_model)] if grouped else [])
+    assert out.shape == h.shape and stats.shape == (8 + 2,)
 
 
 # -------------------------------------------------------- the whole forward
