@@ -98,7 +98,7 @@ from mcpx.scheduler.admission import ewma_update
 from mcpx.scheduler.locality import locality_order
 from mcpx.telemetry import ledger as ledger_mod
 from mcpx.telemetry import tracing
-from mcpx.telemetry.costs import CostRegistry, device_peaks, rounded_roofline
+from mcpx.telemetry.costs import CostRegistry, device_peaks
 from mcpx.telemetry.flight import SEGMENT_PARTS, WorkerProfiler
 from mcpx.telemetry.metrics import Metrics
 from mcpx.utils.ownership import owned_by
@@ -147,6 +147,12 @@ class GenerateRequest:  # mcpx: request-payload
     # line (time.monotonic; stamped for requests that carry a span only):
     # enqueued_at..seen_at is the engine.queue_wait span's ``unseen_ms``.
     seen_at: float = 0.0
+    # The engine as ``generate`` found it at enqueue: the newest segment's
+    # ready stamp (0.0 = none yet; engine.queue_wait's ``since_ready_ms``)
+    # and the number of segments dispatched so far (engine.decode's
+    # ``missed_dispatches``). Two plain reads of worker-written scalars.
+    ready_seen: float = 0.0
+    dispatch_seen: int = 0
 
     def prefix_key(self, page_size: int) -> Optional[tuple]:
         """Page-aligned shared prefix as the cache key (None = no sharing).
@@ -206,6 +212,10 @@ class GenerateResult:
     # request task (generate()). None while telemetry.ledger is off, so the
     # disabled path carries no billing state at all.
     bill: Optional[dict] = None
+    # The ready stamp (time.monotonic) of the segment whose harvest
+    # delivered this: ``generate`` measures engine.generate's
+    # ``deliver_ms`` from it to the coroutine's resumption.
+    ready_at: float = 0.0
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -213,6 +223,21 @@ def _bucket(n: int, buckets: tuple[int, ...]) -> int:
         if n <= b:
             return b
     raise EngineError(f"length {n} exceeds largest bucket {buckets[-1]}")
+
+
+@dataclasses.dataclass
+class _PlanTrack:
+    """A traced slab row's wall, placed against the segments' ready stamps:
+    what engine.decode says of a plan besides its tokens. Times are
+    ``time.monotonic``, the spans' clock."""
+
+    admit_t0: float  # its admission's start on the host = engine.queue_wait's end
+    admit_t1: float  # that admission's end: prefill chain and first sample enqueued
+    first_seq: int = 0  # the first segment dispatched with the row resident
+    t_device: float = 0.0  # when the device could start it: max(dispatch, previous ready stamp)
+    segments: int = 0  # segments harvested with the row resident
+    live_forwards: int = 0  # sum of the row's device counter over them
+    ridden_forwards: int = 0  # sum of their forwards
 
 
 @owned_by("engine-worker")
@@ -283,12 +308,12 @@ class _Slab:
         self.temp = np.zeros((B,), np.float32)
         self.cons = np.zeros((B,), bool)
         self.dfa = np.zeros((B,), np.int32)
-        # Per-row snapshot of the engine's decode cost totals (flops,
-        # bytes, wall seconds) taken at admission for TRACED rows only:
-        # the retirement-time delta is the row's residency roofline
-        # (engine.decode span attrs). Written only when a span rides the
-        # request, so the untraced hot path never touches it.
-        self.cost0 = np.zeros((B, 3), np.float64)
+        # Where a TRACED row's plan stands against the segments' ready
+        # stamps (``_PlanTrack``: engine.decode's placement attributes):
+        # made at admission only when a span rides the request, advanced
+        # at each harvest that carried the row, dropped with the row. The
+        # untraced hot path never touches it.
+        self.track: list[Optional[_PlanTrack]] = [None] * B
         # Per-row cost-ledger accumulators (telemetry/ledger.py), written
         # ONLY while telemetry.ledger is enabled (engine._ledger_on) —
         # ledger-off leaves every array untouched, the pass-through
@@ -367,6 +392,7 @@ class _Slab:
         if r is not None and r.span is not None:
             self.n_traced -= 1
         self.req[i] = None
+        self.track[i] = None
         self.sid[i] = None
         self.done[i] = True
         self.cur[i] = self.pad_id
@@ -536,7 +562,19 @@ class InferenceEngine:
         # spans and the step_num of the segment's ``mcpx.segment`` event in
         # a profiler trace (_seg_counter also counts admissions: it seeds
         # the PRNG).
-        self._dispatch_seq = 0  # mcpx: owner[engine-worker]
+        # ``generate`` reads it at enqueue, a plain int (atomic): how many
+        # dispatches a request then sits out is engine.decode's
+        # ``missed_dispatches``.
+        self._dispatch_seq = 0  # mcpx: owner[engine-worker, atomic]
+        # The newest segment's ready stamp (its blocking fetch returned;
+        # ``time.monotonic``, 0.0 = none yet): what a plan's wall is placed
+        # against. ``generate`` reads it at enqueue (atomic) for
+        # engine.queue_wait's ``since_ready_ms``.
+        self._t_ready = 0.0  # mcpx: owner[engine-worker, atomic]
+        # When the first device work since the previous segment dispatch
+        # was enqueued (an admission's prefill chain; 0.0 = none, the next
+        # dispatch is the first): the timeline's ``starved_ms``.
+        self._t_queued = 0.0  # mcpx: owner[engine-worker]
         # Rows admitted since the previous segment dispatch: their prefills
         # are chained in front of the next segment on the device, so its
         # engine.segment spans carry the count as ``prefill_rows``.
@@ -709,15 +747,6 @@ class InferenceEngine:
             metrics=self.metrics,
             enabled=self.config.telemetry.cost_accounting,
         )
-        # Device peaks for span rooflines (None off-TPU: spans then carry
-        # achieved rates + arithmetic intensity without an mfu/bound claim).
-        self._peak_flops_total: Optional[float] = None
-        self._peak_bytes_total: Optional[float] = None
-        # Cumulative decode-segment cost totals {flops, bytes, wall_s},
-        # advanced at harvest while any resident row is traced — the
-        # residency-delta source for engine.decode span rooflines. Worker
-        # thread only.
-        self._seg_cost_totals = {"flops": 0.0, "bytes": 0.0, "wall_s": 0.0}  # mcpx: owner[engine-worker]
         # Decode-loop host profiler (telemetry/flight.py): per-iteration
         # phase timers tiling the worker loop's wall time into named
         # phases on the spans' clock, surfaced via
@@ -935,9 +964,12 @@ class InferenceEngine:
                 deadline_at=deadline_at,
                 tenant=tenant or "default",
                 span=esp,
+                ready_seen=self._t_ready,
+                dispatch_seen=self._dispatch_seq,
             )
             self._queue.put(req)
             res = await req.future
+            t_resumed = time.monotonic()
             if res.bill is not None:
                 # Fold the worker's engine bill into the request's ledger
                 # bill (contextvar — this runs back on the request task, so
@@ -951,6 +983,10 @@ class InferenceEngine:
                     queue_ms=round(res.queue_ms, 3),
                     prefill_ms=round(res.prefill_ms, 3),
                     decode_ms=round(res.decode_ms, 3),
+                    # From the ready stamp of the plan's last segment to
+                    # here, back on the event loop: the harvest's per-row
+                    # work, call_soon_threadsafe and the loop's wake-up.
+                    deliver_ms=round((t_resumed - res.ready_at) * 1e3, 3),
                 )
             return res
 
@@ -1392,15 +1428,10 @@ class InferenceEngine:
                 self._spill_readmit_dispatch,
                 kv_bytes_per_token,
             )
-        # Datasheet peaks over the chips this engine actually meshes: the
-        # denominator for span roofline attrs. The CPU backend has none
-        # (spans then report achieved rates without an mfu/bound claim); an
-        # accelerator missing from the table fails start-up here.
-        pk = device_peaks()
-        n_chips = int(self._mesh.devices.size)
-        if pk["flops_per_chip"]:
-            self._peak_flops_total = pk["flops_per_chip"] * n_chips
-            self._peak_bytes_total = pk["hbm_bytes_s_per_chip"] * n_chips
+        # GET /costs sets the registry's numbers against datasheet peaks:
+        # an accelerator missing from the table fails start-up here, not
+        # at the first scrape (the CPU backend has none and says so).
+        device_peaks()
         if ecfg.speculative.enabled and ecfg.hetero_batch:
             # The verify window samples [B, K+1]-shaped draws each forward;
             # with the default non-partitionable threefry every mesh device
@@ -1972,38 +2003,6 @@ class InferenceEngine:
             hst.at[rows].set(hst_v, mode="drop"),
         )
 
-    def _span_roofline(
-        self,
-        flops: Optional[float],
-        bytes_accessed: Optional[float],
-        wall_s: float,
-    ) -> dict:
-        """Rounded roofline attrs for engine spans: achieved FLOP/s and
-        bytes/s, arithmetic intensity, and — when datasheet peaks are known
-        for this hardware — mfu / HBM-bandwidth utilisation / which roof
-        binds. Empty when XLA published no costs (labeled absence beats a
-        guessed number). With pipeline_depth > 1 consecutive segment spans
-        overlap, so per-span achieved rates are upper-bounded approximations
-        of the interval."""
-        rl = rounded_roofline(
-            flops,
-            bytes_accessed,
-            wall_s,
-            peak_flops=self._peak_flops_total,
-            peak_bytes_s=self._peak_bytes_total,
-        )
-        out: dict[str, Any] = {
-            k: rl[k]
-            for k in (
-                "achieved_flops_s", "achieved_bytes_s",
-                "arithmetic_intensity", "mfu", "hbm_bw_util",
-            )
-            if k in rl
-        }
-        if "bound" in rl:
-            out["roofline_bound"] = rl["bound"]
-        return out
-
     def _poll_admissions(self, slab: "_Slab") -> None:
         """Resolve pending admission chains whose device work has finished
         (non-blocking ``is_ready`` checks, FIFO — device order means a
@@ -2048,11 +2047,6 @@ class InferenceEngine:
                 slab.t_decode0[i] = now
                 r = slab.req[i]
                 if r.span is not None:
-                    if pf_entry is not None:
-                        # Lazy cost materialisation (one AOT compile per
-                        # signature, idempotent): paid only when a traced
-                        # request actually reads the numbers.
-                        pf_entry.ensure()
                     # Admission-start to chain-completion: host prep, the
                     # cohort prefill this row rode in, commit-to-pages and
                     # first sample (observed <=1 tick late, same as the
@@ -2075,14 +2069,6 @@ class InferenceEngine:
                         t1=now,
                         dfa_id=int(slab.dfa[i]),
                         **pfx_attrs,
-                        # XLA-derived roofline of the cohort prefill this
-                        # row rode in (whole-cohort cost over the chain's
-                        # wall window — per-row attribution would be a lie).
-                        **self._span_roofline(
-                            pf_entry.flops if pf_entry is not None else None,
-                            pf_entry.bytes_accessed if pf_entry is not None else None,
-                            now - t_admit0,
-                        ),
                     )
 
     def _dispatch_merge(self, slab: "_Slab", rows: list[int]) -> None:
@@ -2926,8 +2912,10 @@ class InferenceEngine:
         Emissions are written at absolute slots ``out_buf[b, emitted..]`` so
         rows admitted at different segment boundaries coexist in one slab.
         Returns (cur, pos, st, emitted, done, pools_k, pools_v, out_buf,
-        prev, n_forwards) and, for a model with sparse or windowed layers,
-        their counters (``_segment_stats``) as one more int32 vector.
+        prev, n_forwards, live_forwards [B]: each row's count of the
+        forwards at whose start it was not done) and, for a model with
+        sparse or windowed layers, their counters (``_segment_stats``) as
+        one more int32 vector.
         """
         cfg = self.model_cfg
         tok = self.tokenizer
@@ -2942,13 +2930,13 @@ class InferenceEngine:
         sparse, windowed = self._segment_stats
 
         def cond(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = c
             return (it < iters) & jnp.any(~done)
 
         def draft_body(c):
             from mcpx.engine.sampling import NEG_INF
 
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = c
             J = chunk - 1
             Lp = prompt_toks.shape[1]
             j_ar = jnp.arange(J)
@@ -3090,10 +3078,11 @@ class InferenceEngine:
                 prev2,
                 key,
                 ms + fwd_ms[0] if sparse else ms,
+                live + ~done,
             )
 
         def body(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = c
 
             if chunk > 1 and constrained:
                 # Fast-forward: chain of forced tokens after `cur`. Emission
@@ -3202,6 +3191,7 @@ class InferenceEngine:
                 prev2,
                 key,
                 ms + fwd_ms[0] if sparse else ms,
+                live + ~done,
             )
 
         rows_at_dispatch = []
@@ -3221,11 +3211,14 @@ class InferenceEngine:
             prev,
             key,
             moe_stats_init(cfg) if sparse else None,
+            # Row-forwards by state, counted where they happen: a row's
+            # count of the forwards at whose start it was not done.
+            jnp.zeros_like(done, jnp.int32),
         )
-        it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms = lax.while_loop(
+        it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = lax.while_loop(
             cond, draft_body if use_draft else body, init
         )
-        out = (cur, pos, st, e, done, k_p, v_p, buf, prev, it)
+        out = (cur, pos, st, e, done, k_p, v_p, buf, prev, it, live)
         if not any(self._segment_stats):
             return out
         # What the layer kinds did, for the lagged harvest's one fetch: the
@@ -3285,7 +3278,7 @@ class InferenceEngine:
         unembed and proposal chain are single-grammar, and hetero mode
         trades it for admission freedom (grammar fast-forward — the larger
         win on plan JSON — stays). Returns (cur, pos, st, emitted, done,
-        pools_k, pools_v, out_buf, n_forwards)."""
+        pools_k, pools_v, out_buf, n_forwards, live_forwards [B])."""
         cfg = self.model_cfg
         tok = self.tokenizer
         B = cur.shape[0]
@@ -3295,11 +3288,11 @@ class InferenceEngine:
         b_idx = jnp.arange(B)
 
         def cond(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, key = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, key, live = c
             return (it < iters) & jnp.any(~done)
 
         def body(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, key = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, key, live = c
 
             if chunk > 1:
 
@@ -3388,6 +3381,7 @@ class InferenceEngine:
                 kv["v"],
                 buf,
                 key,
+                live + ~done,
             )
 
         init = (
@@ -3401,11 +3395,12 @@ class InferenceEngine:
             paged_v,
             out_buf,
             key,
+            jnp.zeros_like(done, jnp.int32),
         )
-        it, cur, pos, st, e, done, k_p, v_p, buf, key = lax.while_loop(
+        it, cur, pos, st, e, done, k_p, v_p, buf, key, live = lax.while_loop(
             cond, body, init
         )
-        return cur, pos, st, e, done, k_p, v_p, buf, it
+        return cur, pos, st, e, done, k_p, v_p, buf, it, live
 
     def _hetero_segment_spec_impl(
         self,
@@ -3484,7 +3479,7 @@ class InferenceEngine:
         bought only pays on an all-done slab (the drain tail), where the
         extra iterations are cheap no-ops (every row masked done). Returns
         (cur, pos, st, emitted, done, pools_k, pools_v, out_buf, hstate,
-        drafted [B], accepted [B], n_forwards)."""
+        drafted [B], accepted [B], n_forwards, live_forwards [B])."""
         cfg = self.model_cfg
         tok = self.tokenizer
         B = cur.shape[0]
@@ -3499,7 +3494,7 @@ class InferenceEngine:
         j_ar = jnp.arange(K + 1)
 
         def body(c):
-            cur, pos, st, e, done, k_p, v_p, buf, h, n_dr, n_ac, key = c
+            cur, pos, st, e, done, k_p, v_p, buf, h, n_dr, n_ac, key, live = c
 
             # --- 1. draft K tokens per row through the grammar pre-filter.
             # The walk also emits the verify window's per-position
@@ -3629,6 +3624,7 @@ class InferenceEngine:
                 n_dr + jnp.sum(p_use, axis=1).astype(jnp.int32),
                 n_ac + a,
                 key,
+                live + ~done,
             )
 
         c = (
@@ -3644,13 +3640,14 @@ class InferenceEngine:
             jnp.zeros((B,), jnp.int32),
             jnp.zeros((B,), jnp.int32),
             key,
+            jnp.zeros_like(done, jnp.int32),
         )
         for _ in range(max(1, iters)):
             c = body(c)
-        cur, pos, st, e, done, k_p, v_p, buf, h, n_dr, n_ac, key = c
+        cur, pos, st, e, done, k_p, v_p, buf, h, n_dr, n_ac, key, live = c
         return (
             cur, pos, st, e, done, k_p, v_p, buf, h, n_dr, n_ac,
-            jnp.asarray(max(1, iters), jnp.int32),
+            jnp.asarray(max(1, iters), jnp.int32), live,
         )
 
     # --- worker -----------------------------------------------------------
@@ -4646,6 +4643,8 @@ class InferenceEngine:
 
         t1 = time.monotonic()
         self._last_admit_t = t1
+        # The prefill chain and its first sample are on the device's queue.
+        self._t_queued = self._t_queued or t1
         self.metrics.prefill_tokens.inc(int(seq_lens[: len(cohort)].sum()))
         self.metrics.admissions.inc()
         self.metrics.admitted_rows.inc(len(cohort))
@@ -4683,8 +4682,7 @@ class InferenceEngine:
                 # as a histogram (the chip benchmark's engine.queue_*
                 # metrics read this span).
                 slab.n_traced += 1
-                tot = self._seg_cost_totals
-                slab.cost0[i] = (tot["flops"], tot["bytes"], tot["wall_s"])
+                slab.track[i] = _PlanTrack(admit_t0=t0, admit_t1=t1)
                 r.span.child(
                     "engine.queue_wait",
                     t0=r.enqueued_at,
@@ -4809,10 +4807,18 @@ class InferenceEngine:
         pending line (it sat in queue.Queue while the worker was not
         looking), and ``free_row_ms``, the part of the wait during which
         the slab had a free row by the worker's books. Both <= the span's
-        duration. Empty without a profiler."""
-        if self._profiler is None:
-            return {}
+        duration, and written only with a profiler. ``since_ready_ms``
+        needs none: the enqueue minus the newest segment's ready stamp as
+        ``generate`` read it then, i.e. how long after the harvest that
+        (in a closed loop) answered its caller the next request arrived:
+        delivery, HTTP, client, server, planner, tokeniser. Left out
+        before the first ready stamp."""
         t_enq = r.enqueued_at
+        out = {}
+        if r.ready_seen:
+            out["since_ready_ms"] = round((t_enq - r.ready_seen) * 1e3, 3)
+        if self._profiler is None:
+            return out
         # Transitions alternate, so before the oldest one kept the state
         # was its opposite; with none kept it is the current state.
         transitions = self._occupancy
@@ -4828,10 +4834,9 @@ class InferenceEngine:
         if not full and t_admit > t_prev:
             free_s += t_admit - t_prev
         seen = min(max(r.seen_at, t_enq), t_admit)
-        return {
-            "unseen_ms": round((seen - t_enq) * 1e3, 3),
-            "free_row_ms": round(free_s * 1e3, 3),
-        }
+        out["unseen_ms"] = round((seen - t_enq) * 1e3, 3)
+        out["free_row_ms"] = round(free_s * 1e3, 3)
+        return out
 
     def _reap_cancelled(self, slab: "_Slab") -> None:
         """Free rows whose request future was cancelled (client disconnect,
@@ -4891,6 +4896,7 @@ class InferenceEngine:
         self._hold_joined_total += hold_joined
         prefill_moe, self._prefill_moe = self._prefill_moe, []
         t_submit = self._pacer.clock()
+        t_queued, self._t_queued = self._t_queued or t_submit, 0.0
         # A step event in a profiler trace: the segment's device ops carry
         # its step_num, which is the engine.segment spans' ``seq``.
         with StepTraceAnnotation("mcpx.segment", step_num=seq):
@@ -4919,7 +4925,7 @@ class InferenceEngine:
                 )
                 (
                     cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, hst_d,
-                    dr_d, ac_d, n_fwd,
+                    dr_d, ac_d, n_fwd, live_d,
                 ) = out
                 # Class snapshot at dispatch: the drafted/accepted vectors the
                 # lagged harvest fetches belong to the rows resident NOW.
@@ -4945,7 +4951,7 @@ class InferenceEngine:
                     iters=np.int32(window),
                     chunk=chunk,
                 )
-                cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, n_fwd = out
+                cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, n_fwd, live_d = out
             else:
                 dfa = self._dfa_for(slab.grammar or self.grammar)
                 out = self._jit_segment(  # mcpx: ignore[jit-contract] - homogeneous-mode debt: per-request temperature/constrained ARE trace statics here, bounded by the slab-wide compat triple (one config per occupancy, drain-to-switch); hetero_batch moves both into per-row device state
@@ -4972,7 +4978,7 @@ class InferenceEngine:
                     draft=ecfg.draft_mode == "prompt",
                 )
                 (cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, prev_d, n_fwd,
-                 *kind_stats) = out
+                 live_d, *kind_stats) = out
                 kinds_d = kind_stats[0] if kind_stats else None
         self._paged_kv = {"k": k_p, "v": v_p}
         slab.dev = (
@@ -5018,6 +5024,15 @@ class InferenceEngine:
                 # the prefills in front of it: _layer_kind_attrs.
                 kinds_d,
                 prefill_moe,
+                # Row-forwards by state (_row_forwards): each row's count
+                # of this segment's forwards it was live in, still on the
+                # device; the host's book of which rows hold a request
+                # now; and when the first device work since the previous
+                # dispatch was enqueued (an admission's prefill chain, or
+                # this dispatch): the timeline's ``starved_ms``.
+                live_d,
+                [r is not None for r in slab.req],
+                t_queued,
             )
         )
 
@@ -5068,6 +5083,64 @@ class InferenceEngine:
         totals = self._layer_kind_totals
         self._layer_kind_totals = {k: totals.get(k, 0) + v for k, v in attrs.items()}
         return attrs
+
+    def _row_forwards(
+        self, live: np.ndarray, occupied: list[bool], n_fwd: int
+    ) -> dict[str, int]:
+        """What the slab's rows did in one harvested segment of ``n_fwd``
+        forwards, as engine.segment attributes (identical on the segment's
+        rows) and, traced or not, into
+        ``mcpx_engine_row_forwards_total{state=...}``. ``live`` is the
+        device's count, a row, of the forwards at whose start it was not
+        done; ``occupied`` the host's book at the segment's dispatch. Of
+        ``row_forwards`` = rows x forwards: ``row_forwards_empty``, the
+        rows that held no request; ``row_forwards_live``, the counter
+        summed over those that did; ``row_forwards_done``, the rest of
+        theirs: rows that finished earlier in this segment, and rows that
+        finished in the segment before, whose harvest came after this
+        dispatch (a pipelined worker's harvest lag). The three sum to
+        ``row_forwards`` exactly."""
+        rows, taken = len(occupied), sum(occupied)
+        n_live = int(live[occupied].sum())
+        by_state = {
+            "live": n_live,
+            "done": taken * n_fwd - n_live,
+            "empty": (rows - taken) * n_fwd,
+        }
+        for state, n in by_state.items():
+            self.metrics.row_forwards.labels(state=state).inc(n)
+        return {
+            "row_forwards": rows * n_fwd,
+            **{"row_forwards_" + state: n for state, n in by_state.items()},
+        }
+
+    @staticmethod
+    def _plan_placement(track: _PlanTrack, r: GenerateRequest, seq: int) -> dict:
+        """Where a delivered plan's wall stood against the ready stamps, as
+        engine.decode attributes; ``seq`` is the segment whose harvest
+        delivers it. ``first_seq``..``last_seq`` carried the row, over
+        ``segments`` harvests; of their ``ridden_forwards`` the row was
+        live in ``live_forwards`` (its device counter, summed).
+        ``missed_dispatches``: segments dispatched after the request was
+        enqueued and before ``first_seq`` (0 = the next dispatch carried
+        it). ``admit_host_ms``: its admission on the host, from the end of
+        engine.queue_wait to the prefill chain enqueued. ``behind_ms``:
+        from there to when the device could start ``first_seq`` (its
+        dispatch, or the previous ready stamp if later): the prefill chain
+        and the segment in flight ahead. With engine.generate's
+        ``deliver_ms``: queue wait + admit_host_ms + behind_ms + the
+        ``period_ms`` of segments first_seq..last_seq + deliver_ms tile
+        engine.generate's duration."""
+        return {
+            "first_seq": track.first_seq,
+            "last_seq": seq,
+            "segments": track.segments,
+            "live_forwards": track.live_forwards,
+            "ridden_forwards": track.ridden_forwards,
+            "missed_dispatches": track.first_seq - r.dispatch_seen - 1,
+            "admit_host_ms": round((track.admit_t1 - track.admit_t0) * 1e3, 3),
+            "behind_ms": round((track.t_device - track.admit_t1) * 1e3, 3),
+        }
 
     def _account_speculation(
         self, dr: np.ndarray, ac: np.ndarray, cons_snap: np.ndarray
@@ -5126,12 +5199,14 @@ class InferenceEngine:
                 done_d, e_d, buf_d, nfwd_d, gen_snap, t_disp, spec_h, cons_snap,
                 seg_cost, seg_name, seq, prefill_rows, hold_joined,
                 window, window_max, kinds_d, prefill_moe,
+                live_d, occupied, t_queued,
             ) = self._inflight.popleft()
             # ONE combined fetch (flags + out_buf): a blocking fetch costs
             # its round trip, not the ~24KB of buffer — splitting into
             # flags-then-buf would add a second round trip on every
             # retirement tick, which at steady state is most ticks. The
-            # speculation counters ([B] ints) ride the same fetch. The
+            # speculation counters ([B] ints) ride the same fetch, as do
+            # each row's live forwards and the layer kinds' counters. The
             # blocking wait is carved out as the profiler's "sync" phase:
             # time spent waiting for device compute, not host bookkeeping
             # (the harvest lap keeps only the latter).
@@ -5140,22 +5215,24 @@ class InferenceEngine:
             dr = ac = None
             with TraceAnnotation("mcpx.worker.sync"):
                 if spec_h is not None:
-                    done, e, buf, n_fwd, dr, ac = jax.device_get(
-                        (done_d, e_d, buf_d, nfwd_d) + spec_h
+                    done, e, buf, n_fwd, live, dr, ac = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d, live_d) + spec_h
                     )
                 elif kinds_d is not None:
-                    done, e, buf, n_fwd, kind_counts, prefill_counts = jax.device_get(
-                        (done_d, e_d, buf_d, nfwd_d, kinds_d, prefill_moe)
+                    done, e, buf, n_fwd, live, kind_counts, prefill_counts = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d, live_d, kinds_d, prefill_moe)
                     )
                 else:
-                    done, e, buf, n_fwd = jax.device_get(
-                        (done_d, e_d, buf_d, nfwd_d)
+                    done, e, buf, n_fwd, live = jax.device_get(
+                        (done_d, e_d, buf_d, nfwd_d, live_d)
                     )
+            n_fwd = int(n_fwd)
             kind_attrs = (
-                self._layer_kind_attrs(kind_counts, int(n_fwd), prefill_counts)
+                self._layer_kind_attrs(kind_counts, n_fwd, prefill_counts)
                 if kinds_d is not None
                 else {}
             )
+            row_attrs = self._row_forwards(live, occupied, n_fwd)
             timeline = {}
             # The segment's ready stamp: the fetch has just returned. The
             # pacer learns the period from it, profiler or none.
@@ -5168,11 +5245,12 @@ class InferenceEngine:
                 if t_disp:
                     timeline = self._segment_timeline(
                         seq, prefill_rows, hold_joined, t_disp, t_ready,
-                        t_prev, phases,
+                        t_prev, phases, t_queued,
                     )
             else:
                 t_ready = self._pacer.clock()
-            self._pacer.ready(t_ready, int(n_fwd))
+            t_prev_ready, self._t_ready = self._t_ready, t_ready
+            self._pacer.ready(t_ready, n_fwd)
             if dr is not None:
                 self._account_speculation(dr, ac, cons_snap)
             # The blocking fetch above implies every earlier admission chain
@@ -5183,30 +5261,11 @@ class InferenceEngine:
             # pipeline's depth-1 segment lag, because that lag is part of
             # what the caller actually waits for.
             t1 = time.monotonic()
-            self.metrics.decode_forwards.inc(int(n_fwd))
+            self.metrics.decode_forwards.inc(n_fwd)
             if t_disp:
-                # Segment cost accumulation (traced windows only — t_disp
+                # Per-segment decode attribution for traced rows (t_disp
                 # is set iff some resident row is traced, which holds for
-                # every segment of a traced row's residency): the
-                # engine.decode span's residency roofline is the delta of
-                # these totals between admission and retirement.
-                seg_wall = t1 - t_disp
-                if seg_cost is not None:
-                    # Lazy cost materialisation: only traced windows read
-                    # the XLA numbers, and only the first read per
-                    # signature compiles (idempotent).
-                    seg_cost.ensure()
-                if seg_cost is not None and seg_cost.flops is not None:
-                    tot = self._seg_cost_totals
-                    tot["flops"] += seg_cost.flops
-                    tot["bytes"] += seg_cost.bytes_accessed or 0.0
-                    tot["wall_s"] += seg_wall
-                seg_attrs = self._span_roofline(
-                    seg_cost.flops if seg_cost is not None else None,
-                    seg_cost.bytes_accessed if seg_cost is not None else None,
-                    seg_wall,
-                )
-                # Per-segment decode attribution for traced rows: dispatch
+                # every segment of a traced row's residency): dispatch
                 # to (lagged) harvest, per-row token delta against the host
                 # emitted mirror (valid per row lifetime: cleared to 0 at
                 # admission, advanced only here), the row's grammar slot and
@@ -5215,6 +5274,16 @@ class InferenceEngine:
                     r = slab.req[i]
                     if r is None or r.span is None or gen_snap[i] != slab.gen[i]:
                         continue
+                    # The plan's placement: this segment carried the row
+                    # (a finished row is released below, at the harvest of
+                    # the segment it finished in, so none is seen twice).
+                    track = slab.track[i]
+                    if not track.segments:
+                        track.first_seq = seq
+                        track.t_device = max(t_disp, t_prev_ready)
+                    track.segments += 1
+                    track.live_forwards += int(live[i])
+                    track.ridden_forwards += n_fwd
                     delta = int(e[i]) - int(slab.emitted[i])
                     slab.emitted[i] = e[i]
                     if delta <= 0 and not done[i]:
@@ -5223,16 +5292,19 @@ class InferenceEngine:
                         tokens=delta,
                         dfa_id=int(slab.dfa[i]),
                         cls="constrained" if slab.cons[i] else "free",
-                        forwards=int(n_fwd),
+                        forwards=n_fwd,
+                        # Of them, those at whose start this row was not
+                        # done (its device counter): they sum to
+                        # engine.decode's ``live_forwards``.
+                        live_forwards=int(live[i]),
                         # What the pacer asked for at the dispatch, and
                         # the configured ceiling it chose under.
                         window=window,
                         window_max=window_max,
-                        # Whole-slab segment roofline (XLA cost over the
-                        # dispatch->harvest window) — identical across the
-                        # segment's rows by construction, as is its
-                        # timeline (_segment_timeline).
-                        **seg_attrs,
+                        # The whole slab's, so identical across the
+                        # segment's rows: its row-forwards by state, its
+                        # timeline, its layer kinds' counters.
+                        **row_attrs,
                         **timeline,
                         **kind_attrs,
                     )
@@ -5249,13 +5321,13 @@ class InferenceEngine:
                 # segment (not just traced ones): the whole-slab XLA cost
                 # apportioned by row-residency share, plus the forwards
                 # and accepted speculative tokens the row was resident for.
-                live = [
+                resident = [
                     i for i in range(slab.B)
                     if slab.req[i] is not None and gen_snap[i] == slab.gen[i]
                 ]
-                self._ledger_account(seg_cost, seg_name, live, slab)
-                for i in live:
-                    slab.bill_fwd[i] += int(n_fwd)
+                self._ledger_account(seg_cost, seg_name, resident, slab)
+                for i in resident:
+                    slab.bill_fwd[i] += n_fwd
                     if ac is not None:
                         slab.bill_spec[i] += int(ac[i])
             retired = False
@@ -5272,6 +5344,7 @@ class InferenceEngine:
                     queue_ms=slab.queue_ms[i],
                     prefill_ms=max(0.0, slab.prefill_ms[i]),
                     decode_ms=(t1 - slab.t_decode0[i]) * 1e3,
+                    ready_at=t_ready,
                 )
                 if self._ledger_on:
                     # The engine's itemized bill for this request — a fresh
@@ -5316,24 +5389,15 @@ class InferenceEngine:
                 if r.span is not None:
                     # Slab residency (admission to delivery, the pipeline's
                     # depth-1 lag included): the summary span whose window
-                    # the engine.segment spans subdivide.
-                    # Residency roofline: decode-segment cost totals
-                    # accumulated since this row's admission snapshot, over
-                    # its decode wall — the whole-slab achieved rate during
-                    # the row's residency (cost0 is per-row, the work is
-                    # the slab's).
-                    tot = self._seg_cost_totals
+                    # the engine.segment spans subdivide, and where the
+                    # plan's wall stood against the ready stamps.
                     r.span.child(
                         "engine.decode",
                         t0=slab.t_decode0[i],
                         t1=t1,
                         tokens=len(ids),
                         row=i,
-                        **self._span_roofline(
-                            tot["flops"] - slab.cost0[i, 0] or None,
-                            tot["bytes"] - slab.cost0[i, 1] or None,
-                            t1 - slab.t_decode0[i],
-                        ),
+                        **self._plan_placement(slab.track[i], r, seq),
                     )
                     if self.config.tracing.exemplars and r.span.record.sampled:
                         # Head-unsampled traces are (usually) never
@@ -5360,6 +5424,7 @@ class InferenceEngine:
         t_ready: float,
         t_prev: float,
         phases: dict[str, float],
+        t_queued: float,
     ) -> dict:
         """When a harvested segment ran and what the worker did meanwhile,
         as flat engine.segment span attributes. ``period_ms``: from when
@@ -5373,8 +5438,17 @@ class InferenceEngine:
         arrivals to join the segment being held, while the device was
         busy) and ``host_ms`` (every other phase, with its named parts)
         are the worker's phases since ``t_prev``: with ``sync_ms`` they
-        sum to ``t_ready - t_prev``. ``hold_joined_rows`` of the
-        ``prefill_rows`` were admitted while the segment was held."""
+        sum to ``t_ready - t_prev``, which is ``ready_gap_ms``.
+        ``hold_joined_rows`` of the ``prefill_rows`` were admitted while
+        the segment was held. ``starved_ms``: from the previous ready
+        stamp to ``t_queued``, the first thing the worker put on the
+        device's queue for this segment (an admission's prefill chain, or
+        the dispatch itself); 0 when that was queued already. The device's
+        idle time as the program sees it, inside the ``host_ms``,
+        ``idle_ms`` and ``hold_ms`` that say what the worker did meanwhile.
+        A floor: a device that finished before the host asked
+        (``sync_ms`` near zero) idled from then, unseen, to the dispatch
+        that preceded the stamp."""
         ms = {p: v * 1e3 for p, v in phases.items()}
         waits = ms["sync"] + ms["idle"] + ms["hold"]
         out = {
@@ -5382,6 +5456,8 @@ class InferenceEngine:
             "prefill_rows": prefill_rows,
             "hold_joined_rows": hold_joined_rows,
             "period_ms": round((t_ready - max(t_disp, t_prev)) * 1e3, 3),
+            "ready_gap_ms": round((t_ready - t_prev) * 1e3, 3),
+            "starved_ms": round(max(0.0, t_queued - t_prev) * 1e3, 3),
             "sync_ms": round(ms["sync"], 3),
             "idle_ms": round(ms["idle"], 3),
             "hold_ms": round(ms["hold"], 3),
@@ -5436,6 +5512,7 @@ class InferenceEngine:
         slab.dev = None
         self._inflight.clear()
         self._prefill_moe.clear()
+        self._t_queued = 0.0
         self._pacer.reset()
         self._dirty_rows.clear()
         self._pending_admissions.clear()

@@ -49,10 +49,12 @@ Roofline helpers (:func:`device_peaks`, :func:`roofline`) turn executed
 flops/bytes + wall time into achieved FLOP/s, achieved bytes/s, arithmetic
 intensity and a roofline position against the chip's datasheet peaks;
 :func:`hbm_stats`/:func:`update_hbm_gauges` expose per-device
-``memory_stats()`` as HBM-pressure gauges. Consumers: the engine's
-``engine.prefill``/``engine.segment``/``engine.decode`` spans and the
-``GET /costs`` endpoint (docs/observability.md §Roofline & cost
-accounting).
+``memory_stats()`` as HBM-pressure gauges. Consumer: the ``GET /costs``
+endpoint (docs/observability.md §Roofline & cost accounting), whose
+``totals`` a caller differences around a phase it timed itself. No span
+carries a roofline number (ISSUE 40): XLA's estimate over a host wall two
+segments deep is not a device measurement, and writing it made the engine
+worker compile.
 """
 
 from __future__ import annotations
@@ -70,7 +72,6 @@ __all__ = [
     "device_peaks",
     "hbm_stats",
     "roofline",
-    "rounded_roofline",
     "update_hbm_gauges",
 ]
 
@@ -192,7 +193,11 @@ def roofline(
 ) -> dict:
     """Achieved rates + roofline position for ``flops``/``bytes_accessed``
     of work done in ``wall_s`` seconds. Keys are only present when their
-    inputs are: no peak -> no ``mfu``/``bound`` (never a made-up one)."""
+    inputs are: no peak -> no ``mfu``/``bound`` (never a made-up one). Fed
+    XLA's ``cost_analysis()`` and a wall the caller read on the host, its
+    ``mfu`` is an estimate over a host clock, not a device measurement: a
+    helper for whoever differences ``GET /costs`` totals around a phase;
+    no program path calls it."""
     out: dict[str, Any] = {}
     if wall_s <= 0:
         return out
@@ -211,40 +216,6 @@ def roofline(
             out["ridge_ai"] = ridge
             out["bound"] = "memory" if out["arithmetic_intensity"] < ridge else "compute"
     return out
-
-
-# Report precision per roofline key, as the engine's span attrs carry it.
-_ROOFLINE_ROUNDING = {
-    "achieved_flops_s": 1,
-    "achieved_bytes_s": 1,
-    "arithmetic_intensity": 3,
-    "ridge_ai": 3,
-    "mfu": 6,
-    "hbm_bw_util": 6,
-}
-
-
-def rounded_roofline(
-    flops: Optional[float],
-    bytes_accessed: Optional[float],
-    wall_s: float,
-    *,
-    peak_flops: Optional[float] = None,
-    peak_bytes_s: Optional[float] = None,
-) -> dict:
-    """:func:`roofline` at report precision (floats coerced so numpy
-    scalars can't leak into json.dumps consumers like /traces)."""
-    rl = roofline(
-        float(flops) if flops is not None else None,
-        float(bytes_accessed) if bytes_accessed is not None else None,
-        float(wall_s),
-        peak_flops=peak_flops,
-        peak_bytes_s=peak_bytes_s,
-    )
-    return {
-        k: (round(v, _ROOFLINE_ROUNDING[k]) if k in _ROOFLINE_ROUNDING else v)
-        for k, v in rl.items()
-    }
 
 
 # --------------------------------------------------------------- signatures
@@ -335,10 +306,9 @@ class ExecCost:
         compile from the stored abstract spec, harvest cost_analysis()/
         memory_analysis(), discard the compiled object. At most once per
         signature; callers are read paths (/costs off the event loop, the
-        warmup tail, or a traced span on the worker — the latter is the
-        one read that can stall serving, bounded to once per signature
-        warmup didn't cover and persistent-cache-served on TPU), never
-        the dispatch path."""
+        warmup tail, and, only while ``telemetry.ledger`` bills requests
+        their flops, the worker's ledger accounting), never the dispatch
+        path and, since ISSUE 40, no traced span."""
         if self.cost_basis != "pending":
             return self
         with self.lock:

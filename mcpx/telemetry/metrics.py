@@ -193,6 +193,16 @@ class Metrics:
         self.segments = Counter(
             "mcpx_engine_segments_total", "Decode segments run", registry=self.registry
         )
+        self.row_forwards = Counter(
+            "mcpx_engine_row_forwards_total",
+            "Slab rows x decode forwards by what the row was doing, counted "
+            "on the device: live (decoding), done (held a request that had "
+            "finished: earlier in the segment, or in the one before while "
+            "its harvest was still to come), empty (held none); "
+            "live / sum = the slab's occupancy by work done",
+            ["state"],
+            registry=self.registry,
+        )
         self.ring_prefills = Counter(
             "mcpx_engine_ring_prefills_total",
             "Full prefills routed through sequence-parallel ring attention",

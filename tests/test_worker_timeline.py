@@ -5,7 +5,9 @@ a request waited on ``engine.queue_wait``, and the worker's phases as
 ``model=test``, the ragged kernel interpreted."""
 
 import asyncio
+import functools
 import glob
+import math
 
 import pytest
 
@@ -16,11 +18,16 @@ from mcpx.telemetry.flight import PROFILE_PHASES, SEGMENT_PARTS
 from mcpx.telemetry.tracing import Tracer
 from tests.test_hold_dispatch import HoldsTillDone
 
+ROW_FORWARDS = ("row_forwards", "row_forwards_live", "row_forwards_done",
+                "row_forwards_empty")
 TIMELINE = ("seq", "prefill_rows", "hold_joined_rows", "period_ms", "sync_ms",
-            "idle_ms", "hold_ms", "host_ms", *SEGMENT_PARTS)
+            "idle_ms", "hold_ms", "host_ms", *SEGMENT_PARTS, "ready_gap_ms",
+            "starved_ms", *ROW_FORWARDS)
+PLACEMENT = ("first_seq", "last_seq", "segments", "live_forwards", "ridden_forwards",
+             "missed_dispatches", "admit_host_ms", "behind_ms")
 
 
-def make_engine(rows: int) -> InferenceEngine:
+def make_engine(rows: int, **sections) -> InferenceEngine:
     return InferenceEngine(
         MCPXConfig.from_dict(
             {
@@ -32,18 +39,19 @@ def make_engine(rows: int) -> InferenceEngine:
                     "max_decode_len": 64,
                     "temperature": 0.0,
                 },
+                **sections,
             }
         )
     )
 
 
-async def traced(eng, tracer, text: str, n: int) -> list:
-    """One traced unconstrained generate of exactly ``n`` tokens' budget;
-    returns the request's spans."""
+async def traced(eng, tracer, text: str, n: int, constrained: bool = False) -> list:
+    """One traced generate of exactly ``n`` tokens' budget, unconstrained
+    unless asked otherwise; returns the request's spans."""
     root = tracer.start_request("/plan")
     with tracing.activate(root):
         await eng.generate(
-            eng.tokenizer.encode(text), max_new_tokens=n, constrained=False,
+            eng.tokenizer.encode(text), max_new_tokens=n, constrained=constrained,
             temperature=0.0,
         )
     tracer.finish(root)
@@ -54,14 +62,25 @@ def named(spans: list, name: str) -> list:
     return [s for s in spans if s.name == name]
 
 
+def assert_row_forwards_add_up(a: dict, rows: int) -> None:
+    """The integer identity on one engine.segment span's attributes."""
+    assert a["row_forwards"] == rows * a["forwards"]
+    assert a["row_forwards"] == sum(a[k] for k in ROW_FORWARDS[1:])
+    assert min(a[k] for k in ROW_FORWARDS) >= 0
+
+
 @pytest.mark.parametrize("held", [False, True], ids=["own-estimate", "held"])
 def test_segment_spans_carry_the_segments_timeline_and_it_tiles(held):
     """Every engine.segment span of one harvest carries the same timeline
     (seq, prefill_rows, hold_joined_rows, period_ms, sync_ms, idle_ms,
-    hold_ms, host_ms and its parts), and host_ms + sync_ms + idle_ms +
-    hold_ms is the wall between two consecutive ready stamps: checked
-    against the spans' own ends, which the worker stamps a few statements
-    after the ready stamp. Once with the pacer's own estimate (on a CPU
+    hold_ms, host_ms and its parts, ready_gap_ms, starved_ms, the
+    row-forwards by state), and host_ms + sync_ms + idle_ms + hold_ms is
+    the wall between two consecutive ready stamps: ``ready_gap_ms``, cut at
+    the worker's own stamps. Against the spans' clock the stamps are held
+    by ORDER alone: until ISSUE 40 the sum was compared, at 2% or 5 ms,
+    with the difference of two span ends, which the worker stamps a few
+    statements after the ready stamp, and on a loaded machine one end in a
+    hundred came 10 ms late. Once with the pacer's own estimate (on a CPU
     that may hold or not), once with every segment held (ISSUE 29)."""
 
     async def go():
@@ -101,7 +120,12 @@ def test_segment_spans_carry_the_segments_timeline_and_it_tiles(held):
         assert seqs == list(range(seqs[0], seqs[-1] + 1))  # none skipped
         first = by_seq[seqs[0]][0].attrs
         assert first["prefill_rows"] == 3  # the gathered cohort's prefills
-        tiled = wall = 0.0
+        # A ready stamp is the first one plus the gaps since. On the spans'
+        # clock it lies before its own span's end (stamped a few statements
+        # later) and after the start of the span behind it (the worker
+        # dispatches the next segment, THEN fetches): one first stamp has
+        # to fit every such bracket, however late a loaded machine ran.
+        since_first, earliest, latest = 0.0, -math.inf, by_seq[seqs[0]][0].t1
         for prev, cur in zip(seqs, seqs[1:]):
             a = by_seq[cur][0].attrs
             parts = sum(a[k] for k in SEGMENT_PARTS)
@@ -110,15 +134,22 @@ def test_segment_spans_carry_the_segments_timeline_and_it_tiles(held):
             assert 0.0 <= a["hold_ms"]
             assert 0 <= a["hold_joined_rows"] <= a["prefill_rows"]
             window = a["host_ms"] + a["sync_ms"] + a["idle_ms"] + a["hold_ms"]
+            assert window == pytest.approx(a["ready_gap_ms"], abs=0.01)
             # Dispatched before the previous segment was ready or after:
             # either way the period ends at this ready stamp and starts no
-            # earlier than the previous one.
-            assert 0.0 < a["period_ms"] <= window + 0.01
-            ends = (by_seq[cur][0].t1 - by_seq[prev][0].t1) * 1e3
-            assert window == pytest.approx(ends, rel=0.02, abs=5.0)
-            tiled += window
-            wall += ends
-        assert tiled == pytest.approx(wall, rel=0.02)
+            # earlier than the previous one; and what the device waited
+            # for its first work lies before that start.
+            assert 0.0 < a["period_ms"] <= a["ready_gap_ms"] + 0.001
+            assert 0.0 <= a["starved_ms"] <= a["ready_gap_ms"] - a["period_ms"] + 0.002
+            earliest = max(earliest, by_seq[cur][0].t0 - since_first)
+            since_first += a["ready_gap_ms"] / 1e3
+            latest = min(latest, by_seq[cur][0].t1 - since_first)
+        assert earliest <= latest + 1e-5
+        # Pipelined, two deep: every segment but the first of a burst was
+        # on the device's queue before the one ahead of it was ready.
+        assert all(by_seq[q][0].attrs["starved_ms"] == 0.0 for q in seqs[1:])
+        for q in seqs:
+            assert_row_forwards_add_up(by_seq[q][0].attrs, rows=4)
         if held:
             # Three rows of four taken: segments behind the first were
             # held, so the worker waited in ``hold`` while the device
@@ -127,6 +158,130 @@ def test_segment_spans_carry_the_segments_timeline_and_it_tiles(held):
             assert eng.queue_stats()["worker_profile"]["phases"]["hold"]["count"] > 0
 
     asyncio.run(asyncio.wait_for(go(), 240))
+
+
+@functools.lru_cache(maxsize=None)
+def placed_plans() -> dict:
+    """One engine of 4 rows, served once for the tests below: three traced
+    unconstrained requests at once (one token a live forward: no grammar,
+    so nothing forced and no draft), then one traced constrained one (the
+    prompt draft and the grammar's forced tokens ride along: more than one
+    token a forward). Returns each request's spans by kind."""
+
+    async def go():
+        eng = make_engine(rows=4)
+        await eng.start()
+        try:
+            tracer = Tracer(None, enabled=True, sample_rate=1.0)
+            await traced(eng, tracer, "warm the shapes", 40)
+            plain = await asyncio.gather(
+                traced(eng, tracer, "first request of three", 56),
+                traced(eng, tracer, "the second request", 40),
+                traced(eng, tracer, "a third", 24),
+            )
+            drafted = await traced(eng, tracer, "plan: compose. JSON:", 48, constrained=True)
+        finally:
+            await eng.aclose()
+        return {"plain": plain, "drafted": [drafted]}
+
+    return asyncio.run(asyncio.wait_for(go(), 240))
+
+
+def one(spans: list, name: str):
+    (span,) = named(spans, name)
+    return span
+
+
+@pytest.mark.parametrize("kind", ["plain", "drafted"])
+def test_row_forwards_by_state_add_up_on_every_segment(kind):
+    """live + done + empty == rows x forwards, exactly, on every
+    engine.segment span; a row is live in no more forwards than ran."""
+    segments = [s for spans in placed_plans()[kind] for s in named(spans, "engine.segment")]
+    assert segments
+    for s in segments:
+        a = s.attrs
+        assert_row_forwards_add_up(a, rows=4)
+        assert 0 < a["live_forwards"] <= a["forwards"]  # this row's own
+        assert a["live_forwards"] <= a["row_forwards_live"]  # the slab's
+
+
+@pytest.mark.parametrize("kind", ["plain", "drafted"])
+def test_a_plans_live_forwards_are_its_rows_counter_summed_over_its_segments(kind):
+    """engine.decode's placement: first_seq..last_seq are the segments
+    whose spans the row wrote, none skipped; live_forwards is the sum of
+    the row's device counter over them and ridden_forwards of their
+    forwards. With no grammar (nothing forced, no draft) and greedy decode
+    a live forward is one token; with the prompt draft on it is at least
+    one."""
+    for spans in placed_plans()[kind]:
+        d = one(spans, "engine.decode").attrs
+        assert set(PLACEMENT) <= set(d)
+        segs = sorted(named(spans, "engine.segment"), key=lambda s: s.attrs["seq"])
+        assert [s.attrs["seq"] for s in segs] == list(range(d["first_seq"], d["last_seq"] + 1))
+        assert d["segments"] == len(segs)
+        assert d["live_forwards"] == sum(s.attrs["live_forwards"] for s in segs)
+        assert d["ridden_forwards"] == sum(s.attrs["forwards"] for s in segs)
+        # (the admission's first sample counts in the first segment's delta)
+        assert d["tokens"] == sum(s.attrs["tokens"] for s in segs)
+        assert d["missed_dispatches"] >= 0
+        if kind == "plain":
+            assert d["tokens"] == d["live_forwards"]
+        else:
+            assert d["tokens"] > d["live_forwards"] > 0
+
+
+@pytest.mark.parametrize("kind", ["plain", "drafted"])
+def test_a_plans_wall_tiles_against_the_ready_stamps(kind):
+    """queue wait + the admission on the host + behind_ms + the period_ms
+    of segments first_seq..last_seq + deliver_ms tile engine.generate's
+    duration: every piece is cut at the worker's own stamps (enqueue,
+    admission's start and end, the device's start of first_seq, the ready
+    stamps) but the span's two ends, a few statements outside them."""
+    for spans in placed_plans()[kind]:
+        gen, wait, d = (one(spans, n) for n in ("engine.generate", "engine.queue_wait", "engine.decode"))
+        periods = [s.attrs["period_ms"] for s in named(spans, "engine.segment")]
+        assert len(periods) == d.attrs["segments"]
+        assert wait.attrs["since_ready_ms"] > 0.0  # the warm request's last harvest
+        pieces = (wait.duration_ms, d.attrs["admit_host_ms"], d.attrs["behind_ms"],
+                  sum(periods), gen.attrs["deliver_ms"])
+        assert min(pieces) >= 0.0
+        assert sum(pieces) <= gen.duration_ms + 0.05  # they lie inside the span
+        assert sum(pieces) == pytest.approx(gen.duration_ms, rel=0.02, abs=5.0)
+
+
+def test_the_gathered_plans_rode_the_same_first_segment_and_none_missed_a_dispatch():
+    plain = [one(spans, "engine.decode").attrs for spans in placed_plans()["plain"]]
+    assert len({d["first_seq"] for d in plain}) == 1
+    assert [d["missed_dispatches"] for d in plain] == [0, 0, 0]
+    # budgets of 56, 40 and 24 tokens: the shorter plans left earlier
+    assert plain[0]["last_seq"] > plain[1]["last_seq"] > plain[2]["last_seq"]
+    assert plain[0]["live_forwards"] == 56 and plain[2]["live_forwards"] == 24
+
+
+def test_row_forwards_are_counted_with_tracing_off():
+    """``mcpx_engine_row_forwards_total{state}`` moves with no tracer, no
+    profiler and no span: three increments a harvest, from the vector the
+    harvest's one fetch brings back anyway."""
+
+    async def go():
+        eng = make_engine(rows=4, tracing={"enabled": False})
+        await eng.start()
+        try:
+            assert eng._profiler is None
+            res = await eng.generate(
+                eng.tokenizer.encode("no span rides this one"), max_new_tokens=24,
+                constrained=False, temperature=0.0,
+            )
+        finally:
+            await eng.aclose()
+        return res, eng.metrics.registry.get_sample_value
+
+    res, sample = asyncio.run(asyncio.wait_for(go(), 240))
+    by_state = {s: sample("mcpx_engine_row_forwards_total", {"state": s})
+                for s in ("live", "done", "empty")}
+    assert by_state["live"] == res.generated_tokens == 24
+    assert by_state["empty"] >= 3 * by_state["live"]  # three of four rows held nothing
+    assert sum(by_state.values()) == 4 * sample("mcpx_engine_decode_forwards_total")
 
 
 @pytest.mark.parametrize(
@@ -157,7 +312,7 @@ def test_the_hold_metrics_read_attributes_the_engine_writes(metric, num, den, mo
         "num_per": "segment", "den_per": "segment",
     }
     written = set(
-        E._segment_timeline(1, 2, 1, 0.0, 1.0, 0.5, dict.fromkeys(PROFILE_PHASES, 0.0))
+        E._segment_timeline(1, 2, 1, 0.0, 1.0, 0.5, dict.fromkeys(PROFILE_PHASES, 0.0), 0.6)
     ) | {"forwards", "tokens"}
     assert {num, den} <= written
     with open(os.path.join(root, "BENCHMARK.json")) as f:
