@@ -18,17 +18,28 @@ queries of the padded ``[B, S_max, ...]`` window —
 Per-row ``q_len`` / ``start_pos`` / page tables are scalar-prefetched
 DATA, so one compiled launch serves any prefill/decode/spec mix — compile
 count is a function of the padded window shape alone (the Ragged Paged
-Attention design, PAPERS.md). Grid is ``(B, K)``; each program DMAs its
-row's pages HBM→VMEM one at a time and accumulates flash-style (online
-softmax in fp32), so
+Attention design, PAPERS.md). Grid is ``(B, cdiv(K, H_BLK), cdiv(S, Sq))``:
+a program holds ``H_BLK`` KV heads of its row and walks the row's pages a
+BLOCK of ``P_BLK`` pages at a time — each page its own HBM→VMEM DMA for all
+of the program's heads (the table scatters pages), a block's DMAs all in
+flight at once and the next block's issued before this one is multiplied —
+accumulating flash-style (online softmax in fp32). ``_blocking`` derives
+``H_BLK`` and ``P_BLK`` from the shapes under ``VMEM_BUDGET``: every local
+head in a decode window, as few as fit in a 128-query prefill block; a lane
+width of keys a step (8 pages of 16), two where the heads leave room. So
   - no ``[B, S_max]`` dense cache is ever materialised (ragged batches share
     the pool — the RPA paper's point, PAPERS.md),
   - a row streams only ``cdiv(start + q_len, page_size)`` pages — a decode
-    row pays decode traffic even when batched next to a prefill row,
-  - per-page tiles are ``[page_size, head_dim]`` — contiguous,
-    lane-aligned (head_dim multiple of 128), no in-kernel transposes,
-  - arithmetic is ``q [S*G, hd] @ k.T -> [S*G, page_size]`` then
-    ``p @ v -> [S*G, hd]``: MXU matmuls with GQA group size G rows.
+    row pays decode traffic even when batched next to a prefill row; pages
+    of a block past the row's last are neither fetched nor seen,
+  - a key block is ``[H_BLK, P_BLK * page_size, head_dim]`` — page rows
+    contiguous, lane-aligned (head_dim multiple of 128), no in-kernel
+    transposes,
+  - arithmetic is one batched product over the program's heads,
+    ``q [H, S*G, hd] @ k.T -> [H, S*G, P_BLK * page_size]`` (operands as
+    stored, float32 out) then ``p @ v -> [H, S*G, hd]`` (float32): MXU
+    matmuls with GQA group size G rows; a cell's 85-135-token context is
+    one such step.
 
 The jnp reference implements identical semantics by gathering pages; kernel
 tests assert exact agreement in interpret mode on CPU (SURVEY.md §4.2) and
@@ -39,6 +50,7 @@ body TPUs run.
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -153,28 +165,82 @@ def _ragged_n_pages(start, qn, page_size: int, p_max: int):
     return jnp.where(qn > 0, n, 0)
 
 
-def _ragged_kernel(*refs, page_size: int, n_buf: int, windowed: bool):
+# What the blocking may spend, counted in ``_blocking``: the page buffers in
+# flight, the pipelined q/out blocks, the three carries and one score tile.
+# Mosaic's default scoped-VMEM limit is 16 MiB; the rest is left to what the
+# compiler materialises beside them (the float32 copy of a value block, the
+# softmax weights, the masks), which are of the counted tiles' sizes.
+VMEM_BUDGET = 6 * 2**20
+# Keys a compute step multiplies: a lane width of scores, and a second one
+# where the program's heads still fit (``_blocking``).
+KEY_BLOCK = 128
+# Queries per kernel program: a wider window runs as blocks of this many
+# (a whole 1024-token prefill window's carries fit no program). Each query
+# block re-streams the row's pages up to its own last query (PERF.md, open
+# questions).
+Q_BLOCK = 128
+
+
+def _vmem_bytes(h_blk, p_blk, *, G, hd, page_size, sq, pool_itemsize, q_itemsize):
+    """Bytes of VMEM one program holds at this blocking: K and V buffers of
+    two blocks in flight, the q and out blocks (double-buffered by the
+    pipeline), the three float32 carries (m and l one lane-padded column
+    each) and one float32 score tile."""
+    rows, keys = sq * G, p_blk * page_size
+    buffers = 2 * 2 * h_blk * keys * hd * pool_itemsize
+    q_out = 2 * 2 * h_blk * rows * hd * q_itemsize
+    carries = h_blk * rows * (hd + 2 * 128) * 4
+    scores = h_blk * rows * max(keys, 128) * 4
+    return buffers + q_out + carries + scores
+
+
+def _blocking(K, G, hd, page_size, sq, pool_itemsize, q_itemsize, p_max):
+    """``(H_BLK, P_BLK)``: KV heads a program holds and key pages a compute
+    step covers, from the shapes alone. ``P_BLK`` pages make a lane width
+    of keys (``KEY_BLOCK``), no more than the table is wide; ``H_BLK`` is
+    every local head where that fits ``VMEM_BUDGET`` (a decode window), else
+    the heads split evenly over the fewest programs that do (a 128-query
+    prefill block at a wide head takes one). Heads come first: a program
+    more costs a row a DMA round trip nothing overlaps, a step more does
+    not. What room the heads leave goes to a second lane width of keys
+    (the cells' decode windows: a 135-token context is then one step). A
+    layout whose single head does not fit halves the key block instead."""
+    size = functools.partial(
+        _vmem_bytes, G=G, hd=hd, page_size=page_size, sq=sq,
+        pool_itemsize=pool_itemsize, q_itemsize=q_itemsize,
+    )
+    p_blk = max(1, min(KEY_BLOCK // page_size, p_max))
+    while p_blk > 1 and size(1, p_blk) > VMEM_BUDGET:
+        p_blk //= 2
+    fit = max([h for h in range(1, K + 1) if size(h, p_blk) <= VMEM_BUDGET], default=1)
+    h_blk = pl.cdiv(K, pl.cdiv(K, fit))
+    if p_blk * page_size == KEY_BLOCK and size(h_blk, 2 * p_blk) <= VMEM_BUDGET:
+        p_blk = min(2 * p_blk, p_max)
+    return h_blk, p_blk
+
+
+def _ragged_kernel(*refs, page_size: int, p_blk: int, n_heads: int, windowed: bool):
     """``refs``: the scalar prefetch — page_table [B, Pmax], start_pos [B],
     q_lens [B] (live queries per row; 0 = idle row), layer [1] (which
     layer's pool slice to stream) and, ``windowed``, window [1] (this
-    call's attention window) — all SMEM; then the blocks q [1, Sq, 1, G, hd]
-    VMEM (one query block of the window), k_pages / v_pages [K, L, N, Psz,
-    hd] ANY (they stay in HBM), out [1, Sq, 1, G, hd] VMEM; then the scratch
-    k_buf / v_buf [NBUF, Psz, hd] VMEM and their DMA semaphores [NBUF]."""
+    call's attention window) — all SMEM; then the blocks q [1, Sq, H, G, hd]
+    VMEM (one query block of the window, H = H_BLK heads), k_pages /
+    v_pages [K, L, N, Psz, hd] ANY (they stay in HBM), out [1, Sq, H, G, hd]
+    VMEM; then the scratch k_buf / v_buf [2, H, P_BLK * Psz, hd] VMEM (two
+    key blocks in flight) and their DMA semaphores [2, 2]."""
     page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
     window_ref = refs[4] if windowed else None
-    q_ref, k_pages_ref, v_pages_ref, out_ref, k_buf, v_buf, sem_k, sem_v = refs[
-        4 + windowed :
-    ]
+    q_ref, k_pages_ref, v_pages_ref, out_ref, k_buf, v_buf, sem = refs[4 + windowed :]
     b = pl.program_id(0)
-    kh = pl.program_id(1)
+    hb = pl.program_id(1)
     layer = layer_ref[0]
-    S, G, hd = q_ref.shape[1], q_ref.shape[3], q_ref.shape[4]
+    S, H, G, hd = q_ref.shape[1:]
+    keys = p_blk * page_size
     # Query block j of the row's window is itself a ragged window: it
     # starts S*j positions further into the cache and holds whatever part
     # of the row's live queries falls inside it. Everything below is the
     # whole-window kernel applied to that sub-window, so the carries and
-    # the q/out blocks stay [S*G, hd] however long a prefill window is.
+    # the q/out blocks stay [H, S*G, hd] however long a prefill window is.
     q0 = pl.program_id(2) * S
     start = start_pos_ref[b] + q0
     qn = jnp.clip(q_lens_ref[b] - q0, 0, S)
@@ -186,14 +252,21 @@ def _ragged_kernel(*refs, page_size: int, n_buf: int, windowed: bool):
     n_pages = _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1])
     # Under a window the block's FIRST query sees no key before position
     # start - window + 1, and a later query sees none earlier still: pages
-    # wholly before it are never streamed. (No window: every page from 0.)
-    n_stream, page_at = n_pages, lambda i: i
+    # wholly before it are never streamed, and the key blocks are counted
+    # from the first page it can see. (No window: every page from 0.)
+    first = 0
     if windowed:
         window = window_ref[0]
         first = jnp.minimum(jnp.maximum(start - window + 1, 0) // page_size, n_pages)
-        n_stream, page_at = n_pages - first, lambda i: first + i
+    n_stream = n_pages - first
+    n_blocks = pl.cdiv(n_stream, p_blk)
 
-    q = q_ref[0, :, 0].reshape(S * G, hd).astype(jnp.float32)
+    # The heads of this program, gathered head-major: [H, S*G, hd]. The
+    # q @ k.T product takes both operands as they are stored where they
+    # are stored alike (two bfloat16 values multiply exactly in float32).
+    q = jnp.stack([q_ref[0, :, h].reshape(S * G, hd) for h in range(H)])
+    if q.dtype != k_buf.dtype:
+        q = q.astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     # Visible length per q row r (row r is query r//G): start + r//G + 1;
     # pad queries (r//G >= qn) see nothing and zero out below.
@@ -201,47 +274,87 @@ def _ragged_kernel(*refs, page_size: int, n_buf: int, windowed: bool):
     q_valid = row_q < qn  # [S*G, 1]
     vis = start + row_q + 1  # [S*G, 1]
 
-    def dma_k(slot, page_idx):
-        return pltpu.make_async_copy(
-            k_pages_ref.at[kh, layer, page_table_ref[b, page_idx]],
-            k_buf.at[slot],
-            sem_k.at[slot],
-        )
+    # A page is its own DMA (the table scatters them), for all of the
+    # program's heads at once: H runs of one page each. The last head block
+    # of a K that H does not divide copies only the heads there are.
+    tail = n_heads % H
+    head_counts = [(H, None)] if not tail else [
+        (H, hb < pl.num_programs(1) - 1), (tail, hb == pl.num_programs(1) - 1)
+    ]
 
-    def dma_v(slot, page_idx):
-        return pltpu.make_async_copy(
-            v_pages_ref.at[kh, layer, page_table_ref[b, page_idx]],
-            v_buf.at[slot],
-            sem_v.at[slot],
-        )
+    def page_copies(slot, blk, p, nh):
+        page = page_table_ref[b, first + blk * p_blk + p]
+        rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+        heads = pl.ds(hb * H, nh)
+        return [
+            pltpu.make_async_copy(
+                pages.at[heads, layer, page], buf.at[slot, pl.ds(0, nh), rows], sem.at[i, slot]
+            )
+            for i, (pages, buf) in enumerate(((k_pages_ref, k_buf), (v_pages_ref, v_buf)))
+        ]
 
-    # Fill the pipeline: up to n_buf DMAs in flight hides per-transfer
-    # latency (the decode-attention bottleneck at small page sizes).
-    for j in range(n_buf):
+    def each_page(slot, blk, act):
+        """``act`` (start / wait) on the DMAs of block ``blk``'s pages that
+        the row streams; returns how many those are."""
+        n_here = jnp.minimum(n_stream - blk * p_blk, p_blk)
 
-        @pl.when(j < n_stream)
-        def _():
-            dma_k(j, page_at(j)).start()
-            dma_v(j, page_at(j)).start()
+        def one(p, carry):
+            for nh, here in head_counts:
+                def go(nh=nh):
+                    for copy in page_copies(slot, blk, p, nh):
+                        act(copy)
+
+                if here is None:
+                    go()
+                else:
+                    pl.when(here)(go)
+            return carry
+
+        lax.fori_loop(0, n_here, one, 0)
+        return n_here
+
+    def start_block(slot, blk):
+        n_here = each_page(slot, blk, operator.methodcaller("start"))
+
+        # Pages of the block past the row's last are not fetched. Their keys
+        # are masked by position; their values meet a weight of exactly 0,
+        # which only a finite value leaves 0: what the buffer held before
+        # this kernel ran is anything.
+        def blank(p, carry):
+            rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+            v_buf[slot, :, rows] = jnp.zeros((H, page_size, hd), v_buf.dtype)
+            return carry
+
+        lax.fori_loop(n_here, p_blk, blank, 0)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        start_block(0, 0)
 
     def body(i, carry):
-        m, l, acc = carry  # [S*G, 1], [S*G, 1], [S*G, hd] fp32
-        slot = lax.rem(i, n_buf)
+        m, l, acc = carry  # [H, S*G, 1], [H, S*G, 1], [H, S*G, hd] fp32
+        slot = lax.rem(i, 2)
 
-        dma_k(slot, page_at(i)).wait()
-        dma_v(slot, page_at(i)).wait()
-        k_tile = k_buf[slot].astype(jnp.float32)  # [Psz, hd]
+        # The next block's pages are in flight while this one is multiplied.
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            start_block(1 - slot, i + 1)
+
+        each_page(slot, i, operator.methodcaller("wait"))
+        k_tile = k_buf[slot]  # [H, keys, hd]
+        if k_tile.dtype != q.dtype:
+            k_tile = k_tile.astype(jnp.float32)
         v_tile = v_buf[slot].astype(jnp.float32)
 
         s = lax.dot_general(
-            q, k_tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [S*G, Psz]
+            q, k_tile, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        )  # [H, S*G, keys]
         s = s * scale
-        pos = page_at(i) * page_size + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        seen = q_valid & (pos < vis)
+        pos = (first + i * p_blk) * page_size + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        seen = q_valid & (pos < vis)  # [S*G, keys]
         if windowed:
             seen &= pos >= vis - window
-        s = jnp.where(seen, s, NEG_INF)
+        s = jnp.where(seen[None], s, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -249,41 +362,26 @@ def _ragged_kernel(*refs, page_size: int, n_buf: int, windowed: bool):
         # NEG_INF, where exp(s - m_new) would be exp(0) = 1 — guard so
         # their weights stay exactly 0 and the l == 0 fallthrough below
         # emits the reference's zeros (with no window live queries always
-        # see page 0's position 0, so the guard never fires for them; under
+        # see block 0's position 0, so the guard never fires for them; under
         # a window a block's later queries may see nothing of its first
-        # pages, and the guard keeps those tiles at weight 0 for them).
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))  # [S*G, Psz]
+        # key blocks, and the guard keeps those tiles at weight 0 for them).
+        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))  # [H, S*G, keys]
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + lax.dot_general(
-            p, v_tile, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p, v_tile, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )
-
-        # Refill the slot we just drained with the page n_buf ahead.
-        @pl.when(i + n_buf < n_stream)
-        def _():
-            dma_k(slot, page_at(i + n_buf)).start()
-            dma_v(slot, page_at(i + n_buf)).start()
-
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((S * G, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((S * G, 1), jnp.float32)
-    acc0 = jnp.zeros((S * G, hd), jnp.float32)
-    m, l, acc = lax.fori_loop(0, n_stream, body, (m0, l0, acc0))
+    m0 = jnp.full((H, S * G, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((H, S * G, 1), jnp.float32)
+    acc0 = jnp.zeros((H, S * G, hd), jnp.float32)
+    m, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
     out = jnp.where(l > 0.0, acc / jnp.maximum(l, 1e-30), 0.0)
-    out_ref[0, :, 0] = out.reshape(S, G, hd).astype(out_ref.dtype)
+    for h in range(H):
+        out_ref[0, :, h] = out[h].reshape(S, G, hd).astype(out_ref.dtype)
 
 
-# Queries per kernel program. The q/out blocks and the three f32 carries
-# are [Q_BLOCK * G, hd]; at the 2B head layout (G = 8, hd = 256) that is
-# 1 MiB per carry, which with the double-buffered blocks and the score
-# tiles stays inside Mosaic's default scoped-VMEM limit. A whole 1024-token
-# prefill window in one program does not. Each query block re-streams the
-# row's pages up to its own last query (PERF.md, open questions).
-Q_BLOCK = 128
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "n_buf"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def ragged_paged_attention(
     q: jax.Array,  # [B, S, K, G, hd] — padded query windows
     k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers (stays in HBM)
@@ -295,30 +393,29 @@ def ragged_paged_attention(
     window: "jax.Array | int | None" = None,
     *,
     interpret: bool = False,
-    n_buf: int = 4,
 ) -> jax.Array:
-    """The ragged mixed-phase kernel: grid (B, K, cdiv(S, Sq)); ONE program
-    streams a row's pages once for a block of Sq <= Q_BLOCK of its queries
-    ([Sq*G, hd] MXU rows/page vs [G, hd] for a single-query kernel folded
-    over B*S programs — Sq times fewer DMA issues, Sq*G-row matmuls instead
-    of G-row). Decode, draft and verify windows are one block. Row
-    raggedness (``q_lens``) is scalar-prefetched DATA like the start
-    offsets and page tables, so suffix-prefill, plain-decode and
-    spec-verify rows share ONE launch of ONE executable per padded window
-    shape — compile count is independent of the phase mix. The pools hold
-    every layer ([K, L, ...]) so the decode loop can carry them through
-    lax.scan and the kernel streams just ``layer``'s slice — slicing
-    host-side would materialise a per-layer copy. The kernel only READS
-    the pools: the window's own K/V rows are already in them when it is
-    called, written by ``paged_decode._write_kv_window`` as whole pages in
-    this same shape, so the pools reach the Mosaic call in the layout they
-    are kept in and XLA copies nothing to reconcile the two.
+    """The ragged mixed-phase kernel: grid (B, cdiv(K, H_BLK), cdiv(S, Sq));
+    ONE program streams a row's pages once, a block of P_BLK pages a step,
+    for H_BLK of its KV heads and a block of Sq <= Q_BLOCK of its queries
+    (``_blocking`` derives both from the shapes). Decode, draft and verify
+    windows are one query block. Row raggedness (``q_lens``) is
+    scalar-prefetched DATA like the start offsets and page tables, so
+    suffix-prefill, plain-decode and spec-verify rows share ONE launch of
+    ONE executable per padded window shape — compile count is independent
+    of the phase mix. The pools hold every layer ([K, L, ...]) so the
+    decode loop can carry them through lax.scan and the kernel streams just
+    ``layer``'s slice — slicing host-side would materialise a per-layer
+    copy. The kernel only READS the pools: the window's own K/V rows are
+    already in them when it is called, written by
+    ``paged_decode._write_kv_window`` as whole pages in this same shape, so
+    the pools reach the Mosaic call in the layout they are kept in and XLA
+    copies nothing to reconcile the two.
 
     ``window`` (None: no window, and the program this always was) is this
     CALL's attention window, one more prefetched scalar beside ``layer``,
     so the layers of one scan may differ in it: a query at position p sees
-    keys in (p - window, p]. The page loop starts at the first page the
-    block's first query can see."""
+    keys in (p - window, p]. The key blocks start at the first page the
+    query block's first query can see."""
     B, S, K, G, hd = q.shape
     windowed = window is not None
     _, _, _, page_size, _ = k_pages.shape
@@ -330,29 +427,31 @@ def ragged_paged_attention(
     s_pad = pl.cdiv(S, sq) * sq
     if s_pad != S:
         q = jnp.pad(q, ((0, 0), (0, s_pad - S), (0, 0), (0, 0), (0, 0)))
+    h_blk, p_blk = _blocking(
+        K, G, hd, page_size, sq, k_pages.dtype.itemsize, q.dtype.itemsize, page_table.shape[1]
+    )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 + windowed,
-        grid=(B, K, s_pad // sq),
+        grid=(B, pl.cdiv(K, h_blk), s_pad // sq),
         in_specs=[
             pl.BlockSpec(
-                (1, sq, 1, G, hd), lambda b, k, j, *_: (b, j, k, 0, 0), memory_space=pltpu.VMEM
+                (1, sq, h_blk, G, hd), lambda b, h, j, *_: (b, j, h, 0, 0), memory_space=pltpu.VMEM
             ),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (1, sq, 1, G, hd), lambda b, k, j, *_: (b, j, k, 0, 0), memory_space=pltpu.VMEM
+            (1, sq, h_blk, G, hd), lambda b, h, j, *_: (b, j, h, 0, 0), memory_space=pltpu.VMEM
         ),
         scratch_shapes=[
-            pltpu.VMEM((n_buf, page_size, hd), k_pages.dtype),
-            pltpu.VMEM((n_buf, page_size, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((n_buf,)),
-            pltpu.SemaphoreType.DMA((n_buf,)),
+            pltpu.VMEM((2, h_blk, p_blk * page_size, hd), k_pages.dtype),
+            pltpu.VMEM((2, h_blk, p_blk * page_size, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     kernel = functools.partial(
-        _ragged_kernel, page_size=page_size, n_buf=n_buf, windowed=windowed
+        _ragged_kernel, page_size=page_size, p_blk=p_blk, n_heads=K, windowed=windowed
     )
     scalars = (jnp.asarray(layer, jnp.int32).reshape(1),)
     if windowed:
@@ -384,7 +483,6 @@ def paged_attention_chunk(
     window: "jax.Array | int | None" = None,
     *,
     interpret: bool = False,
-    n_buf: int = 4,
 ) -> jax.Array:
     """Dense-window chunk attention: the ``q_lens = S`` specialisation of
     ``ragged_paged_attention`` (every window position live — the pre-ragged
@@ -400,7 +498,6 @@ def paged_attention_chunk(
         layer,
         window,
         interpret=interpret,
-        n_buf=n_buf,
     )
 
 

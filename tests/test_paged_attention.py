@@ -58,6 +58,43 @@ def test_kernel_matches_reference(B, K, G, hd, psz, maxlen):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("S", [1, 8], ids=["single-query", "chunk"])
+@pytest.mark.parametrize(
+    "context",
+    [1, 16, 17, 256, 257, 272, 512],
+    ids=["one-key", "one-page", "page-plus-key", "one-block", "block-plus-key",
+         "last-block-one-page", "two-blocks"],
+)
+def test_single_query_and_chunk_kernels_at_the_key_blocks_edges(context, S):
+    """The ``q_lens = S`` and single-query specialisations run the one
+    blocked body: every row's context ends on the same edge of a key block
+    (16 pages of 16 keys), at the cells' head_dim."""
+    from mcpx.engine.kernels.paged_attention import (
+        paged_attention_chunk,
+        paged_attention_chunk_reference,
+    )
+
+    B, K, G, hd, psz, p_max = 2, 2, 2, 128, 16, 33
+    n_pages = B * p_max + 1
+    ks = jax.random.split(jax.random.PRNGKey(context), 3)
+    kp = jax.random.normal(ks[1], (K, 2, n_pages, psz, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 2, n_pages, psz, hd), jnp.float32)
+    table = jnp.asarray(
+        1 + np.random.default_rng(context).permutation(n_pages - 1).reshape(B, p_max), jnp.int32
+    )
+    if S == 1:
+        q = jax.random.normal(ks[0], (B, K, G, hd), jnp.float32)
+        lens = jnp.asarray([context, max(1, context - 1)], jnp.int32)
+        out = paged_attention(q, kp, vp, table, lens, 1, interpret=True)
+        ref = paged_attention_reference(q, kp, vp, table, lens, layer=1)
+    else:
+        q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+        starts = jnp.asarray([context - 1, context], jnp.int32)  # last query: context + S - 1
+        out = paged_attention_chunk(q, kp, vp, table, starts, 1, interpret=True)
+        ref = paged_attention_chunk_reference(q, kp, vp, table, starts, layer=1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
 def test_reference_matches_dense_attention():
     """The paged reference itself must equal vanilla dense attention."""
     B, K, G, hd, psz = 1, 1, 4, 64, 4

@@ -134,8 +134,135 @@ def test_ragged_kernel_blocks_long_windows_over_queries():
         assert np.all(np.asarray(out[b, int(q_lens[b]):]) == 0.0), b
 
 
+# ------------------------------------------------- the blocking's edges
+def _edge_case(contexts, q_lens, S, *, K=2, G=2, hd=16, psz=16, p_max=40, seed=11):
+    """Rows whose last live query sits at cache position ``context - 1``,
+    over a shuffled page table; returns the kernel's arguments."""
+    B = len(contexts)
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    n_pages = B * p_max + 1
+    q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (K, 2, n_pages, psz, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 2, n_pages, psz, hd), jnp.float32)
+    table = 1 + rng.permutation(n_pages - 1).astype(np.int32).reshape(B, p_max)
+    q_lens = np.asarray(q_lens, np.int32)
+    starts = np.maximum(np.asarray(contexts, np.int32) - q_lens, 0)
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(starts), jnp.asarray(q_lens)
+
+
+# One key block is 16 pages of 16 keys here (``_blocking``: two lane widths).
+_BLOCK_EDGES = {
+    "one-page": 16,
+    "one-key": 1,
+    "a-page-and-a-key": 17,
+    "one-block-less-a-key": 255,
+    "exactly-one-block": 256,
+    "one-block-plus-one-key": 257,
+    "last-block-holds-one-page": 272,
+    "last-block-holds-a-page-and-a-key": 273,
+    "exactly-two-blocks": 512,
+    "the-whole-table": 640,
+}
+
+
+@pytest.mark.parametrize("edge", sorted(_BLOCK_EDGES))
+@pytest.mark.parametrize("S", [1, 8], ids=["decode", "segment"])
+def test_ragged_kernel_at_the_key_blocks_edges(edge, S):
+    """A compute step covers a block of 16 pages: contexts that end on a
+    block's edge, one key past it, inside its first page and in a last
+    block of one page agree with the gathered reference, beside a row of
+    another length and an idle row in the same launch."""
+    from mcpx.engine.kernels.paged_attention import _blocking
+
+    assert _blocking(2, 2, 16, 16, S, 4, 4, 40) == (2, 16)
+    ctx = _BLOCK_EDGES[edge]
+    live = min(S, ctx)
+    args = _edge_case([ctx, 300, 77], [live, 1, 0], S)
+    out = ragged_paged_attention(*args, 1, interpret=True)
+    ref = ragged_paged_attention_reference(*args, 1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    assert np.all(np.asarray(out[0, live:]) == 0.0) and np.all(np.asarray(out[2]) == 0.0)
+    assert np.any(np.asarray(out[0, :live]) != 0.0)
+
+
+def test_ragged_kernel_serves_a_mixed_slab_in_one_launch():
+    """A prefill row of Q_BLOCK + 90 queries (two query blocks, the second
+    ragged), a verify window, decode rows at three depths and idle rows, in
+    one launch of one executable."""
+    from mcpx.engine.kernels.paged_attention import Q_BLOCK
+
+    S = Q_BLOCK + 90
+    q_lens = [S, 1, 0, 5, 1, 0, 1]
+    contexts = [S + 37, 256, 600, 257, 16, 0, 639]
+    args = _edge_case(contexts, q_lens, S)
+    out = ragged_paged_attention(*args, 0, interpret=True)
+    ref = ragged_paged_attention_reference(*args, 0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    for b, n in enumerate(q_lens):
+        assert np.all(np.asarray(out[b, n:]) == 0.0), b
+
+
+def test_ragged_kernel_with_a_last_head_block_that_is_not_full():
+    """Three KV heads where two fit a program: the second head block holds
+    one head, copies one head's pages and writes one head's output."""
+    from mcpx.engine.kernels.paged_attention import _blocking
+
+    S, K, G, hd = 128, 3, 2, 256
+    assert _blocking(K, G, hd, 16, S, 4, 4, 40) == (2, 16)
+    args = _edge_case([300, 257, 0, 40], [S, 3, 0, 1], S, K=K, G=G, hd=hd)
+    out = ragged_paged_attention(*args, 1, interpret=True)
+    ref = ragged_paged_attention_reference(*args, 1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    assert np.all(np.isfinite(np.asarray(out)))
+
+
 # ----------------------------------------- lowering for the chip, from CPU
-_HEAD_LAYOUTS = {"2b": (1, 8, 256), "7b": (16, 1, 256)}  # (K, G, head_dim)
+# (K, G, head_dim): Gemma's two, then every cell's: olmo2-1b; mistral-7b
+# (on four chips K local is 4: the shard_map case below); mellum2 and
+# trinity-mini.
+_HEAD_LAYOUTS = {
+    "2b": (1, 8, 256), "7b": (16, 1, 256),
+    "olmo2-1b": (16, 1, 128), "mistral-7b": (8, 4, 128), "mellum2": (4, 8, 128),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(_HEAD_LAYOUTS))
+@pytest.mark.parametrize("sq", [1, 8, 128])
+def test_blocking_fits_its_budget_at_every_layout(layout, sq):
+    """``_blocking`` alone, from shapes: the heads split evenly over the
+    fewest programs that fit, one or two lane widths of keys a step, and what a program
+    then holds (buffers in flight, q/out blocks, carries, a score tile) is
+    inside the budget it names. A decode window takes every head; the 2B
+    layout's 128-query block the one it has."""
+    from mcpx.engine.kernels.paged_attention import VMEM_BUDGET, _blocking, _vmem_bytes
+
+    K, G, hd = _HEAD_LAYOUTS[layout]
+    for k_local in {K, max(1, K // 2)}:  # whole, and split over a model axis of 2
+        h_blk, p_blk = _blocking(k_local, G, hd, 16, sq, 2, 2, 32)
+        assert 1 <= h_blk <= k_local and p_blk in (8, 16)
+        n_programs = -(-k_local // h_blk)
+        assert h_blk == -(-k_local // n_programs)  # even: no program nearly empty
+        size = dict(G=G, hd=hd, page_size=16, sq=sq, pool_itemsize=2, q_itemsize=2)
+        assert _vmem_bytes(h_blk, p_blk, **size) <= VMEM_BUDGET
+        if h_blk < k_local:  # fewer programs would not have fit, even at one lane width
+            assert _vmem_bytes(-(-k_local // (n_programs - 1)), 8, **size) > VMEM_BUDGET
+        if p_blk == 8:  # the second lane width would not have
+            assert _vmem_bytes(h_blk, 16, **size) > VMEM_BUDGET
+        if sq <= 8:
+            assert h_blk == k_local
+    assert _blocking(K, G, hd, 16, sq, 2, 2, 3)[1] == 3  # no wider than the table
+
+
+def test_blocking_halves_the_key_block_when_one_head_does_not_fit():
+    from mcpx.engine.kernels.paged_attention import VMEM_BUDGET, _blocking, _vmem_bytes
+
+    h_blk, p_blk = _blocking(2, 16, 512, 16, 128, 4, 4, 32)
+    assert h_blk == 1 and 1 <= p_blk < 8
+    size = dict(G=16, hd=512, page_size=16, sq=128, pool_itemsize=4, q_itemsize=4)
+    assert _vmem_bytes(1, 2 * p_blk, **size) > VMEM_BUDGET
+
+
 
 
 def _kernel_arg_shapes(S, K, G, hd, B=4, psz=16, p_max=128):
@@ -152,7 +279,8 @@ def _kernel_arg_shapes(S, K, G, hd, B=4, psz=16, p_max=128):
 @pytest.mark.parametrize("S", [1, 8, 9, 128])
 def test_kernel_lowers_for_tpu_on_one_device_and_under_shard_map(layout, S):
     """Cross-platform lowering (jaxpr -> Mosaic MLIR, no chip needed) at the
-    published head layouts for every window class the engine dispatches:
+    published head layouts and every cell's, for every window class the
+    engine dispatches (``_blocking`` gives each its own H_BLK):
     the bare kernel on one device, and the engine's call — the kernel under
     ``shard_map`` — on a 2x2 (data x model) mesh. Interpret mode lowers to
     plain HLO, so without this neither a jaxpr->Mosaic error nor "Mosaic
@@ -163,6 +291,8 @@ def test_kernel_lowers_for_tpu_on_one_device_and_under_shard_map(layout, S):
     args = _kernel_arg_shapes(S, *_HEAD_LAYOUTS[layout])
     jax.jit(ragged_paged_attention).trace(*args).lower(lowering_platforms=("tpu",))
     mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    # as the four-chip cell shapes it: 8 rows a device, a table 32 wide
+    args = _kernel_arg_shapes(S, *_HEAD_LAYOUTS[layout], B=16, p_max=32)
     jax.jit(
         lambda *a: _ragged_kernel_on_mesh(mesh, *a, interpret=False)
     ).trace(*args).lower(lowering_platforms=("tpu",))

@@ -80,6 +80,52 @@ def test_a_query_block_past_the_first_starts_at_its_own_first_visible_page():
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
 
 
+def _deep_case(S, seed, p_max=200):
+    """Contexts deep enough for three key blocks (64 pages of 4 keys each)."""
+    rng = np.random.default_rng(seed)
+    B = 4
+    n_pages = 1 + B * p_max
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, S, K, G, HD), jnp.float32)
+    kp = jax.random.normal(ks[1], (K, 1, n_pages, PSZ, HD), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 1, n_pages, PSZ, HD), jnp.float32)
+    table = 1 + rng.permutation(B * p_max).astype(np.int32).reshape(B, p_max)
+    q_lens = np.asarray([S, 1, 0, max(1, S - 3)], np.int32)
+    starts = np.asarray([601, 256, 200, p_max * PSZ - S], np.int32)
+    return q, kp, vp, table, starts, q_lens
+
+
+# A key block is 64 pages of 4 keys here: 256 keys.
+_WINDOWS = {
+    "first-visible-page-inside-a-block": 260,  # the row at 601: page 85 of 151, not 64 or 128
+    "skips-whole-blocks-to-one-page": 3,
+    "skips-whole-blocks-to-two-blocks": 400,
+    "exactly-one-block": 256,
+    "one-block-plus-one-key": 257,
+    "a-page-less-than-a-block": 252,
+}
+
+
+@pytest.mark.parametrize("name", sorted(_WINDOWS))
+@pytest.mark.parametrize("S", [1, 8], ids=["decode", "segment"])
+def test_windowed_kernel_at_the_key_blocks_edges(S, name):
+    """The key blocks are counted from the first page the query block's
+    first query can see, wherever in the row's pages that falls: a window
+    that starts inside what would be a block from page 0, one that skips
+    whole blocks, and windows that end on a block's edge."""
+    from mcpx.engine.kernels.paged_attention import _blocking
+
+    assert _blocking(K, G, HD, PSZ, S, 4, 4, 200) == (K, 64)
+    window = _WINDOWS[name]
+    q, kp, vp, table, starts, q_lens = _deep_case(S, seed=S + window)
+    want = _dense_masked_softmax(q, kp, vp, table, starts, q_lens, window)
+    out = ragged_paged_attention(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(starts), jnp.asarray(q_lens), 0,
+        jnp.int32(window), interpret=True,
+    )
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
+
+
 def test_the_windows_of_one_scan_may_differ_by_layer():
     """The window is data of the call, not of the executable: one jitted
     function serves a sliding and a full layer."""
@@ -100,15 +146,22 @@ def test_the_dense_chunk_reference_takes_the_window_too():
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize(
+    "Kh,Gh,hd",
+    [(4, 8, 128), (16, 1, 128), (8, 4, 128), (1, 8, 256), (16, 1, 256)],
+    ids=["mellum2", "olmo2-1b", "mistral-7b", "2b", "7b"],
+)
 @pytest.mark.parametrize("S", [1, 8, 128])
-def test_windowed_kernel_lowers_for_tpu_at_the_mellum_head_layout(S):
-    """GQA 32:4 at head_dim 128, the window one more prefetched scalar: the
-    bare kernel on one device and under the engine's shard_map on 2 x 2."""
+def test_windowed_kernel_lowers_for_tpu_at_the_mellum_head_layout(S, Kh, Gh, hd):
+    """GQA 32:4 at head_dim 128 (mellum2, trinity-mini: the cells that pass
+    a window) and every other cell's layout, the window one more prefetched
+    scalar: the bare kernel on one device and under the engine's shard_map
+    on 2 x 2."""
     from mcpx.engine.paged_decode import _ragged_kernel_on_mesh
     from mcpx.parallel.mesh import make_mesh
 
     sd = jax.ShapeDtypeStruct
-    B, Kh, Gh, hd, p_max = 4, 4, 8, 128, 32
+    B, p_max = 4, 32
     pool = sd((Kh, 12, B * p_max + 1, 16, hd), jnp.bfloat16)
     args = (sd((B, S, Kh, Gh, hd), jnp.bfloat16), pool, pool, sd((B, p_max), jnp.int32),
             sd((B,), jnp.int32), sd((B,), jnp.int32), sd((), jnp.int32), sd((), jnp.int32))
