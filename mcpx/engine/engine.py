@@ -461,7 +461,8 @@ class InferenceEngine:
                     self.config.model.quantize != "none",
                 "engine.speculative (the drafter scores against a tied embedding)":
                     ecfg.speculative.enabled,
-                "engine.ring_prefill_min_tokens (ring attention has one rope and no window)":
+                "engine.ring_prefill_min_tokens (ring attention has one rope, no window and "
+                "no expanded latent form)":
                     ecfg.ring_prefill_min_tokens > 0,
             }
             asked = [what for what, on in unsupported.items() if on]
@@ -490,14 +491,15 @@ class InferenceEngine:
                     "interpreter is the CPU tests' route; on a TPU the "
                     "kernel is compiled by Mosaic"
                 )
-            if self.model_cfg.head_dim % 128 != 0:
+            if not mc.kernel_lanes_ok:
                 raise ConfigError(
-                    f"head_dim {self.model_cfg.head_dim} is not a multiple "
+                    f"cache widths {mc.kv_widths} (head_dim, or the latent block's "
+                    "rotated key and kv_lora_rank) are not multiples "
                     "of 128: Mosaic cannot tile the ragged kernel for this "
                     "model on a TPU"
                 )
         self._use_pallas = ecfg.use_pallas and (
-            ecfg.interpret or self.model_cfg.head_dim % 128 == 0
+            ecfg.interpret or mc.kernel_lanes_ok
         )
         # Per-path kernel dispatch counters (decode / suffix-prefill /
         # spec-verify): how often each serving path actually ran, next to
@@ -1063,8 +1065,10 @@ class InferenceEngine:
         if not ecfg.use_pallas:
             blocked = "engine.use_pallas=false (config)"
         elif not on:
+            mc = self.model_cfg
+            what = f"cache widths {mc.kv_widths}" if mc.latent else f"head_dim {mc.head_dim}"
             blocked = (
-                f"head_dim {self.model_cfg.head_dim} % 128 != 0: Mosaic "
+                f"{what} % 128 != 0: Mosaic "
                 "lane tiling rejects the kernel on hardware "
                 "(engine.interpret=true lifts the constraint off-TPU)"
             )
@@ -1415,18 +1419,10 @@ class InferenceEngine:
                     donate_argnames=("paged_k", "paged_v"),
                 ),
             )
-            mc = self.model_cfg
-            kv_bytes_per_token = (
-                2
-                * mc.n_kv_heads
-                * mc.n_layers
-                * mc.head_dim
-                * jnp.dtype(mc.dtype).itemsize
-            )
             self._spill_tier.bind(
                 self._spill_gather_dispatch,
                 self._spill_readmit_dispatch,
-                kv_bytes_per_token,
+                self.model_cfg.kv_bytes_per_token,
             )
         # GET /costs sets the registry's numbers against datasheet peaks:
         # an accelerator missing from the table fails start-up here, not
@@ -2538,6 +2534,8 @@ class InferenceEngine:
             "n_kv_heads": mc.n_kv_heads,
             "n_layers": mc.n_layers,
             "head_dim": mc.head_dim,
+            # the pools' last axes: a latent block's are not head_dim
+            "kv_widths": list(mc.kv_widths),
             "dtype": str(jnp.dtype(mc.dtype).name),
             "vocab_size": self.tokenizer.vocab_size,
         }
@@ -5055,7 +5053,14 @@ class InferenceEngine:
         what reading every expert would come to), ``moe_layer_forwards``
         (forwards x sparse layers), ``weight_bytes_routed`` (touched experts
         x one expert's bytes) and ``weight_bytes_read`` (that plus
-        everything else a forward reads, a constant x forwards). Windowed
+        everything else a forward reads, a constant x forwards);
+        ``moe_tokens_routed`` (live tokens times the experts each chose, held
+        here or not, over the sparse layers: ``moe_assignments`` over it is
+        this device's share of the routing); ``attn_ctx_tokens`` (the context
+        tokens the segment's attention calls read, summed over live rows,
+        forwards and layers), ``attn_row_calls`` (live rows x forwards x
+        layers) and ``kv_bytes_read`` (those tokens times a token's useful
+        cache bytes a layer, ``GemmaConfig.kv_bytes_per_token``). Windowed
         attention:
         ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
         whose position had reached the window."""
@@ -5066,6 +5071,12 @@ class InferenceEngine:
             E = mc.n_experts_held
             per_expert = counts[:E]
             attrs["moe_assignments"] = int(per_expert.sum())
+            # The forward's own counters (moe.add_forward_stats) follow the
+            # layers' E + 2.
+            routed, ctx_tokens, row_calls = (int(c) for c in counts[E + 2 : E + 5])
+            attrs["moe_tokens_routed"] = routed
+            attrs["attn_ctx_tokens"], attrs["attn_row_calls"] = ctx_tokens, row_calls
+            attrs["kv_bytes_read"] = ctx_tokens * (mc.kv_bytes_per_token // mc.n_layers)
             attrs["moe_experts_touched"] = int(counts[E])
             attrs["moe_layer_forwards"] = n_fwd * mc.n_sparse_layers
             attrs["moe_expert_slots"] = attrs["moe_layer_forwards"] * E

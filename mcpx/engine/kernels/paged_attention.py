@@ -519,3 +519,232 @@ def paged_attention(
         q[:, None], k_pages, v_pages, page_table, seq_lens - 1, layer, interpret=interpret
     )
     return out[:, 0]
+
+
+# ------------------------------------------------- latent (absorbed) kernel
+# Rows of the score tile (queries x heads) a latent program holds: its three
+# float32 carries are rows x (latent width + 2 lane columns), 1.5 MB at 512
+# rows of a 512-wide latent.
+LATENT_ROWS = 512
+# Keys a latent step multiplies: two lane widths of scores.
+LATENT_KEY_BLOCK = 256
+
+
+def latent_paged_attention_reference(
+    q_latent: jax.Array,  # [B, S, H, r] — the queries in the latent's space
+    q_rope: jax.Array,  # [B, S, H, w] — their rotated part, zeros past its width
+    rope_pages: jax.Array,  # [1, L, N, Psz, w] — the shared rotated key
+    latent_pages: jax.Array,  # [1, L, N, Psz, r] — the normed latent
+    page_table: jax.Array,  # [B, Pmax]
+    start_pos: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: jax.Array | int = 0,
+    *,
+    scale: float,
+) -> jax.Array:
+    """The absorbed form, pure jnp: one shared "KV head" whose key is the
+    latent beside the rotated key and whose value is the latent again.
+    ``score = (q_latent . c + q_rope . k_rope) * scale`` over the keys a
+    query sees (the chunk contract of ``ragged_paged_attention_reference``),
+    ``out = sum_t p_t c_t`` [B, S, H, r]; pad queries output zeros."""
+    B, S, H, r = q_latent.shape
+    psz = latent_pages.shape[3]
+    n_keys = page_table.shape[1] * psz
+    c = latent_pages[0, layer][page_table].reshape(B, n_keys, r)
+    kr = rope_pages[0, layer][page_table].reshape(B, n_keys, -1)
+    logits = jnp.einsum("bshr,blr->bshl", q_latent, c, preferred_element_type=jnp.float32)
+    logits += jnp.einsum("bshw,blw->bshl", q_rope, kr, preferred_element_type=jnp.float32)
+    logits = logits * scale
+    vis = start_pos[:, None] + jnp.arange(S) + 1  # [B, S]
+    mask = jnp.arange(n_keys)[None, None, :] < vis[:, :, None]
+    logits = jnp.where(mask[:, :, None, :], logits, NEG_INF)
+    weights = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bshl,blr->bshr", weights.astype(c.dtype), c)
+    valid = jnp.arange(S)[None, :] < q_lens[:, None]
+    return jnp.where(valid[:, :, None, None], out, 0).astype(q_latent.dtype)
+
+
+def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float):
+    """``refs``: the scalar prefetch (page_table [B, Pmax], start_pos [B],
+    q_lens [B], layer [1]; SMEM), the blocks q_latent [1, Sq, G, r] and
+    q_rope [1, Sq, G, w] VMEM (one query block, G of the heads), rope_pages /
+    latent_pages [1, L, N, Psz, w / r] ANY, out [1, Sq, G, r] VMEM; then the
+    scratch rope_buf / latent_buf [2, P_BLK * Psz, w / r] (two key blocks in
+    flight) and their DMA semaphores [2, 2]. The whole-window kernel's
+    structure (``_ragged_kernel``) with ONE shared key head: a page is
+    fetched once and its latent rows serve as the keys' first part and as
+    the values."""
+    page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
+    ql_ref, qr_ref, rope_pages_ref, latent_pages_ref, out_ref, rope_buf, latent_buf, sem = refs[4:]
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    S, G, r = ql_ref.shape[1:]
+    w = qr_ref.shape[3]
+    keys = p_blk * page_size
+    q0 = pl.program_id(2) * S
+    start = start_pos_ref[b] + q0
+    qn = jnp.clip(q_lens_ref[b] - q0, 0, S)
+    n_pages = _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1])
+    n_blocks = pl.cdiv(n_pages, p_blk)
+
+    q_lat = ql_ref[0].reshape(S * G, r)
+    q_rot = qr_ref[0].reshape(S * G, w)
+    if q_lat.dtype != latent_buf.dtype:
+        q_lat, q_rot = q_lat.astype(jnp.float32), q_rot.astype(jnp.float32)
+    row_q = lax.broadcasted_iota(jnp.int32, (S * G, 1), 0) // G
+    q_valid = row_q < qn
+    vis = start + row_q + 1
+
+    def page_copies(slot, blk, p):
+        page = page_table_ref[b, blk * p_blk + p]
+        rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+        return [
+            pltpu.make_async_copy(pages.at[0, layer, page], buf.at[slot, rows], sem.at[i, slot])
+            for i, (pages, buf) in enumerate(
+                ((rope_pages_ref, rope_buf), (latent_pages_ref, latent_buf))
+            )
+        ]
+
+    def each_page(slot, blk, act):
+        n_here = jnp.minimum(n_pages - blk * p_blk, p_blk)
+
+        def one(p, carry):
+            for copy in page_copies(slot, blk, p):
+                act(copy)
+            return carry
+
+        lax.fori_loop(0, n_here, one, 0)
+        return n_here
+
+    def start_block(slot, blk):
+        n_here = each_page(slot, blk, operator.methodcaller("start"))
+
+        # As in ``_ragged_kernel``: an unfetched page's latent rows meet a
+        # weight of exactly 0 as VALUES, which only a finite value leaves 0.
+        def blank(p, carry):
+            rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+            latent_buf[slot, rows] = jnp.zeros((page_size, r), latent_buf.dtype)
+            return carry
+
+        lax.fori_loop(n_here, p_blk, blank, 0)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        start_block(0, 0)
+
+    def body(i, carry):
+        m, l, acc = carry  # [S*G, 1], [S*G, 1], [S*G, r] fp32
+        slot = lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            start_block(1 - slot, i + 1)
+
+        each_page(slot, i, operator.methodcaller("wait"))
+        c_tile = latent_buf[slot]  # [keys, r]
+        k_tile = rope_buf[slot]  # [keys, w]
+        if c_tile.dtype != q_lat.dtype:
+            c_tile, k_tile = c_tile.astype(jnp.float32), k_tile.astype(jnp.float32)
+        contract_last = (((1,), (1,)), ((), ()))
+        s = lax.dot_general(q_lat, c_tile, contract_last, preferred_element_type=jnp.float32)
+        s += lax.dot_general(q_rot, k_tile, contract_last, preferred_element_type=jnp.float32)
+        s = s * scale  # [S*G, keys]
+        pos = i * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        s = jnp.where(q_valid & (pos < vis), s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        # The weights in the latent's own type, as the jnp reference rounds
+        # them: a bfloat16 product accumulated in float32.
+        acc_new = acc * alpha + jnp.dot(
+            p.astype(c_tile.dtype), c_tile, preferred_element_type=jnp.float32
+        )
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((S * G, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((S * G, 1), jnp.float32)
+    acc0 = jnp.zeros((S * G, r), jnp.float32)
+    m, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+    out = jnp.where(l > 0.0, acc / jnp.maximum(l, 1e-30), 0.0)
+    out_ref[0] = out.reshape(S, G, r).astype(out_ref.dtype)
+
+
+def _latent_blocking(S: int, H: int, page_size: int, p_max: int) -> tuple[int, int, int]:
+    """``(Sq, G, P_BLK)``: queries and heads a latent program holds, at most
+    ``LATENT_ROWS`` rows of scores between them, and the pages a step covers.
+    A decode window of 8 x 64 heads is one program a row; a wider window
+    runs as query blocks of whole sublane tiles, a head count past the rows
+    as head blocks, and each block re-streams the row's pages."""
+    g = H
+    while g > LATENT_ROWS and g % 2 == 0:
+        g //= 2
+    sq = max(1, min(S, Q_BLOCK, LATENT_ROWS // g))
+    if sq < S:
+        sq = max(8, sq // 8 * 8)
+    return sq, g, max(1, min(LATENT_KEY_BLOCK // page_size, p_max))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def ragged_paged_attention_latent(
+    q_latent: jax.Array,  # [B, S, H, r]
+    q_rope: jax.Array,  # [B, S, H, w]
+    rope_pages: jax.Array,  # [1, L, N, Psz, w] (stays in HBM)
+    latent_pages: jax.Array,  # [1, L, N, Psz, r] (stays in HBM)
+    page_table: jax.Array,  # [B, Pmax]
+    start_pos: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: jax.Array | int = 0,
+    *,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """The ragged kernel for a latent cache, in ABSORBED form
+    (``latent_paged_attention_reference``): grid (B, cdiv(H, G), cdiv(S, Sq));
+    one program streams a row's pages once, ``P_BLK`` pages a step, each page
+    two DMAs (its rotated key and its latent rows), and multiplies them by a
+    block of Sq queries x G heads: ``[Sq*G, r] @ latent.T + [Sq*G, w] @
+    rope.T`` for the scores, ``p @ latent`` for the output, flash-style in
+    float32. Rows ragged by ``q_lens`` as in ``ragged_paged_attention``. Its
+    custom call carries this function's name: ``ragged_paged_attention``
+    selects both kernels in a trace, the whole name this one."""
+    B, S, H, r = q_latent.shape
+    w = q_rope.shape[3]
+    page_size = latent_pages.shape[3]
+    sq, g, p_blk = _latent_blocking(S, H, page_size, page_table.shape[1])
+    s_pad = pl.cdiv(S, sq) * sq
+    if s_pad != S:
+        pad = ((0, 0), (0, s_pad - S), (0, 0), (0, 0))
+        q_latent, q_rope = jnp.pad(q_latent, pad), jnp.pad(q_rope, pad)
+    q_block = lambda width: pl.BlockSpec(
+        (1, sq, g, width), lambda b, h, j, *_: (b, j, h, 0), memory_space=pltpu.VMEM
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, H // g, s_pad // sq),
+        in_specs=[q_block(r), q_block(w), pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_block(r),
+        scratch_shapes=[
+            pltpu.VMEM((2, p_blk * page_size, w), rope_pages.dtype),
+            pltpu.VMEM((2, p_blk * page_size, r), latent_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=page_size, p_blk=p_blk, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_latent.shape, q_latent.dtype),
+        interpret=interpret,
+        name="ragged_paged_attention_latent",
+    )(
+        page_table.astype(jnp.int32),
+        start_pos.astype(jnp.int32),
+        q_lens.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q_latent,
+        q_rope,
+        rope_pages,
+        latent_pages,
+    )
+    return out[:, :S]

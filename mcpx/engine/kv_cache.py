@@ -163,10 +163,15 @@ class PageAllocator:
 def init_paged_kv(
     cfg: GemmaConfig, n_pages: int, page_size: int, dtype: str | None = None
 ) -> dict[str, jax.Array]:
-    """Device page pools: ``[K, L, N_pages, page_size, head_dim]``."""
+    """Device page pools: ``[K, L, N_pages, page_size, head_dim]``. Under
+    latent attention (``GemmaConfig.kv_widths``) K is 1 and a page holds ONE
+    row a token: ``k`` the rotated key every head shares (its values, then
+    zeros up to a lane width) and ``v`` the normed latent, which the absorbed
+    kernel reads for the scores and for the values."""
     d = jnp.dtype(dtype or cfg.dtype)
-    shape = (cfg.n_kv_heads, cfg.n_layers, n_pages, page_size, cfg.head_dim)
-    return {"k": jnp.zeros(shape, d), "v": jnp.zeros(shape, d)}
+    shape = (cfg.n_kv_heads, cfg.n_layers, n_pages, page_size)
+    k_width, v_width = cfg.kv_widths
+    return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
 
 
 def commit_prefill_to_pages(
@@ -182,13 +187,14 @@ def commit_prefill_to_pages(
     sequence's pages are routed to the reserved null page 0, which is never
     read (positions are masked by seq_lens at attention time).
     """
-    L, B, T, K, hd = dense["k"].shape
+    L, B, T, K, _ = dense["k"].shape
     n_chunks = T // page_size
     if T % page_size:
         raise EngineError(f"prefill length {T} not a multiple of page_size {page_size}")
 
     def scatter(pool: jax.Array, dense_arr: jax.Array) -> jax.Array:
         # dense [L, B, T, K, hd] -> [K, L, B*n_chunks, page_size, hd]
+        hd = dense_arr.shape[-1]  # the two pools' widths may differ (latent attention)
         chunks = dense_arr.reshape(L, B, n_chunks, page_size, K, hd)
         chunks = chunks.transpose(4, 0, 1, 2, 3, 5).reshape(
             K, L, B * n_chunks, page_size, hd

@@ -19,9 +19,11 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mcpx.engine.kernels.paged_attention import (
+    latent_paged_attention_reference,
     paged_attention_chunk,
     paged_attention_chunk_reference,
     ragged_paged_attention,
+    ragged_paged_attention_latent,
     ragged_paged_attention_reference,
 )
 from mcpx.models.gemma.config import GemmaConfig
@@ -36,7 +38,7 @@ from mcpx.models.gemma.model import (
     rms_norm,
     sparse_index,
 )
-from mcpx.models.gemma.moe import moe_stats_init
+from mcpx.models.gemma.moe import add_forward_stats, add_layer_stats, moe_stats_init
 from mcpx.parallel.mesh import DATA_AXIS, MODEL_AXIS, _axis
 
 
@@ -81,6 +83,61 @@ def _ragged_kernel_on_mesh(
         out_specs=q_spec,
         check_vma=False,
     )(qg, k_all, v_all, page_table, positions, q_lens, *scalars)
+
+
+def _latent_attend(
+    q: jax.Array,  # [B, S, H, hd + dr]: a head's unrotated values, then its rotated ones
+    lp: dict[str, jax.Array],
+    cfg: GemmaConfig,
+    rope_pool: jax.Array,  # [1, L, N, Psz, w]: paged_kv["k"]
+    latent_pool: jax.Array,  # [1, L, N, Psz, r]: paged_kv["v"]
+    page_table: jax.Array,
+    positions: jax.Array,
+    q_lens: "jax.Array | None",
+    layer: jax.Array,
+    *,
+    mesh: Optional[Mesh],
+    use_pallas: bool,
+    interpret: bool,
+) -> jax.Array:
+    """Latent attention against the pages, ABSORBED: a head's unrotated
+    query goes through its own key expansion ``W_uk,h`` into the latent's
+    space (``q~ = q_nope W_uk^T``), scores and weighted sums are taken on the
+    cached latents themselves (one shared key head, ``kernels/
+    paged_attention.py``), and the head's value expansion ``W_uv,h`` comes
+    after: [B, S, H * dv]. The same mathematics as the expanded form of the
+    dense prefill (``model.latent_expand``), with no per-head key or value
+    ever built for a cached token. Heads split over ``model`` where they
+    divide; the pools are whole on every device (a latent has no head axis)."""
+    B, S, H, _ = q.shape
+    hd, dr = cfg.head_dim, cfg.qk_rope_head_dim
+    w_uk, w_uv = lp["w_ukv"][..., :hd], lp["w_ukv"][..., hd:]  # [r, H, hd], [r, H, dv]
+    q_latent = jnp.einsum("bshe,rhe->bshr", q[..., :hd], w_uk)
+    q_rope = q[..., hd:]
+    pad = rope_pool.shape[-1] - dr
+    if pad:
+        q_rope = jnp.pad(q_rope, ((0, 0), (0, 0), (0, 0), (0, pad)))
+    scale = cfg.attn_score_factor / (hd + dr) ** 0.5
+    lens = jnp.full((B,), S, jnp.int32) if q_lens is None else q_lens
+    if use_pallas:
+        kernel = functools.partial(ragged_paged_attention_latent, scale=scale, interpret=interpret)
+        if mesh is not None:
+            rows, heads = _axis(mesh, DATA_AXIS, B), _axis(mesh, MODEL_AXIS, H)
+            q_spec = P(rows, None, heads, None)
+            kernel = jax.shard_map(
+                kernel, mesh=mesh,
+                in_specs=(q_spec, q_spec, P(), P(), P(rows, None), P(rows), P(rows), P()),
+                out_specs=q_spec, check_vma=False,
+            )
+        out = kernel(
+            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens,
+            jnp.asarray(layer, jnp.int32),
+        )
+    else:
+        out = latent_paged_attention_reference(
+            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens, layer, scale=scale
+        )
+    return jnp.einsum("bshr,rhe->bshe", out, w_uv).reshape(B, S, cfg.attn_out_width)
 
 
 def _kv_window(
@@ -218,7 +275,12 @@ def decode_chunk_paged(
     # or an idle row chooses no expert, reads none and is counted nowhere.
     live = None if q_lens is None else jnp.arange(S)[None, :] < q_lens[:, None]
 
-    def attend(q, k_all, v_all, layer, window):
+    def attend(q, k_all, v_all, layer, window, lp):
+        if cfg.latent:
+            return _latent_attend(
+                q, lp, cfg, k_all, v_all, page_table, positions, q_lens, layer,
+                mesh=mesh, use_pallas=use_pallas, interpret=interpret,
+            )
         # Both paths stream/gather each sequence's pages ONCE for all S
         # chunk queries (folding the chunk into the batch dim instead would
         # multiply page traffic by S — the dominant decode cost), and the
@@ -256,13 +318,13 @@ def decode_chunk_paged(
         q, k, v = attention_inputs(h, lp, cfg, pos_mat, kind)  # the pages hold k as attended
         k_all = _write_kv_window(k_all, layer, k, kv_window)
         v_all = _write_kv_window(v_all, layer, v, kv_window)
-        attn = attend(q, k_all, v_all, layer, kind.get("window"))
+        attn = attend(q, k_all, v_all, layer, kind.get("window"), lp)
         x = attention_residual(x, h, attn, lp, cfg)
         x, layer_stats, chosen = feed_forward_residual(
             x, lp, cfg, moe=(experts, sparse_index(cfg, layer), live)
         )
         if layer_stats is not None:
-            stats = stats + layer_stats
+            stats = add_layer_stats(stats, layer_stats)
         return (x, k_all, v_all, layer + 1, stats), chosen
 
     # One scan a stack (one, but for leading dense layers before sparse
@@ -274,6 +336,11 @@ def decode_chunk_paged(
     for scanned, lo, hi in stacks:
         carry, chosen = lax.scan(body, carry, (scanned, layer_kinds(cfg, lo, hi)))
     x, k_new, v_new, _, stats = carry
+    if stats is not None:
+        # What this forward's attention calls read, by row: a live row's
+        # context runs through its last live query (``_ragged_n_pages``).
+        lens = jnp.full((B,), S, jnp.int32) if q_lens is None else q_lens
+        stats = add_forward_stats(cfg, stats, positions + lens, lens)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     pools = {"k": k_new, "v": v_new}
     # What a sparse model's callers may ask for beside the logits: the

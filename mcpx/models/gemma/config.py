@@ -96,10 +96,42 @@ class GemmaConfig:
     router_scoring: str = "softmax"
     router_bias_scale: float = 0.0
     router_scale: float = 1.0
+    # --- the attention kind: ``heads`` (MHA / GQA / MQA: ``n_kv_heads`` heads
+    # of K and of V a token in the cache) or ``latent``: the query through a
+    # rank-``q_lora_rank`` bottleneck with its own norm, keys and values through
+    # ONE normed latent of ``kv_lora_rank`` a token beside ONE rotated key of
+    # ``qk_rope_head_dim`` that every head shares; the cache holds those two
+    # and no head. A head's query and key are ``head_dim`` unrotated values
+    # (from the latent) and the ``qk_rope_head_dim`` rotated ones, its value
+    # ``v_head_dim`` wide. ``attn_score_factor`` multiplies the softmax scale
+    # (YaRN's m^2 where a family applies it there and not to cos and sin).
+    attention: str = "heads"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    attn_score_factor: float = 1.0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
             raise ConfigError("n_heads must be divisible by n_kv_heads")
+        if self.attention not in ("heads", "latent"):
+            raise ConfigError(f"attention {self.attention!r}: heads or latent")
+        latent_sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_rope_head_dim, self.v_head_dim)
+        if self.latent:
+            if min(latent_sizes) < 1 or self.qk_rope_head_dim % 2:
+                raise ConfigError(
+                    "latent attention needs q_lora_rank, kv_lora_rank, v_head_dim >= 1 and an "
+                    "even qk_rope_head_dim"
+                )
+            if self.n_kv_heads != 1:
+                raise ConfigError("latent attention caches one latent a token: n_kv_heads is 1")
+            if self.qk_norm or self.attn_gate or self.layer_types:
+                raise ConfigError(
+                    "latent attention has its own two norms, no output gate and no window layers"
+                )
+        elif any(latent_sizes) or self.attn_score_factor != 1.0:
+            raise ConfigError("the latent ranks and attn_score_factor belong to attention='latent'")
         if self.activation not in ("gelu_tanh", "silu"):
             raise ConfigError(f"activation {self.activation!r}: gelu_tanh or silu")
         # A JSON round trip (dataclasses.asdict -> GemmaConfig(**d)) hands a list.
@@ -142,6 +174,56 @@ class GemmaConfig:
         return self.n_heads // self.n_kv_heads
 
     @property
+    def latent(self) -> bool:
+        return self.attention == "latent"
+
+    @property
+    def rope_dim(self) -> int:
+        """The values of a query or key head that rotate."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
+
+    @property
+    def attn_out_width(self) -> int:
+        """What the attention hands to Wo: every head's value."""
+        return self.n_heads * (self.v_head_dim if self.latent else self.head_dim)
+
+    @property
+    def kv_widths(self) -> tuple[int, int]:
+        """The last axis of the two cache pools, ``k`` and ``v``. Heads: a
+        head's key and value. Latent: ``k`` holds the shared rotated key in a
+        whole number of 128-lane rows (64 values and 64 zeros at the published
+        width: the padding is the pool's, never a useful byte), ``v`` the
+        normed latent, which the scores read too."""
+        if not self.latent:
+            return self.head_dim, self.head_dim
+        return -(-self.qk_rope_head_dim // 128) * 128, self.kv_lora_rank
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """USEFUL cache bytes a token holds over all layers (no lane padding)."""
+        import jax.numpy as jnp  # bfloat16 is a dtype to jax's numpy, not to numpy's
+
+        per_layer = (
+            self.kv_lora_rank + self.qk_rope_head_dim if self.latent
+            else 2 * self.n_kv_heads * self.head_dim
+        )
+        return per_layer * self.n_layers * jnp.dtype(self.dtype).itemsize
+
+    @property
+    def branches_float32(self) -> bool:
+        """Whether a branch's output stays float32, as accumulated, until it
+        has joined the residual: where it is normed first, and in a latent
+        block, whose sharper softmax (``attn_score_factor``) makes it the most
+        sensitive to rounding of the blocks here."""
+        return self.post_norms or self.latent
+
+    @property
+    def kernel_lanes_ok(self) -> bool:
+        """Whether Mosaic can tile the paged kernel for this geometry: every
+        width it multiplies is a whole number of 128 lanes."""
+        return all(w % 128 == 0 for w in self.kv_widths)
+
+    @property
     def n_experts_held(self) -> int:
         return self.experts_held or self.n_experts
 
@@ -159,7 +241,7 @@ class GemmaConfig:
             for f in (
                 "activation", "tie_embeddings", "scale_embeddings", "norm_plus_one",
                 "layer_types", "sliding_window", "yarn_factor", "n_experts",
-                "rope_full_layers", "qk_norm", "attn_gate", "post_norms",
+                "rope_full_layers", "qk_norm", "attn_gate", "post_norms", "attention",
             )
         )
 
@@ -180,7 +262,7 @@ class GemmaConfig:
         ``yarn_attention_factor``."""
         if not self.yarn_factor and self.rope_full_layers:
             return None
-        dim = self.head_dim
+        dim = self.rope_dim
         k = np.arange(dim // 2, dtype=np.float64)
         plain = self.rope_theta ** (-2.0 * k / dim)
         full = np.asarray([t != SLIDING for t in self.layer_types or (FULL,) * self.n_layers])
@@ -228,6 +310,13 @@ class GemmaConfig:
     def _count(self, experts: int) -> int:
         D, H, K, hd, F = self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.d_ff
         attn = D * H * hd + 2 * D * K * hd + H * hd * D + 2 * D
+        if self.latent:
+            rq, rkv, dr, dv = self.q_lora_rank, self.kv_lora_rank, self.qk_rope_head_dim, self.v_head_dim
+            attn = (
+                D * rq + rq + rq * H * (hd + dr)  # w_dq, its norm, w_uq
+                + D * (rkv + dr) + rkv + rkv * H * (hd + dv)  # w_dkv, its norm, w_ukv
+                + H * dv * D + 2 * D  # wo, the layer's two norms
+            )
         attn += self.attn_gate * D * H * hd + self.qk_norm * 2 * hd + self.post_norms * 2 * D
         sparse_ff = (
             (D + bool(self.router_bias_scale)) * self.n_experts

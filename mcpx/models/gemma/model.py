@@ -30,7 +30,9 @@ import numpy as np
 from jax import lax
 
 from mcpx.models.gemma.config import GemmaConfig
-from mcpx.models.gemma.moe import activation, moe_forward, moe_stats_init, split_layers
+from mcpx.models.gemma.moe import (
+    activation, add_forward_stats, add_layer_stats, moe_forward, moe_stats_init, split_layers,
+)
 
 Params = dict[str, Any]
 KVCache = dict[str, jax.Array]
@@ -109,8 +111,33 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         fill = jnp.zeros if cfg.norm_plus_one else jnp.ones
         return t(name, fill(shape, dtype, device=sharding(stack + name)))
 
+    def latent_attention(n, stack):
+        """``cfg.latent``: the query's bottleneck and its norm, the shared
+        latent (with the rotated key beside it, ``w_dkv``'s last columns) and
+        its norm, the per-head expansions, Wo over every head's value."""
+        rq, rkv, dr, dv = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+        return {
+            "pre_attn_norm": gain("pre_attn_norm", (n, D), stack),
+            "pre_mlp_norm": gain("pre_mlp_norm", (n, D), stack),
+            "w_dq": normal("w_dq", k_q, (n, D, rq), D, stack=stack),
+            "q_lora_norm": gain("q_lora_norm", (n, rq), stack),
+            # fan-in times the score factor squared: a head's scores then have
+            # unit variance under the block's softmax scale, as 1 / sqrt(fan_in)
+            # leaves them where the scale is head_dim^-0.5 alone
+            "w_uq": normal(
+                "w_uq", jax.random.fold_in(key, 15), (n, rq, H, hd + dr),
+                rq * cfg.attn_score_factor**2, stack=stack,
+            ),
+            "w_dkv": normal("w_dkv", k_k, (n, D, rkv + dr), D, stack=stack),
+            "kv_lora_norm": gain("kv_lora_norm", (n, rkv), stack),
+            "w_ukv": normal("w_ukv", k_v, (n, rkv, H, hd + dv), rkv, stack=stack),
+            "wo": normal("wo", k_o, (n, H, dv, D), H * dv, stack=stack),
+        }
+
     def attention(n, stack=""):
         """The leaves every layer has, for a stack of ``n`` layers."""
+        if cfg.latent:
+            return latent_attention(n, stack)
         leaves = {
             "pre_attn_norm": gain("pre_attn_norm", (n, D), stack),
             "pre_mlp_norm": gain("pre_mlp_norm", (n, D), stack),
@@ -177,9 +204,13 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
 
 
 def init_kv_cache(cfg: GemmaConfig, batch: int, max_len: int, dtype: str | None = None) -> KVCache:
+    """The dense cache ``[L, B, S, K, width]``: a head's key and value, or
+    under latent attention the shared rotated key (``k``) and the latent
+    (``v``), ``GemmaConfig.kv_widths``."""
     d = jnp.dtype(dtype or cfg.dtype)
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, d), "v": jnp.zeros(shape, d)}
+    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads)
+    k_width, v_width = cfg.kv_widths
+    return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
 
 
 # ------------------------------------------------------------------- pieces
@@ -243,18 +274,40 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
-def _attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array) -> jax.Array:
-    """q: [B, T, K, G, hd]; k,v: [B, S, K, hd]; mask: [B, T, S] (True=keep).
+def _attend(
+    q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, score_factor: float = 1.0
+) -> jax.Array:
+    """q: [B, T, K, G, hd]; k: [B, S, K, hd]; v: [B, S, K, hv]; mask:
+    [B, T, S] (True=keep). ``score_factor`` multiplies the softmax scale.
 
-    Returns [B, T, K, G, hd]. Softmax in float32.
+    Returns [B, T, K, G, hv]. Softmax in float32.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = score_factor / math.sqrt(q.shape[-1])
     logits = jnp.einsum("btkgh,bskh->btkgs", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     logits = jnp.where(mask[:, :, None, None, :], logits, -1e30)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("btkgs,bskh->btkgh", weights.astype(v.dtype), v)
     return out
+
+
+# Queries a latent layer's expanded attention scores at a time: every head is
+# its own key head there, so a 1,024-token window's float32 scores are 268 MB
+# a row at 64 heads, and a cohort's would not fit beside the weights.
+QUERY_BLOCK = 256
+
+
+def _attend_query_blocks(q, k, v, mask, score_factor: float) -> jax.Array:
+    """``_attend`` a block of ``QUERY_BLOCK`` queries at a time (any mask:
+    every block sees every key its mask keeps)."""
+    T = q.shape[1]
+    if T <= QUERY_BLOCK:
+        return _attend(q, k, v, mask, score_factor)
+    blocks = [
+        _attend(q[:, i : i + QUERY_BLOCK], k, v, mask[:, i : i + QUERY_BLOCK], score_factor)
+        for i in range(0, T, QUERY_BLOCK)
+    ]
+    return jnp.concatenate(blocks, axis=1)
 
 
 # What a block computes around its attention op, written once for the dense
@@ -265,7 +318,10 @@ def attention_inputs(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Normed input h [B, T, D] -> q [B, T, H, hd], k and v [B, T, K, hd] as
     the cache holds them: q and k normed per head where the block has a q/k
-    norm, then rotated by this layer's rope."""
+    norm, then rotated by this layer's rope. Latent attention:
+    ``latent_attention_inputs``."""
+    if cfg.latent:
+        return latent_attention_inputs(h, lp, cfg, positions, kind)
     q = jnp.einsum("btd,dkh->btkh", h, lp["wq"])
     k = jnp.einsum("btd,dkh->btkh", h, lp["wk"])
     v = jnp.einsum("btd,dkh->btkh", h, lp["wv"])
@@ -278,21 +334,75 @@ def attention_inputs(
     return q, k, v
 
 
+def latent_attention_inputs(
+    h: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig, positions: jax.Array, kind
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Normed input h [B, T, D] -> q [B, T, H, hd + dr] (a head's unrotated
+    values, then its rotated ones), and what the cache holds of a token, ONE
+    row for every head: ``k`` [B, T, 1, kv_widths[0]], the shared key after
+    the rotation (zeros past its ``dr`` values), and ``v`` [B, T, 1, rkv],
+    the latent after its norm. The rotated dims pair half-split (value i with
+    value i + dr/2), as ``apply_rope`` pairs them."""
+    hd, dr, rkv = cfg.head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    f32, dtype = jnp.float32, h.dtype
+    # Each product is taken as accumulated (float32), and what follows it
+    # elementwise (norm, rotation) runs on that: every tensor below is rounded
+    # to the activations' type once, where a matmul or the cache takes it.
+    c_q = rms_norm(
+        jnp.einsum("btd,dr->btr", h, lp["w_dq"], preferred_element_type=f32),
+        lp["q_lora_norm"], cfg.norm_eps, cfg.norm_plus_one, dtype,
+    )
+    q = jnp.einsum("btr,rhe->bthe", c_q, lp["w_uq"], preferred_element_type=f32)
+    q_rope = apply_rope(q[..., hd:], positions, cfg.rope_theta, kind)
+    q = jnp.concatenate([q[..., :hd], q_rope], axis=-1).astype(dtype)
+    down = jnp.einsum("btd,dr->btr", h, lp["w_dkv"], preferred_element_type=f32)
+    latent = rms_norm(
+        down[..., :rkv], lp["kv_lora_norm"], cfg.norm_eps, cfg.norm_plus_one, dtype
+    )
+    k_rope = apply_rope(down[:, :, None, rkv:], positions, cfg.rope_theta, kind).astype(dtype)
+    pad = cfg.kv_widths[0] - dr
+    if pad:
+        k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, 0), (0, pad)))
+    return q, k_rope, latent[:, :, None, :]
+
+
+def latent_expand(
+    k_rope: jax.Array, latent: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig
+) -> tuple[jax.Array, jax.Array]:
+    """The EXPANDED form's keys and values from what the cache holds,
+    ``k_rope`` [B, S, 1, kv_widths[0]] and ``latent`` [B, S, 1, rkv]: every
+    head's key [B, S, H, hd + dr] (its own unrotated values from the latent,
+    then the one rotated key all heads share) and value [B, S, H, dv]."""
+    hd, dr = cfg.head_dim, cfg.qk_rope_head_dim
+    kv = jnp.einsum("bsr,rhe->bshe", latent[:, :, 0], lp["w_ukv"])
+    shared = jnp.broadcast_to(k_rope[..., :dr], kv.shape[:3] + (dr,))
+    return jnp.concatenate([kv[..., :hd], shared], axis=-1), kv[..., hd:]
+
+
 def attention_residual(
     x: jax.Array, h: jax.Array, attn: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig
 ) -> jax.Array:
     """x + the attention branch: ``attn`` [B, T, H * hd] gated by
     ``sigmoid(Wg h)`` where the block has an output gate, through Wo, normed
     where the block norms its branches' outputs."""
-    F = cfg.n_heads * cfg.head_dim
+    F = cfg.attn_out_width
     if cfg.attn_gate:
         gate = jnp.einsum("btd,df->btf", h, lp["w_attn_gate"].reshape(cfg.d_model, F))
         attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
     wo = lp["wo"].reshape(F, cfg.d_model)
-    if not cfg.post_norms:
-        return x + jnp.einsum("btf,fd->btd", attn, wo)
-    out = jnp.einsum("btf,fd->btd", attn, wo, preferred_element_type=jnp.float32)
-    return _add_normed(x, out, lp["post_attn_norm"], cfg)
+    out = jnp.einsum(
+        "btf,fd->btd", attn, wo,
+        preferred_element_type=jnp.float32 if cfg.branches_float32 else None,
+    )
+    if cfg.post_norms:
+        return _add_normed(x, out, lp["post_attn_norm"], cfg)
+    return _join(x, out)
+
+
+def _join(x: jax.Array, branch: jax.Array) -> jax.Array:
+    """x + branch in the branch's type (x's own, or float32 as accumulated:
+    ``GemmaConfig.branches_float32``), rounded to x's type once."""
+    return (x.astype(branch.dtype) + branch).astype(x.dtype)
 
 
 def _add_normed(x: jax.Array, branch32: jax.Array, gain: jax.Array, cfg: GemmaConfig) -> jax.Array:
@@ -319,7 +429,7 @@ def feed_forward_residual(
     h = rms_norm(x, lp["pre_mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
     stats = chosen = None
     # A branch that is normed before it joins stays float32 until it has.
-    branch_dtype = jnp.float32 if cfg.post_norms else h.dtype
+    branch_dtype = jnp.float32 if cfg.branches_float32 else h.dtype
     if "router" in lp:
         experts, layer, live = moe
         ff, stats, chosen = moe_forward(
@@ -333,7 +443,7 @@ def feed_forward_residual(
         ff = gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg, out_dtype=branch_dtype)
     if cfg.post_norms:
         return _add_normed(x, ff, lp["post_mlp_norm"], cfg), stats, chosen
-    return x + ff, stats, chosen
+    return _join(x, ff), stats, chosen
 
 
 def layer_stacks(cfg: GemmaConfig, params: Params) -> tuple[list, dict]:
@@ -388,9 +498,15 @@ def _layer(
         # A sliding layer's query sees itself and the window - 1 keys before.
         s_idx = jnp.arange(mask.shape[-1])
         mask = mask & (s_idx[None, None, :] > positions[:, :, None] - kind["window"])
-    qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
-    attn = (attend_fn or _attend)(qg, k_cache, v_cache, mask)
-    x = attention_residual(x, h, attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp, cfg)
+    if cfg.latent:
+        # The expanded form: every head's keys and values rebuilt from the
+        # cached latents, one head a "KV head" (no sharing to group).
+        keys, values = latent_expand(k_cache, v_cache, lp, cfg)
+        attn = _attend_query_blocks(q[:, :, :, None, :], keys, values, mask, cfg.attn_score_factor)
+    else:
+        qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+        attn = (attend_fn or _attend)(qg, k_cache, v_cache, mask)
+    x = attention_residual(x, h, attn.reshape(B, T, cfg.attn_out_width), lp, cfg)
     x, stats, chosen = feed_forward_residual(x, lp, cfg, moe)
     return x, k_cache, v_cache, stats, chosen
 
@@ -463,7 +579,7 @@ def forward(
             x, lp, k_c, v_c, positions, mask, positions, cfg, attend_fn, kind, moe
         )
         if layer_stats is not None:
-            stats = stats + layer_stats
+            stats = add_layer_stats(stats, layer_stats)
         return ((x, layer + 1, stats) if cfg.n_experts else x), (k_c, v_c, chosen)
 
     carry = (x, jnp.asarray(0, jnp.int32), moe_stats_init(cfg)) if cfg.n_experts else x
@@ -491,6 +607,9 @@ def forward(
         x = x[jnp.arange(B), logits_at]  # [B, D]
     out = output_logits(params, cfg, x), {"k": k_new, "v": v_new}
     stats = carry[2] if cfg.n_experts else None  # as decode_chunk_paged: None from a dense model
+    if stats is not None:
+        n_live = jnp.sum(live, axis=1) if live is not None else jnp.full(tokens.shape[:1], tokens.shape[1])
+        stats = add_forward_stats(cfg, stats, positions[:, 0] + n_live, n_live)
     return out + ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
 
 

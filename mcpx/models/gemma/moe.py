@@ -106,12 +106,41 @@ def route(
     return chosen.astype(jnp.int32), w * cfg.router_scale
 
 
+# What a forward counts once, after its layers' own counters (``moe_forward``).
+FORWARD_STATS = 3
+
+
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     """The counters a forward adds to: tokens per held expert ``[E_held]``
     (summed over layers), then the number of (layer, expert) pairs with at
     least one live token, then the rows multiplied by an expert's matrices
-    (the window's slots a step of the loop, a tile's a grouped step)."""
-    return jnp.zeros((cfg.n_experts_held + 2,), jnp.int32)
+    (the window's slots a step of the loop, a tile's a grouped step): a
+    layer's own, ``moe_forward``. Then the forward's (``add_forward_stats``):
+    the (token, choice) pairs its sparse layers routed, held here or not; the
+    context tokens its attention calls read, over live rows and layers; and
+    those calls, live rows times layers."""
+    return jnp.zeros((cfg.n_experts_held + 2 + FORWARD_STATS,), jnp.int32)
+
+
+def add_layer_stats(stats: jax.Array, layer_stats: jax.Array) -> jax.Array:
+    """A layer's counters into the forward's, which are longer by the
+    forward's own."""
+    return stats.at[: layer_stats.shape[0]].add(layer_stats)
+
+
+def add_forward_stats(
+    cfg: GemmaConfig, stats: jax.Array, context: jax.Array, q_lens: jax.Array
+) -> jax.Array:
+    """The forward's own counters: ``context`` [B] the cache positions a
+    row's attention read through, ``q_lens`` [B] its live tokens (0: an idle
+    row, which reads nothing and routes nothing)."""
+    live = q_lens > 0
+    own = jnp.stack([
+        jnp.sum(q_lens) * (cfg.n_experts_per_tok * cfg.n_sparse_layers),
+        jnp.sum(jnp.where(live, context, 0)) * cfg.n_layers,
+        jnp.sum(live) * cfg.n_layers,
+    ]).astype(jnp.int32)
+    return stats.at[-FORWARD_STATS:].add(own)
 
 
 # The widest window whose expert steps multiply every slot: the v5e's ridge

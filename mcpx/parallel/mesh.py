@@ -114,6 +114,16 @@ def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
         "wv": P(None, None, m(cfg.n_kv_heads), None),
         "wo": P(None, m(cfg.n_heads), None, None),
     }
+    if cfg.latent:
+        # The bottlenecks and their norms whole on every device; the per-head
+        # expansions and Wo split by head, as the absorbed kernel's queries.
+        attention = {
+            "pre_attn_norm": whole(2), "pre_mlp_norm": whole(2),
+            "w_dq": whole(3), "q_lora_norm": whole(2), "w_dkv": whole(3), "kv_lora_norm": whole(2),
+            "w_uq": P(None, None, m(cfg.n_heads), None),
+            "w_ukv": P(None, None, m(cfg.n_heads), None),
+            "wo": P(None, m(cfg.n_heads), None, None),
+        }
     # What a block beyond the default adds stays whole on every device.
     if cfg.qk_norm:
         attention.update(q_norm=whole(2), k_norm=whole(2))

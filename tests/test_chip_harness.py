@@ -85,7 +85,7 @@ CELL = "olmo2-1b.distinct-closed"
 # Readers that need a device profile, allocator statistics or the load
 # generator's own clock: nothing a served program on the CPU can feed.
 NOT_FED_HERE = {"device_op_share", "device_idle_share", "memory_in_use", "endpoint_spread",
-                "client_quantile"}
+                "client_quantile", "mla_roofline"}
 LABELLED_SAMPLE = 'mcpx_engine_compiles_total{executable="admit"}'
 
 
@@ -107,9 +107,14 @@ def _fed_in(cell):
 # expert: the attributes and the per-expert counter its metrics read (PR 36).
 MIXED_CELL = "trinity-mini.distinct-closed"
 
+# The cell whose cache is latent and whose sparse layers hold a share of the
+# router's experts: the attributes its metrics read (PR 42).
+LATENT_CELL = "a.x-k1.wide-shortlist-closed"
+
 FED = _fed_in(CELL)
 FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
 FED_MIXED = [m for m in _fed_in(MIXED_CELL) if m not in FED]
+FED_LATENT = [m for m in _fed_in(LATENT_CELL) if m not in FED + FED_MIXED]
 
 
 @pytest.fixture(scope="module")
@@ -127,7 +132,14 @@ def served_mixed(tmp_path_factory):
     return _serve(MIXED_CELL, tmp_path_factory)
 
 
-def _serve(cell_name, tmp_path_factory):
+@pytest.fixture(scope="module")
+def served_latent(tmp_path_factory):
+    # The cell's 128-service shortlist and its 1,024 warm-up bucket make a
+    # rehearsal of minutes; the attributes' names do not depend on either.
+    return _serve(LATENT_CELL, tmp_path_factory, warmup_max_len=128, shortlist_top_k=8)
+
+
+def _serve(cell_name, tmp_path_factory, warmup_max_len=None, shortlist_top_k=None):
     """One rehearsal child of the harness (``child.py --rehearse-cpu``: the
     served app at the cell's block's rehearsal size, LLM planner, interpreted
     kernel, tracing at rate 1), five fresh ``/plan`` requests and one re-send,
@@ -139,6 +151,11 @@ def _serve(cell_name, tmp_path_factory):
         sys.path.remove(CHIP_DIR)
     readers, spec, loadgen = (sys.modules[n] for n in ("readers", "spec", "loadgen"))
     cell = spec.load_cell(cell_name)
+    if warmup_max_len is not None:
+        config = json.loads(json.dumps(cell.config))
+        config["warmup_max_len"] = warmup_max_len
+        config["mcpx"]["planner"]["shortlist_top_k"] = shortlist_top_k
+        cell = dataclasses.replace(cell, config=config)
     gen = loadgen.Generator({**cell.traffic, "registry_services": 120}, seed=30)
     names = {r["name"] for r in gen.registry}
     endpoints = sorted({m["args"]["endpoint"] for m in fed if "endpoint" in m["args"]} | {"/metrics"})
@@ -385,6 +402,45 @@ def test_the_mixed_blocks_attributes_count_sparse_layers_and_bytes(served_mixed)
     model = served_mixed["costs"]["model"]
     assert model["params_held"] == cfg.n_params
     assert model["params_held"] - model["params_active_per_token"] == 6 * 6 * 3 * cfg.d_model * cfg.d_expert
+
+
+@pytest.mark.parametrize("metric", FED_LATENT, ids=[m["name"] for m in FED_LATENT])
+def test_the_latent_block_feeds_its_metrics(served_latent, metric):
+    assert {m["name"] for m in FED_LATENT} == {
+        "attn.ctx_tok_per_call", "attn.latent_bytes_share", "moe.held_assignment_share"}
+    v = served_latent["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v)
+    if metric["name"] == "attn.ctx_tok_per_call":
+        assert 60 < v < 200  # an 8-service shortlist's prompt and what was decoded behind it
+    if metric["name"] in ("attn.latent_bytes_share", "moe.held_assignment_share"):
+        assert 0 < v < 1
+
+
+def test_the_latent_blocks_attributes_count_context_and_this_share(served_latent):
+    """At the rehearsal size: the dense lead and one sparse layer, experts
+    4..7 of 16 held, 2 a token; a cache row of 64 + 16 values a token a layer."""
+    segments = _segments(served_latent)
+    assert segments
+    for sp in segments:
+        a = sp["attrs"]
+        assert a["attn_row_calls"] % 2 == 0 and 0 < a["attn_row_calls"] <= 8 * a["forwards"] * 2
+        assert a["attn_ctx_tokens"] > a["attn_row_calls"]
+        assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (64 + 16) * 2
+        assert a["moe_tokens_routed"] % 2 == 0  # 2 experts a live token in the one sparse layer
+        assert 0 <= a["moe_assignments"] <= a["moe_tokens_routed"]
+        assert a["moe_expert_slots"] == a["forwards"] * 1 * 4  # the 4 experts held
+    profile = served_latent["health"]["engine_queue"]["worker_profile"]
+    for attr in ("attn_ctx_tokens", "attn_row_calls", "kv_bytes_read", "moe_tokens_routed"):
+        assert profile[attr] >= sum(sp["attrs"][attr] for sp in _segments_once(served_latent)) > 0
+    per_expert = {key for key in served_latent["ev"].counters_after["/metrics"]
+                  if key.startswith("mcpx_engine_moe_expert_tokens_total{")}
+    assert per_expert == {f'mcpx_engine_moe_expert_tokens_total{{expert="{e}"}}' for e in range(4, 8)}
+    # /costs counts the latent attention's leaves: the tree's own count
+    spec = sys.modules["spec"]
+    cfg = spec.load_block("mla", CHIP_DIR).rehearsal_config(3072)
+    assert served_latent["costs"]["model"]["params_held"] == cfg.n_params
+    # the latent kernel served the decode path
+    assert served_latent["paths"]["decode"]["engaged"] and served_latent["paths"]["decode"]["dispatches"] > 0
 
 
 def _segments_once(served):

@@ -1,0 +1,144 @@
+"""The absorbed latent kernel (``ragged_paged_attention_latent``), beside
+``test_ragged_kernel.py``: interpret mode against the ``jax.numpy``
+reference of the absorbed form over ragged rows, a row of one page and of
+128, a window wider than one query block; its blocking; and the lowering for
+the TPU from the CPU, bare and under the engine's ``shard_map``."""
+
+import random
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mcpx.engine.kernels.paged_attention import (
+    LATENT_ROWS,
+    _latent_blocking,
+    latent_paged_attention_reference,
+    ragged_paged_attention_latent,
+)
+
+
+def _case(seed, B, S, H, r, w, psz, p_max, layers=2, dtype=jnp.float32):
+    """Random queries, pools and a page table that scatters every row's
+    pages over the pool."""
+    rng = random.Random(seed)
+    n_pages = B * p_max + 2
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q_latent = jax.random.normal(ks[0], (B, S, H, r), dtype)
+    q_rope = jax.random.normal(ks[1], (B, S, H, w), dtype)
+    rope = jax.random.normal(ks[2], (1, layers, n_pages, psz, w), dtype)
+    latent = jax.random.normal(ks[3], (1, layers, n_pages, psz, r), dtype)
+    pages = list(range(1, n_pages))
+    rng.shuffle(pages)
+    table = jnp.asarray(np.asarray(pages[: B * p_max], np.int32).reshape(B, p_max))
+    return q_latent, q_rope, rope, latent, table
+
+
+def _both(args, starts, q_lens, layer, scale=0.13):
+    starts, q_lens = jnp.asarray(starts, jnp.int32), jnp.asarray(q_lens, jnp.int32)
+    ref = latent_paged_attention_reference(*args, starts, q_lens, layer, scale=scale)
+    out = ragged_paged_attention_latent(*args, starts, q_lens, layer, scale=scale, interpret=True)
+    return np.asarray(out), np.asarray(ref)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_latent_kernel_matches_reference_over_mixed_batches(seed):
+    """One launch over a MIXED slab — a full window, plain decode rows, a
+    partial window, an idle row — agrees with the absorbed jnp reference
+    everywhere, the zeroed pad and idle positions included, in both layers."""
+    rng = random.Random(seed)
+    B, S, H, r, w, psz, p_max = 6, 5, 4, 32, 128, 4, 12
+    args = _case(seed, B, S, H, r, w, psz, p_max)
+    q_lens = [S, 1, rng.randint(2, S - 1), 0, rng.randint(0, S), 1]
+    starts = [rng.randint(0, p_max * psz - max(1, n) - 1) for n in q_lens]
+    for layer in (0, 1):
+        out, ref = _both(args, starts, q_lens, layer)
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+        for b in range(B):
+            assert np.all(out[b, q_lens[b]:] == 0.0), (layer, b)
+
+
+def test_a_row_of_one_page_and_a_row_of_128():
+    """The cell's table is 128 pages wide: a row that fills it (2,047 keys
+    behind its query, eight key blocks of 256) beside a row whose whole
+    context is its first page, and an idle one."""
+    B, S, H, r, w, psz, p_max = 3, 8, 4, 32, 128, 16, 128
+    args = _case(7, B, S, H, r, w, psz, p_max, layers=1)
+    out, ref = _both(args, [p_max * psz - S, 3, 500], [S, 1, 0], 0)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    assert np.abs(out[0]).max() > 0 and np.all(out[2] == 0.0)
+
+
+def test_a_window_wider_than_a_query_block_runs_as_blocks():
+    """A suffix-prefill window: 64 heads leave a program 8 queries, so a
+    40-query window is five query blocks, each streaming up to its own last
+    query; rows ragged within it."""
+    B, S, H, r, w, psz, p_max = 2, 40, 64, 16, 128, 8, 16
+    assert _latent_blocking(S, H, psz, p_max)[:2] == (8, 64)
+    args = _case(3, B, S, H, r, w, psz, p_max, layers=1)
+    out, ref = _both(args, [20, 0], [17, 40], 0)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    assert np.all(out[0, 17:] == 0.0)
+
+
+def test_bfloat16_pools_round_the_weights_as_the_reference_does():
+    B, S, H, r, w, psz, p_max = 2, 8, 4, 128, 128, 16, 4
+    args = _case(5, B, S, H, r, w, psz, p_max, layers=1, dtype=jnp.bfloat16)
+    out, ref = _both(args, [30, 11], [8, 1], 0, scale=0.05)
+    np.testing.assert_allclose(out.astype(np.float32), ref.astype(np.float32), rtol=0.02, atol=0.02)
+
+
+@pytest.mark.parametrize("S, H, want", [
+    (1, 64, (1, 64)), (8, 64, (8, 64)), (128, 64, (8, 64)), (1024, 64, (8, 64)),
+    (8, 4, (8, 4)), (300, 4, (128, 4)), (8, 1024, (8, 512)),
+])
+def test_latent_blocking_keeps_a_program_to_its_rows(S, H, want):
+    sq, g, p_blk = _latent_blocking(S, H, 16, 128)
+    assert (sq, g) == want and p_blk == 16 and H % g == 0
+    assert sq * g <= max(LATENT_ROWS, 8 * g)
+    assert _latent_blocking(S, H, 16, 4)[2] == 4  # no wider than the table
+
+
+@pytest.mark.parametrize("S", [1, 8, 9, 128])
+def test_latent_kernel_lowers_for_tpu_on_one_device_and_under_shard_map(S):
+    """At the published widths (64 heads, a 512-wide latent, the rotated key
+    in a 128-lane row), from the CPU: the Mosaic lowering, bare and under the
+    engine's shard_map on a 2 x 2 mesh (rows over data, heads over model, the
+    pools whole on every device)."""
+    import functools
+
+    from mcpx.engine.paged_decode import _latent_attend
+    from mcpx.models.gemma.config import GemmaConfig
+    from mcpx.parallel.mesh import make_mesh
+
+    B, H, r, w, L, n_pages, psz, p_max = 8, 64, 512, 128, 2, 33, 16, 32
+    bf = jnp.bfloat16
+    shapes = [
+        jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((B, S, H, r), bf), ((B, S, H, w), bf), ((1, L, n_pages, psz, w), bf),
+            ((1, L, n_pages, psz, r), bf), ((B, p_max), jnp.int32), ((B,), jnp.int32),
+            ((B,), jnp.int32), ((), jnp.int32),
+        )
+    ]
+    kernel = functools.partial(ragged_paged_attention_latent, scale=0.13)
+    text = jax.jit(kernel).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "ragged_paged_attention_latent" in text
+
+    cfg = GemmaConfig(
+        vocab_size=384, d_model=256, n_layers=L, n_heads=H, n_kv_heads=1, head_dim=128, d_ff=256,
+        attention="latent", q_lora_rank=64, kv_lora_rank=r, qk_rope_head_dim=64, v_head_dim=128,
+        norm_plus_one=False,
+    )
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    lp = {"w_ukv": jax.ShapeDtypeStruct((r, H, 256), bf)}
+
+    def attend(q, w_ukv, rope_pool, latent_pool, table, pos, lens):
+        return _latent_attend(q, {"w_ukv": w_ukv}, cfg, rope_pool, latent_pool, table, pos, lens,
+                              jnp.asarray(1), mesh=mesh, use_pallas=True, interpret=False)
+
+    q = jax.ShapeDtypeStruct((B, S, H, 192), bf)
+    text = jax.jit(attend).trace(
+        q, lp["w_ukv"], shapes[2], shapes[3], shapes[4], shapes[5], shapes[6]
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
