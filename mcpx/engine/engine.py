@@ -686,6 +686,9 @@ class InferenceEngine:
         self._prefix_seen = {  # mcpx: owner[engine-worker]
             "hits": 0, "misses": 0, "evictions": 0, "matched_tokens": 0,
         }
+        # (dispatches, tokens) of the declared-head build in flight: what
+        # its engine.prefix_build span reports.
+        self._prefix_built = (0, 0)  # mcpx: owner[engine-worker]
         self._prefill_buckets = tuple(
             b
             for b in (64, 128, 256, 512, 768, 1024, 1536, 2048)
@@ -1422,7 +1425,7 @@ class InferenceEngine:
             self._spill_tier.bind(
                 self._spill_gather_dispatch,
                 self._spill_readmit_dispatch,
-                self.model_cfg.kv_bytes_per_token,
+                self.model_cfg.kv_bytes_per_token + self.model_cfg.index_bytes_per_token,
             )
         # GET /costs sets the registry's numbers against datasheet peaks:
         # an accelerator missing from the table fails start-up here, not
@@ -2139,6 +2142,10 @@ class InferenceEngine:
         if not eligible:
             return full_cap  # admission falls back to the full path too
         prefix_cap = max(1, P + min(eligible[-1], capacity - P - budget - slack))
+        if P > full_eligible[-1]:
+            # A head longer than every bucket is served over its cached
+            # pages or not at all: no full prefill could take it.
+            return prefix_cap
         # Admission may fall back to full prefill at runtime (page pressure,
         # unbuildable prefix), whose head-keep trim would cut the prompt
         # TAIL — so the caller must fit the WORST of the two paths.
@@ -2753,13 +2760,35 @@ class InferenceEngine:
     ) -> Optional[PrefixNode]:
         """Make the declared shared prompt head ``key`` fully resident in
         the radix tree, prefilling only the part the tree does not already
-        hold (one [1, T] dispatch — suffix-offset when a head is matched,
-        dense full prefill from zero). Returns the deepest node covering
+        hold. A part that fits a prefill bucket is ONE dispatch
+        (``_build_prefix``); a longer one (a catalogue head of thousands of
+        tokens) is built in chunks of the largest bucket that fits beside
+        what is resident, each a suffix prefill over the pages of those
+        before it and inserted into the tree before the next is prefilled
+        over it. The chunks run back to back on the worker: a head is built
+        once per registry version, and decode segments wait for it (ROADMAP
+        M2 keeps interleaving them). Returns the deepest node covering
         ``key`` (unpinned), or None when it cannot be built right now (page
-        pressure, capacity) — per-row matching then reuses whatever IS
-        resident. This pre-build exists so even the FIRST cohort of a burst
-        shares its declared header instead of prefilling it once per row.
-        Worker-thread only."""
+        pressure, capacity): per-row matching then reuses whatever IS
+        resident. Worker-thread only."""
+        P = len(key)
+        capacity = self.config.engine.max_pages_per_seq * self.config.engine.kv_page_size
+        while True:
+            n = self._prefix_cache.match(key, cap=P, record=False)[0]
+            eligible = [b for b in self._prefill_buckets if b + n <= capacity]
+            end = P if not eligible or P - n <= eligible[-1] else n + eligible[-1]
+            node = self._build_prefix(key[:end], tenant)
+            if node is None or end == P:
+                return node
+
+    def _build_prefix(
+        self, key: tuple, tenant: str = "default"
+    ) -> Optional[PrefixNode]:
+        """``_ensure_prefix`` for a head whose unmatched part fits a prefill
+        bucket (one [1, T] dispatch — suffix-offset when a head is matched,
+        dense full prefill from zero). This pre-build exists so even the
+        FIRST cohort of a burst shares its declared header instead of
+        prefilling it once per row."""
         ecfg = self.config.engine
         cache = self._prefix_cache
         P = len(key)
@@ -2835,6 +2864,8 @@ class InferenceEngine:
         # prefix, not per request) — prefill-tokens-per-request accounting
         # must see it or reuse would overstate itself.
         self.metrics.prefill_tokens.inc(R)
+        self.metrics.prefix_build_chunks.inc()
+        self._prefix_built = (self._prefix_built[0] + 1, self._prefix_built[1] + R)
         cache.seal()  # dispatched: later cohorts may read these pages
         node.refs -= 1  # drop the insert's born-pin; callers re-pin
         return node
@@ -4141,6 +4172,7 @@ class InferenceEngine:
             # A snapshot head whose KV could not be restored rebuilds here
             # too — lazily, on its first matching use after restart.
             t_pm = prof.mark() if prof is not None else 0.0
+            t_build, self._prefix_built = time.monotonic(), (0, 0)
             try:
                 if warm_head is not None:
                     if (
@@ -4165,6 +4197,13 @@ class InferenceEngine:
             finally:
                 if prof is not None:
                     prof.carve("prefix_match", t_pm)
+            chunks, built = self._prefix_built
+            if chunks and head_req.span is not None:
+                # The head's build, on the request that paid for it.
+                head_req.span.child(
+                    "engine.prefix_build", t0=t_build, t1=time.monotonic(),
+                    chunks=chunks, head_tokens=built,
+                )
         if hold is not None:
             # Admission hold: page-pressure eviction inside the cohort loop
             # must never free the head this very admission is wiring into
@@ -5065,7 +5104,11 @@ class InferenceEngine:
         tokens the segment's attention calls read, summed over live rows,
         forwards and layers), ``attn_row_calls`` (live rows x forwards x
         layers) and ``kv_bytes_read`` (those tokens times a token's useful
-        cache bytes a layer, ``GemmaConfig.kv_bytes_per_token``). Windowed
+        cache bytes a layer, ``GemmaConfig.kv_bytes_per_token``). A learned
+        index: ``attn_sel_tokens`` (the keys those calls attended after the
+        selection), ``index_ctx_tokens`` (the index keys they scored: none
+        for a row of no more than ``index_topk`` tokens) and
+        ``index_bytes_read`` (those times an index key's bytes). Windowed
         attention:
         ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
         whose position had reached the window."""
@@ -5083,6 +5126,15 @@ class InferenceEngine:
             attrs["moe_tokens_routed"] = routed
             attrs["attn_ctx_tokens"], attrs["attn_row_calls"] = ctx_tokens, row_calls
             attrs["kv_bytes_read"] = ctx_tokens * (mc.kv_bytes_per_token // mc.n_layers)
+            if mc.index_topk:
+                # A learned index: the masked form streams every page (what
+                # kv_bytes_read counts) and attends the selected keys alone.
+                attrs["attn_sel_tokens"], attrs["index_ctx_tokens"] = (
+                    int(c) for c in counts[own + 3 : own + 5]
+                )
+                attrs["index_bytes_read"] = attrs["index_ctx_tokens"] * (
+                    mc.index_bytes_per_token // mc.n_layers
+                )
             attrs["moe_experts_touched"] = int(counts[E])
             attrs["moe_layer_forwards"] = n_fwd * mc.n_sparse_layers
             attrs["moe_expert_slots"] = attrs["moe_layer_forwards"] * E

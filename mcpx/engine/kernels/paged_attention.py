@@ -528,6 +528,8 @@ def paged_attention(
 LATENT_ROWS = 512
 # Keys a latent step multiplies: two lane widths of scores.
 LATENT_KEY_BLOCK = 256
+# Scoped VMEM of a latent program whose score rows pass LATENT_ROWS.
+LATENT_VMEM_LIMIT = 48 * 2**20
 
 
 def latent_paged_attention_reference(
@@ -539,24 +541,29 @@ def latent_paged_attention_reference(
     start_pos: jax.Array,  # [B]
     q_lens: jax.Array,  # [B]
     layer: jax.Array | int = 0,
+    select: "jax.Array | None" = None,  # [B, S, Pmax * Psz]: 0 a key the query reads, NEG_INF one it does not
     *,
     scale: float,
 ) -> jax.Array:
     """The absorbed form, pure jnp: one shared "KV head" whose key is the
     latent beside the rotated key and whose value is the latent again.
     ``score = (q_latent . c + q_rope . k_rope) * scale`` over the keys a
-    query sees (the chunk contract of ``ragged_paged_attention_reference``),
-    ``out = sum_t p_t c_t`` [B, S, H, r]; pad queries output zeros."""
+    query sees (the chunk contract of ``ragged_paged_attention_reference``;
+    under ``select`` those of them it names, ``index_select``), ``out = sum_t
+    p_t c_t`` [B, S, H, r]; pad queries output zeros. The rotated key is the
+    first ``w`` values of its page row."""
     B, S, H, r = q_latent.shape
     psz = latent_pages.shape[3]
     n_keys = page_table.shape[1] * psz
     c = latent_pages[0, layer][page_table].reshape(B, n_keys, r)
-    kr = rope_pages[0, layer][page_table].reshape(B, n_keys, -1)
+    kr = rope_pages[0, layer][page_table].reshape(B, n_keys, -1)[..., : q_rope.shape[3]]
     logits = jnp.einsum("bshr,blr->bshl", q_latent, c, preferred_element_type=jnp.float32)
     logits += jnp.einsum("bshw,blw->bshl", q_rope, kr, preferred_element_type=jnp.float32)
     logits = logits * scale
     vis = start_pos[:, None] + jnp.arange(S) + 1  # [B, S]
     mask = jnp.arange(n_keys)[None, None, :] < vis[:, :, None]
+    if select is not None:
+        mask &= select > NEG_INF * 0.5
     logits = jnp.where(mask[:, :, None, :], logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bshl,blr->bshr", weights.astype(c.dtype), c)
@@ -564,7 +571,7 @@ def latent_paged_attention_reference(
     return jnp.where(valid[:, :, None, None], out, 0).astype(q_latent.dtype)
 
 
-def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float):
+def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float, selecting: bool = False):
     """``refs``: the scalar prefetch (page_table [B, Pmax], start_pos [B],
     q_lens [B], layer [1]; SMEM), the blocks q_latent [1, Sq, G, r] and
     q_rope [1, Sq, G, w] VMEM (one query block, G of the heads), rope_pages /
@@ -573,9 +580,13 @@ def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float):
     flight) and their DMA semaphores [2, 2]. The whole-window kernel's
     structure (``_ragged_kernel``) with ONE shared key head: a page is
     fetched once and its latent rows serve as the keys' first part and as
-    the values."""
+    the values. ``selecting``: one more block after q_rope, select [1, Sq,
+    Pmax * Psz] float32 (``index_select``), added to the scores: a key the
+    query does not read weighs nothing. Every page is still streamed."""
     page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
-    ql_ref, qr_ref, rope_pages_ref, latent_pages_ref, out_ref, rope_buf, latent_buf, sem = refs[4:]
+    refs = list(refs[4:])
+    select_ref = refs.pop(2) if selecting else None
+    ql_ref, qr_ref, rope_pages_ref, latent_pages_ref, out_ref, rope_buf, latent_buf, sem = refs
     b = pl.program_id(0)
     layer = layer_ref[0]
     S, G, r = ql_ref.shape[1:]
@@ -598,10 +609,13 @@ def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float):
     def page_copies(slot, blk, p):
         page = page_table_ref[b, blk * p_blk + p]
         rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+        rope_page = rope_pages_ref.at[0, layer, page]
+        if rope_pages_ref.shape[4] != w:  # an index key lies behind the rotated key's lanes
+            rope_page = rope_page.at[:, pl.ds(0, w)]
         return [
-            pltpu.make_async_copy(pages.at[0, layer, page], buf.at[slot, rows], sem.at[i, slot])
-            for i, (pages, buf) in enumerate(
-                ((rope_pages_ref, rope_buf), (latent_pages_ref, latent_buf))
+            pltpu.make_async_copy(page_src, buf.at[slot, rows], sem.at[i, slot])
+            for i, (page_src, buf) in enumerate(
+                ((rope_page, rope_buf), (latent_pages_ref.at[0, layer, page], latent_buf))
             )
         ]
 
@@ -651,6 +665,9 @@ def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float):
         s = s * scale  # [S*G, keys]
         pos = i * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         s = jnp.where(q_valid & (pos < vis), s, NEG_INF)
+        if selecting:
+            chosen = select_ref[0, :, pl.ds(pl.multiple_of(i * keys, keys), keys)]  # [S, keys]
+            s = (s.reshape(S, G, keys) + chosen[:, None, :]).reshape(S * G, keys)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
@@ -695,6 +712,7 @@ def ragged_paged_attention_latent(
     start_pos: jax.Array,  # [B]
     q_lens: jax.Array,  # [B]
     layer: jax.Array | int = 0,
+    select: "jax.Array | None" = None,  # [B, S, Pmax * Psz] float32 (``index_select``)
     *,
     scale: float,
     interpret: bool = False,
@@ -707,23 +725,37 @@ def ragged_paged_attention_latent(
     rope.T`` for the scores, ``p @ latent`` for the output, flash-style in
     float32. Rows ragged by ``q_lens`` as in ``ragged_paged_attention``. Its
     custom call carries this function's name: ``ragged_paged_attention``
-    selects both kernels in a trace, the whole name this one."""
+    selects both kernels in a trace, the whole name this one.
+
+    Under ``select`` (a latent block with an index) a query reads only the
+    keys it names: the same program with the selection added to its scores,
+    every page streamed and the unselected weighing nothing, under a name of
+    its own, ``ragged_paged_attention_selected``: ``ragged_paged_attention``
+    selects it with the other two in a trace, ``ragged_paged_attention_latent``
+    does not. The rotated key is then the first ``w`` lanes of its page row."""
     B, S, H, r = q_latent.shape
     w = q_rope.shape[3]
     page_size = latent_pages.shape[3]
+    selecting = select is not None
     sq, g, p_blk = _latent_blocking(S, H, page_size, page_table.shape[1])
     s_pad = pl.cdiv(S, sq) * sq
     if s_pad != S:
         pad = ((0, 0), (0, s_pad - S), (0, 0), (0, 0))
         q_latent, q_rope = jnp.pad(q_latent, pad), jnp.pad(q_rope, pad)
+        if selecting:
+            select = jnp.pad(select, pad[:3])
     q_block = lambda width: pl.BlockSpec(
         (1, sq, g, width), lambda b, h, j, *_: (b, j, h, 0), memory_space=pltpu.VMEM
     )
+    blocks = [q_block(r), q_block(w)]
+    if selecting:
+        blocks.append(pl.BlockSpec(
+            (1, sq, select.shape[2]), lambda b, h, j, *_: (b, j, 0), memory_space=pltpu.VMEM
+        ))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, H // g, s_pad // sq),
-        in_specs=[q_block(r), q_block(w), pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=blocks + [pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_block(r),
         scratch_shapes=[
             pltpu.VMEM((2, p_blk * page_size, w), rope_pages.dtype),
@@ -731,12 +763,22 @@ def ragged_paged_attention_latent(
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
+    kernel = functools.partial(_latent_kernel, page_size=page_size, p_blk=p_blk, scale=scale)
+    more = {}
+    if selecting:
+        kernel = functools.partial(kernel, selecting=True)
+    if sq * g > LATENT_ROWS:
+        # A window of whole sublane tiles over more heads than LATENT_ROWS
+        # counts on (8 queries x 128 heads): the carries and the score tile
+        # double, past Mosaic's default scoped limit; the chip's VMEM is 128 MiB.
+        more["compiler_params"] = pltpu.CompilerParams(vmem_limit_bytes=LATENT_VMEM_LIMIT)
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, page_size=page_size, p_blk=p_blk, scale=scale),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_latent.shape, q_latent.dtype),
         interpret=interpret,
-        name="ragged_paged_attention_latent",
+        name="ragged_paged_attention_selected" if selecting else "ragged_paged_attention_latent",
+        **more,
     )(
         page_table.astype(jnp.int32),
         start_pos.astype(jnp.int32),
@@ -744,7 +786,210 @@ def ragged_paged_attention_latent(
         jnp.asarray(layer, jnp.int32).reshape(1),
         q_latent,
         q_rope,
+        *((select,) if selecting else ()),
         rope_pages,
         latent_pages,
+    )
+    return out[:, :S]
+
+
+# ------------------------------------------------------- the learned index
+# Queries an index program scores: one bfloat16 sublane tile (16 rows), so
+# that the head-major query rows merge as they are stored and the sum over
+# the index heads is a sum of whole float32 tiles.
+INDEX_QUERIES = 16
+
+
+def index_select_reference(
+    q_index: jax.Array,  # [B, S, Hi, di]
+    w_index: jax.Array,  # [B, S, Hi] float32
+    key_pages: jax.Array,  # [1, L, N, Psz, lane0 + di]: the index key behind lane0
+    page_table: jax.Array,  # [B, Pmax]
+    start_pos: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: jax.Array | int = 0,
+    *,
+    topk: int,
+    lane0: int,
+) -> jax.Array:
+    """Which cached keys each query reads, pure jnp: float32 [B, S, Pmax *
+    Psz], 0 at the ``topk`` keys of largest index score ``sum_h w_h relu(q_h
+    . k)`` among those the query can see (all of them where it sees no more
+    than ``topk``; ties to the lower position), NEG_INF elsewhere and
+    everywhere for a pad query."""
+    from mcpx.models.gemma.model import index_scores, select_top
+
+    B, S = q_index.shape[:2]
+    n_keys = page_table.shape[1] * key_pages.shape[3]
+    k_i = key_pages[0, layer][page_table].reshape(B, n_keys, -1)[..., lane0:]
+    vis = start_pos[:, None] + jnp.arange(S) + 1
+    visible = jnp.arange(n_keys)[None, None, :] < vis[:, :, None]
+    visible &= (jnp.arange(S)[None, :] < q_lens[:, None])[:, :, None]
+    chosen = select_top(index_scores(q_index, w_index, k_i), visible, topk)
+    return jnp.where(chosen, 0.0, NEG_INF).astype(jnp.float32)
+
+
+def _index_kernel(*refs, page_size: int, p_blk: int, topk: int, lane0: int):
+    """``refs``: the scalar prefetch (page_table, start_pos, q_lens, layer;
+    SMEM), q [1, Hi, Sq, di] and w [1, Hi, Sq, 128] VMEM (one block of Sq =
+    ``INDEX_QUERIES`` queries, head-major; a weight repeated along its lane
+    row), key_pages [1, L, N, Psz, lane0 + di] ANY, out [1, Sq, Pmax * Psz]
+    float32 VMEM; then key_buf [2, P_BLK * Psz, di] and its DMA semaphores
+    [2]. The program streams its row's index keys a block of pages at a time
+    as ``_latent_kernel`` streams the latents, writes each block's scores
+    into ``out``, and then turns ``out`` into the selection in place: the
+    ``topk``-th largest score of every query by a search over the bits of the
+    float32 (32 counts), ties cut at a position found the same way. A block
+    whose queries all see no more than ``topk`` keys streams nothing: they
+    read every key they see."""
+    page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
+    q_ref, w_ref, key_pages_ref, out_ref, key_buf, sem = refs[4:]
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    Hi, S, di = q_ref.shape[1:]
+    n_keys = out_ref.shape[2]
+    keys = p_blk * page_size
+    q0 = pl.program_id(1) * S
+    start = start_pos_ref[b] + q0
+    qn = jnp.clip(q_lens_ref[b] - q0, 0, S)
+    searching = start + qn > topk
+    n_pages = jnp.where(
+        searching, _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1]), 0
+    )
+    n_blocks = pl.cdiv(n_pages, p_blk)
+    q = q_ref[0].reshape(Hi * S, di)
+    weight = w_ref[0].reshape(Hi * S, w_ref.shape[3])[:, :1]  # [Hi * S, 1]
+
+    def each_page(slot, blk, act):
+        def one(p, carry):
+            page = page_table_ref[b, blk * p_blk + p]
+            rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+            act(pltpu.make_async_copy(
+                key_pages_ref.at[0, layer, page].at[:, pl.ds(lane0, di)],
+                key_buf.at[slot, rows], sem.at[slot],
+            ))
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(n_pages - blk * p_blk, p_blk), one, 0)
+
+    out_ref[0] = jnp.full((S, n_keys), -jnp.inf, jnp.float32)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_page(0, 0, operator.methodcaller("start"))
+
+    def body(i, carry):
+        slot = lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            each_page(1 - slot, i + 1, operator.methodcaller("start"))
+
+        each_page(slot, i, operator.methodcaller("wait"))
+        k_tile = key_buf[slot]  # [keys, di]; rows of a page not fetched are masked below
+        s = lax.dot_general(
+            q, k_tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [Hi * S, keys]
+        part = jnp.sum((jnp.maximum(s, 0.0) * weight).reshape(Hi, S, keys), axis=0)
+        part = jnp.where(part == 0.0, 0.0, part)  # no -0.0: the search below orders bits
+        out_ref[0, :, pl.ds(pl.multiple_of(i * keys, keys), keys)] = part
+        return carry
+
+    lax.fori_loop(0, n_blocks, body, 0)
+
+    row = lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+    pos = lax.broadcasted_iota(jnp.int32, (S, n_keys), 1)
+    visible = (row < qn) & (pos < start + row + 1)
+
+    @pl.when(jnp.logical_not(searching))
+    def _():
+        out_ref[0] = jnp.where(visible, 0.0, NEG_INF)
+
+    @pl.when(searching)
+    def _():
+        # A float32's bits as an int32 that orders as the float does.
+        bits = lax.bitcast_convert_type(jnp.where(visible, out_ref[0], -jnp.inf), jnp.int32)
+        x = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+        count = lambda hit: jnp.sum(hit.astype(jnp.int32), axis=-1, keepdims=True)
+
+        def raise_threshold(i, t):  # the largest t with at least topk scores >= t
+            cand = t ^ lax.shift_left(jnp.int32(1), 31 - i)
+            return jnp.where(count(x >= cand) >= topk, cand, t)
+
+        kth = lax.fori_loop(0, 32, raise_threshold, jnp.full((S, 1), -(2**31), jnp.int32))
+        tie = x == kth
+        need = topk - count(x > kth)
+        n_bits = n_keys.bit_length()
+
+        def raise_cut(i, cut):  # the largest cut with at most ``need`` ties before it
+            cand = cut | lax.shift_left(jnp.int32(1), n_bits - 1 - i)
+            return jnp.where(count(tie & (pos < cand)) <= need, cand, cut)
+
+        cut = lax.fori_loop(0, n_bits, raise_cut, jnp.zeros((S, 1), jnp.int32))
+        chosen = visible & ((x > kth) | (tie & (pos < cut)))
+        out_ref[0] = jnp.where(chosen, 0.0, NEG_INF)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "lane0", "interpret"))
+def lightning_indexer(
+    q_index: jax.Array,  # [B, S, Hi, di]
+    w_index: jax.Array,  # [B, S, Hi] float32
+    key_pages: jax.Array,  # [1, L, N, Psz, lane0 + di] (stays in HBM)
+    page_table: jax.Array,  # [B, Pmax]
+    start_pos: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: jax.Array | int = 0,
+    *,
+    topk: int,
+    lane0: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """``index_select_reference`` as a kernel: grid (B, cdiv(S, 16)); one
+    program scores a block of 16 queries x every index head against its row's
+    cached index keys through the page table (one DMA a page: lanes ``lane0``
+    on of the row's rotated-key page), ``[Hi * 16, di] @ keys.T`` a block of
+    ``LATENT_KEY_BLOCK`` keys, and selects in VMEM. Its custom call carries
+    this function's name, which is part of no other kernel's."""
+    B, S, Hi, di = q_index.shape
+    page_size = key_pages.shape[3]
+    p_max = page_table.shape[1]
+    n_keys = p_max * page_size
+    sq = INDEX_QUERIES
+    p_blk = max(1, min(LATENT_KEY_BLOCK // page_size, p_max))
+    # Head-major, so that the sum over heads adds whole [16, keys] tiles; the
+    # window padded to whole query blocks with dead queries.
+    q = q_index.transpose(0, 2, 1, 3)
+    w = jnp.broadcast_to(w_index.transpose(0, 2, 1)[..., None], (B, Hi, S, 128))
+    s_pad = pl.cdiv(S, sq) * sq
+    if s_pad != S:
+        pad = ((0, 0), (0, 0), (0, s_pad - S), (0, 0))
+        q, w = jnp.pad(q, pad), jnp.pad(w, pad)
+    block = lambda width: pl.BlockSpec(
+        (1, Hi, sq, width), lambda b, j, *_: (b, 0, j, 0), memory_space=pltpu.VMEM
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, s_pad // sq),
+        in_specs=[block(di), block(128), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, sq, n_keys), lambda b, j, *_: (b, j, 0), memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, p_blk * page_size, di), key_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, page_size=page_size, p_blk=p_blk, topk=topk, lane0=lane0),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, s_pad, n_keys), jnp.float32),
+        interpret=interpret,
+        name="lightning_indexer",
+    )(
+        page_table.astype(jnp.int32),
+        start_pos.astype(jnp.int32),
+        q_lens.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q,
+        w,
+        key_pages,
     )
     return out[:, :S]

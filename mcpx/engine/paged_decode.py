@@ -19,7 +19,9 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mcpx.engine.kernels.paged_attention import (
+    index_select_reference,
     latent_paged_attention_reference,
+    lightning_indexer,
     paged_attention_chunk,
     paged_attention_chunk_reference,
     ragged_paged_attention,
@@ -95,11 +97,12 @@ def _latent_attend(
     positions: jax.Array,
     q_lens: "jax.Array | None",
     layer: jax.Array,
+    index: "tuple | None" = None,  # the window's index queries and weights
     *,
     mesh: Optional[Mesh],
     use_pallas: bool,
     interpret: bool,
-) -> jax.Array:
+) -> tuple:
     """Latent attention against the pages, ABSORBED: a head's unrotated
     query goes through its own key expansion ``W_uk,h`` into the latent's
     space (``q~ = q_nope W_uk^T``), scores and weighted sums are taken on the
@@ -108,36 +111,66 @@ def _latent_attend(
     after: [B, S, H * dv]. The same mathematics as the expanded form of the
     dense prefill (``model.latent_expand``), with no per-head key or value
     ever built for a cached token. Heads split over ``model`` where they
-    divide; the pools are whole on every device (a latent has no head axis)."""
+    divide; the pools are whole on every device (a latent has no head axis).
+    -> (the attention's output, the selection it read under or None).
+
+    With an index (``index``, and a table wide enough to hold a key the
+    selection drops) each query first scores its row's cached index keys
+    (``kernels/paged_attention.lightning_indexer``: they lie in the rotated
+    key's page rows) and the attention reads its ``index_topk`` best alone:
+    every page is still streamed, the unselected weigh nothing. Each device
+    selects for its own rows, every index head at once."""
     B, S, H, _ = q.shape
     hd, dr = cfg.head_dim, cfg.qk_rope_head_dim
     w_uk, w_uv = lp["w_ukv"][..., :hd], lp["w_ukv"][..., hd:]  # [r, H, hd], [r, H, dv]
     q_latent = jnp.einsum("bshe,rhe->bshr", q[..., :hd], w_uk)
     q_rope = q[..., hd:]
-    pad = rope_pool.shape[-1] - dr
+    pad = cfg.index_key_offset - dr
     if pad:
         q_rope = jnp.pad(q_rope, ((0, 0), (0, 0), (0, 0), (0, pad)))
     scale = cfg.attn_score_factor / (hd + dr) ** 0.5
     lens = jnp.full((B,), S, jnp.int32) if q_lens is None else q_lens
+    selecting = index is not None and page_table.shape[1] * rope_pool.shape[3] > cfg.index_topk
+    rows = None if mesh is None else _axis(mesh, DATA_AXIS, B)
+    row_specs = (P(rows, None), P(rows), P(rows), P())  # table, positions, lens, layer
+    select = None
+    if selecting:
+        choose = functools.partial(
+            lightning_indexer if use_pallas else index_select_reference,
+            topk=cfg.index_topk, lane0=cfg.index_key_offset,
+            **({"interpret": interpret} if use_pallas else {}),
+        )
+        if use_pallas and mesh is not None:
+            choose = jax.shard_map(
+                choose, mesh=mesh,
+                in_specs=(P(rows, None, None, None), P(rows, None, None), P()) + row_specs,
+                out_specs=P(rows, None, None), check_vma=False,
+            )
+        select = choose(
+            *index, rope_pool, page_table, positions, lens, jnp.asarray(layer, jnp.int32)
+        )
+    selected = () if select is None else (select,)
     if use_pallas:
         kernel = functools.partial(ragged_paged_attention_latent, scale=scale, interpret=interpret)
         if mesh is not None:
-            rows, heads = _axis(mesh, DATA_AXIS, B), _axis(mesh, MODEL_AXIS, H)
+            heads = _axis(mesh, MODEL_AXIS, H)
             q_spec = P(rows, None, heads, None)
             kernel = jax.shard_map(
                 kernel, mesh=mesh,
-                in_specs=(q_spec, q_spec, P(), P(), P(rows, None), P(rows), P(rows), P()),
+                in_specs=(q_spec, q_spec, P(), P()) + row_specs + (P(rows, None, None),) * selecting,
                 out_specs=q_spec, check_vma=False,
             )
         out = kernel(
             q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens,
-            jnp.asarray(layer, jnp.int32),
+            jnp.asarray(layer, jnp.int32), *selected,
         )
     else:
         out = latent_paged_attention_reference(
-            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens, layer, scale=scale
+            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens, layer, *selected,
+            scale=scale,
         )
-    return jnp.einsum("bshr,rhe->bshe", out, w_uv).reshape(B, S, cfg.attn_out_width)
+    attn = jnp.einsum("bshr,rhe->bshe", out, w_uv).reshape(B, S, cfg.attn_out_width)
+    return attn, select
 
 
 def _kv_window(
@@ -222,6 +255,7 @@ def decode_chunk_paged(
     mesh: Optional[Mesh] = None,  # engine mesh; required with q_lens + use_pallas
     moe_stats: bool = False,  # sparse models: also the forward's expert counters
     routing: bool = False,  # sparse models: also the experts chosen [Ls, B, S, k]
+    selection: bool = False,  # a learned index: also the keys each query read [L, B, S, keys / 8]
 ) -> tuple:
     """Multi-token decode step: S new tokens per sequence in ONE forward.
 
@@ -279,12 +313,15 @@ def decode_chunk_paged(
     # which XLA partitions (no cell runs a sparse model across chips).
     experts_kernel = use_pallas and (mesh is None or mesh.size == 1)
 
-    def attend(q, k_all, v_all, layer, window, lp):
+    def attend(q, k_all, v_all, layer, window, lp, index):
         if cfg.latent:
-            return _latent_attend(
-                q, lp, cfg, k_all, v_all, page_table, positions, q_lens, layer,
+            attn, select = _latent_attend(
+                q, lp, cfg, k_all, v_all, page_table, positions, q_lens, layer, index,
                 mesh=mesh, use_pallas=use_pallas, interpret=interpret,
             )
+            # What a comparison may ask for (``selection``): the keys every
+            # query read, a bit a key of the row's table.
+            return attn, (jnp.packbits(select == 0.0, axis=-1) if selection else None)
         # Both paths stream/gather each sequence's pages ONCE for all S
         # chunk queries (folding the chunk into the batch dim instead would
         # multiply page traffic by S — the dominant decode cost), and the
@@ -312,17 +349,17 @@ def decode_chunk_paged(
             out = paged_attention_chunk_reference(
                 qg, k_all, v_all, page_table, positions, layer, window
             )
-        return out.reshape(B, S, cfg.n_heads * cfg.head_dim)
+        return out.reshape(B, S, cfg.n_heads * cfg.head_dim), None
 
     def body(carry, scanned):
         x, k_all, v_all, layer, stats = carry  # pools: [K, L, N, Psz, hd]
         lp, kind = scanned
         lp = dequant_layer(lp, jnp.dtype(cfg.dtype))
         h = rms_norm(x, lp["pre_attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
-        q, k, v = attention_inputs(h, lp, cfg, pos_mat, kind)  # the pages hold k as attended
+        q, k, v, index = attention_inputs(h, lp, cfg, pos_mat, kind)  # the pages hold k as attended
         k_all = _write_kv_window(k_all, layer, k, kv_window)
         v_all = _write_kv_window(v_all, layer, v, kv_window)
-        attn = attend(q, k_all, v_all, layer, kind.get("window"), lp)
+        attn, read = attend(q, k_all, v_all, layer, kind.get("window"), lp, index)
         x = attention_residual(x, h, attn, lp, cfg)
         x, layer_stats, chosen = feed_forward_residual(
             x, lp, cfg, moe=(experts, sparse_index(cfg, layer), live),
@@ -330,7 +367,7 @@ def decode_chunk_paged(
         )
         if layer_stats is not None:
             stats = add_layer_stats(stats, layer_stats)
-        return (x, k_all, v_all, layer + 1, stats), chosen
+        return (x, k_all, v_all, layer + 1, stats), (chosen, read)
 
     # One scan a stack (one, but for leading dense layers before sparse
     # ones): the global layer counter, the pools and the counters cross.
@@ -338,8 +375,10 @@ def decode_chunk_paged(
         x, paged_kv["k"], paged_kv["v"], jnp.asarray(0, jnp.int32),
         moe_stats_init(cfg) if cfg.n_experts else None,
     )
+    reads = []
     for scanned, lo, hi in stacks:
-        carry, chosen = lax.scan(body, carry, (scanned, layer_kinds(cfg, lo, hi)))
+        carry, (chosen, read) = lax.scan(body, carry, (scanned, layer_kinds(cfg, lo, hi)))
+        reads.append(read)
     x, k_new, v_new, _, stats = carry
     if stats is not None:
         # What this forward's attention calls read, by row: a live row's
@@ -351,6 +390,8 @@ def decode_chunk_paged(
     # What a sparse model's callers may ask for beside the logits: the
     # forward's expert counters (``moe_stats_init``) and the experts chosen.
     extra = ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
+    if selection:
+        extra += (jnp.concatenate(reads),)
     if active_cols is not None:
         # Draft verification needs logits at EVERY chunk position, but only
         # over the grammar's C active columns: gather those unembed rows

@@ -96,6 +96,13 @@ class GemmaConfig:
     router_scoring: str = "softmax"
     router_bias_scale: float = 0.0
     router_scale: float = 1.0
+    # Group-limited choice (sigmoid scoring; 0 = none): the experts lie in
+    # ``router_groups`` runs of consecutive ones, a group scores the sum of
+    # its two largest (biased) scores, the best ``router_groups_kept`` groups
+    # stay and the choice is made inside them. The weights are the unbiased
+    # scores, as without groups.
+    router_groups: int = 0
+    router_groups_kept: int = 0
     # --- the attention kind: ``heads`` (MHA / GQA / MQA: ``n_kv_heads`` heads
     # of K and of V a token in the cache) or ``latent``: the query through a
     # rank-``q_lora_rank`` bottleneck with its own norm, keys and values through
@@ -111,6 +118,18 @@ class GemmaConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     attn_score_factor: float = 1.0
+    # --- a learned index over a latent cache (``index_topk`` > 0; 0 = none,
+    # and the block every latent configuration was before): ``index_n_heads``
+    # index queries of ``index_head_dim`` a token (out of the query's latent)
+    # score every cached token's ONE index key (LayerNorm of its own
+    # projection, its first ``qk_rope_head_dim`` values rotated), ``sum_h w_h
+    # relu(q_h . k)`` with per-head weights out of the token's normed input,
+    # and the attention reads the ``index_topk`` best-scoring tokens a query
+    # can see and no others. The index key lives in the rotated key's page
+    # row, behind its lanes (``kv_widths``, ``index_key_offset``).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -132,6 +151,14 @@ class GemmaConfig:
                 )
         elif any(latent_sizes) or self.attn_score_factor != 1.0:
             raise ConfigError("the latent ranks and attn_score_factor belong to attention='latent'")
+        index_sizes = (self.index_n_heads, self.index_head_dim, self.index_topk)
+        if any(index_sizes) and (
+            not self.latent or min(index_sizes) < 1 or self.index_head_dim < self.qk_rope_head_dim
+        ):
+            raise ConfigError(
+                "the index (index_n_heads, index_head_dim >= qk_rope_head_dim, index_topk, all "
+                ">= 1) belongs to attention='latent'"
+            )
         if self.activation not in ("gelu_tanh", "silu"):
             raise ConfigError(f"activation {self.activation!r}: gelu_tanh or silu")
         # A JSON round trip (dataclasses.asdict -> GemmaConfig(**d)) hands a list.
@@ -163,9 +190,23 @@ class GemmaConfig:
             if self.router_scoring not in ("softmax", "sigmoid"):
                 raise ConfigError(f"router_scoring {self.router_scoring!r}: softmax or sigmoid")
             if self.router_scoring == "softmax" and (
-                self.router_bias_scale or self.router_scale != 1.0
+                self.router_bias_scale or self.router_scale != 1.0 or self.router_groups
             ):
-                raise ConfigError("router bias and scale belong to sigmoid scoring")
+                raise ConfigError("router bias, scale and groups belong to sigmoid scoring")
+            if self.router_groups:
+                size = self.n_experts // self.router_groups
+                if (
+                    self.n_experts % self.router_groups
+                    or size < 2
+                    or not 1 <= self.router_groups_kept <= self.router_groups
+                    or self.router_groups_kept * size < self.n_experts_per_tok
+                ):
+                    raise ConfigError(
+                        "router_groups divides n_experts into groups of >= 2, of which "
+                        "router_groups_kept (1..router_groups) hold n_experts_per_tok"
+                    )
+            elif self.router_groups_kept:
+                raise ConfigError("router_groups_kept needs router_groups")
         elif self.n_dense_layers or self.d_shared_expert:
             raise ConfigError("n_dense_layers / d_shared_expert need n_experts")
 
@@ -196,11 +237,21 @@ class GemmaConfig:
         normed latent, which the scores read too."""
         if not self.latent:
             return self.head_dim, self.head_dim
-        return -(-self.qk_rope_head_dim // 128) * 128, self.kv_lora_rank
+        return self.index_key_offset + self.index_head_dim, self.kv_lora_rank
+
+    @property
+    def index_key_offset(self) -> int:
+        """Where a token's index key starts in its row of the ``k`` pool: past
+        the rotated key's lanes. One page id then addresses the latent, the
+        rotated key and the index key, and whatever carries a page run (the
+        commit, the window write, spill, readmit, snapshot, a radix split)
+        carries all three."""
+        return -(-self.qk_rope_head_dim // 128) * 128
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """USEFUL cache bytes a token holds over all layers (no lane padding)."""
+        """USEFUL cache bytes a token's ATTENTION reads over all layers (no
+        lane padding; the index key is ``index_bytes_per_token``'s)."""
         import jax.numpy as jnp  # bfloat16 is a dtype to jax's numpy, not to numpy's
 
         per_layer = (
@@ -208,6 +259,13 @@ class GemmaConfig:
             else 2 * self.n_kv_heads * self.head_dim
         )
         return per_layer * self.n_layers * jnp.dtype(self.dtype).itemsize
+
+    @property
+    def index_bytes_per_token(self) -> int:
+        """Bytes of a token's index keys over all layers."""
+        import jax.numpy as jnp
+
+        return self.index_head_dim * self.n_layers * jnp.dtype(self.dtype).itemsize
 
     @property
     def branches_float32(self) -> bool:
@@ -317,6 +375,9 @@ class GemmaConfig:
                 + D * (rkv + dr) + rkv + rkv * H * (hd + dv)  # w_dkv, its norm, w_ukv
                 + H * dv * D + 2 * D  # wo, the layer's two norms
             )
+            if self.index_topk:
+                Hi, di = self.index_n_heads, self.index_head_dim
+                attn += rq * Hi * di + D * di + 2 * di + D * Hi  # w_qi, w_ki, its norm, w_wi
         attn += self.attn_gate * D * H * hd + self.qk_norm * 2 * hd + self.post_norms * 2 * D
         sparse_ff = (
             (D + bool(self.router_bias_scale)) * self.n_experts

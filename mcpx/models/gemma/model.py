@@ -116,7 +116,23 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         latent (with the rotated key beside it, ``w_dkv``'s last columns) and
         its norm, the per-head expansions, Wo over every head's value."""
         rq, rkv, dr, dv = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+        index = {}
+        if cfg.index_topk:
+            # The index: its queries out of the query's latent, one key and
+            # the per-head weights out of the layer's normed input; the key's
+            # LayerNorm at its identity (gain 1, bias 0).
+            Hi, di = cfg.index_n_heads, cfg.index_head_dim
+            index = {
+                "w_qi": normal("w_qi", jax.random.fold_in(key, 16), (n, rq, Hi, di), rq, stack=stack),
+                "w_ki": normal("w_ki", jax.random.fold_in(key, 17), (n, D, di), D, stack=stack),
+                "ki_norm": t("ki_norm", jnp.ones((n, di), dtype, device=sharding(stack + "ki_norm"))),
+                "ki_norm_bias": t(
+                    "ki_norm_bias", jnp.zeros((n, di), dtype, device=sharding(stack + "ki_norm_bias"))
+                ),
+                "w_wi": normal("w_wi", jax.random.fold_in(key, 18), (n, D, Hi), D, stack=stack),
+            }
         return {
+            **index,
             "pre_attn_norm": gain("pre_attn_norm", (n, D), stack),
             "pre_mlp_norm": gain("pre_mlp_norm", (n, D), stack),
             "w_dq": normal("w_dq", k_q, (n, D, rq), D, stack=stack),
@@ -315,11 +331,12 @@ def _attend_query_blocks(q, k, v, mask, score_factor: float) -> jax.Array:
 # only in where K/V are written and what attends.
 def attention_inputs(
     h: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig, positions: jax.Array, kind
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple:
     """Normed input h [B, T, D] -> q [B, T, H, hd], k and v [B, T, K, hd] as
     the cache holds them: q and k normed per head where the block has a q/k
-    norm, then rotated by this layer's rope. Latent attention:
-    ``latent_attention_inputs``."""
+    norm, then rotated by this layer's rope; and the tokens' index queries
+    (``latent_attention_inputs``; None for a block with no index). Latent
+    attention: ``latent_attention_inputs``."""
     if cfg.latent:
         return latent_attention_inputs(h, lp, cfg, positions, kind)
     q = jnp.einsum("btd,dkh->btkh", h, lp["wq"])
@@ -331,18 +348,23 @@ def attention_inputs(
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_plus_one, jnp.float32)
     q = apply_rope(q, positions, cfg.rope_theta, kind).astype(h.dtype)
     k = apply_rope(k, positions, cfg.rope_theta, kind).astype(h.dtype)
-    return q, k, v
+    return q, k, v, None
 
 
 def latent_attention_inputs(
     h: jax.Array, lp: dict[str, jax.Array], cfg: GemmaConfig, positions: jax.Array, kind
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple:
     """Normed input h [B, T, D] -> q [B, T, H, hd + dr] (a head's unrotated
     values, then its rotated ones), and what the cache holds of a token, ONE
     row for every head: ``k`` [B, T, 1, kv_widths[0]], the shared key after
-    the rotation (zeros past its ``dr`` values), and ``v`` [B, T, 1, rkv],
-    the latent after its norm. The rotated dims pair half-split (value i with
-    value i + dr/2), as ``apply_rope`` pairs them."""
+    the rotation (zeros up to ``index_key_offset``, then the token's index
+    key where the block has an index), and ``v`` [B, T, 1, rkv], the latent
+    after its norm. The rotated dims pair half-split (value i with value i +
+    dr/2), as ``apply_rope`` pairs them. Last, the tokens' side of the index
+    (None without one): their index queries [B, T, Hi, di] and per-head
+    weights [B, T, Hi] float32, ``w = (h W_w) Hi^-0.5 di^-0.5``; the index
+    key is ``LayerNorm(h W_ki)``, and the first ``dr`` values of the key and
+    of every index query are rotated as the shared key is."""
     hd, dr, rkv = cfg.head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     f32, dtype = jnp.float32, h.dtype
     # Each product is taken as accumulated (float32), and what follows it
@@ -360,10 +382,62 @@ def latent_attention_inputs(
         down[..., :rkv], lp["kv_lora_norm"], cfg.norm_eps, cfg.norm_plus_one, dtype
     )
     k_rope = apply_rope(down[:, :, None, rkv:], positions, cfg.rope_theta, kind).astype(dtype)
-    pad = cfg.kv_widths[0] - dr
+    pad = cfg.index_key_offset - dr
     if pad:
         k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, 0), (0, pad)))
-    return q, k_rope, latent[:, :, None, :]
+    index = None
+    if cfg.index_topk:
+        Hi, di = cfg.index_n_heads, cfg.index_head_dim
+
+        def rotate_head(x):  # [B, T, heads, di]: the first dr values rotate
+            rotated = apply_rope(x[..., :dr], positions, cfg.rope_theta, kind)
+            return jnp.concatenate([rotated, x[..., dr:]], axis=-1).astype(dtype)
+
+        q_i = rotate_head(jnp.einsum("btr,rhe->bthe", c_q, lp["w_qi"], preferred_element_type=f32))
+        k_i = jnp.einsum("btd,de->bte", h, lp["w_ki"], preferred_element_type=f32)
+        mean = jnp.mean(k_i, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k_i - mean), axis=-1, keepdims=True)
+        k_i = (k_i - mean) * lax.rsqrt(var + cfg.norm_eps) * lp["ki_norm"].astype(f32)
+        k_i = rotate_head((k_i + lp["ki_norm_bias"].astype(f32))[:, :, None, :])
+        w_i = jnp.einsum("btd,dh->bth", h, lp["w_wi"], preferred_element_type=f32)
+        index = (q_i, w_i * (Hi**-0.5 * di**-0.5))
+        k_rope = jnp.concatenate([k_rope, k_i], axis=-1)
+    return q, k_rope, latent[:, :, None, :], index
+
+
+def index_scores(q_i: jax.Array, w_i: jax.Array, k_i: jax.Array) -> jax.Array:
+    """The index score of every (query, key) pair, float32 [B, T, S]: ``sum_h
+    w[t, h] relu(q_i[t, h] . k_i[s])`` with q_i [B, T, Hi, di], w_i [B, T, Hi]
+    float32, k_i [B, S, di]."""
+    s = jnp.einsum("bthe,bse->bths", q_i, k_i, preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * w_i[..., None], axis=2)
+
+
+def select_top(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
+    """The ``k`` best-scoring of the keys a query may see, as a mask like
+    ``visible`` [..., S] (S >= k): exactly ``min(k, visible)`` keys a query,
+    ties going to the lower position."""
+    s = jnp.where(visible, scores, -jnp.inf)
+    kth = lax.top_k(s, k)[0][..., -1:]
+    over = s > kth
+    tie = s == kth
+    need = k - jnp.sum(over, axis=-1, keepdims=True)
+    return visible & (over | (tie & (jnp.cumsum(tie, axis=-1) <= need)))
+
+
+def index_mask(index, k_cache: jax.Array, mask: jax.Array, cfg: GemmaConfig) -> jax.Array:
+    """``mask`` [B, T, S] narrowed to each query's ``index_topk`` best keys:
+    the dense forward's form of the selection, a block of queries at a time."""
+    q_i, w_i = index
+    k_i = k_cache[:, :, 0, cfg.index_key_offset :]
+    blocks = [
+        select_top(
+            index_scores(q_i[:, i : i + QUERY_BLOCK], w_i[:, i : i + QUERY_BLOCK], k_i),
+            mask[:, i : i + QUERY_BLOCK], cfg.index_topk,
+        )
+        for i in range(0, mask.shape[1], QUERY_BLOCK)
+    ]
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
 
 
 def latent_expand(
@@ -491,7 +565,7 @@ def _layer(
     """
     B, T, D = x.shape
     h = rms_norm(x, lp["pre_attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
-    q, k, v = attention_inputs(h, lp, cfg, positions, kind)
+    q, k, v, index = attention_inputs(h, lp, cfg, positions, kind)
 
     b_idx = jnp.arange(B)[:, None]  # [B, 1] broadcast with write_idx [B, T]
     k_cache = k_cache.at[b_idx, write_idx].set(k.astype(k_cache.dtype))
@@ -501,6 +575,9 @@ def _layer(
         # A sliding layer's query sees itself and the window - 1 keys before.
         s_idx = jnp.arange(mask.shape[-1])
         mask = mask & (s_idx[None, None, :] > positions[:, :, None] - kind["window"])
+    if index is not None and mask.shape[-1] > cfg.index_topk:
+        # Only a cache longer than the selection can hold a key it drops.
+        mask = index_mask(index, k_cache, mask, cfg)
     if cfg.latent:
         # The expanded form: every head's keys and values rebuilt from the
         # cached latents, one head a "KV head" (no sharing to group).

@@ -111,7 +111,17 @@ def route(
         p_top, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
         return chosen.astype(jnp.int32), p_top / jnp.sum(p_top, axis=-1, keepdims=True)
     scores = jax.nn.sigmoid(logits)
-    _, chosen = lax.top_k(scores if bias is None else scores + bias, cfg.n_experts_per_tok)
+    choice = scores if bias is None else scores + bias
+    if cfg.router_groups:
+        # Group-limited: a group of consecutive experts scores the sum of its
+        # two largest; only the best ``router_groups_kept`` groups' experts
+        # can be chosen.
+        T, G = choice.shape[0], cfg.router_groups
+        grouped = choice.reshape(T, G, -1)
+        _, kept = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], axis=-1), cfg.router_groups_kept)
+        in_kept = jnp.any(kept[:, :, None] == jnp.arange(G)[None, None, :], axis=1)  # [T, G]
+        choice = jnp.where(in_kept[:, :, None], grouped, -jnp.inf).reshape(T, -1)
+    _, chosen = lax.top_k(choice, cfg.n_experts_per_tok)
     w = jnp.take_along_axis(scores, chosen, axis=-1)  # the bias chose; it weighs nothing
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return chosen.astype(jnp.int32), w * cfg.router_scale
@@ -124,6 +134,9 @@ FORWARD_STATS = 3
 # What a layer counts after its tokens per held expert (``moe_forward``).
 LAYER_STATS = 4
 
+# What a forward of a block with a learned index counts after those.
+INDEX_STATS = 2
+
 
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     """The counters a forward adds to: tokens per held expert ``[E_held]``
@@ -135,8 +148,12 @@ def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     layer's own, ``moe_forward``. Then the forward's (``add_forward_stats``):
     the (token, choice) pairs its sparse layers routed, held here or not; the
     context tokens its attention calls read, over live rows and layers; and
-    those calls, live rows times layers."""
-    return jnp.zeros((cfg.n_experts_held + LAYER_STATS + FORWARD_STATS,), jnp.int32)
+    those calls, live rows times layers. A block with a learned index
+    (``GemmaConfig.index_topk``) counts two more: the keys those calls
+    attended after the selection, and the keys the index scored (a call whose
+    row holds no more than ``index_topk`` tokens scores none)."""
+    index = INDEX_STATS if cfg.index_topk else 0
+    return jnp.zeros((cfg.n_experts_held + LAYER_STATS + FORWARD_STATS + index,), jnp.int32)
 
 
 def add_layer_stats(stats: jax.Array, layer_stats: jax.Array) -> jax.Array:
@@ -152,12 +169,18 @@ def add_forward_stats(
     row's attention read through, ``q_lens`` [B] its live tokens (0: an idle
     row, which reads nothing and routes nothing)."""
     live = q_lens > 0
-    own = jnp.stack([
+    own = [
         jnp.sum(q_lens) * (cfg.n_experts_per_tok * cfg.n_sparse_layers),
         jnp.sum(jnp.where(live, context, 0)) * cfg.n_layers,
         jnp.sum(live) * cfg.n_layers,
-    ]).astype(jnp.int32)
-    return stats.at[-FORWARD_STATS:].add(own)
+    ]
+    if cfg.index_topk:
+        k = cfg.index_topk
+        own += [
+            jnp.sum(jnp.where(live, jnp.minimum(context, k), 0)) * cfg.n_layers,
+            jnp.sum(jnp.where(live & (context > k), context, 0)) * cfg.n_layers,
+        ]
+    return stats.at[-len(own) :].add(jnp.stack(own).astype(jnp.int32))
 
 
 # The widest window whose expert steps multiply every slot: the v5e's ridge

@@ -124,6 +124,13 @@ def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
             "w_ukv": P(None, None, m(cfg.n_heads), None),
             "wo": P(None, m(cfg.n_heads), None, None),
         }
+        if cfg.index_topk:
+            # Index heads split like the query expansion; the key's and the
+            # weights' projections whole.
+            attention.update(
+                w_qi=P(None, None, m(cfg.index_n_heads), None), w_ki=whole(3),
+                ki_norm=whole(2), ki_norm_bias=whole(2), w_wi=whole(3),
+            )
     # What a block beyond the default adds stays whole on every device.
     if cfg.qk_norm:
         attention.update(q_norm=whole(2), k_norm=whole(2))

@@ -66,7 +66,27 @@ def render_prompt(
     so the training corpus builder (``models/corpus.py``) renders
     byte-identical prompts to the serving path."""
     header = _PROMPT_HEADER[:-1]  # strip trailing \n; joined back below
-    lines = header.split("\n")
+    lines = header.split("\n") + _service_lines(services, context)
+    if avoid:
+        # Warm-replan splice: exclusions ride AFTER the services block (in
+        # the prompt SUFFIX), so a replan prompt shares every byte of the
+        # original block and the engine's radix prefix cache serves its KV
+        # instead of re-prefilling it. The grammar trie still excludes
+        # these names — the line is advisory context, the trie is the
+        # guarantee.
+        lines.append("Avoid: " + ",".join(avoid))
+    lines.append(f"Intent: {intent}")
+    lines.append("JSON:")
+    text = "\n".join(lines)
+    # Fixed header = the instruction + "Services:" lines INCLUDING the
+    # trailing newline, identical for every request against any registry.
+    header_chars = len(lines[0]) + 1 + len(lines[1]) + 1
+    return text, header_chars
+
+
+def _service_lines(services: list[ServiceRecord], context: PlanContext) -> list[str]:
+    """The services block, one line a service."""
+    lines = []
     for s in services:
         feat = ""
         st = context.telemetry.get(s.name)
@@ -85,21 +105,7 @@ def render_prompt(
         ins = ",".join(sorted(s.input_schema))
         outs = ",".join(sorted(s.output_schema))
         lines.append(f"{s.name} in:{ins} out:{outs}{feat}")
-    if avoid:
-        # Warm-replan splice: exclusions ride AFTER the services block (in
-        # the prompt SUFFIX), so a replan prompt shares every byte of the
-        # original block and the engine's radix prefix cache serves its KV
-        # instead of re-prefilling it. The grammar trie still excludes
-        # these names — the line is advisory context, the trie is the
-        # guarantee.
-        lines.append("Avoid: " + ",".join(avoid))
-    lines.append(f"Intent: {intent}")
-    lines.append("JSON:")
-    text = "\n".join(lines)
-    # Fixed header = the instruction + "Services:" lines INCLUDING the
-    # trailing newline, identical for every request against any registry.
-    header_chars = len(lines[0]) + 1 + len(lines[1]) + 1
-    return text, header_chars
+    return lines
 
 
 def build_prompt_ids(
@@ -159,6 +165,10 @@ class LLMPlanner:
         # matter for batching, not just build time.
         self._grammar_cache: "OrderedDict[tuple, PlanGrammar]" = OrderedDict()
         self._grammar_lock = asyncio.Lock()
+        # The catalogue (``_catalogue_ids``): (the registry version it was
+        # rendered at with no live features in it, else None; the services
+        # block's text; its ids).
+        self._catalogue: "tuple[int | None, str, list[int]]" = (None, "", [])
 
     @classmethod
     def from_config(cls, config: MCPXConfig, retriever=None, metrics=None) -> "LLMPlanner":
@@ -263,11 +273,29 @@ class LLMPlanner:
         # can make smaller than the full-prefill one.
         tok = self.engine.tokenizer
         prefix_ids = tok.encode(_PROMPT_HEADER)
-        budget = self._token_budget(len(prefix_ids))
-        prefix_ids, suffix_ids, kept_names = build_prompt_ids(
-            tok, intent, services, context, budget, prefix_ids=prefix_ids,
-            avoid=avoid,
-        )
+        suffix_ids = None
+        if (
+            avoid is None
+            and not context.shortlist
+            and not context.exclude
+            and self.config.shortlist_top_k >= len(all_services)
+        ):
+            # A shortlist that covers the registry is a CATALOGUE: the whole
+            # registry in its own (name) order, the same bytes whatever the
+            # intent, so header + block are one shared head the engine
+            # prefills once per registry version, and only ``Intent:`` /
+            # ``JSON:`` are this request's own.
+            head_ids = prefix_ids + self._catalogue_ids(tok, version, services, context)
+            tail_ids = tok.encode(f"Intent: {intent}\nJSON:", bos=False)
+            if len(head_ids) + len(tail_ids) <= self._token_budget(len(head_ids)):
+                prefix_ids, suffix_ids = head_ids, tail_ids
+                kept_names = [s.name for s in services]
+        if suffix_ids is None:  # a shortlist (or a catalogue past the budget: trimmed as one)
+            budget = self._token_budget(len(prefix_ids))
+            prefix_ids, suffix_ids, kept_names = build_prompt_ids(
+                tok, intent, services, context, budget, prefix_ids=prefix_ids,
+                avoid=avoid,
+            )
         prompt_ids = prefix_ids + suffix_ids
 
         last_problems: list[str] = []
@@ -351,6 +379,27 @@ class LLMPlanner:
             if short:
                 return short
         return services
+
+    def _catalogue_ids(
+        self, tok, version: int, services: list[ServiceRecord], context: PlanContext
+    ) -> list[int]:
+        """The ids of the services block listing ``services`` (every line
+        with its newline), encoded apart from the header before it and the
+        intent after it so that they are the same ids in every request, and
+        encoded ONCE for a block's text: a 1,000-line block is thousands of
+        tokens, ~10 ms of BPE a request otherwise (and 2 ms to render). With
+        no live features to print, the lines follow the records alone and the
+        registry version is the key; with some, the text is rendered and
+        compared, so a feature that changes a line encodes anew."""
+        plain = version if not context.telemetry else None
+        if plain is not None and plain == self._catalogue[0]:
+            return self._catalogue[2]
+        block = "\n".join(_service_lines(services, context)) + "\n"
+        if block != self._catalogue[1]:
+            self._catalogue = (plain, block, tok.encode(block, bos=False))
+        else:
+            self._catalogue = (plain,) + self._catalogue[1:]
+        return self._catalogue[2]
 
     async def _grammar(
         self, context: PlanContext, version: int, all_services: list[ServiceRecord]

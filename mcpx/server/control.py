@@ -415,8 +415,13 @@ class ControlPlane:
             # Over-fetch so excluded (replanned-around) services don't starve
             # the shortlist of viable candidates.
             k = self.config.planner.shortlist_top_k
-            names = await self.retriever.shortlist(intent, k + len(exclude))
-            shortlist = [n for n in names if n not in exclude][:k]
+            size = getattr(self.retriever, "size", None)
+            if size is None or k < size or exclude:
+                names = await self.retriever.shortlist(intent, k + len(exclude))
+                shortlist = [n for n in names if n not in exclude][:k]
+            # else: a shortlist that covers the registry ranks nothing: the
+            # planner is shown the registry itself, in its own order (a
+            # catalogue, planner/llm.py), and retrieval is skipped.
         if version is None:
             version = await self.registry.version()
         return PlanContext(

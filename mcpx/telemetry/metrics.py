@@ -413,6 +413,13 @@ class Metrics:
             "gives goodput model-FLOPs for MFU accounting",
             registry=self.registry,
         )
+        self.prefix_build_chunks = Counter(
+            "mcpx_engine_prefix_build_chunks_total",
+            "Prefill dispatches that built a declared shared prompt head into "
+            "the radix tree: one for a head that fits a prefill bucket, one a "
+            "chunk for a longer one",
+            registry=self.registry,
+        )
         # Per-request cost ledger & per-tenant usage attribution
         # (mcpx/telemetry/ledger.py, docs/observability.md "Cost ledger &
         # SLO budgets"). All families stay empty while

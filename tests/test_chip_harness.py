@@ -85,7 +85,7 @@ CELL = "olmo2-1b.distinct-closed"
 # Readers that need a device profile, allocator statistics or the load
 # generator's own clock: nothing a served program on the CPU can feed.
 NOT_FED_HERE = {"device_op_share", "device_idle_share", "memory_in_use", "endpoint_spread",
-                "client_quantile", "mla_roofline"}
+                "client_quantile", "mla_roofline", "index_roofline", "selected_roofline"}
 LABELLED_SAMPLE = 'mcpx_engine_compiles_total{executable="admit"}'
 
 
@@ -111,10 +111,15 @@ MIXED_CELL = "trinity-mini.distinct-closed"
 # router's experts: the attributes its metrics read (PR 42).
 LATENT_CELL = "a.x-k1.wide-shortlist-closed"
 
+# The cell whose latent cache is read through a learned index, behind a
+# catalogue head longer than a prefill bucket (PR 44).
+INDEX_CELL = "deepseek-v3.2-exp.catalogue-closed"
+
 FED = _fed_in(CELL)
 FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
 FED_MIXED = [m for m in _fed_in(MIXED_CELL) if m not in FED]
 FED_LATENT = [m for m in _fed_in(LATENT_CELL) if m not in FED + FED_MIXED]
+FED_INDEX = [m for m in _fed_in(INDEX_CELL) if m not in FED + FED_MIXED + FED_LATENT]
 
 
 @pytest.fixture(scope="module")
@@ -137,6 +142,14 @@ def served_latent(tmp_path_factory):
     # The cell's 128-service shortlist and its 1,024 warm-up bucket make a
     # rehearsal of minutes; the attributes' names do not depend on either.
     return _serve(LATENT_CELL, tmp_path_factory, warmup_max_len=128, shortlist_top_k=8)
+
+
+@pytest.fixture(scope="module")
+def served_index(tmp_path_factory):
+    # The cell's own shortlist (1,000 >= the 120 services served here: a
+    # catalogue of ~800 tokens, past the rehearsal block's 256-token buckets,
+    # so the head is built in chunks) with a warm-up the CPU can afford.
+    return _serve(INDEX_CELL, tmp_path_factory, warmup_max_len=256, shortlist_top_k=1000)
 
 
 def _serve(cell_name, tmp_path_factory, warmup_max_len=None, shortlist_top_k=None):
@@ -487,6 +500,55 @@ def test_the_latent_blocks_attributes_count_context_and_this_share(served_latent
     assert served_latent["costs"]["model"]["params_held"] == cfg.n_params
     # the latent kernel served the decode path
     assert served_latent["paths"]["decode"]["engaged"] and served_latent["paths"]["decode"]["dispatches"] > 0
+
+
+@pytest.mark.parametrize("metric", FED_INDEX, ids=[m["name"] for m in FED_INDEX])
+def test_the_index_block_feeds_its_metrics(served_index, metric):
+    assert {m["name"] for m in FED_INDEX} == {
+        "attn.selected_share", "attn.index_tok_per_call", "attn.index_bytes_share"}
+    v = served_index["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v)
+    if metric["name"] == "attn.selected_share":
+        assert 0.02 < v < 0.06  # the 32 best of a ~800-token catalogue's keys
+    if metric["name"] == "attn.index_tok_per_call":
+        assert 600 < v < 1200  # every live row decodes behind the whole catalogue
+    if metric["name"] == "attn.index_bytes_share":
+        assert v == pytest.approx(32 / (32 + 64 + 16))  # an index key beside the latent and the rotated key
+
+
+def test_the_index_blocks_attributes_count_the_selection_and_the_heads_chunks(served_index):
+    """At the rehearsal size: an index of 4 heads x 32 over the 32 best keys,
+    two layers; the catalogue of 120 services a head of ~800 tokens, built in
+    chunks of the block's largest bucket (256) on the first plan."""
+    segments = _segments(served_index)
+    assert segments
+    for sp in segments:
+        a = sp["attrs"]
+        assert a["attn_row_calls"] > 0 and a["attn_sel_tokens"] == a["attn_row_calls"] * 32
+        assert a["index_ctx_tokens"] == a["attn_ctx_tokens"] > a["attn_sel_tokens"]  # every row is past the 32nd key
+        assert a["index_bytes_read"] == a["index_ctx_tokens"] * 32 * 2
+        assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (64 + 16) * 2  # the masked form streams every page
+    profile = served_index["health"]["engine_queue"]["worker_profile"]
+    for attr in ("attn_sel_tokens", "index_ctx_tokens", "index_bytes_read"):
+        assert profile[attr] >= sum(sp["attrs"][attr] for sp in _segments_once(served_index)) > 0
+    # the head: one dense chunk and suffix chunks over the pages before it, counted and spanned once
+    chunks = served_index["ev"].counters_after["/metrics"]["mcpx_engine_prefix_build_chunks_total"]
+    builds = [sp for tr in served_index["ev"].traces for sp in tr["tree"] if sp["name"] == "engine.prefix_build"]
+    assert chunks >= 3 and len(builds) == 1
+    assert builds[0]["attrs"]["chunks"] == chunks and 600 < builds[0]["attrs"]["head_tokens"] < 1200
+    assert builds[0]["attrs"]["head_tokens"] % 16 == 0 and builds[0]["attrs"]["head_tokens"] > 256 * (chunks - 1)
+    # every plan's own prefill is its intent behind the shared head
+    by_name = {m["name"]: m for m in METRICS}
+    per_plan = served_index["read"](by_name["engine.prefill_tok_per_plan"]["reader"],
+                                    by_name["engine.prefill_tok_per_plan"]["args"])
+    assert 0 < per_plan < 80
+    # both kernel paths engaged: the suffix route carries every plan's prompt
+    assert served_index["kernel_paths"] == {"decode": 1, "prefill": 1}
+    for path in ("decode", "prefill"):
+        assert served_index["paths"][path]["engaged"] and served_index["paths"][path]["dispatches"] > 0
+    spec = sys.modules["spec"]
+    cfg = spec.load_block("dsa", CHIP_DIR).rehearsal_config(3072)
+    assert served_index["costs"]["model"]["params_held"] == cfg.n_params
 
 
 def _segments_once(served):

@@ -142,3 +142,60 @@ def test_latent_kernel_lowers_for_tpu_on_one_device_and_under_shard_map(S):
         q, lp["w_ukv"], shapes[2], shapes[3], shapes[4], shapes[5], shapes[6]
     ).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("S", [1, 8, 64])
+def test_the_index_and_the_selecting_attention_lower_for_tpu_on_one_device_and_under_shard_map(S):
+    """At the published widths of the block with a learned index (128 heads,
+    64 index heads x 128, the index key behind the rotated key's 128 lanes, a
+    table of 512 pages), from the CPU: the Mosaic lowering of both calls,
+    bare and through ``_latent_attend`` under the engine's shard_map on a
+    2 x 2 mesh (rows over data, attention heads over model, every index head
+    on each device)."""
+    import functools
+
+    from mcpx.engine.kernels.paged_attention import lightning_indexer
+    from mcpx.engine.paged_decode import _latent_attend
+    from mcpx.models.gemma.config import GemmaConfig
+    from mcpx.parallel.mesh import make_mesh
+
+    B, H, Hi, di, r, w, L, n_pages, psz, p_max = 8, 128, 64, 128, 512, 128, 2, 33, 16, 512
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    sd = jax.ShapeDtypeStruct
+    rope_pool, latent_pool = sd((1, L, n_pages, psz, w + di), bf), sd((1, L, n_pages, psz, r), bf)
+    rows = [sd((B, p_max), i32), sd((B,), i32), sd((B,), i32), sd((), i32)]
+    index = functools.partial(lightning_indexer, topk=2048, lane0=128)
+    text = jax.jit(index).trace(
+        sd((B, S, Hi, di), bf), sd((B, S, Hi), f32), rope_pool, *rows
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "lightning_indexer" in text
+    attend = functools.partial(ragged_paged_attention_latent, scale=0.13)
+    text = jax.jit(attend).trace(
+        sd((B, S, H, r), bf), sd((B, S, H, w), bf), rope_pool, latent_pool, *rows,
+        sd((B, S, p_max * psz), f32),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "ragged_paged_attention_selected" in text
+
+    cfg = GemmaConfig(
+        vocab_size=384, d_model=256, n_layers=L, n_heads=H, n_kv_heads=1, head_dim=128, d_ff=256,
+        attention="latent", q_lora_rank=64, kv_lora_rank=r, qk_rope_head_dim=64, v_head_dim=128,
+        index_n_heads=Hi, index_head_dim=di, index_topk=2048, norm_plus_one=False,
+    )
+    assert cfg.kv_widths == (w + di, r)
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+
+    def both(q, w_ukv, q_i, w_i, rope, latent, table, pos, lens):
+        return _latent_attend(q, {"w_ukv": w_ukv}, cfg, rope, latent, table, pos, lens,
+                              jnp.asarray(1), (q_i, w_i), mesh=mesh, use_pallas=True, interpret=False)
+
+    text = jax.jit(both).trace(
+        sd((B, S, H, 192), bf), sd((r, H, 256), bf), sd((B, S, Hi, di), bf), sd((B, S, Hi), f32),
+        rope_pool, latent_pool, *rows[:3],
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 2
+    # a table no wider than the selection traces no index at all
+    narrow = jax.jit(both).trace(
+        sd((B, S, H, 192), bf), sd((r, H, 256), bf), sd((B, S, Hi, di), bf), sd((B, S, Hi), f32),
+        rope_pool, latent_pool, sd((B, 128), i32), *rows[1:3],
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert "lightning_indexer" not in narrow and "ragged_paged_attention_latent" in narrow

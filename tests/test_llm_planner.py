@@ -496,3 +496,129 @@ def test_typed_dataflow_size_gate_is_observable():
         assert typed_off() == before + 1
 
     asyncio.run(go())
+
+
+# ------------------------------------------------- the catalogue (a shortlist
+# that covers the registry): one shared head, encoded once a registry version
+class _CountingTokenizer(ByteTokenizer):
+    def __init__(self):
+        super().__init__()
+        self.encoded = []
+
+    def encode(self, text, **kw):
+        self.encoded.append(text)
+        return super().encode(text, **kw)
+
+
+class _RecordingEngine(FakeEngine):
+    def __init__(self, outputs):
+        super().__init__(outputs)
+        self.tokenizer = _CountingTokenizer()
+        self.calls = []
+
+    async def generate(self, prompt_ids, **kw):
+        self.calls.append((list(prompt_ids), kw))
+        return await super().generate(prompt_ids, **kw)
+
+
+async def _catalogue_registry(n=12):
+    reg = InMemoryRegistry()
+    for i in reversed(range(n)):  # put in another order than the registry lists them
+        await reg.put(ServiceRecord(name=f"svc{i:02d}", endpoint=f"http://svc/{i}",
+                                    input_schema={"a": "str"}, output_schema={f"o{i}": "str"}))
+    return reg
+
+
+_ONE = '{"steps":[{"s":"svc03","in":[],"next":[]}]}'
+
+
+@pytest.mark.parametrize("top_k, catalogue", [(12, True), (1000, True), (11, False)],
+                         ids=["covers", "past", "one-short"])
+def test_a_shortlist_that_covers_the_registry_is_one_shared_catalogue(top_k, catalogue):
+    from mcpx.planner.llm import _PROMPT_HEADER, render_prompt
+
+    async def go():
+        reg = await _catalogue_registry()
+        eng = _RecordingEngine([_ONE, _ONE, _ONE])
+        p = LLMPlanner(eng, PlannerConfig(kind="llm", shortlist_top_k=top_k, max_prompt_tokens=8192))
+        ctx = PlanContext(registry=reg, registry_version=await reg.version())
+        for intent in ("convert a and report", "an entirely different wording"):
+            await p.plan(intent, ctx)
+        services = await reg.list_services()
+        header = eng.tokenizer.encode(_PROMPT_HEADER)
+        blocks = [t for t in eng.tokenizer.encoded if t.startswith("svc00 ")]
+        (ids_a, kw_a), (ids_b, kw_b) = eng.calls
+        for intent, ids in (("convert a and report", ids_a), ("an entirely different wording", ids_b)):
+            # the same bytes as the one-piece rendering, whatever way they were encoded
+            assert eng.tokenizer.decode(ids) == render_prompt(intent, services, ctx)[0]
+        if not catalogue:
+            # rendered and declared as today: only the fixed header is shared
+            assert kw_a["shared_prefix_len"] == kw_b["shared_prefix_len"] == len(header)
+            assert not [t for t in blocks if t.endswith("\n")]
+            return
+        # registry (name) order, the same ids for two intents, encoded ONCE
+        assert len(blocks) == 1 and blocks[0].endswith("\n") and "Intent" not in blocks[0]
+        assert [line.split(" ")[0] for line in blocks[0].splitlines()] == [f"svc{i:02d}" for i in range(12)]
+        head = len(header) + len(ByteTokenizer().encode(blocks[0], bos=False))
+        assert kw_a["shared_prefix_len"] == kw_b["shared_prefix_len"] == head
+        assert ids_a[:head] == ids_b[:head] and ids_a[head:] != ids_b[head:]
+        if top_k < 13:
+            return  # a thirteenth service makes this one a shortlist again
+        # a new registry version (a line changes) encodes anew, once
+        await reg.put(ServiceRecord(name="svc99", endpoint="http://svc/99", input_schema={"a": "str"}))
+        await p.plan("a third", PlanContext(registry=reg, registry_version=await reg.version()))
+        blocks = [t for t in eng.tokenizer.encoded if t.startswith("svc00 ")]
+        assert len(blocks) == 2 and "svc99 " in blocks[1]
+        assert eng.calls[2][1]["shared_prefix_len"] > head
+
+    asyncio.run(go())
+
+
+def test_a_ranked_shortlist_renders_as_it_always_did():
+    """Below the registry's size the retriever's order and the header-only
+    shared prefix stay, byte for byte."""
+    from mcpx.planner.llm import _PROMPT_HEADER, render_prompt
+
+    async def go():
+        reg = await _catalogue_registry()
+        eng = _RecordingEngine([_ONE])
+        p = LLMPlanner(eng, PlannerConfig(kind="llm", shortlist_top_k=3))
+        ctx = PlanContext(registry=reg, shortlist=["svc07", "svc03", "svc10"])
+        await p.plan("rank these", ctx)
+        by = {s.name: s for s in await reg.list_services()}
+        want, _ = render_prompt("rank these", [by[n] for n in ("svc07", "svc03", "svc10")], ctx)
+        ids, kw = eng.calls[0]
+        assert eng.tokenizer.decode(ids) == want
+        assert kw["shared_prefix_len"] == len(eng.tokenizer.encode(_PROMPT_HEADER))
+
+    asyncio.run(go())
+
+
+def test_the_control_plane_skips_retrieval_for_a_catalogue():
+    from mcpx.server.control import ControlPlane
+
+    class Retriever:
+        size = 12
+
+        def __init__(self):
+            self.asked = []
+
+        async def shortlist(self, intent, k):
+            self.asked.append(k)
+            return [f"svc{i:02d}" for i in range(min(k, 12))][::-1]
+
+    async def go():
+        reg = await _catalogue_registry()
+        for top_k, asked, listed in ((12, [], None), (5, [5], 5)):
+            cfg = MCPXConfig()
+            cfg.planner.shortlist_top_k = top_k
+            retriever = Retriever()
+            cp = ControlPlane(config=cfg, registry=reg, planner=None, orchestrator=None, retriever=retriever)
+            ctx = await cp._context("anything")
+            assert retriever.asked == asked
+            assert (ctx.shortlist is None) if listed is None else len(ctx.shortlist) == listed
+            # a replan's exclusions are ranked around as before
+            await cp._context("anything", exclude={"svc01"})
+            assert retriever.asked == asked + [top_k + 1]
+
+    asyncio.run(go())
