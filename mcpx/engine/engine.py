@@ -79,7 +79,9 @@ from mcpx.core.errors import ConfigError, EngineError
 from mcpx.engine.kv_cache import PageAllocator, commit_prefill_to_pages, init_paged_kv
 from mcpx.engine.pacing import SegmentPacer, hold_until
 from mcpx.engine.paged_decode import decode_chunk_paged
-from mcpx.models.gemma.moe import LAYER_STATS, forward_weight_bytes, moe_stats_init
+from mcpx.models.gemma.moe import (
+    FORWARD_STATS, INDEX_STATS, LAYER_STATS, forward_weight_bytes, moe_stats_init,
+)
 from mcpx.engine.prefix_cache import PrefixNode, RadixPrefixCache
 from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window_rows
 from mcpx.engine.speculative import advance_drafter_state, draft_window
@@ -5108,7 +5110,12 @@ class InferenceEngine:
         index: ``attn_sel_tokens`` (the keys those calls attended after the
         selection), ``index_ctx_tokens`` (the index keys they scored: none
         for a row of no more than ``index_topk`` tokens) and
-        ``index_bytes_read`` (those times an index key's bytes). Windowed
+        ``index_bytes_read`` (those times an index key's bytes). A latent
+        cache: ``attn_query_slots`` (the query slots those calls' score tiles
+        covered: a live row's rung, ``kernels/paged_attention.latent_rung``,
+        over forwards and layers; over ``attn_row_calls`` it is 1 where every
+        live row decodes one token and the window's width where the tile
+        takes no notice of ``q_lens``). Windowed
         attention:
         ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
         whose position had reached the window."""
@@ -5134,6 +5141,12 @@ class InferenceEngine:
                 )
                 attrs["index_bytes_read"] = attrs["index_ctx_tokens"] * (
                     mc.index_bytes_per_token // mc.n_layers
+                )
+            if mc.latent:
+                # The forward's last counter: what the absorbed kernel's
+                # score tiles covered, a rung a live query block.
+                attrs["attn_query_slots"] = int(
+                    counts[own + FORWARD_STATS + (INDEX_STATS if mc.index_topk else 0)]
                 )
             attrs["moe_experts_touched"] = int(counts[E])
             attrs["moe_layer_forwards"] = n_fwd * mc.n_sparse_layers

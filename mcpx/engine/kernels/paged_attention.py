@@ -571,7 +571,36 @@ def latent_paged_attention_reference(
     return jnp.where(valid[:, :, None, None], out, 0).astype(q_latent.dtype)
 
 
-def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float, selecting: bool = False):
+def latent_rungs(sq: int) -> tuple[int, ...]:
+    """The query slots a latent program's score tile may hold, of a block of
+    ``sq``: the powers of two under it, then the block."""
+    return tuple(1 << i for i in range((sq - 1).bit_length())) + (sq,)
+
+
+def latent_rung(qn, sq: int, rungs: "tuple[int, ...] | None" = None):
+    """The rung a block with ``qn`` live slots takes (``qn`` a number, an
+    array or a scalar inside the kernel): the lowest of ``latent_rungs(sq)``
+    (of ``rungs``, which end in ``sq``, where a test compiles fewer arms)
+    that holds them. A block with none takes the lowest, and streams nothing."""
+    *lower, rung = rungs or latent_rungs(sq)
+    for n in reversed(lower):
+        rung = jnp.where(qn <= n, n, rung)
+    return rung
+
+
+def latent_query_slots(q_lens: jax.Array, S: int, H: int) -> jax.Array:
+    """The query slots one ``ragged_paged_attention_latent`` call over a
+    window of ``S`` slots x ``H`` heads multiplies for rows of ``q_lens`` [B]
+    live queries: the rung of every query block that holds a live one."""
+    sq = _latent_blocking(S, H, 1, 1)[0]  # the pages do not enter the query block
+    qn = jnp.clip(q_lens[:, None] - jnp.arange(0, S, sq), 0, sq)  # [B, blocks]
+    return jnp.sum(jnp.where(qn > 0, latent_rung(qn, sq), 0))
+
+
+def _latent_kernel(
+    *refs, page_size: int, p_blk: int, scale: float, selecting: bool = False,
+    rungs: "tuple[int, ...] | None" = None,
+):
     """``refs``: the scalar prefetch (page_table [B, Pmax], start_pos [B],
     q_lens [B], layer [1]; SMEM), the blocks q_latent [1, Sq, G, r] and
     q_rope [1, Sq, G, w] VMEM (one query block, G of the heads), rope_pages /
@@ -582,7 +611,13 @@ def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float, selecting: b
     fetched once and its latent rows serve as the keys' first part and as
     the values. ``selecting``: one more block after q_rope, select [1, Sq,
     Pmax * Psz] float32 (``index_select``), added to the scores: a key the
-    query does not read weighs nothing. Every page is still streamed."""
+    query does not read weighs nothing. Every page is still streamed.
+
+    The score tile covers the block's LIVE slots, its leading ones, rounded
+    up to a rung (``latent_rung``): one arm a rung around the same key-block
+    loop at ``n * G`` rows, the slots past ``n`` stored as the zeros a pad
+    query outputs. ``rungs``: the arms compiled, ``latent_rungs(Sq)`` unless
+    a test asks for fewer (``(Sq,)``: the whole block whatever is live)."""
     page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
     refs = list(refs[4:])
     select_ref = refs.pop(2) if selecting else None
@@ -597,14 +632,6 @@ def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float, selecting: b
     qn = jnp.clip(q_lens_ref[b] - q0, 0, S)
     n_pages = _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1])
     n_blocks = pl.cdiv(n_pages, p_blk)
-
-    q_lat = ql_ref[0].reshape(S * G, r)
-    q_rot = qr_ref[0].reshape(S * G, w)
-    if q_lat.dtype != latent_buf.dtype:
-        q_lat, q_rot = q_lat.astype(jnp.float32), q_rot.astype(jnp.float32)
-    row_q = lax.broadcasted_iota(jnp.int32, (S * G, 1), 0) // G
-    q_valid = row_q < qn
-    vis = start + row_q + 1
 
     def page_copies(slot, blk, p):
         page = page_table_ref[b, blk * p_blk + p]
@@ -646,45 +673,62 @@ def _latent_kernel(*refs, page_size: int, p_blk: int, scale: float, selecting: b
     def _():
         start_block(0, 0)
 
-    def body(i, carry):
-        m, l, acc = carry  # [S*G, 1], [S*G, 1], [S*G, r] fp32
-        slot = lax.rem(i, 2)
+    def attend(n):
+        """The key-block loop over the block's first ``n`` slots."""
+        q_lat = ql_ref[0, :n].reshape(n * G, r)
+        q_rot = qr_ref[0, :n].reshape(n * G, w)
+        if q_lat.dtype != latent_buf.dtype:
+            q_lat, q_rot = q_lat.astype(jnp.float32), q_rot.astype(jnp.float32)
+        row_q = lax.broadcasted_iota(jnp.int32, (n * G, 1), 0) // G
+        q_valid = row_q < qn
+        vis = start + row_q + 1
 
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            start_block(1 - slot, i + 1)
+        def body(i, carry):
+            m, l, acc = carry  # [n*G, 1], [n*G, 1], [n*G, r] fp32
+            slot = lax.rem(i, 2)
 
-        each_page(slot, i, operator.methodcaller("wait"))
-        c_tile = latent_buf[slot]  # [keys, r]
-        k_tile = rope_buf[slot]  # [keys, w]
-        if c_tile.dtype != q_lat.dtype:
-            c_tile, k_tile = c_tile.astype(jnp.float32), k_tile.astype(jnp.float32)
-        contract_last = (((1,), (1,)), ((), ()))
-        s = lax.dot_general(q_lat, c_tile, contract_last, preferred_element_type=jnp.float32)
-        s += lax.dot_general(q_rot, k_tile, contract_last, preferred_element_type=jnp.float32)
-        s = s * scale  # [S*G, keys]
-        pos = i * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
-        s = jnp.where(q_valid & (pos < vis), s, NEG_INF)
-        if selecting:
-            chosen = select_ref[0, :, pl.ds(pl.multiple_of(i * keys, keys), keys)]  # [S, keys]
-            s = (s.reshape(S, G, keys) + chosen[:, None, :]).reshape(S * G, keys)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # The weights in the latent's own type, as the jnp reference rounds
-        # them: a bfloat16 product accumulated in float32.
-        acc_new = acc * alpha + jnp.dot(
-            p.astype(c_tile.dtype), c_tile, preferred_element_type=jnp.float32
-        )
-        return m_new, l_new, acc_new
+            @pl.when(i + 1 < n_blocks)
+            def _():
+                start_block(1 - slot, i + 1)
 
-    m0 = jnp.full((S * G, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((S * G, 1), jnp.float32)
-    acc0 = jnp.zeros((S * G, r), jnp.float32)
-    m, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    out = jnp.where(l > 0.0, acc / jnp.maximum(l, 1e-30), 0.0)
-    out_ref[0] = out.reshape(S, G, r).astype(out_ref.dtype)
+            each_page(slot, i, operator.methodcaller("wait"))
+            c_tile = latent_buf[slot]  # [keys, r]
+            k_tile = rope_buf[slot]  # [keys, w]
+            if c_tile.dtype != q_lat.dtype:
+                c_tile, k_tile = c_tile.astype(jnp.float32), k_tile.astype(jnp.float32)
+            contract_last = (((1,), (1,)), ((), ()))
+            s = lax.dot_general(q_lat, c_tile, contract_last, preferred_element_type=jnp.float32)
+            s += lax.dot_general(q_rot, k_tile, contract_last, preferred_element_type=jnp.float32)
+            s = s * scale  # [n*G, keys]
+            pos = i * keys + lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+            s = jnp.where(q_valid & (pos < vis), s, NEG_INF)
+            if selecting:
+                chosen = select_ref[0, :n, pl.ds(pl.multiple_of(i * keys, keys), keys)]  # [n, keys]
+                s = (s.reshape(n, G, keys) + chosen[:, None, :]).reshape(n * G, keys)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # The weights in the latent's own type, as the jnp reference rounds
+            # them: a bfloat16 product accumulated in float32.
+            acc_new = acc * alpha + jnp.dot(
+                p.astype(c_tile.dtype), c_tile, preferred_element_type=jnp.float32
+            )
+            return m_new, l_new, acc_new
+
+        m0 = jnp.full((n * G, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((n * G, 1), jnp.float32)
+        acc0 = jnp.zeros((n * G, r), jnp.float32)
+        m, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+        out = jnp.where(l > 0.0, acc / jnp.maximum(l, 1e-30), 0.0)
+        out_ref[0, :n] = out.reshape(n, G, r).astype(out_ref.dtype)
+        if n < S:
+            out_ref[0, n:] = jnp.zeros((S - n, G, r), out_ref.dtype)
+
+    rungs = rungs or latent_rungs(S)
+    rung = latent_rung(qn, S, rungs)  # the block itself where it is the one rung: no branch
+    for n in rungs:
+        pl.when(rung == n)(functools.partial(attend, n))
 
 
 def _latent_blocking(S: int, H: int, page_size: int, p_max: int) -> tuple[int, int, int]:
@@ -702,37 +746,11 @@ def _latent_blocking(S: int, H: int, page_size: int, p_max: int) -> tuple[int, i
     return sq, g, max(1, min(LATENT_KEY_BLOCK // page_size, p_max))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def ragged_paged_attention_latent(
-    q_latent: jax.Array,  # [B, S, H, r]
-    q_rope: jax.Array,  # [B, S, H, w]
-    rope_pages: jax.Array,  # [1, L, N, Psz, w] (stays in HBM)
-    latent_pages: jax.Array,  # [1, L, N, Psz, r] (stays in HBM)
-    page_table: jax.Array,  # [B, Pmax]
-    start_pos: jax.Array,  # [B]
-    q_lens: jax.Array,  # [B]
-    layer: jax.Array | int = 0,
-    select: "jax.Array | None" = None,  # [B, S, Pmax * Psz] float32 (``index_select``)
-    *,
-    scale: float,
-    interpret: bool = False,
+def _latent_call(
+    q_latent, q_rope, rope_pages, latent_pages, page_table, start_pos, q_lens, layer=0,
+    select=None, *, scale: float, interpret: bool = False, rungs: "tuple[int, ...] | None" = None,
 ) -> jax.Array:
-    """The ragged kernel for a latent cache, in ABSORBED form
-    (``latent_paged_attention_reference``): grid (B, cdiv(H, G), cdiv(S, Sq));
-    one program streams a row's pages once, ``P_BLK`` pages a step, each page
-    two DMAs (its rotated key and its latent rows), and multiplies them by a
-    block of Sq queries x G heads: ``[Sq*G, r] @ latent.T + [Sq*G, w] @
-    rope.T`` for the scores, ``p @ latent`` for the output, flash-style in
-    float32. Rows ragged by ``q_lens`` as in ``ragged_paged_attention``. Its
-    custom call carries this function's name: ``ragged_paged_attention``
-    selects both kernels in a trace, the whole name this one.
-
-    Under ``select`` (a latent block with an index) a query reads only the
-    keys it names: the same program with the selection added to its scores,
-    every page streamed and the unselected weighing nothing, under a name of
-    its own, ``ragged_paged_attention_selected``: ``ragged_paged_attention``
-    selects it with the other two in a trace, ``ragged_paged_attention_latent``
-    does not. The rotated key is then the first ``w`` lanes of its page row."""
+    """``ragged_paged_attention_latent``'s call; ``rungs`` as ``_latent_kernel``'s."""
     B, S, H, r = q_latent.shape
     w = q_rope.shape[3]
     page_size = latent_pages.shape[3]
@@ -763,7 +781,9 @@ def ragged_paged_attention_latent(
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    kernel = functools.partial(_latent_kernel, page_size=page_size, p_blk=p_blk, scale=scale)
+    kernel = functools.partial(
+        _latent_kernel, page_size=page_size, p_blk=p_blk, scale=scale, rungs=rungs
+    )
     more = {}
     if selecting:
         kernel = functools.partial(kernel, selecting=True)
@@ -791,6 +811,46 @@ def ragged_paged_attention_latent(
         latent_pages,
     )
     return out[:, :S]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def ragged_paged_attention_latent(
+    q_latent: jax.Array,  # [B, S, H, r]
+    q_rope: jax.Array,  # [B, S, H, w]
+    rope_pages: jax.Array,  # [1, L, N, Psz, w] (stays in HBM)
+    latent_pages: jax.Array,  # [1, L, N, Psz, r] (stays in HBM)
+    page_table: jax.Array,  # [B, Pmax]
+    start_pos: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: jax.Array | int = 0,
+    select: "jax.Array | None" = None,  # [B, S, Pmax * Psz] float32 (``index_select``)
+    *,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """The ragged kernel for a latent cache, in ABSORBED form
+    (``latent_paged_attention_reference``): grid (B, cdiv(H, G), cdiv(S, Sq));
+    one program streams a row's pages once, ``P_BLK`` pages a step, each page
+    two DMAs (its rotated key and its latent rows), and multiplies them by a
+    block of Sq queries x G heads: ``[Sq*G, r] @ latent.T + [Sq*G, w] @
+    rope.T`` for the scores, ``p @ latent`` for the output, flash-style in
+    float32; of the block's Sq slots the tile covers the live ones, rounded
+    up to a rung (``latent_rung``: a decode row with one live query
+    multiplies G rows, not Sq * G). Rows ragged by ``q_lens`` as in
+    ``ragged_paged_attention``. Its
+    custom call carries this function's name: ``ragged_paged_attention``
+    selects both kernels in a trace, the whole name this one.
+
+    Under ``select`` (a latent block with an index) a query reads only the
+    keys it names: the same program with the selection added to its scores,
+    every page streamed and the unselected weighing nothing, under a name of
+    its own, ``ragged_paged_attention_selected``: ``ragged_paged_attention``
+    selects it with the other two in a trace, ``ragged_paged_attention_latent``
+    does not. The rotated key is then the first ``w`` lanes of its page row."""
+    return _latent_call(
+        q_latent, q_rope, rope_pages, latent_pages, page_table, start_pos, q_lens, layer, select,
+        scale=scale, interpret=interpret,
+    )
 
 
 # ------------------------------------------------------- the learned index

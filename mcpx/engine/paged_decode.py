@@ -384,7 +384,7 @@ def decode_chunk_paged(
         # What this forward's attention calls read, by row: a live row's
         # context runs through its last live query (``_ragged_n_pages``).
         lens = jnp.full((B,), S, jnp.int32) if q_lens is None else q_lens
-        stats = add_forward_stats(cfg, stats, positions + lens, lens)
+        stats = add_forward_stats(cfg, stats, positions + lens, lens, S)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     pools = {"k": k_new, "v": v_new}
     # What a sparse model's callers may ask for beside the logits: the

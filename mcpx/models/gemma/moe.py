@@ -137,6 +137,9 @@ LAYER_STATS = 4
 # What a forward of a block with a learned index counts after those.
 INDEX_STATS = 2
 
+# What a forward of a latent block counts last.
+LATENT_STATS = 1
+
 
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     """The counters a forward adds to: tokens per held expert ``[E_held]``
@@ -151,9 +154,11 @@ def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     those calls, live rows times layers. A block with a learned index
     (``GemmaConfig.index_topk``) counts two more: the keys those calls
     attended after the selection, and the keys the index scored (a call whose
-    row holds no more than ``index_topk`` tokens scores none)."""
-    index = INDEX_STATS if cfg.index_topk else 0
-    return jnp.zeros((cfg.n_experts_held + LAYER_STATS + FORWARD_STATS + index,), jnp.int32)
+    row holds no more than ``index_topk`` tokens scores none). A latent block
+    counts one more, last: the query slots its paged attention calls
+    multiplied (``kernels/paged_attention.latent_query_slots``), over layers."""
+    more = (INDEX_STATS if cfg.index_topk else 0) + (LATENT_STATS if cfg.latent else 0)
+    return jnp.zeros((cfg.n_experts_held + LAYER_STATS + FORWARD_STATS + more,), jnp.int32)
 
 
 def add_layer_stats(stats: jax.Array, layer_stats: jax.Array) -> jax.Array:
@@ -163,11 +168,14 @@ def add_layer_stats(stats: jax.Array, layer_stats: jax.Array) -> jax.Array:
 
 
 def add_forward_stats(
-    cfg: GemmaConfig, stats: jax.Array, context: jax.Array, q_lens: jax.Array
+    cfg: GemmaConfig, stats: jax.Array, context: jax.Array, q_lens: jax.Array,
+    window: "int | None" = None,
 ) -> jax.Array:
     """The forward's own counters: ``context`` [B] the cache positions a
     row's attention read through, ``q_lens`` [B] its live tokens (0: an idle
-    row, which reads nothing and routes nothing)."""
+    row, which reads nothing and routes nothing), ``window`` the slots of
+    the window its attention read the pages through (None: a dense prefill,
+    which reads no page)."""
     live = q_lens > 0
     own = [
         jnp.sum(q_lens) * (cfg.n_experts_per_tok * cfg.n_sparse_layers),
@@ -180,6 +188,11 @@ def add_forward_stats(
             jnp.sum(jnp.where(live, jnp.minimum(context, k), 0)) * cfg.n_layers,
             jnp.sum(jnp.where(live & (context > k), context, 0)) * cfg.n_layers,
         ]
+    if cfg.latent:
+        from mcpx.engine.kernels.paged_attention import latent_query_slots
+
+        slots = 0 if window is None else latent_query_slots(q_lens, window, cfg.n_heads)
+        own.append(jnp.asarray(slots) * cfg.n_layers)
     return stats.at[-len(own) :].add(jnp.stack(own).astype(jnp.int32))
 
 

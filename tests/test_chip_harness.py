@@ -297,10 +297,11 @@ def test_costs_counts_the_params_a_token_reads(served, served_sparse):
 
 def test_a_dense_block_writes_no_layer_kind_attribute(served):
     assert _segments(served)
-    assert not any(a in sp["attrs"] for sp in _segments(served) for a in LAYER_KIND_ATTRS)
+    absent = LAYER_KIND_ATTRS + ("attn_query_slots",)  # a latent block's alone
+    assert not any(a in sp["attrs"] for sp in _segments(served) for a in absent)
     assert "mcpx_engine_moe_expert_tokens_total{" not in served["engine_metrics"][1]
     profile = served["health"]["engine_queue"]["worker_profile"]
-    assert not any(a in profile for a in LAYER_KIND_ATTRS)
+    assert not any(a in profile for a in absent)
 
 
 @pytest.mark.parametrize("metric", FED_SPARSE, ids=[m["name"] for m in FED_SPARSE])
@@ -466,9 +467,12 @@ def test_the_mixed_blocks_attributes_count_sparse_layers_and_bytes(served_mixed)
 @pytest.mark.parametrize("metric", FED_LATENT, ids=[m["name"] for m in FED_LATENT])
 def test_the_latent_block_feeds_its_metrics(served_latent, metric):
     assert {m["name"] for m in FED_LATENT} == {
-        "attn.ctx_tok_per_call", "attn.latent_bytes_share", "moe.held_assignment_share"}
+        "attn.ctx_tok_per_call", "attn.latent_bytes_share", "moe.held_assignment_share",
+        "attn.slots_per_row_call"}
     v = served_latent["read"](metric["reader"], metric["args"])
     assert v is not None and math.isfinite(v)
+    if metric["name"] == "attn.slots_per_row_call":
+        assert 1 <= v < 2  # a live row decodes a token or two of its window's 8 slots a forward
     if metric["name"] == "attn.ctx_tok_per_call":
         assert 60 < v < 200  # an 8-service shortlist's prompt and what was decoded behind it
     if metric["name"] in ("attn.latent_bytes_share", "moe.held_assignment_share"):
@@ -485,11 +489,13 @@ def test_the_latent_blocks_attributes_count_context_and_this_share(served_latent
         assert a["attn_row_calls"] % 2 == 0 and 0 < a["attn_row_calls"] <= 8 * a["forwards"] * 2
         assert a["attn_ctx_tokens"] > a["attn_row_calls"]
         assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (64 + 16) * 2
+        # a live row's score tile: its rung, from one slot to the window's 8
+        assert a["attn_row_calls"] <= a["attn_query_slots"] <= 8 * a["attn_row_calls"]
         assert a["moe_tokens_routed"] % 2 == 0  # 2 experts a live token in the one sparse layer
         assert 0 <= a["moe_assignments"] <= a["moe_tokens_routed"]
         assert a["moe_expert_slots"] == a["forwards"] * 1 * 4  # the 4 experts held
     profile = served_latent["health"]["engine_queue"]["worker_profile"]
-    for attr in ("attn_ctx_tokens", "attn_row_calls", "kv_bytes_read", "moe_tokens_routed"):
+    for attr in ("attn_ctx_tokens", "attn_row_calls", "attn_query_slots", "kv_bytes_read", "moe_tokens_routed"):
         assert profile[attr] >= sum(sp["attrs"][attr] for sp in _segments_once(served_latent)) > 0
     per_expert = {key for key in served_latent["ev"].counters_after["/metrics"]
                   if key.startswith("mcpx_engine_moe_expert_tokens_total{")}

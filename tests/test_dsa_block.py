@@ -331,11 +331,12 @@ def test_a_forward_counts_the_keys_it_selected_and_the_keys_it_scored():
             params, cfg, window, jnp.asarray(positions), table, pools, use_pallas=False,
             q_lens=jnp.asarray(q_lens), moe_stats=True,
         )
-        assert stats.shape == (E + moe.LAYER_STATS + moe.FORWARD_STATS + moe.INDEX_STATS,)
-        assert stats[-moe.INDEX_STATS :].tolist() == want
+        own = E + moe.LAYER_STATS + moe.FORWARD_STATS
+        assert stats.shape == (own + moe.INDEX_STATS + moe.LATENT_STATS,)
+        assert stats[own : own + moe.INDEX_STATS].tolist() == want
     # a block with no index keeps the vector it had
     assert moe.moe_stats_init(dataclasses.replace(cfg, index_n_heads=0, index_head_dim=0, index_topk=0)).shape == (
-        E + moe.LAYER_STATS + moe.FORWARD_STATS,)
+        E + moe.LAYER_STATS + moe.FORWARD_STATS + moe.LATENT_STATS,)
 
 
 # ------------------------------------------------------ group-limited routing

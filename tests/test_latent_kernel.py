@@ -4,6 +4,7 @@ reference of the absorbed form over ragged rows, a row of one page and of
 128, a window wider than one query block; its blocking; and the lowering for
 the TPU from the CPU, bare and under the engine's ``shard_map``."""
 
+import functools
 import random
 
 import jax
@@ -13,8 +14,13 @@ import pytest
 
 from mcpx.engine.kernels.paged_attention import (
     LATENT_ROWS,
+    NEG_INF,
     _latent_blocking,
+    _latent_call,
     latent_paged_attention_reference,
+    latent_query_slots,
+    latent_rung,
+    latent_rungs,
     ragged_paged_attention_latent,
 )
 
@@ -89,6 +95,107 @@ def test_bfloat16_pools_round_the_weights_as_the_reference_does():
     np.testing.assert_allclose(out.astype(np.float32), ref.astype(np.float32), rtol=0.02, atol=0.02)
 
 
+# ------------------------------------------------- the live slots' rung
+# (q_lens of the row under test, window, heads, selecting): every live count
+# of an 8-slot decode window at both published head counts, and a 64-slot
+# suffix window whose third query block holds 6 live slots.
+RUNG_CASES = [
+    (n, 8, H, selecting) for H in (64, 128) for selecting in (False, True) for n in (0, 1, 2, 3, 5, 8)
+] + [(22, 64, 64, True), (22, 64, 128, False)]
+
+
+@functools.lru_cache(maxsize=None)
+def _interpreted(rungs):
+    """One jitted interpret-mode call a ladder: a case's live counts are data."""
+    return jax.jit(functools.partial(_latent_call, scale=0.13, interpret=True, rungs=rungs))
+
+
+@pytest.mark.parametrize("n, S, H, selecting", RUNG_CASES)
+def test_a_rows_live_slots_take_their_rung_and_nothing_else_moves(n, S, H, selecting):
+    """The kernel at the rung of each block's live slots agrees with the jnp
+    reference; its live rows are bit for bit those of the same body run at
+    the whole block (the tile before the rungs: ``rungs=(Sq,)``), and every
+    dead slot is the zero a pad query outputs."""
+    B, r, w, psz, p_max = 2, 32, 128, 16, 32  # 512 keys: two key blocks, the second part filled
+    args = _case(11 + n, B, S, H, r, w, psz, p_max, layers=1)
+    q_lens = jnp.asarray([n, 1], jnp.int32)
+    starts = jnp.asarray([20 * psz - S - 3, 17], jnp.int32)
+    select = None
+    if selecting:
+        picked = jax.random.bernoulli(jax.random.PRNGKey(n), 0.3, (B, S, p_max * psz))
+        select = jnp.where(picked.at[:, :, 0].set(True), 0.0, NEG_INF).astype(jnp.float32)
+    sq = _latent_blocking(S, H, psz, p_max)[0]
+    assert sq == 8
+    out, whole = (np.asarray(_interpreted(rungs)(*args, starts, q_lens, 0, select)) for rungs in (None, (sq,)))
+    ref = np.asarray(latent_paged_attention_reference(*args, starts, q_lens, 0, select, scale=0.13))
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    live = np.arange(S)[None, :] < np.asarray(q_lens)[:, None]
+    assert np.array_equal(out[live], whole[live])
+    assert np.all(out[~live] == 0.0) and np.all(whole[~live] == 0.0)
+    assert n == 0 or np.abs(out[0, :n]).min(axis=(1, 2)).max() > 0
+
+
+@pytest.mark.parametrize("sq", [1, 2, 3, 5, 8, 16, 128])
+def test_a_rung_holds_its_live_slots_and_never_more_than_the_block(sq):
+    rungs = latent_rungs(sq)
+    assert rungs == tuple(sorted(set(rungs))) and rungs[-1] == sq and rungs[0] == 1
+    taken = [int(latent_rung(q, sq)) for q in range(sq + 1)]
+    assert taken == sorted(taken) and set(taken) <= set(rungs)  # monotone, on the ladder
+    assert all(max(q, 1) <= t <= sq and t < 2 * max(q, 1) for q, t in zip(range(sq + 1), taken))
+    # an array of live counts takes the rungs its members take
+    assert np.broadcast_to(latent_rung(jnp.arange(sq + 1), sq), (sq + 1,)).tolist() == taken
+
+
+@pytest.mark.parametrize("S, H", [(8, 64), (8, 128), (64, 128), (5, 4)])
+def test_the_forwards_counter_is_the_sum_of_the_kernels_rungs(S, H):
+    """``moe.add_forward_stats``' last counter of a latent block is what the
+    kernel's programs multiplied: the rung of every query block with a live
+    slot, by the one function both call, times the layers."""
+    from mcpx.models.gemma import moe
+    from mcpx.models.gemma.config import GemmaConfig
+
+    cfg = GemmaConfig(
+        vocab_size=384, d_model=64, n_layers=3, n_heads=H, n_kv_heads=1, head_dim=16, d_ff=64,
+        attention="latent", q_lora_rank=16, kv_lora_rank=32, qk_rope_head_dim=16, v_head_dim=16,
+        n_experts=4, n_experts_per_tok=2, d_expert=16, norm_plus_one=False,
+    )
+    q_lens = np.asarray([0, 1, 2, 3, 5, S, min(22, S), 0])
+    sq = _latent_blocking(S, H, 16, 32)[0]
+    want = 0
+    for q in q_lens:  # a program a (row, query block), as the kernel's grid walks them
+        for q0 in range(0, S, sq):
+            qn = int(np.clip(q - q0, 0, sq))
+            want += int(latent_rung(qn, sq)) if qn else 0
+    assert int(latent_query_slots(jnp.asarray(q_lens), S, H)) == want
+    stats = moe.add_forward_stats(cfg, moe.moe_stats_init(cfg), jnp.asarray(q_lens) + 40, jnp.asarray(q_lens), S)
+    assert int(stats[-1]) == want * cfg.n_layers
+    # a forward that read no page (the expanded prefill) multiplied none
+    assert int(moe.add_forward_stats(cfg, moe.moe_stats_init(cfg), jnp.asarray(q_lens), jnp.asarray(q_lens))[-1]) == 0
+    # a block with heads for a cache counts no such thing
+    dense = GemmaConfig.named("test")
+    assert not dense.latent and moe.moe_stats_init(dense).shape == (moe.LAYER_STATS + moe.FORWARD_STATS,)
+
+
+@pytest.mark.parametrize("H, p_max, selecting", [(64, 128, False), (128, 512, True)])
+@pytest.mark.parametrize("n", [1, 2, 4, 8])
+def test_every_rung_lowers_for_tpu_at_the_published_widths(n, H, p_max, selecting):
+    """An 8-slot decode window of 64 heads (a.x-k1's) and of 128 under a
+    selection (deepseek's): the arm of each rung beside the whole block's,
+    lowered for Mosaic from the CPU."""
+    B, S, r, w, L, n_pages, psz = 8, 8, 512, 128, 2, 33, 16
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    sd = jax.ShapeDtypeStruct
+    shapes = [
+        sd((B, S, H, r), bf), sd((B, S, H, w), bf), sd((1, L, n_pages, psz, w + (128 if selecting else 0)), bf),
+        sd((1, L, n_pages, psz, r), bf), sd((B, p_max), i32), sd((B,), i32), sd((B,), i32), sd((), i32),
+    ] + ([sd((B, S, p_max * psz), f32)] if selecting else [])
+    assert n in latent_rungs(S)
+    call = functools.partial(_latent_call, scale=0.13, rungs=tuple(sorted({n, S})))
+    text = jax.jit(call).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    name = "ragged_paged_attention_selected" if selecting else "ragged_paged_attention_latent"
+    assert "tpu_custom_call" in text and name in text
+
+
 @pytest.mark.parametrize("S, H, want", [
     (1, 64, (1, 64)), (8, 64, (8, 64)), (128, 64, (8, 64)), (1024, 64, (8, 64)),
     (8, 4, (8, 4)), (300, 4, (128, 4)), (8, 1024, (8, 512)),
@@ -106,8 +213,6 @@ def test_latent_kernel_lowers_for_tpu_on_one_device_and_under_shard_map(S):
     in a 128-lane row), from the CPU: the Mosaic lowering, bare and under the
     engine's shard_map on a 2 x 2 mesh (rows over data, heads over model, the
     pools whole on every device)."""
-    import functools
-
     from mcpx.engine.paged_decode import _latent_attend
     from mcpx.models.gemma.config import GemmaConfig
     from mcpx.parallel.mesh import make_mesh
@@ -152,8 +257,6 @@ def test_the_index_and_the_selecting_attention_lower_for_tpu_on_one_device_and_u
     bare and through ``_latent_attend`` under the engine's shard_map on a
     2 x 2 mesh (rows over data, attention heads over model, every index head
     on each device)."""
-    import functools
-
     from mcpx.engine.kernels.paged_attention import lightning_indexer
     from mcpx.engine.paged_decode import _latent_attend
     from mcpx.models.gemma.config import GemmaConfig

@@ -327,15 +327,18 @@ def test_a_forward_counts_what_it_routed_and_what_its_attention_read():
     lens = jnp.asarray([20, 9])
     _, _, stats = prefill(params, cfg, seq, lens, init_kv_cache(cfg, B, T), last_only=True, moe_stats=True)
     held = cfg.n_experts_held
-    assert stats.shape == (held + moe.LAYER_STATS + moe.FORWARD_STATS,)
-    assert stats[-moe.FORWARD_STATS :].tolist() == [29 * 2 * 3, 29 * 4, 2 * 4]
+    own = held + moe.LAYER_STATS
+    assert stats.shape == (own + moe.FORWARD_STATS + moe.LATENT_STATS,)
+    # the expanded prefill reads no page: no slot of the absorbed kernel's
+    assert stats[own:].tolist() == [29 * 2 * 3, 29 * 4, 2 * 4, 0]
     assert 0 < int(stats[:held].sum()) < 29 * 2 * 3  # this share's part of the routing
     _, _, pools, table = _prefilled(cfg, params, seq, lens)
     mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
     _, _, stats = decode_chunk_paged(
         params, cfg, seq[:, :8], lens, table, pools, use_pallas=False, mesh=mesh,
         q_lens=jnp.asarray([3, 0]), moe_stats=True)
-    assert stats[-moe.FORWARD_STATS :].tolist() == [3 * 2 * 3, 23 * 4, 1 * 4]  # the idle row reads and routes nothing
+    # the idle row reads and routes nothing; the live row's 3 queries take the rung of 4 slots
+    assert stats[own:].tolist() == [3 * 2 * 3, 23 * 4, 1 * 4, 4 * 4]
 
 
 # ------------------------------------------------ the comparison, and controls

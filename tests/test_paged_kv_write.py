@@ -334,3 +334,36 @@ def test_compiled_for_v5e_the_layer_loop_copies_no_pool(one_v5e, S):
     assert not copies, copies
     pool_bytes = K * L * N * psz * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("H, p_max, selecting", [(64, 128, False), (128, 512, True)])
+def test_compiled_for_v5e_the_latent_kernel_with_an_arm_a_rung(one_v5e, H, p_max, selecting):
+    """The absorbed kernel's decode window at the published widths (8 slots x
+    64 heads over a.x-k1's table, x 128 heads under deepseek's selection):
+    Mosaic takes every rung's arm (a partly live tile slices the query block,
+    the selection's rows and the output), which lowering alone cannot show."""
+    import functools
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from mcpx.engine.kernels.paged_attention import latent_rungs, ragged_paged_attention_latent
+
+    _, replicated = one_v5e
+    B, S, r, w, L, n_pages, psz = 8, 8, 512, 128, 2, 33, 16
+    bf, i32 = jnp.bfloat16, jnp.int32
+    sd = functools.partial(jax.ShapeDtypeStruct, sharding=replicated)
+    shapes = [
+        sd((B, S, H, r), bf), sd((B, S, H, w), bf), sd((1, L, n_pages, psz, w + (128 if selecting else 0)), bf),
+        sd((1, L, n_pages, psz, r), bf), sd((B, p_max), i32), sd((B,), i32), sd((B,), i32), sd((), i32),
+    ] + ([sd((B, S, p_max * psz), jnp.float32)] if selecting else [])
+    assert latent_rungs(S) == (1, 2, 4, 8)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        kernel = functools.partial(ragged_paged_attention_latent, scale=0.13)
+        compiled = jax.jit(kernel).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
