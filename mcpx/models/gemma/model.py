@@ -3,7 +3,11 @@
 Design choices (vs a torch-style port):
   - params are a plain pytree with layer weights **stacked on a leading
     axis**, and the layer stack runs under ``lax.scan`` — one layer is traced
-    and compiled once regardless of depth, and XLA pipelines the scan;
+    and compiled once regardless of depth, and XLA pipelines the scan; a
+    leaf is scanned in the shape its matmul contracts, so that the scan's
+    slice feeds the dot with nothing between (``wo`` is stored per head,
+    ``[L, H, hd, D]``, and scanned as the view ``[L, H * hd, D]`` that
+    ``layer_stacks`` takes of the whole stack outside the loop);
   - two entry points, both jit-friendly with **static shapes**: ``prefill``
     (full-sequence, causal) and ``decode_step`` (one token per sequence
     against a KV cache) — no data-dependent Python control flow;
@@ -458,14 +462,15 @@ def attention_residual(
 ) -> jax.Array:
     """x + the attention branch: ``attn`` [B, T, H * hd] gated by
     ``sigmoid(Wg h)`` where the block has an output gate, through Wo, normed
-    where the block norms its branches' outputs."""
+    where the block norms its branches' outputs. ``lp["wo"]`` is the layer's
+    slice of ``layer_stacks``' view, [H * hd, D]: no reshape stands between
+    the scan's slice and the dot."""
     F = cfg.attn_out_width
     if cfg.attn_gate:
         gate = jnp.einsum("btd,df->btf", h, lp["w_attn_gate"].reshape(cfg.d_model, F))
         attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
-    wo = lp["wo"].reshape(F, cfg.d_model)
     out = jnp.einsum(
-        "btf,fd->btd", attn, wo,
+        "btf,fd->btd", attn, lp["wo"],
         preferred_element_type=jnp.float32 if cfg.branches_float32 else None,
     )
     if cfg.post_norms:
@@ -528,12 +533,29 @@ def layer_stacks(cfg: GemmaConfig, params: Params) -> tuple[list, dict]:
     layer, one past the last)]``, and the expert stacks the scan closes
     over. One run, but for a sparse model with leading dense layers: those
     are a stack of their own (``params["dense_layers"]``), so the same body
-    runs over two stacks with its carry handed across."""
+    runs over two stacks with its carry handed across.
+
+    A run's ``wo`` is the stored ``[n, H, dv, D]`` leaf VIEWED as
+    ``[n, H * dv, D]`` (``_merge_heads``): the reshape is of the whole
+    stack, here, outside the scan, so the body's ``btf,fd->btd`` takes its
+    layer's slice as it is and the dot reads it out of the stack. Reshaped
+    inside the body, the slice is materialised before the dot: on the core
+    where it fits, and at 128 heads x 128 x 7,168 (235 MB) as an HBM -> HBM
+    copy that the dot then reads again (PERF.md, PR 46)."""
     scanned, experts = split_layers(cfg, params["layers"])
     Ld, L = cfg.n_dense_layers, cfg.n_layers
     if not Ld:
-        return [(scanned, 0, L)], experts
-    return [(params["dense_layers"], 0, Ld), (scanned, Ld, L)], experts
+        return [(_merge_heads(scanned), 0, L)], experts
+    return [(_merge_heads(params["dense_layers"]), 0, Ld), (_merge_heads(scanned), Ld, L)], experts
+
+
+def _merge_heads(stack: dict) -> dict:
+    """``stack`` with ``wo`` [n, H, dv, D] as [n, H * dv, D], H-major (a
+    head-sharded leaf stays sharded on the merged axis). An int8 leaf
+    (``quant.py``) is a dict of two arrays: its scale [n, 1, 1, D] goes to
+    [n, 1, D] with it."""
+    wo = jax.tree.map(lambda a: a.reshape(a.shape[0], -1, a.shape[-1]), stack["wo"])
+    return {**stack, "wo": wo}
 
 
 def sparse_index(cfg: GemmaConfig, layer: jax.Array) -> jax.Array:

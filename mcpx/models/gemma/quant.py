@@ -41,7 +41,8 @@ _CONTRACT_AXES: dict[str, tuple[int, ...]] = {
     "wq": (1,),           # [L, D, K, hd]: contracts D
     "wk": (1,),
     "wv": (1,),
-    "wo": (1, 2),         # [L, H, hd, D]: contracts H*hd
+    "wo": (1, 2),         # [L, H, hd, D]: contracts H*hd (scanned as [L, H*hd, D], the
+                          # scale [L, 1, 1, D] as [L, 1, D]: model.layer_stacks)
     "w_gate": (1,),       # [L, D, F]: contracts D
     "w_up": (1,),
     "w_down": (1,),       # [L, F, D]: contracts F

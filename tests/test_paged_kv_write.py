@@ -11,6 +11,9 @@ Three guards:
   - compiled for a described v5e (no chip attached), the layer loop holds
     no ``copy`` of the pool: the relayout the flat scatter cost on the
     chip (PERF.md, PR 25) cannot come back unnoticed.
+
+Beside them, compiled the same way: at 128 heads the layer loop writes no
+copy of a layer's ``wo`` before the projection reads it (PERF.md, PR 46).
 """
 
 import re
@@ -300,6 +303,22 @@ def one_v5e():
     return mesh, NamedSharding(mesh, PartitionSpec())
 
 
+def _compile_uncached(fn, *args, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` compiled for ``args`` with the persistent
+    compilation cache off: a described device's executable cannot be read
+    back from it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn, **jit_kw).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("S", [8, 128])
 def test_compiled_for_v5e_the_layer_loop_copies_no_pool(one_v5e, S):
     """The optimised HLO of the forward at the slab's shape, compiled for
@@ -307,22 +326,11 @@ def test_compiled_for_v5e_the_layer_loop_copies_no_pool(one_v5e, S):
     array of the pool's shape in either view, and the program needs no
     pool-sized temporary. With the flat scatter this module held four such
     copies in the while body and 271 MB of temporaries."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     mesh, replicated = one_v5e
     cfg = GemmaConfig(**_OLMO)
     args = _slab_args(cfg, S, sharding=replicated)
     K, L, N, psz, hd = args[4]["k"].shape
-    # a described device's executable cannot be read back from the
-    # persistent cache; keep this compile out of it
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(_slab_step(cfg, mesh), donate_argnums=(4,)).lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compile_uncached(_slab_step(cfg, mesh), *args, donate_argnums=(4,))
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the Mosaic kernel is in the program
     views = (f"[{K},{L},{N},{psz},{hd}]", f"[{K},{L},{N * psz},{hd}]")
@@ -344,8 +352,6 @@ def test_compiled_for_v5e_the_latent_kernel_with_an_arm_a_rung(one_v5e, H, p_max
     the selection's rows and the output), which lowering alone cannot show."""
     import functools
 
-    from jax.experimental.compilation_cache import compilation_cache
-
     from mcpx.engine.kernels.paged_attention import latent_rungs, ragged_paged_attention_latent
 
     _, replicated = one_v5e
@@ -357,13 +363,58 @@ def test_compiled_for_v5e_the_latent_kernel_with_an_arm_a_rung(one_v5e, H, p_max
         sd((1, L, n_pages, psz, r), bf), sd((B, p_max), i32), sd((B,), i32), sd((B,), i32), sd((), i32),
     ] + ([sd((B, S, p_max * psz), jnp.float32)] if selecting else [])
     assert latent_rungs(S) == (1, 2, 4, 8)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        kernel = functools.partial(ragged_paged_attention_latent, scale=0.13)
-        compiled = jax.jit(kernel).lower(*shapes).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compile_uncached(functools.partial(ragged_paged_attention_latent, scale=0.13), *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _unfused_results(text):
+    """``(result type, op)`` of every instruction of an optimised HLO module
+    that is not inside a fused computation: what the device runs as an op of
+    its own, and writes out."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    computation, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            computation = head.group(1)
+        elif computation not in fused and " = " in line:
+            m = re.match(r"\s*\S+ = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", line)
+            if m:
+                out.append(m.groups())
+    return out
+
+
+@pytest.mark.parametrize("S", [8, 64])
+def test_compiled_for_v5e_wo_at_128_heads_is_read_out_of_the_stack(one_v5e, S):
+    """Latent attention at deepseek's widths (128 heads x 128 x 7,168: 235 MB
+    of ``wo`` a layer), the decode window and the 64-slot suffix prefill: no
+    op of its own produces a layer's ``wo`` (with the slice reshaped inside
+    the scan body it was an HBM -> HBM copy that the dot read again: PERF.md,
+    PR 46), the stack is not copied, the program's temporaries are under
+    one slice, and the stack itself is an operand of the fusion that returns
+    the branch's ``[B, S, D]``."""
+    mesh, replicated = one_v5e
+    cfg = GemmaConfig(
+        vocab_size=3072, d_model=7168, n_layers=3, n_heads=128, n_kv_heads=1, head_dim=128, d_ff=2048,
+        attention="latent", q_lora_rank=1536, kv_lora_rank=512, qk_rope_head_dim=64, v_head_dim=128,
+        yarn_factor=40.0, yarn_original_max_pos=4096, attn_score_factor=1.8739, activation="silu",
+        tie_embeddings=False, scale_embeddings=False, norm_plus_one=False,
+    )
+    args = _slab_args(cfg, S, sharding=replicated)
+    n, H, dv, D = args[0]["layers"]["wo"].shape
+    assert (n, H, dv, D) == (3, 128, 128, 7168)  # the tree keeps its heads
+    compiled = _compile_uncached(_slab_step(cfg, mesh), *args, donate_argnums=(4,))
+    text = compiled.as_text()
+    slice_types = {f"bf16[{lead}{dims}]" for lead in ("", "1,") for dims in (f"{H},{dv},{D}", f"{H * dv},{D}")}
+    stack_types = {f"bf16[{n},{H},{dv},{D}]", f"bf16[{n},{H * dv},{D}]"}
+    views = ("parameter", "get-tuple-element", "bitcast")  # names of a buffer, not ops that write one
+    written = [
+        (type_, op) for type_, op in _unfused_results(text)
+        if type_ in slice_types | stack_types and op not in views
+    ]
+    assert not written, written
+    assert compiled.memory_analysis().temp_size_in_bytes < H * dv * D * 2 * 2 // 3
+    B = _SLAB["B"]
+    assert re.search(
+        rf"%fused_computation[\w.\-]* \([^)]*bf16\[{n},{H * dv},{D}\][^)]*\) -> \(?[^{{]*bf16\[{B},{S},{D}\]", text
+    ), "no fusion takes the stacked wo and returns the branch"
