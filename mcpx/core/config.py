@@ -75,13 +75,11 @@ class SpeculativeConfig:
     # Draft tokens proposed per verify forward (the window is k+1 wide:
     # current token + k drafts). Clamped at runtime when page capacity
     # cannot spare the window's garbage-write slack (logged once). The
-    # default sits at the measured cost-curve knee: the spec segment costs
-    # ~1.4x a legacy step at k=2, ~1.9x at k=4 but ~3.6x at k=8 (the
-    # verify window's draft/mask/accept machinery grows with width even
-    # where the forward itself is overhead-bound), while the mean accepted
-    # prefix on plan text (~0.6-0.8 per-position accept) saturates well
-    # before 8 — so k=4 nets >2x wall-clock decode where k=8 gives the
-    # window back in machinery and loses.
+    # default comes from a CPU reading at the test model's size (the spec
+    # segment cost ~1.4x a legacy step at k=2, ~1.9x at k=4, ~3.6x at k=8,
+    # against a mean accepted prefix that saturates well before 8). It has
+    # never run on the chip: no cell enables it and every non-default block
+    # refuses it, so what it nets there is not measured (ROADMAP D4).
     k: int = 4
     # Draft source for positions the DFA does not force:
     #   "recurrent" — the recurrent drafter head (embedding-EWMA hidden
@@ -232,14 +230,6 @@ class EngineConfig:
     # fuller cohort (an idle slab always admits immediately).
     admit_max_wait_s: float = 0.15
     max_decode_len: int = 512
-    # Long-prompt routing: full prefills whose padded length reaches this
-    # threshold run as sequence-parallel RING prefill (ppermute ring over
-    # the mesh's data devices re-viewed as a seq axis) instead of one
-    # dense [B, T, S]-masked pass. 0 disables. Requires a data axis >= 2;
-    # buckets not divisible by the seq axis fall back to dense. Planner
-    # prompts are short by design (retrieval shortlists, SURVEY.md §5), so
-    # this serves the long-context /plan tail, not the common case.
-    ring_prefill_min_tokens: int = 0
     # Sampling defaults: temperature matches the reference planner call,
     # control_plane.py:72.
     temperature: float = 0.2

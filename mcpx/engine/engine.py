@@ -463,9 +463,6 @@ class InferenceEngine:
                     self.config.model.quantize != "none",
                 "engine.speculative (the drafter scores against a tied embedding)":
                     ecfg.speculative.enabled,
-                "engine.ring_prefill_min_tokens (ring attention has one rope, no window and "
-                "no expanded latent form)":
-                    ecfg.ring_prefill_min_tokens > 0,
             }
             asked = [what for what, on in unsupported.items() if on]
             if asked:
@@ -522,7 +519,6 @@ class InferenceEngine:
         # Device state (worker thread only after start):
         self._params = None  # mcpx: owner[engine-worker]
         self._paged_kv = None  # mcpx: owner[engine-worker]
-        self._seq_mesh = None
         self._dfa_cache: "OrderedDict[tuple, tuple]" = OrderedDict()  # mcpx: owner[engine-worker]
         # Heterogeneous batching (EngineConfig.hetero_batch): the stacked-DFA
         # slot table. ``_dfa_slots[k]`` is the grammar whose padded tables
@@ -908,7 +904,6 @@ class InferenceEngine:
             self._params = None  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
             self._paged_kv = None  # mcpx: ignore[thread-ownership] - worker joined (guard above); teardown
             self._jit_prefill = None
-            self._seq_mesh = None
             self._jit_admit = None
             self._jit_segment = None
             self._jit_suffix_prefill = None
@@ -1308,26 +1303,6 @@ class InferenceEngine:
             },
         }
         self._paged_kv = self._init_pools()
-        # Long-prompt routing (ring prefill): the serving mesh's data
-        # devices double as a seq axis — same device order, so the ring's
-        # ppermute hops ride the neighbouring ICI links the data axis
-        # already occupies. A caller-injected mesh that already carries a
-        # real seq axis is used as-is. Armed only when routing can trigger.
-        self._seq_mesh = None
-        if ecfg.ring_prefill_min_tokens > 0:
-            from mcpx.parallel.mesh import DATA_AXIS, SEQ_AXIS
-
-            n_data = self._mesh.shape.get(DATA_AXIS, 1)
-            n_seq = self._mesh.shape.get(SEQ_AXIS, 1)
-            if n_seq > 1:
-                self._seq_mesh = self._mesh
-            elif n_data > 1:
-                self._seq_mesh = make_mesh(
-                    data=1,
-                    seq=n_data,
-                    model=self._mesh.shape.get("model", 1),
-                    devices=list(self._mesh.devices.flatten()),
-                )
         # Every jitted executable goes through the cost registry
         # (telemetry/costs.py): one AOT compile per signature harvests
         # XLA's cost_analysis() and increments the
@@ -1339,10 +1314,10 @@ class InferenceEngine:
             "prefill",
             jax.jit(
                 self._prefill_impl,
-                static_argnames=("T", "ring"),
+                static_argnames=("T",),
                 donate_argnames=("paged_k", "paged_v"),
             ),
-            static_argnames=("T", "ring"),
+            static_argnames=("T",),
         )
         self._jit_admit = wrap(
             "admit",
@@ -1667,9 +1642,6 @@ class InferenceEngine:
         # Null page table: scatters land on reserved page 0, which
         # no live sequence ever reads.
         table = np.zeros((A, ecfg.max_pages_per_seq), np.int32)
-        # Compile the executable serving will dispatch for this
-        # bucket: ring buckets warm the ring route, not a dense
-        # executable serving would never run.
         last, k_p, v_p, _ = self._jit_prefill(
             self._params,
             self._put(tokens, self._row_spec(A, 1)),
@@ -1678,7 +1650,6 @@ class InferenceEngine:
             self._paged_kv["v"],
             self._put(table, self._row_spec(A, 1)),
             T=T,
-            ring=self._ring_ok(T),
         )
         self._paged_kv = {"k": k_p, "v": v_p}
         if ecfg.prefix_cache:
@@ -2385,34 +2356,18 @@ class InferenceEngine:
         cur0 = jnp.where(done0, tok.pad_id, first)
         return cur0, state0, done0
 
-    def _prefill_impl(
-        self, params, tokens, seq_lens, paged_k, paged_v, page_table, *, T, ring=False
-    ):
+    def _prefill_impl(self, params, tokens, seq_lens, paged_k, paged_v, page_table, *, T):
         cfg = self.model_cfg
         B = tokens.shape[0]
         dense = init_kv_cache(cfg, B, T)
         # last_only: the [B, T, V] logits buffer must never exist — at
         # subword vocab sizes it is hundreds of MB per cohort and its
         # unembed matmul rivals the whole layer stack.
-        if ring:
-            # Long-prompt route (static flag -> its own executable per T):
-            # the dense causal pass swapped for sequence-parallel ring
-            # attention (parallel/ring_attention.py) — T shards over the
-            # seq mesh (the data devices re-viewed), K/V blocks rotate by
-            # ppermute, softmax accumulates online; no [B, T, S] mask or
-            # score matrix ever exists. Same contract either way.
-            from mcpx.parallel.ring_attention import ring_prefill
-
-            last, dense = ring_prefill(
-                params, cfg, tokens, seq_lens, self._seq_mesh, dense, last_only=True
-            )
-            moe = None
-        else:
-            # A sparse model's expert counters (moe_stats_init); None from a
-            # dense one, which adds no output to its executable.
-            last, dense, moe = prefill(
-                params, cfg, tokens, seq_lens, dense, last_only=True, moe_stats=True
-            )
+        # A sparse model's expert counters (moe_stats_init); None from a
+        # dense one, which adds no output to its executable.
+        last, dense, moe = prefill(
+            params, cfg, tokens, seq_lens, dense, last_only=True, moe_stats=True
+        )
         paged = commit_prefill_to_pages(
             {"k": paged_k, "v": paged_v},
             dense,
@@ -2421,18 +2376,6 @@ class InferenceEngine:
             self.config.engine.kv_page_size,
         )
         return last, paged["k"], paged["v"], moe
-
-    def _ring_ok(self, T: int) -> bool:
-        """True when a ``T``-token full prefill should take the ring route:
-        threshold met, a real seq mesh exists, and the bucket divides the
-        seq axis. Pure predicate — metric increments stay at serving call
-        sites so warmup compiles don't pollute the counter."""
-        ecfg = self.config.engine
-        if self._seq_mesh is None or T < ecfg.ring_prefill_min_tokens:
-            return False
-        from mcpx.parallel.mesh import SEQ_AXIS
-
-        return T % self._seq_mesh.shape[SEQ_AXIS] == 0
 
     def _suffix_prefill_impl(
         self, params, tokens, seq_lens, positions, page_table, paged_k, paged_v
@@ -2841,12 +2784,6 @@ class InferenceEngine:
                 # "engaged but never ran" (pallas_paths).
                 self._pallas_dispatches["prefill"] += 1
             else:
-                # Long shared prefixes are the prime ring workload — route
-                # them like any full prefill (B=1 rides the seq mesh's
-                # size-1 data axis replicated).
-                use_ring = self._ring_ok(T)
-                if use_ring:
-                    self.metrics.ring_prefills.inc()
                 last, k_p, v_p, _ = self._jit_prefill(
                     self._params,
                     self._put(tokens, self._row_spec(1, 1)),
@@ -2855,7 +2792,6 @@ class InferenceEngine:
                     self._paged_kv["v"],
                     self._put(table, self._row_spec(1, 1)),
                     T=T,
-                    ring=use_ring,
                 )
             self._paged_kv = {"k": k_p, "v": v_p}
             del last
@@ -4612,9 +4548,6 @@ class InferenceEngine:
                     (cons_np, rs),
                     (dfa_np, rs),
                 )
-                use_ring = self._ring_ok(T)
-                if use_ring:
-                    self.metrics.ring_prefills.inc()
                 last_logits, k_p, v_p, moe_d = self._jit_prefill(
                     self._params,
                     tokens_d,
@@ -4623,7 +4556,6 @@ class InferenceEngine:
                     self._paged_kv["v"],
                     table_d,
                     T=T,
-                    ring=use_ring,
                 )
                 pf_entry = getattr(self._jit_prefill, "last_entry", None)
                 pf_name = "prefill"

@@ -62,49 +62,28 @@ NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------- reference
-def paged_attention_reference(
-    q: jax.Array,  # [B, K, G, hd]
-    k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers
-    v_pages: jax.Array,
-    page_table: jax.Array,  # [B, Pmax] int32
-    seq_lens: jax.Array,  # [B] int32 (tokens valid in cache, incl. current)
-    layer: jax.Array | int = 0,
-) -> jax.Array:
-    """Pure-jnp semantics reference; returns [B, K, G, hd] in q.dtype."""
-    B, K, G, hd = q.shape
-    _, _, _, psz, _ = k_pages.shape
-    p_max = page_table.shape[1]
-    # Gather pages: [B, K, Pmax*Psz, hd]
-    k = k_pages[:, layer][:, page_table].transpose(1, 0, 2, 3, 4).reshape(B, K, p_max * psz, hd)
-    v = v_pages[:, layer][:, page_table].transpose(1, 0, 2, 3, 4).reshape(B, K, p_max * psz, hd)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    logits = jnp.einsum("bkgh,bksh->bkgs", q, k, preferred_element_type=jnp.float32)
-    logits = logits * scale
-    pos = jnp.arange(p_max * psz)
-    mask = pos[None, :] < seq_lens[:, None]  # [B, S]
-    logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
-    weights = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bksh->bkgh", weights.astype(v.dtype), v)
-    return out.astype(q.dtype)
-
-
-def paged_attention_chunk_reference(
-    q: jax.Array,  # [B, S, K, G, hd] — S new queries per sequence
+def ragged_paged_attention_reference(
+    q: jax.Array,  # [B, S, K, G, hd] — padded query windows
     k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, Pmax] int32
     start_pos: jax.Array,  # [B] int32 — cache position of query 0
+    q_lens: jax.Array,  # [B] int32 — live queries per row (0 = idle row)
     layer: jax.Array | int = 0,
     window: "jax.Array | int | None" = None,
 ) -> jax.Array:
-    """Chunked decode attention, pure jnp: query i of sequence b attends
-    through cache position ``start_pos[b]+i`` (itself + earlier chunk
-    tokens, already written to the pools), and under a ``window`` no
-    further back than the ``window - 1`` positions before itself. Gathers
-    each sequence's pages ONCE for all S queries — folding the chunk into
-    the batch dim instead would re-gather the same pages S times, which at
-    chunk width 8 is 8x the HBM traffic of this formulation (the dominant
-    cost of jnp-path decode). Returns [B, S, K, G, hd] in q.dtype."""
+    """Ragged mixed-phase semantics, pure jnp: row ``b``'s queries at
+    window index ``i < q_lens[b]`` attend through cache position
+    ``start_pos[b] + i`` (itself + earlier window tokens, already written to
+    the pools), and under a ``window`` no further back than the
+    ``window - 1`` positions before itself; queries at ``i >= q_lens[b]``
+    are pads and output exactly ZERO — the kernel's idle-row/pad contract,
+    pinned here so the interpret-parity tests cover pads too, not just the
+    positions the callers happen to read. Gathers each row's pages ONCE for
+    all S queries — folding the window into the batch dim instead would
+    re-gather the same pages S times, which at window width 8 is 8x the HBM
+    traffic of this formulation (the dominant cost of jnp-path decode).
+    Returns [B, S, K, G, hd] in q.dtype."""
     B, S, K, G, hd = q.shape
     _, _, _, psz, _ = k_pages.shape
     p_max = page_table.shape[1]
@@ -121,29 +100,7 @@ def paged_attention_chunk_reference(
     logits = jnp.where(mask[:, :, None, None, :], logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bskgl,bklh->bskgh", weights.astype(v.dtype), v)
-    return out.astype(q.dtype)
-
-
-def ragged_paged_attention_reference(
-    q: jax.Array,  # [B, S, K, G, hd] — padded query windows
-    k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers
-    v_pages: jax.Array,
-    page_table: jax.Array,  # [B, Pmax] int32
-    start_pos: jax.Array,  # [B] int32 — cache position of query 0
-    q_lens: jax.Array,  # [B] int32 — live queries per row (0 = idle row)
-    layer: jax.Array | int = 0,
-    window: "jax.Array | int | None" = None,
-) -> jax.Array:
-    """Ragged mixed-phase semantics, pure jnp: row ``b``'s queries at
-    window index ``i < q_lens[b]`` attend through cache position
-    ``start_pos[b] + i`` (the chunk contract); queries at ``i >= q_lens[b]``
-    are pads and output exactly ZERO — the kernel's idle-row/pad contract,
-    pinned here so the interpret-parity tests cover pads too, not just the
-    positions the callers happen to read. Returns [B, S, K, G, hd]."""
-    out = paged_attention_chunk_reference(
-        q, k_pages, v_pages, page_table, start_pos, layer, window
-    )
-    valid = jnp.arange(q.shape[1])[None, :] < q_lens[:, None]  # [B, S]
+    valid = jnp.arange(S)[None, :] < q_lens[:, None]  # [B, S]
     return jnp.where(valid[:, :, None, None, None], out, 0).astype(q.dtype)
 
 
@@ -473,54 +430,6 @@ def ragged_paged_attention(
     return out[:, :S]
 
 
-def paged_attention_chunk(
-    q: jax.Array,  # [B, S, K, G, hd]
-    k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers
-    v_pages: jax.Array,
-    page_table: jax.Array,  # [B, Pmax]
-    start_pos: jax.Array,  # [B] — cache position of query 0
-    layer: jax.Array | int = 0,
-    window: "jax.Array | int | None" = None,
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    """Dense-window chunk attention: the ``q_lens = S`` specialisation of
-    ``ragged_paged_attention`` (every window position live — the pre-ragged
-    contract, kept for callers whose pads are never read)."""
-    B, S = q.shape[0], q.shape[1]
-    return ragged_paged_attention(
-        q,
-        k_pages,
-        v_pages,
-        page_table,
-        start_pos,
-        jnp.full((B,), S, jnp.int32),
-        layer,
-        window,
-        interpret=interpret,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(
-    q: jax.Array,  # [B, K, G, hd]
-    k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers
-    v_pages: jax.Array,
-    page_table: jax.Array,  # [B, Pmax]
-    seq_lens: jax.Array,  # [B]
-    layer: jax.Array | int = 0,
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    """Single-query paged attention: the S=1 case of ``paged_attention_chunk``
-    (ONE streaming-softmax kernel to maintain; ``seq_lens`` counts the
-    just-written token, so the chunk's start position is ``seq_lens-1``)."""
-    out = paged_attention_chunk(
-        q[:, None], k_pages, v_pages, page_table, seq_lens - 1, layer, interpret=interpret
-    )
-    return out[:, 0]
-
-
 # ------------------------------------------------- latent (absorbed) kernel
 # Rows of the score tile (queries x heads) a latent program holds: its three
 # float32 carries are rows x (latent width + 2 lane columns), 1.5 MB at 512
@@ -548,7 +457,7 @@ def latent_paged_attention_reference(
     """The absorbed form, pure jnp: one shared "KV head" whose key is the
     latent beside the rotated key and whose value is the latent again.
     ``score = (q_latent . c + q_rope . k_rope) * scale`` over the keys a
-    query sees (the chunk contract of ``ragged_paged_attention_reference``;
+    query sees (the window contract of ``ragged_paged_attention_reference``;
     under ``select`` those of them it names, ``index_select``), ``out = sum_t
     p_t c_t`` [B, S, H, r]; pad queries output zeros. The rotated key is the
     first ``w`` values of its page row."""

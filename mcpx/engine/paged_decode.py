@@ -22,8 +22,6 @@ from mcpx.engine.kernels.paged_attention import (
     index_select_reference,
     latent_paged_attention_reference,
     lightning_indexer,
-    paged_attention_chunk,
-    paged_attention_chunk_reference,
     ragged_paged_attention,
     ragged_paged_attention_latent,
     ragged_paged_attention_reference,
@@ -95,7 +93,7 @@ def _latent_attend(
     latent_pool: jax.Array,  # [1, L, N, Psz, r]: paged_kv["v"]
     page_table: jax.Array,
     positions: jax.Array,
-    q_lens: "jax.Array | None",
+    q_lens: jax.Array,  # [B] live window slots of each row
     layer: jax.Array,
     index: "tuple | None" = None,  # the window's index queries and weights
     *,
@@ -129,10 +127,9 @@ def _latent_attend(
     if pad:
         q_rope = jnp.pad(q_rope, ((0, 0), (0, 0), (0, 0), (0, pad)))
     scale = cfg.attn_score_factor / (hd + dr) ** 0.5
-    lens = jnp.full((B,), S, jnp.int32) if q_lens is None else q_lens
     selecting = index is not None and page_table.shape[1] * rope_pool.shape[3] > cfg.index_topk
     rows = None if mesh is None else _axis(mesh, DATA_AXIS, B)
-    row_specs = (P(rows, None), P(rows), P(rows), P())  # table, positions, lens, layer
+    row_specs = (P(rows, None), P(rows), P(rows), P())  # table, positions, q_lens, layer
     select = None
     if selecting:
         choose = functools.partial(
@@ -147,7 +144,7 @@ def _latent_attend(
                 out_specs=P(rows, None, None), check_vma=False,
             )
         select = choose(
-            *index, rope_pool, page_table, positions, lens, jnp.asarray(layer, jnp.int32)
+            *index, rope_pool, page_table, positions, q_lens, jnp.asarray(layer, jnp.int32)
         )
     selected = () if select is None else (select,)
     if use_pallas:
@@ -161,13 +158,13 @@ def _latent_attend(
                 out_specs=q_spec, check_vma=False,
             )
         out = kernel(
-            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens,
+            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, q_lens,
             jnp.asarray(layer, jnp.int32), *selected,
         )
     else:
         out = latent_paged_attention_reference(
-            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, lens, layer, *selected,
-            scale=scale,
+            q_latent, q_rope, rope_pool, latent_pool, page_table, positions, q_lens, layer,
+            *selected, scale=scale,
         )
     attn = jnp.einsum("bshr,rhe->bshe", out, w_uv).reshape(B, S, cfg.attn_out_width)
     return attn, select
@@ -251,8 +248,8 @@ def decode_chunk_paged(
     interpret: bool = False,
     logits_at: "jax.Array | None" = None,  # [B] chunk slot per row, or None
     active_cols: "jax.Array | None" = None,  # [C] token ids: compact unembed
-    q_lens: "jax.Array | None" = None,  # [B] live window slots (ragged rows)
-    mesh: Optional[Mesh] = None,  # engine mesh; required with q_lens + use_pallas
+    q_lens: jax.Array,  # [B] live window slots of each row (0: an idle row)
+    mesh: Optional[Mesh] = None,  # engine mesh; required with use_pallas
     moe_stats: bool = False,  # sparse models: also the forward's expert counters
     routing: bool = False,  # sparse models: also the experts chosen [Ls, B, S, k]
     selection: bool = False,  # a learned index: also the keys each query read [L, B, S, keys / 8]
@@ -278,21 +275,20 @@ def decode_chunk_paged(
     widths the attention (kernel AND jnp reference, in lockstep) streams
     only each row's own pages and zeroes pad-query outputs — suffix
     prefill, plain decode and spec-verify rows share one executable whose
-    compile key is the padded window shape alone. None keeps the dense
-    pre-ragged contract (every slot computed, pads garbage-but-unread);
-    either way the logits callers read are bit-identical, because a pad
-    slot's cache position lies strictly past every live query's visible
-    range at every layer. Returns ([B, S, V] logits, pools) — or
+    compile key is the padded window shape alone. A live slot's logits do
+    not depend on what its row's pad slots hold: a pad slot's cache position
+    lies strictly past every live query's visible range at every layer.
+    Returns ([B, S, V] logits, pools) — or
     ([B, V], pools) when ``logits_at`` names the single chunk slot per
     row to unembed.
     """
     B, S = tokens.shape
     _, _, N, psz, _ = paged_kv["k"].shape
-    if use_pallas and q_lens is not None and mesh is None:
+    if use_pallas and mesh is None:
         # The ragged kernel only runs under shard_map (a one-device mesh is
         # its trivial case): a bare Mosaic call cannot lower on >1 chip, and
         # the CPU interpreter would not show that.
-        raise ValueError("decode_chunk_paged: the ragged kernel route (q_lens) needs mesh=")
+        raise ValueError("decode_chunk_paged: the kernel route (use_pallas) needs mesh=")
     from mcpx.models.gemma.quant import dequant_layer
 
     # Weight-only int8 serving mode (models/gemma/quant.py): identity
@@ -307,7 +303,7 @@ def decode_chunk_paged(
     stacks, experts = layer_stacks(cfg, params)
     # A sparse feed-forward routes only the window's live slots: a pad slot
     # or an idle row chooses no expert, reads none and is counted nowhere.
-    live = None if q_lens is None else jnp.arange(S)[None, :] < q_lens[:, None]
+    live = jnp.arange(S)[None, :] < q_lens[:, None]
     # Their kernel (``kernels/routed_experts.py``) where ONE device holds the
     # window's rows beside the stacks; a mesh of several keeps the jnp loop,
     # which XLA partitions (no cell runs a sparse model across chips).
@@ -331,23 +327,13 @@ def decode_chunk_paged(
         # has none).
         qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
         if use_pallas:
-            if q_lens is not None:
-                out = _ragged_kernel_on_mesh(
-                    mesh, qg, k_all, v_all, page_table, positions, q_lens, layer, window,
-                    interpret=interpret,
-                )
-            else:
-                out = paged_attention_chunk(
-                    qg, k_all, v_all, page_table, positions, layer, window,
-                    interpret=interpret,
-                )
-        elif q_lens is not None:
-            out = ragged_paged_attention_reference(
-                qg, k_all, v_all, page_table, positions, q_lens, layer, window
+            out = _ragged_kernel_on_mesh(
+                mesh, qg, k_all, v_all, page_table, positions, q_lens, layer, window,
+                interpret=interpret,
             )
         else:
-            out = paged_attention_chunk_reference(
-                qg, k_all, v_all, page_table, positions, layer, window
+            out = ragged_paged_attention_reference(
+                qg, k_all, v_all, page_table, positions, q_lens, layer, window
             )
         return out.reshape(B, S, cfg.n_heads * cfg.head_dim), None
 
@@ -383,8 +369,7 @@ def decode_chunk_paged(
     if stats is not None:
         # What this forward's attention calls read, by row: a live row's
         # context runs through its last live query (``_ragged_n_pages``).
-        lens = jnp.full((B,), S, jnp.int32) if q_lens is None else q_lens
-        stats = add_forward_stats(cfg, stats, positions + lens, lens, S)
+        stats = add_forward_stats(cfg, stats, positions + q_lens, q_lens, S)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     pools = {"k": k_new, "v": v_new}
     # What a sparse model's callers may ask for beside the logits: the
@@ -410,33 +395,3 @@ def decode_chunk_paged(
         # buffer and those FLOPs rival a whole transformer layer.
         x = x[jnp.arange(B), logits_at]  # [B, D]
     return (output_logits(params, cfg, x), pools) + extra
-
-
-def decode_step_paged(
-    params: dict[str, Any],
-    cfg: GemmaConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32 — slot this token is written to
-    page_table: jax.Array,  # [B, Pmax] int32
-    paged_kv: dict[str, jax.Array],  # k/v: [K, L, N, Psz, hd]
-    *,
-    use_pallas: bool = True,
-    interpret: bool = False,
-) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """One decode step for the whole batch; returns ([B, V] logits, pools).
-
-    The S=1 specialisation of ``decode_chunk_paged`` — a single forward body
-    to maintain (their equivalence is pinned by
-    ``test_decode_chunk_matches_sequential_steps``).
-    """
-    logits, pools = decode_chunk_paged(
-        params,
-        cfg,
-        tokens[:, None],
-        positions,
-        page_table,
-        paged_kv,
-        use_pallas=use_pallas,
-        interpret=interpret,
-    )
-    return logits[:, 0], pools

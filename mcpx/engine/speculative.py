@@ -74,14 +74,6 @@ from mcpx.models.gemma.quant import embed_lookup, unembed
 DRAFT_DECAY = 0.5
 
 
-def drafter_flops_per_token(d_model: int, vocab_size: int) -> float:
-    """Analytic FLOPs attributed to one recurrent-drafter proposal: the
-    per-step ``h @ embed.T`` scoring matmul (2·D·V), so that a speculated
-    run's accounting can bill the drafter's compute alongside the model's
-    own 2·params·tokens. No caller since PR 30 (ROADMAP, Design debts)."""
-    return 2.0 * d_model * vocab_size
-
-
 def advance_drafter_state(hstate, embed, window, n_absorb):
     """Advance the recurrent drafter state over the first ``n_absorb``
     tokens of ``window`` ([B, W] — current token + accepted drafts) in

@@ -292,7 +292,7 @@ class GemmaConfig:
     @property
     def is_default_block(self) -> bool:
         """True for the block every default describes: what the int8 path,
-        the drafter, ring prefill and the trainer were written against."""
+        the drafter and the trainer were written against."""
         d = GemmaConfig()
         return all(
             getattr(self, f) == getattr(d, f)

@@ -573,7 +573,6 @@ def _layer(
     mask: jax.Array,
     write_idx: jax.Array,
     cfg: GemmaConfig,
-    attend_fn=None,
     kind: "dict[str, jax.Array] | None" = None,
     moe: "tuple | None" = None,
 ) -> tuple:
@@ -607,7 +606,7 @@ def _layer(
         attn = _attend_query_blocks(q[:, :, :, None, :], keys, values, mask, cfg.attn_score_factor)
     else:
         qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
-        attn = (attend_fn or _attend)(qg, k_cache, v_cache, mask)
+        attn = _attend(qg, k_cache, v_cache, mask)
     x = attention_residual(x, h, attn.reshape(B, T, cfg.attn_out_width), lp, cfg)
     x, stats, chosen = feed_forward_residual(x, lp, cfg, moe)
     return x, k_cache, v_cache, stats, chosen
@@ -643,7 +642,6 @@ def forward(
     positions: jax.Array,
     kv_cache: KVCache,
     mask: jax.Array,
-    attend_fn=None,
     logits_at: "jax.Array | None" = None,
     live: "jax.Array | None" = None,
     routing: bool = False,
@@ -651,8 +649,7 @@ def forward(
 ) -> tuple:
     """Core forward over a [B, T] token chunk against a [L, B, S, K, hd]
     cache. ``positions`` are absolute (double as cache write slots);
-    ``mask`` is [B, T, S] (True = attend). ``attend_fn`` swaps the attention
-    op (e.g. ring attention for sequence-parallel long-context prefill).
+    ``mask`` is [B, T, S] (True = attend).
     ``logits_at`` [B]: unembed only that position per row -> [B, V].
     ``live`` [B, T]: the slots that are tokens and not padding; a sparse
     feed-forward routes the others nowhere. ``routing``: also return the
@@ -678,7 +675,7 @@ def forward(
         x, layer, stats = carry if cfg.n_experts else (carry, None, None)
         moe = (experts, sparse_index(cfg, layer), live) if cfg.n_experts else None
         x, k_c, v_c, layer_stats, chosen = _layer(
-            x, lp, k_c, v_c, positions, mask, positions, cfg, attend_fn, kind, moe
+            x, lp, k_c, v_c, positions, mask, positions, cfg, kind, moe
         )
         if layer_stats is not None:
             stats = add_layer_stats(stats, layer_stats)

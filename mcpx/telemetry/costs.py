@@ -45,9 +45,7 @@ keyed by a stable argument signature:
     jitted callable unchanged: a true pass-through, matching the repo's
     config-gated-subsystem convention.
 
-Roofline helpers (:func:`device_peaks`, :func:`roofline`) turn executed
-flops/bytes + wall time into achieved FLOP/s, achieved bytes/s, arithmetic
-intensity and a roofline position against the chip's datasheet peaks;
+:func:`device_peaks` gives the chip's datasheet peaks;
 :func:`hbm_stats`/:func:`update_hbm_gauges` expose per-device
 ``memory_stats()`` as HBM-pressure gauges. Consumer: the ``GET /costs``
 endpoint (docs/observability.md §Roofline & cost accounting), whose
@@ -71,7 +69,6 @@ __all__ = [
     "TrackedExecutable",
     "device_peaks",
     "hbm_stats",
-    "roofline",
     "update_hbm_gauges",
 ]
 
@@ -181,41 +178,6 @@ def update_hbm_gauges(metrics: Any) -> None:
             metrics.hbm_bytes_in_use.labels(device=dev).set(row["bytes_in_use"])
         if row.get("bytes_limit") is not None:
             metrics.hbm_bytes_limit.labels(device=dev).set(row["bytes_limit"])
-
-
-def roofline(
-    flops: Optional[float],
-    bytes_accessed: Optional[float],
-    wall_s: float,
-    *,
-    peak_flops: Optional[float] = None,
-    peak_bytes_s: Optional[float] = None,
-) -> dict:
-    """Achieved rates + roofline position for ``flops``/``bytes_accessed``
-    of work done in ``wall_s`` seconds. Keys are only present when their
-    inputs are: no peak -> no ``mfu``/``bound`` (never a made-up one). Fed
-    XLA's ``cost_analysis()`` and a wall the caller read on the host, its
-    ``mfu`` is an estimate over a host clock, not a device measurement: a
-    helper for whoever differences ``GET /costs`` totals around a phase;
-    no program path calls it."""
-    out: dict[str, Any] = {}
-    if wall_s <= 0:
-        return out
-    if flops:
-        out["achieved_flops_s"] = flops / wall_s
-        if peak_flops:
-            out["mfu"] = flops / wall_s / peak_flops
-    if bytes_accessed:
-        out["achieved_bytes_s"] = bytes_accessed / wall_s
-        if peak_bytes_s:
-            out["hbm_bw_util"] = bytes_accessed / wall_s / peak_bytes_s
-    if flops and bytes_accessed:
-        out["arithmetic_intensity"] = flops / bytes_accessed
-        if peak_flops and peak_bytes_s:
-            ridge = peak_flops / peak_bytes_s
-            out["ridge_ai"] = ridge
-            out["bound"] = "memory" if out["arithmetic_intensity"] < ridge else "compute"
-    return out
 
 
 # --------------------------------------------------------------- signatures

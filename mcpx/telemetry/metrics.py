@@ -203,11 +203,6 @@ class Metrics:
             ["state"],
             registry=self.registry,
         )
-        self.ring_prefills = Counter(
-            "mcpx_engine_ring_prefills_total",
-            "Full prefills routed through sequence-parallel ring attention",
-            registry=self.registry,
-        )
         # Radix-tree prefix KV cache (mcpx/engine/prefix_cache.py,
         # docs/engine.md "Prefix KV reuse"): cross-request prompt-head
         # sharing over the paged pool.

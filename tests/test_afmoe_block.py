@@ -134,7 +134,6 @@ def test_a_full_layer_is_not_rotated_and_a_window_layer_is():
 @pytest.mark.parametrize("feature, cfg_json", [
     ("quantize", {"model": {"quantize": "int8"}}),
     ("speculative", {"engine": {"speculative": {"enabled": True}, "hetero_batch": True}}),
-    ("ring_prefill", {"engine": {"ring_prefill_min_tokens": 512}}),
 ])
 def test_what_the_block_does_not_do_yet_is_an_error_at_construction(feature, cfg_json):
     from mcpx.engine.engine import InferenceEngine

@@ -1,6 +1,6 @@
 """Roofline cost observatory (mcpx/telemetry/costs.py): per-executable XLA
-cost accounting, the mcpx_engine_compiles_total retrace sentinel, roofline
-math, span wiring, spec-rate gauges, and the GET /costs surface."""
+cost accounting, the mcpx_engine_compiles_total retrace sentinel, span
+wiring, spec-rate gauges, and the GET /costs surface."""
 
 import asyncio
 from types import SimpleNamespace
@@ -8,7 +8,7 @@ from types import SimpleNamespace
 import numpy as np
 
 from mcpx.core.config import MCPXConfig
-from mcpx.telemetry.costs import CostRegistry, hbm_stats, roofline
+from mcpx.telemetry.costs import CostRegistry, hbm_stats
 from mcpx.telemetry.metrics import Metrics
 
 
@@ -152,26 +152,6 @@ def test_release_drops_executables_keeps_history():
     # Still callable post-release (falls back to the jit path).
     out = f(jnp.ones((4,)))
     assert float(out[0]) == 3.0
-
-
-# ------------------------------------------------------------ roofline math
-def test_roofline_math_and_labeled_absences():
-    rl = roofline(100.0, 10.0, 2.0, peak_flops=1000.0, peak_bytes_s=10.0)
-    assert rl["achieved_flops_s"] == 50.0
-    assert rl["achieved_bytes_s"] == 5.0
-    assert rl["arithmetic_intensity"] == 10.0
-    assert rl["mfu"] == 0.05
-    assert rl["hbm_bw_util"] == 0.5
-    assert rl["ridge_ai"] == 100.0
-    assert rl["bound"] == "memory"  # AI 10 < ridge 100
-    # Compute-bound side of the ridge.
-    assert roofline(1e6, 10.0, 1.0, peak_flops=1e6, peak_bytes_s=1e3)["bound"] == "compute"
-    # No peaks -> achieved rates + AI only, never a made-up mfu/bound.
-    bare = roofline(100.0, 10.0, 2.0)
-    assert "mfu" not in bare and "bound" not in bare
-    assert bare["achieved_flops_s"] == 50.0
-    # No wall -> nothing.
-    assert roofline(100.0, 10.0, 0.0) == {}
 
 
 def test_hbm_stats_labeled_unavailable_on_cpu():

@@ -11,11 +11,16 @@
     N × per-request forwards) on 1x1, 2x4 and 8x1 meshes alike — DP adds
     capacity without multiplying model forwards.
 
+  - a long prompt's full prefill on a data=4 mesh serves the one-device
+    greedy tokens: the dense route is the one full-prefill route, whatever
+    the prompt's length.
+
 Wall-clock is deliberately NOT asserted (virtual CPU devices share host
 cores; only accounting and sharding structure are stable evidence there).
 """
 
 import asyncio
+import dataclasses
 import re
 from collections import Counter
 
@@ -162,3 +167,48 @@ def test_cohort_accounting_is_mesh_invariant(mesh_shape):
             await eng.aclose()
 
     asyncio.run(go())
+
+
+def test_a_long_prompt_on_a_data_mesh_serves_the_one_device_greedy_tokens():
+    """A ~300-token prompt (the 512 prefill bucket) and a short one, greedy,
+    on a data=4 x model=2 mesh and on one device: the same tokens, through
+    the one dense full-prefill executable. float32 end to end, so that the
+    sharded sums cannot wobble the argmax."""
+    model = dataclasses.replace(MODEL, dtype="float32", max_seq_len=512)
+    cfg = MCPXConfig.from_dict(
+        {
+            "model": {"size": "test", "max_seq_len": 512},
+            "engine": {
+                "use_pallas": False,
+                "max_batch_size": 2,
+                "max_decode_len": 48,
+                "kv_page_size": 16,
+                "max_pages_per_seq": 32,
+                "temperature": 0.0,
+                "prefix_cache": False,  # every prompt a full prefill
+            },
+        }
+    )
+    long_prompt = (
+        "Compose a service DAG over the following services. "
+        + " ".join(f"svc-{i:03d} in:query out:result" for i in range(18))
+        + " Intent: fetch then summarize. JSON:"
+    )
+
+    async def serve(mesh):
+        eng = InferenceEngine(cfg, model_cfg=model, mesh=mesh)
+        await eng.start()
+        try:
+            ids = eng.tokenizer.encode(long_prompt)
+            assert len(ids) >= 256
+            long = await eng.generate(ids, max_new_tokens=40)
+            short = await eng.generate(eng.tokenizer.encode("plan. JSON:"), max_new_tokens=24)
+            compiled = eng.costs.snapshot(materialize=False)["executables"]
+            assert compiled["prefill"]["compiles"] == 2  # one a bucket, no other route
+            return long.token_ids, short.token_ids
+        finally:
+            await eng.aclose()
+
+    one = asyncio.run(serve(make_mesh(data=1, model=1, devices=jax.devices()[:1])))
+    assert all(one)
+    assert asyncio.run(serve(make_mesh(data=4, model=2))) == one

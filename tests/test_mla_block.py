@@ -151,7 +151,6 @@ def test_a_configuration_that_cannot_be_is_refused(bad):
 @pytest.mark.parametrize("feature, cfg_json", [
     ("quantize", {"model": {"quantize": "int8"}}),
     ("speculative", {"engine": {"speculative": {"enabled": True}, "hetero_batch": True}}),
-    ("ring_prefill", {"engine": {"ring_prefill_min_tokens": 512}}),
 ])
 def test_what_the_block_does_not_do_yet_is_an_error_at_construction(feature, cfg_json):
     from mcpx.engine.engine import InferenceEngine

@@ -115,7 +115,6 @@ def test_params_read_a_token_are_not_all_params_held():
 @pytest.mark.parametrize("feature, cfg_json", [
     ("quantize", {"model": {"quantize": "int8"}}),
     ("speculative", {"engine": {"speculative": {"enabled": True}, "hetero_batch": True}}),
-    ("ring_prefill", {"engine": {"ring_prefill_min_tokens": 512}}),
 ])
 def test_what_the_block_does_not_do_yet_is_an_error_at_construction(feature, cfg_json):
     from mcpx.engine.engine import InferenceEngine

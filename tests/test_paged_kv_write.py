@@ -14,6 +14,8 @@ Three guards:
 
 Beside them, compiled the same way: at 128 heads the layer loop writes no
 copy of a layer's ``wo`` before the projection reads it (PERF.md, PR 46).
+And the pool's other two writers: the prefill's commit and the allocator
+that says which pages a row owns.
 """
 
 import re
@@ -23,8 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mcpx.core.errors import EngineError
 from mcpx.engine import paged_decode
-from mcpx.engine.kv_cache import init_paged_kv
+from mcpx.engine.kv_cache import PageAllocator, commit_prefill_to_pages, init_paged_kv
 from mcpx.engine.paged_decode import _kv_window, _write_kv_window, decode_chunk_paged
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import init_params
@@ -77,9 +80,9 @@ def _window_case(name):
     each with ``_PMAX`` private pages unless the edit says otherwise."""
     B = 4
     table = np.arange(1, B * _PMAX + 1, dtype=np.int32).reshape(B, _PMAX)
-    q_lens = None
     if name == "S1":
         S, pos = 1, [0, 15, 16, 37]
+        q_lens = [1, 1, 1, 1]
     elif name == "S8-straddle":
         # windows that end on, start on and cross a page edge
         S, pos = 8, [8, 16, 12, 30]
@@ -135,12 +138,10 @@ def test_forward_leaves_the_pools_as_the_flat_scatter_did(window, layout, route,
     }
     tokens = jax.random.randint(jax.random.PRNGKey(7), (B, S), 0, cfg.vocab_size)
     positions, page_table = jnp.asarray(pos), jnp.asarray(table)
-    kw = dict(use_pallas=route != "jnp", interpret=True)
-    if q_lens is not None:
-        kw.update(
-            q_lens=jnp.asarray(q_lens, jnp.int32),
-            mesh=make_mesh(data=1, model=1, devices=jax.devices()[:1]),
-        )
+    kw = dict(
+        use_pallas=route != "jnp", interpret=True, q_lens=jnp.asarray(q_lens, jnp.int32),
+        mesh=make_mesh(data=1, model=1, devices=jax.devices()[:1]),
+    )
 
     def forward():
         return jax.jit(
@@ -200,6 +201,84 @@ def test_window_pages_are_static_in_the_window_width():
         assert int(live.sum()) == 2 * S  # the table's end is the pages' business, not the mask's
         assert int(pages[0, 0]) == 1 and int(pages[1, 0]) == 8
         assert all(int(p) == 99 for p in pages[1, 1:])  # past the table: dropped
+
+
+# ------------------------------------- commit, then write; who owns a page
+def test_commit_and_decode_write_roundtrip():
+    cfg = GemmaConfig(dtype="float32", n_layers=2, n_kv_heads=2, head_dim=16)
+    psz, n_pages, B, T = 4, 16, 2, 8
+    paged = init_paged_kv(cfg, n_pages, psz)
+    dense = {
+        "k": jax.random.normal(jax.random.PRNGKey(1), (2, B, T, 2, 16)),
+        "v": jax.random.normal(jax.random.PRNGKey(2), (2, B, T, 2, 16)),
+    }
+    table = jnp.array([[1, 2, 0, 0], [3, 4, 0, 0]], jnp.int32)
+    seq_lens = jnp.array([T, 5])
+    paged = commit_prefill_to_pages(paged, dense, table, seq_lens, psz)
+    # Page 1 holds seq0 chunk0, page 2 chunk1.
+    np.testing.assert_allclose(
+        np.asarray(paged["k"][:, 0, 1]),  # [K, psz, hd]
+        np.asarray(dense["k"][0, 0, :psz].transpose(1, 0, 2)),
+    )
+    np.testing.assert_allclose(
+        np.asarray(paged["k"][:, 1, 4]),
+        np.asarray(dense["k"][1, 1, psz:].transpose(1, 0, 2)),
+    )
+    # Decode write at position 5 for seq1 -> page 4 slot 1.
+    k_new = jax.random.normal(jax.random.PRNGKey(3), (2, B, 2, 16))
+    v_new = jax.random.normal(jax.random.PRNGKey(4), (2, B, 2, 16))
+    window = _kv_window(jnp.array([8, 5]), table, 1, psz, n_pages)
+    for layer in range(2):
+        paged = {
+            "k": _write_kv_window(paged["k"], layer, k_new[layer][:, None], window),
+            "v": _write_kv_window(paged["v"], layer, v_new[layer][:, None], window),
+        }
+    np.testing.assert_allclose(
+        np.asarray(paged["k"][:, 0, 4, 1]), np.asarray(k_new[0, 1])
+    )
+    np.testing.assert_allclose(
+        np.asarray(paged["v"][:, 1, 4, 1]), np.asarray(v_new[1, 1])
+    )
+    # seq1's prefill rows in the same page are still there
+    np.testing.assert_allclose(
+        np.asarray(paged["k"][:, 1, 4, 0]), np.asarray(dense["k"][1, 1, psz])
+    )
+
+
+def test_allocator_invariants():
+    a = PageAllocator(n_pages=32, page_size=8, max_pages_per_seq=8)
+    p1 = a.allocate(1, 20)  # 3 pages
+    assert len(p1) == 3
+    p2 = a.allocate(2, 1)
+    assert len(p2) == 1
+    a.check_invariants()
+    grown = a.extend(1, 40)  # 5 pages
+    assert len(grown) == 5
+    a.check_invariants()
+    a.free(1)
+    a.free(1)  # double-free is a no-op
+    a.check_invariants()
+    stats = a.stats()
+    assert stats.sequences == 1
+    assert stats.free_pages == 31 - 1  # only seq 2's single page held
+    with pytest.raises(EngineError, match="already has pages"):
+        a.allocate(2, 4)
+
+
+def test_allocator_exhaustion():
+    a = PageAllocator(n_pages=4, page_size=8, max_pages_per_seq=8)
+    a.allocate(1, 24)  # 3 pages = all available
+    assert not a.can_allocate(1)
+    with pytest.raises(EngineError, match="out of KV pages"):
+        a.allocate(2, 1)
+    a.free(1)
+    assert a.can_allocate(24)
+
+
+def test_allocator_respects_max_pages_per_seq():
+    a = PageAllocator(n_pages=64, page_size=8, max_pages_per_seq=2)
+    with pytest.raises(EngineError, match="max_pages_per_seq"):
+        a.allocate(1, 100)
 
 
 # --------------------------------------------------------- structure

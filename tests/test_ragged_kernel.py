@@ -68,6 +68,96 @@ def test_ragged_kernel_matches_reference_over_mixed_batches(seed):
             assert np.all(np.asarray(out[b, q_lens[b]:]) == 0.0), (layer, b)
 
 
+@pytest.mark.parametrize("S", [1, 8], ids=["single-query", "window"])
+def test_reference_matches_plain_dense_attention(S):
+    """The one jnp reference against attention written with no pages at all:
+    each row's keys and values laid out densely from the pages its table
+    names, query i attending the first ``start + i + 1`` of them."""
+    B, K, G, hd, psz, p_max = 2, 2, 3, 16, 4, 6
+    n_pages = B * p_max + 1
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (K, 2, n_pages, psz, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 2, n_pages, psz, hd), jnp.float32)
+    table = 1 + np.random.default_rng(5).permutation(n_pages - 1).reshape(B, p_max)
+    starts = [2, 9]
+    ref = ragged_paged_attention_reference(
+        q, kp, vp, jnp.asarray(table, jnp.int32), jnp.asarray(starts, jnp.int32),
+        jnp.full((B,), S, jnp.int32), 1,
+    )
+    for b in range(B):
+        k = np.asarray(kp[:, 1][:, table[b]]).reshape(K, p_max * psz, hd)
+        v = np.asarray(vp[:, 1][:, table[b]]).reshape(K, p_max * psz, hd)
+        for i in range(S):
+            n = starts[b] + i + 1
+            logits = np.einsum("kgh,ksh->kgs", np.asarray(q[b, i]), k[:, :n]) / np.sqrt(hd)
+            dense = np.einsum("kgs,ksh->kgh", np.asarray(jax.nn.softmax(logits, -1)), v[:, :n])
+            np.testing.assert_allclose(np.asarray(ref[b, i]), dense, rtol=1e-5, atol=1e-5)
+
+
+# (B, S, K, G, head_dim, page size, deepest start): windows whose every slot
+# is live, the dense contract as the ragged one's case.
+_ALL_LIVE = {
+    "single-query-mqa": (1, 1, 1, 8, 128, 16, 39),
+    "single-query-gqa-ragged-batch": (3, 1, 2, 2, 128, 16, 49),
+    "single-query-mha-odd-lengths": (2, 1, 4, 1, 256, 8, 16),
+    "window-mqa": (1, 8, 1, 8, 128, 16, 40),  # the Gemma-2B shape class
+    "window-gqa-ragged-starts": (3, 4, 2, 2, 128, 16, 50),
+    "window-of-one-mha": (2, 1, 4, 1, 256, 8, 17),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ALL_LIVE))
+def test_kernel_matches_reference_with_every_slot_live(case):
+    """Rows at random depths whose tables name only the pages they hold
+    (null page 0 past them), every window slot live, at the published head
+    widths; layer 1 exercises the prefetched layer-slice selection."""
+    B, S, K, G, hd, psz, deepest = _ALL_LIVE[case]
+    rng = random.Random(case)
+    p_max = -(-(deepest + S) // psz) + 1
+    n_pages = B * p_max + 2
+    ks = jax.random.split(jax.random.PRNGKey(B * 10 + S), 3)
+    q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (K, 2, n_pages, psz, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 2, n_pages, psz, hd), jnp.float32)
+    starts = [rng.randint(0, deepest) for _ in range(B)]
+    free = rng.sample(range(1, n_pages), n_pages - 1)
+    table = np.zeros((B, p_max), np.int32)
+    for b, start in enumerate(starts):
+        for i in range(-(-(start + S) // psz)):
+            table[b, i] = free.pop()
+    args = (
+        q, kp, vp, jnp.asarray(table), jnp.asarray(starts, jnp.int32),
+        jnp.full((B,), S, jnp.int32), 1,
+    )
+    out = ragged_paged_attention(*args, interpret=True)
+    ref = ragged_paged_attention_reference(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+def test_kernel_clamps_a_window_that_overhangs_the_table():
+    """A row's start + window width may overhang its page table by up to one
+    window; the kernel must clamp its page walk to the table width instead
+    of reading page_table[b, Pmax] out of bounds (regression: done rows in
+    the speculative decode loop)."""
+    B, S, K, G, hd, psz, p_max = 1, 4, 1, 2, 16, 4, 3
+    n_pages = p_max + 1
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (K, 2, n_pages, psz, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (K, 2, n_pages, psz, hd), jnp.float32)
+    table = jnp.asarray([[1, 2, 3]], jnp.int32)
+    start = jnp.array([p_max * psz - 1], jnp.int32)  # last in-table position
+    full = jnp.full((B,), S, jnp.int32)
+    out = ragged_paged_attention(q, kp, vp, table, start, full, interpret=True)
+    ref = ragged_paged_attention_reference(q, kp, vp, table, start, full)
+    # Query 0 is fully in-table; its output must be exact. Later queries'
+    # visible ranges overhang the table and are garbage by contract.
+    np.testing.assert_allclose(
+        np.asarray(out[:, 0]), np.asarray(ref[:, 0]), rtol=2e-5, atol=2e-5
+    )
+
+
 def test_ragged_idle_rows_stream_zero_pages_and_output_zeros():
     """The idle-row contract, tested at the only level it CAN be tested:
     from the outputs alone, streamed-then-masked and never-streamed are
@@ -186,6 +276,23 @@ def test_ragged_kernel_at_the_key_blocks_edges(edge, S):
     assert np.any(np.asarray(out[0, :live]) != 0.0)
 
 
+@pytest.mark.parametrize("edge", sorted(_BLOCK_EDGES))
+@pytest.mark.parametrize("S", [1, 8], ids=["single-query", "window"])
+def test_all_live_windows_at_the_key_blocks_edges_at_the_cells_head_dim(edge, S):
+    """The same edges at head_dim 128 with every slot live: row 0's FIRST
+    query sees exactly the edge's keys (its window walks on across it, as
+    far as the table goes), row 1's LAST query does."""
+    from mcpx.engine.kernels.paged_attention import _blocking
+
+    assert _blocking(2, 2, 128, 16, S, 4, 4, 40) == (2, 16)
+    ctx = _BLOCK_EDGES[edge]
+    first, last = min(ctx - 1, 640 - S), max(ctx - S, 0)
+    args = _edge_case([first + S, last + S], [S, S], S, hd=128, seed=ctx)
+    out = ragged_paged_attention(*args, 1, interpret=True)
+    ref = ragged_paged_attention_reference(*args, 1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
 def test_ragged_kernel_serves_a_mixed_slab_in_one_launch():
     """A prefill row of Q_BLOCK + 90 queries (two query blocks, the second
     ragged), a verify window, decode rows at three depths and idle rows, in
@@ -264,7 +371,6 @@ def test_blocking_halves_the_key_block_when_one_head_does_not_fit():
 
 
 
-
 def _kernel_arg_shapes(S, K, G, hd, B=4, psz=16, p_max=128):
     sd = jax.ShapeDtypeStruct
     pool = sd((K, 2, B * p_max + 1, psz, hd), jnp.bfloat16)
@@ -332,6 +438,9 @@ def test_ragged_kernel_route_refuses_to_run_without_a_mesh():
             {}, None, z, z[0], z, {"k": jnp.zeros((1, 1, 1, 1, 1))},
             interpret=True, q_lens=z[0],
         )
+    # ... and one that states no live widths has no contract to fall back on.
+    with pytest.raises(TypeError, match="q_lens"):
+        decode_chunk_paged({}, None, z, z[0], z, {"k": jnp.zeros((1, 1, 1, 1, 1))})
 
 
 def test_sharded_kernel_matches_reference_on_virtual_mesh():
@@ -359,6 +468,134 @@ def test_sharded_kernel_matches_reference_on_virtual_mesh():
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
         )
+
+
+# ------------------------------------------------- through the model step
+def _step_case(cfg, B, S, psz, p_max, pos0):
+    """Params, random resident pools, private tables and a window of tokens."""
+    from mcpx.engine.kv_cache import init_paged_kv
+    from mcpx.models.gemma.model import init_params
+
+    pools = {
+        name: jax.random.normal(jax.random.PRNGKey(i + 1), pool.shape, pool.dtype)
+        for i, (name, pool) in enumerate(init_paged_kv(cfg, B * p_max + 1, psz).items())
+    }
+    table = jnp.asarray(np.arange(B * p_max, dtype=np.int32).reshape(B, p_max) + 1)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (B, S), 0, cfg.vocab_size)
+    return init_params(cfg, jax.random.PRNGKey(0)), pools, table, tokens, jnp.asarray(pos0, jnp.int32)
+
+
+def test_one_window_of_s_tokens_matches_s_windows_of_one():
+    """decode_chunk_paged over S tokens == S forwards of one token each: the
+    same logits at every window slot and identical page pools afterward (the
+    speculation verify pass must be an exact re-expression of sequential
+    decode)."""
+    from mcpx.engine.paged_decode import decode_chunk_paged
+    from mcpx.models.gemma.config import GemmaConfig
+
+    cfg = GemmaConfig(
+        dtype="float32", d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=8, d_ff=64
+    )
+    B, S = 2, 5
+    params, pool0, table, tokens, pos0 = _step_case(cfg, B, S, 4, 4, [3, 6])  # mid-page starts
+
+    seq_pool, seq_logits = pool0, []
+    for i in range(S):
+        lg, seq_pool = decode_chunk_paged(
+            params, cfg, tokens[:, i : i + 1], pos0 + i, table, seq_pool,
+            use_pallas=False, q_lens=jnp.ones((B,), jnp.int32),
+        )
+        seq_logits.append(lg[:, 0])
+    window_logits, window_pool = decode_chunk_paged(
+        params, cfg, tokens, pos0, table, pool0,
+        use_pallas=False, q_lens=jnp.full((B,), S, jnp.int32),
+    )
+    np.testing.assert_allclose(
+        np.asarray(window_logits), np.asarray(jnp.stack(seq_logits, axis=1)),
+        rtol=2e-5, atol=2e-5,
+    )
+    for key in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(window_pool[key]), np.asarray(seq_pool[key]), rtol=2e-5, atol=2e-5
+        )
+
+
+def test_model_step_on_the_interpreted_kernel_matches_the_jnp_route():
+    from mcpx.engine.paged_decode import decode_chunk_paged
+    from mcpx.models.gemma.config import GemmaConfig
+    from mcpx.parallel.mesh import make_mesh
+
+    cfg = GemmaConfig(
+        dtype="float32", d_model=32, n_layers=1, n_heads=2, n_kv_heads=1, head_dim=128, d_ff=64
+    )
+    B, S = 2, 3
+    params, pool0, table, tokens, pos0 = _step_case(cfg, B, S, 4, 3, [1, 5])
+    q_lens = jnp.full((B,), S, jnp.int32)
+    ref_logits, ref_pool = decode_chunk_paged(
+        params, cfg, tokens, pos0, table, pool0, use_pallas=False, q_lens=q_lens
+    )
+    pal_logits, pal_pool = decode_chunk_paged(
+        params, cfg, tokens, pos0, table, pool0, use_pallas=True, interpret=True,
+        q_lens=q_lens, mesh=make_mesh(data=1, model=1, devices=jax.devices()[:1]),
+    )
+    np.testing.assert_allclose(
+        np.asarray(pal_logits), np.asarray(ref_logits), rtol=2e-5, atol=2e-5
+    )
+    for key in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(pal_pool[key]), np.asarray(ref_pool[key]))
+
+
+_TINY = dict(vocab_size=384, d_model=64, n_heads=4, d_ff=128, dtype="float32")
+_SPARSE = dict(
+    n_experts=8, n_experts_per_tok=2, d_expert=32, activation="silu",
+    tie_embeddings=False, scale_embeddings=False, norm_plus_one=False,
+)
+_BLOCKS = {
+    "default": dict(_TINY, n_layers=2, n_kv_heads=2, head_dim=32),
+    "windowed-sparse": dict(
+        _TINY, **_SPARSE, n_layers=4, n_kv_heads=2, head_dim=32, sliding_window=8,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+    ),
+    "latent": dict(
+        _TINY, **_SPARSE, n_layers=2, n_kv_heads=1, head_dim=16, attention="latent",
+        q_lora_rank=24, kv_lora_rank=32, qk_rope_head_dim=8, v_head_dim=16,
+    ),
+}
+
+
+@pytest.mark.parametrize("block", sorted(_BLOCKS))
+def test_a_live_slots_logits_do_not_depend_on_its_rows_pad_slots(block):
+    """Same ``q_lens < S``, two fillings of the pad slots (and of an idle
+    row): bit-equal logits at every live slot and equal expert counters. A
+    pad slot's key lies past every live query's visible range at every
+    layer, and a sparse feed-forward routes it nowhere."""
+    from mcpx.engine.paged_decode import decode_chunk_paged
+    from mcpx.models.gemma.config import GemmaConfig
+
+    cfg = GemmaConfig(**_BLOCKS[block])
+    B, S = 4, 8
+    # row 2's window crosses a page edge
+    params, pools, table, _, positions = _step_case(cfg, B, S, 16, 4, [20, 9, 14, 37])
+    q_lens = jnp.asarray([3, 0, 7, 1], jnp.int32)
+    rng = np.random.default_rng(0)
+    live = np.arange(S)[None, :] < np.asarray(q_lens)[:, None]
+    one = rng.integers(0, cfg.vocab_size, (B, S))
+    other = np.where(live, one, rng.integers(0, cfg.vocab_size, (B, S)))
+    assert (one != other)[~live].any()
+
+    def run(tokens):
+        return decode_chunk_paged(
+            params, cfg, jnp.asarray(tokens, jnp.int32), positions, table, pools,
+            use_pallas=False, q_lens=q_lens, moe_stats=True,
+        )
+
+    (logits_a, _, stats_a), (logits_b, _, stats_b) = run(one), run(other)
+    np.testing.assert_array_equal(np.asarray(logits_a)[live], np.asarray(logits_b)[live])
+    if cfg.n_experts:
+        assert np.asarray(stats_a).tolist() == np.asarray(stats_b).tolist()
+        assert int(stats_a[: cfg.n_experts].sum()) == cfg.n_layers * 2 * int(q_lens.sum())
+    else:
+        assert stats_a is None and stats_b is None
 
 
 # ------------------------------------------------------------ engine-level

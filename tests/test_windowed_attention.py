@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from mcpx.engine.kernels.paged_attention import (
-    paged_attention_chunk_reference,
     ragged_paged_attention,
     ragged_paged_attention_reference,
 )
@@ -138,11 +137,13 @@ def test_the_windows_of_one_scan_may_differ_by_layer():
     assert fn._cache_size() == 1
 
 
-def test_the_dense_chunk_reference_takes_the_window_too():
+def test_the_reference_takes_the_window_with_every_slot_live():
     q, kp, vp, table, starts, _ = _case(8, seed=4)
     full = np.full((4,), 8, np.int32)
     want = _dense_masked_softmax(q, kp, vp, table, starts, full, 5)
-    out = paged_attention_chunk_reference(q, kp, vp, jnp.asarray(table), jnp.asarray(starts), 0, 5)
+    out = ragged_paged_attention_reference(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(starts), jnp.asarray(full), 0, 5
+    )
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
 
 
