@@ -76,9 +76,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mcpx.core.config import MCPXConfig
 from mcpx.core.errors import ConfigError, EngineError
-from mcpx.engine.kv_cache import PageAllocator, commit_prefill_to_pages, init_paged_kv
+from mcpx.engine.kv_cache import (
+    PageAllocator, commit_prefill_to_pages, init_paged_kv, init_state_pool, write_prefill_state,
+)
 from mcpx.engine.pacing import SegmentPacer, hold_until
-from mcpx.engine.paged_decode import decode_chunk_paged
+from mcpx.engine.paged_decode import decode_chunk_paged, keep_window
 from mcpx.models.gemma.moe import (
     FORWARD_STATS, INDEX_STATS, LAYER_STATS, forward_weight_bytes, moe_stats_init,
 )
@@ -469,6 +471,21 @@ class InferenceEngine:
                 raise ConfigError(
                     f"this model's block departs from the default one, which {asked} requires"
                 )
+        if mc.hybrid:
+            # A recurrent state beside the pages: what would have to carry
+            # it and does not is refused here, by name.
+            unsupported = {
+                "engine.hetero_batch (the stacked-grammar segments do not carry the "
+                "recurrent state)": ecfg.hetero_batch,
+                "engine.kv_tier (a spilled or snapshotted page run has no state slot)":
+                    ecfg.kv_tier.enabled or bool(ecfg.kv_tier.snapshot_path),
+            }
+            asked = [what for what, on in unsupported.items() if on]
+            if asked:
+                raise ConfigError(
+                    f"this model keeps a recurrent state a row (layer_pattern), which {asked} "
+                    "does not carry"
+                )
         # Which counters the segment returns beside its state (sparse
         # feed-forward, windowed attention); a default block returns none.
         self._segment_stats = (bool(mc.n_experts), mc.layer_windows() is not None)
@@ -506,7 +523,7 @@ class InferenceEngine:
         # `pallas=true` can then never mask a jnp fork OR an idle path.
         # Worker-thread writes, GIL-atomic cross-thread reads.
         self._pallas_dispatches = {  # mcpx: owner[engine-worker, atomic]
-            "decode": 0, "prefill": 0, "spec_verify": 0,
+            "decode": 0, "prefill": 0, "spec_verify": 0, "ssm": 0,
         }
         self.state = "cold"
         self._state_lock = threading.Lock()
@@ -519,6 +536,14 @@ class InferenceEngine:
         # Device state (worker thread only after start):
         self._params = None  # mcpx: owner[engine-worker]
         self._paged_kv = None  # mcpx: owner[engine-worker]
+        # The second kind of per-row state (kv_cache.init_state_pool): {} for
+        # a model with no recurrent layer, which adds nothing to a jitted call.
+        self._state_pool: dict = {}  # mcpx: owner[engine-worker]
+        # Admissions of a model with recurrent layers that found their pages
+        # resident and prefilled whole all the same (no node holds the STATE
+        # a recurrent layer starts from): mcpx_engine_prefix_state_total
+        # {event="miss"}.
+        self._prefix_state_misses = 0  # mcpx: owner[engine-worker, atomic]
         self._dfa_cache: "OrderedDict[tuple, tuple]" = OrderedDict()  # mcpx: owner[engine-worker]
         # Heterogeneous batching (EngineConfig.hetero_batch): the stacked-DFA
         # slot table. ``_dfa_slots[k]`` is the grammar whose padded tables
@@ -1083,17 +1108,33 @@ class InferenceEngine:
                 "reason": blocked if not on else idle,
             }
 
+        more = {}
+        if self.model_cfg.hybrid:
+            # The recurrent layers' window kernel (kernels/ssm.py): every
+            # decode segment of such a model runs it, where ONE device holds
+            # the state pool.
+            one = self._mesh is None or self._mesh.size == 1
+            more["ssm"] = {
+                "engaged": on and one,
+                "dispatches": d["ssm"],
+                "reason": blocked if not on else (
+                    None if one else "the state pool's kernel runs on one device; a mesh takes the jnp form"
+                ),
+            }
         return {
             "enabled": on,
             "interpret": bool(ecfg.interpret),
             "reason": blocked,
             "paths": {
+                **more,
                 "decode": path("decode", None),
                 "prefill": path(
                     "prefill",
-                    None
-                    if ecfg.prefix_cache
-                    else "idle: prefix_cache=off (no suffix prefills)",
+                    "idle: prefix_cache=off (no suffix prefills)"
+                    if not ecfg.prefix_cache
+                    else "idle: a model with recurrent layers prefills whole (no suffix prefills)"
+                    if self.model_cfg.hybrid
+                    else None,
                 ),
                 "spec_verify": path(
                     "spec_verify",
@@ -1160,6 +1201,10 @@ class InferenceEngine:
                     "window_forwards": self._window_total,
                     "window_max_forwards": self._window_max_total,
                     **self._layer_kind_totals,
+                    **(
+                        {"prefix_state_miss": self._prefix_state_misses}
+                        if self.model_cfg.hybrid else {}
+                    ),
                 }
             }
             if prof is not None
@@ -1303,6 +1348,7 @@ class InferenceEngine:
             },
         }
         self._paged_kv = self._init_pools()
+        self._state_pool = self._init_state_pool()
         # Every jitted executable goes through the cost registry
         # (telemetry/costs.py): one AOT compile per signature harvests
         # XLA's cost_analysis() and increments the
@@ -1315,7 +1361,7 @@ class InferenceEngine:
             jax.jit(
                 self._prefill_impl,
                 static_argnames=("T",),
-                donate_argnames=("paged_k", "paged_v"),
+                donate_argnames=("paged_k", "paged_v", "state"),
             ),
             static_argnames=("T",),
         )
@@ -1342,7 +1388,7 @@ class InferenceEngine:
             jax.jit(
                 self._segment_impl,
                 static_argnames=("chunk", "temperature", "constrained", "draft"),
-                donate_argnames=("paged_k", "paged_v"),
+                donate_argnames=("paged_k", "paged_v", "state"),
             ),
             static_argnames=("chunk", "temperature", "constrained", "draft"),
         )
@@ -1642,19 +1688,24 @@ class InferenceEngine:
         # Null page table: scatters land on reserved page 0, which
         # no live sequence ever reads.
         table = np.zeros((A, ecfg.max_pages_per_seq), np.int32)
-        last, k_p, v_p, _ = self._jit_prefill(
+        # Every row a padding row: its state slot is out of range, dropped.
+        last, k_p, v_p, _, state = self._jit_prefill(
             self._params,
             self._put(tokens, self._row_spec(A, 1)),
             self._put(seq_lens, self._row_spec(A)),
             self._paged_kv["k"],
             self._paged_kv["v"],
             self._put(table, self._row_spec(A, 1)),
+            self._state_pool,
+            self._cohort_slots(A),
             T=T,
         )
         self._paged_kv = {"k": k_p, "v": v_p}
-        if ecfg.prefix_cache:
+        self._state_pool = state
+        if ecfg.prefix_cache and not self.model_cfg.hybrid:
             # Shared-prefix serving prefills SUFFIXES through the
-            # chunked path; compile it for the same buckets.
+            # chunked path; compile it for the same buckets. (A model with
+            # recurrent layers never takes it: its rows prefill whole.)
             last, k_p, v_p, _ = self._jit_suffix_prefill(
                 self._params,
                 self._put(tokens, self._row_spec(A, 1)),
@@ -1765,12 +1816,14 @@ class InferenceEngine:
                     (slab.prev, rs_b),
                 ),
                 key,
+                self._state_pool,
                 iters=iters,
                 chunk=chunk,
                 temperature=ecfg.temperature,
                 constrained=True,
                 draft=ecfg.draft_mode == "prompt",
             )
+            self._state_pool = out[-1]
         self._paged_kv = {"k": out[5], "v": out[6]}
 
     def _warm_grammar(self, grammar: PlanGrammar) -> None:
@@ -1985,7 +2038,7 @@ class InferenceEngine:
         now = time.monotonic()
         while self._pending_admissions:
             (
-                t0, marker, rows, gens, t_admit0, pf_entry, pf_name,
+                t0, marker, rows, gens, t_admit0, pf_entry, pf_name, pf_toks,
             ) = self._pending_admissions[0]
             if not marker.is_ready():
                 # Purge entries whose rows were ALL cancelled/reaped before
@@ -2012,7 +2065,7 @@ class InferenceEngine:
                     if slab.req[i] is not None and slab.gen[i] == g
                 ]
                 self._ledger_account(pf_entry, pf_name, live, slab)
-            for i, g in zip(rows, gens):
+            for i, g, n_pf in zip(rows, gens, pf_toks):
                 if slab.req[i] is None or slab.gen[i] != g:
                     continue
                 slab.prefill_ms[i] = dt
@@ -2035,6 +2088,10 @@ class InferenceEngine:
                         if self.config.engine.prefix_cache
                         else {}
                     )
+                    if self.model_cfg.hybrid:
+                        # The tokens this row's prefill moved its recurrent
+                        # state by, over the Mamba layers: all it prefilled.
+                        pfx_attrs["ssm_prefill_tokens"] = n_pf * self.model_cfg.n_mamba_layers
                     r.span.child(
                         "engine.prefill",
                         t0=t_admit0,
@@ -2356,7 +2413,13 @@ class InferenceEngine:
         cur0 = jnp.where(done0, tok.pad_id, first)
         return cur0, state0, done0
 
-    def _prefill_impl(self, params, tokens, seq_lens, paged_k, paged_v, page_table, *, T):
+    def _prefill_impl(
+        self, params, tokens, seq_lens, paged_k, paged_v, page_table, state, slots, *, T
+    ):
+        """``state``, ``slots``: the state pool and each cohort row's slot of
+        it ({} and None for a model with no recurrent layer): a row's slot is
+        OVERWRITTEN with its prompt's final state, nothing pending (a slot
+        out of range, a padding row's, is dropped)."""
         cfg = self.model_cfg
         B = tokens.shape[0]
         dense = init_kv_cache(cfg, B, T)
@@ -2375,7 +2438,9 @@ class InferenceEngine:
             seq_lens,
             self.config.engine.kv_page_size,
         )
-        return last, paged["k"], paged["v"], moe
+        if cfg.hybrid:
+            state = write_prefill_state(state, slots, dense["ssm"])
+        return last, paged["k"], paged["v"], moe, state
 
     def _suffix_prefill_impl(
         self, params, tokens, seq_lens, positions, page_table, paged_k, paged_v
@@ -2784,13 +2849,15 @@ class InferenceEngine:
                 # "engaged but never ran" (pallas_paths).
                 self._pallas_dispatches["prefill"] += 1
             else:
-                last, k_p, v_p, _ = self._jit_prefill(
+                last, k_p, v_p, _, self._state_pool = self._jit_prefill(
                     self._params,
                     self._put(tokens, self._row_spec(1, 1)),
                     self._put(np.asarray([R], np.int32), self._row_spec(1)),
                     self._paged_kv["k"],
                     self._paged_kv["v"],
                     self._put(table, self._row_spec(1, 1)),
+                    self._state_pool,
+                    None,
                     T=T,
                 )
             self._paged_kv = {"k": k_p, "v": v_p}
@@ -2841,6 +2908,7 @@ class InferenceEngine:
         prompt_lens,
         prev,
         key,
+        state,
         *,
         iters: int,
         chunk: int,
@@ -2882,7 +2950,11 @@ class InferenceEngine:
         prev, n_forwards, live_forwards [B]: each row's count of the
         forwards at whose start it was not done) and, for a model with
         sparse or windowed layers, their counters (``_segment_stats``) as
-        one more int32 vector.
+        one more int32 vector; last, always, the state pool (``state``: {}
+        for a model with no recurrent layer). A row's recurrent state moves
+        by exactly the tokens its position moves by, ``1 + accepted`` of a
+        window and never the window: each forward leaves its window pending
+        and ``keep_window`` says, after the verify, how much of it stays.
         """
         cfg = self.model_cfg
         tok = self.tokenizer
@@ -2897,13 +2969,21 @@ class InferenceEngine:
         sparse, windowed = self._segment_stats
 
         def cond(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live, ssm = c
             return (it < iters) & jnp.any(~done)
+
+        def kept(kv, ms, adv, done):
+            """The state pool after a forward whose rows keep ``adv`` tokens
+            of their windows, and the counters with those tokens added."""
+            if not cfg.hybrid:
+                return ssm0, ms
+            tokens = jnp.sum(adv).astype(jnp.int32) * cfg.n_mamba_layers
+            return keep_window(kv["state"], b_idx, adv, ~done), ms.at[-1].add(tokens)
 
         def draft_body(c):
             from mcpx.engine.sampling import NEG_INF
 
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live, ssm = c
             J = chunk - 1
             Lp = prompt_toks.shape[1]
             j_ar = jnp.arange(J)
@@ -2971,7 +3051,7 @@ class InferenceEngine:
                 chunk_toks,
                 pos,
                 page_table,
-                {"k": k_p, "v": v_p},
+                {"k": k_p, "v": v_p, "state": ssm},
                 use_pallas=self._use_pallas,
                 interpret=self.config.engine.interpret,
                 mesh=self._mesh,
@@ -3032,6 +3112,7 @@ class InferenceEngine:
             prev2 = jnp.where(
                 done | newly_done, prev, chunk_toks[b_idx, a]
             )
+            ssm, ms = kept(kv, ms + fwd_ms[0] if sparse else ms, adv, done)
             return (
                 it + 1,
                 nxt,
@@ -3044,12 +3125,13 @@ class InferenceEngine:
                 buf,
                 prev2,
                 key,
-                ms + fwd_ms[0] if sparse else ms,
+                ms,
                 live + ~done,
+                ssm,
             )
 
         def body(c):
-            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = c
+            it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live, ssm = c
 
             if chunk > 1 and constrained:
                 # Fast-forward: chain of forced tokens after `cur`. Emission
@@ -3104,7 +3186,7 @@ class InferenceEngine:
                 chunk_toks,
                 pos,
                 page_table,
-                {"k": k_p, "v": v_p},
+                {"k": k_p, "v": v_p, "state": ssm},
                 use_pallas=self._use_pallas,
                 interpret=self.config.engine.interpret,
                 mesh=self._mesh,
@@ -3145,6 +3227,7 @@ class InferenceEngine:
                 prev,
                 chunk_toks[b_idx, jnp.maximum(adv - 1, 0)],
             )
+            ssm, ms = kept(kv, ms + fwd_ms[0] if sparse else ms, adv, done)
             return (
                 it + 1,
                 nxt,
@@ -3157,10 +3240,12 @@ class InferenceEngine:
                 buf,
                 prev2,
                 key,
-                ms + fwd_ms[0] if sparse else ms,
+                ms,
                 live + ~done,
+                ssm,
             )
 
+        ssm0 = state
         rows_at_dispatch = []
         if windowed:
             past = ~done & (pos >= cfg.sliding_window)
@@ -3181,17 +3266,18 @@ class InferenceEngine:
             # Row-forwards by state, counted where they happen: a row's
             # count of the forwards at whose start it was not done.
             jnp.zeros_like(done, jnp.int32),
+            state,
         )
-        it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live = lax.while_loop(
+        it, cur, pos, st, e, done, k_p, v_p, buf, prev, key, ms, live, state = lax.while_loop(
             cond, draft_body if use_draft else body, init
         )
         out = (cur, pos, st, e, done, k_p, v_p, buf, prev, it, live)
         if not any(self._segment_stats):
-            return out
+            return out + (state,)
         # What the layer kinds did, for the lagged harvest's one fetch: the
         # expert counters of the segment's forwards, then the rows that were
         # live at dispatch and those of them at or past the window.
-        return out + (jnp.concatenate(([ms] if sparse else []) + rows_at_dispatch),)
+        return out + (jnp.concatenate(([ms] if sparse else []) + rows_at_dispatch), state)
 
     def _hetero_segment_impl(
         self,
@@ -4088,7 +4174,9 @@ class InferenceEngine:
             self._dispatch_merge(slab, [])
         hold: Optional[PrefixNode] = None
         head_key = (
-            head_req.prefix_key(ecfg.kv_page_size) if ecfg.prefix_cache else None
+            head_req.prefix_key(ecfg.kv_page_size)
+            # (a head no recurrent layer could start from is not built)
+            if ecfg.prefix_cache and not self.model_cfg.hybrid else None
         )
         warm_head = (
             self._pop_warm_head(head_req)
@@ -4200,6 +4288,11 @@ class InferenceEngine:
         free = slab.free_rows()
         cache = self._prefix_cache
         use_prefix = bool(ecfg.prefix_cache)
+        # A recurrent layer starts from the STATE at a hit's boundary and no
+        # radix node holds one: such a model's rows prefill whole (and still
+        # insert their heads: pages are pages), and a row whose pages were
+        # resident is a counted miss.
+        no_state = use_prefix and self.model_cfg.hybrid
         psz = ecfg.kv_page_size
 
     # --- per-request geometry
@@ -4286,7 +4379,7 @@ class InferenceEngine:
             """Matched depth for ``r`` under ``cap_tokens``, degraded to 0
             when that depth leaves no room for a decode budget or any
             prefill bucket (serve without reuse rather than failing)."""
-            if not use_prefix or cap_tokens <= 0:
+            if not use_prefix or no_state or cap_tokens <= 0:
                 return 0
             P = cache.probe(
                 r.prompt_ids,
@@ -4419,6 +4512,11 @@ class InferenceEngine:
                     cache.matched_tokens += P
                 else:
                     cache.misses += 1
+                if no_state and cache.probe(
+                    r.prompt_ids, min(capacity - T, cache.match_cap(len(r.prompt_ids)))
+                ) > 0:
+                    self._prefix_state_misses += 1
+                    self.metrics.prefix_state.labels(event="miss").inc()
                 if self._governor is not None:
                     # Per-tenant reuse accounting: matched vs prefilled
                     # tokens — the per-tenant hit-rate spread GET /cache
@@ -4500,6 +4598,9 @@ class InferenceEngine:
             # (budgets/active/sampling-config ride along for the admit call
             # and the admit-merge below).
             rs, rs2 = self._row_spec(A), self._row_spec(A, 1)
+            # The slab rows this cohort takes (``free``, in order, below) are
+            # its rows' slots of the state pool.
+            slots_d = self._cohort_slots(A, free[: len(cohort)])
             if any_prefix:
                 (
                     tokens_d, lens_d, p_d, table_d, budgets_d, active_d,
@@ -4548,13 +4649,15 @@ class InferenceEngine:
                     (cons_np, rs),
                     (dfa_np, rs),
                 )
-                last_logits, k_p, v_p, moe_d = self._jit_prefill(
+                last_logits, k_p, v_p, moe_d, self._state_pool = self._jit_prefill(
                     self._params,
                     tokens_d,
                     lens_d,
                     self._paged_kv["k"],
                     self._paged_kv["v"],
                     table_d,
+                    self._state_pool,
+                    slots_d,
                     T=T,
                 )
                 pf_entry = getattr(self._jit_prefill, "last_entry", None)
@@ -4741,6 +4844,7 @@ class InferenceEngine:
             (
                 t1, slab.dev[4], rows_idx,
                 [int(slab.gen[i]) for i in rows_idx], t0, pf_entry, pf_name,
+                [int(n) for n in seq_lens[: len(cohort)]],
             )
         )
         self.metrics.kv_page_utilization.set(self._allocator.stats().utilization)
@@ -4851,6 +4955,8 @@ class InferenceEngine:
         # decode-path dispatch; the spec segment is ALSO a spec-verify
         # dispatch (its verify forward rides the same executable).
         self._pallas_dispatches["decode"] += 1
+        if self.model_cfg.hybrid:
+            self._pallas_dispatches["ssm"] += 1
         if hetero and slab.spec:
             self._pallas_dispatches["spec_verify"] += 1
         self._seg_counter += 1
@@ -4942,6 +5048,7 @@ class InferenceEngine:
                     plens_d,
                     prev_d,
                     prng,
+                    self._state_pool,
                     iters=np.int32(window),
                     chunk=chunk,
                     temperature=slab.temperature,
@@ -4949,7 +5056,7 @@ class InferenceEngine:
                     draft=ecfg.draft_mode == "prompt",
                 )
                 (cur_d, pos_d, st_d, e_d, done_d, k_p, v_p, buf_d, prev_d, n_fwd,
-                 live_d, *kind_stats) = out
+                 live_d, *kind_stats, self._state_pool) = out
                 kinds_d = kind_stats[0] if kind_stats else None
         self._paged_kv = {"k": k_p, "v": v_p}
         slab.dev = (
@@ -5064,7 +5171,7 @@ class InferenceEngine:
             routed, ctx_tokens, row_calls = (int(c) for c in counts[own : own + 3])
             attrs["moe_tokens_routed"] = routed
             attrs["attn_ctx_tokens"], attrs["attn_row_calls"] = ctx_tokens, row_calls
-            attrs["kv_bytes_read"] = ctx_tokens * (mc.kv_bytes_per_token // mc.n_layers)
+            attrs["kv_bytes_read"] = ctx_tokens * (mc.kv_bytes_per_token // mc.n_attn_layers)
             if mc.index_topk:
                 # A learned index: the masked form streams every page (what
                 # kv_bytes_read counts) and attends the selected keys alone.
@@ -5080,6 +5187,16 @@ class InferenceEngine:
                 attrs["attn_query_slots"] = int(
                     counts[own + FORWARD_STATS + (INDEX_STATS if mc.index_topk else 0)]
                 )
+            if mc.hybrid:
+                # Recurrent layers: calls on live rows, the live window slots
+                # they computed, the tokens the state moved by; a call reads
+                # a slot's state once and writes it once.
+                ssm = own + FORWARD_STATS
+                attrs["ssm_row_calls"], attrs["ssm_slots"], attrs["ssm_tokens"] = (
+                    int(c) for c in counts[ssm : ssm + 3]
+                )
+                attrs["ssm_state_bytes"] = attrs["ssm_row_calls"] * mc.ssm_slot_bytes * 2
+                attrs["ssm_prefill_tokens"] = sum(int(c[ssm + 2]) for c in prefills)
             attrs["moe_experts_touched"] = int(counts[E])
             attrs["moe_layer_forwards"] = n_fwd * mc.n_sparse_layers
             attrs["moe_expert_slots"] = attrs["moe_layer_forwards"] * E
@@ -5506,6 +5623,30 @@ class InferenceEngine:
             out_shardings=self._named(kv_spec),
         )()
 
+    def _init_state_pool(self) -> dict:
+        """Fresh zeroed state pool (``kv_cache.init_state_pool``; {} for a
+        model with no recurrent layer): a slot a slab row, whole on every
+        device, its pending window as wide as the widest decode window."""
+        mc = self.model_cfg
+        if not mc.hybrid:
+            return {}
+        width = max(8, self._spec_chunk(True))
+        n_slots = self.config.engine.max_batch_size  # slab row i owns slot i
+        self.metrics.prefix_state.labels(event="miss")  # (the sample exists from the start, at 0)
+        return jax.jit(
+            lambda: init_state_pool(mc, n_slots, width), out_shardings=self._named(P()),
+        )()
+
+    def _cohort_slots(self, A: int, rows=()):
+        """Each row's slot of the state pool for a cohort of ``A``'s prefill:
+        the slab row it takes; out of range, which the write drops, for a
+        padding row. None for a model with no recurrent layer."""
+        if not self._state_pool:
+            return None
+        slots = np.full((A,), int(self._state_pool["n"].shape[0]), np.int32)
+        slots[: len(rows)] = rows
+        return self._put(slots, self._row_spec(A))
+
     def _reset_pools(self) -> None:
         """Recreate the KV page pools after a failed jit call. Prefill and
         segment calls DONATE the pools: an exception after dispatch leaves
@@ -5518,6 +5659,7 @@ class InferenceEngine:
         the whole tree is dropped (and rebuilt on next use)."""
         self._prefix_cache.drop_all()
         self._paged_kv = self._init_pools()
+        self._state_pool = self._init_state_pool()
         self.metrics.engine_resets.inc()
 
     def _fail_rows(self, slab: "_Slab", error: BaseException) -> None:

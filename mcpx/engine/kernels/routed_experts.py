@@ -66,7 +66,7 @@ def _vmem_bytes(f_blk: int, *, T: int, D: int, E: int, F: int, w_itemsize: int, 
     float32 output block (two buffers each); a step's gate, up and product
     tiles in float32 and its down product; the float32 scratch that sums
     the down product where ``F`` takes several blocks."""
-    weights = 2 * 3 * D * f_blk * w_itemsize
+    weights = 2 * 3 * D * f_blk * w_itemsize  # (an expert with no gate has two: counted as three)
     rows = 2 * T * D * x_itemsize + 2 * T * max(E, LANES) * 4
     out = 2 * T * D * 4
     step = 3 * T * f_blk * 4 + T * D * 4
@@ -95,13 +95,13 @@ def _kernel(
     order_ref, n_ref, layer_ref,  # scalar prefetch (SMEM): [E], [1], [1]
     x_ref,  # [T, D] the window's rows
     combine_ref,  # [T, E] float32: a slot's weight for an expert, 0 unchosen
-    w_gate_ref, w_up_ref,  # [D, F_BLK] of expert order[i]
-    w_down_ref,  # [F_BLK, D]
-    out_ref,  # [T, D] float32, resident across the grid
-    *scratch,  # ([T, D] float32,) where F takes several blocks
-    act: Callable, F: int, f_blk: int,
+    *refs,  # (w_gate where gated,) w_up [D, F_BLK] of expert order[i], w_down [F_BLK, D],
+    # out [T, D] float32, resident across the grid, ([T, D] float32 scratch where F takes several blocks)
+    act: Callable, F: int, f_blk: int, gated: bool,
 ):
     del layer_ref  # the index maps'
+    w_gate_ref = refs[0] if gated else None
+    w_up_ref, w_down_ref, out_ref, *scratch = refs[gated:]
     i, j = pl.program_id(0), pl.program_id(1)
     n_f = pl.cdiv(F, f_blk)
 
@@ -111,16 +111,19 @@ def _kernel(
 
     @pl.when(i < n_ref[0])
     def _():
-        x, w_gate, w_up, w_down = x_ref[...], w_gate_ref[...], w_up_ref[...], w_down_ref[...]
-        dtype = jnp.promote_types(x.dtype, w_gate.dtype)
+        x, w_up, w_down = x_ref[...], w_up_ref[...], w_down_ref[...]
+        dtype = jnp.promote_types(x.dtype, w_up.dtype)
         f32 = jnp.float32
         # Gate and up rounded to the rows' type, as moe._expert_rows' dots
         # round them; the elementwise chain between those and its own rounding
         # in float32, which is how XLA computes a bfloat16 chain on a core
         # with no bfloat16 vector unit (and Mosaic lowers no bfloat16 logistic).
-        gate = jnp.dot(x, w_gate, preferred_element_type=f32).astype(dtype).astype(f32)
         up = jnp.dot(x, w_up, preferred_element_type=f32).astype(dtype).astype(f32)
-        a = (act(gate) * up).astype(dtype)  # [T, F_BLK]
+        if gated:
+            gate = jnp.dot(x, w_gate_ref[...], preferred_element_type=f32).astype(dtype).astype(f32)
+            a = (act(gate) * up).astype(dtype)  # [T, F_BLK]
+        else:
+            a = act(up).astype(dtype)  # an expert of two matrices: act(x U) V
         if F % f_blk:
             # The last block overhangs F: what was read past it is no weight.
             left = F - j * f_blk
@@ -153,7 +156,7 @@ def _kernel(
 def routed_experts(
     x: jax.Array,  # [T, D]
     combine: jax.Array,  # [T, E] float32
-    w_gate: jax.Array,  # [L, E, D, F] (stays in HBM; a step's blocks are fetched)
+    w_gate: "jax.Array | None",  # [L, E, D, F] (stays in HBM; a step's blocks are fetched); None: no gate
     w_up: jax.Array,  # [L, E, D, F]
     w_down: jax.Array,  # [L, E, F, D]
     order: jax.Array,  # [E] int32: the held experts, touched ones first
@@ -165,10 +168,11 @@ def routed_experts(
 ) -> jax.Array:
     """``sum_i combine[:, e_i] * down_{e_i}(act(x gate_{e_i}) * (x up_{e_i}))``
     over ``e_i = order[i]``, ``i < n_touched``, in that order: float32
-    [T, D]. See the module docstring."""
+    [T, D]; without ``w_gate``, ``down(act(x up))``. See the module docstring."""
     T, D = x.shape
-    _, E, _, F = w_gate.shape
-    f_blk = _blocking(T, D, F, E, w_gate.dtype.itemsize, x.dtype.itemsize)
+    _, E, _, F = w_up.shape
+    gated = w_gate is not None
+    f_blk = _blocking(T, D, F, E, w_up.dtype.itemsize, x.dtype.itemsize)
     n_f = pl.cdiv(F, f_blk)
     n_touched = jnp.asarray(n_touched, jnp.int32)
 
@@ -195,15 +199,14 @@ def routed_experts(
         in_specs=[
             pl.BlockSpec((T, D), whole),
             pl.BlockSpec((T, E), whole),
-            pl.BlockSpec((None, None, D, f_blk), w_in),
-            pl.BlockSpec((None, None, D, f_blk), w_in),
+            *[pl.BlockSpec((None, None, D, f_blk), w_in)] * (1 + gated),
             pl.BlockSpec((None, None, f_blk, D), w_out),
         ],
         out_specs=pl.BlockSpec((T, D), whole),
         scratch_shapes=[pltpu.VMEM((T, D), jnp.float32)] if n_f > 1 else [],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, act=act, F=F, f_blk=f_blk),
+        functools.partial(_kernel, act=act, F=F, f_blk=f_blk, gated=gated),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -215,5 +218,5 @@ def routed_experts(
         order.astype(jnp.int32),
         n_touched.reshape(1),
         jnp.asarray(layer, jnp.int32).reshape(1),
-        x, combine, w_gate, w_up, w_down,
+        x, combine, *((w_gate,) if gated else ()), w_up, w_down,
     )

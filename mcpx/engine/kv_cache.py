@@ -169,9 +169,63 @@ def init_paged_kv(
     zeros up to a lane width) and ``v`` the normed latent, which the absorbed
     kernel reads for the scores and for the values."""
     d = jnp.dtype(dtype or cfg.dtype)
-    shape = (cfg.n_kv_heads, cfg.n_layers, n_pages, page_size)
+    shape = (cfg.n_kv_heads, cfg.n_attn_layers, n_pages, page_size)
     k_width, v_width = cfg.kv_widths
     return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
+
+
+def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int) -> dict:
+    """The SECOND kind of per-row state, beside the pages: what a Mamba layer
+    keeps of a row, indexed by SLOT (slab row ``i`` owns slot ``i``), not by
+    page. ``{}`` for a model with no such layer: an empty pytree adds nothing
+    to a jitted call. Else ``{"ssm", "layers": one dict a Mamba layer, "n":
+    [n_slots] int32}``: ``ssm`` ``[Mamba layers, n_slots, state, heads x
+    head_dim]`` float32, the recurrent states (heads x head_dim merged and
+    innermost: the kernel's lanes, a whole number of lane widths; a
+    ``[.., heads, head_dim]`` view of the pool would be another tiling, a copy
+    of the pool a forward; the layers in ONE array, which the compiler
+    cannot stage through VMEM around a layer's call: ``kernels/ssm.py``); and
+    a layer's small arrays, each its own: ``conv`` ``[n_slots, K - 1, C]``,
+    the convolution's last inputs, and the slot's PENDING decode window of up
+    to ``window`` tokens, which a forward leaves and the next applies as far
+    as ``n`` says the row kept it (``models/gemma/ssm.py``): ``dt``
+    ``[n_slots, window, heads]`` float32, ``pre`` and ``post`` ``[n_slots,
+    window, C]``, the convolution's inputs and outputs. A forward updates
+    each in place."""
+    if not cfg.n_mamba_layers:
+        return {}
+    d = jnp.dtype(cfg.dtype)
+    C = cfg.conv_width
+
+    def layer():
+        return {
+            "conv": jnp.zeros((n_slots, cfg.conv_kernel - 1, C), d),
+            "dt": jnp.zeros((n_slots, window, cfg.mamba_n_heads), jnp.float32),
+            "pre": jnp.zeros((n_slots, window, C), d),
+            "post": jnp.zeros((n_slots, window, C), d),
+        }
+
+    L = cfg.n_mamba_layers
+    return {
+        "ssm": jnp.zeros((L, n_slots, cfg.ssm_state_size, cfg.mamba_inner), jnp.float32),
+        "layers": tuple(layer() for _ in range(L)),
+        "n": jnp.zeros((n_slots,), jnp.int32),
+    }
+
+
+def write_prefill_state(state: dict, slots: jax.Array, finals: list) -> dict:
+    """A prefill's states ``[(h [A, N, H, P], tail [A, K - 1, C])]`` a Mamba
+    layer into ``slots`` [A] (a padding row's slot is out of range and
+    dropped): the state AT each prompt's length, nothing pending."""
+    ssm, layers = state["ssm"], []
+    for j, (pool, (h, tail)) in enumerate(zip(state["layers"], finals)):
+        ssm = ssm.at[j, slots].set(h.reshape(h.shape[:2] + (-1,)), mode="drop")
+        layers.append({
+            **pool,
+            "conv": pool["conv"].at[slots].set(tail.astype(pool["conv"].dtype), mode="drop"),
+            "dt": pool["dt"].at[slots].set(0.0, mode="drop"),
+        })
+    return {"ssm": ssm, "layers": tuple(layers), "n": state["n"].at[slots].set(0, mode="drop")}
 
 
 def commit_prefill_to_pages(

@@ -28,15 +28,20 @@ from mcpx.engine.kernels.paged_attention import (
 )
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import (
+    _join,
     attention_inputs,
     attention_residual,
     embed_tokens,
     feed_forward_residual,
+    hybrid_attention_inputs,
+    hybrid_feed_forward,
     layer_kinds,
     layer_stacks,
     output_logits,
+    pattern_rows,
     rms_norm,
     sparse_index,
+    stack_row,
 )
 from mcpx.models.gemma.moe import add_forward_stats, add_layer_stats, moe_stats_init
 from mcpx.parallel.mesh import DATA_AXIS, MODEL_AXIS, _axis
@@ -236,6 +241,88 @@ def _write_kv_window(
     return pool.at[:, layer, pages].set(merged, mode="drop")
 
 
+def _hybrid_chunk(
+    params, cfg: GemmaConfig, x, positions, page_table, paged_kv, kv_window, q_lens, slots, *,
+    use_pallas, interpret, mesh, logits_at, active_cols, moe_stats, routing,
+) -> tuple:
+    """``decode_chunk_paged`` for a ``layer_pattern`` model: a static walk
+    over the pattern, each layer one thing alone. A Mamba layer reads and
+    writes its part of the state pool by slot (``ssm.mamba_window``, through
+    ``kernels/ssm.ssm_window`` on the kernel route); the attention layers
+    write and read the pages as every model's do, unrotated; an ``E`` layer
+    is the routed experts in their latent beside the shared expert."""
+    from mcpx.models.gemma.ssm import mamba_window
+
+    B, S, _ = x.shape
+    state = paged_kv["state"]
+    one_device = mesh is None or mesh.size == 1
+    kernel = None
+    if use_pallas and one_device:
+        from mcpx.engine.kernels.ssm import ssm_window
+
+        kernel = functools.partial(ssm_window, interpret=interpret)
+    live = jnp.arange(S)[None, :] < q_lens[:, None]
+    kept = state["n"][slots]
+    k_all, v_all = paged_kv["k"], paged_kv["v"]
+    stats = moe_stats_init(cfg) if cfg.n_experts else None
+    ssm, layers_new, chosen_all = state["ssm"], list(state["layers"]), []
+    for kind, j in pattern_rows(cfg):
+        if kind == "M":
+            lp = stack_row(params["mamba_layers"], j)
+            n = rms_norm(x, lp["norm"], cfg.norm_eps, cfg.norm_plus_one)
+            out, ssm, layers_new[j] = mamba_window(
+                n, lp, cfg, ssm, j, state["layers"][j], slots, q_lens, kept, kernel=kernel
+            )
+            x = _join(x, out)
+        elif kind == "E":
+            x, layer_stats, chosen = hybrid_feed_forward(
+                x, params["layers"], j, cfg, live,
+                use_pallas=use_pallas and one_device, interpret=interpret,
+            )
+            stats = add_layer_stats(stats, layer_stats)
+            chosen_all.append(chosen)
+        else:
+            lp = stack_row(params["attn_layers"], j)
+            n = rms_norm(x, lp["norm"], cfg.norm_eps, cfg.norm_plus_one)
+            q, k, v = hybrid_attention_inputs(n, lp, cfg)
+            k_all = _write_kv_window(k_all, j, k, kv_window)
+            v_all = _write_kv_window(v_all, j, v, kv_window)
+            qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+            if use_pallas:
+                attn = _ragged_kernel_on_mesh(
+                    mesh, qg, k_all, v_all, page_table, positions, q_lens, j, interpret=interpret
+                )
+            else:
+                attn = ragged_paged_attention_reference(
+                    qg, k_all, v_all, page_table, positions, q_lens, j, None
+                )
+            attn = attn.reshape(B, S, cfg.attn_out_width)
+            x = _join(x, jnp.einsum("btf,fd->btd", attn, lp["wo"]))
+    if stats is not None:
+        stats = add_forward_stats(cfg, stats, positions + q_lens, q_lens, S)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
+    # The window this forward left pending is kept as far as the caller says
+    # after it: nothing, until then.
+    n_new = state["n"].at[jnp.where(q_lens > 0, slots, state["n"].shape[0])].set(0, mode="drop")
+    pools = {"k": k_all, "v": v_all, "state": {"ssm": ssm, "layers": tuple(layers_new), "n": n_new}}
+    extra = ((stats,) if moe_stats else ()) + ((jnp.stack(chosen_all),) if routing else ())
+    if active_cols is not None:
+        return (output_logits(params, cfg, x, subset=active_cols), pools) + extra
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    return (output_logits(params, cfg, x), pools) + extra
+
+
+def keep_window(state: dict, slots: jax.Array, kept: jax.Array, live: jax.Array) -> dict:
+    """The state pool with ``kept`` [B] written as what the live rows keep
+    of the window their last forward left pending (``1 + accepted`` tokens
+    of a decode window): the next forward's read applies exactly those."""
+    if not state:
+        return state
+    at = jnp.where(live, slots, state["n"].shape[0])
+    return {**state, "n": state["n"].at[at].set(kept.astype(jnp.int32), mode="drop")}
+
+
 def decode_chunk_paged(
     params: dict[str, Any],
     cfg: GemmaConfig,
@@ -281,6 +368,13 @@ def decode_chunk_paged(
     Returns ([B, S, V] logits, pools) — or
     ([B, V], pools) when ``logits_at`` names the single chunk slot per
     row to unembed.
+
+    A model with recurrent layers (``GemmaConfig.hybrid``) carries its
+    state pool in ``paged_kv["state"]`` (``kv_cache.init_state_pool``) and
+    hands it back there: this forward first applies what each live row KEPT
+    of its previous window (``state["n"]``), leaves its own window pending
+    with ``n`` 0, and the caller writes how many of its tokens the row keeps
+    (``models/gemma/ssm.py``).
     """
     B, S = tokens.shape
     _, _, N, psz, _ = paged_kv["k"].shape
@@ -300,6 +394,13 @@ def decode_chunk_paged(
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
     kv_window = _kv_window(positions, page_table, S, psz, N)
+    if cfg.hybrid:
+        return _hybrid_chunk(
+            params, cfg, x, positions, page_table, paged_kv, kv_window, q_lens,
+            jnp.arange(B, dtype=jnp.int32),  # row i's state is slot i
+            use_pallas=use_pallas, interpret=interpret, mesh=mesh, logits_at=logits_at,
+            active_cols=active_cols, moe_stats=moe_stats, routing=routing,
+        )
     stacks, experts = layer_stacks(cfg, params)
     # A sparse feed-forward routes only the window's live slots: a pad slot
     # or an idle row chooses no expert, reads none and is counted nowhere.

@@ -130,6 +130,33 @@ class GemmaConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # --- a layer that is ONE thing alone (``layer_pattern`` non-empty; empty =
+    # every block above, attention followed by a feed-forward): one letter a
+    # layer, ``M`` a Mamba-2 mixer, ``E`` a feed-forward (the sparse one above),
+    # ``*`` attention (heads, unrotated), each ``x + f(norm(x))`` with one norm.
+    # The Mamba widths: ``mamba_n_heads`` heads of ``mamba_head_dim``, B and C
+    # in ``mamba_n_groups`` groups of ``ssm_state_size``, a causal depthwise
+    # convolution of ``conv_kernel`` taps over x, B and C, the scan in chunks
+    # of ``ssm_chunk_size``; ``time_step_*`` say how a random ``dt_bias`` is
+    # drawn. The recurrent state a row holds a Mamba layer is ``[heads,
+    # head_dim, state]`` float32 beside the convolution's last ``conv_kernel
+    # - 1`` inputs: no pages (``engine/kv_cache.init_state_pool``).
+    layer_pattern: str = ""
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    ssm_chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # The routed experts work in a latent of this width (0: on the model's
+    # own): the layer projects its normed input down once, an expert is TWO
+    # matrices (``act(l U) V``, no gate), the weighted sum is taken in the
+    # latent and projected up once. The router and the shared expert read the
+    # full width.
+    moe_latent_size: int = 0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -159,8 +186,33 @@ class GemmaConfig:
                 "the index (index_n_heads, index_head_dim >= qk_rope_head_dim, index_topk, all "
                 ">= 1) belongs to attention='latent'"
             )
-        if self.activation not in ("gelu_tanh", "silu"):
-            raise ConfigError(f"activation {self.activation!r}: gelu_tanh or silu")
+        if self.activation not in ("gelu_tanh", "silu", "relu2"):
+            raise ConfigError(f"activation {self.activation!r}: gelu_tanh, silu or relu2")
+        if self.hybrid:
+            if set(self.layer_pattern) - set("ME*") or len(self.layer_pattern) != self.n_layers:
+                raise ConfigError("layer_pattern: one of M, E, * for each of n_layers layers")
+            sizes = (self.mamba_n_heads, self.mamba_head_dim, self.mamba_n_groups, self.ssm_state_size)
+            if "M" in self.layer_pattern and (
+                min(sizes) < 1 or self.mamba_n_heads % self.mamba_n_groups or self.conv_kernel < 2
+                or self.mamba_inner % self.mamba_n_groups
+            ):
+                raise ConfigError(
+                    "a Mamba layer needs mamba_n_heads (a multiple of mamba_n_groups), "
+                    "mamba_head_dim, ssm_state_size >= 1 and conv_kernel >= 2"
+                )
+            if "E" not in self.layer_pattern or not (self.n_experts and self.d_shared_expert):
+                raise ConfigError(
+                    "a layer_pattern has an E layer, the sparse feed-forward beside its shared "
+                    "expert (the segment's counters ride on the sparse layers')"
+                )
+            if self.latent or self.layer_types or self.n_dense_layers or self.qk_norm \
+                    or self.attn_gate or self.post_norms or self.rope_full_layers:
+                raise ConfigError(
+                    "a layer_pattern's attention is plain heads, unrotated "
+                    "(rope_full_layers off), with no window, gate, q/k norm or second norm"
+                )
+        elif self.moe_latent_size or self.mamba_n_heads or self.activation == "relu2":
+            raise ConfigError("the Mamba widths, moe_latent_size and relu2 belong to a layer_pattern")
         # A JSON round trip (dataclasses.asdict -> GemmaConfig(**d)) hands a list.
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.layer_types:
@@ -185,6 +237,8 @@ class GemmaConfig:
                     f"experts {self.expert_first}..{self.expert_first + self.n_experts_held} "
                     f"are not among the router's {self.n_experts}"
                 )
+            if self.hybrid and "E" not in self.layer_pattern:
+                raise ConfigError("n_experts needs an E layer in layer_pattern")
             if not 0 <= self.n_dense_layers < self.n_layers:
                 raise ConfigError("n_dense_layers leaves no sparse layer (or is negative)")
             if self.router_scoring not in ("softmax", "sigmoid"):
@@ -213,6 +267,34 @@ class GemmaConfig:
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def hybrid(self) -> bool:
+        """A model whose layers are a mixer OR a feed-forward alone."""
+        return bool(self.layer_pattern)
+
+    @property
+    def n_mamba_layers(self) -> int:
+        return self.layer_pattern.count("M")
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that cache keys and values: the page pools' layer axis."""
+        return self.layer_pattern.count("*") if self.hybrid else self.n_layers
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """What the convolution runs over: x, then B and C of every group."""
+        return self.mamba_inner + 2 * self.mamba_n_groups * self.ssm_state_size
+
+    @property
+    def ssm_slot_bytes(self) -> int:
+        """Bytes of ONE Mamba layer's recurrent state of one row (float32)."""
+        return self.mamba_inner * self.ssm_state_size * 4
 
     @property
     def latent(self) -> bool:
@@ -258,7 +340,7 @@ class GemmaConfig:
             self.kv_lora_rank + self.qk_rope_head_dim if self.latent
             else 2 * self.n_kv_heads * self.head_dim
         )
-        return per_layer * self.n_layers * jnp.dtype(self.dtype).itemsize
+        return per_layer * self.n_attn_layers * jnp.dtype(self.dtype).itemsize
 
     @property
     def index_bytes_per_token(self) -> int:
@@ -287,6 +369,8 @@ class GemmaConfig:
 
     @property
     def n_sparse_layers(self) -> int:
+        if self.hybrid:
+            return self.layer_pattern.count("E")
         return self.n_layers - self.n_dense_layers if self.n_experts else 0
 
     @property
@@ -300,6 +384,7 @@ class GemmaConfig:
                 "activation", "tie_embeddings", "scale_embeddings", "norm_plus_one",
                 "layer_types", "sliding_window", "yarn_factor", "n_experts",
                 "rope_full_layers", "qk_norm", "attn_gate", "post_norms", "attention",
+                "layer_pattern",
             )
         )
 
@@ -367,6 +452,23 @@ class GemmaConfig:
 
     def _count(self, experts: int) -> int:
         D, H, K, hd, F = self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.d_ff
+        if self.hybrid:
+            inner, C, Hm = self.mamba_inner, self.conv_width, self.mamba_n_heads
+            mamba = (
+                D * (inner + C + Hm) + C * (self.conv_kernel + 1)  # w_in, the taps and their bias
+                + 3 * Hm + inner + inner * D + D  # dt_bias, A_log, D_skip, the gated norm, w_out, norm
+            )
+            attn = D * H * hd + 2 * D * K * hd + H * hd * D + D
+            Dl = self.moe_latent_size or D
+            ff = (
+                D + (D + bool(self.router_bias_scale)) * self.n_experts
+                + (2 * D * Dl if self.moe_latent_size else 0)
+                + experts * (2 if self.activation == "relu2" else 3) * Dl * self.d_expert
+                + (2 if self.activation == "relu2" else 3) * D * self.d_shared_expert
+            )
+            layers = self.n_mamba_layers * mamba + self.n_attn_layers * attn + self.n_sparse_layers * ff
+            head = 0 if self.tie_embeddings else D * self.vocab_size
+            return self.vocab_size * D + layers + D + head
         attn = D * H * hd + 2 * D * K * hd + H * hd * D + 2 * D
         if self.latent:
             rq, rkv, dr, dv = self.q_lora_rank, self.kv_lora_rank, self.qk_rope_head_dim, self.v_head_dim

@@ -92,10 +92,11 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         from mcpx.parallel.mesh import param_pspecs
 
         specs = param_pspecs(cfg, mesh)
+        stacks = ("layers", "dense_layers", "mamba_layers", "attn_layers")
         by_name = {
             **specs["layers"],
-            **{k: v for k, v in specs.items() if k not in ("layers", "dense_layers")},
-            **{"dense_layers." + k: v for k, v in specs.get("dense_layers", {}).items()},
+            **{k: v for k, v in specs.items() if k not in stacks},
+            **{s + "." + k: v for s in stacks[1:] for k, v in specs.get(s, {}).items()},
         }
         sharding = lambda name: NamedSharding(mesh, by_name[name])
 
@@ -185,6 +186,8 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
             "w_down": normal("w_down", k_down, (n, F, D), F, stack=stack),
         }
 
+    if cfg.hybrid:
+        return _init_hybrid(cfg, key, normal, sharding, t)
     Ls = cfg.n_sparse_layers
     layers = attention(Ls or L)
     if cfg.n_experts:
@@ -223,12 +226,102 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
     return params
 
 
+def _init_hybrid(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Params:
+    """``init_params`` of a ``layer_pattern`` model: three stacks, a row a
+    layer of its kind in layer order: ``mamba_layers``, ``layers`` (the
+    feed-forward ones: router, latent projections, shared expert, the experts
+    held) and ``attn_layers``; every layer has ONE norm, ``norm``. The
+    Mamba mixer's scalars are drawn as the family initialises them, so that
+    a random stack's states neither vanish nor saturate: ``dt_bias`` the
+    inverse softplus of a step log-uniform in [time_step_min, time_step_max]
+    (floored at time_step_floor), ``A_log = log U[1, 16]``, ``D_skip`` 1, the
+    taps uniform in +-1 / sqrt(K), their bias 0, the gated norm's gain 1."""
+    dtype = jnp.dtype(cfg.dtype)
+    f32 = jnp.float32
+    D, H, K, hd, V = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.vocab_size
+    Lm, La, Ls = cfg.n_mamba_layers, cfg.n_attn_layers, cfg.n_sparse_layers
+    fold = lambda i: jax.random.fold_in(key, 100 + i)
+
+    def ones(name, shape, stack, as_type=dtype):
+        return t(name, jnp.ones(shape, as_type, device=sharding(stack + name)))
+
+    def drawn(name, stack, fn, shape, k):
+        out = jax.jit(fn, static_argnames=("shape",), out_shardings=sharding(stack + name))
+        return t(name, out(k, shape=shape))
+
+    params = {
+        "embed": normal("embed", fold(0), (V, D), D),
+        "final_norm": t("final_norm", jnp.ones((D,), dtype, device=sharding("final_norm"))),
+    }
+    if not cfg.tie_embeddings:
+        params["head"] = normal("head", fold(1), (D, V), D)
+    if Lm:
+        st = "mamba_layers."
+        inner, C, Hm, Kc = cfg.mamba_inner, cfg.conv_width, cfg.mamba_n_heads, cfg.conv_kernel
+        lo, hi, floor = cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor
+
+        def dt_bias(k, shape):
+            u = jax.random.uniform(k, shape, f32)
+            step = jnp.maximum(jnp.exp(u * (math.log(hi) - math.log(lo)) + math.log(lo)), floor)
+            return step + jnp.log(-jnp.expm1(-step))  # softplus^-1(step)
+
+        params["mamba_layers"] = {
+            "norm": ones("norm", (Lm, D), st),
+            "w_in": normal("w_in", fold(2), (Lm, D, inner + C + Hm), D, stack=st),
+            "conv_w": drawn(
+                "conv_w", st,
+                lambda k, shape: jax.random.uniform(k, shape, f32, -1.0, 1.0).astype(dtype) * Kc**-0.5,
+                (Lm, C, Kc), fold(3),
+            ),
+            "conv_b": t("conv_b", jnp.zeros((Lm, C), dtype, device=sharding(st + "conv_b"))),
+            "dt_bias": drawn("dt_bias", st, dt_bias, (Lm, Hm), fold(4)),
+            "A_log": drawn(
+                "A_log", st, lambda k, shape: jnp.log(jax.random.uniform(k, shape, f32, 1.0, 16.0)),
+                (Lm, Hm), fold(5),
+            ),
+            "D_skip": ones("D_skip", (Lm, Hm), st, f32),
+            "gate_norm": ones("gate_norm", (Lm, inner), st),
+            "w_out": normal("w_out", fold(6), (Lm, inner, D), inner, stack=st),
+        }
+    if La:
+        st = "attn_layers."
+        params["attn_layers"] = {
+            "norm": ones("norm", (La, D), st),
+            # Heads merged on the matmul's own axis: a layer's row of the stack
+            # feeds its dot as it lies (a [D, H, hd] row is transposed first).
+            "wq": normal("wq", fold(7), (La, D, H * hd), D, stack=st),
+            "wk": normal("wk", fold(8), (La, D, K * hd), D, stack=st),
+            "wv": normal("wv", fold(9), (La, D, K * hd), D, stack=st),
+            "wo": normal("wo", fold(10), (La, H * hd, D), H * hd, stack=st),
+        }
+    if Ls:
+        E, Fe, Fs = cfg.n_experts_held, cfg.d_expert, cfg.d_shared_expert
+        Dl = cfg.moe_latent_size or D
+        layers = {
+            "norm": ones("norm", (Ls, D), ""),
+            "router": normal("router", fold(11), (Ls, D, cfg.n_experts), D),
+            "shared_up": normal("shared_up", fold(12), (Ls, D, Fs), D),
+            "shared_down": normal("shared_down", fold(13), (Ls, Fs, D), Fs),
+            "w_up": normal("w_up", fold(14), (Ls, E, Dl, Fe), Dl, by_layer=True),
+            "w_down": normal("w_down", fold(15), (Ls, E, Fe, Dl), Fe, by_layer=True),
+        }
+        if cfg.router_bias_scale:
+            layers["router_bias"] = normal(
+                "router_bias", fold(16), (Ls, cfg.n_experts), cfg.router_bias_scale**-2, as_type=f32
+            )
+        if cfg.moe_latent_size:
+            layers["latent_down"] = normal("latent_down", fold(17), (Ls, D, Dl), D)
+            layers["latent_up"] = normal("latent_up", fold(18), (Ls, Dl, D), Dl)
+        params["layers"] = layers
+    return params
+
+
 def init_kv_cache(cfg: GemmaConfig, batch: int, max_len: int, dtype: str | None = None) -> KVCache:
     """The dense cache ``[L, B, S, K, width]``: a head's key and value, or
     under latent attention the shared rotated key (``k``) and the latent
     (``v``), ``GemmaConfig.kv_widths``."""
     d = jnp.dtype(dtype or cfg.dtype)
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads)
+    shape = (cfg.n_attn_layers, batch, max_len, cfg.n_kv_heads)
     k_width, v_width = cfg.kv_widths
     return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
 
@@ -612,6 +705,108 @@ def _layer(
     return x, k_cache, v_cache, stats, chosen
 
 
+# ------------------------------------------- a layer that is one thing alone
+def pattern_rows(cfg: GemmaConfig) -> list[tuple[str, int]]:
+    """``layer_pattern`` as (kind, the layer's row in its kind's stack)."""
+    seen = {"M": 0, "E": 0, "*": 0}
+    rows = []
+    for kind in cfg.layer_pattern:
+        rows.append((kind, seen[kind]))
+        seen[kind] += 1
+    return rows
+
+
+def stack_row(stack: dict, j: int) -> dict:
+    """Row ``j`` of every leaf of a stack (a static slice: the walk over a
+    ``layer_pattern`` is unrolled)."""
+    return {k: v[j] for k, v in stack.items()}
+
+
+def hybrid_attention_inputs(n: jax.Array, lp: dict, cfg: GemmaConfig) -> tuple:
+    """n [B, T, D] -> q [B, T, H, hd], k and v [B, T, K, hd]: no rotation
+    (position reaches this model through its recurrent layers)."""
+    heads = lambda a, n_heads: a.reshape(a.shape[:2] + (n_heads, cfg.head_dim))
+    q = heads(jnp.einsum("btd,de->bte", n, lp["wq"]), cfg.n_heads)
+    k = heads(jnp.einsum("btd,de->bte", n, lp["wk"]), cfg.n_kv_heads)
+    v = heads(jnp.einsum("btd,de->bte", n, lp["wv"]), cfg.n_kv_heads)
+    return q, k, v
+
+
+def hybrid_feed_forward(
+    x: jax.Array, layers: dict, j: int, cfg: GemmaConfig, live, *,
+    use_pallas: bool = False, interpret: bool = False,
+) -> tuple:
+    """An ``E`` layer, ``x + f(norm(x))``: the routed experts held here,
+    which read and write the latent (``latent_down`` before them, ONE
+    ``latent_up`` after their weighted sum) where the model has one, beside
+    the shared expert on the full width. ``layers`` is the whole stack, ``j``
+    the layer's row. -> (x, the layer's expert counters, the experts
+    chosen)."""
+    scanned, experts = split_layers(cfg, layers)
+    lp = stack_row(scanned, j)
+    n = rms_norm(x, lp["norm"], cfg.norm_eps, cfg.norm_plus_one)
+    rows = None
+    if cfg.moe_latent_size:
+        rows = jnp.einsum("btd,dl->btl", n, lp["latent_down"])
+    routed, stats, chosen = moe_forward(
+        n, lp["router"], experts, j, cfg, live, lp.get("router_bias"),
+        use_pallas=use_pallas, interpret=interpret, rows=rows,
+    )
+    if cfg.moe_latent_size:
+        routed = jnp.einsum(
+            "btl,ld->btd", routed.astype(n.dtype), lp["latent_up"], preferred_element_type=jnp.float32
+        )
+    shared = activation(cfg, jnp.einsum("btd,df->btf", n, lp["shared_up"]))
+    shared = jnp.einsum("btf,fd->btd", shared, lp["shared_down"], preferred_element_type=jnp.float32)
+    return _join(x, routed + shared), stats, chosen
+
+
+def _hybrid_forward(
+    params: Params, cfg: GemmaConfig, tokens, seq_lens, kv_cache, mask, logits_at, live,
+    routing: bool, moe_stats: bool,
+) -> tuple:
+    """``forward`` for a ``layer_pattern`` model, from an EMPTY state (the
+    dense prefill; a dense ``decode_step`` has no state to continue from):
+    the cache it returns holds, beside ``k`` and ``v`` [attention layers, ...],
+    ``ssm``: ``(state, tail)`` AT each row's length, a Mamba layer."""
+    from mcpx.models.gemma.ssm import mamba_prefill
+
+    B, T = tokens.shape
+    x = embed_tokens(params, cfg, tokens)
+    stats = moe_stats_init(cfg) if cfg.n_experts else None
+    ks, vs, finals, chosen_all = [], [], [], []
+    for kind, j in pattern_rows(cfg):
+        if kind == "M":
+            lp = stack_row(params["mamba_layers"], j)
+            n = rms_norm(x, lp["norm"], cfg.norm_eps, cfg.norm_plus_one)
+            out, final = mamba_prefill(n, lp, cfg, seq_lens)
+            x = _join(x, out)
+            finals.append(final)
+        elif kind == "E":
+            x, layer_stats, chosen = hybrid_feed_forward(x, params["layers"], j, cfg, live)
+            stats = add_layer_stats(stats, layer_stats)
+            chosen_all.append(chosen)
+        else:
+            lp = stack_row(params["attn_layers"], j)
+            n = rms_norm(x, lp["norm"], cfg.norm_eps, cfg.norm_plus_one)
+            q, k, v = hybrid_attention_inputs(n, lp, cfg)
+            k_c = kv_cache["k"][j].at[:, :T].set(k.astype(kv_cache["k"].dtype))
+            v_c = kv_cache["v"][j].at[:, :T].set(v.astype(kv_cache["v"].dtype))
+            qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+            attn = _attend(qg, k_c, v_c, mask).reshape(B, T, cfg.attn_out_width)
+            x = _join(x, jnp.einsum("btf,fd->btd", attn, lp["wo"]))
+            ks.append(k_c)
+            vs.append(v_c)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    out = output_logits(params, cfg, x), {"k": jnp.stack(ks), "v": jnp.stack(vs), "ssm": finals}
+    if stats is not None:
+        stats = add_forward_stats(cfg, stats, seq_lens, seq_lens)
+    chosen = jnp.stack(chosen_all) if chosen_all else None
+    return out + ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
+
+
 def embed_tokens(params: Params, cfg: GemmaConfig, tokens: jax.Array) -> jax.Array:
     from mcpx.models.gemma.quant import embed_lookup
 
@@ -657,6 +852,13 @@ def forward(
     the forward's expert counters (``moe_stats_init``), before ``routing``'s."""
     from mcpx.models.gemma.quant import dequant_layer
 
+    if cfg.hybrid:
+        if live is None:
+            raise ValueError("a layer_pattern model's dense forward is its prefill (prefill())")
+        seq_lens = jnp.sum(live, axis=1).astype(jnp.int32)
+        return _hybrid_forward(
+            params, cfg, tokens, seq_lens, kv_cache, mask, logits_at, live, routing, moe_stats
+        )
     # Weight-only int8 serving mode (quant.py): identity plumbing on plain
     # params. The quantized leaves stay the HBM-resident buffers — embed
     # rows gather as int8 + per-row scales, and the layer stack dequantizes

@@ -69,9 +69,17 @@ from mcpx.models.gemma.config import GemmaConfig
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
+def expert_leaves(experts: dict) -> tuple[str, ...]:
+    """The expert stacks a layer has, which is a property of the stacks: the
+    three of a gated expert, or the two of one with no gate (``act(x U) V``)."""
+    return tuple(k for k in EXPERT_LEAVES if k in experts)
+
+
 def activation(cfg: GemmaConfig, x: jax.Array) -> jax.Array:
     if cfg.activation == "silu":
         return jax.nn.silu(x)
+    if cfg.activation == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.gelu(x, approximate=True)
 
 
@@ -81,7 +89,7 @@ def split_layers(cfg: GemmaConfig, layers: dict) -> tuple[dict, dict]:
         return layers, {}
     return (
         {k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
-        {k: layers[k] for k in EXPERT_LEAVES},
+        {k: layers[k] for k in expert_leaves(layers)},
     )
 
 
@@ -90,7 +98,7 @@ def forward_weight_bytes(cfg: GemmaConfig, params: dict) -> tuple[int, int]:
     other leaf a forward reads whole: attention, routers, shared experts,
     dense layers, gains, the head). The embedding table is among them only
     where it is the head too; untied, a forward gathers a few of its rows."""
-    stacks = [params["layers"][k] for k in EXPERT_LEAVES]
+    stacks = [params["layers"][k] for k in expert_leaves(params["layers"])]
     n_experts = stacks[0].shape[0] * stacks[0].shape[1]
     routed = sum(a.nbytes for a in stacks)
     rest = sum(a.nbytes for a in jax.tree.leaves(params)) - routed
@@ -140,6 +148,12 @@ INDEX_STATS = 2
 # What a forward of a latent block counts last.
 LATENT_STATS = 1
 
+# What a forward of a model with recurrent layers counts last: its Mamba
+# layers' calls on live rows, the live window slots those calls computed, and
+# the tokens the state was advanced by (a prefill's all stay; a decode
+# window's are known after its verify, and the segment adds them).
+SSM_STATS = 3
+
 
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     """The counters a forward adds to: tokens per held expert ``[E_held]``
@@ -156,8 +170,12 @@ def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     attended after the selection, and the keys the index scored (a call whose
     row holds no more than ``index_topk`` tokens scores none). A latent block
     counts one more, last: the query slots its paged attention calls
-    multiplied (``kernels/paged_attention.latent_query_slots``), over layers."""
-    more = (INDEX_STATS if cfg.index_topk else 0) + (LATENT_STATS if cfg.latent else 0)
+    multiplied (``kernels/paged_attention.latent_query_slots``), over layers.
+    A model with recurrent layers counts ``SSM_STATS`` more, last."""
+    more = (
+        (INDEX_STATS if cfg.index_topk else 0) + (LATENT_STATS if cfg.latent else 0)
+        + (SSM_STATS if cfg.hybrid else 0)
+    )
     return jnp.zeros((cfg.n_experts_held + LAYER_STATS + FORWARD_STATS + more,), jnp.int32)
 
 
@@ -175,12 +193,13 @@ def add_forward_stats(
     row's attention read through, ``q_lens`` [B] its live tokens (0: an idle
     row, which reads nothing and routes nothing), ``window`` the slots of
     the window its attention read the pages through (None: a dense prefill,
-    which reads no page)."""
+    which reads no page, and whose every live token stays in a recurrent
+    state)."""
     live = q_lens > 0
     own = [
         jnp.sum(q_lens) * (cfg.n_experts_per_tok * cfg.n_sparse_layers),
-        jnp.sum(jnp.where(live, context, 0)) * cfg.n_layers,
-        jnp.sum(live) * cfg.n_layers,
+        jnp.sum(jnp.where(live, context, 0)) * cfg.n_attn_layers,
+        jnp.sum(live) * cfg.n_attn_layers,
     ]
     if cfg.index_topk:
         k = cfg.index_topk
@@ -193,6 +212,9 @@ def add_forward_stats(
 
         slots = 0 if window is None else latent_query_slots(q_lens, window, cfg.n_heads)
         own.append(jnp.asarray(slots) * cfg.n_layers)
+    if cfg.hybrid:
+        slots = jnp.sum(q_lens) * cfg.n_mamba_layers
+        own += [jnp.sum(live) * cfg.n_mamba_layers, slots, slots if window is None else 0 * slots]
     return stats.at[-len(own) :].add(jnp.stack(own).astype(jnp.int32))
 
 
@@ -209,12 +231,16 @@ GROUP_TILE = 64
 
 def _expert_rows(cfg: GemmaConfig, rows: jax.Array, experts: dict, layer, e) -> jax.Array:
     """rows [R, D] through expert ``e`` of sparse layer ``layer``, float32
-    [R, D]: gate and up in the rows' type, down accumulated in float32."""
+    [R, D]: gate and up in the rows' type, down accumulated in float32. An
+    expert with no gate (no ``w_gate`` stack) is ``act(rows up) down``."""
     D, F = rows.shape[1], cfg.d_expert
-    w_gate = lax.dynamic_slice(experts["w_gate"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
     w_up = lax.dynamic_slice(experts["w_up"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
     w_down = lax.dynamic_slice(experts["w_down"], (layer, e, 0, 0), (1, 1, F, D))[0, 0]
-    a = activation(cfg, jnp.einsum("td,df->tf", rows, w_gate)) * jnp.einsum("td,df->tf", rows, w_up)
+    if "w_gate" in experts:
+        w_gate = lax.dynamic_slice(experts["w_gate"], (layer, e, 0, 0), (1, 1, D, F))[0, 0]
+        a = activation(cfg, jnp.einsum("td,df->tf", rows, w_gate)) * jnp.einsum("td,df->tf", rows, w_up)
+    else:
+        a = activation(cfg, jnp.einsum("td,df->tf", rows, w_up))
     return jnp.einsum("tf,fd->td", a, w_down, preferred_element_type=jnp.float32)
 
 
@@ -256,7 +282,7 @@ def _grouped_experts(
 
 
 def moe_forward(
-    h: jax.Array,  # [B, S, D]
+    h: jax.Array,  # [B, S, D]; routed on, and multiplied unless ``rows`` is given
     router: jax.Array,  # [D, E] this layer's
     experts: dict,  # w_gate / w_up [L, E_held, D, F], w_down [L, E_held, F, D]
     layer: jax.Array,
@@ -266,15 +292,18 @@ def moe_forward(
     *,
     use_pallas: bool = False,  # a window at or under the ridge: the kernel, not the loop
     interpret: bool = False,
+    rows: "jax.Array | None" = None,  # [B, S, Dl]: what the experts multiply (a latent of h)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """-> (this device's routed experts' part of the layer's output [B, S,
     D] as accumulated, float32, the counters of ``moe_stats_init`` for this layer, the experts
     chosen [B, S, k]). ``layer`` indexes the expert stacks: the SPARSE
-    layer's number."""
-    B, S, D = h.shape
+    layer's number. With ``rows`` the router reads ``h`` and the experts
+    read and write ``rows``' width: the output is [B, S, Dl]."""
+    B, S, _ = h.shape
     T, E, k = B * S, cfg.n_experts_held, cfg.n_experts_per_tok
-    x = h.reshape(T, D)
-    chosen, w = route(x, router, cfg, bias)
+    chosen, w = route(h.reshape(T, -1), router, cfg, bias)
+    D = h.shape[-1] if rows is None else rows.shape[-1]
+    x = (h if rows is None else rows).reshape(T, D)
     local = chosen - cfg.expert_first
     here = (local >= 0) & (local < E)
     if live is not None:
@@ -294,7 +323,7 @@ def moe_forward(
             from mcpx.engine.kernels.routed_experts import routed_experts
 
             out = routed_experts(
-                x, combine, *(experts[k] for k in EXPERT_LEAVES), order, n_touched, layer,
+                x, combine, *(experts.get(k) for k in EXPERT_LEAVES), order, n_touched, layer,
                 act=functools.partial(activation, cfg), interpret=interpret,
             )
             kernel_steps = n_touched

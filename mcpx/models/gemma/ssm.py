@@ -1,0 +1,267 @@
+"""The Mamba-2 mixer of a ``layer_pattern`` model (``GemmaConfig.hybrid``).
+
+One layer, on its normed input ``n`` [B, T, D] (H heads of P, G groups of N
+state, ``inner = H P``, ``C = inner + 2 G N``):
+
+  [z | xBC | dt] = n W_in                    widths inner | C | H
+  xBC   = silu(conv(xBC))                    causal, depthwise, K taps, bias
+  x, B, C = xBC split [H, P] | [G, N] | [G, N];  head h reads group h // (H/G)
+  dt    = softplus(dt + dt_bias)             float32; A = -exp(A_log), a head
+  h_t   = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t        state [N, H, P] float32
+  y_t   = h_t C_t + D_skip x_t
+  out   = RMSNorm_groups(y * silu(z)) W_out  the mean square over a group's
+                                             inner / G values, one gain of inner
+
+What a row keeps between forwards is ``h`` and the convolution's last K - 1
+inputs (its TAIL). The recurrence is computed a CHUNK at a time
+(``chunk_outputs`` / ``advance_state``: the state-space duality form): inside a chunk of T tokens
+``y_t = exp(L_t) h_0 C_t + sum_{s<=t} (C_t . B_s) exp(L_t - L_s) dt_s x_s``
+with ``L`` the running sum of ``dt A``, and the chunk hands on ``h_T =
+exp(L_T) h_0 + sum_s exp(L_T - L_s) dt_s x_s (x) B_s``. A position whose
+``dt`` is 0 decays nothing and adds nothing: that is how a pad slot, a dead
+window slot and a rejected proposal leave the state alone.
+
+The dense prefill scans chunks of ``ssm_chunk_size`` from a zero state
+(``mamba_prefill``). The paged forward's window (``mamba_window``) is ONE
+chunk from the row's stored state, and it does not commit: a decode window is
+``[cur, proposals]`` and how many of its tokens the row keeps is decided
+AFTER the forward, so a forward leaves its window's small tensors (``dt``,
+the convolution's inputs and outputs) in the state pool as PENDING, the
+caller writes how many tokens of it were kept (``state["n"]``), and the NEXT
+forward's read of the state applies exactly those (``1 + accepted``) before
+it computes anything: the state is read once and written once a layer a
+forward, and never holds a rejected token.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mcpx.models.gemma.config import GemmaConfig
+
+HIGHEST = lax.Precision.HIGHEST
+
+
+def split_xbc(xbc: jax.Array, cfg: GemmaConfig) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """[..., C] -> x [..., H, P], B and C [..., G, N]."""
+    inner, G, N = cfg.mamba_inner, cfg.mamba_n_groups, cfg.ssm_state_size
+    lead = xbc.shape[:-1]
+    x = xbc[..., :inner].reshape(lead + (cfg.mamba_n_heads, cfg.mamba_head_dim))
+    b = xbc[..., inner : inner + G * N].reshape(lead + (G, N))
+    c = xbc[..., inner + G * N :].reshape(lead + (G, N))
+    return x, b, c
+
+
+def mamba_inputs(n: jax.Array, lp: dict, cfg: GemmaConfig) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """n [B, T, D] -> z [B, T, inner], the convolution's input [B, T, C]
+    (both the activations' type) and dt [B, T, H] float32 after its
+    softplus."""
+    inner, C = cfg.mamba_inner, cfg.conv_width
+    zxd = jnp.einsum("btd,de->bte", n, lp["w_in"], preferred_element_type=jnp.float32)
+    dt = jax.nn.softplus(zxd[..., inner + C :] + lp["dt_bias"].astype(jnp.float32))
+    return zxd[..., :inner].astype(n.dtype), zxd[..., inner : inner + C].astype(n.dtype), dt
+
+
+def causal_conv(pre: jax.Array, tail: jax.Array, lp: dict, dtype) -> jax.Array:
+    """``silu(conv(.))`` of the inputs ``pre`` [B, T, C] that follow ``tail``
+    [B, K - 1, C]: output t is taps over inputs t - K + 1 .. t. Computed in
+    float32, rounded to ``dtype`` once."""
+    K = tail.shape[1] + 1
+    T = pre.shape[1]
+    full = jnp.concatenate([tail, pre], axis=1).astype(jnp.float32)
+    w = lp["conv_w"].astype(jnp.float32)  # [C, K]
+    out = lp["conv_b"].astype(jnp.float32)
+    for k in range(K):
+        out = out + full[:, k : k + T] * w[:, k]
+    return jax.nn.silu(out).astype(dtype)
+
+
+def tail_at(pre: jax.Array, tail: jax.Array, n: jax.Array) -> jax.Array:
+    """The convolution's tail after ``n`` [B] of the inputs ``pre`` that
+    follow ``tail``: the K - 1 inputs before position n."""
+    K1 = tail.shape[1]
+    full = jnp.concatenate([tail, pre.astype(tail.dtype)], axis=1)
+    idx = n[:, None] + jnp.arange(K1, dtype=n.dtype)[None, :]
+    return jnp.take_along_axis(full, idx[:, :, None], axis=1)
+
+
+def _log_decay(dt: jax.Array, lp: dict) -> jax.Array:
+    """dt [B, T, H] float32 -> the running sum of ``dt A`` [B, T, H]."""
+    a = -jnp.exp(lp["A_log"].astype(jnp.float32))
+    return jnp.cumsum(dt * a, axis=1)
+
+
+def commit_terms(dt: jax.Array, x: jax.Array, lp: dict) -> tuple[jax.Array, jax.Array]:
+    """What moves a state over a chunk whose dead positions have ``dt`` 0:
+    ``total`` [B, H] = exp(L_T), the old state's factor, and ``xs`` [B, T, H,
+    P] float32 = exp(L_T - L_s) dt_s x_s, so that ``h_T = total h_0 + sum_s
+    xs_s (x) B_s``."""
+    L = _log_decay(dt, lp)
+    scale = jnp.exp(L[:, -1:, :] - L) * dt  # [B, T, H]
+    return jnp.exp(L[:, -1]), x.astype(jnp.float32) * scale[..., None]
+
+
+def advance_state(h: jax.Array, total: jax.Array, xs: jax.Array, b: jax.Array) -> jax.Array:
+    """h [B, N, H, P] float32 (the state's N before the heads: the pool's
+    layout, heads x head_dim on the lanes) moved by ``commit_terms``' chunk:
+    B [B, T, G, N]."""
+    Bsz, T, H, P = xs.shape
+    G = b.shape[2]
+    xg = xs.reshape(Bsz, T, G, H // G, P)
+    add = jnp.einsum("btgn,btgrp->bngrp", b.astype(jnp.float32), xg, precision=HIGHEST)
+    return total[:, None, :, None] * h + add.reshape(h.shape)
+
+
+def state_outputs(h: jax.Array, c: jax.Array) -> jax.Array:
+    """``h C_t`` of every position: h [B, N, H, P], C [B, T, G, N] -> [B, T,
+    H, P] float32, before the position's own decay."""
+    Bsz, N, H, P = h.shape
+    G = c.shape[2]
+    hg = h.reshape(Bsz, N, G, H // G, P)
+    out = jnp.einsum("btgn,bngrp->btgrp", c.astype(jnp.float32), hg, precision=HIGHEST)
+    return out.reshape(Bsz, c.shape[1], H, P)
+
+
+def chunk_outputs(
+    hc: jax.Array, dt: jax.Array, x: jax.Array, b: jax.Array, c: jax.Array, lp: dict
+) -> jax.Array:
+    """A chunk's ``y`` [B, T, H, P] float32 from ``hc = state_outputs(h_0,
+    C)``: the old state's part decayed to each position, the chunk's own
+    tokens' part (the quadratic form) and the skip."""
+    Bsz, T, H, P = x.shape
+    G = b.shape[2]
+    f32 = jnp.float32
+    L = _log_decay(dt, lp)  # [B, T, H]
+    # exp(L_t - L_s) for s <= t, 0 above the diagonal (masked before the exp:
+    # L_t - L_s is positive there and may overflow).
+    diff = L[:, :, None, :] - L[:, None, :, :]  # [B, t, s, H]
+    causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])[None, :, :, None]
+    decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    cb = jnp.einsum("btgn,bsgn->btsg", c, b, preferred_element_type=f32)  # [B, t, s, G]
+    m = decay.reshape(Bsz, T, T, G, H // G) * cb[..., None] * dt.reshape(Bsz, 1, T, G, H // G)
+    xg = x.reshape(Bsz, T, G, H // G, P)
+    intra = jnp.einsum("btsgr,bsgrp->btgrp", m.astype(x.dtype), xg, preferred_element_type=f32)
+    skip = x.astype(f32) * lp["D_skip"].astype(f32)[:, None]
+    return jnp.exp(L)[..., None] * hc + intra.reshape(Bsz, T, H, P) + skip
+
+
+def gated_out(y: jax.Array, z: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
+    """y [B, T, H, P] float32, z [B, T, inner] -> the mixer's output [B, T, D]
+    as accumulated (float32): the gate, then the norm over each group."""
+    Bsz, T = z.shape[:2]
+    G = cfg.mamba_n_groups
+    g = y.reshape(Bsz, T, -1) * jax.nn.silu(z.astype(jnp.float32))
+    gg = g.reshape(Bsz, T, G, -1)
+    gg = gg * lax.rsqrt(jnp.mean(jnp.square(gg), axis=-1, keepdims=True) + cfg.norm_eps)
+    g = (gg.reshape(Bsz, T, -1) * lp["gate_norm"].astype(jnp.float32)).astype(z.dtype)
+    return jnp.einsum("bte,ed->btd", g, lp["w_out"], preferred_element_type=jnp.float32)
+
+
+def ssd_scan(
+    h0: jax.Array, dt: jax.Array, x: jax.Array, b: jax.Array, c: jax.Array, lp: dict, chunk: int
+) -> tuple[jax.Array, jax.Array]:
+    """The recurrence over T positions from ``h0``, a chunk at a time under a
+    ``lax.scan`` that carries the state -> (y [B, T, H, P] float32, h_T)."""
+    T = x.shape[1]
+    if T <= chunk:
+        y = chunk_outputs(state_outputs(h0, c), dt, x, b, c, lp)
+        return y, advance_state(h0, *commit_terms(dt, x, lp), b)
+    n = -(-T // chunk)
+
+    def chunks(a):  # [B, T, ...] -> [n, B, chunk, ...]; a position past T has dt 0: it is none
+        a = jnp.pad(a, ((0, 0), (0, n * chunk - T)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape(a.shape[0], n, chunk, *a.shape[2:]), 1, 0)
+
+    def one(h, xs):
+        dt_c, x_c, b_c, c_c = xs
+        y = chunk_outputs(state_outputs(h, c_c), dt_c, x_c, b_c, c_c, lp)
+        return advance_state(h, *commit_terms(dt_c, x_c, lp), b_c), y
+
+    h, ys = lax.scan(one, h0, (chunks(dt), chunks(x), chunks(b), chunks(c)))
+    return jnp.moveaxis(ys, 0, 1).reshape(x.shape[0], n * chunk, *x.shape[2:])[:, :T], h
+
+
+def mamba_prefill(n: jax.Array, lp: dict, cfg: GemmaConfig, seq_lens: jax.Array) -> tuple:
+    """The mixer over a padded prompt from an empty state: n [B, T, D] ->
+    (its output [B, T, D] float32, (the state AT ``seq_lens`` [B, N, H, P], the
+    tail AT ``seq_lens`` [B, K - 1, C])). A pad position has ``dt`` 0."""
+    Bsz, T, _ = n.shape
+    z, pre, dt = mamba_inputs(n, lp, cfg)
+    dt = jnp.where(jnp.arange(T)[None, :, None] < seq_lens[:, None, None], dt, 0.0)
+    tail0 = jnp.zeros((Bsz, cfg.conv_kernel - 1, cfg.conv_width), n.dtype)
+    x, b, c = split_xbc(causal_conv(pre, tail0, lp, n.dtype), cfg)
+    h0 = jnp.zeros((Bsz, cfg.ssm_state_size, cfg.mamba_n_heads, cfg.mamba_head_dim), jnp.float32)
+    y, h = ssd_scan(h0, dt, x, b, c, lp, cfg.ssm_chunk_size)
+    return gated_out(y, z, lp, cfg), (h, tail_at(pre, tail0, seq_lens))
+
+
+def mamba_window(
+    n: jax.Array,  # [B, S, D] the window's normed input
+    lp: dict,
+    cfg: GemmaConfig,
+    ssm: jax.Array,  # the state pool's recurrent states [layers, slots, N, H P]: kv_cache.init_state_pool
+    layer: int,  # which of them this layer's are
+    state: dict,  # this layer's small arrays of the pool, [slots, ...]
+    slots: jax.Array,  # [B] each row's slot
+    q_lens: jax.Array,  # [B] live window slots (0: an idle row, which changes nothing)
+    kept: jax.Array,  # [B] tokens of the PENDING window the row kept (state["n"][slots])
+    *,
+    kernel=None,  # engine/kernels/ssm.ssm_window, or None: the same in jnp
+) -> tuple[jax.Array, dict]:
+    """One paged forward's window of a Mamba layer -> (its output [B, S, D]
+    float32, the states with this layer's moved, the layer's new small
+    arrays). First the pending window's
+    ``kept`` tokens are applied to the stored state and tail; the window's
+    ``y`` is computed from that; then the window stays PENDING (the caller
+    says later how much of it was kept). An idle row's slot is not written.
+    A window wider than the pool's pending width has no route: nothing
+    serves a recurrent layer a suffix prefill (its rows prefill whole)."""
+    Bsz, S, _ = n.shape
+    W = state["dt"].shape[1]
+    if S > W:
+        raise ValueError(f"a window of {S} slots, the state pool keeps {W} pending")
+    f32 = jnp.float32
+    # A row whose slot is out of range (a cohort's padding row) owns no state:
+    # it is idle here, whatever its tokens.
+    n_slots = ssm.shape[1]
+    q_lens = jnp.where(slots < n_slots, q_lens, 0)
+    slots = jnp.minimum(slots, n_slots - 1)
+    live = q_lens > 0
+    in_window = jnp.arange(S)[None, :] < q_lens[:, None]
+    # --- what the row left pending, masked to what it kept
+    p_dt = jnp.where(jnp.arange(W)[None, :, None] < kept[:, None, None], state["dt"][slots], 0.0)
+    p_x, p_b, _ = split_xbc(state["post"][slots], cfg)
+    total, xs = commit_terms(p_dt, p_x, lp)
+    tail = tail_at(state["pre"][slots], state["conv"][slots], kept)
+    # --- this window
+    z, pre, dt = mamba_inputs(n, lp, cfg)
+    dt = jnp.where(in_window[:, :, None], dt, 0.0)
+    post = causal_conv(pre, tail, lp, n.dtype)
+    x, b, c = split_xbc(post, cfg)
+    at = jnp.where(live, slots, n_slots)  # an idle row's slot is written nowhere
+    H, P = cfg.mamba_n_heads, cfg.mamba_head_dim
+    N = cfg.ssm_state_size
+    if kernel is None:
+        # (the pool holds heads x head_dim merged)
+        h = advance_state(ssm[layer, slots].reshape(Bsz, N, H, P), total, xs, p_b)
+        hc = state_outputs(h, c)
+        ssm = ssm.at[layer, at].set(h.reshape(Bsz, N, H * P), mode="drop")
+    else:
+        # A head's factor repeated over its lanes, B and C a group's matrix.
+        ssm, hc = kernel(
+            ssm, layer, slots, q_lens,
+            jnp.repeat(total, P, axis=1), xs.reshape(Bsz, W, H * P),
+            jnp.transpose(p_b.astype(f32), (0, 2, 3, 1)), jnp.transpose(c.astype(f32), (0, 2, 1, 3)),
+        )
+        hc = hc.reshape(Bsz, S, H, P)
+    y = chunk_outputs(hc, dt, x, b, c, lp)
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, W - S)) + ((0, 0),) * (a.ndim - 2))
+    new = {
+        "conv": state["conv"].at[at].set(tail, mode="drop"),
+        "dt": state["dt"].at[at].set(pad(dt), mode="drop"),
+        "pre": state["pre"].at[at].set(pad(pre), mode="drop"),
+        "post": state["post"].at[at].set(pad(post), mode="drop"),
+    }
+    return gated_out(y, z, lp, cfg), ssm, new

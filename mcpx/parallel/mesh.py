@@ -106,6 +106,8 @@ def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
     """PartitionSpec pytree matching ``init_params`` output."""
     m = lambda dim: _axis(mesh, MODEL_AXIS, dim)
     whole = lambda rank: P(*(None,) * rank)
+    if cfg.hybrid:
+        return _hybrid_pspecs(cfg, m, whole)
     attention = {
         "pre_attn_norm": P(None, None),
         "pre_mlp_norm": P(None, None),
@@ -160,6 +162,43 @@ def param_pspecs(cfg: GemmaConfig, mesh: Mesh) -> dict[str, Any]:
             specs["layers"].update(shared_gate=whole(3), shared_up=whole(3), shared_down=whole(3))
         if cfg.n_dense_layers:
             specs["dense_layers"] = {**attention, **dense_ff}
+    if not cfg.tie_embeddings:
+        specs["head"] = P(None, m(cfg.vocab_size))
+    return specs
+
+
+def _hybrid_pspecs(cfg: GemmaConfig, m, whole) -> dict[str, Any]:
+    """``param_pspecs`` of a ``layer_pattern`` model: three stacks. The
+    attention's heads split over ``model`` as everywhere; the Mamba leaves,
+    the router, the latent projections, the shared expert and the experts
+    held stay whole on every device (the cut's deployment: data-parallel
+    mixers, and which experts a device holds is the configuration's)."""
+    specs = {
+        "embed": P(m(cfg.vocab_size), None),
+        "final_norm": P(None),
+        "mamba_layers": {
+            "norm": whole(2), "w_in": whole(3), "conv_w": whole(3), "conv_b": whole(2),
+            "dt_bias": whole(2), "A_log": whole(2), "D_skip": whole(2), "gate_norm": whole(2),
+            "w_out": whole(3),
+        },
+        "attn_layers": {
+            "norm": whole(2),
+            # heads merged with head_dim, head-major: a split of the merged
+            # axis is a split by head where the heads divide
+            "wq": P(None, None, m(cfg.n_heads)),
+            "wk": P(None, None, m(cfg.n_kv_heads)),
+            "wv": P(None, None, m(cfg.n_kv_heads)),
+            "wo": P(None, m(cfg.n_heads), None),
+        },
+        "layers": {
+            "norm": whole(2), "router": whole(3), "shared_up": whole(3), "shared_down": whole(3),
+            "w_up": whole(4), "w_down": whole(4),
+        },
+    }
+    if cfg.router_bias_scale:
+        specs["layers"]["router_bias"] = whole(2)
+    if cfg.moe_latent_size:
+        specs["layers"].update(latent_down=whole(3), latent_up=whole(3))
     if not cfg.tie_embeddings:
         specs["head"] = P(None, m(cfg.vocab_size))
     return specs

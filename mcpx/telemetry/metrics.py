@@ -354,6 +354,15 @@ class Metrics:
             ["device"],
             registry=self.registry,
         )
+        self.prefix_state = Counter(
+            "mcpx_engine_prefix_state_total",
+            "Admissions of a model with recurrent layers by what the radix "
+            "tree could give them of the STATE a hit needs at its boundary: "
+            "miss (pages resident, no node holds a state: the row prefilled "
+            "whole) is the one event there is",
+            ["event"],
+            registry=self.registry,
+        )
         self.moe_expert_tokens = Counter(
             "mcpx_engine_moe_expert_tokens_total",
             "Live tokens routed to each expert this engine holds, summed over "

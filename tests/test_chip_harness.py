@@ -85,7 +85,8 @@ CELL = "olmo2-1b.distinct-closed"
 # Readers that need a device profile, allocator statistics or the load
 # generator's own clock: nothing a served program on the CPU can feed.
 NOT_FED_HERE = {"device_op_share", "device_idle_share", "memory_in_use", "endpoint_spread",
-                "client_quantile", "mla_roofline", "index_roofline", "selected_roofline"}
+                "client_quantile", "mla_roofline", "index_roofline", "selected_roofline",
+                "ssm_state_roofline", "routed_experts_roofline"}
 LABELLED_SAMPLE = 'mcpx_engine_compiles_total{executable="admit"}'
 
 
@@ -115,11 +116,16 @@ LATENT_CELL = "a.x-k1.wide-shortlist-closed"
 # catalogue head longer than a prefill bucket (PR 44).
 INDEX_CELL = "deepseek-v3.2-exp.catalogue-closed"
 
+# The cell whose layers are a mixer OR a feed-forward alone and whose rows keep
+# a recurrent state beside the pages (PR 48).
+STATE_CELL = "nemotron-3-super.distinct-closed"
+
 FED = _fed_in(CELL)
 FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
 FED_MIXED = [m for m in _fed_in(MIXED_CELL) if m not in FED]
 FED_LATENT = [m for m in _fed_in(LATENT_CELL) if m not in FED + FED_MIXED]
 FED_INDEX = [m for m in _fed_in(INDEX_CELL) if m not in FED + FED_MIXED + FED_LATENT]
+FED_STATE = [m for m in _fed_in(STATE_CELL) if m not in FED]
 
 
 @pytest.fixture(scope="module")
@@ -150,6 +156,11 @@ def served_index(tmp_path_factory):
     # catalogue of ~800 tokens, past the rehearsal block's 256-token buckets,
     # so the head is built in chunks) with a warm-up the CPU can afford.
     return _serve(INDEX_CELL, tmp_path_factory, warmup_max_len=256, shortlist_top_k=1000)
+
+
+@pytest.fixture(scope="module")
+def served_state(tmp_path_factory):
+    return _serve(STATE_CELL, tmp_path_factory)
 
 
 def _serve(cell_name, tmp_path_factory, warmup_max_len=None, shortlist_top_k=None):
@@ -390,7 +401,7 @@ def test_the_experts_kernels_name_is_what_its_metric_selects():
     from mcpx.engine.kernels.routed_experts import routed_experts
 
     regex = {m["name"]: m["args"]["regex"] for m in METRICS if m["reader"] == "device_op_share"}
-    assert _CELLS_OF["kernel.moe_busy_share"] == [SPARSE_CELL, MIXED_CELL, LATENT_CELL]
+    assert _CELLS_OF["kernel.moe_busy_share"] == [SPARSE_CELL, MIXED_CELL, LATENT_CELL, STATE_CELL]
     bf, S = jnp.bfloat16, jax.ShapeDtypeStruct
     shapes = (
         S((64, 256), bf), S((64, 8), jnp.float32), S((2, 8, 256, 256), bf), S((2, 8, 256, 256), bf),
@@ -555,6 +566,87 @@ def test_the_index_blocks_attributes_count_the_selection_and_the_heads_chunks(se
     spec = sys.modules["spec"]
     cfg = spec.load_block("dsa", CHIP_DIR).rehearsal_config(3072)
     assert served_index["costs"]["model"]["params_held"] == cfg.n_params
+
+
+@pytest.mark.parametrize("metric", FED_STATE, ids=[m["name"] for m in FED_STATE])
+def test_the_state_block_feeds_its_metrics(served_state, metric):
+    """Its own metric, and the sparse cells' that list it too: its expert
+    layers write what every sparse block's do."""
+    assert {m["name"] for m in FED_STATE} == {
+        "ssm.state_bytes_share", "engine.prefix_state_miss_share", "moe.experts_touched_share", "moe.tok_per_touched_expert",
+        "moe.held_assignment_share", "moe.load_max_over_mean", "moe.touched_per_sparse_layer",
+        "moe.prefill_rows_per_assignment", "moe.routed_bytes_share"}
+    v = served_state["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v)
+    if metric["name"] == "engine.prefix_state_miss_share":
+        assert v == 0.0  # the cell's prompts differ from their first page on: no row finds pages resident
+    elif metric["unit"] == "ratio" and metric["name"] != "moe.load_max_over_mean":
+        assert 0 < v < 1
+
+
+def test_the_state_blocks_attributes_count_calls_slots_and_what_was_kept(served_state):
+    """At the rehearsal size: 5 Mamba layers among 11, a state of 16 heads x
+    32 x 32 float32 a row a layer. Every new span attribute, counter and
+    ``pallas.paths`` entry the cell's five new metrics read."""
+    spec = sys.modules["spec"]
+    cfg = spec.load_block("nemotron_h", CHIP_DIR).rehearsal_config(3072)
+    assert (cfg.n_mamba_layers, cfg.n_sparse_layers, cfg.n_attn_layers) == (5, 5, 1)
+    segments = _segments(served_state)
+    assert segments
+    for sp in segments:
+        a = sp["attrs"]
+        assert a["ssm_row_calls"] % 5 == 0 and 0 < a["ssm_row_calls"] <= a["forwards"] * 8 * 5
+        assert a["ssm_state_bytes"] == a["ssm_row_calls"] * cfg.ssm_slot_bytes * 2
+        assert a["ssm_row_calls"] <= a["ssm_tokens"] <= a["ssm_slots"] <= a["ssm_row_calls"] * 8
+        assert a["attn_row_calls"] * 5 == a["ssm_row_calls"]  # ONE attention layer
+        assert a["moe_layer_forwards"] == a["forwards"] * 5
+        assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * cfg.kv_bytes_per_token
+        assert "ssm_prefill_tokens" in a
+    once = _segments_once(served_state)
+    profile = served_state["health"]["engine_queue"]["worker_profile"]
+    for attr in ("ssm_row_calls", "ssm_state_bytes", "ssm_slots", "ssm_tokens", "ssm_prefill_tokens"):
+        assert profile[attr] >= sum(sp["attrs"][attr] for sp in once) > 0, attr
+    # every admitted prompt's tokens went through each Mamba layer once
+    prefills = [sp for tr in served_state["ev"].traces for sp in tr.get("tree", []) if sp["name"] == "engine.prefill"]
+    assert prefills and all(sp["attrs"]["ssm_prefill_tokens"] % 5 == 0 and sp["attrs"]["ssm_prefill_tokens"] > 0
+                            for sp in prefills)
+    # pages found resident by a row that prefilled whole all the same (no radix node
+    # holds a state): the lifetime sum and the counter agree, and the suffix route never ran
+    assert {k for k in profile if k.startswith("prefix_state_")} == {"prefix_state_miss"}
+    metrics = served_state["ev"].counters_after["/metrics"]
+    assert metrics['mcpx_engine_prefix_state_total{event="miss"}'] == profile["prefix_state_miss"] >= 0
+    assert served_state["paths"]["prefill"]["dispatches"] == 0
+    # the kernel paths the cell's ``correct`` asks for
+    assert served_state["kernel_paths"] == {"decode": 1, "prefill": 0, "ssm": 1}
+    ssm = served_state["paths"]["ssm"]
+    assert ssm["engaged"] is True and ssm["dispatches"] == served_state["paths"]["decode"]["dispatches"] > 0
+    model = served_state["costs"]["model"]
+    assert model["params_held"] == cfg.n_params
+    # a token reads 3 of the 8 experts held, of two matrices in the latent, in 5 layers
+    assert model["params_held"] - model["params_active_per_token"] == 5 * 5 * 2 * cfg.moe_latent_size * cfg.d_expert
+
+
+def test_the_state_kernels_name_is_what_its_metrics_select():
+    """``kernel.ssm_busy_share`` and ``kernel.ssm_window_roofline`` find the
+    state pool's kernel by the name Mosaic gives its op, and no other kernel's
+    metric does."""
+    import jax.numpy as jnp
+
+    sys.path.insert(0, REPO)
+    from mcpx.engine.kernels.ssm import ssm_window
+
+    regex = {m["name"]: m["args"]["regex"] for m in METRICS if "regex" in m["args"]}
+    f32, i32 = jnp.float32, jnp.int32
+    sd = jax.ShapeDtypeStruct
+    text = jax.jit(ssm_window, static_argnums=1).trace(
+        sd((2, 8, 128, 1024), f32), 1, sd((4,), i32), sd((4,), i32), sd((4, 1024), f32), sd((4, 8, 1024), f32),
+        sd((4, 2, 128, 8), f32), sd((4, 2, 8, 128), f32)).lower(lowering_platforms=("tpu",)).as_text()
+    (name,) = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert re.search(regex["kernel.ssm_busy_share"], name) and re.search(regex["kernel.ssm_window_roofline"], name)
+    for other in ("kernel.attn_busy_share", "kernel.moe_busy_share", "kernel.mla_busy_share",
+                  "kernel.routed_experts_roofline"):
+        assert not re.search(regex[other], name)
+    assert regex["kernel.routed_experts_roofline"] == regex["kernel.moe_busy_share"]
 
 
 def _segments_once(served):

@@ -497,3 +497,50 @@ def test_compiled_for_v5e_wo_at_128_heads_is_read_out_of_the_stack(one_v5e, S):
     assert re.search(
         rf"%fused_computation[\w.\-]* \([^)]*bf16\[{n},{H * dv},{D}\][^)]*\) -> \(?[^{{]*bf16\[{B},{S},{D}\]", text
     ), "no fusion takes the stacked wo and returns the branch"
+
+
+def test_compiled_for_v5e_the_state_pools_kernel_and_the_two_matrix_experts(one_v5e):
+    """The sparse hybrid cell's two kernels at the published widths, compiled
+    by Mosaic for the v5e (lowering alone cannot show a block Mosaic will not
+    tile, nor a product's precision it will not take): ``ssm_window`` on a
+    pool of 5 layers x 8 slots x 128 x 8,192 float32 with 8 rows' decode windows of 8
+    slots (both products at ``highest``), and ``routed_experts`` with no gate
+    on a 1,024-wide latent, 128 experts of 2,688 held."""
+    import functools
+
+    from mcpx.engine.kernels.routed_experts import routed_experts
+    from mcpx.engine.kernels.ssm import _blocking, ssm_window
+
+    _, replicated = one_v5e
+    f32, bf, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    sd = functools.partial(jax.ShapeDtypeStruct, sharding=replicated)
+    B, W, S, G, N, M = 8, 8, 8, 8, 128, 8192
+    assert _blocking(M // G, N) == 1024  # a whole group's lanes a step
+    shapes = [sd((5, 8, N, M), f32), sd((B,), i32), sd((B,), i32), sd((B, M), f32), sd((B, W, M), f32),
+              sd((B, G, N, W), f32), sd((B, G, S, N), f32)]
+
+    def forwards(pool, *window):
+        # as the segment holds it: the donated pool carried through a loop of forwards
+        def body(c):
+            pool, acc = c[1], c[2]
+            for layer in range(5):
+                pool, hc = ssm_window(pool, layer, *window)
+                acc = acc + hc
+            return c[0] + 1, pool, acc
+
+        return jax.lax.while_loop(lambda c: c[0] < 4, body, (0, pool, jnp.zeros((B, S, M), f32)))[1:]
+
+    compiled = _compile_uncached(forwards, *shapes, donate_argnums=(0,))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the pool is updated in place: no second pool among the temporaries, and
+    # it is nowhere in VMEM (a layer's pool of its own that fits there was
+    # staged whole around every call: 67 MB a layer a forward)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * N * M * 4 // 8
+    assert not re.search(rf"f32\[5,8,{N},{M}\]\{{[^}}]*S\(1\)", text)
+    D, F, E = 1024, 2688, 128
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
+    experts = lambda x, c, up, down, *s: routed_experts(x, c, None, up, down, *s, act=relu2)
+    shapes = [sd((64, D), bf), sd((64, E), f32), sd((5, E, D, F), bf), sd((5, E, F, D), bf),
+              sd((E,), i32), sd((), i32), sd((), i32)]
+    assert "tpu_custom_call" in _compile_uncached(experts, *shapes).as_text()

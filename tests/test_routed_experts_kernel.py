@@ -170,3 +170,59 @@ def test_the_kernel_lowers_for_a_tpu_at_the_cells_shapes(name):
     fn = jax.jit(lambda *a: kernel.routed_experts(*a, act=jax.nn.silu))
     text = fn.trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text and "routed_experts" in text
+
+
+# ------------------------------------- an expert of two matrices, in a latent
+def _latent_block(dtype):
+    """A ``layer_pattern`` model's expert layer: 16 experts of TWO matrices
+    (``relu(l U)^2 V``, no gate) on a 32-wide latent, 8 held, top-3."""
+    return GemmaConfig(
+        vocab_size=384, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=32, d_ff=0,
+        layer_pattern="ME", mamba_n_heads=8, mamba_head_dim=16, mamba_n_groups=2, ssm_state_size=32,
+        n_experts=16, n_experts_per_tok=3, d_expert=48, expert_first=4, experts_held=8,
+        d_shared_expert=96, moe_latent_size=32, router_scoring="sigmoid", router_bias_scale=0.1,
+        router_scale=5.0, rope_full_layers=False, activation="relu2", tie_embeddings=False,
+        scale_embeddings=False, norm_plus_one=False, dtype=dtype,
+    )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_two_matrix_experts_in_a_latent_by_the_kernel_and_by_the_loop(dtype):
+    """The router reads the full width, the experts read and write the
+    latent's rows (``moe_forward(rows=...)``); the stacks hold no ``w_gate``,
+    which is what tells the loop and the kernel that an expert is
+    ``act(x U) V``: same counters, same choices, the outputs within the
+    rounding of one product."""
+    cfg = _latent_block(dtype)
+    params = init_params(cfg, jax.random.PRNGKey(3))["layers"]
+    experts = {k: params[k] for k in moe.expert_leaves(params)}
+    assert set(experts) == {"w_up", "w_down"} and experts["w_up"].shape == (1, 8, 32, 48)
+    B, S = 4, 8
+    h = jax.random.normal(jax.random.PRNGKey(4), (B, S, 64), jnp.float32).astype(dtype)
+    rows = jnp.einsum("btd,dl->btl", h, params["latent_down"][0])
+    live = jax.random.permutation(jax.random.PRNGKey(5), jnp.arange(B * S) % 4 != 1).reshape(B, S)
+    args = (params["router"][0], experts, jnp.int32(0), cfg, live, params["router_bias"][0])
+    out_l, stats_l, chosen_l = jax.jit(lambda h, r: moe.moe_forward(h, *args, rows=r))(h, rows)
+    out_k, stats_k, chosen_k = jax.jit(
+        lambda h, r: moe.moe_forward(h, *args, rows=r, use_pallas=True, interpret=True))(h, rows)
+    assert out_k.shape == (B, S, 32) and out_k.dtype == jnp.float32
+    assert np.asarray(chosen_k).tolist() == np.asarray(chosen_l).tolist()
+    assert np.asarray(stats_k)[:11].tolist() == np.asarray(stats_l)[:11].tolist() and int(stats_k[8]) > 0
+    assert (np.asarray(out_k)[~np.asarray(live)] == 0).all() and float(jnp.abs(out_l).max()) > 0
+    tol = 1e-5 if dtype == "float32" else 2.0**-7
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_l), rtol=0,
+                               atol=tol * float(jnp.abs(out_l).max()))
+
+
+def test_the_two_matrix_kernel_lowers_for_a_tpu_at_the_cells_shape():
+    """nemotron-3-super: 128 experts held of 1,024 x 2,688 x 2 (11.0 MB, a
+    whole expert a step), a decode segment's 64 slots, no ``w_gate`` operand."""
+    D, F, E = 1024, 2688, 128
+    assert kernel._blocking(64, D, F, E, 2, 2) == F
+    bf, S = jnp.bfloat16, jax.ShapeDtypeStruct
+    shapes = (S((64, D), bf), S((64, E), jnp.float32), S((5, E, D, F), bf), S((5, E, F, D), bf),
+              S((E,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
+    fn = jax.jit(lambda x, c, up, down, *s: kernel.routed_experts(x, c, None, up, down, *s, act=relu2))
+    text = fn.trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "routed_experts" in text

@@ -219,6 +219,13 @@ def test_at_every_cells_widths_the_tree_keeps_its_heads_and_the_scan_merges_them
     cfg = block.model_config(spec.model_keys(file), 3072)
     shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     H, dv, D = _wo_width(cfg)
+    if cfg.hybrid:
+        # A layer_pattern model walks its stacks without a scan, and its
+        # attention leaves are STORED with the heads merged on the matmul's own
+        # axis (its reference reads them so): a row of the stack feeds its dot.
+        assert shapes["attn_layers"]["wo"].shape == (cfg.n_attn_layers, H * dv, D)
+        assert shapes["attn_layers"]["wq"].shape == (cfg.n_attn_layers, D, H * dv)
+        return
     runs = jax.eval_shape(lambda p: [scanned for scanned, _, _ in layer_stacks(cfg, p)[0]], shapes)
     stored = _stored_stacks(cfg, shapes)
     assert len(runs) == len(stored)
