@@ -82,7 +82,7 @@ from mcpx.engine.kv_cache import (
 from mcpx.engine.pacing import SegmentPacer, hold_until
 from mcpx.engine.paged_decode import decode_chunk_paged, keep_window
 from mcpx.models.gemma.moe import (
-    FORWARD_STATS, INDEX_STATS, LAYER_STATS, forward_weight_bytes, moe_stats_init,
+    FORWARD_STATS, INDEX_STATS, LATENT_STATS, LAYER_STATS, forward_weight_bytes, moe_stats_init,
 )
 from mcpx.engine.prefix_cache import PrefixNode, RadixPrefixCache
 from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window_rows
@@ -5154,7 +5154,14 @@ class InferenceEngine:
         covered: a live row's rung, ``kernels/paged_attention.latent_rung``,
         over forwards and layers; over ``attn_row_calls`` it is 1 where every
         live row decodes one token and the window's width where the tile
-        takes no notice of ``q_lens``). Windowed
+        takes no notice of ``q_lens``), ``attn_key_blocks`` (the key blocks
+        those calls' programs fetched, over live rows, query blocks, forwards
+        and layers) and ``attn_run_blocks`` (those of them fetched in ONE
+        copy a pool, the block's pages lying side by side in the pool:
+        ``kernels/paged_attention.page_run_flags``, the flags the kernels
+        read; their ratio is how far the allocator's order reaches the
+        kernels; also ``mcpx_engine_attn_key_blocks_total`` /
+        ``_run_blocks_total``). Windowed
         attention:
         ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
         whose position had reached the window."""
@@ -5182,11 +5189,15 @@ class InferenceEngine:
                     mc.index_bytes_per_token // mc.n_layers
                 )
             if mc.latent:
-                # The forward's last counter: what the absorbed kernel's
-                # score tiles covered, a rung a live query block.
-                attrs["attn_query_slots"] = int(
-                    counts[own + FORWARD_STATS + (INDEX_STATS if mc.index_topk else 0)]
+                # The forward's last counters: what the absorbed kernel's
+                # score tiles covered, a rung a live query block; the key
+                # blocks its programs fetched and those fetched as one run.
+                latent = own + FORWARD_STATS + (INDEX_STATS if mc.index_topk else 0)
+                attrs["attn_query_slots"], attrs["attn_key_blocks"], attrs["attn_run_blocks"] = (
+                    int(c) for c in counts[latent : latent + LATENT_STATS]
                 )
+                self.metrics.attn_key_blocks.inc(attrs["attn_key_blocks"])
+                self.metrics.attn_run_blocks.inc(attrs["attn_run_blocks"])
             if mc.hybrid:
                 # Recurrent layers: calls on live rows, the live window slots
                 # they computed, the tokens the state moved by; a call reads

@@ -506,19 +506,67 @@ def latent_query_slots(q_lens: jax.Array, S: int, H: int) -> jax.Array:
     return jnp.sum(jnp.where(qn > 0, latent_rung(qn, sq), 0))
 
 
+def latent_key_pages(page_size: int, p_max: int) -> int:
+    """``P_BLK``: the pages one key block of the latent and the index kernel
+    covers (``LATENT_KEY_BLOCK`` keys, or the whole of a narrower table)."""
+    return max(1, min(LATENT_KEY_BLOCK // page_size, p_max))
+
+
+def page_run_flags(page_table: jax.Array, page_size: int, n_pool_pages: int) -> jax.Array:
+    """Which key blocks of a page table are RUNS: int32 [B, cdiv(Pmax, P_BLK)],
+    1 where the block's ``P_BLK`` table columns hold ``a, a + 1, ..., a +
+    P_BLK - 1`` (every step checked: distinct ids whose ends differ by
+    ``P_BLK - 1`` may still be out of order) inside the pool, so that the
+    pages lie side by side in a pool's layer and ONE DMA a pool fetches the
+    block. Off the table alone: the same for every layer and for the latent,
+    the selecting and the index kernel, so a forward computes it once,
+    outside its layer scan. A block the table's width cuts short is no run.
+    What a kernel does with a flag is speed, never the result."""
+    B, p_max = page_table.shape
+    p_blk = latent_key_pages(page_size, p_max)
+    n_blk = pl.cdiv(p_max, p_blk)
+    table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, n_blk * p_blk - p_max)))
+    table = table.reshape(B, n_blk, p_blk)
+    ascending = jnp.all(table[..., 1:] - table[..., :-1] == 1, axis=-1)
+    inside = (table[..., 0] >= 0) & (table[..., -1] < n_pool_pages)
+    return (ascending & inside).astype(jnp.int32)
+
+
+def latent_key_blocks(
+    runs: jax.Array, start_pos: jax.Array, q_lens: jax.Array, S: int, H: int,
+    page_size: int, p_max: int,
+) -> tuple[jax.Array, jax.Array]:
+    """The key blocks one ``ragged_paged_attention_latent`` call over a window
+    of ``S`` slots x ``H`` heads fetches, over its rows, head blocks and query
+    blocks, and those of them it fetches as one run (``runs``:
+    ``page_run_flags`` of its table): what ``_latent_kernel`` decides a block
+    by, a flag set and every page of the block streamed."""
+    sq, g, p_blk = _latent_blocking(S, H, page_size, p_max)
+    q0 = jnp.arange(0, S, sq)
+    qn = jnp.clip(q_lens[:, None] - q0, 0, sq)  # [B, query blocks]
+    n_pages = _ragged_n_pages(start_pos[:, None] + q0, qn, page_size, p_max)
+    whole = jnp.arange(runs.shape[1]) < (n_pages // p_blk)[..., None]  # [B, query blocks, blocks]
+    as_run = jnp.sum(jnp.where(whole, runs[:, None, :], 0))
+    return jnp.sum(pl.cdiv(n_pages, p_blk)) * (H // g), as_run * (H // g)
+
+
 def _latent_kernel(
     *refs, page_size: int, p_blk: int, scale: float, selecting: bool = False,
     rungs: "tuple[int, ...] | None" = None,
 ):
-    """``refs``: the scalar prefetch (page_table [B, Pmax], start_pos [B],
-    q_lens [B], layer [1]; SMEM), the blocks q_latent [1, Sq, G, r] and
-    q_rope [1, Sq, G, w] VMEM (one query block, G of the heads), rope_pages /
-    latent_pages [1, L, N, Psz, w / r] ANY, out [1, Sq, G, r] VMEM; then the
-    scratch rope_buf / latent_buf [2, P_BLK * Psz, w / r] (two key blocks in
+    """``refs``: the scalar prefetch (page_table [B, Pmax], runs [B, blocks]
+    (``page_run_flags``), start_pos [B], q_lens [B], layer [1]; SMEM), the
+    blocks q_latent [1, Sq, G, r] and q_rope [1, Sq, G, w] VMEM (one query
+    block, G of the heads), rope_pages / latent_pages [1, L, N, Psz, w / r]
+    ANY, out [1, Sq, G, r] VMEM; then the
+    scratch rope_buf / latent_buf [2, P_BLK, Psz, w / r] (two key blocks in
     flight) and their DMA semaphores [2, 2]. The whole-window kernel's
     structure (``_ragged_kernel``) with ONE shared key head: a page is
     fetched once and its latent rows serve as the keys' first part and as
-    the values. ``selecting``: one more block after q_rope, select [1, Sq,
+    the values. A key block that is a run (its flag set, and every page of
+    it streamed) is ONE DMA a pool, started and awaited once: the scalar core
+    pays for a copy, not for its bytes. Any other block is fetched page by
+    page. ``selecting``: one more block after q_rope, select [1, Sq,
     Pmax * Psz] float32 (``index_select``), added to the scores: a key the
     query does not read weighs nothing. Every page is still streamed.
 
@@ -527,8 +575,8 @@ def _latent_kernel(
     loop at ``n * G`` rows, the slots past ``n`` stored as the zeros a pad
     query outputs. ``rungs``: the arms compiled, ``latent_rungs(Sq)`` unless
     a test asks for fewer (``(Sq,)``: the whole block whatever is live)."""
-    page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
-    refs = list(refs[4:])
+    page_table_ref, runs_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:5]
+    refs = list(refs[5:])
     select_ref = refs.pop(2) if selecting else None
     ql_ref, qr_ref, rope_pages_ref, latent_pages_ref, out_ref, rope_buf, latent_buf, sem = refs
     b = pl.program_id(0)
@@ -542,38 +590,48 @@ def _latent_kernel(
     n_pages = _ragged_n_pages(start, qn, page_size, page_table_ref.shape[1])
     n_blocks = pl.cdiv(n_pages, p_blk)
 
-    def page_copies(slot, blk, p):
-        page = page_table_ref[b, blk * p_blk + p]
-        rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
-        rope_page = rope_pages_ref.at[0, layer, page]
-        if rope_pages_ref.shape[4] != w:  # an index key lies behind the rotated key's lanes
-            rope_page = rope_page.at[:, pl.ds(0, w)]
+    def copies(slot, pages, at):
+        """The two pools' copies of ``pages`` of the layer (one page id, or a
+        run's ``pl.ds``) into ``at`` of the slot's buffers."""
+        # (an index key lies behind the rotated key's lanes)
+        lanes = slice(None) if rope_pages_ref.shape[4] == w else pl.ds(0, w)
         return [
-            pltpu.make_async_copy(page_src, buf.at[slot, rows], sem.at[i, slot])
-            for i, (page_src, buf) in enumerate(
-                ((rope_page, rope_buf), (latent_pages_ref.at[0, layer, page], latent_buf))
-            )
+            pltpu.make_async_copy(src, buf.at[(slot,) + at], sem.at[i, slot])
+            for i, (src, buf) in enumerate((
+                (rope_pages_ref.at[0, layer, pages, :, lanes], rope_buf),
+                (latent_pages_ref.at[0, layer, pages], latent_buf),
+            ))
         ]
 
-    def each_page(slot, blk, act):
+    def each_copy(slot, blk, act):
+        """``act`` (start or wait) on every copy of key block ``blk``: one a
+        pool where the block is a run, else one a pool a page. -> the pages."""
         n_here = jnp.minimum(n_pages - blk * p_blk, p_blk)
+        run = (runs_ref[b, blk] != 0) & (n_here == p_blk)
+
+        @pl.when(run)
+        def _():
+            for copy in copies(slot, pl.ds(page_table_ref[b, blk * p_blk], p_blk), ()):
+                act(copy)
 
         def one(p, carry):
-            for copy in page_copies(slot, blk, p):
+            for copy in copies(slot, page_table_ref[b, blk * p_blk + p], (p,)):
                 act(copy)
             return carry
 
-        lax.fori_loop(0, n_here, one, 0)
+        @pl.when(jnp.logical_not(run))
+        def _():
+            lax.fori_loop(0, n_here, one, 0)
+
         return n_here
 
     def start_block(slot, blk):
-        n_here = each_page(slot, blk, operator.methodcaller("start"))
+        n_here = each_copy(slot, blk, operator.methodcaller("start"))
 
         # As in ``_ragged_kernel``: an unfetched page's latent rows meet a
         # weight of exactly 0 as VALUES, which only a finite value leaves 0.
         def blank(p, carry):
-            rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
-            latent_buf[slot, rows] = jnp.zeros((page_size, r), latent_buf.dtype)
+            latent_buf[slot, p] = jnp.zeros((page_size, r), latent_buf.dtype)
             return carry
 
         lax.fori_loop(n_here, p_blk, blank, 0)
@@ -600,9 +658,10 @@ def _latent_kernel(
             def _():
                 start_block(1 - slot, i + 1)
 
-            each_page(slot, i, operator.methodcaller("wait"))
-            c_tile = latent_buf[slot]  # [keys, r]
-            k_tile = rope_buf[slot]  # [keys, w]
+            each_copy(slot, i, operator.methodcaller("wait"))
+            # Psz rows are whole sublane tiles: the merge moves nothing.
+            c_tile = latent_buf[slot].reshape(keys, r)
+            k_tile = rope_buf[slot].reshape(keys, w)
             if c_tile.dtype != q_lat.dtype:
                 c_tile, k_tile = c_tile.astype(jnp.float32), k_tile.astype(jnp.float32)
             contract_last = (((1,), (1,)), ((), ()))
@@ -652,18 +711,21 @@ def _latent_blocking(S: int, H: int, page_size: int, p_max: int) -> tuple[int, i
     sq = max(1, min(S, Q_BLOCK, LATENT_ROWS // g))
     if sq < S:
         sq = max(8, sq // 8 * 8)
-    return sq, g, max(1, min(LATENT_KEY_BLOCK // page_size, p_max))
+    return sq, g, latent_key_pages(page_size, p_max)
 
 
 def _latent_call(
     q_latent, q_rope, rope_pages, latent_pages, page_table, start_pos, q_lens, layer=0,
-    select=None, *, scale: float, interpret: bool = False, rungs: "tuple[int, ...] | None" = None,
+    select=None, runs=None, *, scale: float, interpret: bool = False,
+    rungs: "tuple[int, ...] | None" = None,
 ) -> jax.Array:
     """``ragged_paged_attention_latent``'s call; ``rungs`` as ``_latent_kernel``'s."""
     B, S, H, r = q_latent.shape
     w = q_rope.shape[3]
     page_size = latent_pages.shape[3]
     selecting = select is not None
+    if runs is None:
+        runs = page_run_flags(page_table, page_size, latent_pages.shape[2])
     sq, g, p_blk = _latent_blocking(S, H, page_size, page_table.shape[1])
     s_pad = pl.cdiv(S, sq) * sq
     if s_pad != S:
@@ -680,13 +742,14 @@ def _latent_call(
             (1, sq, select.shape[2]), lambda b, h, j, *_: (b, j, 0), memory_space=pltpu.VMEM
         ))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(B, H // g, s_pad // sq),
         in_specs=blocks + [pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_block(r),
         scratch_shapes=[
-            pltpu.VMEM((2, p_blk * page_size, w), rope_pages.dtype),
-            pltpu.VMEM((2, p_blk * page_size, r), latent_pages.dtype),
+            # A page a leading index: a run's destination is the slot's whole buffer.
+            pltpu.VMEM((2, p_blk, page_size, w), rope_pages.dtype),
+            pltpu.VMEM((2, p_blk, page_size, r), latent_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
@@ -710,6 +773,7 @@ def _latent_call(
         **more,
     )(
         page_table.astype(jnp.int32),
+        runs.astype(jnp.int32),
         start_pos.astype(jnp.int32),
         q_lens.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
@@ -733,16 +797,19 @@ def ragged_paged_attention_latent(
     q_lens: jax.Array,  # [B]
     layer: jax.Array | int = 0,
     select: "jax.Array | None" = None,  # [B, S, Pmax * Psz] float32 (``index_select``)
+    runs: "jax.Array | None" = None,  # [B, blocks] ``page_run_flags`` (None: computed here)
     *,
     scale: float,
     interpret: bool = False,
 ) -> jax.Array:
     """The ragged kernel for a latent cache, in ABSORBED form
     (``latent_paged_attention_reference``): grid (B, cdiv(H, G), cdiv(S, Sq));
-    one program streams a row's pages once, ``P_BLK`` pages a step, each page
-    two DMAs (its rotated key and its latent rows), and multiplies them by a
-    block of Sq queries x G heads: ``[Sq*G, r] @ latent.T + [Sq*G, w] @
-    rope.T`` for the scores, ``p @ latent`` for the output, flash-style in
+    one program streams a row's pages once, ``P_BLK`` pages a step, each step
+    two DMAs (the block's rotated keys and its latent rows) where the block's
+    pages lie side by side in the pool (``runs``: a forward computes the flags
+    once for all its layers' calls) and two a page where they do not, and
+    multiplies them by a block of Sq queries x G heads: ``[Sq*G, r] @
+    latent.T + [Sq*G, w] @ rope.T`` for the scores, ``p @ latent`` for the output, flash-style in
     float32; of the block's Sq slots the tile covers the live ones, rounded
     up to a rung (``latent_rung``: a decode row with one live query
     multiplies G rows, not Sq * G). Rows ragged by ``q_lens`` as in
@@ -758,7 +825,7 @@ def ragged_paged_attention_latent(
     does not. The rotated key is then the first ``w`` lanes of its page row."""
     return _latent_call(
         q_latent, q_rope, rope_pages, latent_pages, page_table, start_pos, q_lens, layer, select,
-        scale=scale, interpret=interpret,
+        runs, scale=scale, interpret=interpret,
     )
 
 
@@ -799,20 +866,21 @@ def index_select_reference(
 
 
 def _index_kernel(*refs, page_size: int, p_blk: int, topk: int, lane0: int):
-    """``refs``: the scalar prefetch (page_table, start_pos, q_lens, layer;
-    SMEM), q [1, Hi, Sq, di] and w [1, Hi, Sq, 128] VMEM (one block of Sq =
+    """``refs``: the scalar prefetch (page_table, runs, start_pos, q_lens,
+    layer; SMEM), q [1, Hi, Sq, di] and w [1, Hi, Sq, 128] VMEM (one block of Sq =
     ``INDEX_QUERIES`` queries, head-major; a weight repeated along its lane
     row), key_pages [1, L, N, Psz, lane0 + di] ANY, out [1, Sq, Pmax * Psz]
-    float32 VMEM; then key_buf [2, P_BLK * Psz, di] and its DMA semaphores
+    float32 VMEM; then key_buf [2, P_BLK, Psz, di] and its DMA semaphores
     [2]. The program streams its row's index keys a block of pages at a time
-    as ``_latent_kernel`` streams the latents, writes each block's scores
+    as ``_latent_kernel`` streams the latents (a run in one copy, by the same
+    flags), writes each block's scores
     into ``out``, and then turns ``out`` into the selection in place: the
     ``topk``-th largest score of every query by a search over the bits of the
     float32 (32 counts), ties cut at a position found the same way. A block
     whose queries all see no more than ``topk`` keys streams nothing: they
     read every key they see."""
-    page_table_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:4]
-    q_ref, w_ref, key_pages_ref, out_ref, key_buf, sem = refs[4:]
+    page_table_ref, runs_ref, start_pos_ref, q_lens_ref, layer_ref = refs[:5]
+    q_ref, w_ref, key_pages_ref, out_ref, key_buf, sem = refs[5:]
     b = pl.program_id(0)
     layer = layer_ref[0]
     Hi, S, di = q_ref.shape[1:]
@@ -829,33 +897,45 @@ def _index_kernel(*refs, page_size: int, p_blk: int, topk: int, lane0: int):
     q = q_ref[0].reshape(Hi * S, di)
     weight = w_ref[0].reshape(Hi * S, w_ref.shape[3])[:, :1]  # [Hi * S, 1]
 
-    def each_page(slot, blk, act):
+    def copy(slot, pages, at):
+        return pltpu.make_async_copy(
+            key_pages_ref.at[0, layer, pages, :, pl.ds(lane0, di)],
+            key_buf.at[(slot,) + at], sem.at[slot],
+        )
+
+    def each_copy(slot, blk, act):
+        """As ``_latent_kernel``'s: a run's index keys are one copy."""
+        n_here = jnp.minimum(n_pages - blk * p_blk, p_blk)
+        run = (runs_ref[b, blk] != 0) & (n_here == p_blk)
+
+        @pl.when(run)
+        def _():
+            act(copy(slot, pl.ds(page_table_ref[b, blk * p_blk], p_blk), ()))
+
         def one(p, carry):
-            page = page_table_ref[b, blk * p_blk + p]
-            rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
-            act(pltpu.make_async_copy(
-                key_pages_ref.at[0, layer, page].at[:, pl.ds(lane0, di)],
-                key_buf.at[slot, rows], sem.at[slot],
-            ))
+            act(copy(slot, page_table_ref[b, blk * p_blk + p], (p,)))
             return carry
 
-        lax.fori_loop(0, jnp.minimum(n_pages - blk * p_blk, p_blk), one, 0)
+        @pl.when(jnp.logical_not(run))
+        def _():
+            lax.fori_loop(0, n_here, one, 0)
 
     out_ref[0] = jnp.full((S, n_keys), -jnp.inf, jnp.float32)
 
     @pl.when(n_blocks > 0)
     def _():
-        each_page(0, 0, operator.methodcaller("start"))
+        each_copy(0, 0, operator.methodcaller("start"))
 
     def body(i, carry):
         slot = lax.rem(i, 2)
 
         @pl.when(i + 1 < n_blocks)
         def _():
-            each_page(1 - slot, i + 1, operator.methodcaller("start"))
+            each_copy(1 - slot, i + 1, operator.methodcaller("start"))
 
-        each_page(slot, i, operator.methodcaller("wait"))
-        k_tile = key_buf[slot]  # [keys, di]; rows of a page not fetched are masked below
+        each_copy(slot, i, operator.methodcaller("wait"))
+        # [keys, di]; rows of a page not fetched are masked below
+        k_tile = key_buf[slot].reshape(keys, di)
         s = lax.dot_general(
             q, k_tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [Hi * S, keys]
@@ -908,6 +988,7 @@ def lightning_indexer(
     start_pos: jax.Array,  # [B]
     q_lens: jax.Array,  # [B]
     layer: jax.Array | int = 0,
+    runs: "jax.Array | None" = None,  # [B, blocks] ``page_run_flags`` (None: computed here)
     *,
     topk: int,
     lane0: int,
@@ -915,8 +996,9 @@ def lightning_indexer(
 ) -> jax.Array:
     """``index_select_reference`` as a kernel: grid (B, cdiv(S, 16)); one
     program scores a block of 16 queries x every index head against its row's
-    cached index keys through the page table (one DMA a page: lanes ``lane0``
-    on of the row's rotated-key page), ``[Hi * 16, di] @ keys.T`` a block of
+    cached index keys through the page table (lanes ``lane0`` on of the row's
+    rotated-key pages: one DMA a key block that is a run, ``runs``, one a
+    page of any other), ``[Hi * 16, di] @ keys.T`` a block of
     ``LATENT_KEY_BLOCK`` keys, and selects in VMEM. Its custom call carries
     this function's name, which is part of no other kernel's."""
     B, S, Hi, di = q_index.shape
@@ -924,7 +1006,9 @@ def lightning_indexer(
     p_max = page_table.shape[1]
     n_keys = p_max * page_size
     sq = INDEX_QUERIES
-    p_blk = max(1, min(LATENT_KEY_BLOCK // page_size, p_max))
+    p_blk = latent_key_pages(page_size, p_max)
+    if runs is None:
+        runs = page_run_flags(page_table, page_size, key_pages.shape[2])
     # Head-major, so that the sum over heads adds whole [16, keys] tiles; the
     # window padded to whole query blocks with dead queries.
     q = q_index.transpose(0, 2, 1, 3)
@@ -937,12 +1021,12 @@ def lightning_indexer(
         (1, Hi, sq, width), lambda b, j, *_: (b, 0, j, 0), memory_space=pltpu.VMEM
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(B, s_pad // sq),
         in_specs=[block(di), block(128), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, sq, n_keys), lambda b, j, *_: (b, j, 0), memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, p_blk * page_size, di), key_pages.dtype),
+            pltpu.VMEM((2, p_blk, page_size, di), key_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -954,6 +1038,7 @@ def lightning_indexer(
         name="lightning_indexer",
     )(
         page_table.astype(jnp.int32),
+        runs.astype(jnp.int32),
         start_pos.astype(jnp.int32),
         q_lens.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),

@@ -23,6 +23,7 @@ double-free, no page aliasing).
 
 from __future__ import annotations
 
+import heapq
 from dataclasses import dataclass
 
 import jax
@@ -49,7 +50,16 @@ class PageAllocator:
     """Free-list page allocator; page 0 is reserved as the null page.
     Single-writer by construction — the engine worker thread owns it, and
     the ``owned_by`` marks (class + mutators) let mcpxlint's
-    thread-ownership pass prove no other thread can reach a mutation."""
+    thread-ownership pass prove no other thread can reach a mutation.
+
+    It always hands out the LOWEST free ids, ascending (the free list is a
+    min-heap: O(log n) a page taken or returned, whatever was freed and in
+    what order), so a sequence's pages, and the pages of allocations made
+    back to back (a catalogue head built in chunks, a row's tree node and
+    its own pages), lie side by side in the pools wherever the free ids do.
+    The latent kernels fetch such a run of a key block's pages in one copy
+    (``kernels/paged_attention.page_run_flags``): the order is speed, never
+    correctness, and nothing may rely on an id's value."""
 
     def __init__(self, n_pages: int, page_size: int, max_pages_per_seq: int) -> None:
         if n_pages < 2:
@@ -57,7 +67,7 @@ class PageAllocator:
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
         self.n_pages = n_pages
-        self._free: list[int] = list(range(n_pages - 1, 0, -1))  # stack; 0 reserved
+        self._free: list[int] = list(range(1, n_pages))  # min-heap; 0 reserved
         self._seq_pages: dict[int, list[int]] = {}
 
     # ------------------------------------------------------------------ api
@@ -79,7 +89,7 @@ class PageAllocator:
             )
         if need > len(self._free):
             raise EngineError(f"out of KV pages: need {need}, free {len(self._free)}")
-        pages = [self._free.pop() for _ in range(need)]
+        pages = [heapq.heappop(self._free) for _ in range(need)]
         self._seq_pages[seq_id] = pages
         return list(pages)
 
@@ -98,7 +108,7 @@ class PageAllocator:
         while len(pages) < need:
             if not self._free:
                 raise EngineError("out of KV pages during extend")
-            pages.append(self._free.pop())
+            pages.append(heapq.heappop(self._free))
         return list(pages)
 
     @owned_by("engine-worker")
@@ -131,7 +141,7 @@ class PageAllocator:
         for p in pages:
             if p <= 0 or p >= self.n_pages:
                 raise EngineError(f"corrupt page id {p}")
-            self._free.append(p)
+            heapq.heappush(self._free, p)
 
     def pages_of(self, seq_id: int) -> list[int]:
         return list(self._seq_pages.get(seq_id, []))

@@ -22,6 +22,7 @@ from mcpx.engine.kernels.paged_attention import (
     index_select_reference,
     latent_paged_attention_reference,
     lightning_indexer,
+    page_run_flags,
     ragged_paged_attention,
     ragged_paged_attention_latent,
     ragged_paged_attention_reference,
@@ -101,6 +102,7 @@ def _latent_attend(
     q_lens: jax.Array,  # [B] live window slots of each row
     layer: jax.Array,
     index: "tuple | None" = None,  # the window's index queries and weights
+    runs: "jax.Array | None" = None,  # [B, blocks] the table's ``page_run_flags``
     *,
     mesh: Optional[Mesh],
     use_pallas: bool,
@@ -122,7 +124,11 @@ def _latent_attend(
     (``kernels/paged_attention.lightning_indexer``: they lie in the rotated
     key's page rows) and the attention reads its ``index_topk`` best alone:
     every page is still streamed, the unselected weigh nothing. Each device
-    selects for its own rows, every index head at once."""
+    selects for its own rows, every index head at once.
+
+    ``runs``: which key blocks of the table lie side by side in the pools
+    (``page_run_flags``), for both kernels: the caller computes them once a
+    forward, outside its layer scan (None: each call computes its own)."""
     B, S, H, _ = q.shape
     hd, dr = cfg.head_dim, cfg.qk_rope_head_dim
     w_uk, w_uv = lp["w_ukv"][..., :hd], lp["w_ukv"][..., hd:]  # [r, H, hd], [r, H, dv]
@@ -135,6 +141,8 @@ def _latent_attend(
     selecting = index is not None and page_table.shape[1] * rope_pool.shape[3] > cfg.index_topk
     rows = None if mesh is None else _axis(mesh, DATA_AXIS, B)
     row_specs = (P(rows, None), P(rows), P(rows), P())  # table, positions, q_lens, layer
+    if runs is None:
+        runs = page_run_flags(page_table, rope_pool.shape[3], rope_pool.shape[2])
     select = None
     if selecting:
         choose = functools.partial(
@@ -145,11 +153,13 @@ def _latent_attend(
         if use_pallas and mesh is not None:
             choose = jax.shard_map(
                 choose, mesh=mesh,
-                in_specs=(P(rows, None, None, None), P(rows, None, None), P()) + row_specs,
+                in_specs=(P(rows, None, None, None), P(rows, None, None), P()) + row_specs
+                + (P(rows, None),),
                 out_specs=P(rows, None, None), check_vma=False,
             )
         select = choose(
-            *index, rope_pool, page_table, positions, q_lens, jnp.asarray(layer, jnp.int32)
+            *index, rope_pool, page_table, positions, q_lens, jnp.asarray(layer, jnp.int32),
+            *((runs,) if use_pallas else ()),
         )
     selected = () if select is None else (select,)
     if use_pallas:
@@ -159,12 +169,13 @@ def _latent_attend(
             q_spec = P(rows, None, heads, None)
             kernel = jax.shard_map(
                 kernel, mesh=mesh,
-                in_specs=(q_spec, q_spec, P(), P()) + row_specs + (P(rows, None, None),) * selecting,
+                in_specs=(q_spec, q_spec, P(), P()) + row_specs
+                + (P(rows, None, None) if selecting else None, P(rows, None)),
                 out_specs=q_spec, check_vma=False,
             )
         out = kernel(
             q_latent, q_rope, rope_pool, latent_pool, page_table, positions, q_lens,
-            jnp.asarray(layer, jnp.int32), *selected,
+            jnp.asarray(layer, jnp.int32), select, runs,
         )
     else:
         out = latent_paged_attention_reference(
@@ -410,10 +421,15 @@ def decode_chunk_paged(
     # which XLA partitions (no cell runs a sparse model across chips).
     experts_kernel = use_pallas and (mesh is None or mesh.size == 1)
 
+    # A latent cache's kernels fetch a key block whose pages lie side by side
+    # in the pool in one copy: which blocks those are is the table's alone,
+    # the same for every layer and for the index beside the attention.
+    runs = page_run_flags(page_table, psz, N) if cfg.latent else None
+
     def attend(q, k_all, v_all, layer, window, lp, index):
         if cfg.latent:
             attn, select = _latent_attend(
-                q, lp, cfg, k_all, v_all, page_table, positions, q_lens, layer, index,
+                q, lp, cfg, k_all, v_all, page_table, positions, q_lens, layer, index, runs,
                 mesh=mesh, use_pallas=use_pallas, interpret=interpret,
             )
             # What a comparison may ask for (``selection``): the keys every
@@ -470,7 +486,10 @@ def decode_chunk_paged(
     if stats is not None:
         # What this forward's attention calls read, by row: a live row's
         # context runs through its last live query (``_ragged_n_pages``).
-        stats = add_forward_stats(cfg, stats, positions + q_lens, q_lens, S)
+        stats = add_forward_stats(
+            cfg, stats, positions + q_lens, q_lens, S,
+            None if runs is None else (runs, psz, page_table.shape[1]),
+        )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     pools = {"k": k_new, "v": v_new}
     # What a sparse model's callers may ask for beside the logits: the

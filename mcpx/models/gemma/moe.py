@@ -149,8 +149,9 @@ LAYER_STATS = 4
 # What a forward of a block with a learned index counts after those.
 INDEX_STATS = 2
 
-# What a forward of a latent block counts last.
-LATENT_STATS = 1
+# What a forward of a latent block counts last: its paged attention calls'
+# query slots, the key blocks they fetched and those fetched as one run.
+LATENT_STATS = 3
 
 # What a forward of a model with recurrent layers counts last: its Mamba
 # layers' calls on live rows, the live window slots those calls computed, and
@@ -173,8 +174,10 @@ def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     (``GemmaConfig.index_topk``) counts two more: the keys those calls
     attended after the selection, and the keys the index scored (a call whose
     row holds no more than ``index_topk`` tokens scores none). A latent block
-    counts one more, last: the query slots its paged attention calls
-    multiplied (``kernels/paged_attention.latent_query_slots``), over layers.
+    counts three more, last: the query slots its paged attention calls
+    multiplied (``kernels/paged_attention.latent_query_slots``), the key
+    blocks they fetched and those of them fetched in one copy a pool, their
+    pages lying side by side (``latent_key_blocks``), each over layers.
     A model with recurrent layers counts ``SSM_STATS`` more, last."""
     more = (
         (INDEX_STATS if cfg.index_topk else 0) + (LATENT_STATS if cfg.latent else 0)
@@ -191,14 +194,15 @@ def add_layer_stats(stats: jax.Array, layer_stats: jax.Array) -> jax.Array:
 
 def add_forward_stats(
     cfg: GemmaConfig, stats: jax.Array, context: jax.Array, q_lens: jax.Array,
-    window: "int | None" = None,
+    window: "int | None" = None, pages: "tuple | None" = None,
 ) -> jax.Array:
     """The forward's own counters: ``context`` [B] the cache positions a
     row's attention read through, ``q_lens`` [B] its live tokens (0: an idle
     row, which reads nothing and routes nothing), ``window`` the slots of
     the window its attention read the pages through (None: a dense prefill,
     which reads no page, and whose every live token stays in a recurrent
-    state)."""
+    state), ``pages`` of a latent block's window ``(runs, page_size, p_max)``:
+    its table's ``page_run_flags`` and geometry."""
     live = q_lens > 0
     own = [
         jnp.sum(q_lens) * (cfg.n_experts_per_tok * cfg.n_sparse_layers),
@@ -212,10 +216,13 @@ def add_forward_stats(
             jnp.sum(jnp.where(live & (context > k), context, 0)) * cfg.n_layers,
         ]
     if cfg.latent:
-        from mcpx.engine.kernels.paged_attention import latent_query_slots
+        from mcpx.engine.kernels.paged_attention import latent_key_blocks, latent_query_slots
 
         slots = 0 if window is None else latent_query_slots(q_lens, window, cfg.n_heads)
-        own.append(jnp.asarray(slots) * cfg.n_layers)
+        blocks = (0, 0) if pages is None else latent_key_blocks(
+            pages[0], context - q_lens, q_lens, window, cfg.n_heads, *pages[1:]
+        )
+        own += [jnp.asarray(n) * cfg.n_layers for n in (slots, *blocks)]
     if cfg.hybrid:
         slots = jnp.sum(q_lens) * cfg.n_mamba_layers
         own += [jnp.sum(live) * cfg.n_mamba_layers, slots, slots if window is None else 0 * slots]

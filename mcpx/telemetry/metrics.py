@@ -372,6 +372,21 @@ class Metrics:
             ["expert"],
             registry=self.registry,
         )
+        self.attn_key_blocks = Counter(
+            "mcpx_engine_attn_key_blocks_total",
+            "Key blocks (16 pages of 16 keys) the paged attention calls of a "
+            "latent cache fetched in decode segments, over live rows, query "
+            "blocks, forwards and layers; a model with heads for a cache "
+            "writes none",
+            registry=self.registry,
+        )
+        self.attn_run_blocks = Counter(
+            "mcpx_engine_attn_run_blocks_total",
+            "Of mcpx_engine_attn_key_blocks_total, the blocks fetched in one "
+            "copy a pool because their pages lie side by side in the pool: "
+            "how far the page allocator's ascending order reaches the kernels",
+            registry=self.registry,
+        )
         self.weights_init_seconds = Gauge(
             "mcpx_engine_weights_init_seconds",
             "Wall seconds of the weights' random draw or checkpoint restore "

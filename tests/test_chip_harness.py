@@ -308,7 +308,7 @@ def test_costs_counts_the_params_a_token_reads(served, served_sparse):
 
 def test_a_dense_block_writes_no_layer_kind_attribute(served):
     assert _segments(served)
-    absent = LAYER_KIND_ATTRS + ("attn_query_slots",)  # a latent block's alone
+    absent = LAYER_KIND_ATTRS + ("attn_query_slots", "attn_key_blocks", "attn_run_blocks")  # a latent block's alone
     assert not any(a in sp["attrs"] for sp in _segments(served) for a in absent)
     assert "mcpx_engine_moe_expert_tokens_total{" not in served["engine_metrics"][1]
     profile = served["health"]["engine_queue"]["worker_profile"]
@@ -479,9 +479,11 @@ def test_the_mixed_blocks_attributes_count_sparse_layers_and_bytes(served_mixed)
 def test_the_latent_block_feeds_its_metrics(served_latent, metric):
     assert {m["name"] for m in FED_LATENT} == {
         "attn.ctx_tok_per_call", "attn.latent_bytes_share", "moe.held_assignment_share",
-        "attn.slots_per_row_call"}
+        "attn.slots_per_row_call", "attn.page_run_share"}
     v = served_latent["read"](metric["reader"], metric["args"])
     assert v is not None and math.isfinite(v)
+    if metric["name"] == "attn.page_run_share":
+        assert v == 0  # a context under 256 tokens has no whole key block to be a run
     if metric["name"] == "attn.slots_per_row_call":
         assert 1 <= v < 2  # a live row decodes a token or two of its window's 8 slots a forward
     if metric["name"] == "attn.ctx_tok_per_call":
@@ -502,6 +504,7 @@ def test_the_latent_blocks_attributes_count_context_and_this_share(served_latent
         assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (64 + 16) * 2
         # a live row's score tile: its rung, from one slot to the window's 8
         assert a["attn_row_calls"] <= a["attn_query_slots"] <= 8 * a["attn_row_calls"]
+        assert a["attn_key_blocks"] == a["attn_row_calls"] and a["attn_run_blocks"] == 0  # one part block a call
         assert a["moe_tokens_routed"] % 2 == 0  # 2 experts a live token in the one sparse layer
         assert 0 <= a["moe_assignments"] <= a["moe_tokens_routed"]
         assert a["moe_expert_slots"] == a["forwards"] * 1 * 4  # the 4 experts held
@@ -545,6 +548,14 @@ def test_the_index_blocks_attributes_count_the_selection_and_the_heads_chunks(se
         assert a["index_ctx_tokens"] == a["attn_ctx_tokens"] > a["attn_sel_tokens"]  # every row is past the 32nd key
         assert a["index_bytes_read"] == a["index_ctx_tokens"] * 32 * 2
         assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (64 + 16) * 2  # the masked form streams every page
+        # ~50 pages a row: three whole key blocks and a part of a fourth a call
+        assert 3 * a["attn_row_calls"] <= a["attn_key_blocks"] <= 5 * a["attn_row_calls"]
+        assert a["attn_run_blocks"] <= a["attn_key_blocks"]
+    # the head, built in chunks after the warm-up's rows were freed, lies side by
+    # side in the pools: its whole key blocks are fetched as runs
+    by_name = {m["name"]: m for m in METRICS}
+    run_share = served_index["read"](by_name["attn.page_run_share"]["reader"], by_name["attn.page_run_share"]["args"])
+    assert 0.6 <= run_share < 1
     profile = served_index["health"]["engine_queue"]["worker_profile"]
     for attr in ("attn_sel_tokens", "index_ctx_tokens", "index_bytes_read"):
         assert profile[attr] >= sum(sp["attrs"][attr] for sp in _segments_once(served_index)) > 0
@@ -555,7 +566,6 @@ def test_the_index_blocks_attributes_count_the_selection_and_the_heads_chunks(se
     assert builds[0]["attrs"]["chunks"] == chunks and 600 < builds[0]["attrs"]["head_tokens"] < 1200
     assert builds[0]["attrs"]["head_tokens"] % 16 == 0 and builds[0]["attrs"]["head_tokens"] > 256 * (chunks - 1)
     # every plan's own prefill is its intent behind the shared head
-    by_name = {m["name"]: m for m in METRICS}
     per_plan = served_index["read"](by_name["engine.prefill_tok_per_plan"]["reader"],
                                     by_name["engine.prefill_tok_per_plan"]["args"])
     assert 0 < per_plan < 80

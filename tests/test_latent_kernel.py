@@ -168,9 +168,9 @@ def test_the_forwards_counter_is_the_sum_of_the_kernels_rungs(S, H):
             want += int(latent_rung(qn, sq)) if qn else 0
     assert int(latent_query_slots(jnp.asarray(q_lens), S, H)) == want
     stats = moe.add_forward_stats(cfg, moe.moe_stats_init(cfg), jnp.asarray(q_lens) + 40, jnp.asarray(q_lens), S)
-    assert int(stats[-1]) == want * cfg.n_layers
+    assert int(stats[-moe.LATENT_STATS]) == want * cfg.n_layers
     # a forward that read no page (the expanded prefill) multiplied none
-    assert int(moe.add_forward_stats(cfg, moe.moe_stats_init(cfg), jnp.asarray(q_lens), jnp.asarray(q_lens))[-1]) == 0
+    assert int(moe.add_forward_stats(cfg, moe.moe_stats_init(cfg), jnp.asarray(q_lens), jnp.asarray(q_lens))[-moe.LATENT_STATS]) == 0
     # a block with heads for a cache counts no such thing
     dense = GemmaConfig.named("test")
     assert not dense.latent and moe.moe_stats_init(dense).shape == (moe.LAYER_STATS + moe.FORWARD_STATS,)
@@ -302,3 +302,188 @@ def test_the_index_and_the_selecting_attention_lower_for_tpu_on_one_device_and_u
         rope_pool, latent_pool, sd((B, 128), i32), *rows[1:3],
     ).lower(lowering_platforms=("tpu",)).as_text()
     assert "lightning_indexer" not in narrow and "ragged_paged_attention_latent" in narrow
+
+
+# ------------------------------------------------- a run of pages in one copy
+# Page tables of 3 key blocks of 16 pages and half a fourth (the row's last 8
+# pages, then the null page), over a pool of RUN_POOL pages: (name, the row's
+# page ids, the flags ``page_run_flags`` must give its four blocks).
+RUN_POOL = 120
+_asc = lambda a, n=16: list(range(a, a + n))
+_mid = lambda a: [a] + [a + 1 + (5 * i) % 14 for i in range(14)] + [a + 15]  # ends right, middle out of order
+RUN_TABLES = {
+    "all-runs": (_asc(1) + _asc(40) + _asc(17) + _asc(60, 8), [1, 1, 1, 0]),
+    "no-runs": ([1 + (37 * i) % 101 for i in range(56)], [0, 0, 0, 0]),
+    "mixed": (_asc(5) + [21 + (7 * i) % 16 for i in range(16)] + _asc(80) + _asc(100, 8), [1, 0, 1, 0]),
+    "descending-block": (_asc(1) + _asc(40)[::-1] + _asc(17) + _asc(60, 8), [1, 0, 1, 0]),
+    "ends-match-middle-shuffled": (_asc(1) + _mid(40) + _asc(17) + _asc(60, 8), [1, 0, 1, 0]),
+    "one-step-off": (_asc(1) + _asc(40, 8) + _asc(49, 8) + _asc(17) + _asc(60, 8), [1, 0, 1, 0]),
+    "run-ends-at-the-pools-last-page": (_asc(1) + _asc(40) + _asc(RUN_POOL - 16) + _asc(60, 8), [1, 1, 1, 0]),
+}
+# (start of the window, live slots): a context that ends inside block 2 (its
+# first two blocks whole, the third fetched page by page whatever its flag),
+# one that fills the three blocks exactly, one that reaches the cut block.
+RUN_CONTEXTS = [(2 * 256 + 70, 3), (3 * 256 - 8, 8), (3 * 256 + 40, 1)]
+
+
+def _run_table(name, B):
+    from mcpx.engine.kernels.paged_attention import page_run_flags
+
+    pages, want = RUN_TABLES[name]
+    assert len(pages) == 56 and sorted(set(pages)) == sorted(pages) and max(pages) < RUN_POOL
+    # the other rows read the same pages in an order with no run in it
+    table = np.asarray([pages + [0] * 8] + [pages[::-1] + [0] * 8] * (B - 1), np.int32)
+    flags = np.asarray(page_run_flags(jnp.asarray(table), 16, RUN_POOL))
+    assert flags.tolist() == [want] + [[0] * 4] * (B - 1)
+    return jnp.asarray(table), jnp.asarray(flags)
+
+
+def _pools(seed, layers, width_k, width_v, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (
+        jax.random.normal(ks[0], (1, layers, RUN_POOL, 16, width_k), dtype),
+        jax.random.normal(ks[1], (1, layers, RUN_POOL, 16, width_v), dtype),
+    )
+
+
+def _run_case_attention(name, selecting):
+    B, S, H, r, w = 2, 8, 4, 32, 128
+    table, flags = _run_table(name, B)
+    rope, latent = _pools(3, 2, w + (128 if selecting else 0), r, jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q_latent = jax.random.normal(ks[0], (B, S, H, r), jnp.float32)
+    q_rope = jax.random.normal(ks[1], (B, S, H, w), jnp.float32)
+    select = None
+    if selecting:
+        picked = jax.random.bernoulli(ks[2], 0.4, (B, S, 64 * 16))
+        select = jnp.where(picked.at[:, :, 0].set(True), 0.0, NEG_INF).astype(jnp.float32)
+    for start, n in RUN_CONTEXTS:
+        rows = (jnp.asarray([start, start - 100], jnp.int32), jnp.asarray([n, 1], jnp.int32), 1, select)
+        args = (q_latent, q_rope, rope, latent, table)
+        by_run = np.asarray(_interpreted(None)(*args, *rows, flags))
+        by_page = np.asarray(_interpreted(None)(*args, *rows, jnp.zeros_like(flags)))
+        assert np.array_equal(by_run, by_page), (name, start)
+        ref_rope = rope[..., :w]  # the reference reads the rotated key's lanes alone
+        ref = np.asarray(latent_paged_attention_reference(q_latent, q_rope, ref_rope, latent, table, *rows, scale=0.13))
+        np.testing.assert_allclose(by_run, ref, rtol=2e-5, atol=2e-5)
+        assert np.abs(by_run[0, :n]).min(axis=(1, 2)).max() > 0
+
+
+@pytest.mark.parametrize("name", list(RUN_TABLES))
+def test_the_latent_kernel_fetches_a_run_in_one_copy_and_nothing_moves(name):
+    """A key block whose pages lie side by side is one DMA a pool: bit for bit
+    the page-by-page fetch (the same call with every flag 0) and within
+    tolerance of the jnp reference, at contexts that end inside a block, on a
+    block's edge and in the block the table cuts short."""
+    _run_case_attention(name, selecting=False)
+
+
+@pytest.mark.parametrize("name", list(RUN_TABLES))
+def test_the_selecting_kernel_fetches_a_run_in_one_copy_and_nothing_moves(name):
+    """The same under a selection, the rotated key the first 128 lanes of a
+    256-lane page row (a run's copy slices the lanes of 16 pages at once)."""
+    _run_case_attention(name, selecting=True)
+
+
+@pytest.mark.parametrize("name", list(RUN_TABLES))
+def test_the_index_kernel_fetches_a_run_in_one_copy_and_chooses_the_same_keys(name):
+    """The index keys (lanes 128 on of the rotated key's page rows) of a run
+    in one copy: the selection is EQUAL to the page-by-page fetch's and to
+    the jnp reference's."""
+    from mcpx.engine.kernels.paged_attention import index_select_reference, lightning_indexer
+
+    B, S, Hi, di, lane0, topk = 2, 8, 4, 32, 128, 64
+    table, flags = _run_table(name, B)
+    pool, _ = _pools(9, 2, lane0 + di, 8, jnp.bfloat16)
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    q_i = jax.random.normal(ks[0], (B, S, Hi, di), jnp.bfloat16)
+    w_i = jax.random.normal(ks[1], (B, S, Hi), jnp.float32)
+    for start, n in RUN_CONTEXTS:
+        args = (q_i, w_i, pool, table, jnp.asarray([start, 30], jnp.int32), jnp.asarray([n, 1], jnp.int32), jnp.int32(1))
+        by_run = np.asarray(lightning_indexer(*args, flags, topk=topk, lane0=lane0, interpret=True))
+        by_page = np.asarray(lightning_indexer(*args, jnp.zeros_like(flags), topk=topk, lane0=lane0, interpret=True))
+        ref = np.asarray(index_select_reference(*args, topk=topk, lane0=lane0))
+        assert np.array_equal(by_run, by_page) and np.array_equal(by_run, ref), (name, start)
+        assert (by_run[0, :n] == 0.0).sum(axis=-1).tolist() == [topk] * n
+
+
+def test_the_flag_is_what_the_kernels_fetch_by():
+    """A flag set on a block that is NO run makes the kernels read the 16
+    pages after the block's first id, not the table's: what they return is
+    what the table with that run written into it gives. (No caller sets such
+    a flag: ``page_run_flags`` checks every step. It shows that the flag, and
+    not a second look at the table, chooses the fetch.)"""
+    from mcpx.engine.kernels.paged_attention import lightning_indexer
+
+    B, S, H, r, w = 1, 8, 4, 32, 128
+    pages = RUN_TABLES["no-runs"][0]
+    table = jnp.asarray([pages + [0] * 8], jnp.int32)
+    as_run = jnp.asarray([pages[:16] + _asc(pages[16]) + pages[32:] + [0] * 8], jnp.int32)
+    forced = jnp.asarray([[0, 1, 0, 0]], jnp.int32)
+    rope, latent = _pools(3, 1, w + 32, r, jnp.bfloat16)
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    q_latent = jax.random.normal(ks[0], (B, S, H, r), jnp.bfloat16)
+    q_rope = jax.random.normal(ks[1], (B, S, H, w), jnp.bfloat16)
+    rows = (jnp.asarray([3 * 256 - 8], jnp.int32), jnp.asarray([8], jnp.int32), 0, None)
+    call = _interpreted(None)
+    got = np.asarray(call(q_latent, q_rope, rope, latent, table, *rows, forced))
+    assert np.array_equal(got, np.asarray(call(q_latent, q_rope, rope, latent, as_run, *rows)))
+    assert not np.array_equal(got, np.asarray(call(q_latent, q_rope, rope, latent, table, *rows)))
+    q_i = jax.random.normal(ks[2], (B, S, 4, 32), jnp.bfloat16)
+    w_i = jax.random.normal(ks[3], (B, S, 4), jnp.float32)
+    index = functools.partial(lightning_indexer, topk=64, lane0=w, interpret=True)
+    got = np.asarray(index(q_i, w_i, rope, table, *rows[:3], forced))
+    assert np.array_equal(got, np.asarray(index(q_i, w_i, rope, as_run, *rows[:3])))
+    assert not np.array_equal(got, np.asarray(index(q_i, w_i, rope, table, *rows[:3])))
+
+
+@pytest.mark.parametrize("pages, pool, want", [
+    (_asc(1) + _asc(17), 33, [1, 1]),
+    (_asc(1) + _asc(18), 33, [1, 0]),  # ids past the pool: the last would be page 33 of 33
+    (_asc(0) + [0] * 16, 33, [1, 0]),  # an idle row's zeros are no run; the null page may start one
+    (_asc(1, 20), 33, [1, 0]),  # a table 20 wide: its second block is cut short
+    ([3, 4, 5, 6], 33, [1]),  # a table narrower than a key block is one block of its width
+    ([3, 5, 4, 6], 33, [0]),
+])
+def test_page_run_flags_check_every_step_and_the_pools_bounds(pages, pool, want):
+    from mcpx.engine.kernels.paged_attention import latent_key_pages, page_run_flags
+
+    table = jnp.asarray([pages], jnp.int32)
+    assert latent_key_pages(16, len(pages)) == min(16, len(pages))
+    assert np.asarray(page_run_flags(table, 16, pool)).tolist() == [want]
+
+
+@pytest.mark.parametrize("S, H", [(8, 128), (64, 128), (8, 1024)])
+def test_the_key_block_counters_are_what_the_kernels_programs_fetch(S, H):
+    """``latent_key_blocks`` walks the kernel's grid: a (row, head block,
+    query block) program fetches ``cdiv(pages, 16)`` key blocks through its
+    last live query, those before its last page's block whole; a run among
+    the whole ones counts. Times the layers it is the forward's counter."""
+    from mcpx.engine.kernels.paged_attention import latent_key_blocks
+    from mcpx.models.gemma import moe
+    from mcpx.models.gemma.config import GemmaConfig
+
+    table, flags = _run_table("mixed", 3)
+    flags = flags.at[1].set(jnp.asarray([1, 1, 1, 0]))  # a second row of three runs
+    starts, q_lens = np.asarray([600, 3 * 256 - 30, 90]), np.asarray([min(S, 22), min(S, 40), 0])
+    sq, g, _ = _latent_blocking(S, H, 16, 64)
+    blocks = runs = 0
+    for b in range(3):
+        for q0 in range(0, S, sq):
+            qn = int(np.clip(q_lens[b] - q0, 0, sq))
+            n_pages = min(-(-(starts[b] + q0 + qn) // 16), 64) if qn else 0
+            blocks += -(-n_pages // 16) * (H // g)
+            runs += sum(int(flags[b, i]) for i in range(n_pages // 16)) * (H // g)
+    got = latent_key_blocks(flags, jnp.asarray(starts), jnp.asarray(q_lens), S, H, 16, 64)
+    assert (int(got[0]), int(got[1])) == (blocks, runs) and 0 < runs < blocks
+    cfg = GemmaConfig(
+        vocab_size=384, d_model=64, n_layers=3, n_heads=H, n_kv_heads=1, head_dim=16, d_ff=64,
+        attention="latent", q_lora_rank=16, kv_lora_rank=32, qk_rope_head_dim=16, v_head_dim=16,
+        n_experts=4, n_experts_per_tok=2, d_expert=16, norm_plus_one=False,
+    )
+    stats = moe.add_forward_stats(
+        cfg, moe.moe_stats_init(cfg), jnp.asarray(starts + q_lens), jnp.asarray(q_lens), S, (flags, 16, 64)
+    )
+    assert stats[-2:].tolist() == [blocks * 3, runs * 3]
+    # no table given (a dense prefill reads no page): no block counted
+    assert moe.add_forward_stats(cfg, moe.moe_stats_init(cfg), jnp.asarray(q_lens), jnp.asarray(q_lens))[-2:].tolist() == [0, 0]

@@ -329,7 +329,7 @@ def test_a_forward_counts_what_it_routed_and_what_its_attention_read():
     own = held + moe.LAYER_STATS
     assert stats.shape == (own + moe.FORWARD_STATS + moe.LATENT_STATS,)
     # the expanded prefill reads no page: no slot of the absorbed kernel's
-    assert stats[own:].tolist() == [29 * 2 * 3, 29 * 4, 2 * 4, 0]
+    assert stats[own:].tolist() == [29 * 2 * 3, 29 * 4, 2 * 4, 0, 0, 0]
     assert 0 < int(stats[:held].sum()) < 29 * 2 * 3  # this share's part of the routing
     _, _, pools, table = _prefilled(cfg, params, seq, lens)
     mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
@@ -337,7 +337,8 @@ def test_a_forward_counts_what_it_routed_and_what_its_attention_read():
         params, cfg, seq[:, :8], lens, table, pools, use_pallas=False, mesh=mesh,
         q_lens=jnp.asarray([3, 0]), moe_stats=True)
     # the idle row reads and routes nothing; the live row's 3 queries take the rung of 4 slots
-    assert stats[own:].tolist() == [3 * 2 * 3, 23 * 4, 1 * 4, 4 * 4]
+    # and fetch ONE key block a layer, a part of one: no run
+    assert stats[own:].tolist() == [3 * 2 * 3, 23 * 4, 1 * 4, 4 * 4, 1 * 4, 0]
 
 
 # ------------------------------------------------ the comparison, and controls
@@ -524,6 +525,84 @@ def test_the_engine_serves_the_same_tokens_at_every_segment_length():
 
     cfg_layers = 4
     asyncio.run(go())
+
+
+def test_the_segments_count_the_key_blocks_fetched_and_those_fetched_as_runs():
+    """``attn_key_blocks`` / ``attn_run_blocks`` are what the engine's page
+    tables imply: a row of 17..20 pages fetches two key blocks a layer a
+    forward, the first whole; off a fresh pool its pages ascend (share 1/2:
+    every WHOLE block is a run), off a pool whose free pages are the odd ids
+    alone no block is a run, and the tokens are the same (a page id enters no
+    arithmetic). A direct forward over a table of whole blocks reads share 1,
+    the same pages shuffled 0."""
+    import asyncio
+
+    from mcpx.engine.engine import InferenceEngine
+
+    cfg = small(max_seq_len=512)
+    config = MCPXConfig.from_dict({
+        "model": {"max_seq_len": 512},
+        "engine": {"max_batch_size": 2, "max_decode_len": 16, "kv_page_size": 16, "max_pages_per_seq": 32,
+                   "temperature": 0.0, "use_pallas": True, "interpret": True, "prefix_cache": False,
+                   "warmup_compile": False},
+    })
+
+    async def go():
+        eng = InferenceEngine(config, model_cfg=cfg)
+        await eng.start()
+        try:
+            prompt = eng.tokenizer.encode("Key blocks of sixteen pages. " * 40)[:280]
+            assert len(prompt) == 280
+
+            async def serve():
+                before = dict(eng._layer_kind_totals)
+                r = await eng.generate(prompt, max_new_tokens=12, constrained=True, temperature=0.0)
+                for _ in range(200):  # the worker harvests the last segment in its own time
+                    if not eng._inflight:
+                        break
+                    await asyncio.sleep(0.05)
+                now = eng._layer_kind_totals
+                return r.token_ids, {k: now[k] - before.get(k, 0) for k in now}
+
+            tokens, fresh = await serve()
+            assert fresh["attn_row_calls"] > 0
+            assert fresh["attn_key_blocks"] == 2 * fresh["attn_row_calls"]
+            assert fresh["attn_run_blocks"] == fresh["attn_row_calls"]  # the whole block of the two
+            alloc = eng._allocator
+            singles = [alloc.allocate(("frag", i), 1)[0] for i in range(alloc.stats().free_pages)]
+            for i, page in enumerate(singles):
+                if page % 2:
+                    alloc.free(("frag", i))
+            again, odd = await serve()
+            assert again == tokens
+            assert odd["attn_key_blocks"] == fresh["attn_key_blocks"] and odd["attn_run_blocks"] == 0
+            # their Prometheus twins, over both servings
+            assert eng.metrics.attn_key_blocks._value.get() == 2 * fresh["attn_key_blocks"]
+            assert eng.metrics.attn_run_blocks._value.get() == fresh["attn_run_blocks"]
+            text = eng.metrics.render().decode()
+            assert "mcpx_engine_attn_key_blocks_total" in text and "mcpx_engine_attn_run_blocks_total" in text
+        finally:
+            await eng.aclose()
+
+    asyncio.run(go())
+
+    # one forward over whole blocks: every block a run, or none
+    B, S, pages = 2, 8, 32
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    pools = init_paged_kv(cfg, B * pages + 1, 16)
+    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    ids = 1 + np.arange(B * pages, dtype=np.int32).reshape(B, pages)
+    shuffled = np.random.default_rng(0).permuted(ids, axis=1)
+    first = cfg.n_experts_held + moe.LAYER_STATS + moe.FORWARD_STATS
+    for table, share in ((ids, 1.0), (shuffled, 0.0)):
+        out = decode_chunk_paged(
+            params, cfg, jnp.ones((B, S), jnp.int32), jnp.full((B,), pages * 16 - S, jnp.int32),
+            jnp.asarray(table), pools, use_pallas=True, interpret=True, mesh=mesh,
+            q_lens=jnp.asarray([S, 1], jnp.int32), moe_stats=True,
+        )
+        slots, blocks, runs = (int(c) for c in out[2][first : first + moe.LATENT_STATS])
+        # both rows read through their 32nd page: two whole blocks a row a layer
+        assert (blocks, runs) == (4 * cfg.n_layers, round(4 * cfg.n_layers * share)) and slots > 0
 
 
 def test_spilled_latent_pages_come_back_as_they_left():

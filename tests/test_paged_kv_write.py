@@ -281,6 +281,101 @@ def test_allocator_respects_max_pages_per_seq():
         a.allocate(1, 100)
 
 
+def _free_then_allocate(a):
+    first = a.allocate("a", 20 * 8)
+    a.free("a")
+    return first, a.allocate("b", 20 * 8)
+
+
+def _interleaved_frees(a):
+    for sid, pages in (("a", 5), ("b", 7), ("c", 3), ("d", 9)):
+        a.allocate(sid, pages * 8)
+    for sid in ("c", "a", "d"):  # b (pages 6..12) stays
+        a.free(sid)
+    return list(range(1, 6)) + list(range(13, 29)), a.allocate("e", 21 * 8)
+
+
+def _split_then_free(a):
+    a.allocate("a", 10 * 8)
+    a.allocate("hold", 8)  # page 11
+    head = a.split("a", "head", 4)
+    a.free("a")  # the remainder 5..10 first, then the head 1..4
+    a.free("head")
+    assert head == [1, 2, 3, 4]
+    return list(range(1, 11)) + [12, 13], a.allocate("b", 12 * 8)
+
+
+def _extend_after_frees(a):
+    a.allocate("a", 3 * 8)
+    a.allocate("b", 2 * 8)
+    a.allocate("c", 3 * 8)
+    a.free("b")  # 4, 5
+    return [6, 7, 8, 4, 5, 9, 10], a.extend("c", 7 * 8)
+
+
+@pytest.mark.parametrize(
+    "scenario", [_free_then_allocate, _interleaved_frees, _split_then_free, _extend_after_frees],
+    ids=lambda f: f.__name__.strip("_"),
+)
+def test_allocator_hands_out_the_lowest_free_ids_ascending(scenario):
+    """Whatever was freed and in what order, an allocation (and what
+    ``extend`` adds) takes the lowest free ids, ascending: pages lie side by
+    side in the pools wherever the free ids do."""
+    a = PageAllocator(n_pages=64, page_size=8, max_pages_per_seq=32)
+    want, got = scenario(a)
+    assert got == want
+    a.check_invariants()
+
+
+def test_a_head_built_in_chunks_after_the_warm_ups_rows_were_freed_is_one_run():
+    """deepseek's cell: the warm-up fills rows of 448 pages and frees them,
+    then the first plan builds the catalogue head in chunks of 1,024 tokens,
+    each a sequence of its own (``_ensure_prefix``): their pages, chunk after
+    chunk in a row's table, are one ascending run, so every whole key block
+    of the head is fetched in one copy."""
+    import random
+
+    from mcpx.engine.kernels.paged_attention import page_run_flags
+
+    a = PageAllocator(n_pages=8 * 512 + 1, page_size=16, max_pages_per_seq=512)
+    rows = list(range(8))
+    for r in rows:
+        a.allocate(("warm", r), 7168)
+    random.Random(0).shuffle(rows)
+    for r in rows:
+        a.free(("warm", r))
+    a.allocate(("tree", 0), 3 * 16)  # a short node the warm plans left resident
+    head = []
+    for chunk, tokens in enumerate([1024] * 6 + [41 * 16]):
+        head += a.allocate(("head", chunk), tokens)
+    assert head == list(range(head[0], head[0] + 425))
+    own = a.allocate(("row", 0), 6 * 16)
+    table = np.zeros((1, 512), np.int32)
+    table[0, : len(head) + len(own)] = head + own
+    flags = np.asarray(page_run_flags(jnp.asarray(table), 16, a.n_pages))[0]
+    # (the heap keeps handing out what follows: the row's own pages extend the run)
+    assert flags[:26].all() and not flags[27:].any()
+    a.check_invariants()
+
+
+def test_allocator_free_costs_the_pages_freed_not_the_pool():
+    """``free`` (and ``allocate``) work on the pages they move, log(pool)
+    each: no sort and no scan of the free list, which a pool of a million
+    pages would show as milliseconds."""
+    import time
+
+    a = PageAllocator(n_pages=1_000_001, page_size=16, max_pages_per_seq=64)
+    best = float("inf")
+    for i in range(5):
+        a.allocate(i, 16 * 16)
+        a.allocate(("hold", i), 16)
+        t0 = time.perf_counter()
+        a.free(i)
+        best = min(best, time.perf_counter() - t0)
+    assert best < 1e-3
+    assert a.allocate("again", 16 * 16)[:3] == [1, 2, 3]
+
+
 # --------------------------------------------------------- structure
 # The benchmark's slab (benchmarks/chip/configs/olmo2-1b.json): 8 rows, a
 # page table 32 wide over a 257-page pool, MHA 16/16 at head_dim 128.
@@ -443,6 +538,27 @@ def test_compiled_for_v5e_the_latent_kernel_with_an_arm_a_rung(one_v5e, H, p_max
     ] + ([sd((B, S, p_max * psz), jnp.float32)] if selecting else [])
     assert latent_rungs(S) == (1, 2, 4, 8)
     compiled = _compile_uncached(functools.partial(ragged_paged_attention_latent, scale=0.13), *shapes)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compiled_for_v5e_the_index_kernel_fetches_a_run_of_index_lanes(one_v5e):
+    """The index kernel at the published widths (64 index heads x 128 behind
+    the rotated key's 128 lanes, a table of 512 pages): Mosaic takes the run's
+    one copy, a 16-page slice of the pool's page axis with the index lanes
+    sliced out of every page row, beside the page-by-page loop."""
+    import functools
+
+    from mcpx.engine.kernels.paged_attention import lightning_indexer
+
+    _, replicated = one_v5e
+    B, S, Hi, di, L, n_pages, psz, p_max = 8, 8, 64, 128, 2, 33, 16, 512
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    sd = functools.partial(jax.ShapeDtypeStruct, sharding=replicated)
+    compiled = _compile_uncached(
+        functools.partial(lightning_indexer, topk=2048, lane0=128),
+        sd((B, S, Hi, di), bf), sd((B, S, Hi), f32), sd((1, L, n_pages, psz, 128 + di), bf),
+        sd((B, p_max), i32), sd((B,), i32), sd((B,), i32), sd((), i32), sd((B, p_max // 16), i32),
+    )
     assert "tpu_custom_call" in compiled.as_text()
 
 

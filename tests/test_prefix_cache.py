@@ -71,6 +71,27 @@ def test_match_insert_split_basic():
     cache.check_invariants()
 
 
+def test_evicted_and_split_runs_come_back_in_ascending_order():
+    """The tree's own returns (an eviction, a split edge's two halves, a
+    rollback) go through ``PageAllocator.free``: whatever order they come
+    back in, the next allocation takes the lowest free ids, ascending."""
+    alloc, cache = make_cache()
+    insert_all(cache, blocks(1, 2, 3, 4) + [7])  # pages 1..4
+    insert_all(cache, blocks(5, 6) + [7])  # 5, 6
+    insert_all(cache, blocks(1, 2, 8) + [7])  # splits the first edge at 2 blocks; page 7
+    held = alloc.allocate("row", 2 * PAGE)  # 8, 9
+    assert held == [8, 9]
+    assert cache.evict(need_tokens=(alloc.n_pages - 1) * PAGE) > 0  # everything unpinned goes
+    assert len(cache) == 0
+    assert alloc.allocate("next", 9 * PAGE) == [1, 2, 3, 4, 5, 6, 7, 10, 11]
+    node = cache.insert(blocks(9, 9), 0, 2 * PAGE)
+    assert node.pages == [12, 13]
+    cache.rollback(node)
+    alloc.free("row")
+    assert alloc.allocate("last", 3 * PAGE) == [8, 9, 12]
+    alloc.check_invariants()
+
+
 def test_within_page_divergence_shares_nothing_but_both_cache():
     _alloc, cache = make_cache()
     a = [5, 6, 7, 8, 5, 5, 5, 5, 9]
