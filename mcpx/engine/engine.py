@@ -77,12 +77,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from mcpx.core.config import MCPXConfig
 from mcpx.core.errors import ConfigError, EngineError
 from mcpx.engine.kv_cache import (
-    PageAllocator, commit_prefill_to_pages, init_paged_kv, init_state_pool, write_prefill_state,
+    PageAllocator, commit_prefill_key_sums, commit_prefill_to_pages, init_paged_kv, init_state_pool,
+    write_prefill_state,
 )
 from mcpx.engine.pacing import SegmentPacer, hold_until
 from mcpx.engine.paged_decode import decode_chunk_paged, keep_window
 from mcpx.models.gemma.moe import (
-    FORWARD_STATS, INDEX_STATS, LATENT_STATS, LAYER_STATS, forward_weight_bytes, moe_stats_init,
+    BLOCK_STATS, FORWARD_STATS, INDEX_STATS, LATENT_STATS, LAYER_STATS, forward_weight_bytes,
+    moe_stats_init,
 )
 from mcpx.engine.prefix_cache import PrefixNode, RadixPrefixCache
 from mcpx.engine.sampling import accept_rows, sample, sample_rows, sample_window_rows
@@ -486,9 +488,15 @@ class InferenceEngine:
                     f"this model keeps a recurrent state a row (layer_pattern), which {asked} "
                     "does not carry"
                 )
-        # Which counters the segment returns beside its state (sparse
-        # feed-forward, windowed attention); a default block returns none.
-        self._segment_stats = (bool(mc.n_experts), mc.layer_windows() is not None)
+        if mc.n_block_layers and ecfg.kv_page_size != mc.pool_stride:
+            raise ConfigError(
+                f"this model pools its keys every {mc.pool_stride} tokens (pool_stride): a page "
+                f"holds one stride, engine.kv_page_size={ecfg.kv_page_size} does not"
+            )
+        # Which counters the segment returns beside its state (a sparse
+        # feed-forward's, or a mixer + feed-forward pattern's own; windowed
+        # attention); a default block returns none.
+        self._segment_stats = (bool(mc.n_experts) or mc.mixer_ffn, mc.layer_windows() is not None)
         self.grammar: PlanGrammar = build_plan_grammar(self.tokenizer)
         self.metrics = metrics or Metrics()
         # Resolved kernel route, decided at construction so a COLD engine
@@ -523,7 +531,7 @@ class InferenceEngine:
         # `pallas=true` can then never mask a jnp fork OR an idle path.
         # Worker-thread writes, GIL-atomic cross-thread reads.
         self._pallas_dispatches = {  # mcpx: owner[engine-worker, atomic]
-            "decode": 0, "prefill": 0, "spec_verify": 0, "ssm": 0,
+            "decode": 0, "prefill": 0, "spec_verify": 0, "ssm": 0, "gather": 0,
         }
         self.state = "cold"
         self._state_lock = threading.Lock()
@@ -544,6 +552,12 @@ class InferenceEngine:
         # a recurrent layer starts from): mcpx_engine_prefix_state_total
         # {event="miss"}.
         self._prefix_state_misses = 0  # mcpx: owner[engine-worker, atomic]
+        # ... and those that started from a copy of the declared head's end
+        # state ({event="hit"}; ``GemmaConfig.head_state``). ``_head_state``
+        # is the head whose END STATE the pool's last slot holds (None: no
+        # head's), written by ``_ensure_prefix`` alone.
+        self._prefix_state_hits = 0  # mcpx: owner[engine-worker, atomic]
+        self._head_state: Optional[tuple] = None  # mcpx: owner[engine-worker]
         self._dfa_cache: "OrderedDict[tuple, tuple]" = OrderedDict()  # mcpx: owner[engine-worker]
         # Heterogeneous batching (EngineConfig.hetero_batch): the stacked-DFA
         # slot table. ``_dfa_slots[k]`` is the grammar whose padded tables
@@ -1121,6 +1135,20 @@ class InferenceEngine:
                     None if one else "the state pool's kernel runs on one device; a mesh takes the jnp form"
                 ),
             }
+        if self.model_cfg.n_block_layers:
+            # Block-selecting attention: a decode window's calls run the
+            # ragged kernel over each (slot, KV head)'s own page list, where
+            # a row's table can hold a block a query drops.
+            from mcpx.models.gemma.sparse import selects
+
+            wide = selects(self.model_cfg, ecfg.max_pages_per_seq * ecfg.kv_page_size)
+            more["gather"] = {
+                "engaged": on and wide,
+                "dispatches": d["gather"],
+                "reason": blocked if not on else (
+                    None if wide else "a row's pages hold no more than the blocks every query keeps"
+                ),
+            }
         return {
             "enabled": on,
             "interpret": bool(ecfg.interpret),
@@ -1133,7 +1161,7 @@ class InferenceEngine:
                     "idle: prefix_cache=off (no suffix prefills)"
                     if not ecfg.prefix_cache
                     else "idle: a model with recurrent layers prefills whole (no suffix prefills)"
-                    if self.model_cfg.hybrid
+                    if self.model_cfg.hybrid and not self.model_cfg.head_state
                     else None,
                 ),
                 "spec_verify": path(
@@ -1202,7 +1230,13 @@ class InferenceEngine:
                     "window_max_forwards": self._window_max_total,
                     **self._layer_kind_totals,
                     **(
-                        {"prefix_state_miss": self._prefix_state_misses}
+                        {
+                            "prefix_state_miss": self._prefix_state_misses,
+                            **(
+                                {"prefix_state_hit": self._prefix_state_hits}
+                                if self.model_cfg.head_state else {}
+                            ),
+                        }
                         if self.model_cfg.hybrid else {}
                     ),
                 }
@@ -1326,6 +1360,10 @@ class InferenceEngine:
         )
         jax.block_until_ready(self._params)
         init_s = time.monotonic() - t_weights
+        if self.model_cfg.mixer_ffn:
+            # No routed expert: every leaf but the embedding is read whole.
+            held = sum(a.nbytes for a in jax.tree.leaves(self._params))
+            self._weight_bytes = (0, held - self._params["embed"].nbytes)
         if self.model_cfg.n_experts:
             self._weight_bytes = forward_weight_bytes(self.model_cfg, self._params)
             # One sample an expert held, from 0: an expert no token ever
@@ -1373,7 +1411,7 @@ class InferenceEngine:
         self._jit_suffix_prefill = wrap(
             "suffix_prefill",
             jax.jit(
-                self._suffix_prefill_impl, donate_argnames=("paged_k", "paged_v")
+                self._suffix_prefill_impl, donate_argnames=("paged_k", "paged_v", "state")
             ),
         )
         # out_buf is NOT donated: the pipelined worker reads a LAGGED
@@ -1702,11 +1740,12 @@ class InferenceEngine:
         )
         self._paged_kv = {"k": k_p, "v": v_p}
         self._state_pool = state
-        if ecfg.prefix_cache and not self.model_cfg.hybrid:
+        if ecfg.prefix_cache and (not self.model_cfg.hybrid or self.model_cfg.head_state):
             # Shared-prefix serving prefills SUFFIXES through the
-            # chunked path; compile it for the same buckets. (A model with
-            # recurrent layers never takes it: its rows prefill whole.)
-            last, k_p, v_p, _ = self._jit_suffix_prefill(
+            # chunked path; compile it for the same buckets. (A model whose
+            # recurrent layers have no suffix route never takes it: its rows
+            # prefill whole.)
+            last, k_p, v_p, _, self._state_pool = self._jit_suffix_prefill(
                 self._params,
                 self._put(tokens, self._row_spec(A, 1)),
                 self._put(seq_lens, self._row_spec(A)),
@@ -1714,6 +1753,9 @@ class InferenceEngine:
                 self._put(table, self._row_spec(A, 1)),
                 self._paged_kv["k"],
                 self._paged_kv["v"],
+                self._state_pool,
+                self._cohort_slots(A),
+                self._cohort_slots(A),
             )
             self._paged_kv = {"k": k_p, "v": v_p}
         return last
@@ -2091,7 +2133,7 @@ class InferenceEngine:
                     if self.model_cfg.hybrid:
                         # The tokens this row's prefill moved its recurrent
                         # state by, over the Mamba layers: all it prefilled.
-                        pfx_attrs["ssm_prefill_tokens"] = n_pf * self.model_cfg.n_mamba_layers
+                        pfx_attrs["ssm_prefill_tokens"] = n_pf * self.model_cfg.n_recurrent_layers
                     r.span.child(
                         "engine.prefill",
                         t0=t_admit0,
@@ -2440,10 +2482,15 @@ class InferenceEngine:
         )
         if cfg.hybrid:
             state = write_prefill_state(state, slots, dense["ssm"])
+        if cfg.n_block_layers:
+            state = {**state, "ksum": commit_prefill_key_sums(
+                state["ksum"], dense["k"], page_table, self.config.engine.kv_page_size
+            )}
         return last, paged["k"], paged["v"], moe, state
 
     def _suffix_prefill_impl(
-        self, params, tokens, seq_lens, positions, page_table, paged_k, paged_v
+        self, params, tokens, seq_lens, positions, page_table, paged_k, paged_v,
+        state=None, src=None, slots=None,
     ):
         """Prefill only the prompt SUFFIX: one chunked forward whose queries
         sit at positions ``positions..positions+S-1`` and attend the shared
@@ -2456,7 +2503,14 @@ class InferenceEngine:
         seven PRs is the bug class mcpxlint's ``hardcoded-kernel-fallback``
         rule now polices): per-row suffix lengths are the kernel's
         ``q_lens``, so short-suffix rows (warm replans prefilling ~1 page)
-        stream pages for their own width, not the cohort bucket's."""
+        stream pages for their own width, not the cohort bucket's.
+
+        ``state``, ``src``, ``slots`` (a model whose recurrent layers have a
+        suffix route, ``GemmaConfig.head_state``; {} and None otherwise): the
+        state pool, the slot each row's state is READ from (the declared
+        head's; out of range: an empty state) and the slot the state AT the
+        row's last token is written to, nothing pending (a padding row's is
+        out of range and dropped)."""
         cfg = self.model_cfg
         last, kv, moe = decode_chunk_paged(
             params,
@@ -2464,15 +2518,17 @@ class InferenceEngine:
             tokens,
             positions,
             page_table,
-            {"k": paged_k, "v": paged_v},
+            {"k": paged_k, "v": paged_v, **({"state": state} if cfg.head_state else {})},
             use_pallas=self._use_pallas,
             interpret=self.config.engine.interpret,
             mesh=self._mesh,
             logits_at=seq_lens - 1,  # [A, V]: suffix-final logits only
             q_lens=seq_lens,
             moe_stats=True,  # as _prefill_impl: None from a dense model
+            state_slots=(src, slots) if cfg.head_state else None,
+            commit=cfg.head_state,
         )
-        return last, kv["k"], kv["v"], moe
+        return last, kv["k"], kv["v"], moe, kv.get("state", state)
 
     # --- tiered KV cache: device<->host page-run copies -------------------
     def _spill_gather_impl(self, paged_k, paged_v, pages):
@@ -2783,12 +2839,28 @@ class InferenceEngine:
         resident. Worker-thread only."""
         P = len(key)
         capacity = self.config.engine.max_pages_per_seq * self.config.engine.kv_page_size
+        stateful = self.model_cfg.head_state
+        if stateful:
+            # The head's END STATE lives in the state pool's last slot, each
+            # chunk of the build handing the next its state through it. ONE
+            # such state exists: pages of this head that are resident with no
+            # state to continue from (the slot holds another head's end, or
+            # was reset) are not built over, the head gets no state, and rows
+            # that reach it prefill whole and count as misses.
+            n, _, node = self._prefix_cache.match(key, cap=P, record=False)
+            if self._head_state == key and n == P:
+                return node
+            if n > 0:
+                return None
+            self._head_state = None  # the slot is overwritten from here on
         while True:
             n = self._prefix_cache.match(key, cap=P, record=False)[0]
             eligible = [b for b in self._prefill_buckets if b + n <= capacity]
             end = P if not eligible or P - n <= eligible[-1] else n + eligible[-1]
             node = self._build_prefix(key[:end], tenant)
             if node is None or end == P:
+                if stateful and node is not None:
+                    self._head_state = key
                 return node
 
     def _build_prefix(
@@ -2830,10 +2902,16 @@ class InferenceEngine:
         table[0, n // ecfg.kv_page_size : P // ecfg.kv_page_size] = node.pages
         tokens = np.full((1, T), self.tokenizer.pad_id, np.int32)
         tokens[0, :R] = key[n:]
+        # A head's state (``GemmaConfig.head_state``) is read from and written
+        # to the pool's last slot: the chunk before left it there.
+        head_slot = (
+            self._cohort_slots(1, [self.config.engine.max_batch_size])
+            if self.model_cfg.head_state else None
+        )
         try:
             if n > 0:
                 # Continue from the resident head: prefill only [n, P).
-                last, k_p, v_p, _ = self._jit_suffix_prefill(
+                last, k_p, v_p, _, self._state_pool = self._jit_suffix_prefill(
                     self._params,
                     self._put(tokens, self._row_spec(1, 1)),
                     self._put(np.asarray([R], np.int32), self._row_spec(1)),
@@ -2841,6 +2919,9 @@ class InferenceEngine:
                     self._put(table, self._row_spec(1, 1)),
                     self._paged_kv["k"],
                     self._paged_kv["v"],
+                    self._state_pool,
+                    head_slot,
+                    head_slot,
                 )
                 # Every suffix-prefill dispatch counts toward the
                 # prefill path's engagement report, not just the
@@ -2857,7 +2938,7 @@ class InferenceEngine:
                     self._paged_kv["v"],
                     self._put(table, self._row_spec(1, 1)),
                     self._state_pool,
-                    None,
+                    head_slot,
                     T=T,
                 )
             self._paged_kv = {"k": k_p, "v": v_p}
@@ -2977,7 +3058,7 @@ class InferenceEngine:
             of their windows, and the counters with those tokens added."""
             if not cfg.hybrid:
                 return ssm0, ms
-            tokens = jnp.sum(adv).astype(jnp.int32) * cfg.n_mamba_layers
+            tokens = jnp.sum(adv).astype(jnp.int32) * cfg.n_recurrent_layers
             return keep_window(kv["state"], b_idx, adv, ~done), ms.at[-1].add(tokens)
 
         def draft_body(c):
@@ -4176,7 +4257,8 @@ class InferenceEngine:
         head_key = (
             head_req.prefix_key(ecfg.kv_page_size)
             # (a head no recurrent layer could start from is not built)
-            if ecfg.prefix_cache and not self.model_cfg.hybrid else None
+            if ecfg.prefix_cache and (not self.model_cfg.hybrid or self.model_cfg.head_state)
+            else None
         )
         warm_head = (
             self._pop_warm_head(head_req)
@@ -4292,8 +4374,29 @@ class InferenceEngine:
         # radix node holds one: such a model's rows prefill whole (and still
         # insert their heads: pages are pages), and a row whose pages were
         # resident is a counted miss.
-        no_state = use_prefix and self.model_cfg.hybrid
+        no_state = use_prefix and self.model_cfg.hybrid and not self.model_cfg.head_state
+        # ... but where the declared head's END STATE is kept
+        # (``GemmaConfig.head_state``) a row whose prompt starts with that
+        # head matches it at EXACTLY its length and starts from a copy of the
+        # state; any other depth has no state and is the same counted miss.
+        stateful = use_prefix and self.model_cfg.head_state
+        head = self._head_state if stateful else None
+        head_slot = ecfg.max_batch_size
         psz = ecfg.kv_page_size
+
+        behind_head: dict[int, bool] = {}  # a request's prompt starts with the head: compared once
+
+        def _match_cap(r: GenerateRequest, cap_tokens: int) -> int:
+            """How deep ``r`` may match: the tree's own cap under
+            ``cap_tokens``, or the head's length alone (0: nothing)."""
+            cap = min(cap_tokens, cache.match_cap(len(r.prompt_ids)))
+            if not stateful:
+                return cap
+            if head is None or cap < len(head):
+                return 0
+            if id(r) not in behind_head:
+                behind_head[id(r)] = tuple(r.prompt_ids[: len(head)]) == head
+            return len(head) if behind_head[id(r)] else 0
 
     # --- per-request geometry
         # Hetero slabs always run the constrained-width chunk (the segment
@@ -4381,11 +4484,9 @@ class InferenceEngine:
             prefill bucket (serve without reuse rather than failing)."""
             if not use_prefix or no_state or cap_tokens <= 0:
                 return 0
-            P = cache.probe(
-                r.prompt_ids,
-                min(cap_tokens, cache.match_cap(len(r.prompt_ids))),
-            )
-            if P <= 0:
+            cap = _match_cap(r, cap_tokens)
+            P = cache.probe(r.prompt_ids, cap) if cap > 0 else 0
+            if P <= 0 or (stateful and P != cap):
                 return 0
             if min(slab.steps, capacity - 1 - slack - P) < 1 or not any(
                 b + P <= capacity for b in base_eligible
@@ -4453,10 +4554,10 @@ class InferenceEngine:
                 # (tree shrank, geometry infeasible) must not inflate the
                 # reuse counters.
                 P2, mpages, mnode = cache.match(
-                    r.prompt_ids,
-                    min(capacity - T, cache.match_cap(len(r.prompt_ids))),
-                    record=False,
+                    r.prompt_ids, _match_cap(r, capacity - T), record=False,
                 )
+                if stateful and P2 != P:
+                    P2 = 0  # a part of the head has no state to start from
                 if P2 != P:
                     # The tree changed between plan and commit (an earlier
                     # cohort-mate's insert evicted a planned node under
@@ -4482,7 +4583,7 @@ class InferenceEngine:
             # never the admission.
             ins = 0
             inode: Optional[PrefixNode] = None
-            if use_prefix:
+            if use_prefix and not stateful:  # (no row could start from a node below the head)
                 want = ((P + len(ids)) // psz) * psz - P
                 if want > 0:
                     inode = cache.insert(r.prompt_ids, P, want, tenant=r.tenant)
@@ -4512,7 +4613,10 @@ class InferenceEngine:
                     cache.matched_tokens += P
                 else:
                     cache.misses += 1
-                if no_state and cache.probe(
+                if stateful and P > 0:
+                    self._prefix_state_hits += 1
+                    self.metrics.prefix_state.labels(event="hit").inc()
+                elif (no_state or stateful) and cache.probe(
                     r.prompt_ids, min(capacity - T, cache.match_cap(len(r.prompt_ids)))
                 ) > 0:
                     self._prefix_state_misses += 1
@@ -4623,7 +4727,16 @@ class InferenceEngine:
                 # (decode_chunk_paged's contract) — a matched prefix's
                 # FLOPs are paid once per resident tree path, not per
                 # request.
-                last_logits, k_p, v_p, moe_d = self._jit_suffix_prefill(
+                src_d = None
+                if stateful:
+                    # A row that matched the head reads the head's state; one
+                    # that did not starts from an empty one (out of range).
+                    src = np.full((A,), int(self._state_pool["n"].shape[0]), np.int32)
+                    src[: len(cohort)] = [
+                        head_slot if pfx[0] > 0 else src[0] for pfx in prefixes
+                    ]
+                    src_d = self._put(src, rs)
+                last_logits, k_p, v_p, moe_d, self._state_pool = self._jit_suffix_prefill(
                     self._params,
                     tokens_d,
                     lens_d,
@@ -4631,6 +4744,9 @@ class InferenceEngine:
                     table_d,
                     self._paged_kv["k"],
                     self._paged_kv["v"],
+                    self._state_pool,
+                    src_d,
+                    slots_d if stateful else None,
                 )
                 pf_entry = getattr(self._jit_suffix_prefill, "last_entry", None)
                 pf_name = "suffix_prefill"
@@ -4957,6 +5073,8 @@ class InferenceEngine:
         self._pallas_dispatches["decode"] += 1
         if self.model_cfg.hybrid:
             self._pallas_dispatches["ssm"] += 1
+        if self.model_cfg.n_block_layers:
+            self._pallas_dispatches["gather"] += 1
         if hetero and slab.spec:
             self._pallas_dispatches["spec_verify"] += 1
         self._seg_counter += 1
@@ -5198,11 +5316,26 @@ class InferenceEngine:
                 )
                 self.metrics.attn_key_blocks.inc(attrs["attn_key_blocks"])
                 self.metrics.attn_run_blocks.inc(attrs["attn_run_blocks"])
+            if mc.n_block_layers:
+                # Block-selecting attention: what its calls attended after
+                # the selection, the key sums they scored (a page's float32
+                # row a KV head), the query slots they computed, and the
+                # pages those slots' programs fetched against their contexts'
+                # (the gathered form of a decode window fetches a slot's
+                # chosen blocks alone; kv_bytes_read counts what was FETCHED).
+                blk = own + FORWARD_STATS
+                sel, sums, q_slots, got, of = (int(c) for c in counts[blk : blk + BLOCK_STATS])
+                attrs["attn_sel_tokens"], attrs["attn_query_slots"] = sel, q_slots
+                attrs["attn_gathered_pages"], attrs["attn_ctx_pages"] = got, of
+                page_bytes = self.config.engine.kv_page_size * mc.head_dim * 2 * jnp.dtype(mc.dtype).itemsize
+                if of:
+                    attrs["kv_bytes_read"] = got * page_bytes
+                attrs["index_bytes_read"] = sums * mc.head_dim * 4
             if mc.hybrid:
                 # Recurrent layers: calls on live rows, the live window slots
                 # they computed, the tokens the state moved by; a call reads
                 # a slot's state once and writes it once.
-                ssm = own + FORWARD_STATS
+                ssm = own + FORWARD_STATS + (BLOCK_STATS if mc.n_block_layers else 0)
                 attrs["ssm_row_calls"], attrs["ssm_slots"], attrs["ssm_tokens"] = (
                     int(c) for c in counts[ssm : ssm + 3]
                 )
@@ -5642,10 +5775,15 @@ class InferenceEngine:
         if not mc.hybrid:
             return {}
         width = max(8, self._spec_chunk(True))
-        n_slots = self.config.engine.max_batch_size  # slab row i owns slot i
-        self.metrics.prefix_state.labels(event="miss")  # (the sample exists from the start, at 0)
+        # Slab row i owns slot i; where the declared head's end state is kept
+        # (``GemmaConfig.head_state``) it has the one slot beyond them.
+        n_slots = self.config.engine.max_batch_size + mc.head_state
+        self._head_state = None
+        for event in ("miss",) + (("hit",) if mc.head_state else ()):
+            self.metrics.prefix_state.labels(event=event)  # (the sample exists from the start, at 0)
+        n_pages = self._allocator.n_pages
         return jax.jit(
-            lambda: init_state_pool(mc, n_slots, width), out_shardings=self._named(P()),
+            lambda: init_state_pool(mc, n_slots, width, n_pages), out_shardings=self._named(P()),
         )()
 
     def _cohort_slots(self, A: int, rows=()):

@@ -338,7 +338,7 @@ def _ragged_kernel(*refs, page_size: int, p_blk: int, n_heads: int, windowed: bo
         out_ref[0, :, h] = out[h].reshape(S, G, hd).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def ragged_paged_attention(
     q: jax.Array,  # [B, S, K, G, hd] — padded query windows
     k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers (stays in HBM)
@@ -350,6 +350,7 @@ def ragged_paged_attention(
     window: "jax.Array | int | None" = None,
     *,
     interpret: bool = False,
+    name: "str | None" = None,
 ) -> jax.Array:
     """The ragged mixed-phase kernel: grid (B, cdiv(K, H_BLK), cdiv(S, Sq));
     ONE program streams a row's pages once, a block of P_BLK pages a step,
@@ -418,6 +419,7 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        **({"name": name} if name else {}),
     )(
         page_table.astype(jnp.int32),
         start_pos.astype(jnp.int32),

@@ -184,7 +184,7 @@ def init_paged_kv(
     return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
 
 
-def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int) -> dict:
+def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int, n_pages: int = 0) -> dict:
     """The SECOND kind of per-row state, beside the pages: what a Mamba layer
     keeps of a row, indexed by SLOT (slab row ``i`` owns slot ``i``), not by
     page. ``{}`` for a model with no such layer: an empty pytree adds nothing
@@ -201,10 +201,44 @@ def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int) -> dict:
     as ``n`` says the row kept it (``models/gemma/ssm.py``): ``dt``
     ``[n_slots, window, heads]`` float32, ``pre`` and ``post`` ``[n_slots,
     window, C]``, the convolution's inputs and outputs. A forward updates
-    each in place."""
-    if not cfg.n_mamba_layers:
+    each in place.
+
+    A LINEAR-attention layer (``GemmaConfig.n_linear_layers``) is the pool's
+    second kind: the same ``ssm`` array (state ``head_dim`` of the key on the
+    sublanes, heads x ``head_dim`` of the value on the lanes), no ``conv``,
+    and a pending window that holds the window's keys and values: ``dt``
+    ``[n_slots, window, heads]`` float32 (1 on a live slot), ``k`` and ``v``
+    ``[n_slots, window, heads, head_dim]``. Such a model's pool has one slot
+    beyond the slab rows' (the caller's ``n_slots``): the declared shared
+    head's END STATE, which a row that matches the head starts from.
+
+    With block-selecting attention layers (``n_block_layers``) it also holds
+    ``ksum`` ``[K, those layers, n_pages, head_dim]`` float32, a row a PAGE
+    of the page pools: the sum of that page's keys (``models/gemma/sparse.py``).
+    It rides here because this pytree is what the prefill, the suffix prefill
+    and the segment of such a model hand on beside the two page pools; it is
+    indexed by page, not by slot, and depends on its page's tokens alone, so
+    whatever shares a page shares its row."""
+    if not cfg.n_recurrent_layers:
         return {}
     d = jnp.dtype(cfg.dtype)
+    if cfg.mixer_ffn:
+        H, hd, L = cfg.n_heads, cfg.head_dim, cfg.n_linear_layers
+        pool = {
+            "ssm": jnp.zeros((L, n_slots, hd, H * hd), jnp.float32),
+            "layers": tuple(
+                {
+                    "dt": jnp.zeros((n_slots, window, H), jnp.float32),
+                    "k": jnp.zeros((n_slots, window, H, hd), d),
+                    "v": jnp.zeros((n_slots, window, H, hd), d),
+                }
+                for _ in range(L)
+            ),
+            "n": jnp.zeros((n_slots,), jnp.int32),
+        }
+        if cfg.n_block_layers:
+            pool["ksum"] = jnp.zeros((cfg.n_kv_heads, cfg.n_block_layers, n_pages, hd), jnp.float32)
+        return pool
     C = cfg.conv_width
 
     def layer():
@@ -228,6 +262,11 @@ def write_prefill_state(state: dict, slots: jax.Array, finals: list) -> dict:
     layer into ``slots`` [A] (a padding row's slot is out of range and
     dropped): the state AT each prompt's length, nothing pending."""
     ssm, layers = state["ssm"], []
+    if "conv" not in state["layers"][0]:  # linear layers: a state alone, no tail
+        for j, (pool, h) in enumerate(zip(state["layers"], finals)):
+            ssm = ssm.at[j, slots].set(h.reshape(h.shape[:2] + (-1,)), mode="drop")
+            layers.append({**pool, "dt": pool["dt"].at[slots].set(0.0, mode="drop")})
+        return {**state, "ssm": ssm, "layers": tuple(layers), "n": state["n"].at[slots].set(0, mode="drop")}
     for j, (pool, (h, tail)) in enumerate(zip(state["layers"], finals)):
         ssm = ssm.at[j, slots].set(h.reshape(h.shape[:2] + (-1,)), mode="drop")
         layers.append({
@@ -267,3 +306,18 @@ def commit_prefill_to_pages(
         return pool.at[:, :, dest].set(chunks, mode="drop")
 
     return {"k": scatter(paged["k"], dense["k"]), "v": scatter(paged["v"], dense["v"])}
+
+
+def commit_prefill_key_sums(
+    ksum: jax.Array, dense_k: jax.Array, page_table: jax.Array, page_size: int
+) -> jax.Array:
+    """The key-sum pool ``[K, L, N, hd]`` with the row of every page a dense
+    prefill wrote (``dense_k`` [L, B, T, K, hd]) set to that page's sum. A
+    page past a prompt's end sums pad keys: its row is read only once the
+    page is full, and the write that fills it sums it again."""
+    L, B, T, K, hd = dense_k.shape
+    n_chunks = T // page_size
+    sums = dense_k.astype(jnp.float32).reshape(L, B, n_chunks, page_size, K, hd).sum(axis=3)
+    sums = sums.transpose(3, 0, 1, 2, 4).reshape(K, L, B * n_chunks, hd)
+    dest = page_table[:, :n_chunks].reshape(B * n_chunks)
+    return ksum.at[:, :, dest].set(sums, mode="drop")

@@ -34,10 +34,15 @@ from mcpx.models.gemma.model import (
     attention_residual,
     embed_tokens,
     feed_forward_residual,
+    gated_attention_out,
     hybrid_attention_inputs,
     hybrid_feed_forward,
+    join_scaled,
     layer_kinds,
     layer_stacks,
+    mixer_feed_forward,
+    mixer_norm,
+    mixer_stream,
     output_logits,
     pattern_rows,
     rms_norm,
@@ -60,6 +65,7 @@ def _ragged_kernel_on_mesh(
     window: "jax.Array | None" = None,
     *,
     interpret: bool,
+    name: "str | None" = None,  # the call's own name in a device trace (None: the kernel's)
 ) -> jax.Array:
     """The ragged kernel under ``jax.shard_map`` over the engine mesh: XLA
     will not partition a Mosaic call by itself, so each device runs the
@@ -82,7 +88,9 @@ def _ragged_kernel_on_mesh(
     if window is not None:
         scalars += (jnp.asarray(window, jnp.int32),)
     return jax.shard_map(
-        functools.partial(ragged_paged_attention, interpret=interpret),
+        functools.partial(
+            ragged_paged_attention, interpret=interpret, **({"name": name} if name else {})
+        ),
         mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, P(rows, None), P(rows), P(rows))
         + (P(),) * len(scalars),
@@ -228,6 +236,7 @@ def _write_kv_window(
     layer: jax.Array,
     new: jax.Array,  # [B, S, K, hd] this layer's new K or V rows
     window: tuple[jax.Array, jax.Array, jax.Array],  # _kv_window(...)
+    with_pages: bool = False,  # also the touched pages as written, [K, B, P, psz, hd]
 ) -> jax.Array:
     """Write one layer's window of new rows into the pool IN PLACE, page by
     page, in the pool's own shape: gather the touched pages of ``layer``
@@ -249,7 +258,209 @@ def _write_kv_window(
     rows = jnp.take_along_axis(new, slot[:, :, None, None], axis=1)  # [B, P*psz, K, hd]
     rows = rows.transpose(2, 0, 1, 3).reshape(K, B, n_win, psz, hd)
     merged = jnp.where(live[None, ..., None], rows.astype(pool.dtype), old)
-    return pool.at[:, layer, pages].set(merged, mode="drop")
+    pool = pool.at[:, layer, pages].set(merged, mode="drop")
+    return (pool, merged) if with_pages else pool
+
+
+# Queries the masked form of a block-selecting layer scores at a time, a row:
+# its float32 scores are queries x heads x context.
+BLOCK_QUERY_BLOCK = 128
+
+
+def _block_attend(
+    qg: jax.Array,  # [B, S, K, G, hd]
+    k_all: jax.Array,  # [K, L, N, psz, hd]
+    v_all: jax.Array,
+    ksum: jax.Array,  # [K, L, N, hd] float32: every page's key sum
+    page_table: jax.Array,  # [B, Pmax]
+    positions: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B]
+    layer: int,
+    cfg: GemmaConfig,
+    *,
+    gathered: bool,
+    mesh: Optional[Mesh],
+    use_pallas: bool,
+    interpret: bool,
+) -> jax.Array:
+    """A block-selecting (``S``) layer's attention against the pages, for a
+    table wide enough to hold a block a query drops: every (query slot, KV
+    head) scores the row's pooled keys (two neighbouring pages' sums, read
+    out of ``ksum`` through the table) and reads its own ``block_topk``
+    blocks (``models/gemma/sparse.py``). -> ([B, S, K, G, hd], the blocks each
+    (slot, KV head) chose [B, S, K, blocks] bool).
+
+    ``gathered`` (a decode window): the selection becomes a PAGE LIST a
+    (row, slot, KV head), its blocks ascending and ``block_size / psz`` pages
+    each, and the ragged kernel runs over those lists as its rows' tables: it
+    FETCHES the listed pages and no others. Unrotated keys carry no position,
+    so a list is a context of its own: the query's place in it is its place
+    in the LAST block (its own, always chosen) behind the whole blocks before,
+    and that is all the causal bound needs. The pools are read through a
+    view with KV head, layer and page merged, so one call serves both KV
+    heads with a page id that names all three.
+
+    Else (a prefill window: a suffix over a shared head, a chunk of a head's
+    build) the masked form in jnp, a row and ``BLOCK_QUERY_BLOCK`` queries at
+    a time: every page is gathered, the unselected tokens weigh nothing."""
+    from mcpx.models.gemma import sparse
+
+    B, S, K, G, hd = qg.shape
+    _, L, N, psz, _ = k_all.shape
+    p_max = page_table.shape[1]
+    pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)
+    sums = ksum[:, layer][:, page_table].transpose(1, 0, 2, 3)  # [B, K, Pmax, hd]
+    kc = sparse.pooled_keys(sums, psz)
+    if not gathered:
+        def one_row(args):
+            q_r, table, kc_r, pos_r, qn = args  # [S, K, G, hd], [Pmax], [K, J, hd], [S], []
+            k = k_all[:, layer][:, table].reshape(K, p_max * psz, hd)
+            v = v_all[:, layer][:, table].reshape(K, p_max * psz, hd)
+
+            def one_block(i):
+                q_b = lax.dynamic_slice_in_dim(q_r, i, sb, axis=0)
+                t_b = lax.dynamic_slice_in_dim(pos_r, i, sb, axis=0)
+                chosen = sparse.selected_blocks(q_b[None], kc_r[None], t_b[None], cfg)[0]  # [sb, K, Nb]
+                mask = sparse.token_mask(chosen, cfg.block_size, p_max * psz)
+                mask = jnp.pad(mask, ((0, 0), (0, 0), (0, p_max * psz - mask.shape[-1])))
+                mask &= (jnp.arange(p_max * psz)[None, :] <= t_b[:, None])[:, None, :]
+                logits = jnp.einsum("skgh,klh->skgl", q_b, k, preferred_element_type=jnp.float32)
+                logits = jnp.where(mask[:, :, None, :], logits * hd**-0.5, -1e30)
+                w = jax.nn.softmax(logits, axis=-1)
+                out = jnp.einsum("skgl,klh->skgh", w.astype(v.dtype), v)
+                out = jnp.where(((i + jnp.arange(sb)) < qn)[:, None, None, None], out, 0)
+                return out.astype(q_r.dtype), chosen
+
+            sb = min(S, BLOCK_QUERY_BLOCK)
+            out, chosen = lax.map(one_block, jnp.arange(0, S, sb))
+            return out.reshape(S, K, G, hd), chosen.reshape(S, K, -1)
+
+        if S % min(S, BLOCK_QUERY_BLOCK):
+            raise ValueError(f"a prefill window of {S} slots is no multiple of {BLOCK_QUERY_BLOCK}")
+        return lax.map(one_row, (qg, page_table, kc, pos_mat, q_lens))
+    if use_pallas and (mesh is None or mesh.size == 1):
+        from mcpx.engine.kernels.block_score import block_score
+
+        pooled = block_score(qg, kc, positions, q_lens, stride=psz, interpret=interpret)
+    else:
+        pooled = sparse.pooled_scores(qg, kc, pos_mat, psz)
+    chosen = sparse.blocks_from_scores(pooled, pos_mat, cfg)  # [B, S, K, Nb]
+    ids, count = sparse.block_lists(chosen, cfg.block_topk)  # [B, S, K, topk], [B, S, K]
+    r = cfg.block_size // psz
+    cols = (ids[..., None] * r + jnp.arange(r, dtype=ids.dtype)).reshape(B, S, K, -1)
+    pages = jnp.take_along_axis(
+        page_table[:, None, None, :], jnp.minimum(cols, p_max - 1).reshape(B, 1, 1, -1), axis=-1
+    ).reshape(cols.shape)
+    head = (jnp.arange(K, dtype=pages.dtype) * L + layer) * N
+    lists = jnp.where(cols < p_max, pages + head[None, None, :, None], 0)
+    live = jnp.arange(S)[None, :] < q_lens[:, None]
+    start = (count - 1) * cfg.block_size + (pos_mat % cfg.block_size)[:, :, None]
+    rows = B * S * K
+    flat = (1, 1, K * L * N, psz, hd)
+    args = (
+        qg.reshape(rows, 1, 1, G, hd), k_all.reshape(flat), v_all.reshape(flat),
+        lists.reshape(rows, -1), start.reshape(rows),
+        jnp.broadcast_to(live[:, :, None], (B, S, K)).reshape(rows).astype(jnp.int32),
+    )
+    if use_pallas:
+        out = _ragged_kernel_on_mesh(
+            mesh, *args, 0, interpret=interpret, name="ragged_paged_attention_gathered"
+        )
+    else:
+        out = ragged_paged_attention_reference(*args, 0, None)
+    return out.reshape(B, S, K, G, hd), chosen
+
+
+def _mixer_ffn_chunk(
+    params, cfg: GemmaConfig, x, positions, page_table, paged_kv, kv_window, q_lens, slots, *,
+    use_pallas, interpret, mesh, logits_at, active_cols, moe_stats, commit, selection,
+) -> tuple:
+    """``decode_chunk_paged`` for an ``L`` / ``S`` pattern: a static walk, each
+    layer its mixer then the dense feed-forward. A linear layer reads and
+    writes its part of the state pool (``ssm.linear_window``; ``slots``:
+    (read from, written to), a row); an ``S`` layer writes its keys and
+    values into the pages as every model's do, unrotated, sums the pages it
+    touched again into the key-sum pool, and attends by block selection
+    where the table can hold a block a query drops (``_block_attend``), else
+    as plain grouped attention. ``commit``: the window is a prefill's, all of
+    it stays."""
+    from mcpx.models.gemma import sparse
+    from mcpx.models.gemma.ssm import linear_window
+
+    B, S, _ = x.shape
+    state = paged_kv["state"]
+    src, dst = slots
+    one_device = mesh is None or mesh.size == 1
+    kernel = None
+    if use_pallas and one_device and not commit:
+        from mcpx.engine.kernels.ssm import ssm_window
+
+        kernel = functools.partial(ssm_window, interpret=interpret)
+    n_slots = state["n"].shape[0]
+    kept = state["n"][jnp.minimum(src, n_slots - 1)]
+    pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)
+    k_all, v_all, ksum = paged_kv["k"], paged_kv["v"], state.get("ksum")
+    psz, p_max = k_all.shape[3], page_table.shape[1]
+    selecting = sparse.selects(cfg, p_max * psz)
+    if selection and not selecting:
+        raise ValueError("selection=True: this table is too narrow to hold a block a query drops")
+    ssm, layers_new, reads = state["ssm"], list(state["layers"]), []
+    for kind, j in pattern_rows(cfg):
+        lp = stack_row(params["linear_layers" if kind == "L" else "block_layers"], j)
+        n = mixer_norm(x, lp["norm"], cfg)
+        if kind == "L":
+            out, ssm, layers_new[j] = linear_window(
+                n, lp, cfg, ssm, j, state["layers"][j], src, dst, q_lens, kept, pos_mat,
+                commit=commit, kernel=kernel,
+            )
+        else:
+            q, k, v = hybrid_attention_inputs(n, lp, cfg)
+            k_all, written = _write_kv_window(k_all, j, k, kv_window, with_pages=True)
+            v_all = _write_kv_window(v_all, j, v, kv_window)
+            ksum = ksum.at[:, j, kv_window[0]].set(
+                jnp.sum(written.astype(jnp.float32), axis=3), mode="drop"
+            )
+            qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+            if selecting:
+                attn, chosen = _block_attend(
+                    qg, k_all, v_all, ksum, page_table, positions, q_lens, j, cfg,
+                    gathered=not commit, mesh=mesh, use_pallas=use_pallas, interpret=interpret,
+                )
+                reads.append(chosen)
+            elif use_pallas:
+                attn = _ragged_kernel_on_mesh(
+                    mesh, qg, k_all, v_all, page_table, positions, q_lens, j, interpret=interpret
+                )
+            else:
+                attn = ragged_paged_attention_reference(
+                    qg, k_all, v_all, page_table, positions, q_lens, j, None
+                )
+            out = gated_attention_out(attn.reshape(B, S, cfg.attn_out_width), n, lp, cfg)
+        x = mixer_feed_forward(join_scaled(x, out, cfg), lp, cfg)
+    x = mixer_norm(x, params["final_norm"], cfg)
+    # A decode window stays pending until the caller says what of it was
+    # kept; a prefill's is in the state already. Either way nothing is kept yet.
+    n_new = state["n"].at[jnp.where(q_lens > 0, dst, n_slots)].set(0, mode="drop")
+    new_state = {"ssm": ssm, "layers": tuple(layers_new), "n": n_new}
+    if ksum is not None:
+        new_state["ksum"] = ksum
+    pools = {"k": k_all, "v": v_all, "state": new_state}
+    stats = None
+    if moe_stats:
+        stats = add_forward_stats(
+            cfg, moe_stats_init(cfg), positions + q_lens, q_lens, S,
+            blocks=(psz, p_max, commit),
+        )
+    extra = (stats,) if moe_stats else ()
+    if selection:
+        # What a comparison may ask for: the blocks every (slot, KV head) read,
+        # a bit a block, a row an ``S`` layer.
+        extra += (jnp.packbits(jnp.stack(reads), axis=-1),)
+    if active_cols is not None:
+        return (output_logits(params, cfg, x, subset=active_cols), pools) + extra
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    return (output_logits(params, cfg, x), pools) + extra
 
 
 def _hybrid_chunk(
@@ -350,7 +561,9 @@ def decode_chunk_paged(
     mesh: Optional[Mesh] = None,  # engine mesh; required with use_pallas
     moe_stats: bool = False,  # sparse models: also the forward's expert counters
     routing: bool = False,  # sparse models: also the experts chosen [Ls, B, S, k]
-    selection: bool = False,  # a learned index: also the keys each query read [L, B, S, keys / 8]
+    selection: bool = False,  # a learned index: also the keys each query read [L, B, S, keys / 8]; block selection: the blocks [S layers, B, S, K, blocks / 8]
+    state_slots: "tuple | None" = None,  # recurrent layers: each row's (slot read, slot written); None: row i's is i
+    commit: bool = False,  # recurrent layers: the window is a prefill's, every live slot of it stays
 ) -> tuple:
     """Multi-token decode step: S new tokens per sequence in ONE forward.
 
@@ -401,10 +614,18 @@ def decode_chunk_paged(
     # Quantized leaves stay the HBM-resident buffers — embed rows gather
     # as int8 + per-row scales, layers dequantize per layer INSIDE the
     # scan body (see dequant_layer), unembeds scale on the output.
-    x = embed_tokens(params, cfg, tokens)  # [B, S, D]
+    x = mixer_stream(params, cfg, tokens) if cfg.mixer_ffn else embed_tokens(params, cfg, tokens)  # [B, S, D]
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
     kv_window = _kv_window(positions, page_table, S, psz, N)
+    if cfg.mixer_ffn:
+        own = jnp.arange(B, dtype=jnp.int32)
+        return _mixer_ffn_chunk(
+            params, cfg, x, positions, page_table, paged_kv, kv_window, q_lens,
+            state_slots or (own, own),
+            use_pallas=use_pallas, interpret=interpret, mesh=mesh, logits_at=logits_at,
+            active_cols=active_cols, moe_stats=moe_stats, commit=commit, selection=selection,
+        )
     if cfg.hybrid:
         return _hybrid_chunk(
             params, cfg, x, positions, page_table, paged_kv, kv_window, q_lens,

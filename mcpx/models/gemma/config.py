@@ -157,6 +157,30 @@ class GemmaConfig:
     # latent and projected up once. The router and the shared expert read the
     # full width.
     moe_latent_size: int = 0
+    # --- a layer that is a MIXER followed by the dense gated feed-forward
+    # (``layer_pattern`` of ``L`` and ``S`` alone; two norms a layer), the
+    # mixer one of two, both ``n_heads`` heads of ``head_dim`` behind an
+    # output gate ``sigmoid(Wg n)``: ``L`` LINEAR attention, one key and one
+    # query a head, q and k normed per head and rotated, a constant decay a
+    # head (``linear_decay``), the state a row ``[head_dim, n_heads x
+    # head_dim]`` float32 and an RMSNorm over each head's output
+    # (``models/gemma/ssm.py``); ``S`` attention on ``n_kv_heads`` unrotated KV
+    # heads that reads, a (query, KV head), the ``block_topk`` best of the
+    # context's blocks of ``block_size`` tokens by a parameter-free score over
+    # POOLED keys (the mean of ``2 x pool_stride`` keys every ``pool_stride``),
+    # block 0..``block_init`` - 1 and the blocks reaching back
+    # ``block_window`` tokens always among them (``models/gemma/sparse.py``).
+    # Three scales of the family: ``embed_scale`` on the embedding (0: none),
+    # ``residual_scale`` on every branch before it joins, ``logit_divisor``
+    # on the final normed state before the head.
+    block_size: int = 0
+    block_topk: int = 0
+    block_init: int = 1
+    block_window: int = 0
+    pool_stride: int = 16
+    embed_scale: float = 0.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -188,7 +212,24 @@ class GemmaConfig:
             )
         if self.activation not in ("gelu_tanh", "silu", "relu2"):
             raise ConfigError(f"activation {self.activation!r}: gelu_tanh, silu or relu2")
-        if self.hybrid:
+        if self.mixer_ffn:
+            if set(self.layer_pattern) - set("LS") or len(self.layer_pattern) != self.n_layers:
+                raise ConfigError("layer_pattern: L and S alone (a mixer + feed-forward model), one a layer")
+            if "S" in self.layer_pattern and (
+                min(self.block_size, self.block_topk, self.block_init, self.pool_stride) < 1
+                or self.block_size % self.pool_stride or self.block_window % self.block_size
+            ):
+                raise ConfigError(
+                    "an S layer needs block_size (a multiple of pool_stride), block_topk, "
+                    "block_init >= 1 and block_window a multiple of block_size"
+                )
+            if self.latent or self.layer_types or self.n_experts or self.post_norms \
+                    or self.norm_plus_one or self.mamba_n_heads or self.head_dim % 2:
+                raise ConfigError(
+                    "an L/S layer_pattern is heads attention with plain norm gains, a dense "
+                    "feed-forward and no Mamba widths"
+                )
+        elif self.hybrid:
             if set(self.layer_pattern) - set("ME*") or len(self.layer_pattern) != self.n_layers:
                 raise ConfigError("layer_pattern: one of M, E, * for each of n_layers layers")
             sizes = (self.mamba_n_heads, self.mamba_head_dim, self.mamba_n_groups, self.ssm_state_size)
@@ -213,6 +254,11 @@ class GemmaConfig:
                 )
         elif self.moe_latent_size or self.mamba_n_heads or self.activation == "relu2":
             raise ConfigError("the Mamba widths, moe_latent_size and relu2 belong to a layer_pattern")
+        if not self.mixer_ffn and (
+            self.block_size or self.block_topk or self.block_window or self.embed_scale
+            or self.residual_scale != 1.0 or self.logit_divisor != 1.0
+        ):
+            raise ConfigError("the block selection and the three scales belong to an L/S layer_pattern")
         # A JSON round trip (dataclasses.asdict -> GemmaConfig(**d)) hands a list.
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.layer_types:
@@ -270,16 +316,62 @@ class GemmaConfig:
 
     @property
     def hybrid(self) -> bool:
-        """A model whose layers are a mixer OR a feed-forward alone."""
+        """A ``layer_pattern`` model: its layers are walked one by one, and
+        some keep a recurrent state a row beside the pages."""
         return bool(self.layer_pattern)
+
+    @property
+    def mixer_ffn(self) -> bool:
+        """A ``layer_pattern`` of ``L`` / ``S``: every layer a mixer followed
+        by the dense feed-forward (the other patterns' layers are one thing
+        alone)."""
+        return bool(self.layer_pattern) and not set(self.layer_pattern) - set("LS")
 
     @property
     def n_mamba_layers(self) -> int:
         return self.layer_pattern.count("M")
 
     @property
+    def n_linear_layers(self) -> int:
+        return self.layer_pattern.count("L") if self.mixer_ffn else 0
+
+    @property
+    def n_recurrent_layers(self) -> int:
+        """Layers that keep a state a row in the state pool."""
+        return self.n_mamba_layers + self.n_linear_layers
+
+    @property
+    def n_block_layers(self) -> int:
+        """Layers whose attention selects key blocks (``S``): the key-sum
+        pool's layer axis, which is the page pools' too."""
+        return self.layer_pattern.count("S") if self.mixer_ffn else 0
+
+    @property
+    def head_state(self) -> bool:
+        """Whether the engine keeps the END STATE of a declared shared head
+        in a slot of its own and hands a copy to every row that matches it:
+        where every recurrent layer is linear attention (a Mamba layer's
+        convolution tail has no suffix route: such a model's rows prefill
+        whole)."""
+        return self.n_linear_layers > 0
+
+    @property
+    def linear_decay(self) -> "np.ndarray":
+        """``log lambda_h`` [n_heads] float32 of the linear layers, the same
+        in every layer: ``lambda_h = exp(-2^(-8 (h + 1) / n_heads))``."""
+        h = np.arange(1, self.n_heads + 1, dtype=np.float64)
+        return (-(2.0 ** (-8.0 * h / self.n_heads))).astype(np.float32)
+
+    @property
+    def blocks_kept(self) -> int:
+        """The trailing blocks every query keeps: ``block_window`` tokens' worth."""
+        return self.block_window // self.block_size if self.block_size else 0
+
+    @property
     def n_attn_layers(self) -> int:
         """Layers that cache keys and values: the page pools' layer axis."""
+        if self.mixer_ffn:
+            return self.layer_pattern.count("S")
         return self.layer_pattern.count("*") if self.hybrid else self.n_layers
 
     @property
@@ -293,7 +385,9 @@ class GemmaConfig:
 
     @property
     def ssm_slot_bytes(self) -> int:
-        """Bytes of ONE Mamba layer's recurrent state of one row (float32)."""
+        """Bytes of ONE recurrent layer's state of one row (float32)."""
+        if self.mixer_ffn:
+            return self.n_heads * self.head_dim * self.head_dim * 4
         return self.mamba_inner * self.ssm_state_size * 4
 
     @property
@@ -452,6 +546,13 @@ class GemmaConfig:
 
     def _count(self, experts: int) -> int:
         D, H, K, hd, F = self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.d_ff
+        if self.mixer_ffn:
+            ffn = 3 * D * F + 2 * D  # the feed-forward and the layer's two norms
+            linear = 5 * D * H * hd + 2 * hd + H * hd  # q, k, v, gate, o; q/k norms; the output norm
+            block = 3 * D * H * hd + 2 * D * K * hd  # q, gate, o; k, v
+            layers = self.n_linear_layers * (linear + ffn) + self.n_block_layers * (block + ffn)
+            head = 0 if self.tie_embeddings else D * self.vocab_size
+            return self.vocab_size * D + layers + D + head
         if self.hybrid:
             inner, C, Hm = self.mamba_inner, self.conv_width, self.mamba_n_heads
             mamba = (

@@ -92,9 +92,11 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         from mcpx.parallel.mesh import param_pspecs
 
         specs = param_pspecs(cfg, mesh)
-        stacks = ("layers", "dense_layers", "mamba_layers", "attn_layers")
+        stacks = (
+            "layers", "dense_layers", "mamba_layers", "attn_layers", "linear_layers", "block_layers",
+        )
         by_name = {
-            **specs["layers"],
+            **specs.get("layers", {}),
             **{k: v for k, v in specs.items() if k not in stacks},
             **{s + "." + k: v for s in stacks[1:] for k, v in specs.get(s, {}).items()},
         }
@@ -186,6 +188,8 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
             "w_down": normal("w_down", k_down, (n, F, D), F, stack=stack),
         }
 
+    if cfg.mixer_ffn:
+        return _init_mixer_ffn(cfg, key, normal, sharding, t)
     if cfg.hybrid:
         return _init_hybrid(cfg, key, normal, sharding, t)
     Ls = cfg.n_sparse_layers
@@ -313,6 +317,62 @@ def _init_hybrid(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Param
             layers["latent_down"] = normal("latent_down", fold(17), (Ls, D, Dl), D)
             layers["latent_up"] = normal("latent_up", fold(18), (Ls, Dl, D), Dl)
         params["layers"] = layers
+    return params
+
+
+def _init_mixer_ffn(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Params:
+    """``init_params`` of an ``L`` / ``S`` pattern: two stacks, a row a layer
+    of its kind in layer order, ``linear_layers`` and ``block_layers``, each
+    layer its mixer's leaves (heads merged on the matmul's own axis), its two
+    norms (``norm`` before the mixer, ``mlp_norm`` before the feed-forward)
+    and the dense gated feed-forward. Gains 1; the decay of a linear layer is
+    no leaf (``GemmaConfig.linear_decay``)."""
+    dtype = jnp.dtype(cfg.dtype)
+    D, H, K, hd, F, V = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.vocab_size
+    fold = lambda i: jax.random.fold_in(key, 200 + i)
+
+    def ones(name, shape, stack):
+        return t(name, jnp.ones(shape, dtype, device=sharding(stack + name)))
+
+    def shared(n, st, base):  # the leaves both kinds have
+        leaves = {
+            "norm": ones("norm", (n, D), st),
+            "mlp_norm": ones("mlp_norm", (n, D), st),
+            "wq": normal("wq", fold(base), (n, D, H * hd), D, stack=st),
+            "wo": normal("wo", fold(base + 1), (n, H * hd, D), H * hd, stack=st),
+            "w_gate": normal("w_gate", fold(base + 2), (n, D, F), D, stack=st),
+            "w_up": normal("w_up", fold(base + 3), (n, D, F), D, stack=st),
+            "w_down": normal("w_down", fold(base + 4), (n, F, D), F, stack=st),
+        }
+        if cfg.attn_gate:
+            leaves["w_attn_gate"] = normal("w_attn_gate", fold(base + 5), (n, D, H * hd), D, stack=st)
+        return leaves
+
+    params = {
+        "embed": normal("embed", fold(0), (V, D), D),
+        "final_norm": t("final_norm", jnp.ones((D,), dtype, device=sharding("final_norm"))),
+    }
+    if not cfg.tie_embeddings:
+        params["head"] = normal("head", fold(1), (D, V), D)
+    Ll, Lb = cfg.n_linear_layers, cfg.n_block_layers
+    if Ll:
+        st = "linear_layers."
+        params["linear_layers"] = {
+            **shared(Ll, st, 10),
+            "wk": normal("wk", fold(16), (Ll, D, H * hd), D, stack=st),
+            "wv": normal("wv", fold(17), (Ll, D, H * hd), D, stack=st),
+            "o_norm": ones("o_norm", (Ll, H * hd), st),
+        }
+        if cfg.qk_norm:
+            params["linear_layers"]["q_norm"] = ones("q_norm", (Ll, hd), st)
+            params["linear_layers"]["k_norm"] = ones("k_norm", (Ll, hd), st)
+    if Lb:
+        st = "block_layers."
+        params["block_layers"] = {
+            **shared(Lb, st, 30),
+            "wk": normal("wk", fold(36), (Lb, D, K * hd), D, stack=st),
+            "wv": normal("wv", fold(37), (Lb, D, K * hd), D, stack=st),
+        }
     return params
 
 
@@ -708,7 +768,7 @@ def _layer(
 # ------------------------------------------- a layer that is one thing alone
 def pattern_rows(cfg: GemmaConfig) -> list[tuple[str, int]]:
     """``layer_pattern`` as (kind, the layer's row in its kind's stack)."""
-    seen = {"M": 0, "E": 0, "*": 0}
+    seen = dict.fromkeys("ME*LS", 0)
     rows = []
     for kind in cfg.layer_pattern:
         rows.append((kind, seen[kind]))
@@ -759,6 +819,112 @@ def hybrid_feed_forward(
     shared = activation(cfg, jnp.einsum("btd,df->btf", n, lp["shared_up"]))
     shared = jnp.einsum("btf,fd->btd", shared, lp["shared_down"], preferred_element_type=jnp.float32)
     return _join(x, routed + shared), stats, chosen
+
+
+def join_scaled(x: jax.Array, branch32: jax.Array, cfg: GemmaConfig) -> jax.Array:
+    """x + ``residual_scale`` times the branch, in float32: an ``L`` / ``S``
+    pattern carries its residual stream in float32 (``mixer_stream``) and
+    rounds to the activations' type where a matmul reads it. The embedding's
+    scale makes the stream large beside what a scaled-down branch adds, so a
+    stream rounded to bfloat16 at every join loses the branches' low bits: on
+    the CPU at 1,024 wide the step's distance from the reference is 0.0134
+    with the stream in bfloat16 and 0.0011 in float32 (PERF.md, PR 51)."""
+    return x.astype(jnp.float32) + cfg.residual_scale * branch32.astype(jnp.float32)
+
+
+def mixer_stream(params: Params, cfg: GemmaConfig, tokens: jax.Array) -> jax.Array:
+    """The embedded tokens as the float32 residual stream of an ``L`` / ``S``
+    pattern."""
+    return embed_tokens(params, cfg, tokens).astype(jnp.float32)
+
+
+def mixer_norm(x: jax.Array, gain: jax.Array, cfg: GemmaConfig) -> jax.Array:
+    """RMSNorm of the float32 stream, rounded once to the activations' type:
+    what a layer's matmuls read."""
+    return rms_norm(x, gain, cfg.norm_eps, cfg.norm_plus_one, jnp.dtype(cfg.dtype))
+
+
+def gated_attention_out(attn: jax.Array, n: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
+    """An ``S`` layer's attention [B, T, H * hd] -> its branch [B, T, D]
+    float32: the output gate out of the layer's normed input, then W_o."""
+    f32 = jnp.float32
+    if cfg.attn_gate:
+        gate = jnp.einsum("btd,de->bte", n, lp["w_attn_gate"], preferred_element_type=f32)
+        attn = (attn.astype(f32) * jax.nn.sigmoid(gate)).astype(n.dtype)
+    return jnp.einsum("bte,ed->btd", attn, lp["wo"], preferred_element_type=f32)
+
+
+def mixer_feed_forward(x: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
+    """The second half of an ``L`` / ``S`` layer: x + scale x MLP(norm(x))."""
+    n = mixer_norm(x, lp["mlp_norm"], cfg)
+    f32 = jnp.float32
+    # gate and up as accumulated, their product rounded ONCE where W_down reads it
+    gate = jnp.einsum("btd,df->btf", n, lp["w_gate"], preferred_element_type=f32)
+    up = jnp.einsum("btd,df->btf", n, lp["w_up"], preferred_element_type=f32)
+    ff = jnp.einsum(
+        "btf,fd->btd", (activation(cfg, gate) * up).astype(n.dtype), lp["w_down"], preferred_element_type=f32
+    )
+    return join_scaled(x, ff, cfg)
+
+
+def _block_attention_dense(q, k_c, v_c, mask, positions, cfg: GemmaConfig) -> jax.Array:
+    """An ``S`` layer's attention over the dense cache: q [B, T, H, hd], the
+    caches [B, S, K, hd], ``mask`` [B, T, S] -> [B, T, H * hd]. Plain causal
+    attention where the cache holds no block a query could drop; else each KV
+    head's queries read their own selection (``sparse.py``, the masked form)."""
+    from mcpx.models.gemma import sparse
+
+    B, T = q.shape[:2]
+    S = k_c.shape[1]
+    qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+    if not sparse.selects(cfg, S):
+        return _attend(qg, k_c, v_c, mask).reshape(B, T, -1)
+    p = cfg.pool_stride
+    whole = S // p * p
+    kc = sparse.pooled_keys(sparse.page_sums(k_c[:, :whole], p), p)
+    chosen = sparse.token_mask(sparse.selected_blocks(qg, kc, positions, cfg), cfg.block_size, S)
+    chosen = jnp.pad(chosen, ((0, 0),) * 3 + ((0, S - chosen.shape[-1]),))
+    heads = [
+        _attend_query_blocks(
+            qg[:, :, g : g + 1], k_c[:, :, g : g + 1], v_c[:, :, g : g + 1],
+            mask & chosen[:, :, g], 1.0,
+        )
+        for g in range(cfg.n_kv_heads)
+    ]
+    return jnp.concatenate(heads, axis=2).reshape(B, T, -1)
+
+
+def _mixer_ffn_forward(
+    params: Params, cfg: GemmaConfig, tokens, seq_lens, kv_cache, mask, logits_at
+) -> tuple:
+    """``forward`` for an ``L`` / ``S`` pattern from an EMPTY state (the dense
+    prefill): the cache it returns holds ``k`` and ``v`` [S layers, ...] and
+    ``ssm``: the state AT each row's length, a linear layer."""
+    from mcpx.models.gemma.ssm import linear_prefill
+
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    x = mixer_stream(params, cfg, tokens)
+    ks, vs, finals = [], [], []
+    for kind, j in pattern_rows(cfg):
+        lp = stack_row(params["linear_layers" if kind == "L" else "block_layers"], j)
+        n = mixer_norm(x, lp["norm"], cfg)
+        if kind == "L":
+            out, final = linear_prefill(n, lp, cfg, seq_lens)
+            finals.append(final)
+        else:
+            q, k, v = hybrid_attention_inputs(n, lp, cfg)
+            k_c = kv_cache["k"][j].at[:, :T].set(k.astype(kv_cache["k"].dtype))
+            v_c = kv_cache["v"][j].at[:, :T].set(v.astype(kv_cache["v"].dtype))
+            attn = _block_attention_dense(q, k_c, v_c, mask, positions, cfg)
+            out = gated_attention_out(attn, n, lp, cfg)
+            ks.append(k_c)
+            vs.append(v_c)
+        x = mixer_feed_forward(join_scaled(x, out, cfg), lp, cfg)
+    x = mixer_norm(x, params["final_norm"], cfg)
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    return output_logits(params, cfg, x), {"k": jnp.stack(ks), "v": jnp.stack(vs), "ssm": finals}
 
 
 def _hybrid_forward(
@@ -813,6 +979,8 @@ def embed_tokens(params: Params, cfg: GemmaConfig, tokens: jax.Array) -> jax.Arr
     x = embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
     if cfg.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
     return x
 
 
@@ -824,6 +992,8 @@ def output_logits(
     ``subset`` [C] restricts to those vocabulary entries."""
     from mcpx.models.gemma.quant import unembed
 
+    if cfg.logit_divisor != 1.0:
+        x = (x.astype(jnp.float32) / cfg.logit_divisor).astype(x.dtype)
     if cfg.tie_embeddings:
         return unembed(x, params["embed"], subset=subset)
     head = params["head"] if subset is None else params["head"][:, subset]
@@ -856,6 +1026,12 @@ def forward(
         if live is None:
             raise ValueError("a layer_pattern model's dense forward is its prefill (prefill())")
         seq_lens = jnp.sum(live, axis=1).astype(jnp.int32)
+        if cfg.mixer_ffn:
+            out = _mixer_ffn_forward(params, cfg, tokens, seq_lens, kv_cache, mask, logits_at)
+            stats = None
+            if moe_stats:
+                stats = add_forward_stats(cfg, moe_stats_init(cfg), seq_lens, seq_lens)
+            return out + ((stats,) if moe_stats else ()) + ((None,) if routing else ())
         return _hybrid_forward(
             params, cfg, tokens, seq_lens, kv_cache, mask, logits_at, live, routing, moe_stats
         )

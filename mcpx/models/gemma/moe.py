@@ -159,6 +159,12 @@ LATENT_STATS = 3
 # window's are known after its verify, and the segment adds them).
 SSM_STATS = 3
 
+# What a forward of a model with block-selecting attention layers counts
+# before those: the tokens its calls attended after the selection, the pooled
+# keys they scored, the query slots they computed, the pages those slots'
+# programs FETCHED and the pages of their contexts.
+BLOCK_STATS = 5
+
 
 def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     """The counters a forward adds to: tokens per held expert ``[E_held]``
@@ -181,7 +187,7 @@ def moe_stats_init(cfg: GemmaConfig) -> jax.Array:
     A model with recurrent layers counts ``SSM_STATS`` more, last."""
     more = (
         (INDEX_STATS if cfg.index_topk else 0) + (LATENT_STATS if cfg.latent else 0)
-        + (SSM_STATS if cfg.hybrid else 0)
+        + (BLOCK_STATS if cfg.n_block_layers else 0) + (SSM_STATS if cfg.hybrid else 0)
     )
     return jnp.zeros((cfg.n_experts_held + LAYER_STATS + FORWARD_STATS + more,), jnp.int32)
 
@@ -194,7 +200,7 @@ def add_layer_stats(stats: jax.Array, layer_stats: jax.Array) -> jax.Array:
 
 def add_forward_stats(
     cfg: GemmaConfig, stats: jax.Array, context: jax.Array, q_lens: jax.Array,
-    window: "int | None" = None, pages: "tuple | None" = None,
+    window: "int | None" = None, pages: "tuple | None" = None, blocks: "tuple | None" = None,
 ) -> jax.Array:
     """The forward's own counters: ``context`` [B] the cache positions a
     row's attention read through, ``q_lens`` [B] its live tokens (0: an idle
@@ -202,7 +208,12 @@ def add_forward_stats(
     the window its attention read the pages through (None: a dense prefill,
     which reads no page, and whose every live token stays in a recurrent
     state), ``pages`` of a latent block's window ``(runs, page_size, p_max)``:
-    its table's ``page_run_flags`` and geometry."""
+    its table's ``page_run_flags`` and geometry, ``blocks`` of a block-selecting
+    model's window ``(page_size, p_max, commit)``: None or a ``p_max`` too
+    narrow to hold a block a query drops reads every page (a dense prefill,
+    plain grouped attention), and so does a ``commit`` window's masked form;
+    a decode window's gathered form fetches, a live slot, the pages of its
+    chosen blocks up to its own place."""
     live = q_lens > 0
     own = [
         jnp.sum(q_lens) * (cfg.n_experts_per_tok * cfg.n_sparse_layers),
@@ -223,9 +234,33 @@ def add_forward_stats(
             pages[0], context - q_lens, q_lens, window, cfg.n_heads, *pages[1:]
         )
         own += [jnp.asarray(n) * cfg.n_layers for n in (slots, *blocks)]
+    if cfg.n_block_layers:
+        kept = cfg.block_topk * cfg.block_size
+        calls = cfg.n_block_layers
+        own += [jnp.sum(jnp.where(live, jnp.minimum(context, kept), 0)) * calls]
+        selecting = blocks is not None and blocks[1] * blocks[0] > kept
+        if not selecting:
+            zero = jnp.zeros((), jnp.int32)
+            own += [zero, jnp.sum(q_lens) * calls if window is not None else zero, zero, zero]
+        else:
+            psz, _, commit = blocks
+            S = window
+            t = (context - q_lens)[:, None] + jnp.arange(S)[None, :]  # [B, S] each slot's position
+            on = jnp.arange(S)[None, :] < q_lens[:, None]
+            ctx_pages = t // psz + 1
+            n_sel = jnp.minimum(t // cfg.block_size + 1, cfg.block_topk)
+            got = ((n_sel - 1) * cfg.block_size + t % cfg.block_size) // psz + 1
+            per = calls * cfg.n_kv_heads
+            own += [
+                jnp.sum(jnp.where(live, context // psz, 0)) * per,
+                jnp.sum(q_lens) * calls,
+                jnp.sum(jnp.where(on, ctx_pages if commit else got, 0)) * per,
+                jnp.sum(jnp.where(on, ctx_pages, 0)) * per,
+            ]
     if cfg.hybrid:
-        slots = jnp.sum(q_lens) * cfg.n_mamba_layers
-        own += [jnp.sum(live) * cfg.n_mamba_layers, slots, slots if window is None else 0 * slots]
+        slots = jnp.sum(q_lens) * cfg.n_recurrent_layers
+        stays = window is None or (blocks is not None and blocks[2])  # a prefill's tokens all stay
+        own += [jnp.sum(live) * cfg.n_recurrent_layers, slots, slots if stays else 0 * slots]
     return stats.at[-len(own) :].add(jnp.stack(own).astype(jnp.int32))
 
 

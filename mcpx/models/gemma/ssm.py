@@ -139,10 +139,15 @@ def chunk_outputs(
     diff = L[:, :, None, :] - L[:, None, :, :]  # [B, t, s, H]
     causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])[None, :, :, None]
     decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-    cb = jnp.einsum("btgn,bsgn->btsg", c, b, preferred_element_type=f32)  # [B, t, s, G]
+    # (float32 operands, a linear layer's, are multiplied as float32: the
+    # default would round them to bfloat16 on the way in)
+    exact = HIGHEST if x.dtype == f32 else None
+    cb = jnp.einsum("btgn,bsgn->btsg", c, b, preferred_element_type=f32, precision=exact)  # [B, t, s, G]
     m = decay.reshape(Bsz, T, T, G, H // G) * cb[..., None] * dt.reshape(Bsz, 1, T, G, H // G)
     xg = x.reshape(Bsz, T, G, H // G, P)
-    intra = jnp.einsum("btsgr,bsgrp->btgrp", m.astype(x.dtype), xg, preferred_element_type=f32)
+    intra = jnp.einsum(
+        "btsgr,bsgrp->btgrp", m.astype(x.dtype), xg, preferred_element_type=f32, precision=exact
+    )
     skip = x.astype(f32) * lp["D_skip"].astype(f32)[:, None]
     return jnp.exp(L)[..., None] * hc + intra.reshape(Bsz, T, H, P) + skip
 
@@ -265,3 +270,148 @@ def mamba_window(
         "post": state["post"].at[at].set(pad(post), mode="drop"),
     }
     return gated_out(y, z, lp, cfg), ssm, new
+
+
+# ------------------------------------------------------- linear attention
+# An ``L`` layer of ``GemmaConfig.mixer_ffn``: per head h of H (d = head_dim)
+#
+#   q, k = RMSNorm_d(n W_q), RMSNorm_d(n W_k), both rotated;  v = n W_v
+#   S_t  = lambda_h S_{t-1} + k_t^T v_t        [d, d] float32, lambda a constant
+#   o_t  = (q_t / sqrt(d)) S_t
+#   y    = W_o( sigmoid(n W_g) (.) RMSNorm_d(o) )
+#
+# which IS the recurrence above with one group a head (B = k, C = q / sqrt(d),
+# x = v), ``dt`` 1 on a live position and 0 on a dead one, ``A = log
+# lambda_h``, no skip, no convolution: the chunked scan, the state's layout
+# ([d of k, heads x d of v], the pool's) and the window kernel are the Mamba
+# layers'. The pending window holds the window's keys and values.
+def _linear_scalars(cfg: GemmaConfig) -> dict:
+    """``_log_decay`` / ``chunk_outputs``' per-head scalars for a linear
+    layer: ``-exp(A_log) = log lambda_h``, no skip."""
+    return {
+        "A_log": jnp.log(-jnp.asarray(cfg.linear_decay)),
+        "D_skip": jnp.zeros((cfg.n_heads,), jnp.float32),
+    }
+
+
+def linear_inputs(n: jax.Array, lp: dict, cfg: GemmaConfig, positions: jax.Array) -> tuple:
+    """n [B, T, D], positions [B, T] -> q (scaled), k, v [B, T, H, d], FLOAT32
+    as accumulated: what enters the float32 state is not rounded on the way
+    (the window's products are a few MFLOP; the pending window alone keeps
+    its keys and values in the activations' type)."""
+    from mcpx.models.gemma.model import apply_rope, rms_norm
+
+    H, d = cfg.n_heads, cfg.head_dim
+    f32 = jnp.float32
+    heads = lambda w: jnp.einsum("btd,de->bte", n, w, preferred_element_type=f32).reshape(
+        n.shape[:2] + (H, d)
+    )
+    q, k = heads(lp["wq"]), heads(lp["wk"])
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_plus_one, f32)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_plus_one, f32)
+    if cfg.rope_full_layers:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    return q * d**-0.5, k, heads(lp["wv"])
+
+
+def linear_out(y: jax.Array, n: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
+    """y [B, T, H, d] float32 -> the mixer's output [B, T, D] as accumulated:
+    the norm over each head's values, the gate out of the layer's normed
+    input, W_o."""
+    B, T = y.shape[:2]
+    f32 = jnp.float32
+    o = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
+    o = o.reshape(B, T, -1) * lp["o_norm"].astype(f32)
+    if cfg.attn_gate:
+        gate = jnp.einsum("btd,de->bte", n, lp["w_attn_gate"], preferred_element_type=f32)
+        o = o * jax.nn.sigmoid(gate)
+    return jnp.einsum("bte,ed->btd", o.astype(n.dtype), lp["wo"], preferred_element_type=f32)
+
+
+def linear_prefill(n: jax.Array, lp: dict, cfg: GemmaConfig, seq_lens: jax.Array) -> tuple:
+    """A linear layer over a padded prompt from an empty state -> (its output
+    [B, T, D] float32, the state AT ``seq_lens`` [B, d, H, d])."""
+    Bsz, T, _ = n.shape
+    H, d = cfg.n_heads, cfg.head_dim
+    positions = jnp.broadcast_to(jnp.arange(T), (Bsz, T))
+    q, k, v = linear_inputs(n, lp, cfg, positions)
+    dt = jnp.broadcast_to((positions < seq_lens[:, None])[:, :, None], (Bsz, T, H)).astype(jnp.float32)
+    h0 = jnp.zeros((Bsz, d, H, d), jnp.float32)
+    y, h = ssd_scan(h0, dt, v, k, q, _linear_scalars(cfg), cfg.ssm_chunk_size)
+    return linear_out(y, n, lp, cfg), h
+
+
+def linear_window(
+    n: jax.Array,  # [B, S, D] the window's normed input
+    lp: dict,
+    cfg: GemmaConfig,
+    ssm: jax.Array,  # the state pool's states [layers, slots, d, H d]: kv_cache.init_state_pool
+    layer: int,
+    state: dict,  # this layer's pending window, [slots, ...]: dt, k, v
+    src: jax.Array,  # [B] the slot each row's state is READ from (out of range: an empty state)
+    dst: jax.Array,  # [B] the slot it is written to (out of range: nowhere)
+    q_lens: jax.Array,  # [B] live window slots (0: an idle row, which changes nothing)
+    kept: jax.Array,  # [B] tokens of ``src``'s PENDING window that stay
+    positions: jax.Array,  # [B, S]
+    *,
+    commit: bool,  # every live slot of this window stays (a prefill's): nothing is left pending
+    kernel=None,  # engine/kernels/ssm.ssm_window (a window that stays pending, src == dst), or None
+) -> tuple[jax.Array, jax.Array, dict]:
+    """One paged forward's window of a linear layer -> (its output [B, S, D]
+    float32, the states with this layer's moved, the layer's new pending
+    window). As ``mamba_window``: ``src``'s pending window is applied as far
+    as it was kept, the window's outputs are computed from that state. A
+    decode window then stays PENDING in ``dst``; a prefill's (``commit``: a
+    suffix over a shared head's state, a chunk of a head's build) is scanned
+    in chunks and ``dst`` holds the state AT the row's last live slot,
+    nothing pending. ``src`` and ``dst`` differ where a row starts from a
+    state that is not its own (the head's slot)."""
+    Bsz, S, _ = n.shape
+    H, d = cfg.n_heads, cfg.head_dim
+    W = state["dt"].shape[1]
+    if S > W and not commit:
+        raise ValueError(f"a window of {S} slots, the state pool keeps {W} pending")
+    f32 = jnp.float32
+    scalars = _linear_scalars(cfg)
+    n_slots = ssm.shape[1]
+    q_lens = jnp.where(dst < n_slots, q_lens, 0)
+    live = q_lens > 0
+    in_window = jnp.arange(S)[None, :] < q_lens[:, None]
+    has = src < n_slots
+    at_src = jnp.minimum(src, n_slots - 1)
+    # --- what the source left pending, masked to what was kept of it
+    kept = jnp.where(has, kept, 0)
+    p_dt = jnp.where(jnp.arange(W)[None, :, None] < kept[:, None, None], state["dt"][at_src], 0.0)
+    p_k, p_v = state["k"][at_src], state["v"][at_src]
+    total, xs = commit_terms(p_dt, p_v, scalars)
+    # --- this window
+    q, k, v = linear_inputs(n, lp, cfg, positions)
+    dt = jnp.broadcast_to(in_window[:, :, None], (Bsz, S, H)).astype(f32)
+    at = jnp.where(live, dst, n_slots)  # an idle row's slot is written nowhere
+    if kernel is not None:  # (a window that commits is handed none)
+        ssm, hc = kernel(
+            ssm, layer, at_src, q_lens,
+            jnp.repeat(total, d, axis=1), xs.reshape(Bsz, W, H * d),
+            jnp.transpose(p_k.astype(f32), (0, 2, 3, 1)), jnp.transpose(q.astype(f32), (0, 2, 1, 3)),
+        )
+        y = chunk_outputs(hc.reshape(Bsz, S, H, d), dt, v, k, q, scalars)
+    else:
+        h0 = jnp.where(has[:, None, None, None], ssm[layer, at_src].reshape(Bsz, d, H, d), 0.0)
+        h = advance_state(h0, total, xs, p_k)
+        if commit:
+            y, h = ssd_scan(h, dt, v, k, q, scalars, cfg.ssm_chunk_size)
+        else:
+            y = chunk_outputs(state_outputs(h, q), dt, v, k, q, scalars)
+        ssm = ssm.at[layer, at].set(h.reshape(Bsz, d, H * d), mode="drop")
+    if commit:
+        new = {**state, "dt": state["dt"].at[at].set(0.0, mode="drop")}
+    else:
+        pad = lambda a: jnp.pad(a, ((0, 0), (0, W - S)) + ((0, 0),) * (a.ndim - 2))
+        new = {
+            "dt": state["dt"].at[at].set(pad(dt), mode="drop"),
+            "k": state["k"].at[at].set(pad(k).astype(state["k"].dtype), mode="drop"),
+            "v": state["v"].at[at].set(pad(v).astype(state["v"].dtype), mode="drop"),
+        }
+    return linear_out(y, n, lp, cfg), ssm, new

@@ -172,7 +172,26 @@ def _hybrid_pspecs(cfg: GemmaConfig, m, whole) -> dict[str, Any]:
     attention's heads split over ``model`` as everywhere; the Mamba leaves,
     the router, the latent projections, the shared expert and the experts
     held stay whole on every device (the cut's deployment: data-parallel
-    mixers, and which experts a device holds is the configuration's)."""
+    mixers, and which experts a device holds is the configuration's). An
+    ``L`` / ``S`` pattern's two stacks are whole on every device: its one cell
+    runs on one chip."""
+    if cfg.mixer_ffn:
+        both = {
+            "norm": whole(2), "mlp_norm": whole(2), "wq": whole(3), "wk": whole(3), "wv": whole(3),
+            "wo": whole(3), "w_gate": whole(3), "w_up": whole(3), "w_down": whole(3),
+        }
+        if cfg.attn_gate:
+            both["w_attn_gate"] = whole(3)
+        specs = {"embed": P(m(cfg.vocab_size), None), "final_norm": P(None)}
+        if cfg.n_linear_layers:
+            specs["linear_layers"] = {**both, "o_norm": whole(2)}
+            if cfg.qk_norm:
+                specs["linear_layers"].update(q_norm=whole(2), k_norm=whole(2))
+        if cfg.n_block_layers:
+            specs["block_layers"] = dict(both)
+        if not cfg.tie_embeddings:
+            specs["head"] = P(None, m(cfg.vocab_size))
+        return specs
     specs = {
         "embed": P(m(cfg.vocab_size), None),
         "final_norm": P(None),

@@ -358,8 +358,9 @@ class Metrics:
             "mcpx_engine_prefix_state_total",
             "Admissions of a model with recurrent layers by what the radix "
             "tree could give them of the STATE a hit needs at its boundary: "
-            "miss (pages resident, no node holds a state: the row prefilled "
-            "whole) is the one event there is",
+            "miss (pages resident, no state to start from there: the row "
+            "prefilled whole), and hit where the declared head's end state is "
+            "kept (the row started from a copy of it)",
             ["event"],
             registry=self.registry,
         )

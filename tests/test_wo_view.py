@@ -223,8 +223,12 @@ def test_at_every_cells_widths_the_tree_keeps_its_heads_and_the_scan_merges_them
         # A layer_pattern model walks its stacks without a scan, and its
         # attention leaves are STORED with the heads merged on the matmul's own
         # axis (its reference reads them so): a row of the stack feeds its dot.
-        assert shapes["attn_layers"]["wo"].shape == (cfg.n_attn_layers, H * dv, D)
-        assert shapes["attn_layers"]["wq"].shape == (cfg.n_attn_layers, D, H * dv)
+        stacks = {"attn_layers": cfg.n_attn_layers}
+        if cfg.mixer_ffn:  # both mixers' leaves, heads merged alike
+            stacks = {"block_layers": cfg.n_block_layers, "linear_layers": cfg.n_linear_layers}
+        for name, n in stacks.items():
+            assert shapes[name]["wo"].shape == (n, H * dv, D)
+            assert shapes[name]["wq"].shape == (n, D, H * dv)
         return
     runs = jax.eval_shape(lambda p: [scanned for scanned, _, _ in layer_stacks(cfg, p)[0]], shapes)
     stored = _stored_stacks(cfg, shapes)
