@@ -107,6 +107,7 @@ from mcpx.telemetry import tracing
 from mcpx.telemetry.costs import CostRegistry, device_peaks
 from mcpx.telemetry.flight import SEGMENT_PARTS, WorkerProfiler
 from mcpx.telemetry.metrics import Metrics
+from mcpx.telemetry.startup import StartupTimeline
 from mcpx.utils.ownership import owned_by
 
 log = logging.getLogger("mcpx.engine")
@@ -449,6 +450,14 @@ class InferenceEngine:
         mesh=None,
         metrics: Optional[Metrics] = None,
     ) -> None:
+        self.metrics = metrics or Metrics()
+        # The start-up timeline (telemetry/startup.py), from the process's
+        # start where this is its first engine: ``startup.import`` ends and
+        # ``startup.build`` opens HERE, before anything below is built, and
+        # the worker's ``_setup`` closes it. Served by /healthz's ``startup``
+        # block and GET /traces/startup; ``ControlPlane.startup`` appends its
+        # own phase and finishes it.
+        self.startup = StartupTimeline(self.metrics)
         self.config = config or MCPXConfig()
         ecfg = self.config.engine
         self.tokenizer = make_tokenizer(self.config.model.vocab)
@@ -498,7 +507,6 @@ class InferenceEngine:
         # attention); a default block returns none.
         self._segment_stats = (bool(mc.n_experts) or mc.mixer_ffn, mc.layer_windows() is not None)
         self.grammar: PlanGrammar = build_plan_grammar(self.tokenizer)
-        self.metrics = metrics or Metrics()
         # Resolved kernel route, decided at construction so a COLD engine
         # can already answer pallas_paths()/queue_stats(). Mosaic tiles the
         # last (lane) dim at 128, so a head dim that doesn't align cannot
@@ -788,6 +796,7 @@ class InferenceEngine:
         self.costs = CostRegistry(
             metrics=self.metrics,
             enabled=self.config.telemetry.cost_accounting,
+            startup=self.startup,
         )
         # Decode-loop host profiler (telemetry/flight.py): per-iteration
         # phase timers tiling the worker loop's wall time into named
@@ -1337,29 +1346,47 @@ class InferenceEngine:
         from mcpx.parallel.mesh import make_mesh
         from mcpx.utils.backend import enable_compilation_cache
 
-        ecfg = self.config.engine
-        # Startup compiles dozens of bucket executables; the persistent
-        # cache makes every start after the first load them instead.
-        log.info("compilation cache: %s", enable_compilation_cache())
-        # _use_pallas was resolved in __init__ (config + head-dim probe) so
-        # the cold-engine observability surfaces could already report it;
-        # nothing at setup time changes the verdict.
-        if self._mesh is None:
-            data_axis, model_axis = self._mesh_axes(len(jax.devices()))
-            self._mesh = make_mesh(data=data_axis, model=model_axis)
+        # The timeline's phases from here on tile the worker's start-up:
+        # backend -> weights -> pools -> warmup (telemetry/startup.py).
+        phase = self.startup.phase
+        self.startup.end_build()
+        with phase("startup.backend"):
+            # Startup compiles dozens of bucket executables; the persistent
+            # cache makes every start after the first load them instead. What
+            # the cache holds NOW (empty? at its cap?) is the first thing a
+            # cold start's log should say.
+            log.info(
+                "compilation cache: dir=%(dir)s files=%(files)d bytes=%(bytes)d "
+                "max_bytes=%(max_bytes)d", self.startup.note_cache(enable_compilation_cache()),
+            )
+            # _use_pallas was resolved in __init__ (config + head-dim probe) so
+            # the cold-engine observability surfaces could already report it;
+            # nothing at setup time changes the verdict.
+            if self._mesh is None:
+                data_axis, model_axis = self._mesh_axes(len(jax.devices()))
+                self._mesh = make_mesh(data=data_axis, model=model_axis)
         # quantize="int8" (models/gemma/quant.py): the random-init path
         # quantizes each leaf at creation so the full-precision tree never
         # exists (7B-int8 on one 16 GB chip); checkpoints quantize after
         # restore — see load_or_init's documented limitation.
-        t_weights = time.monotonic()
-        self._params, source = load_or_init(
-            self.model_cfg,
-            self.config.model.checkpoint_path,
-            self._mesh,
-            quantize=self.config.model.quantize,
-        )
-        jax.block_until_ready(self._params)
-        init_s = time.monotonic() - t_weights
+        with phase("startup.weights") as weights:
+            self._params, source = load_or_init(
+                self.model_cfg,
+                self.config.model.checkpoint_path,
+                self._mesh,
+                quantize=self.config.model.quantize,
+            )
+            jax.block_until_ready(self._params)
+        # One pair of stamps, two names: the phase's wall is the gauge's.
+        with phase("startup.pools"):
+            self._setup_pools(source, weights.t1 - weights.t0)
+        if self.config.engine.warmup_compile:
+            self._warmup()
+
+    def _setup_pools(self, source: str, init_s: float) -> None:
+        """``_setup`` between the weights and the warm-up: what was placed
+        where, the KV and state pools, every jit wrapper, the slab."""
+        ecfg = self.config.engine
         if self.model_cfg.mixer_ffn:
             # No routed expert: every leaf but the embedding is read whole.
             held = sum(a.nbytes for a in jax.tree.leaves(self._params))
@@ -1550,8 +1577,6 @@ class InferenceEngine:
         )
         if self._spill_tier is not None and ecfg.kv_tier.snapshot_path:
             self._load_snapshot()
-        if ecfg.warmup_compile:
-            self._warmup()
 
     def _dfa_for(self, grammar: PlanGrammar) -> tuple:
         """Device copies of a grammar's tables (``PlanGrammar.device_tables``:
@@ -1649,9 +1674,18 @@ class InferenceEngine:
         temperature — the planner's only path; an unconstrained request or a
         non-default per-request temperature still compiles on first use.
         The segment warms with all rows inactive: the while_loop exits after
-        zero iterations, so the cost is compile only."""
+        zero iterations, so the cost is compile only.
+
+        The whole of it is the timeline's ``startup.warmup``; its children
+        (``warmup.grammar_tables``, a ``warmup.prefill`` a bucket, a
+        ``warmup.admit`` a cohort bucket, ``warmup.segment``,
+        ``warmup.merge``, ``warmup.cost_table``) tile it."""
+        with self.startup.phase("startup.warmup"):
+            self._warmup_phases()
+
+    def _warmup_phases(self) -> None:
+        phase = self.startup.phase
         ecfg = self.config.engine
-        tok = self.tokenizer
         capacity = ecfg.max_pages_per_seq * ecfg.kv_page_size
         t_buckets = [
             t
@@ -1663,57 +1697,70 @@ class InferenceEngine:
                 f"warmup: no prefill bucket fits page capacity {capacity} "
                 f"(kv_page_size*max_pages_per_seq); raise one of them"
             )
-        dfa = self._dfa_for(self.grammar)
-        # Hetero mode warms the stacked executables instead of the legacy
-        # per-(temperature, constrained) ones: ONE admit + ONE segment
-        # compile covers every sampling config and grammar combination, so
-        # the compile count below is independent of what serving later mixes.
-        sdfa = self._stacked_dfa() if ecfg.hetero_batch else None
+        with phase("warmup.grammar_tables"):
+            dfa = self._dfa_for(self.grammar)
+            # Hetero mode warms the stacked executables instead of the legacy
+            # per-(temperature, constrained) ones: ONE admit + ONE segment
+            # compile covers every sampling config and grammar combination, so
+            # the compile count below is independent of what serving later mixes.
+            sdfa = self._stacked_dfa() if ecfg.hetero_batch else None
         for A in self._batch_buckets:
             last = None
             for T in t_buckets:
-                last = self._warm_prefill(A, T)
-            admit_out = self._warm_admit(A, last, dfa, sdfa)
-            rs_a = self._row_spec(A)
-            rs_a2 = self._row_spec(A, 1)
-            # Admit-merge executable for this cohort bucket (all-dropped
-            # scatter: rows filled with B = padding, a semantic no-op).
-            self._jit_admit_merge(
-                *self._dev_state(self._slab),
-                self._put(np.full((A,), self._slab.B, np.int32), rs_a),
-                *admit_out,
-                self._put(np.zeros((A,), np.int32), rs_a),
-                self._put(np.zeros((A,), np.int32), rs_a),
-                self._put(
-                    np.zeros((A, ecfg.max_pages_per_seq), np.int32), rs_a2
-                ),
-                self._put(
-                    np.full((A, self._slab.prompt_cap), tok.pad_id, np.int32),
-                    rs_a2,
-                ),
-                self._put(np.zeros((A,), np.int32), rs_a),
-                self._put(np.full((A,), tok.pad_id, np.int32), rs_a),
-                self._put(np.zeros((A,), np.float32), rs_a),
-                self._put(np.zeros((A,), bool), rs_a),
-                self._put(np.zeros((A,), np.int32), rs_a),
-                self._put(
-                    np.zeros((A, self._slab.hstate.shape[1]), np.float32), rs_a2
-                ),
-            )
+                with phase("warmup.prefill", A=A, T=T):
+                    last = self._warm_prefill(A, T)
+            with phase("warmup.admit", A=A):
+                self._warm_admit_merge(A, self._warm_admit(A, last, dfa, sdfa))
         slab = self._slab
-        self._warm_segment(slab, dfa, sdfa)
-        # Compile the admission/retirement merge scatter too (row 0 is free,
-        # so merging its clear-values is a semantic no-op); the resulting
-        # device state equals the host state and stays usable for serving.
-        self._dirty_rows.add(0)
-        self._dispatch_merge(slab, [])
-        jax.block_until_ready(self._paged_kv["k"])
-        # Materialise the cost table for every warmed signature NOW (one
-        # lazy AOT compile each — on TPU these hit the persistent XLA
-        # cache): a warmed engine then never compiles for accounting in
-        # the serving path, extending warmup's no-compiles-while-serving
-        # contract to the observatory.
-        self.costs.snapshot(materialize=True)
+        with phase("warmup.segment"):
+            self._warm_segment(slab, dfa, sdfa)
+        with phase("warmup.merge"):
+            # Compile the admission/retirement merge scatter too (row 0 is free,
+            # so merging its clear-values is a semantic no-op); the resulting
+            # device state equals the host state and stays usable for serving.
+            self._dirty_rows.add(0)
+            self._dispatch_merge(slab, [])
+            jax.block_until_ready(self._paged_kv["k"])
+        with phase("warmup.cost_table"):
+            # Materialise the cost table for every warmed signature NOW: every
+            # one is lowered and compiled a SECOND time (``ExecCost.ensure``;
+            # on TPU the compile is a persistent-cache hit, the lowering is
+            # not) so that a warmed engine never compiles for accounting in
+            # the serving path, extending warmup's no-compiles-while-serving
+            # contract to the observatory. What that costs a start is this
+            # phase's wall.
+            self.costs.snapshot(materialize=True)
+
+    def _warm_admit_merge(self, A: int, admit_out: tuple) -> None:
+        """Compile the admit-merge executable for cohort bucket ``A``
+        (all-dropped scatter: rows filled with B = padding, a semantic
+        no-op)."""
+        ecfg = self.config.engine
+        tok = self.tokenizer
+        rs_a = self._row_spec(A)
+        rs_a2 = self._row_spec(A, 1)
+        self._jit_admit_merge(
+            *self._dev_state(self._slab),
+            self._put(np.full((A,), self._slab.B, np.int32), rs_a),
+            *admit_out,
+            self._put(np.zeros((A,), np.int32), rs_a),
+            self._put(np.zeros((A,), np.int32), rs_a),
+            self._put(
+                np.zeros((A, ecfg.max_pages_per_seq), np.int32), rs_a2
+            ),
+            self._put(
+                np.full((A, self._slab.prompt_cap), tok.pad_id, np.int32),
+                rs_a2,
+            ),
+            self._put(np.zeros((A,), np.int32), rs_a),
+            self._put(np.full((A,), tok.pad_id, np.int32), rs_a),
+            self._put(np.zeros((A,), np.float32), rs_a),
+            self._put(np.zeros((A,), bool), rs_a),
+            self._put(np.zeros((A,), np.int32), rs_a),
+            self._put(
+                np.zeros((A, self._slab.hstate.shape[1]), np.float32), rs_a2
+            ),
+        )
 
     def _warm_prefill(self, A: int, T: int):
         """Run one all-pad cohort through the prefill executables serving

@@ -9,7 +9,9 @@ plus the subsystems the reference only advertises:
   GET  /metrics    Prometheus text exposition (README.md:43-44, made real)
   GET  /costs      per-executable XLA cost accounting + compile sentinel +
                    device peaks/HBM stats (mcpx/telemetry/costs.py)
-  GET  /healthz    liveness + engine readiness
+  GET  /healthz    liveness + engine readiness, and where start-up stands
+                   (``startup``: the phases, the open one, the compile cache)
+  GET  /traces/startup   the start-up timeline as a trace (``?format=chrome``)
   GET  /telemetry  per-service rolling stats snapshot
   GET/POST /services, GET/DELETE /services/{name}   registry CRUD
              (the reference has no registration API at all, README.md:86)
@@ -84,7 +86,7 @@ _LIMITED = metrics_mod.LIMITED_ENDPOINTS
 # flush the ring with traces OF the observability itself — and `mcpx trace
 # dump`'s "newest trace" would be its own /traces listing.
 _UNTRACED = {
-    "/metrics", "/costs", "/cache", "/traces", "/traces/{trace_id}",
+    "/metrics", "/costs", "/cache", "/traces", "/traces/startup", "/traces/{trace_id}",
     "/healthz", "/telemetry", "/debug/anomalies",
     "/debug/anomalies/{bundle_id}", "/usage", "/slo", "/cluster",
     "/explain/{trace_id}",
@@ -517,6 +519,15 @@ def build_app(cp: ControlPlane) -> web.Application:
             return web.json_response(rec.to_chrome())
         return web.json_response(rec.to_dict())
 
+    async def trace_startup(request: web.Request) -> web.Response:
+        """The engine's start-up timeline (mcpx/telemetry/startup.py), in a
+        request trace's two formats. Kept outside the sampled ring: never
+        evicted, and there with tracing off."""
+        timeline = getattr(getattr(cp.planner, "engine", None), "startup", None)
+        if timeline is None:
+            return _json_error(404, "no engine, so no start-up timeline")
+        return web.json_response(timeline.trace(chrome=request.query.get("format") == "chrome"))
+
     async def explain_handler(request: web.Request) -> web.Response:
         """Decision-provenance explanation for one retained trace
         (mcpx/telemetry/provenance.py, docs/observability.md): the
@@ -671,6 +682,13 @@ def build_app(cp: ControlPlane) -> web.Application:
             # no compile is left on the serving path.
             "started": cp.started,
         }
+        timeline = getattr(engine, "startup", None)
+        if timeline is not None:
+            # Where start-up stands or stood (mcpx/telemetry/startup.py):
+            # present from the first phase on, so an operator watching a cold
+            # start's "warming" sees WHICH phase is open and what the compile
+            # cache held.
+            body["startup"] = timeline.snapshot()
         if engine_state == "ready":
             # Engine load snapshot (the scheduler's queue_stats() feed):
             # occupancy, per-class backlog, head-of-line age and resident
@@ -783,6 +801,7 @@ def build_app(cp: ControlPlane) -> web.Application:
     app.router.add_get("/costs", costs_handler)
     app.router.add_get("/cache", cache_handler)
     app.router.add_get("/traces", traces_handler)
+    app.router.add_get("/traces/startup", trace_startup)  # before the template
     app.router.add_get("/traces/{trace_id}", trace_get)
     app.router.add_get("/explain/{trace_id}", explain_handler)
     app.router.add_get("/debug/anomalies", anomalies_handler)

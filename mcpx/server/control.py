@@ -10,6 +10,7 @@ registries (SURVEY.md §5 checkpoint/resume).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from collections import OrderedDict
@@ -183,18 +184,33 @@ class ControlPlane:
                 jax=_jax_version(),
                 backend=jax.default_backend(),
             )
+        # The engine's start-up timeline (mcpx/telemetry/startup.py): the
+        # registry grammar is the control plane's own phase of it, and
+        # ``started`` is where it ends. A replica pool has none of its own.
+        timeline = getattr(getattr(self.planner, "engine", None), "startup", None)
         warm = getattr(self.planner, "warm", None)
         if warm is not None:
-            try:
-                await warm(self.registry)
-            except Exception as e:  # broad: serving continues, /healthz says why
-                # Not fatal — the first plan then pays the compile — but
-                # never quiet: GET /healthz reports it as warm_error.
-                self.warm_error = e
-                log.exception(
-                    "registry-grammar warmup failed; first plan pays the compile"
-                )
+            phase = (
+                timeline.phase("startup.registry_grammar")
+                if timeline is not None
+                else contextlib.nullcontext()
+            )
+            with phase as sp:
+                try:
+                    await warm(self.registry)
+                except Exception as e:  # broad: serving continues, /healthz says why
+                    # Not fatal — the first plan then pays the compile — but
+                    # never quiet: GET /healthz reports it as warm_error, and
+                    # the timeline's phase ends failed, with the type.
+                    self.warm_error = e
+                    if sp is not None:
+                        timeline.end(sp, error=e)
+                    log.exception(
+                        "registry-grammar warmup failed; first plan pays the compile"
+                    )
         self.started = True
+        if timeline is not None:
+            timeline.finish()
 
     # ------------------------------------------------------------------ plan
     async def plan(

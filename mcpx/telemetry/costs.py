@@ -21,8 +21,12 @@ keyed by a stable argument signature:
     touches execution.
   - **Retrace sentinel**: a NEW signature is a compile (jit's cache and
     this signature table miss together, by construction of the key). It
-    increments ``mcpx_engine_compiles_total{executable}`` and logs the
-    signature delta against the previous call — recompile storms (a
+    increments ``mcpx_engine_compiles_total{executable}``, adds the first
+    call's wall (trace + lower + compile or cache load + dispatch) to
+    ``mcpx_engine_compile_seconds_total{executable}``, counts as one of the
+    open start-up phase's ``executables`` (telemetry/startup.py) and logs the
+    signature delta against the previous call, with those seconds, once the
+    call has returned — recompile storms (a
     shape/dtype leaking into a jitted call per request) were until now
     only caught by compile-count *tests*; in production the counter +
     the delta line name exactly which argument leaf changed, live.
@@ -40,7 +44,9 @@ keyed by a stable argument signature:
     signature, right after the jit dispatch path itself compiled the same
     program (so on TPU the AOT twin is usually a persistent-XLA-cache
     hit). Backends that publish no costs materialise to a labeled
-    ``cost_basis="unavailable"``, never a guess.
+    ``cost_basis="unavailable"``, never a guess. What the pass costs is
+    stamped into ``mcpx_engine_cost_analysis_seconds_total``, and at warm-up
+    it is the start-up timeline's ``warmup.cost_table`` phase.
   - Disabled (``telemetry.cost_accounting=false``), ``wrap`` returns the
     jitted callable unchanged: a true pass-through, matching the repo's
     config-gated-subsystem convention.
@@ -59,6 +65,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Optional
 
@@ -279,6 +286,7 @@ class ExecCost:
             owner = self.owner
             spec = self.lower_spec
             basis = "unavailable"
+            t0 = time.monotonic()
             try:
                 if owner is None or spec is None:
                     raise RuntimeError("no lowering spec retained")
@@ -319,6 +327,11 @@ class ExecCost:
             # keep — no device program retained per signature.
             self.lower_spec = None
             self.cost_basis = basis
+            metrics = getattr(getattr(owner, "_registry", None), "_metrics", None)
+            if metrics is not None:
+                # What this second lowering and compile cost: the warm-up's
+                # ``warmup.cost_table`` phase is the sum of these.
+                metrics.engine_cost_analysis_seconds.inc(time.monotonic() - t0)
         return self
 
     def to_dict(self) -> dict:
@@ -376,7 +389,7 @@ class TrackedExecutable:
         sig = self._sig(args, kwargs)
         entry = self._entries.get(sig)
         if entry is None:
-            entry = self._registry._on_compile(self, sig, args, kwargs)
+            return self._registry._first_call(self, sig, args, kwargs)
         entry.calls += 1
         self.last_entry = entry
         return self._jitted(*args, **kwargs)
@@ -392,11 +405,19 @@ class CostRegistry:
     (``GET /costs``)."""
 
     def __init__(
-        self, metrics: Any = None, *, enabled: bool = True, name: str = "engine"
+        self,
+        metrics: Any = None,
+        *,
+        enabled: bool = True,
+        name: str = "engine",
+        startup: Any = None,  # mcpx.telemetry.startup.StartupTimeline
     ) -> None:
         self.enabled = enabled
         self.name = name
         self._metrics = metrics
+        # The engine's start-up timeline: a new signature counts as one of
+        # the ``executables`` of whatever phase is open (none after started).
+        self._startup = startup
         self._tracked: list[TrackedExecutable] = []
         self._lock = threading.Lock()
         # Sentinel arming: before arm() — engine startup/warmup, where
@@ -428,9 +449,54 @@ class CostRegistry:
         return t
 
     # Called from TrackedExecutable on a NEW signature (worker thread).
+    def _first_call(self, t: TrackedExecutable, sig: tuple, args: tuple, kwargs: dict):
+        """The first call at a new signature, where the compile happens:
+        register it, run it through the unmodified jitted callable, and stamp
+        the call's wall (trace + lower + compile or cache load + dispatch)
+        into ``mcpx_engine_compile_seconds_total{executable}`` and the
+        sentinel's log line, which is written once the call has returned.
+        The path of a known signature never comes here."""
+        n, last_sig = len(t._entries) + 1, t._last_sig
+        entry = self._on_compile(t, sig, args, kwargs)
+        entry.calls += 1
+        t.last_entry = entry
+        t0 = time.monotonic()
+        try:
+            return t._jitted(*args, **kwargs)
+        finally:
+            seconds = time.monotonic() - t0
+            if self._metrics is not None:
+                self._metrics.engine_compile_seconds.labels(executable=t.name).inc(seconds)
+            if last_sig is None:
+                log.info(
+                    "%s executable '%s' compiled signature #1 in %.3f s: %s",
+                    self.name, t.name, seconds, entry.signature,
+                )
+            elif not self.armed:
+                # Startup/warmup: multi-bucket compiles are the expected cold
+                # path, not a retrace — INFO, so the WARNING below stays a
+                # real signal.
+                log.info(
+                    "%s executable '%s' compiled signature #%d (startup) in %.3f s: %s",
+                    self.name, t.name, n, seconds, _sig_delta(last_sig, sig),
+                )
+            else:
+                # The sentinel line: every post-ready compile names the exact
+                # argument delta that caused it and what it cost. A recompile
+                # storm reads as a stream of these with the same leaf index
+                # churning.
+                log.warning(
+                    "%s executable '%s' RETRACED in the serving path "
+                    "(compile #%d, %.3f s): %s",
+                    self.name, t.name, n, seconds, _sig_delta(last_sig, sig),
+                )
+
     def _on_compile(
         self, t: TrackedExecutable, sig: tuple, args: tuple, kwargs: dict
     ) -> ExecCost:
+        """Book a new signature: its entry with the abstract lowering spec,
+        the compile counter, and one more of the open start-up phase's
+        ``executables``."""
         import jax
 
         entry = ExecCost(signature=_sig_repr(sig), owner=t)
@@ -442,30 +508,8 @@ class CostRegistry:
             log.debug("lowering-spec capture failed for '%s'", t.name, exc_info=True)
         if self._metrics is not None:
             self._metrics.engine_compiles.labels(executable=t.name).inc()
-        if t._last_sig is None:
-            log.info(
-                "%s executable '%s' compiling signature #1 %s",
-                self.name, t.name, entry.signature,
-            )
-        elif not self.armed:
-            # Startup/warmup: multi-bucket compiles are the expected cold
-            # path, not a retrace — INFO, so the WARNING below stays a
-            # real signal.
-            log.info(
-                "%s executable '%s' compiling signature #%d (startup): %s",
-                self.name, t.name, len(t._entries) + 1,
-                _sig_delta(t._last_sig, sig),
-            )
-        else:
-            # The sentinel line: every post-ready compile names the exact
-            # argument delta that caused it. A recompile storm reads as a
-            # stream of these with the same leaf index churning.
-            log.warning(
-                "%s executable '%s' RETRACED in the serving path "
-                "(compile #%d): %s",
-                self.name, t.name, len(t._entries) + 1,
-                _sig_delta(t._last_sig, sig),
-            )
+        if self._startup is not None:
+            self._startup.add("executables", 1)
         t._last_sig = sig
         t._entries[sig] = entry
         return entry

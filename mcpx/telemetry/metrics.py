@@ -12,6 +12,7 @@ app instances (tests!) never collide on the global default registry.
 from __future__ import annotations
 
 import time
+from typing import Optional
 
 from prometheus_client import (
     CollectorRegistry,
@@ -36,6 +37,7 @@ class Metrics:
     def __init__(self) -> None:
         self.registry = CollectorRegistry()
         self._t_start = time.monotonic()
+        self._startup_gauges: dict[str, Gauge] = {}  # set_startup's, made at first write
         # Build identity + uptime (ISSUE 14 satellite): every scrape — and
         # every diagnostic bundle / usage report derived from one — is
         # attributable to a concrete build. The labels are set once by the
@@ -338,6 +340,50 @@ class Metrics:
             ["executable"],
             registry=self.registry,
         )
+        self.engine_compile_seconds = Counter(
+            "mcpx_engine_compile_seconds_total",
+            "Wall seconds of each engine executable's FIRST call at a new "
+            "signature (trace + lower + compile or cache load + dispatch), "
+            "beside mcpx_engine_compiles_total: what a compile cost, at "
+            "start-up and, for a retrace, on the serving path",
+            ["executable"],
+            registry=self.registry,
+        )
+        self.engine_cost_analysis_seconds = Counter(
+            "mcpx_engine_cost_analysis_seconds_total",
+            "Wall seconds the cost registry spent lowering and compiling "
+            "warmed signatures a SECOND time to harvest cost_analysis() "
+            "(ExecCost.ensure: the warm-up's tail, a GET /costs scrape)",
+            registry=self.registry,
+        )
+        # The start-up timeline (mcpx/telemetry/startup.py): written once, at
+        # the control plane's ``started``, and constant after it. ONE label a
+        # gauge: a benchmark metric file addresses a sample by its label set
+        # as the exposition prints it.
+        self.startup_phase_seconds = Gauge(
+            "mcpx_startup_phase_seconds",
+            "Wall seconds of each start-up phase (import, build, backend, "
+            "weights, pools, warmup, registry_grammar; the warm-up's children "
+            "under their own names, warmup.prefill and warmup.admit summed "
+            "over their buckets)",
+            ["phase"],
+            registry=self.registry,
+        )
+        self.startup_warmup_jax_seconds = Gauge(
+            "mcpx_startup_warmup_jax_seconds",
+            "Of the warm-up's wall, the seconds JAX reported lowering to MLIR "
+            "(lower), in the backend's compile-or-load (backend) and, inside "
+            "that, retrieving from the persistent cache (cache_load)",
+            ["stage"],
+            registry=self.registry,
+        )
+        self.startup_cache_events = Gauge(
+            "mcpx_startup_cache_events",
+            "Persistent compilation cache hits and misses from the process's "
+            "start to started",
+            ["event"],
+            registry=self.registry,
+        )
         self.hbm_bytes_in_use = Gauge(
             "mcpx_hbm_bytes_in_use",
             "Device memory in use (memory_stats), per local device — with "
@@ -628,6 +674,29 @@ class Metrics:
         """Stamp the build-identity labels (once, at control-plane build).
         Idempotent: re-stamping with the same labels is a no-op series."""
         self.build_info.labels(version=version, jax=jax, backend=backend).set(1)
+
+    def set_startup(
+        self, *, ready_s: float, executables: int, cache_hit_ratio: Optional[float]
+    ) -> None:
+        """The start-up timeline's unlabelled gauges, registered when they are
+        first written (``StartupTimeline.finish``, at ``started``): absent, not
+        0, until then, and ``mcpx_startup_cache_hit_ratio`` absent for good
+        where no compile asked the cache."""
+        values = {
+            "mcpx_startup_ready_seconds": (
+                ready_s, "Wall seconds from the process's start to started"),
+            "mcpx_startup_executables": (
+                executables, "New executable signatures compiled or loaded up to started"),
+            "mcpx_startup_cache_hit_ratio": (
+                cache_hit_ratio, "hits / (hits + misses) of the persistent compilation "
+                "cache from the process's start to started"),
+        }
+        for name, (value, doc) in values.items():
+            if value is None:
+                continue
+            if name not in self._startup_gauges:
+                self._startup_gauges[name] = Gauge(name, doc, registry=self.registry)
+            self._startup_gauges[name].set(value)
 
     def render(self, *, openmetrics: bool = False) -> bytes:
         """Prometheus text exposition; ``openmetrics=True`` renders the

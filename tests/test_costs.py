@@ -3,9 +3,13 @@ cost accounting, the mcpx_engine_compiles_total retrace sentinel, span
 wiring, spec-rate gauges, and the GET /costs surface."""
 
 import asyncio
+import os
+import re
+import time
 from types import SimpleNamespace
 
 import numpy as np
+import pytest
 
 from mcpx.core.config import MCPXConfig
 from mcpx.telemetry.costs import CostRegistry, hbm_stats
@@ -313,3 +317,341 @@ def test_costs_endpoint_with_engine_serves_snapshot():
             await eng.aclose()
 
     asyncio.run(go())
+
+
+# ------------------------------------------------- what a compile cost (ISSUE 54)
+def _sample(metrics: Metrics, name: str, labels: dict | None = None):
+    return metrics.registry.get_sample_value(name, labels or {})
+
+
+def test_a_new_signature_adds_its_seconds_and_a_known_one_adds_nothing():
+    """``mcpx_engine_compile_seconds_total{executable}`` beside the compile
+    counter: the first call at a signature stamps its wall (trace + lower +
+    compile + dispatch), the path of a known signature stamps nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    metrics = Metrics()
+    reg = CostRegistry(metrics=metrics)
+    f = reg.wrap("toy", jax.jit(lambda x: (x * 3.0).sum()))
+    labels = {"executable": "toy"}
+    assert _sample(metrics, "mcpx_engine_compile_seconds_total", labels) is None
+    assert float(f(jnp.ones((8,)))) == 24.0
+    first = _sample(metrics, "mcpx_engine_compile_seconds_total", labels)
+    assert first > 0.0 and _compiles(metrics, "toy") == 1.0
+    f(jnp.ones((8,)))
+    f(jnp.zeros((8,)))
+    assert _sample(metrics, "mcpx_engine_compile_seconds_total", labels) == first
+    f(jnp.ones((16,)))  # a retrace has its seconds too
+    assert _sample(metrics, "mcpx_engine_compile_seconds_total", labels) > first
+    # ... and the cost table's second lowering stamps its own wall.
+    assert _sample(metrics, "mcpx_engine_cost_analysis_seconds_total") == 0.0
+    reg.snapshot(materialize=True)
+    assert _sample(metrics, "mcpx_engine_cost_analysis_seconds_total") > 0.0
+
+
+def test_the_sentinels_log_lines_carry_the_seconds(caplog):
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+
+    reg = CostRegistry(metrics=Metrics())
+    f = reg.wrap("toy", jax.jit(lambda x: x + 1))
+    with caplog.at_level(logging.INFO, logger="mcpx.costs"):
+        f(jnp.ones((4,)))
+        f(jnp.ones((5,)))
+        reg.arm()
+        f(jnp.ones((6,)))
+    first, startup, retrace = [r for r in caplog.records if "toy" in r.getMessage()]
+    assert "signature #1 in " in first.getMessage() and first.levelno == logging.INFO
+    assert "(startup) in " in startup.getMessage() and " s: leaf[0]" in startup.getMessage()
+    assert retrace.levelno == logging.WARNING and "RETRACED in the serving path" in retrace.getMessage()
+    assert re.search(r"compile #3, \d+\.\d{3} s\): leaf\[0\]", retrace.getMessage())
+
+
+# ------------------------------------------------- the start-up timeline (ISSUE 54)
+JAX_EVENTS = {
+    "lower_s": "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "backend_s": "/jax/core/compile/backend_compile_duration",
+    "cache_load_s": "/jax/compilation_cache/cache_retrieval_time_sec",
+    "cache_requests": "/jax/compilation_cache/compile_requests_use_cache",
+    "cache_hits": "/jax/compilation_cache/cache_hits",
+    "cache_misses": "/jax/compilation_cache/cache_misses",
+}
+
+
+def _record(attr: str, amount: float) -> None:
+    import jax.monitoring
+
+    if attr.endswith("_s"):
+        jax.monitoring.record_event_duration_secs(JAX_EVENTS[attr], amount)
+    else:
+        for _ in range(int(amount)):
+            jax.monitoring.record_event(JAX_EVENTS[attr])
+
+
+def test_a_process_start_is_read_off_proc_and_claimed_once():
+    from mcpx.telemetry import startup
+
+    t_proc = startup._process_start_monotonic()
+    if os.path.exists("/proc/self/stat"):
+        assert t_proc is not None and 0.0 < time.monotonic() - t_proc < 7 * 86400
+    first, second = startup.StartupTimeline(), startup.StartupTimeline()
+    # Whoever built this process's first timeline (an earlier test's engine,
+    # or ``first``) holds the one startup.import; a later one never does.
+    assert "startup.import" not in [s.name for s in second.record.spans]
+    assert second.record.spans[1].name == "startup.build"
+    assert [s.name for s in first.record.spans].count("startup.import") <= 1
+
+
+def test_a_second_engine_in_one_process_writes_no_startup_import():
+    engines = [make_engine(), make_engine()]
+    names = [[s.name for s in e.startup.record.spans] for e in engines]
+    assert "startup.import" not in names[1]
+    assert names[1] == ["startup", "startup.build"]
+    assert engines[1].startup.snapshot()["current"] == "startup.build"
+
+
+@pytest.mark.parametrize("attr", list(JAX_EVENTS))
+def test_an_event_lands_on_the_innermost_open_phase(attr):
+    """The listeners' arithmetic without a compile: an event belongs to the
+    phase open when it ends, a parent's sums include its children's, and an
+    event with no phase open lands nowhere."""
+    from mcpx.telemetry.startup import StartupTimeline
+
+    tl = StartupTimeline()
+    tl.end_build()
+    _record(attr, 2)  # no phase open: counted under none
+    with tl.phase("startup.warmup") as outer:
+        _record(attr, 1)
+        with tl.phase("warmup.prefill", A=1, T=64) as inner:
+            _record(attr, 2)
+            assert tl.snapshot()["current"] == "warmup.prefill"
+        assert inner.attrs[attr] == 2 and tl.snapshot()["current"] == "startup.warmup"
+    assert outer.attrs[attr] == 3 and (inner.attrs["A"], inner.attrs["T"]) == (1, 64)
+    _record(attr, 2)
+    assert tl.record.root.attrs[attr] == 3
+    build = next(s for s in tl.record.spans if s.name == "startup.build")
+    assert build.attrs[attr] == 0
+    for sp in (outer, inner):
+        wall = sp.t1 - sp.t0
+        assert sp.attrs["other_s"] == pytest.approx(wall - sp.attrs["lower_s"] - sp.attrs["backend_s"])
+
+
+def test_the_trace_event_is_not_summed():
+    import jax.monitoring
+
+    from mcpx.telemetry.startup import PHASE_ATTRS, StartupTimeline
+
+    tl = StartupTimeline()
+    tl.end_build()
+    with tl.phase("startup.warmup") as sp:
+        jax.monitoring.record_event_duration_secs("/jax/core/compile/jaxpr_trace_duration", 5.0)
+        jax.monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+    assert [sp.attrs[k] for k in PHASE_ATTRS] == [0] * len(PHASE_ATTRS)
+
+
+def test_a_real_compile_inside_a_phase_leaves_its_seconds_and_one_executable():
+    """One new signature of a CPU jit inside an open phase: JAX's own events
+    reach the phase (``backend_s`` > 0) and the registry's new signature is
+    its one executable. The CPU backend gets no persistent cache, so hits and
+    misses are the chip's to show."""
+    import jax
+    import jax.numpy as jnp
+
+    from mcpx.telemetry.startup import StartupTimeline
+
+    tl = StartupTimeline()
+    tl.end_build()
+    reg = CostRegistry(metrics=Metrics(), startup=tl)
+    f = reg.wrap("toy", jax.jit(lambda x: jnp.tanh(x @ x.T).sum()))
+    with tl.phase("startup.warmup") as sp:
+        f(jnp.ones((24, 8)))
+        f(jnp.ones((24, 8)))  # known: neither an executable nor an event
+    f(jnp.ones((25, 8)))  # no phase open: under none
+    assert sp.attrs["executables"] == 1 and tl.record.root.attrs["executables"] == 1
+    assert sp.attrs["backend_s"] > 0.0 and sp.attrs["lower_s"] > 0.0
+    assert sp.attrs["lower_s"] + sp.attrs["backend_s"] <= sp.t1 - sp.t0
+    assert sp.attrs["cache_load_s"] == 0.0 and sp.attrs["cache_hits"] == 0
+
+
+def test_finish_writes_the_gauges_once_and_leaves_the_hit_ratio_out_without_a_cache():
+    from mcpx.telemetry.startup import StartupTimeline
+
+    metrics = Metrics()
+    tl = StartupTimeline(metrics)
+    tl.end_build()
+    with tl.phase("startup.warmup"):
+        for T in (64, 128):
+            with tl.phase("warmup.prefill", A=1, T=T):
+                _record("lower_s", 0.25)
+                _record("backend_s", 0.5)
+        with tl.phase("warmup.cost_table"):
+            pass
+    assert _sample(metrics, "mcpx_startup_ready_seconds") is None  # absent, not 0, until started
+    tl.finish()
+    ready = _sample(metrics, "mcpx_startup_ready_seconds")
+    assert ready == tl.ready_s > 0.0
+    prefill = [s for s in tl.record.spans if s.name == "warmup.prefill"]
+    assert _sample(metrics, "mcpx_startup_phase_seconds", {"phase": "warmup.prefill"}) == pytest.approx(
+        sum(s.t1 - s.t0 for s in prefill))  # the per-bucket phases summed a kind
+    assert _sample(metrics, "mcpx_startup_phase_seconds", {"phase": "warmup.cost_table"}) >= 0.0
+    assert _sample(metrics, "mcpx_startup_warmup_jax_seconds", {"stage": "lower"}) == 0.5
+    assert _sample(metrics, "mcpx_startup_warmup_jax_seconds", {"stage": "backend"}) == 1.0
+    assert _sample(metrics, "mcpx_startup_executables") == 0.0
+    assert _sample(metrics, "mcpx_startup_cache_events", {"event": "hit"}) == 0.0
+    assert _sample(metrics, "mcpx_startup_cache_hit_ratio") is None
+    tl.finish()  # constant after it
+    assert _sample(metrics, "mcpx_startup_ready_seconds") == ready
+    # ... and with a cache that was asked, the ratio is hits / (hits + misses).
+    warm = StartupTimeline(Metrics())
+    warm.end_build()
+    with warm.phase("startup.warmup"):
+        _record("cache_requests", 5)
+        _record("cache_hits", 3)
+        _record("cache_misses", 1)
+    warm.finish()
+    assert _sample(warm._metrics, "mcpx_startup_cache_hit_ratio") == 0.75
+    assert _sample(warm._metrics, "mcpx_startup_cache_events", {"event": "miss"}) == 1.0
+
+
+def test_an_engines_phases_tile_its_start_and_count_its_executables():
+    """The worker's phases in order, tiling ``startup.build``'s end to the
+    warm-up's; ``warmup.*`` tile ``startup.warmup``; the executables they
+    count are the compile counter's."""
+
+    async def go():
+        eng = make_engine(warmup_compile=True, warmup_max_len=64)
+        try:
+            await eng.start()
+            return eng, eng.startup.snapshot(), eng.metrics.render().decode()
+        finally:
+            await eng.aclose()
+
+    eng, snap, text = asyncio.run(go())
+    rows = snap["phases"]
+    top = [r["name"] for r in rows if r["name"].startswith("startup.")]
+    assert [n for n in top if n != "startup.import"] == [
+        "startup.build", "startup.backend", "startup.weights", "startup.pools", "startup.warmup"]
+    kinds = [r["name"] for r in rows if r["name"].startswith("warmup.")]
+    assert kinds[0] == "warmup.grammar_tables" and kinds[-3:] == [
+        "warmup.segment", "warmup.merge", "warmup.cost_table"]
+    assert kinds.count("warmup.prefill") == kinds.count("warmup.admit") == len(eng._batch_buckets)
+    assert snap["current"] is None and snap["ready_s"] is None  # no control plane said started
+    warmup = next(r for r in rows if r["name"] == "startup.warmup")
+    children = [r for r in rows if r["name"].startswith("warmup.")]
+    assert sum(r["t1_s"] - r["t0_s"] for r in children) == pytest.approx(
+        warmup["t1_s"] - warmup["t0_s"], rel=0.02, abs=0.02)
+    assert sum(r["executables"] for r in children) == warmup["executables"]
+    compiles = sum(v for k, v in _prom(text).items() if k.startswith("mcpx_engine_compiles_total{"))
+    assert warmup["executables"] == compiles > 0
+    for r in rows:
+        assert r["lower_s"] + r["backend_s"] <= (r["t1_s"] - r["t0_s"]) + 0.002, r
+        assert r["cache_load_s"] <= r["backend_s"]
+    weights = next(r for r in rows if r["name"] == "startup.weights")
+    assert _prom(text)["mcpx_engine_weights_init_seconds"] == pytest.approx(
+        weights["t1_s"] - weights["t0_s"], abs=0.002)  # one pair of stamps, two names
+    assert snap["cache"] == {"dir": None, "files": 0, "bytes": 0, "max_bytes": snap["cache"]["max_bytes"]}
+
+
+def _prom(text: str) -> dict:
+    out = {}
+    for line in text.splitlines():
+        if line and not line.startswith("#"):
+            key, _, value = line.rpartition(" ")
+            out[key] = float(value)
+    return out
+
+
+def test_the_control_plane_appends_its_phase_and_serves_the_timeline():
+    """``ControlPlane.startup`` appends ``startup.registry_grammar`` and
+    finishes the timeline at ``started``; a failed warm ends the phase with
+    ``error=true`` and the exception's type, so /healthz's ``startup`` and
+    ``warm_error`` agree; GET /traces/startup serves both formats."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from mcpx.server.app import build_app
+    from mcpx.server.factory import build_control_plane
+
+    async def go():
+        eng = make_engine()
+
+        async def warm(registry):
+            raise RuntimeError("trie too wide")
+
+        cp = build_control_plane(MCPXConfig())
+        r404 = None
+        cp.planner = SimpleNamespace(engine=eng, ensure_ready=eng.start, warm=warm)
+        client = TestClient(TestServer(build_app(cp)))
+        await client.start_server()  # on_startup launches cp.startup()
+        try:
+            for _ in range(3000):
+                if cp.started:
+                    break
+                await asyncio.sleep(0.02)
+            health = await (await client.get("/healthz")).json()
+            tree = await (await client.get("/traces/startup")).json()
+            chrome = await (await client.get("/traces/startup?format=chrome")).json()
+            listed = await (await client.get("/traces")).json()
+            cp.planner = SimpleNamespace()
+            r404 = (await client.get("/traces/startup")).status
+            return health, tree, chrome, listed, r404, eng.metrics
+        finally:
+            await client.close()
+            await eng.aclose()
+
+    health, tree, chrome, listed, r404, metrics = asyncio.run(go())
+    st = health["startup"]
+    assert health["started"] is True and "RuntimeError: trie too wide" in health["warm_error"]
+    last = st["phases"][-1]
+    assert last["name"] == "startup.registry_grammar"
+    assert last["error"] is True and last["error_type"] == "RuntimeError"
+    assert st["current"] is None and st["ready_s"] > 0.0
+    assert st["ready_s"] == pytest.approx(_sample(metrics, "mcpx_startup_ready_seconds"), abs=0.001)
+    assert st["t0_unix"] == pytest.approx(tree["started_at"], abs=0.01) and st["t0_unix"] < time.time()
+    assert tree["name"] == "startup" and tree["tree"][0]["parent_id"] is None
+    failed = next(s for s in tree["tree"] if s["name"] == "startup.registry_grammar")
+    assert failed["status"] == "error" and failed["attrs"]["error_type"] == "RuntimeError"
+    assert {e["name"] for e in chrome["traceEvents"] if e["ph"] == "X"} >= {
+        "startup", "startup.build", "startup.weights", "startup.registry_grammar"}
+    assert listed["traces"] == []  # kept outside the sampled ring
+    assert r404 == 404
+    assert _sample(metrics, "mcpx_startup_phase_seconds", {"phase": "registry_grammar"}) >= 0.0
+
+
+def test_events_from_many_threads_lose_no_update():
+    """The listeners run on whichever thread compiled while phases open and
+    close on others: sums under the timeline's lock, no lost update."""
+    import sys
+    import threading
+
+    from mcpx.telemetry.startup import StartupTimeline
+
+    tl = StartupTimeline()
+    tl.end_build()
+    n_threads, n_events = 16, 400
+    stop = threading.Event()
+
+    def churn():  # phases opening and closing under the one that counts
+        while not stop.is_set():
+            tl.end(tl.begin("warmup.prefill", A=1, T=64))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with tl.phase("startup.warmup") as sp:
+            workers = [threading.Thread(target=lambda: [_record("cache_hits", 1) for _ in range(n_events)])
+                       for _ in range(n_threads)]
+            churner = threading.Thread(target=churn)
+            churner.start()
+            for w in workers:
+                w.start()
+            for w in workers:
+                w.join(timeout=60)
+            stop.set()
+            churner.join(timeout=60)
+            assert not churner.is_alive() and not any(w.is_alive() for w in workers)
+    finally:
+        sys.setswitchinterval(interval)
+    assert sp.attrs["cache_hits"] == n_threads * n_events == tl.record.root.attrs["cache_hits"]
