@@ -258,10 +258,15 @@ class EngineConfig:
     # forward with per-row greedy/stochastic accept rules. Off = the legacy
     # hetero segment, byte-identical (see SpeculativeConfig).
     speculative: SpeculativeConfig = field(default_factory=SpeculativeConfig)
-    # Batch-size buckets requests are padded up to. Few buckets = few XLA
-    # compiles (each (B, T) pair is one prefill executable, each B one decode
-    # executable); padding rows are nearly free on TPU where decode is
-    # weight-load-bound. Empty = auto {1, 8, max_batch_size}.
+    # Row buckets an admission cohort is padded up to. Few buckets = few XLA
+    # compiles (each (B, T) pair is one prefill executable a route, each B one
+    # admit and one admit-merge). Padding rows are nearly free in a DECODE
+    # forward, which is weight-load-bound on a TPU; a PREFILL past ~240 tokens
+    # a weight pass is compute-bound and costs by the slot. Empty = the
+    # engine's own table (``engine.cohort_buckets``): {1, 8, max_batch_size}
+    # and its halves down to 8, and down to 4 where short whole prompts land
+    # (the 128 prefill bucket, no matched prefix). A list given here holds at
+    # every prefill bucket and on both routes.
     batch_buckets: list = field(default_factory=list)
     # Execute one batch per (B, T) bucket at startup so no compile lands in
     # the serving path. Off by default: tests construct many engines.

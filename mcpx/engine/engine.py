@@ -65,7 +65,7 @@ import queue
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Any, Optional
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -230,6 +230,44 @@ def _bucket(n: int, buckets: tuple[int, ...]) -> int:
         if n <= b:
             return b
     raise EngineError(f"length {n} exceeds largest bucket {buckets[-1]}")
+
+
+# Where the automatic cohort buckets go below 8 rows: the whole-prompt route at
+# the prefill bucket of _SMALL_COHORT_T slots, halved down to
+# _SMALL_COHORT_FLOOR rows. A prefill past ~240 tokens a weight pass is
+# compute-bound on a v5e and costs by the slot, so a cohort of 2-4 prompts of
+# 65-128 tokens through 8 x 128 slots pays twice what 4 x 128 does. Every
+# bucket is executables to trace, compile or load at EVERY start, though (one
+# a prefill bucket a route, an admit, an admit-merge, the registry grammar's
+# admit: a prefill executable of an unrolled hybrid stack is ~1.5 s of a warm
+# start), so the small bucket exists only where it pays most: at the 64 bucket
+# 8 rows are the 512 slots that 4 x 128 are; a row past 128 slots, or one
+# behind a shared prefix, is what an explicit ``engine.batch_buckets`` is for
+# (a.x-k1's [1, 2, 4] at the 1,024 bucket costs 63 executables; the catalogue
+# cells warm six prefill buckets on two routes).
+_SMALL_COHORT_FLOOR = 4
+_SMALL_COHORT_T = 128
+
+
+def cohort_buckets(rows: int, explicit: tuple[int, ...], T: int, suffix: bool) -> tuple[int, ...]:
+    """The ONE table of which admission-cohort sizes exist: the row buckets of
+    a ``rows``-row slab at prefill bucket ``T`` on the whole-prompt route or,
+    ``suffix``, behind a matched prefix. Warm-up compiles exactly these and
+    admission rounds a cohort up among exactly these, so no cohort asks for
+    an executable the start did not build. An ``explicit`` list
+    (``engine.batch_buckets``) holds at every ``T`` on both routes; ``rows``
+    itself always exists, so a fully gathered burst has a bucket."""
+    if explicit:
+        sizes = set(explicit)
+    else:
+        small = not suffix and T == _SMALL_COHORT_T
+        floor = _SMALL_COHORT_FLOOR if small else 8
+        sizes = {1, 8, rows}
+        q = rows
+        while q >= 2 * floor:
+            q //= 2
+            sizes.add(q)
+    return tuple(sorted({b for b in sizes if b < rows} | {rows}))
 
 
 @dataclasses.dataclass
@@ -744,22 +782,6 @@ class InferenceEngine:
                 f"no usable prefill bucket <= max_seq_len={self.model_cfg.max_seq_len} "
                 f"that is a multiple of kv_page_size={ecfg.kv_page_size}"
             )
-        # Admission-cohort size buckets. Always include max_batch_size so a
-        # fully-gathered burst has a bucket. Each bucket is one compiled
-        # prefill executable per prompt length; the intermediate sizes keep
-        # hysteresis-sized cohorts (max_batch_size/4, see admit_min_free)
-        # from padding all the way up to a full-slab prefill.
-        auto = {1, 8, ecfg.max_batch_size}
-        q = ecfg.max_batch_size
-        while q >= 16:
-            q //= 2
-            auto.add(q)
-        self._batch_buckets = tuple(
-            sorted(
-                {b for b in (tuple(ecfg.batch_buckets) or tuple(auto)) if b < ecfg.max_batch_size}
-                | {ecfg.max_batch_size}
-            )
-        )
         # DFA tables enter the jitted decode as ARGUMENTS (padded shapes,
         # grammar.device_tables()), so per-registry grammars swap without
         # recompiling; recompiles happen only when a pad bucket changes.
@@ -1704,11 +1726,11 @@ class InferenceEngine:
             # compile covers every sampling config and grammar combination, so
             # the compile count below is independent of what serving later mixes.
             sdfa = self._stacked_dfa() if ecfg.hetero_batch else None
-        for A in self._batch_buckets:
+        for A, shapes in self._cohort_table(t_buckets).items():
             last = None
-            for T in t_buckets:
+            for T, routes in shapes.items():
                 with phase("warmup.prefill", A=A, T=T):
-                    last = self._warm_prefill(A, T)
+                    last = self._warm_prefill(A, T, routes)
             with phase("warmup.admit", A=A):
                 self._warm_admit_merge(A, self._warm_admit(A, last, dfa, sdfa))
         slab = self._slab
@@ -1730,6 +1752,31 @@ class InferenceEngine:
             # contract to the observatory. What that costs a start is this
             # phase's wall.
             self.costs.snapshot(materialize=True)
+
+    def _cohort_buckets(self, T: int, suffix: bool) -> tuple[int, ...]:
+        """``cohort_buckets`` (the module's table) of this engine's slab."""
+        ecfg = self.config.engine
+        return cohort_buckets(ecfg.max_batch_size, tuple(ecfg.batch_buckets), T, suffix)
+
+    def _cohort_table(self, t_buckets: Sequence[int]) -> dict[int, dict[int, tuple[bool, ...]]]:
+        """What a start compiles for admission, read off ``cohort_buckets``:
+        ``{A: {T: routes}}`` over ``t_buckets``, a route ``False`` for the
+        whole-prompt prefill and ``True`` for the suffix prefill. Every ``A``
+        in it gets one admit and one admit-merge besides."""
+        ecfg = self.config.engine
+        # Shared-prefix serving prefills SUFFIXES through the chunked route.
+        # (A model whose recurrent layers have no suffix route never takes
+        # it: its rows prefill whole.)
+        suffix_route = ecfg.prefix_cache and (
+            not self.model_cfg.hybrid or self.model_cfg.head_state
+        )
+        table: dict[int, dict[int, tuple[bool, ...]]] = {}
+        for T in t_buckets:
+            for suffix in (False, True) if suffix_route else (False,):
+                for A in self._cohort_buckets(T, suffix):
+                    shapes = table.setdefault(A, {})
+                    shapes[T] = shapes.get(T, ()) + (suffix,)
+        return dict(sorted(table.items()))
 
     def _warm_admit_merge(self, A: int, admit_out: tuple) -> None:
         """Compile the admit-merge executable for cohort bucket ``A``
@@ -1762,10 +1809,11 @@ class InferenceEngine:
             ),
         )
 
-    def _warm_prefill(self, A: int, T: int):
+    def _warm_prefill(self, A: int, T: int, routes: Sequence[bool]):
         """Run one all-pad cohort through the prefill executables serving
-        dispatches for bucket (A, T) and return its last-position logits
-        handle — the input the first-sample admit is compiled against."""
+        dispatches for bucket (A, T) on ``routes`` (``_cohort_table``'s) and
+        return its last-position logits handle — the input the first-sample
+        admit is compiled against."""
         ecfg = self.config.engine
         tok = self.tokenizer
         tokens = np.full((A, T), tok.pad_id, np.int32)
@@ -1773,25 +1821,23 @@ class InferenceEngine:
         # Null page table: scatters land on reserved page 0, which
         # no live sequence ever reads.
         table = np.zeros((A, ecfg.max_pages_per_seq), np.int32)
-        # Every row a padding row: its state slot is out of range, dropped.
-        last, k_p, v_p, _, state = self._jit_prefill(
-            self._params,
-            self._put(tokens, self._row_spec(A, 1)),
-            self._put(seq_lens, self._row_spec(A)),
-            self._paged_kv["k"],
-            self._paged_kv["v"],
-            self._put(table, self._row_spec(A, 1)),
-            self._state_pool,
-            self._cohort_slots(A),
-            T=T,
-        )
-        self._paged_kv = {"k": k_p, "v": v_p}
-        self._state_pool = state
-        if ecfg.prefix_cache and (not self.model_cfg.hybrid or self.model_cfg.head_state):
-            # Shared-prefix serving prefills SUFFIXES through the
-            # chunked path; compile it for the same buckets. (A model whose
-            # recurrent layers have no suffix route never takes it: its rows
-            # prefill whole.)
+        last = None
+        if False in routes:  # the whole-prompt prefill
+            # Every row a padding row: its state slot is out of range, dropped.
+            last, k_p, v_p, _, state = self._jit_prefill(
+                self._params,
+                self._put(tokens, self._row_spec(A, 1)),
+                self._put(seq_lens, self._row_spec(A)),
+                self._paged_kv["k"],
+                self._paged_kv["v"],
+                self._put(table, self._row_spec(A, 1)),
+                self._state_pool,
+                self._cohort_slots(A),
+                T=T,
+            )
+            self._paged_kv = {"k": k_p, "v": v_p}
+            self._state_pool = state
+        if True in routes:  # the suffix prefill
             last, k_p, v_p, _, self._state_pool = self._jit_suffix_prefill(
                 self._params,
                 self._put(tokens, self._row_spec(A, 1)),
@@ -1926,9 +1972,10 @@ class InferenceEngine:
         if self.config.engine.hetero_batch:
             return
         dfa = self._dfa_for(grammar)
-        for A in self._batch_buckets:
-            last = self._warm_prefill(A, self._prefill_buckets[0])
-            self._warm_admit(A, last, dfa, None)
+        for A, shapes in self._cohort_table(self._prefill_buckets).items():
+            # Any of the bucket's prefills gives the admit its logits' shape.
+            T, routes = next(iter(shapes.items()))
+            self._warm_admit(A, self._warm_prefill(A, T, routes[:1]), dfa, None)
         # Resident rows keep decoding afterwards: the segment is compiled
         # over an idle twin of the live slab, never over its state.
         live = self._slab
@@ -2127,7 +2174,7 @@ class InferenceEngine:
         now = time.monotonic()
         while self._pending_admissions:
             (
-                t0, marker, rows, gens, t_admit0, pf_entry, pf_name, pf_toks,
+                t0, marker, rows, gens, t_admit0, pf_entry, pf_name, pf_toks, A,
             ) = self._pending_admissions[0]
             if not marker.is_ready():
                 # Purge entries whose rows were ALL cancelled/reaped before
@@ -2186,6 +2233,10 @@ class InferenceEngine:
                         t0=t_admit0,
                         t1=now,
                         dfa_id=int(slab.dfa[i]),
+                        # The cohort this row was prefilled in, and the row
+                        # bucket it was padded up to (``cohort_buckets``).
+                        cohort_rows=len(rows),
+                        cohort_bucket=A,
                         **pfx_attrs,
                     )
 
@@ -3001,6 +3052,7 @@ class InferenceEngine:
         # prefix, not per request) — prefill-tokens-per-request accounting
         # must see it or reuse would overstate itself.
         self.metrics.prefill_tokens.inc(R)
+        self.metrics.prefill_slots.inc(T)
         self.metrics.prefix_build_chunks.inc()
         self._prefix_built = (self._prefix_built[0] + 1, self._prefix_built[1] + R)
         cache.seal()  # dispatched: later cohorts may read these pages
@@ -4698,7 +4750,11 @@ class InferenceEngine:
             pending.appendleft(r)
         if not cohort:
             return
-        A = _bucket(len(cohort), self._batch_buckets)
+        # A row behind a matched prefix sends the whole cohort down the
+        # suffix route; which row buckets exist there, at this T, is the
+        # table's to say (what warm-up compiled).
+        any_prefix = any(pfx[0] > 0 for pfx in prefixes)
+        A = _bucket(len(cohort), self._cohort_buckets(T, any_prefix))
         # The STAGE-2 fix-point T, not a recompute from the committed
         # prompts: every planned match depth satisfies P + T <= capacity
         # against THIS T, and a commit-time degraded row's regrown suffix
@@ -4716,7 +4772,6 @@ class InferenceEngine:
         cons_np = np.zeros((A,), bool)
         dfa_np = np.zeros((A,), np.int32)
         table = np.zeros((A, ecfg.max_pages_per_seq), np.int32)
-        any_prefix = False
         for j, (r, ids, budget) in enumerate(zip(cohort, prompts, budgets)):
             ids = ids[:T]
             tokens[j, : len(ids)] = ids
@@ -4737,7 +4792,6 @@ class InferenceEngine:
             # writes land strictly past the prompt, in private pages.
             P, shared_pages, _nodes, _copy = prefixes[j]
             positions[j] = P
-            any_prefix = any_prefix or P > 0
             n_pp = P // psz
             n_sh = len(shared_pages)
             table[j, :n_pp] = shared_pages[:n_pp]
@@ -4887,6 +4941,7 @@ class InferenceEngine:
         # The prefill chain and its first sample are on the device's queue.
         self._t_queued = self._t_queued or t1
         self.metrics.prefill_tokens.inc(int(seq_lens[: len(cohort)].sum()))
+        self.metrics.prefill_slots.inc(A * T)
         self.metrics.admissions.inc()
         self.metrics.admitted_rows.inc(len(cohort))
         rows_idx: list[int] = []
@@ -5011,7 +5066,7 @@ class InferenceEngine:
             (
                 t1, slab.dev[4], rows_idx,
                 [int(slab.gen[i]) for i in rows_idx], t0, pf_entry, pf_name,
-                [int(n) for n in seq_lens[: len(cohort)]],
+                [int(n) for n in seq_lens[: len(cohort)]], A,
             )
         )
         self.metrics.kv_page_utilization.set(self._allocator.stats().utilization)

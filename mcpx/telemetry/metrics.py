@@ -479,6 +479,13 @@ class Metrics:
             "gives goodput model-FLOPs for MFU accounting",
             registry=self.registry,
         )
+        self.prefill_slots = Counter(
+            "mcpx_engine_prefill_slots_total",
+            "Prompt slots prefilled: cohort row bucket x prefill bucket an "
+            "admission, padding included — prefill_tokens / prefill_slots is "
+            "the live share of what the prefill chains computed",
+            registry=self.registry,
+        )
         self.prefix_build_chunks = Counter(
             "mcpx_engine_prefix_build_chunks_total",
             "Prefill dispatches that built a declared shared prompt head into "

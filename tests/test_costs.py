@@ -537,7 +537,9 @@ def test_an_engines_phases_tile_its_start_and_count_its_executables():
     kinds = [r["name"] for r in rows if r["name"].startswith("warmup.")]
     assert kinds[0] == "warmup.grammar_tables" and kinds[-3:] == [
         "warmup.segment", "warmup.merge", "warmup.cost_table"]
-    assert kinds.count("warmup.prefill") == kinds.count("warmup.admit") == len(eng._batch_buckets)
+    table = eng._cohort_table([64])  # warmup_max_len 64: one prefill bucket
+    assert kinds.count("warmup.admit") == len(table)
+    assert kinds.count("warmup.prefill") == sum(len(shapes) for shapes in table.values())
     assert snap["current"] is None and snap["ready_s"] is None  # no control plane said started
     warmup = next(r for r in rows if r["name"] == "startup.warmup")
     children = [r for r in rows if r["name"].startswith("warmup.")]

@@ -903,7 +903,8 @@ def test_warm_grammar_compiles_every_cohort_bucket_before_traffic():
             assert (await resident).text == solo.text
             c1 = compiles(eng)
             grew = {n: c1[n] - c0[n] for n in c1 if c1[n] != c0[n]}
-            assert grew == {"admit": len(eng._batch_buckets), "segment": 1}, grew
+            n_buckets = len(eng._cohort_table(eng._prefill_buckets))
+            assert grew == {"admit": n_buckets, "segment": 1}, grew
             for n in (1, 2, 4, 3):
                 outs = await asyncio.gather(
                     *(

@@ -670,22 +670,16 @@ TILE_CELLS = {
 }
 
 
-@pytest.mark.parametrize("cell", list(TILE_CELLS))
-def test_compiled_for_v5e_a_sparse_prefill_past_the_ridge_is_one_tile_kernel(one_v5e, cell):
-    """``moe_forward(use_pallas=True)`` at a sparse cell's published widths and
-    its widest prefill window, compiled by Mosaic for the v5e: what lowering
-    alone cannot show (a one-row slab that a DMA may slice, a tile's copy out
-    at a start Mosaic can prove whole sublane tiles, 65,536 sorted rows'
-    tokens in SMEM). ONE custom call, and beside its float32 result ``ys``
-    (a row a (slot, choice) pair) no second buffer of that many rows: no
-    sorted copy, no zero-fill, no gather-back."""
+def _sparse_prefill_layer(one_v5e, cell, T, Dm, E_all):
+    """``moe_forward(use_pallas=True, prefill=True)`` at ``cell``'s published
+    widths over a window of ``T`` slots, compiled for the described v5e."""
     import functools
 
     from mcpx.models.gemma import moe
     from tests.test_routed_experts_kernel import TILE_SHAPES
 
     _, replicated = one_v5e
-    (T, k, D, F, E, gated), (Dm, E_all) = TILE_SHAPES[cell], TILE_CELLS[cell]
+    _, k, D, F, E, gated = TILE_SHAPES[cell]
     block = dict(d_ff=128) if gated else dict(  # two-matrix experts in a latent: a layer_pattern model's
         d_ff=0, layer_pattern="ME", mamba_n_heads=8, mamba_head_dim=16, mamba_n_groups=2, ssm_state_size=32,
         d_shared_expert=128, moe_latent_size=D, router_scoring="sigmoid", rope_full_layers=False, activation="relu2",
@@ -702,12 +696,45 @@ def test_compiled_for_v5e_a_sparse_prefill_past_the_ridge_is_one_tile_kernel(one
         leaves["w_gate"] = sd((2, E, D, F), bf)
 
     def layer(h, rows, router, experts, live):
-        return moe.moe_forward(h, router, experts, jnp.int32(1), cfg, live, rows=rows, use_pallas=True)[:2]
+        return moe.moe_forward(
+            h, router, experts, jnp.int32(1), cfg, live, rows=rows, use_pallas=True, prefill=True)[:2]
 
     rows = None if D == Dm else sd((1, T, D), bf)
-    compiled = _compile_uncached(layer, sd((1, T, Dm), bf), rows, sd((Dm, E_all), bf), leaves, sd((1, T), jnp.bool_))
+    return _compile_uncached(layer, sd((1, T, Dm), bf), rows, sd((Dm, E_all), bf), leaves, sd((1, T), jnp.bool_))
+
+
+@pytest.mark.parametrize("cell", list(TILE_CELLS))
+def test_compiled_for_v5e_a_sparse_prefill_past_the_ridge_is_one_tile_kernel(one_v5e, cell):
+    """``moe_forward(use_pallas=True)`` at a sparse cell's published widths and
+    its widest prefill window, compiled by Mosaic for the v5e: what lowering
+    alone cannot show (a one-row slab that a DMA may slice, a tile's copy out
+    at a start Mosaic can prove whole sublane tiles, 65,536 sorted rows'
+    tokens in SMEM). ONE custom call, and beside its float32 result ``ys``
+    (a row a (slot, choice) pair) no second buffer of that many rows: no
+    sorted copy, no zero-fill, no gather-back."""
+    from mcpx.models.gemma import moe
+    from tests.test_routed_experts_kernel import TILE_SHAPES
+
+    (T, k, D, F, E, gated), (Dm, E_all) = TILE_SHAPES[cell], TILE_CELLS[cell]
+    compiled = _sparse_prefill_layer(one_v5e, cell, T, Dm, E_all)
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1 and "prefill_expert_tiles" in text
     A = moe.TILE_ALIGN
     ys = ((T * k + E * (A - 1) + A - 1) // A * A + moe.GROUP_TILE) * D * 4
     assert ys <= compiled.memory_analysis().temp_size_in_bytes < 1.25 * ys
+
+
+# The cells whose whole prompts the cohort table's small bucket takes (ISSUE
+# 55): name -> (the router's width, the experts the router scores).
+SMALL_COHORT_CELLS = {
+    "mellum2-12b-a2.5b": (2304, 64), "trinity-mini": (2048, 128), "nemotron-3-super": (4096, 512),
+}
+
+
+@pytest.mark.parametrize("cell", list(SMALL_COHORT_CELLS))
+def test_compiled_for_v5e_a_cohort_of_fours_sparse_prefill(one_v5e, cell):
+    """The window a 4-row cohort bucket adds to a sparse cell's warm-up, 4 x
+    128 slots, compiled by Mosaic at the published widths: past the ridge, so
+    the tile kernel at half its widest window. One custom call."""
+    text = _sparse_prefill_layer(one_v5e, cell, 4 * 128, *SMALL_COHORT_CELLS[cell]).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "prefill_expert_tiles" in text
