@@ -77,7 +77,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from mcpx.core.config import MCPXConfig
 from mcpx.core.errors import ConfigError, EngineError
 from mcpx.engine.kv_cache import (
-    PageAllocator, commit_prefill_key_sums, commit_prefill_to_pages, init_paged_kv, init_state_pool,
+    PageAllocator, commit_prefill_key_sums, commit_prefill_tails, commit_prefill_to_pages, init_paged_kv,
+    init_state_pool,
     write_prefill_state,
 )
 from mcpx.engine.pacing import SegmentPacer, hold_until
@@ -685,6 +686,8 @@ class InferenceEngine:
         # bound once with the weights: one routed expert's, and the rest of
         # what a forward reads.
         self._weight_bytes = (0, 0)  # mcpx: owner[engine-worker]
+        # The bytes a forward reads of the short-convolution mixers.
+        self._conv_weight_bytes = 0  # mcpx: owner[engine-worker]
         # Just-in-time dispatch of the next segment (engine/pacing.py): the
         # device's queue as the worker knows it and the running estimates
         # its hold deadline comes from. One clock read per admission,
@@ -1154,7 +1157,7 @@ class InferenceEngine:
             }
 
         more = {}
-        if self.model_cfg.hybrid:
+        if self.model_cfg.hybrid and not self.model_cfg.conv_ffn:
             # The recurrent layers' window kernel (kernels/ssm.py): every
             # decode segment of such a model runs it, where ONE device holds
             # the state pool.
@@ -1192,7 +1195,7 @@ class InferenceEngine:
                     "idle: prefix_cache=off (no suffix prefills)"
                     if not ecfg.prefix_cache
                     else "idle: a model with recurrent layers prefills whole (no suffix prefills)"
-                    if self.model_cfg.hybrid and not self.model_cfg.head_state
+                    if not self.model_cfg.suffix_route
                     else None,
                 ),
                 "spec_verify": path(
@@ -1265,7 +1268,7 @@ class InferenceEngine:
                             "prefix_state_miss": self._prefix_state_misses,
                             **(
                                 {"prefix_state_hit": self._prefix_state_hits}
-                                if self.model_cfg.head_state else {}
+                                if self.model_cfg.head_state or self.model_cfg.page_state else {}
                             ),
                         }
                         if self.model_cfg.hybrid else {}
@@ -1413,6 +1416,11 @@ class InferenceEngine:
             # No routed expert: every leaf but the embedding is read whole.
             held = sum(a.nbytes for a in jax.tree.leaves(self._params))
             self._weight_bytes = (0, held - self._params["embed"].nbytes)
+        if self.model_cfg.conv_ffn:
+            # What a forward reads of the short-convolution mixers, whole.
+            self._conv_weight_bytes = sum(
+                a.nbytes for a in jax.tree.leaves(self._params.get("conv_layers", {}))
+            )
         if self.model_cfg.n_experts:
             self._weight_bytes = forward_weight_bytes(self.model_cfg, self._params)
             # One sample an expert held, from 0: an expert no token ever
@@ -1436,6 +1444,15 @@ class InferenceEngine:
         }
         self._paged_kv = self._init_pools()
         self._state_pool = self._init_state_pool()
+        if self.model_cfg.page_state:
+            # The third kind of per-row state, for /healthz: the slots' tails
+            # and pending windows, and the tail a page.
+            tails = self._state_pool["tails"].nbytes
+            self._placement["state_pool"] = {
+                "bytes": sum(a.nbytes for a in jax.tree.leaves(self._state_pool)),
+                "page_tails_bytes": tails,
+                "slots": int(self._state_pool["n"].shape[0]),
+            }
         # Every jitted executable goes through the cost registry
         # (telemetry/costs.py): one AOT compile per signature harvests
         # XLA's cost_analysis() and increments the
@@ -1767,9 +1784,7 @@ class InferenceEngine:
         # Shared-prefix serving prefills SUFFIXES through the chunked route.
         # (A model whose recurrent layers have no suffix route never takes
         # it: its rows prefill whole.)
-        suffix_route = ecfg.prefix_cache and (
-            not self.model_cfg.hybrid or self.model_cfg.head_state
-        )
+        suffix_route = ecfg.prefix_cache and self.model_cfg.suffix_route
         table: dict[int, dict[int, tuple[bool, ...]]] = {}
         for T in t_buckets:
             for suffix in (False, True) if suffix_route else (False,):
@@ -1847,7 +1862,8 @@ class InferenceEngine:
                 self._paged_kv["k"],
                 self._paged_kv["v"],
                 self._state_pool,
-                self._cohort_slots(A),
+                # (admission's operands: a state kept a page is read from no slot)
+                self._cohort_slots(A) if self.model_cfg.head_state else None,
                 self._cohort_slots(A),
             )
             self._paged_kv = {"k": k_p, "v": v_p}
@@ -2224,7 +2240,15 @@ class InferenceEngine:
                         if self.config.engine.prefix_cache
                         else {}
                     )
-                    if self.model_cfg.hybrid:
+                    if self.model_cfg.conv_ffn:
+                        # The tokens this row's prefill moved its convolutions'
+                        # tails by, and the pages it filled to their last slot,
+                        # each with its tail written a ``C`` layer.
+                        psz = self.config.engine.kv_page_size
+                        P = int(slab.prefix_toks[i])
+                        pfx_attrs["conv_prefill_tokens"] = n_pf * self.model_cfg.n_conv_layers
+                        pfx_attrs["tail_pages_written"] = (P + n_pf) // psz - P // psz
+                    elif self.model_cfg.hybrid:
                         # The tokens this row's prefill moved its recurrent
                         # state by, over the Mamba layers: all it prefilled.
                         pfx_attrs["ssm_prefill_tokens"] = n_pf * self.model_cfg.n_recurrent_layers
@@ -2584,6 +2608,12 @@ class InferenceEngine:
         )
         if cfg.hybrid:
             state = write_prefill_state(state, slots, dense["ssm"])
+        if cfg.page_state:
+            # The tail of every page this prompt fills, in the program that
+            # writes the page's keys (docs/engine.md "The state's rule").
+            state = {**state, "tails": commit_prefill_tails(
+                state["tails"], dense["ssm"], page_table, self.config.engine.kv_page_size
+            )}
         if cfg.n_block_layers:
             state = {**state, "ksum": commit_prefill_key_sums(
                 state["ksum"], dense["k"], page_table, self.config.engine.kv_page_size
@@ -2612,23 +2642,27 @@ class InferenceEngine:
         state pool, the slot each row's state is READ from (the declared
         head's; out of range: an empty state) and the slot the state AT the
         row's last token is written to, nothing pending (a padding row's is
-        out of range and dropped)."""
+        out of range and dropped). A model whose state is kept a page
+        (``GemmaConfig.page_state``) reads no slot: each row starts from the
+        tail of the page before ``positions`` (``src`` None), and the program
+        writes the tail of every page it fills beside the page's keys."""
         cfg = self.model_cfg
+        carried = cfg.head_state or cfg.page_state
         last, kv, moe = decode_chunk_paged(
             params,
             cfg,
             tokens,
             positions,
             page_table,
-            {"k": paged_k, "v": paged_v, **({"state": state} if cfg.head_state else {})},
+            {"k": paged_k, "v": paged_v, **({"state": state} if carried else {})},
             use_pallas=self._use_pallas,
             interpret=self.config.engine.interpret,
             mesh=self._mesh,
             logits_at=seq_lens - 1,  # [A, V]: suffix-final logits only
             q_lens=seq_lens,
             moe_stats=True,  # as _prefill_impl: None from a dense model
-            state_slots=(src, slots) if cfg.head_state else None,
-            commit=cfg.head_state,
+            state_slots=(src, slots) if carried else None,
+            commit=carried,
         )
         return last, kv["k"], kv["v"], moe, kv.get("state", state)
 
@@ -3006,9 +3040,11 @@ class InferenceEngine:
         tokens[0, :R] = key[n:]
         # A head's state (``GemmaConfig.head_state``) is read from and written
         # to the pool's last slot: the chunk before left it there.
-        head_slot = (
-            self._cohort_slots(1, [self.config.engine.max_batch_size])
-            if self.model_cfg.head_state else None
+        # A head whose state is its pages' tails has no slot: the build's row
+        # is a padding row to the state pool (out of range, written nowhere;
+        # None for a model with no state pool).
+        head_slot = self._cohort_slots(
+            1, [self.config.engine.max_batch_size] if self.model_cfg.head_state else ()
         )
         try:
             if n > 0:
@@ -3022,7 +3058,7 @@ class InferenceEngine:
                     self._paged_kv["k"],
                     self._paged_kv["v"],
                     self._state_pool,
-                    head_slot,
+                    head_slot if self.model_cfg.head_state else None,
                     head_slot,
                 )
                 # Every suffix-prefill dispatch counts toward the
@@ -4360,7 +4396,7 @@ class InferenceEngine:
         head_key = (
             head_req.prefix_key(ecfg.kv_page_size)
             # (a head no recurrent layer could start from is not built)
-            if ecfg.prefix_cache and (not self.model_cfg.hybrid or self.model_cfg.head_state)
+            if ecfg.prefix_cache and self.model_cfg.suffix_route
             else None
         )
         warm_head = (
@@ -4477,12 +4513,18 @@ class InferenceEngine:
         # radix node holds one: such a model's rows prefill whole (and still
         # insert their heads: pages are pages), and a row whose pages were
         # resident is a counted miss.
-        no_state = use_prefix and self.model_cfg.hybrid and not self.model_cfg.head_state
+        no_state = use_prefix and not self.model_cfg.suffix_route
         # ... but where the declared head's END STATE is kept
         # (``GemmaConfig.head_state``) a row whose prompt starts with that
         # head matches it at EXACTLY its length and starts from a copy of the
         # state; any other depth has no state and is the same counted miss.
         stateful = use_prefix and self.model_cfg.head_state
+        # ... and where the state is kept a PAGE (``GemmaConfig.page_state``:
+        # a short convolution's tail) a row matches the tree at ANY depth, as
+        # a default-block row does, and its suffix prefill starts every such
+        # layer from the tail of its last matched page: a counted hit. Such a
+        # model has no miss.
+        paged_state = use_prefix and self.model_cfg.page_state
         head = self._head_state if stateful else None
         head_slot = ecfg.max_batch_size
         psz = ecfg.kv_page_size
@@ -4716,7 +4758,7 @@ class InferenceEngine:
                     cache.matched_tokens += P
                 else:
                     cache.misses += 1
-                if stateful and P > 0:
+                if (stateful or paged_state) and P > 0:
                     self._prefix_state_hits += 1
                     self.metrics.prefix_state.labels(event="hit").inc()
                 elif (no_state or stateful) and cache.probe(
@@ -4851,7 +4893,7 @@ class InferenceEngine:
                     self._paged_kv["v"],
                     self._state_pool,
                     src_d,
-                    slots_d if stateful else None,
+                    slots_d if stateful or paged_state else None,
                 )
                 pf_entry = getattr(self._jit_suffix_prefill, "last_entry", None)
                 pf_name = "suffix_prefill"
@@ -5177,7 +5219,7 @@ class InferenceEngine:
         # decode-path dispatch; the spec segment is ALSO a spec-verify
         # dispatch (its verify forward rides the same executable).
         self._pallas_dispatches["decode"] += 1
-        if self.model_cfg.hybrid:
+        if self.model_cfg.hybrid and not self.model_cfg.conv_ffn:
             self._pallas_dispatches["ssm"] += 1
         if self.model_cfg.n_block_layers:
             self._pallas_dispatches["gather"] += 1
@@ -5389,7 +5431,15 @@ class InferenceEngine:
         ``_run_blocks_total``). Windowed
         attention:
         ``rows_live`` at dispatch and ``rows_past_window`` of them, the rows
-        whose position had reached the window."""
+        whose position had reached the window. Short convolutions (a ``C`` /
+        ``A`` pattern): ``conv_row_calls`` (live rows x forwards x ``C``
+        layers), ``conv_slots`` (the live window slots those calls computed),
+        ``conv_tokens`` (the tokens the tails moved by), ``conv_tail_bytes``
+        (a call reads and writes its slot's tail and pending window),
+        ``conv_weight_bytes`` (the ``C`` mixers' share of
+        ``weight_bytes_read``) and ``conv_prefill_tokens`` (the admission
+        prefills' tokens times the ``C`` layers), where a Mamba or linear
+        model writes ``ssm_*``."""
         mc = self.model_cfg
         sparse, windowed = self._segment_stats
         attrs: dict[str, int] = {}
@@ -5443,11 +5493,21 @@ class InferenceEngine:
                 # they computed, the tokens the state moved by; a call reads
                 # a slot's state once and writes it once.
                 ssm = own + FORWARD_STATS + (BLOCK_STATS if mc.n_block_layers else 0)
-                attrs["ssm_row_calls"], attrs["ssm_slots"], attrs["ssm_tokens"] = (
+                kind = "conv" if mc.conv_ffn else "ssm"
+                attrs[kind + "_row_calls"], attrs[kind + "_slots"], attrs[kind + "_tokens"] = (
                     int(c) for c in counts[ssm : ssm + 3]
                 )
-                attrs["ssm_state_bytes"] = attrs["ssm_row_calls"] * mc.ssm_slot_bytes * 2
-                attrs["ssm_prefill_tokens"] = sum(int(c[ssm + 2]) for c in prefills)
+                if mc.conv_ffn:
+                    # A short convolution's call reads its slot's tail and
+                    # pending window and writes both; its weights are read
+                    # whole a forward.
+                    pending = self._state_pool["layers"][0]["pre"].shape[1] + mc.conv_kernel - 1
+                    slot_bytes = mc.conv_tail_bytes // (mc.conv_kernel - 1) * pending
+                    attrs["conv_tail_bytes"] = attrs["conv_row_calls"] * slot_bytes * 2
+                    attrs["conv_weight_bytes"] = n_fwd * self._conv_weight_bytes
+                else:
+                    attrs["ssm_state_bytes"] = attrs["ssm_row_calls"] * mc.ssm_slot_bytes * 2
+                attrs[kind + "_prefill_tokens"] = sum(int(c[ssm + 2]) for c in prefills)
             attrs["moe_experts_touched"] = int(counts[E])
             attrs["moe_layer_forwards"] = n_fwd * mc.n_sparse_layers
             attrs["moe_expert_slots"] = attrs["moe_layer_forwards"] * E
@@ -5860,7 +5920,7 @@ class InferenceEngine:
         from mcpx.parallel.mesh import MODEL_AXIS, _axis
 
         kv_spec = P(
-            _axis(self._mesh, MODEL_AXIS, self.model_cfg.n_kv_heads),
+            _axis(self._mesh, MODEL_AXIS, self.model_cfg.kv_pool_heads),
             None,
             None,
             None,
@@ -5886,7 +5946,7 @@ class InferenceEngine:
         # (``GemmaConfig.head_state``) it has the one slot beyond them.
         n_slots = self.config.engine.max_batch_size + mc.head_state
         self._head_state = None
-        for event in ("miss",) + (("hit",) if mc.head_state else ()):
+        for event in ("miss",) + (("hit",) if mc.head_state or mc.page_state else ()):
             self.metrics.prefix_state.labels(event=event)  # (the sample exists from the start, at 0)
         n_pages = self._allocator.n_pages
         return jax.jit(

@@ -33,8 +33,9 @@ width of keys a step (8 pages of 16), two where the heads leave room. So
     row pays decode traffic even when batched next to a prefill row; pages
     of a block past the row's last are neither fetched nor seen,
   - a key block is ``[H_BLK, P_BLK * page_size, head_dim]`` — page rows
-    contiguous, lane-aligned (head_dim multiple of 128), no in-kernel
-    transposes,
+    contiguous, lane-aligned (head_dim multiple of 128; heads of 64 lie two
+    to a pool row, ``GemmaConfig.kv_pack``, and the kernel sees a head of 128
+    with the softmax ``scale`` of 64), no in-kernel transposes,
   - arithmetic is one batched product over the program's heads,
     ``q [H, S*G, hd] @ k.T -> [H, S*G, P_BLK * page_size]`` (operands as
     stored, float32 out) then ``p @ v -> [H, S*G, hd]`` (float32): MXU
@@ -71,6 +72,7 @@ def ragged_paged_attention_reference(
     q_lens: jax.Array,  # [B] int32 — live queries per row (0 = idle row)
     layer: jax.Array | int = 0,
     window: "jax.Array | int | None" = None,
+    scale: "float | None" = None,  # the softmax scale (None: hd ** -0.5)
 ) -> jax.Array:
     """Ragged mixed-phase semantics, pure jnp: row ``b``'s queries at
     window index ``i < q_lens[b]`` attend through cache position
@@ -90,7 +92,8 @@ def ragged_paged_attention_reference(
     L = p_max * psz
     k = k_pages[:, layer][:, page_table].transpose(1, 0, 2, 3, 4).reshape(B, K, L, hd)
     v = v_pages[:, layer][:, page_table].transpose(1, 0, 2, 3, 4).reshape(B, K, L, hd)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     logits = jnp.einsum("bskgh,bklh->bskgl", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     vis = start_pos[:, None] + jnp.arange(S) + 1  # [B, S]
@@ -176,7 +179,9 @@ def _blocking(K, G, hd, page_size, sq, pool_itemsize, q_itemsize, p_max):
     return h_blk, p_blk
 
 
-def _ragged_kernel(*refs, page_size: int, p_blk: int, n_heads: int, windowed: bool):
+def _ragged_kernel(
+    *refs, page_size: int, p_blk: int, n_heads: int, windowed: bool, scale: "float | None" = None
+):
     """``refs``: the scalar prefetch — page_table [B, Pmax], start_pos [B],
     q_lens [B] (live queries per row; 0 = idle row), layer [1] (which
     layer's pool slice to stream) and, ``windowed``, window [1] (this
@@ -224,7 +229,8 @@ def _ragged_kernel(*refs, page_size: int, p_blk: int, n_heads: int, windowed: bo
     q = jnp.stack([q_ref[0, :, h].reshape(S * G, hd) for h in range(H)])
     if q.dtype != k_buf.dtype:
         q = q.astype(jnp.float32)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     # Visible length per q row r (row r is query r//G): start + r//G + 1;
     # pad queries (r//G >= qn) see nothing and zero out below.
     row_q = lax.broadcasted_iota(jnp.int32, (S * G, 1), 0) // G
@@ -338,7 +344,7 @@ def _ragged_kernel(*refs, page_size: int, p_blk: int, n_heads: int, windowed: bo
         out_ref[0, :, h] = out[h].reshape(S, G, hd).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "name"))
+@functools.partial(jax.jit, static_argnames=("interpret", "name", "scale"))
 def ragged_paged_attention(
     q: jax.Array,  # [B, S, K, G, hd] — padded query windows
     k_pages: jax.Array,  # [K, L, N, Psz, hd] — all layers (stays in HBM)
@@ -351,6 +357,7 @@ def ragged_paged_attention(
     *,
     interpret: bool = False,
     name: "str | None" = None,
+    scale: "float | None" = None,
 ) -> jax.Array:
     """The ragged mixed-phase kernel: grid (B, cdiv(K, H_BLK), cdiv(S, Sq));
     ONE program streams a row's pages once, a block of P_BLK pages a step,
@@ -373,7 +380,12 @@ def ragged_paged_attention(
     CALL's attention window, one more prefetched scalar beside ``layer``,
     so the layers of one scan may differ in it: a query at position p sees
     keys in (p - window, p]. The key blocks start at the first page the
-    query block's first query can see."""
+    query block's first query can see.
+
+    ``scale`` (None: ``hd ** -0.5``, and the program this always was) is the
+    softmax scale where a pool row is wider than a head: heads of 64 lie TWO to
+    a 128-lane row (``GemmaConfig.kv_pack``), each query padded with zeros over
+    its row-mate's lanes, and the scale stays the head's own ``64 ** -0.5``."""
     B, S, K, G, hd = q.shape
     windowed = window is not None
     _, _, _, page_size, _ = k_pages.shape
@@ -409,7 +421,8 @@ def ragged_paged_attention(
         ],
     )
     kernel = functools.partial(
-        _ragged_kernel, page_size=page_size, p_blk=p_blk, n_heads=K, windowed=windowed
+        _ragged_kernel, page_size=page_size, p_blk=p_blk, n_heads=K, windowed=windowed,
+        **({} if scale is None else {"scale": scale}),
     )
     scalars = (jnp.asarray(layer, jnp.int32).reshape(1),)
     if windowed:

@@ -179,14 +179,14 @@ def init_paged_kv(
     zeros up to a lane width) and ``v`` the normed latent, which the absorbed
     kernel reads for the scores and for the values."""
     d = jnp.dtype(dtype or cfg.dtype)
-    shape = (cfg.n_kv_heads, cfg.n_attn_layers, n_pages, page_size)
+    shape = (cfg.kv_pool_heads, cfg.n_attn_layers, n_pages, page_size)
     k_width, v_width = cfg.kv_widths
     return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
 
 
 def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int, n_pages: int = 0) -> dict:
-    """The SECOND kind of per-row state, beside the pages: what a Mamba layer
-    keeps of a row, indexed by SLOT (slab row ``i`` owns slot ``i``), not by
+    """The per-row state beside the pages, of three kinds. First, what a Mamba
+    layer keeps of a row, indexed by SLOT (slab row ``i`` owns slot ``i``), not by
     page. ``{}`` for a model with no such layer: an empty pytree adds nothing
     to a jitted call. Else ``{"ssm", "layers": one dict a Mamba layer, "n":
     [n_slots] int32}``: ``ssm`` ``[Mamba layers, n_slots, state, heads x
@@ -218,10 +218,38 @@ def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int, n_pages: int = 
     It rides here because this pytree is what the prefill, the suffix prefill
     and the segment of such a model hand on beside the two page pools; it is
     indexed by page, not by slot, and depends on its page's tokens alone, so
-    whatever shares a page shares its row."""
+    whatever shares a page shares its row.
+
+    A gated SHORT CONVOLUTION (``GemmaConfig.n_conv_layers``) is the pool's
+    THIRD kind: NO ``ssm`` array, because the layer's whole state is its
+    convolution's last ``conv_kernel - 1`` inputs. A layer's ``conv``
+    ``[n_slots, K - 1, D]`` is the row's LIVE tail, at its last committed
+    token, ``pre`` ``[n_slots, window, D]`` the inputs of the window its last
+    forward left pending and ``n`` how many of them the row kept. And because
+    that state is 16 KB a layer (float32, at 2,048 wide), it is ALSO kept at
+    every page boundary: ``tails`` ``[conv layers, n_pages, K - 1, D]``
+    float32, a row a PAGE as ``ksum``'s:
+    layer ``c``'s inputs at the page's last ``K - 1`` slots, written by every
+    program that fills a PROMPT page to its last slot, in the same program as
+    the page's keys. A row that matches ``P`` tokens of the radix tree starts
+    its suffix prefill from ``tails[:, page_table[row, P / page_size - 1]]``
+    whatever node or split the match came from: split, pin, evict and the
+    pending epoch move page ids, and the tails go with them
+    (``GemmaConfig.page_state``; docs/engine.md "The state's rule")."""
     if not cfg.n_recurrent_layers:
         return {}
     d = jnp.dtype(cfg.dtype)
+    if cfg.conv_ffn:
+        K1, D, L = cfg.conv_kernel - 1, cfg.d_model, cfg.n_conv_layers
+        f32 = jnp.float32  # (the mixer's own precision: ``models/gemma/ssm.py`` says why)
+        return {
+            "layers": tuple(
+                {"conv": jnp.zeros((n_slots, K1, D), f32), "pre": jnp.zeros((n_slots, window, D), f32)}
+                for _ in range(L)
+            ),
+            "n": jnp.zeros((n_slots,), jnp.int32),
+            "tails": jnp.zeros((L, n_pages, K1, D), f32),
+        }
     if cfg.mixer_ffn:
         H, hd, L = cfg.n_heads, cfg.head_dim, cfg.n_linear_layers
         pool = {
@@ -260,7 +288,15 @@ def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int, n_pages: int = 
 def write_prefill_state(state: dict, slots: jax.Array, finals: list) -> dict:
     """A prefill's states ``[(h [A, N, H, P], tail [A, K - 1, C])]`` a Mamba
     layer into ``slots`` [A] (a padding row's slot is out of range and
-    dropped): the state AT each prompt's length, nothing pending."""
+    dropped): the state AT each prompt's length, nothing pending. A short
+    convolution's finals are ``(tail [A, K - 1, D], u)``: the tail alone goes
+    to the slot (``commit_prefill_tails`` cuts the pages' from ``u``)."""
+    if "ssm" not in state:
+        layers = tuple(
+            {**pool, "conv": pool["conv"].at[slots].set(tail.astype(pool["conv"].dtype), mode="drop")}
+            for pool, (tail, _u) in zip(state["layers"], finals)
+        )
+        return {**state, "layers": layers, "n": state["n"].at[slots].set(0, mode="drop")}
     ssm, layers = state["ssm"], []
     if "conv" not in state["layers"][0]:  # linear layers: a state alone, no tail
         for j, (pool, h) in enumerate(zip(state["layers"], finals)):
@@ -321,3 +357,23 @@ def commit_prefill_key_sums(
     sums = sums.transpose(3, 0, 1, 2, 4).reshape(K, L, B * n_chunks, hd)
     dest = page_table[:, :n_chunks].reshape(B * n_chunks)
     return ksum.at[:, :, dest].set(sums, mode="drop")
+
+
+def commit_prefill_tails(
+    tails: jax.Array, finals: list, page_table: jax.Array, page_size: int
+) -> jax.Array:
+    """The page-tail pool ``[C layers, N, K - 1, D]`` with the row of every
+    page a dense prefill wrote set to that page's tail: layer ``c``'s
+    convolution inputs ``u`` [B, T, D] (``finals[c][1]``) at the page's last
+    ``K - 1`` slots. A page past a prompt's end, or its last, partial one,
+    gets pad inputs: such a page never enters the radix tree (a row inserts
+    whole pages of its prompt), so its row is never read."""
+    K1 = tails.shape[2]
+    u = jnp.stack([u for _tail, u in finals])  # [C, B, T, D]
+    C, B, T, D = u.shape
+    n_chunks = T // page_size
+    cut = u.reshape(C, B, n_chunks, page_size, D)[:, :, :, page_size - K1 :]
+    dest = page_table[:, :n_chunks].reshape(B * n_chunks)
+    return tails.at[:, dest].set(
+        cut.reshape(C, B * n_chunks, K1, D).astype(tails.dtype), mode="drop"
+    )

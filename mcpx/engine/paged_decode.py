@@ -32,6 +32,9 @@ from mcpx.models.gemma.model import (
     _join,
     attention_inputs,
     attention_residual,
+    conv_attention_inputs,
+    conv_feed_forward,
+    conv_norm,
     embed_tokens,
     feed_forward_residual,
     gated_attention_out,
@@ -44,6 +47,7 @@ from mcpx.models.gemma.model import (
     mixer_norm,
     mixer_stream,
     output_logits,
+    pack_kv,
     pattern_rows,
     rms_norm,
     sparse_index,
@@ -66,6 +70,7 @@ def _ragged_kernel_on_mesh(
     *,
     interpret: bool,
     name: "str | None" = None,  # the call's own name in a device trace (None: the kernel's)
+    scale: "float | None" = None,  # the softmax scale (None: the kernel's hd ** -0.5)
 ) -> jax.Array:
     """The ragged kernel under ``jax.shard_map`` over the engine mesh: XLA
     will not partition a Mosaic call by itself, so each device runs the
@@ -89,7 +94,8 @@ def _ragged_kernel_on_mesh(
         scalars += (jnp.asarray(window, jnp.int32),)
     return jax.shard_map(
         functools.partial(
-            ragged_paged_attention, interpret=interpret, **({"name": name} if name else {})
+            ragged_paged_attention, interpret=interpret, **({"name": name} if name else {}),
+            **({} if scale is None else {"scale": scale}),
         ),
         mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, P(rows, None), P(rows), P(rows))
@@ -535,6 +541,142 @@ def _hybrid_chunk(
     return (output_logits(params, cfg, x), pools) + extra
 
 
+def _packed_attend(
+    qg: jax.Array,  # [B, S, K, G, hd]
+    k_all: jax.Array,  # [K / pack, L, N, psz, pack x hd]
+    v_all: jax.Array,
+    page_table: jax.Array,
+    positions: jax.Array,
+    q_lens: jax.Array,
+    layer: int,
+    cfg: GemmaConfig,
+    *,
+    mesh: Optional[Mesh],
+    use_pallas: bool,
+    interpret: bool,
+) -> jax.Array:
+    """Grouped attention against pools whose rows hold ``GemmaConfig.kv_pack``
+    neighbouring KV heads side by side (heads of 64, two to a 128-lane row):
+    the ragged kernel as it stands, on a head of ``pack x hd`` lanes. A pool
+    row serves the queries of all its KV heads; a query of the row's ``i``-th
+    head is padded with ZEROS over the other heads' lanes, so its scores are
+    its own head's (a product with 0 adds exactly 0), at the head's own scale
+    ``hd ** -0.5``; of the weighted sum over the whole row it keeps its own
+    head's lanes. No byte of the pools is padding, the kernel multiplies whole
+    lane widths, and the arithmetic that is kept is what an unpacked pool
+    would give. -> [B, S, K, G, hd]."""
+    B, S, K, G, hd = qg.shape
+    p = cfg.kv_pack
+    scale = None
+    if p > 1:
+        q6 = qg.reshape(B, S, K // p, p, G, hd)
+        qg = jnp.stack(
+            [jnp.pad(q6[:, :, :, i], ((0, 0),) * 4 + ((i * hd, (p - 1 - i) * hd),)) for i in range(p)],
+            axis=3,
+        ).reshape(B, S, K // p, p * G, p * hd)
+        scale = float(hd) ** -0.5
+    if use_pallas:
+        out = _ragged_kernel_on_mesh(
+            mesh, qg, k_all, v_all, page_table, positions, q_lens, layer, interpret=interpret,
+            scale=scale,
+        )
+    else:
+        out = ragged_paged_attention_reference(
+            qg, k_all, v_all, page_table, positions, q_lens, layer, None, scale
+        )
+    if p > 1:
+        out = out.reshape(B, S, K // p, p, G, p, hd)
+        out = jnp.stack([out[:, :, :, i, :, i] for i in range(p)], axis=3)
+    return out.reshape(B, S, K, G, hd)
+
+
+def _conv_chunk(
+    params, cfg: GemmaConfig, x, positions, page_table, paged_kv, kv_window, q_lens, slots, *,
+    use_pallas, interpret, mesh, logits_at, active_cols, moe_stats, routing, commit,
+) -> tuple:
+    """``decode_chunk_paged`` for a ``C`` / ``A`` pattern: a static walk, each
+    layer its mixer then its feed-forward (dense in the leading layers, the
+    routed experts after them). A ``C`` layer reads and writes its slot's
+    tail and pending window (``ssm.conv_window``); an ``A`` layer writes its
+    rotated, normed keys and its values into the pages, ``kv_pack`` heads a
+    row, and attends through the ragged kernel (``_packed_attend``).
+
+    ``commit``: the window is a PREFILL's (a suffix over matched pages, a
+    chunk of a head's build; ``positions`` a page multiple): every ``C`` layer
+    starts from the tail of the page before the window's first slot
+    (``state["tails"]``; zeros at position 0), all of the window stays, and
+    every page the window FILLS to its last slot gets its tail written, in
+    this program, as its keys are. A decode window writes no page's tail: its
+    pages are the row's own and never enter the radix tree."""
+    from mcpx.models.gemma.ssm import conv_window
+
+    B, S, _ = x.shape
+    state = paged_kv["state"]
+    one_device = mesh is None or mesh.size == 1
+    live = jnp.arange(S)[None, :] < q_lens[:, None]
+    n_slots = state["n"].shape[0]
+    kept = state["n"][jnp.minimum(slots, n_slots - 1)]
+    pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)
+    k_all, v_all, tails = paged_kv["k"], paged_kv["v"], state["tails"]
+    psz, p_max = k_all.shape[3], page_table.shape[1]
+    K1 = tails.shape[2]
+    before = None
+    if commit:
+        before = jnp.take_along_axis(
+            page_table, jnp.clip(positions // psz - 1, 0, p_max - 1)[:, None], axis=1
+        )[:, 0]
+        # The window slot of each touched page's LAST slot; the page is filled
+        # where that slot is live. In ``[tail | u]`` window slot s is row s + K1.
+        last = jnp.arange(kv_window[0].shape[1]) * psz + (psz - 1) - (positions % psz)[:, None]
+        dest = jnp.where(last < q_lens[:, None], kv_window[0], tails.shape[1])  # [B, P]
+        cut = (jnp.minimum(last, S - 1)[:, :, None] + 1 + jnp.arange(K1)).reshape(B, -1, 1)
+    stats = moe_stats_init(cfg) if cfg.n_experts else None
+    layers_new, chosen_all = list(state["layers"]), []
+    for layer, (kind, j) in enumerate(pattern_rows(cfg)):
+        lp = stack_row(params["conv_layers" if kind == "C" else "attn_layers"], j)
+        n = conv_norm(x, lp["norm"], cfg, kind)
+        if kind == "C":
+            # (a layer's rows of the pool read where the layer runs: gathered for every layer at
+            # once, the compiler gives the whole 34 MB pool another layout first, a copy a program)
+            start = None if before is None else jnp.where((positions > 0)[:, None, None], tails[j, before], 0)
+            out, layers_new[j], tail, u = conv_window(n, lp, state["layers"][j], slots, q_lens, kept, start)
+            if commit:
+                rows = jnp.take_along_axis(jnp.concatenate([tail, u], axis=1), cut, axis=1)
+                tails = tails.at[j, dest].set(
+                    rows.reshape(dest.shape + (K1, -1)).astype(tails.dtype), mode="drop"
+                )
+        else:
+            q, k, v = conv_attention_inputs(n, lp, cfg, pos_mat)
+            k_all = _write_kv_window(k_all, j, pack_kv(k, cfg), kv_window)
+            v_all = _write_kv_window(v_all, j, pack_kv(v, cfg), kv_window)
+            qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+            attn = _packed_attend(
+                qg, k_all, v_all, page_table, positions, q_lens, j, cfg,
+                mesh=mesh, use_pallas=use_pallas, interpret=interpret,
+            ).reshape(B, S, cfg.attn_out_width)
+            out = jnp.einsum("btf,fd->btd", attn, lp["wo"], preferred_element_type=jnp.float32)
+        x, layer_stats, chosen = conv_feed_forward(
+            x + out, params, layer, cfg, live,
+            use_pallas=use_pallas and one_device, interpret=interpret,
+        )
+        if layer_stats is not None:
+            stats = add_layer_stats(stats, layer_stats)
+            chosen_all.append(chosen)
+    if stats is not None:
+        stats = add_forward_stats(cfg, stats, positions + q_lens, q_lens, S, blocks=(psz, p_max, commit))
+    x = conv_norm(x, params["final_norm"], cfg)
+    # A decode window stays pending until the caller says what of it was
+    # kept; a prefill's is in the tail already. Either way nothing is kept yet.
+    n_new = state["n"].at[jnp.where(q_lens > 0, slots, n_slots)].set(0, mode="drop")
+    pools = {"k": k_all, "v": v_all, "state": {"layers": tuple(layers_new), "n": n_new, "tails": tails}}
+    extra = ((stats,) if moe_stats else ()) + ((jnp.stack(chosen_all),) if routing and chosen_all else ())
+    if active_cols is not None:
+        return (output_logits(params, cfg, x, subset=active_cols), pools) + extra
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    return (output_logits(params, cfg, x), pools) + extra
+
+
 def keep_window(state: dict, slots: jax.Array, kept: jax.Array, live: jax.Array) -> dict:
     """The state pool with ``kept`` [B] written as what the live rows keep
     of the window their last forward left pending (``1 + accepted`` tokens
@@ -562,7 +704,7 @@ def decode_chunk_paged(
     moe_stats: bool = False,  # sparse models: also the forward's expert counters
     routing: bool = False,  # sparse models: also the experts chosen [Ls, B, S, k]
     selection: bool = False,  # a learned index: also the keys each query read [L, B, S, keys / 8]; block selection: the blocks [S layers, B, S, K, blocks / 8]
-    state_slots: "tuple | None" = None,  # recurrent layers: each row's (slot read, slot written); None: row i's is i
+    state_slots: "tuple | None" = None,  # recurrent layers: each row's (slot read, slot written); None: row i's is i (a short convolution reads pages' tails: the first is unused)
     commit: bool = False,  # recurrent layers: the window is a prefill's, every live slot of it stays
 ) -> tuple:
     """Multi-token decode step: S new tokens per sequence in ONE forward.
@@ -614,7 +756,9 @@ def decode_chunk_paged(
     # Quantized leaves stay the HBM-resident buffers — embed rows gather
     # as int8 + per-row scales, layers dequantize per layer INSIDE the
     # scan body (see dequant_layer), unembeds scale on the output.
-    x = mixer_stream(params, cfg, tokens) if cfg.mixer_ffn else embed_tokens(params, cfg, tokens)  # [B, S, D]
+    # (a mixer + feed-forward pattern carries its residual stream in float32)
+    float_stream = cfg.mixer_ffn or cfg.conv_ffn
+    x = mixer_stream(params, cfg, tokens) if float_stream else embed_tokens(params, cfg, tokens)  # [B, S, D]
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
     kv_window = _kv_window(positions, page_table, S, psz, N)
@@ -625,6 +769,13 @@ def decode_chunk_paged(
             state_slots or (own, own),
             use_pallas=use_pallas, interpret=interpret, mesh=mesh, logits_at=logits_at,
             active_cols=active_cols, moe_stats=moe_stats, commit=commit, selection=selection,
+        )
+    if cfg.conv_ffn:
+        return _conv_chunk(
+            params, cfg, x, positions, page_table, paged_kv, kv_window, q_lens,
+            jnp.arange(B, dtype=jnp.int32) if state_slots is None else state_slots[1],
+            use_pallas=use_pallas, interpret=interpret, mesh=mesh, logits_at=logits_at,
+            active_cols=active_cols, moe_stats=moe_stats, routing=routing, commit=commit,
         )
     if cfg.hybrid:
         return _hybrid_chunk(

@@ -103,6 +103,9 @@ class GemmaConfig:
     # scores, as without groups.
     router_groups: int = 0
     router_groups_kept: int = 0
+    # What the sigmoid router adds to the chosen scores' sum before it
+    # divides by it.
+    router_norm_eps: float = 1e-20
     # --- the attention kind: ``heads`` (MHA / GQA / MQA: ``n_kv_heads`` heads
     # of K and of V a token in the cache) or ``latent``: the query through a
     # rank-``q_lora_rank`` bottleneck with its own norm, keys and values through
@@ -181,6 +184,17 @@ class GemmaConfig:
     embed_scale: float = 0.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
+    # --- a layer that is a MIXER followed by a feed-forward that is DENSE in
+    # the ``n_dense_layers`` leading layers and the ROUTED one after them
+    # (``layer_pattern`` of ``C`` and ``A`` alone; two norms a layer, plain
+    # gains): ``C`` a gated SHORT CONVOLUTION, ``[b | c | x] = n W_in``, ``u =
+    # b (.) x``, ``v`` the causal depthwise convolution of ``u`` over
+    # ``conv_kernel`` taps (no bias, no activation), ``y = W_out (c (.) v)``:
+    # its whole state is the last ``conv_kernel - 1`` values of ``u``, which
+    # the state pool keeps a slot AND a page (``engine/kv_cache.
+    # init_state_pool``); ``A`` rotated GQA, q and k normed per head first
+    # (``models/gemma/ssm.py``, ``model.py``). A ``head_dim`` of 64 lies two
+    # KV heads to a 128-lane row of the pools (``kv_pack``).
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -228,6 +242,17 @@ class GemmaConfig:
                 raise ConfigError(
                     "an L/S layer_pattern is heads attention with plain norm gains, a dense "
                     "feed-forward and no Mamba widths"
+                )
+        elif self.conv_ffn:
+            if len(self.layer_pattern) != self.n_layers or self.conv_kernel < 2:
+                raise ConfigError("layer_pattern: C and A alone, one a layer, and conv_kernel >= 2")
+            if self.latent or self.layer_types or self.post_norms or self.attn_gate \
+                    or self.norm_plus_one or self.mamba_n_heads or self.d_shared_expert \
+                    or self.moe_latent_size or not self.rope_full_layers or self.yarn_factor \
+                    or self.head_dim % 2 or self.activation == "relu2":
+                raise ConfigError(
+                    "a C/A layer_pattern is heads attention, rotated, with plain norm gains, no "
+                    "gate, window, second norm, shared expert, latent or Mamba widths"
                 )
         elif self.hybrid:
             if set(self.layer_pattern) - set("ME*") or len(self.layer_pattern) != self.n_layers:
@@ -283,7 +308,7 @@ class GemmaConfig:
                     f"experts {self.expert_first}..{self.expert_first + self.n_experts_held} "
                     f"are not among the router's {self.n_experts}"
                 )
-            if self.hybrid and "E" not in self.layer_pattern:
+            if self.hybrid and not self.conv_ffn and "E" not in self.layer_pattern:
                 raise ConfigError("n_experts needs an E layer in layer_pattern")
             if not 0 <= self.n_dense_layers < self.n_layers:
                 raise ConfigError("n_dense_layers leaves no sparse layer (or is negative)")
@@ -328,6 +353,35 @@ class GemmaConfig:
         return bool(self.layer_pattern) and not set(self.layer_pattern) - set("LS")
 
     @property
+    def conv_ffn(self) -> bool:
+        """A ``layer_pattern`` of ``C`` / ``A``: every layer a mixer (a gated
+        short convolution, or rotated attention) followed by a feed-forward,
+        dense in the leading layers and routed after them."""
+        return bool(self.layer_pattern) and not set(self.layer_pattern) - set("CA")
+
+    @property
+    def n_conv_layers(self) -> int:
+        return self.layer_pattern.count("C") if self.conv_ffn else 0
+
+    @property
+    def page_state(self) -> bool:
+        """Whether every recurrent layer's WHOLE state is small enough to be
+        kept at every page boundary, a row a page beside the page's keys
+        (``init_state_pool``'s ``tails``): a short convolution's last
+        ``conv_kernel - 1`` inputs. A row of such a model matches the radix
+        tree at ANY depth and starts from the tail of its last matched page."""
+        return self.n_conv_layers > 0
+
+    @property
+    def suffix_route(self) -> bool:
+        """Whether a row may start from pages the radix tree holds: a model
+        with no recurrent layer, one whose state is page-addressable
+        (``page_state``), or one that keeps a declared head's end state
+        (``head_state``, at that head's length alone). Else its rows prefill
+        whole."""
+        return not self.hybrid or self.head_state or self.page_state
+
+    @property
     def n_mamba_layers(self) -> int:
         return self.layer_pattern.count("M")
 
@@ -338,7 +392,7 @@ class GemmaConfig:
     @property
     def n_recurrent_layers(self) -> int:
         """Layers that keep a state a row in the state pool."""
-        return self.n_mamba_layers + self.n_linear_layers
+        return self.n_mamba_layers + self.n_linear_layers + self.n_conv_layers
 
     @property
     def n_block_layers(self) -> int:
@@ -350,9 +404,10 @@ class GemmaConfig:
     def head_state(self) -> bool:
         """Whether the engine keeps the END STATE of a declared shared head
         in a slot of its own and hands a copy to every row that matches it:
-        where every recurrent layer is linear attention (a Mamba layer's
-        convolution tail has no suffix route: such a model's rows prefill
-        whole)."""
+        where every recurrent layer is linear attention. (A Mamba layer's
+        state and tail are kept a slot alone and have no suffix route: such a
+        model's rows prefill whole. A short convolution's tail is kept a
+        PAGE, ``page_state``, and needs no head slot.)"""
         return self.n_linear_layers > 0
 
     @property
@@ -372,6 +427,8 @@ class GemmaConfig:
         """Layers that cache keys and values: the page pools' layer axis."""
         if self.mixer_ffn:
             return self.layer_pattern.count("S")
+        if self.conv_ffn:
+            return self.layer_pattern.count("A")
         return self.layer_pattern.count("*") if self.hybrid else self.n_layers
 
     @property
@@ -388,7 +445,30 @@ class GemmaConfig:
         """Bytes of ONE recurrent layer's state of one row (float32)."""
         if self.mixer_ffn:
             return self.n_heads * self.head_dim * self.head_dim * 4
+        if self.conv_ffn:
+            return 0  # no recurrent state array: ``conv_tail_bytes``
         return self.mamba_inner * self.ssm_state_size * 4
+
+    @property
+    def conv_tail_bytes(self) -> int:
+        """Bytes of ONE short-convolution layer's tail, of a row or of a page
+        (float32: the mixer's own precision, ``models/gemma/ssm.py``)."""
+        return (self.conv_kernel - 1) * self.d_model * 4
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads that share a 128-lane row of the page pools: 2 for a
+        ``C`` / ``A`` pattern's heads of 64 (an even number of them), else 1.
+        A packed pool is ``[n_kv_heads / kv_pack, layers, pages, page_size,
+        kv_pack x head_dim]``: the ragged kernel then multiplies whole lane
+        widths, each query head padded with zeros over its row-mate's lanes
+        (``engine/paged_decode._packed_attend``), and no byte of the pool is
+        padding."""
+        if self.conv_ffn and self.head_dim < 128 and 128 % self.head_dim == 0:
+            pack = 128 // self.head_dim
+            if self.n_kv_heads % pack == 0:
+                return pack
+        return 1
 
     @property
     def latent(self) -> bool:
@@ -405,6 +485,11 @@ class GemmaConfig:
         return self.n_heads * (self.v_head_dim if self.latent else self.head_dim)
 
     @property
+    def kv_pool_heads(self) -> int:
+        """The page pools' leading axis: ``n_kv_heads``, ``kv_pack`` to a row."""
+        return self.n_kv_heads // self.kv_pack
+
+    @property
     def kv_widths(self) -> tuple[int, int]:
         """The last axis of the two cache pools, ``k`` and ``v``. Heads: a
         head's key and value. Latent: ``k`` holds the shared rotated key in a
@@ -412,7 +497,7 @@ class GemmaConfig:
         width: the padding is the pool's, never a useful byte), ``v`` the
         normed latent, which the scores read too."""
         if not self.latent:
-            return self.head_dim, self.head_dim
+            return (self.head_dim * self.kv_pack,) * 2
         return self.index_key_offset + self.index_head_dim, self.kv_lora_rank
 
     @property
@@ -463,7 +548,7 @@ class GemmaConfig:
 
     @property
     def n_sparse_layers(self) -> int:
-        if self.hybrid:
+        if self.hybrid and not self.conv_ffn:
             return self.layer_pattern.count("E")
         return self.n_layers - self.n_dense_layers if self.n_experts else 0
 
@@ -551,6 +636,17 @@ class GemmaConfig:
             linear = 5 * D * H * hd + 2 * hd + H * hd  # q, k, v, gate, o; q/k norms; the output norm
             block = 3 * D * H * hd + 2 * D * K * hd  # q, gate, o; k, v
             layers = self.n_linear_layers * (linear + ffn) + self.n_block_layers * (block + ffn)
+            head = 0 if self.tie_embeddings else D * self.vocab_size
+            return self.vocab_size * D + layers + D + head
+        if self.conv_ffn:
+            conv = 3 * D * D + D * D + D * self.conv_kernel  # w_in, w_out, the taps
+            attn = 2 * D * H * hd + 2 * D * K * hd + 2 * hd  # q, o; k, v; the q/k gains
+            sparse_ff = (D + bool(self.router_bias_scale)) * self.n_experts + experts * 3 * D * self.d_expert
+            n_sparse = self.n_sparse_layers
+            layers = (
+                self.n_conv_layers * conv + self.n_attn_layers * attn + 2 * D * self.n_layers
+                + (self.n_layers - n_sparse) * 3 * D * F + n_sparse * sparse_ff
+            )
             head = 0 if self.tie_embeddings else D * self.vocab_size
             return self.vocab_size * D + layers + D + head
         if self.hybrid:

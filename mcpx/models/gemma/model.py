@@ -94,6 +94,7 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         specs = param_pspecs(cfg, mesh)
         stacks = (
             "layers", "dense_layers", "mamba_layers", "attn_layers", "linear_layers", "block_layers",
+            "conv_layers",
         )
         by_name = {
             **specs.get("layers", {}),
@@ -190,6 +191,8 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
 
     if cfg.mixer_ffn:
         return _init_mixer_ffn(cfg, key, normal, sharding, t)
+    if cfg.conv_ffn:
+        return _init_conv_ffn(cfg, key, normal, sharding, t)
     if cfg.hybrid:
         return _init_hybrid(cfg, key, normal, sharding, t)
     Ls = cfg.n_sparse_layers
@@ -376,12 +379,87 @@ def _init_mixer_ffn(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Pa
     return params
 
 
+def _init_conv_ffn(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Params:
+    """``init_params`` of a ``C`` / ``A`` pattern: a mixer's stack a kind, a row
+    a layer of its kind in layer order, ``conv_layers`` (``w_in`` [D, 3 D] the
+    thirds b | c | x, the taps ``conv_w`` [D, K] uniform in +-1 / sqrt(K),
+    ``w_out``) and ``attn_layers`` (heads merged on the matmul's own axis, one
+    gain of ``head_dim`` each for q and k), each with the layer's first norm
+    ``norm``; and a feed-forward's stack a kind, a row a layer of ITS kind in
+    layer order, ``dense_layers`` (the ``n_dense_layers`` leading ones) and
+    ``layers`` (the routed ones: router, its float32 bias, the experts held),
+    each with the layer's second norm ``pre_mlp_norm``. Gains 1."""
+    dtype = jnp.dtype(cfg.dtype)
+    D, H, K, hd, F, V = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.vocab_size
+    Lc, La, Ls = cfg.n_conv_layers, cfg.n_attn_layers, cfg.n_sparse_layers
+    Ld = cfg.n_layers - Ls
+    Kc = cfg.conv_kernel
+    fold = lambda i: jax.random.fold_in(key, 300 + i)
+
+    def ones(name, shape, stack):
+        return t(name, jnp.ones(shape, dtype, device=sharding(stack + name)))
+
+    params = {
+        "embed": normal("embed", fold(0), (V, D), D),
+        "final_norm": t("final_norm", jnp.ones((D,), dtype, device=sharding("final_norm"))),
+    }
+    if not cfg.tie_embeddings:
+        params["head"] = normal("head", fold(1), (D, V), D)
+    if Lc:
+        st = "conv_layers."
+        taps = jax.jit(
+            lambda k, shape: jax.random.uniform(k, shape, jnp.float32, -1.0, 1.0).astype(dtype) * Kc**-0.5,
+            static_argnames=("shape",), out_shardings=sharding(st + "conv_w"),
+        )
+        params["conv_layers"] = {
+            "norm": ones("norm", (Lc, D), st),
+            "w_in": normal("w_in", fold(2), (Lc, D, 3 * D), D, stack=st),
+            "conv_w": t("conv_w", taps(fold(3), shape=(Lc, D, Kc))),
+            "w_out": normal("w_out", fold(4), (Lc, D, D), D, stack=st),
+        }
+    if La:
+        st = "attn_layers."
+        params["attn_layers"] = {
+            "norm": ones("norm", (La, D), st),
+            "wq": normal("wq", fold(5), (La, D, H * hd), D, stack=st),
+            "wk": normal("wk", fold(6), (La, D, K * hd), D, stack=st),
+            "wv": normal("wv", fold(7), (La, D, K * hd), D, stack=st),
+            "wo": normal("wo", fold(8), (La, H * hd, D), H * hd, stack=st),
+            "q_norm": ones("q_norm", (La, hd), st),
+            "k_norm": ones("k_norm", (La, hd), st),
+        }
+    if Ld:
+        st = "dense_layers."
+        params["dense_layers"] = {
+            "pre_mlp_norm": ones("pre_mlp_norm", (Ld, D), st),
+            "w_gate": normal("w_gate", fold(9), (Ld, D, F), D, stack=st),
+            "w_up": normal("w_up", fold(10), (Ld, D, F), D, stack=st),
+            "w_down": normal("w_down", fold(11), (Ld, F, D), F, stack=st),
+        }
+    if Ls:
+        E, Fe = cfg.n_experts_held, cfg.d_expert
+        layers = {
+            "pre_mlp_norm": ones("pre_mlp_norm", (Ls, D), ""),
+            "router": normal("router", fold(12), (Ls, D, cfg.n_experts), D),
+            "w_gate": normal("w_gate", fold(13), (Ls, E, D, Fe), D, by_layer=True),
+            "w_up": normal("w_up", fold(14), (Ls, E, D, Fe), D, by_layer=True),
+            "w_down": normal("w_down", fold(15), (Ls, E, Fe, D), Fe, by_layer=True),
+        }
+        if cfg.router_bias_scale:
+            layers["router_bias"] = normal(
+                "router_bias", fold(16), (Ls, cfg.n_experts), cfg.router_bias_scale**-2,
+                as_type=jnp.float32,
+            )
+        params["layers"] = layers
+    return params
+
+
 def init_kv_cache(cfg: GemmaConfig, batch: int, max_len: int, dtype: str | None = None) -> KVCache:
     """The dense cache ``[L, B, S, K, width]``: a head's key and value, or
     under latent attention the shared rotated key (``k``) and the latent
     (``v``), ``GemmaConfig.kv_widths``."""
     d = jnp.dtype(dtype or cfg.dtype)
-    shape = (cfg.n_attn_layers, batch, max_len, cfg.n_kv_heads)
+    shape = (cfg.n_attn_layers, batch, max_len, cfg.kv_pool_heads)
     k_width, v_width = cfg.kv_widths
     return {"k": jnp.zeros(shape + (k_width,), d), "v": jnp.zeros(shape + (v_width,), d)}
 
@@ -772,7 +850,7 @@ def _layer(
 # ------------------------------------------- a layer that is one thing alone
 def pattern_rows(cfg: GemmaConfig) -> list[tuple[str, int]]:
     """``layer_pattern`` as (kind, the layer's row in its kind's stack)."""
-    seen = dict.fromkeys("ME*LS", 0)
+    seen = dict.fromkeys("ME*LSCA", 0)
     rows = []
     for kind in cfg.layer_pattern:
         rows.append((kind, seen[kind]))
@@ -860,15 +938,19 @@ def gated_attention_out(attn: jax.Array, n: jax.Array, lp: dict, cfg: GemmaConfi
 
 def mixer_feed_forward(x: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
     """The second half of an ``L`` / ``S`` layer: x + scale x MLP(norm(x))."""
-    n = mixer_norm(x, lp["mlp_norm"], cfg)
+    return join_scaled(x, gated_mlp_float32(mixer_norm(x, lp["mlp_norm"], cfg), lp, cfg), cfg)
+
+
+def gated_mlp_float32(n: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
+    """The dense gated feed-forward of a float32 residual stream, on its normed
+    input ``n`` -> [B, T, D] float32: gate and up as accumulated, their product
+    rounded ONCE where W_down reads it."""
     f32 = jnp.float32
-    # gate and up as accumulated, their product rounded ONCE where W_down reads it
     gate = jnp.einsum("btd,df->btf", n, lp["w_gate"], preferred_element_type=f32)
     up = jnp.einsum("btd,df->btf", n, lp["w_up"], preferred_element_type=f32)
-    ff = jnp.einsum(
+    return jnp.einsum(
         "btf,fd->btd", (activation(cfg, gate) * up).astype(n.dtype), lp["w_down"], preferred_element_type=f32
     )
-    return join_scaled(x, ff, cfg)
 
 
 def _block_attention_dense(q, k_c, v_c, mask, positions, cfg: GemmaConfig) -> jax.Array:
@@ -979,6 +1061,112 @@ def _hybrid_forward(
     return out + ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
 
 
+# ------------------------ a short convolution or attention, then a feed-forward
+def conv_attention_inputs(n: jax.Array, lp: dict, cfg: GemmaConfig, positions: jax.Array) -> tuple:
+    """An ``A`` layer: n [B, T, D], positions [B, T] -> q [B, T, H, hd], k and
+    v [B, T, K, hd] as the pages hold them: q and k normed per head (one gain
+    of ``head_dim`` each), THEN rotated; norm and rope one float32 chain on
+    the products as accumulated, rounded once."""
+    f32 = jnp.float32
+    heads = lambda w, n_heads: jnp.einsum("btd,de->bte", n, w, preferred_element_type=f32).reshape(
+        n.shape[:2] + (n_heads, cfg.head_dim)
+    )
+    q = rms_norm(heads(lp["wq"], cfg.n_heads), lp["q_norm"], cfg.norm_eps, False, f32)
+    k = rms_norm(heads(lp["wk"], cfg.n_kv_heads), lp["k_norm"], cfg.norm_eps, False, f32)
+    q = apply_rope(q, positions, cfg.rope_theta).astype(n.dtype)
+    k = apply_rope(k, positions, cfg.rope_theta).astype(n.dtype)
+    return q, k, heads(lp["wv"], cfg.n_kv_heads).astype(n.dtype)
+
+
+def pack_kv(a: jax.Array, cfg: GemmaConfig) -> jax.Array:
+    """k or v [B, T, K, hd] as a cache row holds it, ``kv_pack`` neighbouring
+    KV heads to a row: [B, T, K / pack, pack x hd] (a reshape)."""
+    return a.reshape(a.shape[:2] + (cfg.kv_pool_heads, -1))
+
+
+def conv_norm(x: jax.Array, gain: jax.Array, cfg: GemmaConfig, kind: str = "") -> jax.Array:
+    """RMSNorm (a plain gain) of a ``C`` / ``A`` pattern's float32 residual
+    stream, rounded once to the activations' type: what a layer's matmuls
+    read. For a ``C`` layer (``kind``) float32 as it is: the short convolution
+    reads it unrounded (``models/gemma/ssm.py`` says why). The stream itself
+    stays float32 from the embedding to the last norm (``mixer_stream``; every
+    branch joins it as accumulated): rounded to bfloat16 at each of a
+    ten-layer stack's twenty joins, the step's distance from the reference is
+    0.035 on the CPU at 256 wide against the 0.02 of ``reference.tol``
+    (PERF.md, PR 56)."""
+    return rms_norm(x, gain, cfg.norm_eps, False, jnp.float32 if kind == "C" else jnp.dtype(cfg.dtype))
+
+
+def conv_feed_forward(
+    x: jax.Array, params: Params, layer: int, cfg: GemmaConfig, live, *,
+    use_pallas: bool = False, interpret: bool = False, prefill: bool = False,
+) -> tuple:
+    """The second half of layer ``layer`` of a ``C`` / ``A`` pattern, on the
+    float32 stream: the dense feed-forward in the leading layers, the routed
+    experts after them (``moe_forward``, the default block's). -> (x, the
+    layer's expert counters, the experts chosen), the last two None for a
+    dense layer."""
+    Ld = cfg.n_layers - cfg.n_sparse_layers
+    if layer < Ld:
+        lp = stack_row(params["dense_layers"], layer)
+        return x + gated_mlp_float32(conv_norm(x, lp["pre_mlp_norm"], cfg), lp, cfg), None, None
+    scanned, experts = split_layers(cfg, params["layers"])
+    j = layer - Ld
+    lp = stack_row(scanned, j)
+    ff, stats, chosen = moe_forward(
+        conv_norm(x, lp["pre_mlp_norm"], cfg), lp["router"], experts, j, cfg, live,
+        lp.get("router_bias"), use_pallas=use_pallas, interpret=interpret, prefill=prefill,
+    )
+    return x + ff.astype(jnp.float32), stats, chosen
+
+
+def _conv_forward(
+    params: Params, cfg: GemmaConfig, tokens, seq_lens, kv_cache, mask, logits_at, live,
+    routing: bool, moe_stats: bool, use_pallas: bool = False, interpret: bool = False,
+) -> tuple:
+    """``forward`` for a ``C`` / ``A`` pattern from an EMPTY state (the dense
+    prefill): the cache it returns holds ``k`` and ``v`` [A layers, ...]
+    (``kv_pack`` heads a row) and ``ssm``: ``(the tail AT each row's length,
+    every position's u)`` a ``C`` layer."""
+    from mcpx.models.gemma.ssm import conv_prefill
+
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    x = mixer_stream(params, cfg, tokens)
+    stats = moe_stats_init(cfg) if cfg.n_experts else None
+    ks, vs, finals, chosen_all = [], [], [], []
+    for layer, (kind, j) in enumerate(pattern_rows(cfg)):
+        lp = stack_row(params["conv_layers" if kind == "C" else "attn_layers"], j)
+        n = conv_norm(x, lp["norm"], cfg, kind)
+        if kind == "C":
+            out, final = conv_prefill(n, lp, cfg, seq_lens)
+            finals.append(final)
+        else:
+            q, k, v = conv_attention_inputs(n, lp, cfg, positions)
+            S = kv_cache["k"].shape[2]
+            pad = lambda a: jnp.pad(a, ((0, 0), (0, S - T), (0, 0), (0, 0)))
+            qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+            attn = _attend(qg, pad(k), pad(v), mask).reshape(B, T, cfg.attn_out_width)
+            out = jnp.einsum("btf,fd->btd", attn, lp["wo"], preferred_element_type=jnp.float32)
+            ks.append(kv_cache["k"][j].at[:, :T].set(pack_kv(k, cfg).astype(kv_cache["k"].dtype)))
+            vs.append(kv_cache["v"][j].at[:, :T].set(pack_kv(v, cfg).astype(kv_cache["v"].dtype)))
+        x, layer_stats, chosen = conv_feed_forward(
+            x + out, params, layer, cfg, live,
+            use_pallas=use_pallas, interpret=interpret, prefill=True,
+        )
+        if layer_stats is not None:
+            stats = add_layer_stats(stats, layer_stats)
+            chosen_all.append(chosen)
+    x = conv_norm(x, params["final_norm"], cfg)
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    out = output_logits(params, cfg, x), {"k": jnp.stack(ks), "v": jnp.stack(vs), "ssm": finals}
+    if stats is not None:
+        stats = add_forward_stats(cfg, stats, seq_lens, seq_lens)
+    chosen = jnp.stack(chosen_all) if chosen_all else None
+    return out + ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
+
+
 def embed_tokens(params: Params, cfg: GemmaConfig, tokens: jax.Array) -> jax.Array:
     from mcpx.models.gemma.quant import embed_lookup
 
@@ -1043,7 +1231,7 @@ def forward(
             if moe_stats:
                 stats = add_forward_stats(cfg, moe_stats_init(cfg), seq_lens, seq_lens)
             return out + ((stats,) if moe_stats else ()) + ((None,) if routing else ())
-        return _hybrid_forward(
+        return (_conv_forward if cfg.conv_ffn else _hybrid_forward)(
             params, cfg, tokens, seq_lens, kv_cache, mask, logits_at, live, routing, moe_stats,
             use_pallas, interpret,
         )

@@ -151,7 +151,7 @@ def route(
         choice = jnp.where(in_kept[:, :, None], grouped, -jnp.inf).reshape(T, -1)
     _, chosen = lax.top_k(choice, cfg.n_experts_per_tok)
     w = jnp.take_along_axis(scores, chosen, axis=-1)  # the bias chose; it weighs nothing
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.router_norm_eps)
     return chosen.astype(jnp.int32), w * cfg.router_scale
 
 

@@ -415,3 +415,127 @@ def linear_window(
             "v": state["v"].at[at].set(pad(v).astype(state["v"].dtype), mode="drop"),
         }
     return linear_out(y, n, lp, cfg), ssm, new
+
+
+# ------------------------------------------------- gated short convolution
+# A ``C`` layer of ``GemmaConfig.conv_ffn``, on its normed input n [B, T, D]
+# (K = ``conv_kernel`` taps):
+#
+#   [b | c | x] = n W_in                     W_in [D, 3 D], thirds in that order
+#   u_t = b_t (.) x_t
+#   v_t = sum_k w[:, k] (.) u_{t - K + 1 + k}      causal, depthwise, no bias,
+#                                                   NO activation; u before
+#                                                   the sequence is 0
+#   y_t = W_out (c_t (.) v_t)
+#
+# The layer's WHOLE state after token t is its TAIL, the last K - 1 values of
+# ``u``.
+#
+# **The mixer runs in float32 between its two weight matrices.** It is a CUBIC
+# form of its input (``b (.) x (.) c``), so a relative error in ``n`` comes out
+# three times as large, and in a stack where 8 layers of 10 are this mixer the
+# roundings of the usual bfloat16 recipe (``n``, ``u``, ``c (.) v``, each
+# rounded where a matmul or the state takes it) carried the step to 0.021-0.023
+# of the reference on the chip against the comparison's 0.02 (PERF.md, PR 56: on
+# the CPU at 256 wide, those three roundings alone read 0.022-0.026 with every
+# other rounding of the step switched off, the rest of the step 0.013-0.016
+# without them). So: the layer's norm hands on float32, ``W_in`` and ``W_out``
+# read their float32 operand as TWO operands of the weights' type (its rounding
+# and what the rounding left, ``_dot_split``: one pass over the weights, twice
+# the rows), ``u`` and the taps stay float32, and the tail (a slot's and a
+# page's: ``engine/kv_cache.init_state_pool``) holds ``u`` in float32, as the
+# other recurrent states of this repo are held. A value weighs the same
+# whether the convolution reads it out of its window or out of a tail.
+def _dot_split(x32: jax.Array, w: jax.Array) -> jax.Array:
+    """x32 [B, T, K] float32 times w [K, N] -> [B, T, N] float32 as
+    accumulated. Weights narrower than float32 read ``x32`` as two operands of
+    their own type, ``hi = round(x32)`` and ``lo = round(x32 - hi)``, stacked
+    along the rows of ONE product: the weights stream once, and what is lost
+    of ``x32`` is under 2^-16 of it."""
+    f32 = jnp.float32
+    if w.dtype == f32:
+        return jnp.einsum("bte,ed->btd", x32, w, preferred_element_type=f32)
+    # (an explicit rounding: the TPU compiler drops a cast to bfloat16 and back,
+    # ``xla_allow_excess_precision``, and ``lo`` would be all zeros)
+    info = jnp.finfo(w.dtype)
+    hi32 = lax.reduce_precision(x32, exponent_bits=info.nexp, mantissa_bits=info.nmant)
+    hi, lo = hi32.astype(w.dtype), (x32 - hi32).astype(w.dtype)
+    T = x32.shape[1]
+    y = jnp.einsum("bte,ed->btd", jnp.concatenate([hi, lo], axis=1), w, preferred_element_type=f32)
+    return y[:, :T] + y[:, T:]
+
+
+def conv_inputs(n: jax.Array, lp: dict) -> tuple[jax.Array, jax.Array]:
+    """n [B, T, D] float32 -> (u [B, T, D], c [B, T, D]), float32."""
+    D = n.shape[-1]
+    bcx = _dot_split(n, lp["w_in"])
+    return bcx[..., :D] * bcx[..., 2 * D :], bcx[..., D : 2 * D]
+
+
+def short_conv(u: jax.Array, tail: jax.Array, w: jax.Array) -> jax.Array:
+    """The taps ``w`` [D, K] over the inputs ``u`` [B, T, D] that follow
+    ``tail`` [B, K - 1, D] -> [B, T, D] float32: output t is taps over inputs
+    t - K + 1 .. t."""
+    K, T = tail.shape[1] + 1, u.shape[1]
+    full = jnp.concatenate([tail, u], axis=1).astype(jnp.float32)
+    w = w.astype(jnp.float32)
+    out = full[:, 0:T] * w[:, 0]
+    for k in range(1, K):
+        out = out + full[:, k : k + T] * w[:, k]
+    return out
+
+
+def conv_out(v: jax.Array, c: jax.Array, lp: dict) -> jax.Array:
+    """-> the mixer's output [B, T, D] as accumulated (float32)."""
+    return _dot_split(c * v, lp["w_out"])
+
+
+def conv_prefill(n: jax.Array, lp: dict, cfg: GemmaConfig, seq_lens: jax.Array) -> tuple:
+    """The mixer over a padded prompt from an empty tail: n [B, T, D] -> (its
+    output [B, T, D] float32, (the tail AT ``seq_lens`` [B, K - 1, D], every
+    position's ``u`` [B, T, D]: what the pages' tails are cut from))."""
+    u, c = conv_inputs(n, lp)
+    tail0 = jnp.zeros((n.shape[0], cfg.conv_kernel - 1, n.shape[-1]), u.dtype)
+    out = conv_out(short_conv(u, tail0, lp["conv_w"]), c, lp)
+    return out, (tail_at(u, tail0, seq_lens), u)
+
+
+def conv_window(
+    n: jax.Array,  # [B, S, D] the window's normed input, float32
+    lp: dict,
+    state: dict,  # this layer's arrays of the pool, [slots, ...]: conv, pre
+    slots: jax.Array,  # [B] each row's slot (out of range: a padding row, written nowhere)
+    q_lens: jax.Array,  # [B] live window slots (0: an idle row, which changes nothing)
+    kept: jax.Array,  # [B] tokens of the PENDING window the row kept (state["n"][slots])
+    start: "jax.Array | None" = None,  # [B, K - 1, D]: the tail a PREFILL window starts from
+) -> tuple[jax.Array, dict, jax.Array, jax.Array]:
+    """One paged forward's window of a ``C`` layer -> (its output [B, S, D]
+    float32, the layer's new arrays, the tail the window started from, the
+    window's ``u``). A decode window (``start`` None) first moves the slot's
+    tail over what the row kept of its pending window (``tail_at``), then
+    leaves its own ``u`` pending. A prefill window (a suffix over matched
+    pages, a chunk of a head's build) starts from ``start``, a page's tail or
+    zeros, and commits: the slot holds the tail AT the row's last live slot,
+    nothing pending."""
+    S = n.shape[1]
+    W = state["pre"].shape[1]
+    if S > W and start is None:
+        raise ValueError(f"a window of {S} slots, the state pool keeps {W} pending")
+    n_slots = state["conv"].shape[0]
+    at = jnp.where((q_lens > 0) & (slots < n_slots), slots, n_slots)
+    own = jnp.minimum(slots, n_slots - 1)
+    u, c = conv_inputs(n, lp)
+    if start is None:
+        tail = tail_at(state["pre"][own], state["conv"][own], kept)
+        new = {
+            "conv": state["conv"].at[at].set(tail, mode="drop"),
+            "pre": state["pre"].at[at].set(
+                jnp.pad(u, ((0, 0), (0, W - S), (0, 0))).astype(state["pre"].dtype), mode="drop"
+            ),
+        }
+    else:
+        tail = start.astype(u.dtype)
+        new = {**state, "conv": state["conv"].at[at].set(
+            tail_at(u, tail, q_lens).astype(state["conv"].dtype), mode="drop"
+        )}
+    return conv_out(short_conv(u, tail, lp["conv_w"]), c, lp), new, tail, u

@@ -175,6 +175,27 @@ def _hybrid_pspecs(cfg: GemmaConfig, m, whole) -> dict[str, Any]:
     mixers, and which experts a device holds is the configuration's). An
     ``L`` / ``S`` pattern's two stacks are whole on every device: its one cell
     runs on one chip."""
+    if cfg.conv_ffn:
+        # Whole on every device: its one cell runs on one chip (the attention's
+        # heads lie ``kv_pack`` to a pool row, which a split by head would cut).
+        specs = {"embed": P(m(cfg.vocab_size), None), "final_norm": P(None)}
+        if cfg.n_conv_layers:
+            specs["conv_layers"] = {"norm": whole(2), "w_in": whole(3), "conv_w": whole(3), "w_out": whole(3)}
+        if cfg.n_attn_layers:
+            specs["attn_layers"] = {
+                "norm": whole(2), "wq": whole(3), "wk": whole(3), "wv": whole(3), "wo": whole(3),
+                "q_norm": whole(2), "k_norm": whole(2),
+            }
+        ffn = {"pre_mlp_norm": whole(2)}
+        if cfg.n_layers > cfg.n_sparse_layers:
+            specs["dense_layers"] = {**ffn, "w_gate": whole(3), "w_up": whole(3), "w_down": whole(3)}
+        if cfg.n_sparse_layers:
+            specs["layers"] = {**ffn, "router": whole(3), "w_gate": whole(4), "w_up": whole(4), "w_down": whole(4)}
+            if cfg.router_bias_scale:
+                specs["layers"]["router_bias"] = whole(2)
+        if not cfg.tie_embeddings:
+            specs["head"] = P(None, m(cfg.vocab_size))
+        return specs
     if cfg.mixer_ffn:
         both = {
             "norm": whole(2), "mlp_norm": whole(2), "wq": whole(3), "wk": whole(3), "wv": whole(3),

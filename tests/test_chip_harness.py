@@ -130,6 +130,11 @@ STATE_CELL = "nemotron-3-super.distinct-closed"
 # STATE every row starts from (PR 51).
 BLOCK_CELL = "minicpm-sala.catalogue-2k-closed"
 
+# The cell whose layers are a mixer + feed-forward, the mixer a gated short
+# convolution whose tail is kept a slot AND a page, or attention on heads of
+# 64, the feed-forward dense then routed: its rows take radix hits (PR 56).
+CONV_CELL = "lfm2-24b-a2b.distinct-closed"
+
 FED = _fed_in(CELL)
 FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
 FED_MIXED = [m for m in _fed_in(MIXED_CELL) if m not in FED]
@@ -137,6 +142,7 @@ FED_LATENT = [m for m in _fed_in(LATENT_CELL) if m not in FED + FED_MIXED]
 FED_INDEX = [m for m in _fed_in(INDEX_CELL) if m not in FED + FED_MIXED + FED_LATENT]
 FED_STATE = [m for m in _fed_in(STATE_CELL) if m not in FED]
 FED_BLOCK = [m for m in _fed_in(BLOCK_CELL) if m not in FED]
+FED_CONV = [m for m in _fed_in(CONV_CELL) if m not in FED]
 
 
 @pytest.fixture(scope="module")
@@ -180,6 +186,11 @@ def served_block(tmp_path_factory):
     # catalogue of ~800 tokens, past the rehearsal block's 256-token buckets and
     # the 256 tokens of the 4 blocks a query keeps) with a warm-up the CPU can afford.
     return _serve(BLOCK_CELL, tmp_path_factory, warmup_max_len=256, shortlist_top_k=1500)
+
+
+@pytest.fixture(scope="module")
+def served_conv(tmp_path_factory):
+    return _serve(CONV_CELL, tmp_path_factory)
 
 
 def _serve(cell_name, tmp_path_factory, warmup_max_len=None, shortlist_top_k=None):
@@ -287,6 +298,7 @@ def _serve(cell_name, tmp_path_factory, warmup_max_len=None, shortlist_top_k=Non
         pallas=pallas, paths=pallas.get("paths") or {}, costs=costs,
         kernel_paths=marks1["kernel_paths"],
         engine_metrics=(marks0["engine_metrics"], marks1["engine_metrics"]),
+        hits_before=(counters0.get("/metrics") or {}).get('mcpx_engine_prefix_state_total{event="hit"}'),
     )
 
 
@@ -333,7 +345,7 @@ def test_eleven_start_up_metrics_read_one_sample_of_metrics_each():
     assert all(m["args"]["path"].count("=") <= 1 for m in STARTUP_METRICS)
     one_chip = {w["name"] for w in json.load(open(os.path.join(REPO, "BENCHMARK.json")))["workloads"]
                 if w["chips"] == 1}
-    assert set(_CELLS_OF["startup.weights_s"]) == one_chip and len(one_chip) == 8
+    assert set(_CELLS_OF["startup.weights_s"]) == one_chip and len(one_chip) == 9
     assert all(_CELLS_OF[m["name"]] is None for m in STARTUP_METRICS if m["name"] != "startup.weights_s")
 
 
@@ -531,7 +543,7 @@ def test_the_experts_kernels_name_is_what_its_metric_selects():
     from mcpx.engine.kernels.routed_experts import routed_experts
 
     regex = {m["name"]: m["args"]["regex"] for m in METRICS if m["reader"] == "device_op_share"}
-    assert _CELLS_OF["kernel.moe_busy_share"] == [SPARSE_CELL, MIXED_CELL, LATENT_CELL, STATE_CELL]
+    assert _CELLS_OF["kernel.moe_busy_share"] == [SPARSE_CELL, MIXED_CELL, LATENT_CELL, STATE_CELL, CONV_CELL]
     bf, S = jnp.bfloat16, jax.ShapeDtypeStruct
     shapes = (
         S((64, 256), bf), S((64, 8), jnp.float32), S((2, 8, 256, 256), bf), S((2, 8, 256, 256), bf),
@@ -1003,3 +1015,84 @@ def test_every_path_and_command_a_document_names_exists(doc):
         if not (os.path.exists(os.path.join(REPO, p)) or os.path.exists(os.path.join(REPO, p + ".py")))
     )
     assert not missing, f"{doc} names {missing}, which the repository does not have"
+
+
+# ------------------------------------------- short convolutions, tails a page
+@pytest.mark.parametrize("metric", FED_CONV, ids=[m["name"] for m in FED_CONV])
+def test_the_conv_block_feeds_its_metrics(served_conv, metric):
+    """Its three own metrics, and the sparse and state cells' that list it too:
+    its routed layers write what every sparse block's do."""
+    assert {m["name"] for m in FED_CONV} == {
+        "conv.mixer_bytes_share", "conv.tail_bytes_share", "engine.prefix_hit_row_share",
+        "engine.prefix_state_hit_share", "engine.prefix_state_miss_share", "moe.experts_touched_share",
+        "moe.tok_per_touched_expert", "moe.load_max_over_mean", "moe.touched_per_sparse_layer",
+        "moe.prefill_rows_per_assignment", "moe.routed_bytes_share", "moe.kernel_step_share"}
+    v = served_conv["read"](metric["reader"], metric["args"])
+    counters = served_conv["ev"].counters_after["/metrics"]
+    hits = counters['mcpx_engine_prefix_state_total{event="hit"}']
+    if metric["name"] == "engine.prefix_state_hit_share":
+        # a share of hits + misses: this model has no miss, so 1.0 wherever a row hit in the window
+        # (five distinct prompts may share no page: then there is nothing to divide)
+        assert v == (1.0 if v is not None else None) and (v is not None or hits == served_conv["hits_before"])
+        return
+    assert v is not None and math.isfinite(v)
+    if metric["name"] == "engine.prefix_state_miss_share":
+        assert v == 0.0  # a page's tail is always there: no row that found pages prefilled whole
+    elif metric["name"] == "engine.prefix_hit_row_share":
+        assert 0.0 <= v <= 1.0
+    elif metric["name"] == "moe.kernel_step_share":
+        assert v == 1.0
+    elif metric["unit"] == "ratio" and metric["name"] != "moe.load_max_over_mean":
+        assert 0 < v < 1
+
+
+def test_the_conv_blocks_attributes_count_calls_tails_and_weights(served_conv):
+    """At the rehearsal size: 8 short convolutions and 2 attention layers among
+    10, 8 routed layers, a tail of 2 x 256 float32 a row a layer beside a
+    pending window of 8. Every new span attribute, counter, ``pallas.paths``
+    entry and /healthz field the cell's metrics read."""
+    spec = sys.modules["spec"]
+    cfg = spec.load_block("lfm2", CHIP_DIR).rehearsal_config(3072)
+    assert (cfg.n_conv_layers, cfg.n_attn_layers, cfg.n_sparse_layers, cfg.kv_pack) == (8, 2, 8, 2)
+    segments = _segments(served_conv)
+    assert segments
+    for sp in segments:
+        a = sp["attrs"]
+        assert a["conv_row_calls"] % 8 == 0 and 0 < a["conv_row_calls"] <= a["forwards"] * 8 * 8
+        assert a["conv_tail_bytes"] == a["conv_row_calls"] * (2 + 8) * 256 * 4 * 2  # float32, read and written
+        assert a["conv_row_calls"] <= a["conv_tokens"] <= a["conv_slots"] <= a["conv_row_calls"] * 8
+        assert a["attn_row_calls"] * 4 == a["conv_row_calls"]  # TWO attention layers
+        assert a["moe_layer_forwards"] == a["forwards"] * 8
+        assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (cfg.kv_bytes_per_token // 2)
+        assert 0 < a["conv_weight_bytes"] < a["weight_bytes_read"] and a["conv_weight_bytes"] % a["forwards"] == 0
+        assert "conv_prefill_tokens" in a and "ssm_row_calls" not in a and "ssm_state_bytes" not in a
+    once = _segments_once(served_conv)
+    profile = served_conv["health"]["engine_queue"]["worker_profile"]
+    for attr in ("conv_row_calls", "conv_tail_bytes", "conv_slots", "conv_tokens", "conv_weight_bytes", "conv_prefill_tokens"):
+        assert profile[attr] >= sum(sp["attrs"][attr] for sp in once) > 0, attr
+    # every admitted prompt's own tokens went through each convolution once, and each page it
+    # filled to its last slot got its tail: the rest were a matched page's
+    prefills = [sp for tr in served_conv["ev"].traces for sp in tr.get("tree", []) if sp["name"] == "engine.prefill"]
+    assert prefills
+    for sp in prefills:
+        a = sp["attrs"]
+        assert a["conv_prefill_tokens"] % 8 == 0 and a["conv_prefill_tokens"] > 0
+        own = a["conv_prefill_tokens"] // 8
+        assert a["tail_pages_written"] == (a["prefix_matched_tokens"] + own) // 16 - a["prefix_matched_tokens"] // 16
+    # hits and no miss: the lifetime sums and the counters agree
+    assert {k for k in profile if k.startswith("prefix_state_")} == {"prefix_state_hit", "prefix_state_miss"}
+    metrics = served_conv["ev"].counters_after["/metrics"]
+    assert metrics['mcpx_engine_prefix_state_total{event="hit"}'] == profile["prefix_state_hit"] >= 0
+    assert metrics['mcpx_engine_prefix_state_total{event="miss"}'] == profile["prefix_state_miss"] == 0
+    assert profile["prefix_state_hit"] == metrics["mcpx_kv_prefix_hits_total"]  # every matched row is a state hit
+    # the kernel paths the cell's ``correct`` asks for; no state kernel exists
+    assert served_conv["kernel_paths"] == {"decode": 1, "prefill": 0}
+    assert "ssm" not in served_conv["paths"] and served_conv["paths"]["prefill"]["engaged"]
+    assert served_conv["paths"]["prefill"]["reason"] is None
+    # the state pool's bytes where the weights' are
+    pool = served_conv["health"]["engine_queue"]["state_pool"]
+    n_pages = pool["page_tails_bytes"] // (8 * 2 * 256 * 4)
+    assert pool["slots"] == 8 and n_pages > 8 * 16 and pool["bytes"] == pool["page_tails_bytes"] + 8 * 8 * 10 * 256 * 4 + 8 * 4
+    model = served_conv["costs"]["model"]
+    assert model["params_held"] == cfg.n_params
+    assert model["params_held"] - model["params_active_per_token"] == 8 * 6 * 3 * 256 * 128
