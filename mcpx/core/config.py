@@ -265,7 +265,8 @@ class EngineConfig:
     # a weight pass is compute-bound and costs by the slot. Empty = the
     # engine's own table (``engine.cohort_buckets``): {1, 8, max_batch_size}
     # and its halves down to 8, and down to 4 where short whole prompts land
-    # (the 128 prefill bucket, no matched prefix). A list given here holds at
+    # (the 128 prefill bucket, no matched prefix) and where short suffixes do
+    # (the 64 bucket behind a matched prefix). A list given here holds at
     # every prefill bucket and on both routes.
     batch_buckets: list = field(default_factory=list)
     # Execute one batch per (B, T) bucket at startup so no compile lands in

@@ -233,21 +233,27 @@ def _bucket(n: int, buckets: tuple[int, ...]) -> int:
     raise EngineError(f"length {n} exceeds largest bucket {buckets[-1]}")
 
 
-# Where the automatic cohort buckets go below 8 rows: the whole-prompt route at
-# the prefill bucket of _SMALL_COHORT_T slots, halved down to
-# _SMALL_COHORT_FLOOR rows. A prefill past ~240 tokens a weight pass is
-# compute-bound on a v5e and costs by the slot, so a cohort of 2-4 prompts of
-# 65-128 tokens through 8 x 128 slots pays twice what 4 x 128 does. Every
-# bucket is executables to trace, compile or load at EVERY start, though (one
-# a prefill bucket a route, an admit, an admit-merge, the registry grammar's
-# admit: a prefill executable of an unrolled hybrid stack is ~1.5 s of a warm
-# start), so the small bucket exists only where it pays most: at the 64 bucket
-# 8 rows are the 512 slots that 4 x 128 are; a row past 128 slots, or one
-# behind a shared prefix, is what an explicit ``engine.batch_buckets`` is for
+# Where the automatic cohort buckets go below 8 rows, halved down to
+# _SMALL_COHORT_FLOOR: at ONE prefill bucket a route, _SMALL_COHORT_T's, the
+# one where that route's short cohorts land: whole prompts of 65-128 tokens at
+# 128 slots, suffixes behind a matched prefix at 64 (behind a shared catalogue
+# head every admission is a suffix of 20-40 tokens). A prefill past ~240
+# tokens a weight pass is compute-bound on a v5e and costs by the slot: a
+# cohort of 2-4 through 8 x 128 slots pays twice what 4 x 128 does, and 8 x 64
+# against 4 x 64 reads 35 against 22 ms of an unrolled stack. Every bucket is
+# executables to trace, compile or load at EVERY start, though: a prefill
+# executable a (rows, slots, route), 0.6 s of a warm start for a scanned dense
+# stack and 3.4 s for an unrolled sparse one, and for a NEW row bucket an
+# admit, an admit-merge and the registry grammar's admit besides (the 4-row
+# ones are there once, for both routes). So a cohort with ONE matched row and
+# mates of 65-128 tokens still rides in 8 rows (PERF.md PR 57: +1 to +2.6% of
+# the rate at the distinct cells, +8.5% of a warm start where the stack is
+# unrolled and sparse); that, a whole prompt under 65 tokens, a row past 128
+# slots or a 2-row bucket is what an explicit ``engine.batch_buckets`` is for
 # (a.x-k1's [1, 2, 4] at the 1,024 bucket costs 63 executables; the catalogue
 # cells warm six prefill buckets on two routes).
 _SMALL_COHORT_FLOOR = 4
-_SMALL_COHORT_T = 128
+_SMALL_COHORT_T = {False: 128, True: 64}  # by route: whole prompts, suffixes
 
 
 def cohort_buckets(rows: int, explicit: tuple[int, ...], T: int, suffix: bool) -> tuple[int, ...]:
@@ -261,8 +267,7 @@ def cohort_buckets(rows: int, explicit: tuple[int, ...], T: int, suffix: bool) -
     if explicit:
         sizes = set(explicit)
     else:
-        small = not suffix and T == _SMALL_COHORT_T
-        floor = _SMALL_COHORT_FLOOR if small else 8
+        floor = _SMALL_COHORT_FLOOR if T == _SMALL_COHORT_T[suffix] else 8
         sizes = {1, 8, rows}
         q = rows
         while q >= 2 * floor:

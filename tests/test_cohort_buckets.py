@@ -1,10 +1,11 @@
-"""Which admission-cohort sizes exist (ISSUE 55): ONE table,
+"""Which admission-cohort sizes exist (ISSUES 55, 57): ONE table,
 ``engine.cohort_buckets``, read by warm-up, the registry-grammar warm and
 admission alike. The automatic list gets the bucket it lacked between 1 and 8
-rows on the whole-prompt route at the 128 prefill bucket; a cohort through it
-decodes what the eight-row bucket decodes; nothing compiles once warm-up is
-over; ``mcpx_engine_prefill_slots_total`` and the ``engine.prefill`` span say
-which bucket an admission took."""
+rows on the whole-prompt route at the 128 prefill bucket and, behind a matched
+prefix, at the 64 bucket; a cohort through it decodes what the
+eight-row bucket decodes; nothing compiles once warm-up is over;
+``mcpx_engine_prefill_slots_total`` and the ``engine.prefill`` span say which
+bucket an admission took."""
 
 import asyncio
 import functools
@@ -18,8 +19,8 @@ from mcpx.engine.engine import InferenceEngine, cohort_buckets
 from mcpx.telemetry import tracing
 from mcpx.telemetry.tracing import Tracer
 
-# What the table held before the small bucket, and holds still at every
-# prefill bucket but 128 and behind a matched prefix.
+# What the table held before the small bucket, and holds still for whole
+# prompts at every prefill bucket but 128 and for suffixes at every one but 64.
 EIGHT_UP = {8: (1, 8), 16: (1, 8, 16), 32: (1, 8, 16, 32)}
 FOUR_UP = {8: (1, 4, 8), 16: (1, 4, 8, 16), 32: (1, 4, 8, 16, 32)}
 
@@ -28,9 +29,9 @@ FOUR_UP = {8: (1, 4, 8), 16: (1, 4, 8, 16), 32: (1, 4, 8, 16, 32)}
 @pytest.mark.parametrize("T", [64, 128, 256])
 @pytest.mark.parametrize("rows", [8, 16, 32])
 def test_the_automatic_table(rows, T, suffix):
-    """Halved down to 4 where whole prompts of 65-128 tokens land, down to 8
-    elsewhere (at the 64 bucket 8 rows are the slots 4 x 128 are)."""
-    want = FOUR_UP if (T == 128 and not suffix) else EIGHT_UP
+    """Halved down to 4 where whole prompts of 65-128 tokens land and where
+    suffixes of up to 64 do, down to 8 elsewhere."""
+    want = FOUR_UP if T == (64 if suffix else 128) else EIGHT_UP
     assert cohort_buckets(rows, (), T, suffix) == want[rows]
 
 
@@ -74,7 +75,7 @@ def test_an_engines_table_is_the_functions():
     eng = make_engine()
     assert eng._cohort_table([64, 128, 256]) == {
         1: {64: (False, True), 128: (False, True), 256: (False, True)},
-        4: {128: (False,)},
+        4: {64: (True,), 128: (False,)},
         8: {64: (False, True), 128: (False, True), 256: (False, True)},
     }
     assert make_engine(prefix_cache=False)._cohort_table([128, 256]) == {
@@ -202,9 +203,9 @@ def test_warm_up_compiles_the_table_and_nothing_else():
     """``startup.executables`` = the table's routes, an admit and an
     admit-merge a row bucket, the segment and the merge."""
     w = warmed()
-    assert set(w["table"]) == {1, 4, 8} and set(w["table"][4]) == {128}
+    assert set(w["table"]) == {1, 4, 8} and w["table"][4] == {64: (True,), 128: (False,)}
     routes = sum(len(r) for shapes in w["table"].values() for r in shapes.values())
-    assert routes == 4 + 1 + 4
+    assert routes == 4 + 2 + 4
     assert w["warmup"]["executables"] == routes + 2 * len(w["table"]) + 2 == w["compiles"][0]
 
 
@@ -219,12 +220,13 @@ def test_no_cohort_of_one_to_eight_rows_compiles_after_warm_up():
 def test_the_span_says_which_bucket_the_cohort_took(route):
     """``cohort_rows`` is the cohort's size on every one of its rows' spans,
     ``cohort_bucket`` the table's bucket for it: 4 for a cohort of 2-4 whole
-    prompts in the 128 bucket, 8 in the 64 bucket and behind a matched prefix."""
+    prompts in the 128 bucket or suffixes in the 64 bucket behind a matched
+    prefix, 8 for whole prompts in the 64 bucket."""
     seen = {n: attrs for r, n, attrs in warmed()["admissions"] if r == route}
     assert sorted(seen) == list(range(1, 9))
     for n, attrs in seen.items():
         assert [a["cohort_rows"] for a in attrs] == [n] * n
-        want = 1 if n == 1 else 4 if (route == "whole128" and n <= 4) else 8
+        want = 1 if n == 1 else 4 if (route != "whole" and n <= 4) else 8
         assert {a["cohort_bucket"] for a in attrs} == {want}, (route, n)
         assert all(a["prefix_hit"] == (route == "suffix") for a in attrs)
 
@@ -238,6 +240,7 @@ def test_the_slot_counter_counts_bucket_times_length_an_admission():
     want = [attrs[0]["cohort_bucket"] * lengths[route] for route, _, attrs in w["admissions"]]
     assert grew == want
     assert sum(want[:8]) == 64 * (1 + 8 * 7) and sum(want[8:16]) == 128 * (1 + 4 * 3 + 8 * 4)
+    assert sum(want[17:]) == 64 * (1 + 4 * 3 + 8 * 4)
 
 
 def _float32_model():
@@ -249,19 +252,25 @@ def _float32_model():
                        head_dim=32, d_ff=256, dtype="float32", max_seq_len=256)
 
 
-@functools.lru_cache(maxsize=None)
-def decoded(mesh_shape: tuple) -> dict:
-    """Cohorts of 2, 3 and 4 rows through the table's bucket and through the
-    eight-row bucket they took before it (``batch_buckets`` [1, 8])."""
+def _mesh(mesh_shape: tuple):
     import jax
 
     from mcpx.parallel.mesh import make_mesh
 
     data, model = mesh_shape
+    return make_mesh(data=data, model=model, devices=jax.devices()[: data * model])
+
+
+MESHES = pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+
+
+@functools.lru_cache(maxsize=None)
+def decoded(mesh_shape: tuple) -> dict:
+    """Cohorts of 2, 3 and 4 rows through the table's bucket and through the
+    eight-row bucket they took before it (``batch_buckets`` [1, 8])."""
 
     async def serve(**engine):
-        mesh = make_mesh(data=data, model=model, devices=jax.devices()[: data * model])
-        eng = make_engine(model_cfg=_float32_model(), mesh=mesh, **engine)
+        eng = make_engine(model_cfg=_float32_model(), mesh=_mesh(mesh_shape), **engine)
         await eng.start()
         try:
             tracer = Tracer(None, enabled=True, sample_rate=1.0)
@@ -284,7 +293,7 @@ def decoded(mesh_shape: tuple) -> dict:
 
 
 @pytest.mark.parametrize("n", [2, 3, 4])
-@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+@MESHES
 def test_a_small_cohort_decodes_what_the_eight_row_bucket_decodes(mesh_shape, n):
     """Prefill rows are independent and padding rows are dropped at the
     merge: token for token the same plan through [4, T] as through [8, T]."""
@@ -293,3 +302,84 @@ def test_a_small_cohort_decodes_what_the_eight_row_bucket_decodes(mesh_shape, n)
     toks8, buckets8 = got["eight"][n]
     assert buckets4 == [4] * n and buckets8 == [8] * n
     assert toks4 == toks8 and all(len(t) > 0 for t in toks4)
+
+
+# Cohorts with a row behind a matched prefix (ISSUE 57): (kind, rows). "one":
+# ONE row of the cohort matched the resident head and its cohort-mates are
+# whole prompts of 40 tokens, so the whole cohort goes down the suffix route
+# at T 64; "all": every row matched, T 64 (the catalogue cells' case);
+# "one128": the mates are whole prompts of 100 tokens, T 128 (the distinct
+# cells' case, which the trim by shape leaves in 8 rows).
+MIXED = [("one", 2), ("one", 3), ("one", 4), ("all", 2), ("all", 3), ("all", 4), ("all", 5),
+         ("one128", 2), ("one128", 4)]
+MATES = {"one": 40, "one128": 100}
+
+
+def mixed_cohort(eng, kind, n):
+    hits = n if kind == "all" else 1
+    behind = [eng.tokenizer.encode(HEAD + f" {kind}{n}{i} tail {chr(66 + i) * 5}") for i in range(hits)]
+    whole = [eng.tokenizer.encode(f"{kind}.{n}.{i} " + "y" * 100)[:MATES[kind]] for i in range(n - hits)]
+    return behind + whole
+
+
+@functools.lru_cache(maxsize=None)
+def decoded_behind_a_prefix(mesh_shape: tuple) -> dict:
+    """``MIXED`` through the table's buckets and through the eight-row bucket
+    they took before (``batch_buckets`` [1, 8]), both engines warmed with
+    ``warmup_compile`` on: tokens, span attributes, slots added, compiles."""
+
+    async def serve(**engine):
+        eng = make_engine(model_cfg=_float32_model(), mesh=_mesh(mesh_shape), warmup_compile=True,
+                          warmup_max_len=128, **engine)
+        await eng.start()
+        try:
+            tracer = Tracer(None, enabled=True, sample_rate=1.0)
+            await burst(eng, tracer, [eng.tokenizer.encode(HEAD + " seeds the tree")])
+            out = {"compiles": [compiles(eng)]}
+            for kind, n in MIXED:
+                before = slots(eng)
+                served = await burst(eng, tracer, mixed_cohort(eng, kind, n), n_new=12)
+                out[kind, n] = ([toks for toks, _ in served],
+                                [prefill_span(spans).attrs for _, spans in served],
+                                slots(eng) - before)
+                out["compiles"].append(compiles(eng))
+            return out
+        finally:
+            await eng.aclose()
+
+    async def go():
+        return {"table": await serve(), "eight": await serve(batch_buckets=[1, 8])}
+
+    return asyncio.run(asyncio.wait_for(go(), 300))
+
+
+@pytest.mark.parametrize("kind,n", MIXED, ids=[f"{k}-{n}" for k, n in MIXED])
+@MESHES
+def test_a_small_cohort_behind_a_prefix_takes_four_rows_and_decodes_the_same(mesh_shape, kind, n):
+    """One matched row sends its cohort down the suffix route: at T 64 2-4
+    rows take bucket 4 there (sharded over ``data`` 2 on the mesh), 5 keep 8,
+    and so do 2-4 at T 128; ``A x T`` slots an admission; token for token what
+    the eight-row bucket decodes."""
+    got = decoded_behind_a_prefix(mesh_shape)
+    toks4, attrs4, slots4 = got["table"][kind, n]
+    toks8, attrs8, slots8 = got["eight"][kind, n]
+    hits = n if kind == "all" else 1
+    T = 128 if kind == "one128" else 64
+    want = 4 if (n <= 4 and T == 64) else 8
+    for attrs, bucket in ((attrs4, want), (attrs8, 8)):
+        assert [a["cohort_rows"] for a in attrs] == [n] * n
+        assert {a["cohort_bucket"] for a in attrs} == {bucket}
+        assert [a["prefix_hit"] for a in attrs] == [True] * hits + [False] * (n - hits)
+    assert (slots4, slots8) == (want * T, 8 * T)
+    assert toks4 == toks8 and all(len(t) > 0 for t in toks4)
+
+
+@MESHES
+def test_no_cohort_behind_a_prefix_compiles_after_warm_up(mesh_shape):
+    """The suffix prefill at (4, 64) is warm-up's: the compile counter is
+    flat over the bursts, with the table and with a list."""
+    got = decoded_behind_a_prefix(mesh_shape)
+    for side in ("table", "eight"):
+        assert len(got[side]["compiles"]) == 1 + len(MIXED)
+        assert got[side]["compiles"] == [got[side]["compiles"][0]] * (1 + len(MIXED))
+    assert got["table"]["compiles"][0] > got["eight"]["compiles"][0]

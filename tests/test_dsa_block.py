@@ -316,6 +316,45 @@ def test_at_a_context_the_selection_covers_the_logits_are_the_index_less_blocks_
             assert (with_index[1][live] == without[1][live]).all(), (pages, use_pallas)
 
 
+@pytest.mark.parametrize("path", ["kernel", "jnp"])
+def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(path):
+    """The admission cohort's row bucket is padding and nothing else (ISSUE
+    57: the suffix route's 4-row bucket). Three rows behind a shared head of
+    48 tokens (past ``index_topk``: every suffix query selects among the
+    head's index keys), padded as the engine pads them (a padding row: one
+    pad token at position 0 over the null page) to 4 rows and to 8: the same
+    last logits and the same pools (the index keys ride in them)."""
+    cfg = small()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    psz, p_max, T, n_pages = 16, 8, 32, 1 + 3 + 3 * 5
+    rng = np.random.default_rng(57)
+    head = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 48)), jnp.int32)
+    own = [rng.integers(0, cfg.vocab_size, n) for n in (5, 9, 13)]
+
+    def run(A):
+        pools = init_paged_kv(cfg, n_pages, psz)
+        _, pools = decode_chunk_paged(
+            params, cfg, head, jnp.zeros((1,), jnp.int32), jnp.asarray([[1, 2, 3, 0, 0, 0, 0, 0]], jnp.int32),
+            pools, use_pallas=path == "kernel", interpret=True, mesh=mesh, q_lens=jnp.asarray([48]))
+        tokens, lens, pos = np.zeros((A, T), np.int32), np.ones((A,), np.int32), np.zeros((A,), np.int32)
+        table = np.zeros((A, p_max), np.int32)
+        for b, o in enumerate(own):
+            tokens[b, : len(o)], lens[b], pos[b] = o, len(o), 48
+            table[b, :3], table[b, 3:] = [1, 2, 3], 4 + 5 * b + np.arange(5)
+        last, pools = decode_chunk_paged(
+            params, cfg, jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(table), pools,
+            use_pallas=path == "kernel", interpret=True, mesh=mesh, logits_at=jnp.asarray(lens - 1),
+            q_lens=jnp.asarray(lens))
+        return np.asarray(last)[:3], pools
+
+    (four, pools4), (eight, pools8) = run(4), run(8)
+    np.testing.assert_allclose(four, eight, atol=1e-5)
+    assert (four.argmax(-1) == eight.argmax(-1)).all()
+    for a, b in zip(jax.tree.leaves(pools4), jax.tree.leaves(pools8)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
 def test_a_forward_counts_the_keys_it_selected_and_the_keys_it_scored():
     cfg = small()
     params = init_params(cfg, jax.random.PRNGKey(0))

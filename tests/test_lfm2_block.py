@@ -480,6 +480,48 @@ def test_a_hit_at_a_split_and_at_a_whole_node_gives_the_logits_a_whole_prefill_g
     np.testing.assert_allclose(logits2[0], _ref(block, cfg, params, again)[-1], atol=2e-4)
 
 
+@pytest.mark.parametrize("path", ["kernel", "jnp"])
+def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(model, path):
+    """The admission cohort's row bucket is padding and nothing else (ISSUE
+    57: the suffix route's 4-row bucket). Three rows of which ONE matched two
+    pages of a resident prompt and starts from the second page's tail, its
+    cohort-mates from zeros, padded as the engine pads them (a padding row:
+    one pad token at position 0 over the null page, its slot out of range)
+    to 4 rows and to 8: the same last logits, live tails and pages' tails."""
+    cfg, params = model
+    rng = np.random.default_rng(57)
+    T, ppr, n_pages, n_slots = 64, 8, 40, 8
+    head = list(rng.integers(0, cfg.vocab_size, size=32))
+    own = [list(rng.integers(0, cfg.vocab_size, size=n)) for n in (21, 40, 33)]
+    depth = [32, 0, 0]
+    first = np.zeros((1, T), np.int32)
+    first[0, :32] = head
+    _, seeded = _prefill(cfg, params, jnp.asarray(first), jnp.asarray([32], jnp.int32),
+                         jnp.asarray([[1, 2, 0, 0, 0, 0, 0, 0]]), n_pages, T)
+
+    def run(A):
+        pools = dict(seeded)
+        pools["state"] = init_state_pool(cfg, n_slots, W, n_pages) | {"tails": seeded["state"]["tails"]}
+        tokens, lens, pos = np.zeros((A, T), np.int32), np.ones((A,), np.int32), np.zeros((A,), np.int32)
+        table, slots = np.zeros((A, ppr), np.int32), np.full((A,), n_slots, np.int32)
+        for b, o in enumerate(own):
+            tokens[b, : len(o)], lens[b], pos[b], slots[b] = o, len(o), depth[b], b
+            shared = depth[b] // PSZ
+            table[b, :shared] = [1, 2][:shared]
+            table[b, shared:] = 10 + 8 * b + np.arange(ppr - shared)
+        logits, pools = _chunk(cfg, params, jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(table), pools,
+                               jnp.asarray(lens), commit=True, path=path, slots=jnp.asarray(slots))
+        return np.asarray(logits)[:3], pools
+
+    (four, pools4), (eight, pools8) = run(4), run(8)
+    np.testing.assert_allclose(four, eight, atol=1e-5)
+    assert (four.argmax(-1) == eight.argmax(-1)).all()
+    for a, b in zip(jax.tree.leaves(pools4), jax.tree.leaves(pools8)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    conv = pools4["state"]["layers"][0]["conv"]
+    assert float(jnp.abs(conv[:3]).sum()) > 0 and float(jnp.abs(conv[3:]).sum()) == 0  # no padding row's tail
+
+
 def test_a_head_built_in_chunks_hands_on_its_tail(model, block):
     """A declared head longer than a prefill bucket is built a chunk at a time,
     each a suffix prefill over the pages of those before it: the tail crosses

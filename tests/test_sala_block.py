@@ -405,6 +405,56 @@ def test_a_head_built_in_chunks_is_the_head_built_at_once():
     np.testing.assert_allclose(pa["state"]["ssm"][:, 1], jnp.stack([h[0].reshape(32, -1) for h in dense["ssm"]]), atol=2e-5)
 
 
+@pytest.mark.parametrize("path", ["kernel", "jnp"])
+def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(path):
+    """The admission cohort's row bucket is padding and nothing else (ISSUE
+    57: the suffix route's 4-row bucket). Three rows behind a head whose END
+    STATE sits in the pool's last slot, padded as the engine pads them (a
+    padding row: one pad token at position 0 over the null page, its state
+    read from and written to a slot out of range) to 4 rows and to 8: the
+    same last logits, and the same pool: the rows' states, key sums and
+    counts, the head's slot untouched."""
+    cfg = small(layer_pattern="SLLS")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    mesh = _one_device()
+    psz, p_max, T, n_slots = 4, 16, 16, 9  # an 8-row slab's pool: a slot a row and the head's
+    n_pages = 1 + 8 + 3 * 8
+    rng = np.random.default_rng(57)
+    head = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 32)), jnp.int32)  # eight shared pages
+    own = [rng.integers(0, cfg.vocab_size, n) for n in (5, 9, 13)]
+    shared = 1 + np.arange(8)
+    head_slot, nowhere = n_slots - 1, n_slots
+
+    def run(A):
+        pools = {**init_paged_kv(cfg, n_pages, psz), "state": init_state_pool(cfg, n_slots, W, n_pages)}
+        head_table = np.zeros((1, p_max), np.int32)
+        head_table[0, :8] = shared
+        _, pools = decode_chunk_paged(
+            params, cfg, head, jnp.zeros((1,), jnp.int32), jnp.asarray(head_table), pools,
+            use_pallas=path == "kernel", interpret=True, mesh=mesh, q_lens=jnp.asarray([32]), commit=True,
+            state_slots=(jnp.asarray([nowhere]), jnp.asarray([head_slot])))
+        tokens, lens, pos = np.zeros((A, T), np.int32), np.ones((A,), np.int32), np.zeros((A,), np.int32)
+        table = np.zeros((A, p_max), np.int32)
+        src, dst = np.full((A,), nowhere, np.int32), np.full((A,), nowhere, np.int32)
+        for b, o in enumerate(own):
+            tokens[b, : len(o)], lens[b], pos[b] = o, len(o), 32
+            table[b, :8], table[b, 8:] = shared, 9 + 8 * b + np.arange(8)
+            src[b], dst[b] = head_slot, b
+        last, pools = decode_chunk_paged(
+            params, cfg, jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(table), pools,
+            use_pallas=path == "kernel", interpret=True, mesh=mesh, logits_at=jnp.asarray(lens - 1),
+            q_lens=jnp.asarray(lens), commit=True, state_slots=(jnp.asarray(src), jnp.asarray(dst)))
+        return np.asarray(last)[:3], pools
+
+    (four, pools4), (eight, pools8) = run(4), run(8)
+    np.testing.assert_allclose(four, eight, atol=1e-5)
+    assert (four.argmax(-1) == eight.argmax(-1)).all()
+    for a, b in zip(jax.tree.leaves(pools4), jax.tree.leaves(pools8)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    assert float(jnp.abs(pools4["state"]["ssm"][:, :3]).sum()) > 0  # the rows' states were written
+    assert float(jnp.abs(pools4["state"]["ssm"][:, 3:head_slot]).sum()) == 0  # and no padding row's
+
+
 # ------------------------------------- the comparison that decides ``correct``
 def _compare(block, reference, control="", seed=5):
     for k in block.CONTROLS:
