@@ -549,7 +549,7 @@ class InferenceEngine:
         # Which counters the segment returns beside its state (a sparse
         # feed-forward's, or a mixer + feed-forward pattern's own; windowed
         # attention); a default block returns none.
-        self._segment_stats = (bool(mc.n_experts) or mc.mixer_ffn, mc.layer_windows() is not None)
+        self._segment_stats = (bool(mc.n_experts) or mc.dense_pattern, mc.layer_windows() is not None)
         self.grammar: PlanGrammar = build_plan_grammar(self.tokenizer)
         # Resolved kernel route, decided at construction so a COLD engine
         # can already answer pallas_paths()/queue_stats(). Mosaic tiles the
@@ -1417,10 +1417,12 @@ class InferenceEngine:
         """``_setup`` between the weights and the warm-up: what was placed
         where, the KV and state pools, every jit wrapper, the slab."""
         ecfg = self.config.engine
-        if self.model_cfg.mixer_ffn:
-            # No routed expert: every leaf but the embedding is read whole.
+        if self.model_cfg.dense_pattern:
+            # No routed expert: every leaf is read whole, but an embedding
+            # that is not the head too (a forward gathers a few of its rows).
             held = sum(a.nbytes for a in jax.tree.leaves(self._params))
-            self._weight_bytes = (0, held - self._params["embed"].nbytes)
+            tied = self.model_cfg.tie_embeddings
+            self._weight_bytes = (0, held - (0 if tied else self._params["embed"].nbytes))
         if self.model_cfg.conv_ffn:
             # What a forward reads of the short-convolution mixers, whole.
             self._conv_weight_bytes = sum(
@@ -1449,6 +1451,14 @@ class InferenceEngine:
         }
         self._paged_kv = self._init_pools()
         self._state_pool = self._init_state_pool()
+        if self.model_cfg.scan_ffn:
+            # The fourth kind, for /healthz: the slots' states, tails and
+            # pending windows, every array stacked a layer.
+            self._placement["state_pool"] = {
+                "bytes": sum(a.nbytes for a in jax.tree.leaves(self._state_pool)),
+                "state_bytes": self._state_pool["ssm"].nbytes,
+                "slots": int(self._state_pool["n"].shape[0]),
+            }
         if self.model_cfg.page_state:
             # The third kind of per-row state, for /healthz: the slots' tails
             # and pending windows, and the tail a page.
@@ -2195,7 +2205,7 @@ class InferenceEngine:
         now = time.monotonic()
         while self._pending_admissions:
             (
-                t0, marker, rows, gens, t_admit0, pf_entry, pf_name, pf_toks, A,
+                t0, marker, rows, gens, t_admit0, pf_entry, pf_name, pf_toks, A, T,
             ) = self._pending_admissions[0]
             if not marker.is_ready():
                 # Purge entries whose rows were ALL cancelled/reaped before
@@ -2257,6 +2267,16 @@ class InferenceEngine:
                         # The tokens this row's prefill moved its recurrent
                         # state by, over the Mamba layers: all it prefilled.
                         pfx_attrs["ssm_prefill_tokens"] = n_pf * self.model_cfg.n_recurrent_layers
+                        if self.model_cfg.scan_ffn:
+                            # A selective scan WALKS its prompt: the COHORT's
+                            # live tokens and the slots of its ``A x T`` window,
+                            # each times the ``J`` layers (the same on every
+                            # row of the cohort: a reader counts a cohort once),
+                            # and the states its calls wrote, one a row a layer.
+                            Lj = self.model_cfg.n_scan_layers
+                            pfx_attrs["scan_tokens"] = sum(pf_toks) * Lj
+                            pfx_attrs["scan_slots"] = A * T * Lj
+                            pfx_attrs["ssm_state_bytes"] = A * Lj * self.model_cfg.ssm_slot_bytes
                     r.span.child(
                         "engine.prefill",
                         t0=t_admit0,
@@ -5113,7 +5133,7 @@ class InferenceEngine:
             (
                 t1, slab.dev[4], rows_idx,
                 [int(slab.gen[i]) for i in rows_idx], t0, pf_entry, pf_name,
-                [int(n) for n in seq_lens[: len(cohort)]], A,
+                [int(n) for n in seq_lens[: len(cohort)]], A, T,
             )
         )
         self.metrics.kv_page_utilization.set(self._allocator.stats().utilization)

@@ -185,7 +185,7 @@ def init_paged_kv(
 
 
 def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int, n_pages: int = 0) -> dict:
-    """The per-row state beside the pages, of three kinds. First, what a Mamba
+    """The per-row state beside the pages, of four kinds. First, what a Mamba
     layer keeps of a row, indexed by SLOT (slab row ``i`` owns slot ``i``), not by
     page. ``{}`` for a model with no such layer: an empty pytree adds nothing
     to a jitted call. Else ``{"ssm", "layers": one dict a Mamba layer, "n":
@@ -235,10 +235,38 @@ def init_state_pool(cfg: GemmaConfig, n_slots: int, window: int, n_pages: int = 
     its suffix prefill from ``tails[:, page_table[row, P / page_size - 1]]``
     whatever node or split the match came from: split, pin, evict and the
     pending epoch move page ids, and the tails go with them
-    (``GemmaConfig.page_state``; docs/engine.md "The state's rule")."""
+    (``GemmaConfig.page_state``; docs/engine.md "The state's rule").
+
+    A Mamba-1 SELECTIVE SCAN (``GemmaConfig.n_scan_layers``) is the pool's
+    FOURTH kind. Its walk over the layers is a ``lax.scan`` over each run of
+    like layers, and a scan cannot index a tuple of dicts by its carried layer
+    number: so there is no ``layers`` tuple, and EVERY array is stacked a
+    layer, ``[J layers, n_slots, ...]``: ``ssm`` ``[.., state, inner]`` float32
+    (the second kind's layout: the state's N on the sublanes, the channels on
+    the lanes), ``conv`` ``[.., K - 1, inner]`` the convolution's last inputs,
+    and the slot's PENDING window: ``dt`` ``[.., window, inner]``, ``pre`` and
+    ``x`` ``[.., window, inner]`` the convolution's inputs and outputs, ``b``
+    ``[.., window, state]``, all float32 (the mixer's own precision between
+    its weight matrices: ``models/gemma/ssm.py``). The rule is the first
+    kind's word for word (``models/gemma/ssm.py::selective_window``): a
+    forward walks what ``n`` says the row kept of its pending window into
+    ``ssm``, moves ``conv`` over it, and leaves its own window pending. No
+    page and no radix node holds such a state: a row prefills whole."""
     if not cfg.n_recurrent_layers:
         return {}
     d = jnp.dtype(cfg.dtype)
+    if cfg.scan_ffn:
+        L, I, N = cfg.n_scan_layers, cfg.scan_inner, cfg.ssm_state_size
+        f32 = jnp.float32
+        return {
+            "ssm": jnp.zeros((L, n_slots, N, I), f32),
+            "conv": jnp.zeros((L, n_slots, cfg.conv_kernel - 1, I), f32),
+            "dt": jnp.zeros((L, n_slots, window, I), f32),
+            "pre": jnp.zeros((L, n_slots, window, I), f32),
+            "x": jnp.zeros((L, n_slots, window, I), f32),
+            "b": jnp.zeros((L, n_slots, window, N), f32),
+            "n": jnp.zeros((n_slots,), jnp.int32),
+        }
     if cfg.conv_ffn:
         K1, D, L = cfg.conv_kernel - 1, cfg.d_model, cfg.n_conv_layers
         f32 = jnp.float32  # (the mixer's own precision: ``models/gemma/ssm.py`` says why)
@@ -290,7 +318,18 @@ def write_prefill_state(state: dict, slots: jax.Array, finals: list) -> dict:
     layer into ``slots`` [A] (a padding row's slot is out of range and
     dropped): the state AT each prompt's length, nothing pending. A short
     convolution's finals are ``(tail [A, K - 1, D], u)``: the tail alone goes
-    to the slot (``commit_prefill_tails`` cuts the pages' from ``u``)."""
+    to the slot (``commit_prefill_tails`` cuts the pages' from ``u``). A
+    selective scan's finals are stacked as its pool is: ``(h [J layers, A, N,
+    I], tail [J layers, A, K - 1, I])``."""
+    if "layers" not in state:  # the fourth kind: every array stacked a layer
+        h, tail = finals
+        return {
+            **state,
+            "ssm": state["ssm"].at[:, slots].set(h, mode="drop"),
+            "conv": state["conv"].at[:, slots].set(tail, mode="drop"),
+            "dt": state["dt"].at[:, slots].set(0.0, mode="drop"),
+            "n": state["n"].at[slots].set(0, mode="drop"),
+        }
     if "ssm" not in state:
         layers = tuple(
             {**pool, "conv": pool["conv"].at[slots].set(tail.astype(pool["conv"].dtype), mode="drop")}

@@ -50,8 +50,11 @@ from mcpx.models.gemma.model import (
     pack_kv,
     pattern_rows,
     rms_norm,
+    scan_norm,
     sparse_index,
+    stack_at,
     stack_row,
+    walk_runs,
 )
 from mcpx.models.gemma.moe import add_forward_stats, add_layer_stats, moe_stats_init
 from mcpx.parallel.mesh import DATA_AXIS, MODEL_AXIS, _axis
@@ -541,6 +544,83 @@ def _hybrid_chunk(
     return (output_logits(params, cfg, x), pools) + extra
 
 
+def _scan_chunk(
+    params, cfg: GemmaConfig, x, positions, page_table, paged_kv, kv_window, q_lens, slots, *,
+    use_pallas, interpret, mesh, logits_at, active_cols, moe_stats,
+) -> tuple:
+    """``decode_chunk_paged`` for a ``J`` / ``Q`` pattern: the walk SCANNED
+    over each run of like layers (``model.walk_runs``), each layer its mixer
+    then the dense feed-forward. The page pools and the state pool's stacked
+    arrays are the scans' carry, indexed by the carried layer number: a ``J``
+    layer reads and writes its rows of the state pool by slot
+    (``ssm.selective_window``, through ``kernels/selective_scan.
+    selective_scan_window`` on the kernel route, where ONE device holds the
+    pool); a ``Q`` layer writes its unrotated keys and values into the pages
+    and attends through the ragged kernel, every query head on the layer's
+    ``n_kv_heads`` (1: MQA). No window of such a model is a prefill's: its
+    rows prefill whole (``GemmaConfig.suffix_route``)."""
+    from mcpx.models.gemma.ssm import selective_window
+
+    B, S, _ = x.shape
+    state = paged_kv["state"]
+    kernel = None
+    if use_pallas and (mesh is None or mesh.size == 1):
+        from mcpx.engine.kernels.selective_scan import selective_scan_window
+
+        kernel = functools.partial(selective_scan_window, interpret=interpret)
+    n_slots = state["n"].shape[0]
+    kept = state["n"][jnp.minimum(slots, n_slots - 1)]
+
+    def scan_layer(carry, j):
+        x, k_all, v_all, pool = carry
+        lp = stack_at(params["scan_layers"], j)
+        out, pool = selective_window(
+            scan_norm(x, lp["norm"], cfg), lp, cfg, pool, j, slots, q_lens, kept, kernel=kernel
+        )
+        return (mixer_feed_forward(x + out, lp, cfg), k_all, v_all, pool), None
+
+    def attn_layer(carry, j):
+        x, k_all, v_all, pool = carry
+        lp = stack_at(params["attn_layers"], j)
+        n = mixer_norm(x, lp["norm"], cfg)
+        q, k, v = hybrid_attention_inputs(n, lp, cfg)
+        k_all = _write_kv_window(k_all, j, k, kv_window)
+        v_all = _write_kv_window(v_all, j, v, kv_window)
+        qg = q.reshape(B, S, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+        if use_pallas:
+            attn = _ragged_kernel_on_mesh(
+                mesh, qg, k_all, v_all, page_table, positions, q_lens, j, interpret=interpret
+            )
+        else:
+            attn = ragged_paged_attention_reference(
+                qg, k_all, v_all, page_table, positions, q_lens, j, None
+            )
+        out = jnp.einsum(
+            "btf,fd->btd", attn.reshape(B, S, cfg.attn_out_width), lp["wo"],
+            preferred_element_type=jnp.float32,
+        )
+        return (mixer_feed_forward(x + out, lp, cfg), k_all, v_all, pool), None
+
+    pool = {k: v for k, v in state.items() if k != "n"}
+    (x, k_all, v_all, pool), _ = walk_runs(
+        cfg, {"J": scan_layer, "Q": attn_layer}, (x, paged_kv["k"], paged_kv["v"], pool)
+    )
+    x = mixer_norm(x, params["final_norm"], cfg)
+    # The window this forward left pending is kept as far as the caller says
+    # after it: nothing, until then.
+    live = (q_lens > 0) & (slots < n_slots)
+    n_new = state["n"].at[jnp.where(live, slots, n_slots)].set(0, mode="drop")
+    pools = {"k": k_all, "v": v_all, "state": {**pool, "n": n_new}}
+    extra = ()
+    if moe_stats:
+        extra = (add_forward_stats(cfg, moe_stats_init(cfg), positions + q_lens, q_lens, S),)
+    if active_cols is not None:
+        return (output_logits(params, cfg, x, subset=active_cols), pools) + extra
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    return (output_logits(params, cfg, x), pools) + extra
+
+
 def _packed_attend(
     qg: jax.Array,  # [B, S, K, G, hd]
     k_all: jax.Array,  # [K / pack, L, N, psz, pack x hd]
@@ -757,7 +837,7 @@ def decode_chunk_paged(
     # as int8 + per-row scales, layers dequantize per layer INSIDE the
     # scan body (see dequant_layer), unembeds scale on the output.
     # (a mixer + feed-forward pattern carries its residual stream in float32)
-    float_stream = cfg.mixer_ffn or cfg.conv_ffn
+    float_stream = cfg.dense_pattern or cfg.conv_ffn
     x = mixer_stream(params, cfg, tokens) if float_stream else embed_tokens(params, cfg, tokens)  # [B, S, D]
 
     pos_mat = positions[:, None] + jnp.arange(S, dtype=positions.dtype)  # [B, S]
@@ -769,6 +849,13 @@ def decode_chunk_paged(
             state_slots or (own, own),
             use_pallas=use_pallas, interpret=interpret, mesh=mesh, logits_at=logits_at,
             active_cols=active_cols, moe_stats=moe_stats, commit=commit, selection=selection,
+        )
+    if cfg.scan_ffn:
+        return _scan_chunk(
+            params, cfg, x, positions, page_table, paged_kv, kv_window, q_lens,
+            jnp.arange(B, dtype=jnp.int32),  # row i's state is slot i
+            use_pallas=use_pallas, interpret=interpret, mesh=mesh, logits_at=logits_at,
+            active_cols=active_cols, moe_stats=moe_stats,
         )
     if cfg.conv_ffn:
         return _conv_chunk(

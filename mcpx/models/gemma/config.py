@@ -195,6 +195,28 @@ class GemmaConfig:
     # init_state_pool``); ``A`` rotated GQA, q and k normed per head first
     # (``models/gemma/ssm.py``, ``model.py``). A ``head_dim`` of 64 lies two
     # KV heads to a 128-lane row of the pools (``kv_pack``).
+    # --- a layer that is a MIXER followed by the dense gated feed-forward, its
+    # walk SCANNED over each run of like layers (``layer_pattern`` of ``J`` and
+    # ``Q`` alone; two norms a layer, plain gains, a float32 residual stream,
+    # no position encoding anywhere): ``J`` a Mamba-1 SELECTIVE SCAN, ``[x |
+    # z] = n W_in`` (``inner = mamba_expand x d_model`` each), ``x`` through a
+    # causal depthwise convolution of ``conv_kernel`` taps with bias and a
+    # silu, ``[r | B | C] = x W_x`` (``mamba_dt_rank | ssm_state_size |
+    # ssm_state_size``, each RMS-normed under its own gain), ``dt =
+    # softplus(r W_dt + b_dt)``, and the recurrence ``h_t[n, c] = exp(dt_t[c]
+    # A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]``, ``y_t[c] = sum_n C_t[n]
+    # h_t[n, c] + D[c] x_t[c]``, ``out = (y (.) silu(z)) W_out``: the decay is a
+    # value a (state, channel) pair, so a chunk has no matrix form and the
+    # recurrence is WALKED (``models/gemma/ssm.py``, ``engine/kernels/
+    # selective_scan.py``); the state a row ``[ssm_state_size, inner]`` float32
+    # beside the convolution's last ``conv_kernel - 1`` inputs; ``Q`` unrotated
+    # attention, ``n_heads`` query heads on ``n_kv_heads`` KV heads (1: MQA).
+    # The two other alphabets of mixer + feed-forward layers keep their walks
+    # unrolled over a tuple of per-layer pool dicts; this one's pool arrays
+    # are STACKED a layer so a scan can index them, which is why it is an
+    # alphabet of its own and not two more letters of ``L`` / ``S``.
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -254,6 +276,23 @@ class GemmaConfig:
                     "a C/A layer_pattern is heads attention, rotated, with plain norm gains, no "
                     "gate, window, second norm, shared expert, latent or Mamba widths"
                 )
+        elif self.scan_ffn:
+            if len(self.layer_pattern) != self.n_layers:
+                raise ConfigError("layer_pattern: J and Q alone, one a layer")
+            sizes = (self.mamba_expand, self.mamba_dt_rank, self.ssm_state_size)
+            if "J" in self.layer_pattern and (min(sizes) < 1 or self.conv_kernel < 2):
+                raise ConfigError(
+                    "a J layer needs mamba_expand, mamba_dt_rank, ssm_state_size >= 1 and "
+                    "conv_kernel >= 2"
+                )
+            if self.latent or self.layer_types or self.n_experts or self.post_norms \
+                    or self.attn_gate or self.qk_norm or self.norm_plus_one or self.mamba_n_heads \
+                    or self.rope_full_layers or self.scale_embeddings or self.activation == "relu2":
+                raise ConfigError(
+                    "a J/Q layer_pattern is heads attention, unrotated (rope_full_layers off), "
+                    "with plain norm gains, unscaled embeddings, a dense feed-forward and no "
+                    "gate, q/k norm, window, second norm, experts, latent or Mamba-2 widths"
+                )
         elif self.hybrid:
             if set(self.layer_pattern) - set("ME*") or len(self.layer_pattern) != self.n_layers:
                 raise ConfigError("layer_pattern: one of M, E, * for each of n_layers layers")
@@ -279,6 +318,8 @@ class GemmaConfig:
                 )
         elif self.moe_latent_size or self.mamba_n_heads or self.activation == "relu2":
             raise ConfigError("the Mamba widths, moe_latent_size and relu2 belong to a layer_pattern")
+        if (self.mamba_expand or self.mamba_dt_rank) and not self.scan_ffn:
+            raise ConfigError("mamba_expand and mamba_dt_rank belong to a J/Q layer_pattern")
         if not self.mixer_ffn and (
             self.block_size or self.block_topk or self.block_window or self.embed_scale
             or self.residual_scale != 1.0 or self.logit_divisor != 1.0
@@ -360,6 +401,29 @@ class GemmaConfig:
         return bool(self.layer_pattern) and not set(self.layer_pattern) - set("CA")
 
     @property
+    def scan_ffn(self) -> bool:
+        """A ``layer_pattern`` of ``J`` / ``Q``: every layer a mixer (a Mamba-1
+        selective scan, or unrotated attention) followed by the dense
+        feed-forward, the walk scanned over each run of like layers."""
+        return bool(self.layer_pattern) and not set(self.layer_pattern) - set("JQ")
+
+    @property
+    def n_scan_layers(self) -> int:
+        return self.layer_pattern.count("J") if self.scan_ffn else 0
+
+    @property
+    def scan_inner(self) -> int:
+        """A ``J`` mixer's inner width: the channels of its recurrence."""
+        return self.mamba_expand * self.d_model
+
+    @property
+    def dense_pattern(self) -> bool:
+        """A ``layer_pattern`` with no routed expert anywhere (``L`` / ``S``,
+        ``J`` / ``Q``): a forward reads every leaf whole, and its counters are
+        the forward's own alone."""
+        return self.mixer_ffn or self.scan_ffn
+
+    @property
     def n_conv_layers(self) -> int:
         return self.layer_pattern.count("C") if self.conv_ffn else 0
 
@@ -392,7 +456,7 @@ class GemmaConfig:
     @property
     def n_recurrent_layers(self) -> int:
         """Layers that keep a state a row in the state pool."""
-        return self.n_mamba_layers + self.n_linear_layers + self.n_conv_layers
+        return self.n_mamba_layers + self.n_linear_layers + self.n_conv_layers + self.n_scan_layers
 
     @property
     def n_block_layers(self) -> int:
@@ -429,6 +493,8 @@ class GemmaConfig:
             return self.layer_pattern.count("S")
         if self.conv_ffn:
             return self.layer_pattern.count("A")
+        if self.scan_ffn:
+            return self.layer_pattern.count("Q")
         return self.layer_pattern.count("*") if self.hybrid else self.n_layers
 
     @property
@@ -447,6 +513,8 @@ class GemmaConfig:
             return self.n_heads * self.head_dim * self.head_dim * 4
         if self.conv_ffn:
             return 0  # no recurrent state array: ``conv_tail_bytes``
+        if self.scan_ffn:
+            return self.scan_inner * self.ssm_state_size * 4
         return self.mamba_inner * self.ssm_state_size * 4
 
     @property
@@ -647,6 +715,18 @@ class GemmaConfig:
                 self.n_conv_layers * conv + self.n_attn_layers * attn + 2 * D * self.n_layers
                 + (self.n_layers - n_sparse) * 3 * D * F + n_sparse * sparse_ff
             )
+            head = 0 if self.tie_embeddings else D * self.vocab_size
+            return self.vocab_size * D + layers + D + head
+        if self.scan_ffn:
+            I, N, R = self.scan_inner, self.ssm_state_size, self.mamba_dt_rank
+            ffn = 3 * D * F + 2 * D  # the feed-forward and the layer's two norms
+            scan = (
+                2 * D * I + I * (self.conv_kernel + 1) + I * (R + 2 * N)  # w_in, taps + bias, w_x
+                + R + 2 * N + R * I + I  # the three inner gains, w_dt, its bias
+                + I * N + I + I * D  # A_log, D_skip, w_out
+            )
+            attn = 2 * D * H * hd + 2 * D * K * hd  # q, o; k, v
+            layers = self.n_scan_layers * (scan + ffn) + self.n_attn_layers * (attn + ffn)
             head = 0 if self.tie_embeddings else D * self.vocab_size
             return self.vocab_size * D + layers + D + head
         if self.hybrid:

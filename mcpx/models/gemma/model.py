@@ -25,6 +25,7 @@ in-tree replacement.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -94,7 +95,7 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         specs = param_pspecs(cfg, mesh)
         stacks = (
             "layers", "dense_layers", "mamba_layers", "attn_layers", "linear_layers", "block_layers",
-            "conv_layers",
+            "conv_layers", "scan_layers",
         )
         by_name = {
             **specs.get("layers", {}),
@@ -193,6 +194,8 @@ def init_params(cfg: GemmaConfig, key: jax.Array, leaf_transform=None, mesh=None
         return _init_mixer_ffn(cfg, key, normal, sharding, t)
     if cfg.conv_ffn:
         return _init_conv_ffn(cfg, key, normal, sharding, t)
+    if cfg.scan_ffn:
+        return _init_scan_ffn(cfg, key, normal, sharding, t)
     if cfg.hybrid:
         return _init_hybrid(cfg, key, normal, sharding, t)
     Ls = cfg.n_sparse_layers
@@ -451,6 +454,96 @@ def _init_conv_ffn(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Par
                 as_type=jnp.float32,
             )
         params["layers"] = layers
+    return params
+
+
+def _init_scan_ffn(cfg: GemmaConfig, key: jax.Array, normal, sharding, t) -> Params:
+    """``init_params`` of a ``J`` / ``Q`` pattern: two stacks, a row a layer of
+    its kind in layer order, ``scan_layers`` (the Mamba-1 mixer: ``w_in`` [D,
+    2 I] the halves x | z, the taps ``conv_w`` [I, K] and their bias, ``w_x``
+    [I, R + 2 N], the three inner gains, ``w_dt`` [R, I] and its float32 bias,
+    ``A_log`` [N, I] and ``D_skip`` [I] float32, ``w_out``) and ``attn_layers``
+    (heads merged on the matmul's own axis), each layer with its two norms
+    (``norm`` before the mixer, ``mlp_norm`` before the feed-forward) and the
+    dense gated feed-forward. The mixer's scalars are drawn as the Mamba
+    family initialises them, so that a random stack's states neither vanish
+    nor saturate: ``A_log[n, c] = log(n + 1)`` (the state's N on the leading
+    axis: the pool's and the kernel's layout), ``D_skip`` 1, ``dt_bias`` the
+    inverse softplus of a step log-uniform in [time_step_min, time_step_max]
+    (floored at time_step_floor), the taps uniform in +-1 / sqrt(K), their
+    bias 0, every gain 1."""
+    dtype = jnp.dtype(cfg.dtype)
+    f32 = jnp.float32
+    D, H, K, hd, F, V = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.vocab_size
+    I, N, R, Kc = cfg.scan_inner, cfg.ssm_state_size, cfg.mamba_dt_rank, cfg.conv_kernel
+    Lj, Lq = cfg.n_scan_layers, cfg.n_attn_layers
+    fold = lambda i: jax.random.fold_in(key, 400 + i)
+
+    def ones(name, shape, stack, as_type=dtype):
+        return t(name, jnp.ones(shape, as_type, device=sharding(stack + name)))
+
+    def drawn(name, stack, fn, shape, k):
+        out = jax.jit(fn, static_argnames=("shape",), out_shardings=sharding(stack + name))
+        return t(name, out(k, shape=shape))
+
+    def shared(n, st, base):  # the leaves both kinds have
+        return {
+            "norm": ones("norm", (n, D), st),
+            "mlp_norm": ones("mlp_norm", (n, D), st),
+            "w_gate": normal("w_gate", fold(base), (n, D, F), D, stack=st),
+            "w_up": normal("w_up", fold(base + 1), (n, D, F), D, stack=st),
+            "w_down": normal("w_down", fold(base + 2), (n, F, D), F, stack=st),
+        }
+
+    params = {
+        "embed": normal("embed", fold(0), (V, D), D),
+        "final_norm": t("final_norm", jnp.ones((D,), dtype, device=sharding("final_norm"))),
+    }
+    if not cfg.tie_embeddings:
+        params["head"] = normal("head", fold(1), (D, V), D)
+    if Lj:
+        st = "scan_layers."
+        lo, hi, floor = cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor
+
+        def dt_bias(k, shape):
+            u = jax.random.uniform(k, shape, f32)
+            step = jnp.maximum(jnp.exp(u * (math.log(hi) - math.log(lo)) + math.log(lo)), floor)
+            return step + jnp.log(-jnp.expm1(-step))  # softplus^-1(step)
+
+        params["scan_layers"] = {
+            **shared(Lj, st, 10),
+            "w_in": normal("w_in", fold(2), (Lj, D, 2 * I), D, stack=st),
+            "conv_w": drawn(
+                "conv_w", st,
+                lambda k, shape: jax.random.uniform(k, shape, f32, -1.0, 1.0).astype(dtype) * Kc**-0.5,
+                (Lj, I, Kc), fold(3),
+            ),
+            "conv_b": t("conv_b", jnp.zeros((Lj, I), dtype, device=sharding(st + "conv_b"))),
+            "w_x": normal("w_x", fold(4), (Lj, I, R + 2 * N), I, stack=st),
+            "dt_norm": ones("dt_norm", (Lj, R), st),
+            "b_norm": ones("b_norm", (Lj, N), st),
+            "c_norm": ones("c_norm", (Lj, N), st),
+            "w_dt": normal("w_dt", fold(5), (Lj, R, I), R, stack=st),
+            "dt_bias": drawn("dt_bias", st, dt_bias, (Lj, I), fold(6)),
+            "A_log": drawn(
+                "A_log", st,
+                lambda k, shape: jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, shape[1] + 1, dtype=f32))[None, :, None], shape
+                ),
+                (Lj, N, I), fold(7),
+            ),
+            "D_skip": ones("D_skip", (Lj, I), st, f32),
+            "w_out": normal("w_out", fold(8), (Lj, I, D), I, stack=st),
+        }
+    if Lq:
+        st = "attn_layers."
+        params["attn_layers"] = {
+            **shared(Lq, st, 20),
+            "wq": normal("wq", fold(30), (Lq, D, H * hd), D, stack=st),
+            "wk": normal("wk", fold(31), (Lq, D, K * hd), D, stack=st),
+            "wv": normal("wv", fold(32), (Lq, D, K * hd), D, stack=st),
+            "wo": normal("wo", fold(33), (Lq, H * hd, D), H * hd, stack=st),
+        }
     return params
 
 
@@ -850,7 +943,7 @@ def _layer(
 # ------------------------------------------- a layer that is one thing alone
 def pattern_rows(cfg: GemmaConfig) -> list[tuple[str, int]]:
     """``layer_pattern`` as (kind, the layer's row in its kind's stack)."""
-    seen = dict.fromkeys("ME*LSCA", 0)
+    seen = dict.fromkeys("ME*LSCAJQ", 0)
     rows = []
     for kind in cfg.layer_pattern:
         rows.append((kind, seen[kind]))
@@ -937,7 +1030,8 @@ def gated_attention_out(attn: jax.Array, n: jax.Array, lp: dict, cfg: GemmaConfi
 
 
 def mixer_feed_forward(x: jax.Array, lp: dict, cfg: GemmaConfig) -> jax.Array:
-    """The second half of an ``L`` / ``S`` layer: x + scale x MLP(norm(x))."""
+    """The second half of an ``L`` / ``S`` layer, and of a ``J`` / ``Q`` one
+    (whose ``residual_scale`` is 1): x + scale x MLP(norm(x))."""
     return join_scaled(x, gated_mlp_float32(mixer_norm(x, lp["mlp_norm"], cfg), lp, cfg), cfg)
 
 
@@ -1167,6 +1261,98 @@ def _conv_forward(
     return out + ((stats,) if moe_stats else ()) + ((chosen,) if routing else ())
 
 
+# ------------- a selective scan or attention, then the dense feed-forward:
+# ------------- the walk SCANNED over each run of like layers
+def pattern_runs(cfg: GemmaConfig) -> list[tuple[str, int, int]]:
+    """``layer_pattern`` as its runs of like layers, ``(kind, the run's first
+    row in its kind's stack, one past its last)``: ``JJQJ`` is ``[("J", 0, 2),
+    ("Q", 0, 1), ("J", 2, 3)]``."""
+    runs: list[tuple[str, int, int]] = []
+    for kind, j in pattern_rows(cfg):
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1], j + 1)
+        else:
+            runs.append((kind, j, j + 1))
+    return runs
+
+
+def walk_runs(cfg: GemmaConfig, bodies: dict, carry):
+    """The walk over a ``J`` / ``Q`` pattern: each run of like layers is ONE
+    ``lax.scan`` of its kind's body over the run's rows, so a body is traced
+    and compiled once a run, not once a layer. ``bodies[kind](carry, row) ->
+    (carry, what the layer hands out)`` takes the layer's row in its kind's
+    stack as a TRACED number: it indexes the whole stack (and the state
+    pool's stacked arrays) by it, as a scan over the stack itself would, with
+    no slice of the stack ever made. -> (carry, {kind: [what each run handed
+    out, stacked over its rows]})."""
+    out: dict[str, list] = {kind: [] for kind in bodies}
+    for kind, lo, hi in pattern_runs(cfg):
+        carry, ys = lax.scan(bodies[kind], carry, jnp.arange(lo, hi, dtype=jnp.int32))
+        out[kind].append(ys)
+    return carry, out
+
+
+def scan_norm(x: jax.Array, gain: jax.Array, cfg: GemmaConfig) -> jax.Array:
+    """RMSNorm (a plain gain) of a ``J`` / ``Q`` pattern's float32 residual
+    stream as a ``J`` layer's mixer reads it: float32 as it is, unrounded
+    (``models/gemma/ssm.py``, "the selective scan", says why). Every other
+    reader of the stream (a ``Q`` layer, the feed-forward, the head) takes
+    ``mixer_norm``'s, rounded once to the activations' type."""
+    return rms_norm(x, gain, cfg.norm_eps, False, jnp.float32)
+
+
+def stack_at(stack: dict, j: jax.Array) -> dict:
+    """Row ``j`` (traced) of every leaf of a stack."""
+    return {k: lax.dynamic_index_in_dim(v, j, keepdims=False) for k, v in stack.items()}
+
+
+def _scan_forward(
+    params: Params, cfg: GemmaConfig, tokens, seq_lens, kv_cache, mask, logits_at,
+    use_pallas: bool = False, interpret: bool = False,
+) -> tuple:
+    """``forward`` for a ``J`` / ``Q`` pattern from an EMPTY state (the dense
+    prefill): the cache it returns holds ``k`` and ``v`` [Q layers, ...] and
+    ``ssm``: ``(the states [J layers, B, N, I], the tails [J layers, B, K - 1,
+    I])`` AT each row's length. ``use_pallas``: the recurrence through
+    ``kernels/selective_scan.selective_scan_prefill``."""
+    from mcpx.models.gemma.ssm import selective_prefill
+
+    B, T = tokens.shape
+    kernel = None
+    if use_pallas:
+        from mcpx.engine.kernels.selective_scan import selective_scan_prefill
+
+        kernel = functools.partial(selective_scan_prefill, interpret=interpret)
+
+    def scan_layer(x, j):
+        lp = stack_at(params["scan_layers"], j)
+        out, final = selective_prefill(scan_norm(x, lp["norm"], cfg), lp, cfg, seq_lens, kernel=kernel)
+        return mixer_feed_forward(x + out, lp, cfg), final
+
+    def attn_layer(x, j):
+        lp = stack_at(params["attn_layers"], j)
+        n = mixer_norm(x, lp["norm"], cfg)  # (the pages hold keys of the activations' type)
+        q, k, v = hybrid_attention_inputs(n, lp, cfg)
+        k_c = lax.dynamic_index_in_dim(kv_cache["k"], j, keepdims=False).at[:, :T].set(k.astype(kv_cache["k"].dtype))
+        v_c = lax.dynamic_index_in_dim(kv_cache["v"], j, keepdims=False).at[:, :T].set(v.astype(kv_cache["v"].dtype))
+        qg = q.reshape(B, T, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
+        attn = _attend_query_blocks(qg, k_c, v_c, mask, 1.0).reshape(B, T, cfg.attn_out_width)
+        out = jnp.einsum("btf,fd->btd", attn, lp["wo"], preferred_element_type=jnp.float32)
+        return mixer_feed_forward(x + out, lp, cfg), (k_c, v_c)
+
+    x, ys = walk_runs(cfg, {"J": scan_layer, "Q": attn_layer}, mixer_stream(params, cfg, tokens))
+    x = mixer_norm(x, params["final_norm"], cfg)
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]
+    join = lambda runs, i: jnp.concatenate([r[i] for r in runs])
+    cache = {"k": kv_cache["k"], "v": kv_cache["v"], "ssm": None}
+    if ys["Q"]:
+        cache["k"], cache["v"] = join(ys["Q"], 0), join(ys["Q"], 1)
+    if ys["J"]:
+        cache["ssm"] = (join(ys["J"], 0), join(ys["J"], 1))
+    return output_logits(params, cfg, x), cache
+
+
 def embed_tokens(params: Params, cfg: GemmaConfig, tokens: jax.Array) -> jax.Array:
     from mcpx.models.gemma.quant import embed_lookup
 
@@ -1225,8 +1411,13 @@ def forward(
         if live is None:
             raise ValueError("a layer_pattern model's dense forward is its prefill (prefill())")
         seq_lens = jnp.sum(live, axis=1).astype(jnp.int32)
-        if cfg.mixer_ffn:
-            out = _mixer_ffn_forward(params, cfg, tokens, seq_lens, kv_cache, mask, logits_at)
+        if cfg.dense_pattern:
+            if cfg.scan_ffn:
+                out = _scan_forward(
+                    params, cfg, tokens, seq_lens, kv_cache, mask, logits_at, use_pallas, interpret
+                )
+            else:
+                out = _mixer_ffn_forward(params, cfg, tokens, seq_lens, kv_cache, mask, logits_at)
             stats = None
             if moe_stats:
                 stats = add_forward_stats(cfg, moe_stats_init(cfg), seq_lens, seq_lens)

@@ -441,12 +441,12 @@ def linear_window(
 # other rounding of the step switched off, the rest of the step 0.013-0.016
 # without them). So: the layer's norm hands on float32, ``W_in`` and ``W_out``
 # read their float32 operand as TWO operands of the weights' type (its rounding
-# and what the rounding left, ``_dot_split``: one pass over the weights, twice
+# and what the rounding left, ``dot_split``: one pass over the weights, twice
 # the rows), ``u`` and the taps stay float32, and the tail (a slot's and a
 # page's: ``engine/kv_cache.init_state_pool``) holds ``u`` in float32, as the
 # other recurrent states of this repo are held. A value weighs the same
 # whether the convolution reads it out of its window or out of a tail.
-def _dot_split(x32: jax.Array, w: jax.Array) -> jax.Array:
+def dot_split(x32: jax.Array, w: jax.Array) -> jax.Array:
     """x32 [B, T, K] float32 times w [K, N] -> [B, T, N] float32 as
     accumulated. Weights narrower than float32 read ``x32`` as two operands of
     their own type, ``hi = round(x32)`` and ``lo = round(x32 - hi)``, stacked
@@ -468,7 +468,7 @@ def _dot_split(x32: jax.Array, w: jax.Array) -> jax.Array:
 def conv_inputs(n: jax.Array, lp: dict) -> tuple[jax.Array, jax.Array]:
     """n [B, T, D] float32 -> (u [B, T, D], c [B, T, D]), float32."""
     D = n.shape[-1]
-    bcx = _dot_split(n, lp["w_in"])
+    bcx = dot_split(n, lp["w_in"])
     return bcx[..., :D] * bcx[..., 2 * D :], bcx[..., D : 2 * D]
 
 
@@ -487,7 +487,7 @@ def short_conv(u: jax.Array, tail: jax.Array, w: jax.Array) -> jax.Array:
 
 def conv_out(v: jax.Array, c: jax.Array, lp: dict) -> jax.Array:
     """-> the mixer's output [B, T, D] as accumulated (float32)."""
-    return _dot_split(c * v, lp["w_out"])
+    return dot_split(c * v, lp["w_out"])
 
 
 def conv_prefill(n: jax.Array, lp: dict, cfg: GemmaConfig, seq_lens: jax.Array) -> tuple:
@@ -539,3 +539,170 @@ def conv_window(
             tail_at(u, tail, q_lens).astype(state["conv"].dtype), mode="drop"
         )}
     return conv_out(short_conv(u, tail, lp["conv_w"]), c, lp), new, tail, u
+
+
+# ------------------------------------------------------- the selective scan
+# A ``J`` layer of ``GemmaConfig.scan_ffn`` (Mamba-1), on its normed input n
+# [B, T, D] (I = ``scan_inner`` channels, N = ``ssm_state_size``, R =
+# ``mamba_dt_rank``, K = ``conv_kernel`` taps):
+#
+#   [x | z] = n W_in                            W_in [D, 2 I], x first
+#   x_t = silu(b + sum_k w[:, k] (.) x_{t - K + 1 + k})     ``causal_conv``
+#   [r | B | C] = x W_x                         W_x [I, R + 2 N]
+#   r, B, C = RMSNorm_R(r), RMSNorm_N(B), RMSNorm_N(C)      a gain each
+#   dt  = softplus(r W_dt + b_dt)               [I] float32
+#   h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+#   y_t[c]    = sum_n C_t[n] h_t[n, c] + D[c] x_t[c]        A = -exp(A_log)
+#   out = (y (.) silu(z)) W_out
+#
+# The decay is a value a (state, channel) pair: the weight of token s on
+# token t inside a chunk is a [T, T, N] tensor a CHANNEL, so the chunk has no
+# matrix form (``chunk_outputs`` / ``advance_state`` above need ONE scalar a
+# head) and the recurrence is WALKED, a token at a time, the state resident:
+# ``selective_walk`` here (a ``lax.scan`` over time; the CPU's and a mesh's
+# route) and ``engine/kernels/selective_scan.py`` on one device. The state is
+# kept ``[N, I]`` (the state's N on the sublanes, the channels on the lanes:
+# ``A_log`` is stored so too), float32, as ``dt`` and the decay are.
+#
+# **The mixer runs in float32 between its weight matrices**, as the short
+# convolution above and for its reason, only more so: ``y`` is a QUARTIC form of
+# ``x`` (``dt(x) x B(x) C(x)``, the three inner norms keeping ``B``, ``C`` and
+# ``r`` at unit scale whatever ``W_x`` gives), so a relative error in the
+# layer's input comes out about four times as large, and it compounds down a
+# stack of 26 such mixers. On the chip, all 28 layers at the published widths
+# (PERF.md section 6, PR 58; three seeds): with ``W_in``, ``W_x``, ``W_dt`` and
+# ``W_out`` on operands rounded ONCE to bfloat16 the logits' rms distance reads
+# 0.0356-0.0369 against ``reference.tol``'s 0.0265, not ``correct`` on any seed
+# (``models/jamba.py``'s ``mixer_in_bfloat16`` control); as here 0.0204-0.0227.
+# So: the layer's norm hands on float32 (``model.scan_norm``), and those four
+# read their float32 operand as TWO operands of the weights' type
+# (``dot_split``: one pass over the weights, twice the rows: free in a decode
+# window, whose weights stream once whatever the rows, and twice the
+# multiplier's work in a prefill). The convolution's inputs and outputs, a
+# slot's tail and its pending window are float32: a value weighs the same
+# whether it is read out of a window or out of the pool. The feed-forward
+# behind the mixer is NOT part of this: it reads the stream rounded once, as
+# every other block's does (in float32 too the step read 0.0018, and the
+# comparison passes without it: what no limit can hold is not kept).
+#
+# The state's rule is the Mamba-2 layers' word for word: a decode window is
+# walked from the stored state WITHOUT committing; its ``dt``, ``x``, ``B``
+# and the convolution's inputs stay PENDING in the pool, the caller writes how
+# many of its tokens the row kept, and the next forward first walks exactly
+# those into the state. A position whose ``dt`` is 0 decays nothing and adds
+# nothing.
+def selective_inputs(n: jax.Array, tail: jax.Array, lp: dict, cfg: GemmaConfig) -> tuple:
+    """n [B, T, D] float32 after ``tail`` [B, K - 1, I] -> (z [B, T, I], the
+    convolution's inputs [B, T, I], x after it [B, T, I], dt [B, T, I], B and
+    C [B, T, N]), all float32."""
+    from mcpx.models.gemma.model import rms_norm
+
+    I, N, R = cfg.scan_inner, cfg.ssm_state_size, cfg.mamba_dt_rank
+    f32 = jnp.float32
+    xz = dot_split(n, lp["w_in"])
+    pre, z = xz[..., :I], xz[..., I:]
+    x = causal_conv(pre, tail, lp, f32)
+    rbc = dot_split(x, lp["w_x"])
+    norm = lambda a, gain: rms_norm(a, gain, cfg.norm_eps, False, f32)
+    r = norm(rbc[..., :R], lp["dt_norm"])
+    b, c = norm(rbc[..., R : R + N], lp["b_norm"]), norm(rbc[..., R + N :], lp["c_norm"])
+    dt = jax.nn.softplus(dot_split(r, lp["w_dt"]) + lp["dt_bias"].astype(f32))
+    return z, pre, x, dt, b, c
+
+
+def selective_walk(
+    h: jax.Array, dt: jax.Array, x: jax.Array, b: jax.Array, c: "jax.Array | None", a_log: jax.Array
+) -> tuple:
+    """The recurrence over T positions from ``h`` [B, N, I] float32, a token a
+    step: dt and x [B, T, I], B and C [B, T, N], ``a_log`` [N, I] -> (y [B, T,
+    I] float32 before the skip, None where ``c`` is, h_T)."""
+    f32 = jnp.float32
+    a = -jnp.exp(a_log.astype(f32))
+    time_major = lambda t: jnp.moveaxis(t.astype(f32), 1, 0)
+
+    def step(h, t):
+        dt_t, x_t, b_t = t[:3]
+        h = jnp.exp(dt_t[:, None, :] * a) * h + b_t[:, :, None] * (dt_t * x_t)[:, None, :]
+        return h, None if c is None else jnp.sum(h * t[3][:, :, None], axis=1)
+
+    terms = (dt, x, b) if c is None else (dt, x, b, c)
+    h, y = lax.scan(step, h, tuple(time_major(t) for t in terms))
+    return None if c is None else jnp.moveaxis(y, 0, 1), h
+
+
+def selective_out(y: jax.Array, x: jax.Array, z: jax.Array, lp: dict) -> jax.Array:
+    """y [B, T, I] float32 -> the mixer's output [B, T, D] as accumulated: the
+    skip, the gate, ``W_out``."""
+    return dot_split((y + lp["D_skip"].astype(jnp.float32) * x) * jax.nn.silu(z), lp["w_out"])
+
+
+def selective_prefill(
+    n: jax.Array, lp: dict, cfg: GemmaConfig, seq_lens: jax.Array, *, kernel=None
+) -> tuple:
+    """The mixer over a padded prompt from an empty state: n [B, T, D] float32 -> (its
+    output [B, T, D] float32, (the state AT ``seq_lens`` [B, N, I], the tail
+    AT ``seq_lens`` [B, K - 1, I])). A pad position has ``dt`` 0. ``kernel``:
+    ``engine/kernels/selective_scan.selective_scan_prefill``, or None: the
+    same walk in jnp."""
+    Bsz, T, _ = n.shape
+    tail0 = jnp.zeros((Bsz, cfg.conv_kernel - 1, cfg.scan_inner), jnp.float32)
+    z, pre, x, dt, b, c = selective_inputs(n, tail0, lp, cfg)
+    dt = jnp.where(jnp.arange(T)[None, :, None] < seq_lens[:, None, None], dt, 0.0)
+    if kernel is None:
+        h0 = jnp.zeros((Bsz, cfg.ssm_state_size, cfg.scan_inner), jnp.float32)
+        y, h = selective_walk(h0, dt, x, b, c, lp["A_log"])
+    else:
+        y, h = kernel(dt, x, b, c, lp["A_log"], seq_lens)
+    return selective_out(y, x, z, lp), (h, tail_at(pre, tail0, seq_lens))
+
+
+def selective_window(
+    n: jax.Array,  # [B, S, D] the window's normed input, float32
+    lp: dict,
+    cfg: GemmaConfig,
+    pool: dict,  # the state pool (kv_cache.init_state_pool's fourth kind): every array [J layers, slots, ...]
+    layer: jax.Array,  # which J layer this is: a scan's carried number
+    slots: jax.Array,  # [B] each row's slot (out of range: a padding row, idle)
+    q_lens: jax.Array,  # [B] live window slots (0: an idle row, which changes nothing)
+    kept: jax.Array,  # [B] tokens of the PENDING window the row kept (pool["n"][slots])
+    *,
+    kernel=None,  # engine/kernels/selective_scan.selective_scan_window, or None: the same in jnp
+) -> tuple[jax.Array, dict]:
+    """One paged forward's window of a ``J`` layer -> (its output [B, S, D]
+    float32, the pool with this layer's rows moved). As ``mamba_window``: the
+    pending window's ``kept`` tokens are walked into the stored state and the
+    tail moved over them, that state is written back, the window's live slots
+    are walked from it WITHOUT committing and stay pending. An idle row's
+    slot is written nowhere."""
+    Bsz, S, _ = n.shape
+    W = pool["dt"].shape[2]
+    if S > W:
+        raise ValueError(f"a window of {S} slots, the state pool keeps {W} pending")
+    n_slots = pool["ssm"].shape[1]
+    q_lens = jnp.where(slots < n_slots, q_lens, 0)
+    own = jnp.minimum(slots, n_slots - 1)
+    in_window = jnp.arange(S)[None, :] < q_lens[:, None]
+    mine = lambda name: pool[name][layer, own]
+    # --- what the row left pending, masked to what it kept
+    p_dt = jnp.where(jnp.arange(W)[None, :, None] < kept[:, None, None], mine("dt"), 0.0)
+    p_x, p_b = mine("x"), mine("b")
+    tail = tail_at(mine("pre"), mine("conv"), kept)
+    # --- this window
+    z, pre, x, dt, b, c = selective_inputs(n, tail, lp, cfg)
+    dt = jnp.where(in_window[:, :, None], dt, 0.0)
+    at = jnp.where(q_lens > 0, slots, n_slots)  # an idle row's slot is written nowhere
+    if kernel is None:
+        _, h = selective_walk(pool["ssm"][layer, own], p_dt, p_x, p_b, None, lp["A_log"])
+        ssm = pool["ssm"].at[layer, at].set(h, mode="drop")
+        y, _ = selective_walk(h, dt, x, b, c, lp["A_log"])
+    else:
+        ssm, y = kernel(
+            pool["ssm"], layer, own, q_lens, p_dt, p_x, p_b, dt, x, b, c, lp["A_log"]
+        )
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, W - S), (0, 0)))
+    put = lambda name, new: pool[name].at[layer, at].set(new, mode="drop")
+    new = {
+        **pool, "ssm": ssm, "conv": put("conv", tail), "dt": put("dt", pad(dt)),
+        "pre": put("pre", pad(pre)), "x": put("x", pad(x)), "b": put("b", pad(b)),
+    }
+    return selective_out(y, x, z, lp), new

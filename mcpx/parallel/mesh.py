@@ -196,6 +196,22 @@ def _hybrid_pspecs(cfg: GemmaConfig, m, whole) -> dict[str, Any]:
         if not cfg.tie_embeddings:
             specs["head"] = P(None, m(cfg.vocab_size))
         return specs
+    if cfg.scan_ffn:
+        # Whole on every device: its one cell runs on one chip, and ONE KV head
+        # has nothing to split.
+        ffn = {"norm": whole(2), "mlp_norm": whole(2), "w_gate": whole(3), "w_up": whole(3), "w_down": whole(3)}
+        specs = {"embed": P(m(cfg.vocab_size), None), "final_norm": P(None)}
+        if cfg.n_scan_layers:
+            specs["scan_layers"] = {
+                **ffn, "w_in": whole(3), "conv_w": whole(3), "conv_b": whole(2), "w_x": whole(3),
+                "dt_norm": whole(2), "b_norm": whole(2), "c_norm": whole(2), "w_dt": whole(3),
+                "dt_bias": whole(2), "A_log": whole(3), "D_skip": whole(2), "w_out": whole(3),
+            }
+        if cfg.n_attn_layers:
+            specs["attn_layers"] = {**ffn, "wq": whole(3), "wk": whole(3), "wv": whole(3), "wo": whole(3)}
+        if not cfg.tie_embeddings:
+            specs["head"] = P(None, m(cfg.vocab_size))
+        return specs
     if cfg.mixer_ffn:
         both = {
             "norm": whole(2), "mlp_norm": whole(2), "wq": whole(3), "wk": whole(3), "wv": whole(3),

@@ -87,7 +87,7 @@ CELL = "olmo2-1b.distinct-closed"
 NOT_FED_HERE = {"device_op_share", "device_idle_share", "memory_in_use", "endpoint_spread",
                 "client_quantile", "mla_roofline", "index_roofline", "selected_roofline",
                 "ssm_state_roofline", "routed_experts_roofline", "linear_window_roofline",
-                "block_score_roofline", "attn_gathered_roofline"}
+                "block_score_roofline", "attn_gathered_roofline", "selective_scan_roofline"}
 LABELLED_SAMPLE = 'mcpx_engine_compiles_total{executable="admit"}'
 # The one metric a rehearsal leaves out by its NAME: the CPU backend gets no
 # persistent compilation cache (``utils/backend.py::enable_compilation_cache``),
@@ -135,6 +135,11 @@ BLOCK_CELL = "minicpm-sala.catalogue-2k-closed"
 # 64, the feed-forward dense then routed: its rows take radix hits (PR 56).
 CONV_CELL = "lfm2-24b-a2b.distinct-closed"
 
+# The cell whose layers are a Mamba-1 selective scan or one-KV-head attention,
+# each followed by the dense feed-forward, the walk scanned over runs of like
+# layers, every row prefilled whole (PR 58).
+SCAN_CELL = "jamba2-3b.wide-shortlist-closed"
+
 FED = _fed_in(CELL)
 FED_SPARSE = [m for m in _fed_in(SPARSE_CELL) if m not in FED]
 FED_MIXED = [m for m in _fed_in(MIXED_CELL) if m not in FED]
@@ -143,6 +148,7 @@ FED_INDEX = [m for m in _fed_in(INDEX_CELL) if m not in FED + FED_MIXED + FED_LA
 FED_STATE = [m for m in _fed_in(STATE_CELL) if m not in FED]
 FED_BLOCK = [m for m in _fed_in(BLOCK_CELL) if m not in FED]
 FED_CONV = [m for m in _fed_in(CONV_CELL) if m not in FED]
+FED_SCAN = [m for m in _fed_in(SCAN_CELL) if m not in FED]
 
 
 @pytest.fixture(scope="module")
@@ -191,6 +197,13 @@ def served_block(tmp_path_factory):
 @pytest.fixture(scope="module")
 def served_conv(tmp_path_factory):
     return _serve(CONV_CELL, tmp_path_factory)
+
+
+@pytest.fixture(scope="module")
+def served_scan(tmp_path_factory):
+    # As ``served_latent``: the attributes' names depend neither on the cell's
+    # 128-service shortlist nor on its 1,024 warm-up bucket.
+    return _serve(SCAN_CELL, tmp_path_factory, warmup_max_len=128, shortlist_top_k=8)
 
 
 def _serve(cell_name, tmp_path_factory, warmup_max_len=None, shortlist_top_k=None):
@@ -345,7 +358,7 @@ def test_eleven_start_up_metrics_read_one_sample_of_metrics_each():
     assert all(m["args"]["path"].count("=") <= 1 for m in STARTUP_METRICS)
     one_chip = {w["name"] for w in json.load(open(os.path.join(REPO, "BENCHMARK.json")))["workloads"]
                 if w["chips"] == 1}
-    assert set(_CELLS_OF["startup.weights_s"]) == one_chip and len(one_chip) == 9
+    assert set(_CELLS_OF["startup.weights_s"]) == one_chip and len(one_chip) >= 10
     assert all(_CELLS_OF[m["name"]] is None for m in STARTUP_METRICS if m["name"] != "startup.weights_s")
 
 
@@ -1096,3 +1109,95 @@ def test_the_conv_blocks_attributes_count_calls_tails_and_weights(served_conv):
     model = served_conv["costs"]["model"]
     assert model["params_held"] == cfg.n_params
     assert model["params_held"] - model["params_active_per_token"] == 8 * 6 * 3 * 256 * 128
+
+
+# --------------------------------------------- the selective-scan cell (PR 58)
+@pytest.mark.parametrize("metric", FED_SCAN, ids=[m["name"] for m in FED_SCAN])
+def test_the_scan_block_feeds_its_metrics(served_scan, metric):
+    """The Mamba-2 cell's two metrics that list this cell too: the names are
+    the same, so the metric files read here unedited."""
+    assert {m["name"] for m in FED_SCAN} == {"ssm.state_bytes_share", "engine.prefix_state_miss_share"}
+    v = served_scan["read"](metric["reader"], metric["args"])
+    assert v is not None and math.isfinite(v)
+    if metric["name"] == "ssm.state_bytes_share":
+        assert 0 < v < 1
+
+
+def test_the_scan_blocks_attributes_count_calls_slots_and_what_was_walked(served_scan):
+    """At the rehearsal size: 6 selective-scan layers among 8, a state of 16 x
+    512 float32 a row a layer. Every span attribute, counter, ``pallas.paths``
+    entry and ``/healthz`` field the cell's metric files and its roofline
+    reader's two forms read."""
+    spec = sys.modules["spec"]
+    cfg = spec.load_block("jamba", CHIP_DIR).rehearsal_config(3072)
+    Lj = cfg.n_scan_layers
+    assert (Lj, cfg.n_attn_layers, cfg.q_per_kv, cfg.ssm_slot_bytes) == (6, 2, 5, 16 * 512 * 4)
+    segments = _segments(served_scan)
+    assert segments
+    for sp in segments:
+        a = sp["attrs"]
+        assert a["ssm_row_calls"] % Lj == 0 and 0 < a["ssm_row_calls"] <= a["forwards"] * 8 * Lj
+        assert a["ssm_state_bytes"] == a["ssm_row_calls"] * cfg.ssm_slot_bytes * 2
+        assert a["ssm_row_calls"] <= a["ssm_tokens"] <= a["ssm_slots"] <= a["ssm_row_calls"] * 8
+        assert a["attn_row_calls"] * Lj == a["ssm_row_calls"] * 2  # TWO attention layers
+        assert a["kv_bytes_read"] == a["attn_ctx_tokens"] * (cfg.kv_bytes_per_token // 2)
+        # every leaf read whole a forward, the tied embedding among them
+        assert a["weight_bytes_read"] == a["forwards"] * (cfg.n_params * 2 + Lj * (16 * 512 + 2 * 512) * 2)
+        assert a["moe_tokens_routed"] == 0 and "conv_row_calls" not in a
+    # an admission's prefill WALKS its cohort: live tokens and A x T slots a J layer
+    prefills = [sp for tr in served_scan["ev"].traces for sp in tr.get("tree", []) if sp["name"] == "engine.prefill"]
+    assert prefills
+    for sp in prefills:
+        a = sp["attrs"]
+        assert a["scan_slots"] == a["cohort_bucket"] * 128 * Lj  # the prompts fit the 128 bucket
+        assert 0 < a["ssm_prefill_tokens"] <= a["scan_tokens"] <= a["scan_slots"] and a["scan_tokens"] % Lj == 0
+        assert a["ssm_state_bytes"] == a["cohort_bucket"] * Lj * cfg.ssm_slot_bytes
+    # what the roofline reader's two forms take from the spans (a device trace apart)
+    mod = spec.import_file(os.path.join(CHIP_DIR, "reader_files", "selective_scan_roofline.py"), "chip_reader_t_")
+    assert mod._segments(served_scan["ev"], "engine.prefill", ("scan_slots", "ssm_state_bytes"))
+    assert mod._segments(served_scan["ev"], "engine.segment", ("ssm_state_bytes",))
+    assert served_scan["read"]("selective_scan_roofline", {"regex": "selective_scan_window"}) is None  # no device trace here
+    profile = served_scan["health"]["engine_queue"]["worker_profile"]
+    assert {k for k in profile if k.startswith("prefix_state_")} == {"prefix_state_miss"}
+    metrics = served_scan["ev"].counters_after["/metrics"]
+    assert metrics['mcpx_engine_prefix_state_total{event="miss"}'] == profile["prefix_state_miss"] >= 0
+    assert served_scan["paths"]["prefill"]["dispatches"] == 0  # no suffix route: every row prefills whole
+    assert served_scan["kernel_paths"] == {"decode": 1, "prefill": 0, "ssm": 1}
+    ssm = served_scan["paths"]["ssm"]
+    assert ssm["engaged"] is True and ssm["dispatches"] == served_scan["paths"]["decode"]["dispatches"] > 0
+    pool = served_scan["health"]["engine_queue"]["state_pool"]
+    assert pool["slots"] == 8 and pool["state_bytes"] == Lj * 8 * cfg.ssm_slot_bytes < pool["bytes"]
+    model = served_scan["costs"]["model"]
+    assert model["params_held"] == model["params_active_per_token"] == cfg.n_params
+
+
+def test_the_scan_kernels_names_are_what_their_metrics_select():
+    """The four new metrics find the scan's two call forms by the names Mosaic
+    gives their ops, each its own form alone, and no other kernel's metric
+    finds either."""
+    import jax.numpy as jnp
+
+    sys.path.insert(0, REPO)
+    from mcpx.engine.kernels.selective_scan import selective_scan_prefill, selective_scan_window
+
+    regex = {m["name"]: m["args"]["regex"] for m in METRICS if "regex" in m["args"]}
+    f32, i32 = jnp.float32, jnp.int32
+    sd = jax.ShapeDtypeStruct
+    B, T, I, N = 2, 256, 512, 16
+    names = {}
+    text = jax.jit(selective_scan_prefill).trace(
+        sd((B, T, I), f32), sd((B, T, I), f32), sd((B, T, N), f32), sd((B, T, N), f32), sd((N, I), f32),
+        sd((B,), i32)).lower(lowering_platforms=("tpu",)).as_text()
+    (names["prefill"],) = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    text = jax.jit(selective_scan_window).trace(
+        sd((3, 4, N, I), f32), sd((), i32), sd((B,), i32), sd((B,), i32), sd((B, 8, I), f32), sd((B, 8, I), f32),
+        sd((B, 8, N), f32), sd((B, 8, I), f32), sd((B, 8, I), f32), sd((B, 8, N), f32), sd((B, 8, N), f32),
+        sd((N, I), f32)).lower(lowering_platforms=("tpu",)).as_text()
+    (names["window"],) = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    for form, other in (("prefill", "window"), ("window", "prefill")):
+        for kind in ("busy_share", "roofline"):
+            pat = regex[f"kernel.selective_scan_{form}_{kind}"]
+            assert re.search(pat, names[form]) and not re.search(pat, names[other])
+    for metric, pat in regex.items():
+        if "selective_scan" not in metric:
+            assert not any(re.search(pat, name) for name in names.values()), metric

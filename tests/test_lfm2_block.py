@@ -204,7 +204,7 @@ def test_the_short_convolution_is_the_definition_and_a_tail_continues_it():
 
 
 def test_a_float32_operand_read_as_two_bfloat16_operands_loses_under_a_part_in_2_to_the_14():
-    """``ssm._dot_split``: the conv mixer's two products read their float32
+    """``ssm.dot_split``: the conv mixer's two products read their float32
     operand as its bfloat16 rounding and what the rounding left, in ONE product
     over the weights. Against the float64 product: 200 times closer than the
     operand rounded once, and the remainder is NOT all zeros (an explicit
@@ -213,15 +213,15 @@ def test_a_float32_operand_read_as_two_bfloat16_operands_loses_under_a_part_in_2
     x = jnp.asarray(rng.normal(size=(2, 5, 256)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(256, 64)) / 16, jnp.bfloat16)
     exact = np.asarray(x, np.float64) @ np.asarray(w, np.float64)
-    split = np.asarray(ssm._dot_split(x, w), np.float64)
+    split = np.asarray(ssm.dot_split(x, w), np.float64)
     once = np.asarray(jnp.einsum("bte,ed->btd", x.astype(jnp.bfloat16), w, preferred_element_type=jnp.float32), np.float64)
     err = lambda a: np.sqrt(np.mean((a - exact) ** 2)) / np.std(exact)
     assert err(split) < 2.0**-14 and err(once) > 200 * err(split)
-    text = jax.jit(ssm._dot_split).lower(x, w).as_text()
+    text = jax.jit(ssm.dot_split).lower(x, w).as_text()
     assert "reduce_precision" in text
     # float32 weights (the tests' own configurations): one plain product
     w32 = w.astype(jnp.float32)
-    np.testing.assert_allclose(ssm._dot_split(x, w32), exact, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ssm.dot_split(x, w32), exact, rtol=1e-5, atol=1e-5)
 
 
 def test_the_router_is_trinity_minis_on_the_same_scores_but_for_its_two_constants():
