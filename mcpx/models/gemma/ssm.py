@@ -441,27 +441,57 @@ def linear_window(
 # other rounding of the step switched off, the rest of the step 0.013-0.016
 # without them). So: the layer's norm hands on float32, ``W_in`` and ``W_out``
 # read their float32 operand as TWO operands of the weights' type (its rounding
-# and what the rounding left, ``dot_split``: one pass over the weights, twice
-# the rows), ``u`` and the taps stay float32, and the tail (a slot's and a
-# page's: ``engine/kv_cache.init_state_pool``) holds ``u`` in float32, as the
-# other recurrent states of this repo are held. A value weighs the same
-# whether the convolution reads it out of its window or out of a tail.
+# and what the rounding left, ``dot_split``: twice the multiplier's work, which
+# is the precision's price, in the form the product's rows choose), ``u`` and
+# the taps stay float32, and the tail (a slot's and a page's:
+# ``engine/kv_cache.init_state_pool``) holds ``u`` in float32, as the other
+# recurrent states of this repo are held. A value weighs the same whether the
+# convolution reads it out of its window or out of a tail.
+
+# Rows of the operand (B x T) up to which a split product STACKS its two halves
+# along the rows of ONE product: the weights stream once, which is what such a
+# product waits for (a decode window: 8 x 8 rows). Past it the multiplier binds
+# and the stacking buys nothing and costs the HBM a [B, 2T, K] operand, a
+# [B, 2T, N] float32 result and the pass that adds its halves: the two halves
+# are two products, summed where the second accumulates. The multiplier's work
+# is the same in both forms (it is the precision's price); the rows in the HBM
+# were the form's. Chosen from the chip (TPU v5 lite; PERF.md section 6, PR 59:
+# a walk of 16 layers, us a layer, stacked / summed): W_in [2560, 10240] at 64
+# rows 120 / 190, at 256 191 / 205, at 512 335 / 341, at 1,024 790 / 633, at
+# 4,096 3,523 / 2,430; the convolution block's [2048, 6144] 77 / 117, 122 /
+# 131, 193 / 190, 352 / 326, 1,911 / 1,234. At 512 rows the forms tie on the
+# large matrices and the stacked one is fewer operations: lfm2's cell, whose
+# cohorts of four prefill 512 rows, read 1.5% slower with them summed.
+SPLIT_STACK_ROWS = 512
+
+
 def dot_split(x32: jax.Array, w: jax.Array) -> jax.Array:
     """x32 [B, T, K] float32 times w [K, N] -> [B, T, N] float32 as
     accumulated. Weights narrower than float32 read ``x32`` as two operands of
-    their own type, ``hi = round(x32)`` and ``lo = round(x32 - hi)``, stacked
-    along the rows of ONE product: the weights stream once, and what is lost
-    of ``x32`` is under 2^-16 of it."""
+    their own type, ``hi = round(x32)`` and ``lo = round(x32 - hi)``; what is
+    lost of ``x32`` is under 2^-16 of it. ``hi @ w + lo @ w`` in float32, in
+    the form the rows choose (``SPLIT_STACK_ROWS``)."""
     f32 = jnp.float32
+    product = lambda x: jnp.einsum("bte,ed->btd", x, w, preferred_element_type=f32)
     if w.dtype == f32:
-        return jnp.einsum("bte,ed->btd", x32, w, preferred_element_type=f32)
+        return product(x32)
     # (an explicit rounding: the TPU compiler drops a cast to bfloat16 and back,
     # ``xla_allow_excess_precision``, and ``lo`` would be all zeros)
     info = jnp.finfo(w.dtype)
     hi32 = lax.reduce_precision(x32, exponent_bits=info.nexp, mantissa_bits=info.nmant)
     hi, lo = hi32.astype(w.dtype), (x32 - hi32).astype(w.dtype)
-    T = x32.shape[1]
-    y = jnp.einsum("bte,ed->btd", jnp.concatenate([hi, lo], axis=1), w, preferred_element_type=f32)
+    B, T, _ = x32.shape
+    if B * T > SPLIT_STACK_ROWS:
+        # The halves are made ONCE and handed to both products as they are: left
+        # to choose, XLA recomputes the operand's producer (a layer's norm, the
+        # gate) and the rounding inside each product's prologue, and on a v5e
+        # that program's scanned run of layers never came back from the device
+        # at 4,096 rows (a cohort of 4 at the 1,024 bucket, of 8 at 512: PERF.md
+        # section 6, PR 59); with the operands pinned every bucket runs, at the
+        # same speed.
+        hi, lo = lax.optimization_barrier((hi, lo))
+        return product(hi) + product(lo)
+    y = product(jnp.concatenate([hi, lo], axis=1))
     return y[:, :T] + y[:, T:]
 
 
@@ -576,14 +606,15 @@ def conv_window(
 # (``models/jamba.py``'s ``mixer_in_bfloat16`` control); as here 0.0204-0.0227.
 # So: the layer's norm hands on float32 (``model.scan_norm``), and those four
 # read their float32 operand as TWO operands of the weights' type
-# (``dot_split``: one pass over the weights, twice the rows: free in a decode
-# window, whose weights stream once whatever the rows, and twice the
-# multiplier's work in a prefill). The convolution's inputs and outputs, a
-# slot's tail and its pending window are float32: a value weighs the same
-# whether it is read out of a window or out of the pool. The feed-forward
-# behind the mixer is NOT part of this: it reads the stream rounded once, as
-# every other block's does (in float32 too the step read 0.0018, and the
-# comparison passes without it: what no limit can hold is not kept).
+# (``dot_split``: twice the multiplier's work, free in a decode window, whose
+# weights stream once whatever the rows, and what the precision costs a
+# prefill; nothing of twice the rows is written there). The convolution's
+# inputs and outputs, a slot's tail and its pending window are float32: a
+# value weighs the same whether it is read out of a window or out of the pool.
+# The feed-forward behind the mixer is NOT part of this: it reads the stream
+# rounded once, as every other block's does (in float32 too the step read
+# 0.0018, and the comparison passes without it: what no limit can hold is not
+# kept).
 #
 # The state's rule is the Mamba-2 layers' word for word: a decode window is
 # walked from the stored state WITHOUT committing; its ``dt``, ``x``, ``B``

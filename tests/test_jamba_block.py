@@ -732,6 +732,18 @@ def test_compiled_for_v5e_the_scan_and_the_one_head_attention_at_the_published_w
     assert "tpu_custom_call" in attn.as_text()
 
 
+def _published(block, replicated):
+    """The cell's configuration as shapes on the described chip -> (cfg, the
+    parameter tree, a leaf's shape there, an int32 shape there)."""
+    with open(os.path.join(CHIP_DIR, "configs", "jamba2-3b.json")) as f:
+        config = json.load(f)
+    spec = _by_path("chip_harness_spec_jamba_t", os.path.join(CHIP_DIR, "spec.py"))
+    cfg = block.model_config(spec.model_keys(config), 3072)
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated)
+    params = jax.tree.map(sd, jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    return cfg, params, sd, lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=replicated)
+
+
 def test_compiled_for_v5e_the_segments_forwards_copy_no_stacked_pool_array(one_v5e, block):
     """The program the chip runs, not a loop of the kernel alone: a segment's
     forwards (``decode_chunk_paged`` at the published widths, all 28 layers, 8
@@ -745,16 +757,10 @@ def test_compiled_for_v5e_the_segments_forwards_copy_no_stacked_pool_array(one_v
     layout where the executable is entered and left (13 MB a SEGMENT), and
     nowhere inside a forward."""
     mesh, replicated = one_v5e
-    with open(os.path.join(CHIP_DIR, "configs", "jamba2-3b.json")) as f:
-        config = json.load(f)
-    spec = _by_path("chip_harness_spec_jamba_t", os.path.join(CHIP_DIR, "spec.py"))
-    cfg = block.model_config(spec.model_keys(config), 3072)
+    cfg, params, sd, ints = _published(block, replicated)
     B, S, n_forwards = 8, W, 4
-    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated)
-    params = jax.tree.map(sd, jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
     pools = jax.tree.map(sd, jax.eval_shape(
         lambda: {**init_paged_kv(cfg, 1025, 16), "state": init_state_pool(cfg, B, W)}))
-    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=replicated)
 
     def segment(params, window, pos, table, pools, q_lens):
         def forward(carry, _):
@@ -771,6 +777,8 @@ def test_compiled_for_v5e_the_segments_forwards_copy_no_stacked_pool_array(one_v
         segment, params, ints(B, S), ints(B), ints(B, 128), pools, ints(B), donate_argnums=(4,)
     ).as_text()
     assert text.count("selective_scan_window") >= 3 and "ragged" in text  # a call a Mamba RUN, not a layer
+    # 8 x 8 rows: a split product stacks its halves, the weights stream once (ssm.SPLIT_STACK_ROWS)
+    assert re.search(rf"f32\[{B},{2 * S},{2 * cfg.scan_inner}\]", text)
     copied = lambda shape: re.findall(rf"^.*f32\[{shape}\][^\n]* copy(?:-start|-done)?\(.*$", text, re.M)
     state = pools["state"]
     dims = lambda a: ",".join(str(n) for n in a.shape)
@@ -780,3 +788,26 @@ def test_compiled_for_v5e_the_segments_forwards_copy_no_stacked_pool_array(one_v
     for name in ("conv", "b"):
         moved = copied(dims(state[name]))
         assert moved and all(line in entry for line in moved), (name, [m[:120] for m in moved if m not in entry])
+
+
+@pytest.mark.parametrize("A", [1, 4])
+def test_compiled_for_v5e_the_prefill_writes_nothing_of_twice_its_rows(one_v5e, block, A):
+    """The admission prefill at the published widths and the cell's bucket
+    (one row and a cohort of four at 1,024 slots), compiled for a described
+    v5e: a split product past ``ssm.SPLIT_STACK_ROWS`` rows is two products
+    whose sum XLA makes where the second accumulates. What the chip's trace
+    showed of the stacked form there (PERF.md section 6, PR 59: ``fusion
+    f32[4,2048,10240]``, the ``slice_add_fusion`` that read it back to add its
+    halves, 84 MB written a row a layer for ``W_in``'s 42 MB of result) is in
+    no operation of the optimised program: no array of 2,048 rows, float32 or
+    bfloat16, and no fusion that slices to add."""
+    cfg, params, _, ints = _published(block, one_v5e[1])
+    T = 1024
+
+    def admit(params, tokens, lens):
+        return prefill(params, cfg, tokens, lens, init_kv_cache(cfg, A, T), last_only=True, use_pallas=True)
+
+    text = _compile_uncached(admit, params, ints(A, T), ints(A)).as_text()
+    assert text.count("selective_scan_prefill") >= 3  # a call a Mamba RUN
+    assert not re.findall(rf"(?:f32|bf16)\[(?:{A},)?{2 * T},\d+\]", text) and "slice_add_fusion" not in text
+    assert re.search(rf"f32\[(?:{A},)?{T},{2 * cfg.scan_inner}\]", text)  # W_in's product, T rows
