@@ -4,6 +4,9 @@
 from __future__ import annotations
 
 import contextlib
+import functools
+import importlib.util
+import sys
 from typing import Any
 
 from mcpx.orchestrator.transport import LocalTransport, TransportError
@@ -67,6 +70,59 @@ def release_prefix_cache(eng) -> None:
     eng.config.engine.prefix_cache_entries = 0
     eng._evict_prefixes()
     eng._prefix_cache.check_invariants()
+
+
+def by_path(name: str, path: str):
+    """The module of one ``.py`` file outside the package (a file of
+    ``benchmarks/chip``), imported under ``name`` and never copied."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module  # its dataclasses look their module up
+    spec.loader.exec_module(module)
+    return module
+
+
+def one_device():
+    """A 1 x 1 mesh over the first virtual CPU device."""
+    import jax
+
+    from mcpx.parallel.mesh import make_mesh
+
+    return make_mesh(data=1, model=1, devices=jax.devices()[:1])
+
+
+@functools.cache
+def params_of(cfg, seed: int = 0):
+    """``init_params(cfg, PRNGKey(seed))``, drawn once a distinct
+    configuration for the process: no test writes to a params tree, and the
+    eager draw compiles every leaf's shape again (seconds a call)."""
+    import jax
+
+    from mcpx.models.gemma.model import init_params
+
+    return init_params(cfg, jax.random.PRNGKey(seed))
+
+
+@functools.cache
+def compiled():
+    """-> (``prefill``, ``decode_chunk_paged``), each under ONE ``jax.jit``
+    for the process: the configuration, the mesh and the route are static,
+    the params an ARGUMENT (a jit that closes over them folds them in as
+    constants and is compiled again by every case), so cases whose shapes
+    agree share an executable, and a body that ran op by op is one program.
+    Pools and state are arguments too: nothing of a case reaches the next.
+    Not for a case that patches what these trace through (the executable of
+    another case would answer)."""
+    import jax
+
+    from mcpx.engine.paged_decode import decode_chunk_paged
+    from mcpx.models.gemma.model import prefill
+
+    route = ("use_pallas", "interpret", "moe_stats", "routing")
+    return (
+        jax.jit(prefill, static_argnums=(1,), static_argnames=("last_only", *route)),
+        jax.jit(decode_chunk_paged, static_argnums=(1,), static_argnames=("mesh", "selection", "commit", *route)),
+    )
 
 
 @contextlib.contextmanager

@@ -4,14 +4,13 @@ gated, QK-normed attention that rotates only in its window layers, a norm on
 each branch's output. CPU, small sizes; the plain reference is the
 benchmark's block module (``benchmarks/chip/models/afmoe.py``), imported by
 path, and the comparison is the one that decides a benchmark run's
-``correct`` (``benchmarks/chip/reference.py``)."""
+``correct`` (``benchmarks/chip/reference.py``),
+run with its controls in ``tests/test_afmoe_rehearsal.py`` beside the rehearsal child."""
 
 import dataclasses
 import hashlib
-import importlib.util
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,32 +24,19 @@ from mcpx.engine.paged_decode import decode_chunk_paged
 from mcpx.models.gemma import moe
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import (
-    apply_rope, feed_forward_residual, gated_mlp, init_kv_cache, init_params, layer_kinds, prefill,
+    apply_rope, feed_forward_residual, gated_mlp, init_kv_cache, layer_kinds, prefill,
 )
 from mcpx.parallel.mesh import make_mesh, param_pspecs
-from tests.helpers import grouped_against_loop
+from tests.helpers import by_path, grouped_against_loop, params_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
 PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
 
 
-def _by_path(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = module
-    spec.loader.exec_module(module)
-    return module
-
-
 @pytest.fixture(scope="module")
 def block():
-    return _by_path("chip_block_afmoe_t", os.path.join(CHIP_DIR, "models", "afmoe.py"))
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return _by_path("chip_harness_reference_afmoe_t", os.path.join(CHIP_DIR, "reference.py"))
+    return by_path("chip_block_afmoe_t", os.path.join(CHIP_DIR, "models", "afmoe.py"))
 
 
 def small(**kw):
@@ -69,7 +55,7 @@ def small(**kw):
 # ------------------------------------------------------------ configuration
 def test_the_tree_has_two_stacks_and_the_count_is_the_trees():
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     assert set(params) == {"embed", "dense_layers", "layers", "final_norm", "head"}
     dense, sparse = params["dense_layers"], params["layers"]
     assert dense["w_gate"].shape == (2, 64, 128) and sparse["w_gate"].shape == (6, 8, 64, 32)
@@ -162,7 +148,7 @@ def test_every_leaf_has_a_spec_and_the_new_ones_stay_whole(mesh_shape):
         assert all(ax is None for ax in specs["layers"][name]), name
     assert specs["dense_layers"]["wq"] == specs["layers"]["wq"]
     # the same bits whatever the mesh
-    alone = init_params(cfg, jax.random.PRNGKey(0))
+    alone = params_of(cfg)
     for a, b in zip(jax.tree.leaves(alone), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -174,7 +160,7 @@ def test_the_bias_chooses_and_weighs_nothing():
     this scale the bias changes a stated share of the choices."""
     cfg = small()
     x = jax.random.normal(jax.random.PRNGKey(2), (256, 64), jnp.float32)
-    params = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    params = params_of(cfg)["layers"]
     router, bias = params["router"][1], params["router_bias"][1]
     chosen, w = moe.route(x, router, cfg, bias)
     s = 1 / (1 + np.exp(-(np.asarray(x, np.float64) @ np.asarray(router, np.float64))))
@@ -201,7 +187,7 @@ def test_past_the_ridge_the_grouped_form_computes_what_the_loop_does(case):
     in the choice alone, so a bias of +-10 steers every token's choice and
     weighs nothing."""
     cfg = small()
-    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    layers = params_of(cfg)["layers"]
     experts = {k: layers[k] for k in moe.EXPERT_LEAVES}
     router, bias = layers["router"][2], layers["router_bias"][2]
     h = jax.random.normal(jax.random.PRNGKey(12), (4, 96, 64), jnp.float32)
@@ -242,7 +228,7 @@ def test_the_shares_add_up_with_the_shared_expert_counted_once():
     the three partial results, less the shared expert's twice, sum to the
     uncut layer's; the counters add up as they are."""
     cfg = small(post_norms=False)
-    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    layers = params_of(cfg)["layers"]
     lp = {k: v[1] for k, v in layers.items() if k not in moe.EXPERT_LEAVES}
     experts = {k: layers[k] for k in moe.EXPERT_LEAVES}
     h = jax.random.normal(jax.random.PRNGKey(5), (3, 5, 64), jnp.float32)
@@ -265,92 +251,12 @@ def test_the_shares_add_up_with_the_shared_expert_counted_once():
 
 def test_weight_bytes_are_the_leaves_a_forward_reads():
     cfg = small(dtype="bfloat16")
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     expert, rest = moe.forward_weight_bytes(cfg, params)
     assert expert == 3 * 64 * 32 * 2
     every = sum(a.nbytes for a in jax.tree.leaves(params))
     assert rest == every - 6 * 8 * expert - params["embed"].nbytes  # untied: the table is gathered by row
     assert rest > params["head"].nbytes + sum(a.nbytes for a in jax.tree.leaves(params["dense_layers"]))
-
-
-# ------------------------------------------------ the comparison, and controls
-def _compare(block, reference, prog=None, control="", **kw):
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    cfg = block.rehearsal_config(3072)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    return reference.compare_with_engine_step(
-        block, params, prog or cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 36, interpret=True,
-        page_size=16, rows=4, pages_per_row=4, prefill_len=48, n_decode=3, control=control, **kw,
-    ), cfg, params
-
-
-@pytest.mark.parametrize("path", ["kernel", "jnp"])
-def test_prefill_then_paged_decode_matches_the_reference(block, reference, path, monkeypatch):
-    """Dense prefill committed to pages, then paged decode one token at a
-    time (the interpreted kernel; the jnp route beside it), over two periods
-    of the layer pattern behind the two dense layers, contexts past the
-    window of 8: logits against the block's plain float32 reference, through
-    the comparison that decides ``correct``, under the step's routing."""
-    if path == "jnp":
-        import mcpx.engine.paged_decode as paged
-
-        monkeypatch.setattr(
-            paged, "decode_chunk_paged",
-            lambda *a, **kw: decode_chunk_paged(*a, **{**kw, "use_pallas": False}),
-        )
-    out, cfg, params = _compare(block, reference)
-    assert out["ok"] and out["positions"] == 16, out
-    assert (out["tol_rms"], out["tol_max"]) == reference.tol(8) == (0.02, 0.12)
-    assert min(out["prompt_lens"]) >= 9 and 0 < out["rms_rel_err"] < out["max_rel_err"]
-    read = block.routing_readings(params, dataclasses.asdict(cfg))
-    assert len(read) == 4 and max(r["distance"] for r in read) < block.MARGIN
-    # every position the step ran, in each of the 6 SPARSE layers
-    assert sum(r["checked"] for r in read) == 6 * (sum(out["prompt_lens"]) + 4 * 3)
-
-
-def _bias_in_the_weights(x, router, cfg, bias=None):
-    """The mistake the bias's control makes: weights from s + b."""
-    scores = jax.nn.sigmoid(jnp.einsum("td,de->te", x, router, preferred_element_type=jnp.float32))
-    w, chosen = jax.lax.top_k(scores + bias, cfg.n_experts_per_tok)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), w * cfg.router_scale
-
-
-CONTROLS = {
-    "no_output_gate": dict(attn_gate=False),
-    "no_qk_norm": dict(qk_norm=False),
-    "full_layers_rotated": dict(rope_full_layers=True),
-    "post_branch_norms_dropped": dict(post_norms=False),
-    "softmax_for_sigmoid": dict(router_scoring="softmax", router_bias_scale=0.0, router_scale=1.0),
-    "bias_in_the_weights": "route",
-    "route_scale_1": dict(router_scale=1.0),
-    "shared_expert_dropped": dict(d_shared_expert=0),
-    "embeddings_unscaled": dict(scale_embeddings=False),
-    "int8_weights": "int8-weights",
-}
-
-
-@pytest.mark.parametrize("control", list(CONTROLS))
-def test_a_step_that_leaves_a_part_out_fails_the_comparison(block, reference, control, monkeypatch):
-    """Each part of the block taken out of (or put wrongly into) the
-    PROGRAM's step alone: the reference keeps it, and the comparison that
-    passes the sound step does not pass this one."""
-    what = CONTROLS[control]
-    cfg = block.rehearsal_config(3072)
-    if what == "route":
-        monkeypatch.setattr(moe, "route", _bias_in_the_weights)
-        out, _, _ = _compare(block, reference)
-    elif isinstance(what, str):
-        out, _, _ = _compare(block, reference, control=what)
-    else:
-        out, _, _ = _compare(block, reference, prog=dataclasses.replace(cfg, **what))
-    assert not out["ok"], out
-
-
-def test_without_the_steps_routing_a_sound_step_fails(block, reference, monkeypatch):
-    monkeypatch.setitem(block.CONTROLS, "follow_step_routing", False)
-    out, _, _ = _compare(block, reference)
-    assert not out["ok"]
 
 
 # ------------------------------------------- the served path, at every length
@@ -456,7 +362,7 @@ PINNED = {
 
 
 def _pinned_step(cfg):
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     B, T, lens = 3, 32, jnp.asarray([20, 9, 14])
     table = jnp.asarray(1 + np.arange(B * 4, dtype=np.int32).reshape(B, 4))
     rng = np.random.default_rng(36)

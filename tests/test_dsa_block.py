@@ -9,10 +9,8 @@ routing. CPU, small sizes; the plain reference is the benchmark's block module
 import asyncio
 import dataclasses
 import hashlib
-import importlib.util
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -33,23 +31,17 @@ from mcpx.models.gemma.model import (
     feed_forward_residual, gated_mlp, index_scores, init_kv_cache, init_params, prefill, select_top,
 )
 from mcpx.parallel.mesh import make_mesh, param_pspecs
+from tests.helpers import by_path, compiled, one_device, params_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
+jit_prefill, jit_chunk = compiled()  # one executable a (configuration, route, shapes): tests/helpers.py
 TOPK = 32
-
-
-def _by_path(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = module
-    spec.loader.exec_module(module)
-    return module
 
 
 @pytest.fixture(scope="module")
 def block():
-    return _by_path("chip_block_dsa_t", os.path.join(CHIP_DIR, "models", "dsa.py"))
+    return by_path("chip_block_dsa_t", os.path.join(CHIP_DIR, "models", "dsa.py"))
 
 
 def small(**kw):
@@ -74,7 +66,7 @@ def small(**kw):
 def test_published_counts_of_the_cut(block):
     with open(os.path.join(CHIP_DIR, "configs", "deepseek-v3.2-exp.json")) as f:
         config = json.load(f)
-    spec = _by_path("chip_harness_spec_dsa_t", os.path.join(CHIP_DIR, "spec.py"))
+    spec = by_path("chip_harness_spec_dsa_t", os.path.join(CHIP_DIR, "spec.py"))
     cfg = block.model_config(spec.model_keys(config), 3072)
     assert cfg.n_params == 5_399_488_256 and "5.399 B" in config["params"]
     assert (cfg.n_layers, cfg.n_dense_layers, cfg.n_experts, cfg.n_experts_held) == (6, 1, 256, 16)
@@ -137,9 +129,9 @@ def compared(block):
     kernels interpreted), three decoded positions each, the dense prefill (the
     selection as a mask) over the same rows, and the plain reference."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     dims = dataclasses.asdict(cfg)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
     B, T, psz, pages = len(CONTEXTS), 112, 16, 7
     block.CHUNK = 24
     rng = np.random.default_rng(44)
@@ -202,9 +194,9 @@ def test_the_reference_under_its_own_selection_agrees_here_and_a_step_that_reads
     following changes nothing; a step with its selection left out reads keys
     far under the threshold, and the check says so."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     dims = dataclasses.asdict(cfg)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
     tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, (1, 64)), jnp.int32)
     table = jnp.asarray(1 + np.arange(5, dtype=np.int32).reshape(1, 5))  # wider than any context
     block.CHUNK = 64
@@ -289,23 +281,23 @@ def test_at_a_context_the_selection_covers_the_logits_are_the_index_less_blocks_
     selection at all; a wider one selects and finds nothing to drop)."""
     cfg = small()
     plain = dataclasses.replace(cfg, index_n_heads=0, index_head_dim=0, index_topk=0)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     index_leaves = {"w_qi", "w_ki", "ki_norm", "ki_norm_bias", "w_wi"}
     strip = lambda stack: {k: v for k, v in stack.items() if k not in index_leaves}
     bare = {**params, "layers": strip(params["layers"]), "dense_layers": strip(params["dense_layers"])}
-    drawn = init_params(plain, jax.random.PRNGKey(0))
+    drawn = params_of(plain)
     assert all((a == b).all() for a, b in zip(jax.tree.leaves(bare), jax.tree.leaves(drawn)))
     B, T, lens = 2, TOPK, jnp.asarray([TOPK - 8, 9])
     toks = jnp.asarray(np.random.default_rng(1).integers(0, cfg.vocab_size, (B, T)), jnp.int32)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
 
     def run(c, p, pages, use_pallas):
         table = jnp.asarray(1 + np.arange(B * pages, dtype=np.int32).reshape(B, pages))
-        last, dense = prefill(p, c, toks, lens, init_kv_cache(c, B, T), last_only=True)
+        last, dense = jit_prefill(p, c, toks, lens, init_kv_cache(c, B, T), last_only=True)
         pools = commit_prefill_to_pages(init_paged_kv(c, 1 + B * pages, 16), dense, table, lens, 16)
         window = toks[:, :8]
-        step, _ = decode_chunk_paged(p, c, window, lens, table, pools, use_pallas=use_pallas, interpret=True,
-                                     q_lens=jnp.asarray([8, 3]), mesh=mesh)
+        step, _ = jit_chunk(p, c, window, lens, table, pools, use_pallas=use_pallas, interpret=True,
+                            q_lens=jnp.asarray([8, 3]), mesh=mesh)
         return np.asarray(last), np.asarray(step)
 
     for pages in (2, 4):  # 32 keys: no selection traced; 64: traced, nothing to drop
@@ -325,8 +317,8 @@ def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(path):
     pad token at position 0 over the null page) to 4 rows and to 8: the same
     last logits and the same pools (the index keys ride in them)."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    params = params_of(cfg)
+    mesh = one_device()
     psz, p_max, T, n_pages = 16, 8, 32, 1 + 3 + 3 * 5
     rng = np.random.default_rng(57)
     head = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 48)), jnp.int32)
@@ -334,7 +326,7 @@ def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(path):
 
     def run(A):
         pools = init_paged_kv(cfg, n_pages, psz)
-        _, pools = decode_chunk_paged(
+        _, pools = jit_chunk(
             params, cfg, head, jnp.zeros((1,), jnp.int32), jnp.asarray([[1, 2, 3, 0, 0, 0, 0, 0]], jnp.int32),
             pools, use_pallas=path == "kernel", interpret=True, mesh=mesh, q_lens=jnp.asarray([48]))
         tokens, lens, pos = np.zeros((A, T), np.int32), np.ones((A,), np.int32), np.zeros((A,), np.int32)
@@ -342,7 +334,7 @@ def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(path):
         for b, o in enumerate(own):
             tokens[b, : len(o)], lens[b], pos[b] = o, len(o), 48
             table[b, :3], table[b, 3:] = [1, 2, 3], 4 + 5 * b + np.arange(5)
-        last, pools = decode_chunk_paged(
+        last, pools = jit_chunk(
             params, cfg, jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(table), pools,
             use_pallas=path == "kernel", interpret=True, mesh=mesh, logits_at=jnp.asarray(lens - 1),
             q_lens=jnp.asarray(lens))
@@ -357,7 +349,7 @@ def test_a_suffix_cohort_of_three_in_four_rows_is_the_cohort_in_eight(path):
 
 def test_a_forward_counts_the_keys_it_selected_and_the_keys_it_scored():
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     table = jnp.asarray(1 + np.arange(12, dtype=np.int32).reshape(2, 6))
     pools = init_paged_kv(cfg, 13, 16)
     window = jnp.ones((2, 8), jnp.int32)
@@ -366,7 +358,7 @@ def test_a_forward_counts_the_keys_it_selected_and_the_keys_it_scored():
         ((8, 3), (60, 10), [3 * (TOPK + 13), 3 * 68]),  # one row past the selection, one under it
         ((0, 5), (60, 40), [3 * TOPK, 3 * 45]),  # the idle row reads and scores nothing
     ]:
-        *_, stats = decode_chunk_paged(
+        *_, stats = jit_chunk(
             params, cfg, window, jnp.asarray(positions), table, pools, use_pallas=False,
             q_lens=jnp.asarray(q_lens), moe_stats=True,
         )
@@ -412,7 +404,7 @@ def test_sixteen_shares_of_thirty_two_experts_add_up_with_the_shared_expert_coun
     all 32 and computing the shared expert: the sixteen partial results, less
     the shared expert's fifteen times, sum to the uncut layer's."""
     cfg = small(n_experts=32, router_groups=8, router_groups_kept=4, n_experts_per_tok=4)
-    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    layers = params_of(cfg)["layers"]
     lp = {k: v[0] for k, v in layers.items() if k not in moe.EXPERT_LEAVES}
     experts = {k: layers[k] for k in moe.EXPERT_LEAVES}
     h = jax.random.normal(jax.random.PRNGKey(5), (3, 5, 64), jnp.float32)
@@ -441,10 +433,10 @@ PINNED_LATENT = (2, "fe5c88747deb27c0", "cacdbc092c279517", "011ba48b8d034aef")
 
 
 def test_a_latent_block_without_an_index_traces_to_the_program_it_always_did():
-    mla = _by_path("chip_block_mla_for_dsa_t", os.path.join(CHIP_DIR, "models", "mla.py"))
+    mla = by_path("chip_block_mla_for_dsa_t", os.path.join(CHIP_DIR, "models", "mla.py"))
     cfg = dataclasses.replace(mla.rehearsal_config(384), dtype="float32")
     assert (cfg.index_topk, cfg.router_groups) == (0, 0)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     B, T, lens = 3, 32, jnp.asarray([20, 9, 14])
     table = jnp.asarray(1 + np.arange(B * 4, dtype=np.int32).reshape(B, 4))
     rng = np.random.default_rng(36)

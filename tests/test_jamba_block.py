@@ -6,16 +6,15 @@ of like layers. CPU, small sizes, the scan's kernel interpreted AND the jnp
 walk in lockstep; the plain reference is the benchmark's block module
 (``benchmarks/chip/models/jamba.py``), imported by path, and the comparison is
 the one that decides a benchmark run's ``correct``
-(``benchmarks/chip/reference.py``)."""
+(``benchmarks/chip/reference.py``),
+run with its controls in ``tests/test_jamba_rehearsal.py`` beside the rehearsal child."""
 
 import asyncio
 import dataclasses
 import functools
-import importlib.util
 import json
 import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -32,28 +31,17 @@ from mcpx.models.gemma import model as gemma_model
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import init_kv_cache, init_params, pattern_runs, prefill
 from mcpx.parallel.mesh import make_mesh, param_pspecs
+from tests.helpers import by_path, compiled, one_device, params_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
 W = 8  # the decode window's slots
-
-
-def _by_path(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = module
-    spec.loader.exec_module(module)
-    return module
+jit_prefill, jit_chunk = compiled()  # one executable a (configuration, route, shapes): tests/helpers.py
 
 
 @pytest.fixture(scope="module")
 def block():
-    return _by_path("chip_block_jamba_t", os.path.join(CHIP_DIR, "models", "jamba.py"))
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return _by_path("chip_harness_reference_jamba_t", os.path.join(CHIP_DIR, "reference.py"))
+    return by_path("chip_block_jamba_t", os.path.join(CHIP_DIR, "models", "jamba.py"))
 
 
 def small(**kw):
@@ -68,10 +56,9 @@ def small(**kw):
     return GemmaConfig(**{**base, **kw})
 
 
-@functools.lru_cache(maxsize=None)
 def _small_params():
     cfg = small()
-    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params_of(cfg)
 
 
 # ------------------------------------------------------------ configuration
@@ -363,8 +350,8 @@ def test_the_scanned_walk_is_the_layers_walked_one_by_one(monkeypatch):
 # ------------------------------------------ the state, at the model's level
 def _prefilled(cfg, params, toks, lens, T, n_slots, use_pallas=False):
     B = toks.shape[0]
-    last, dense = prefill(params, cfg, toks[:, :T], lens, init_kv_cache(cfg, B, T), last_only=True,
-                          use_pallas=use_pallas, interpret=True)
+    last, dense = jit_prefill(params, cfg, toks[:, :T], lens, init_kv_cache(cfg, B, T), last_only=True,
+                              use_pallas=use_pallas, interpret=True)
     table = jnp.asarray(1 + np.arange(B * 4, dtype=np.int32).reshape(B, 4))
     pools = commit_prefill_to_pages(init_paged_kv(cfg, 1 + B * 4, 16), dense, table, lens, 16)
     pools["state"] = write_prefill_state(init_state_pool(cfg, n_slots, W), jnp.arange(B), dense["ssm"])
@@ -381,10 +368,10 @@ def test_a_padded_prefills_state_is_the_unpadded_ones(path):
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, 48)), jnp.int32)
     lens = [20, 16, 37]
     kw = dict(last_only=True, use_pallas=path == "kernel", interpret=True)
-    _, padded = prefill(params, cfg, toks, jnp.asarray(lens), init_kv_cache(cfg, 3, 48), **kw)
+    _, padded = jit_prefill(params, cfg, toks, jnp.asarray(lens), init_kv_cache(cfg, 3, 48), **kw)
     assert padded["ssm"][0].shape == (3, 3, 16, 128) and padded["ssm"][1].shape == (3, 3, 3, 128)
     for b, n in enumerate(lens):
-        _, alone = prefill(params, cfg, toks[b : b + 1, :n], jnp.asarray([n]), init_kv_cache(cfg, 1, n), **kw)
+        _, alone = jit_prefill(params, cfg, toks[b : b + 1, :n], jnp.asarray([n]), init_kv_cache(cfg, 1, n), **kw)
         for got, want in zip(padded["ssm"], alone["ssm"]):
             np.testing.assert_allclose(np.asarray(got[:, b]), np.asarray(want[:, 0]), atol=1e-5)
         assert float(jnp.abs(alone["ssm"][0]).max()) > 1e-3
@@ -402,14 +389,12 @@ def test_windows_with_rejected_proposals_equal_token_by_token_decode(path):
     B, T = 3, 32
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 64)), jnp.int32)
     lens = jnp.asarray([20, 9, 14])
-    full, _ = prefill(params, cfg, toks, jnp.asarray([64] * B), init_kv_cache(cfg, B, 64))
+    full, _ = jit_prefill(params, cfg, toks, jnp.asarray([64] * B), init_kv_cache(cfg, B, 64))
     last, pools, table, _ = _prefilled(cfg, params, toks, lens, T, B + 2, use_pallas=path == "kernel")
     for b in range(B):
         np.testing.assert_allclose(np.asarray(last[b]), np.asarray(full[b, lens[b] - 1]), atol=2e-4)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    step = jax.jit(functools.partial(
-        decode_chunk_paged, use_pallas=path == "kernel", interpret=True, mesh=mesh,
-    ), static_argnums=(1,))
+    mesh = one_device()
+    step = functools.partial(jit_chunk, use_pallas=path == "kernel", interpret=True, mesh=mesh)
     pos = lens
     plan = [([3, 0, 8], [1, 0, 5]), ([8, 4, 1], [8, 2, 1]), ([5, 5, 5], [1, 1, 1]), ([0, 8, 2], [0, 3, 2])]
     for q, keep in plan:
@@ -438,8 +423,8 @@ def test_a_window_wider_than_the_pending_one_has_no_route():
     lens = jnp.asarray([16, 11])
     _, pools, table, _ = _prefilled(cfg, params, toks, lens, 16, 2)
     with pytest.raises(ValueError, match="the state pool keeps 8 pending"):
-        decode_chunk_paged(params, cfg, toks[:, :32], lens, table, pools, use_pallas=False,
-                           q_lens=jnp.asarray([24, 19]))
+        jit_chunk(params, cfg, toks[:, :32], lens, table, pools, use_pallas=False,
+                  q_lens=jnp.asarray([24, 19]))
 
 
 def test_a_missing_inner_norm_is_seen(block):
@@ -449,86 +434,12 @@ def test_a_missing_inner_norm_is_seen(block):
     cfg, params = _small_params()
     rng = np.random.default_rng(6)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 40)), jnp.int32)
-    got, _ = prefill(params, cfg, toks, jnp.asarray([40]), init_kv_cache(cfg, 1, 40))
+    got, _ = jit_prefill(params, cfg, toks, jnp.asarray([40]), init_kv_cache(cfg, 1, 40))
     dims = dataclasses.asdict(cfg)
     want = block._reference(params, dims, toks[0])
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want), atol=2e-4)
     bent = {**params, "scan_layers": {**params["scan_layers"], "b_norm": params["scan_layers"]["b_norm"] * 2}}
     assert float(jnp.abs(block._reference(bent, dims, toks[0]) - want).max()) > 0.02
-
-
-# ------------------------------------------------ the comparison, and controls
-def _compare(block, reference, control="", **switches):
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    cfg = block.rehearsal_config(3072)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    saved = dict(block.CONTROLS)
-    block.CONTROLS.update(switches)
-    try:
-        out = reference.compare_with_engine_step(
-            block, params, cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 58, interpret=True,
-            page_size=16, rows=4, pages_per_row=4, prefill_len=48, n_decode=3, control=control,
-        )
-    finally:
-        block.CONTROLS.update(saved)
-    return out, cfg, params
-
-
-@pytest.mark.parametrize("path", ["kernel", "jnp"])
-def test_prefill_then_decode_windows_match_the_reference(block, reference, path, monkeypatch):
-    """The dense prefill into pages and state slots, then decode windows of
-    uneven live widths of which every row keeps one token (the interpreted
-    kernels; the jnp route beside them), over two periods of ``M^2 A M`` in
-    bfloat16 weights: logits against the block's plain float32 reference, whose
-    recurrence runs token by token, through the comparison that decides
-    ``correct``. The reading (0.012 here, the feed-forwards' bfloat16 operands
-    most of it) is held under three quarters of the limit: with the mixer's
-    matrices on bfloat16 operands too (``mixer_in_bfloat16`` below) this size
-    reads over it."""
-    if path == "jnp":
-        import mcpx.engine.paged_decode as paged
-        import mcpx.models.gemma.model as dense
-
-        monkeypatch.setattr(
-            paged, "decode_chunk_paged",
-            lambda *a, **kw: decode_chunk_paged(*a, **{**kw, "use_pallas": False}),
-        )
-        monkeypatch.setattr(dense, "prefill", lambda *a, **kw: prefill(*a, **{**kw, "use_pallas": False}))
-    out, cfg, params = _compare(block, reference)
-    assert out["ok"] and out["positions"] == 16, out
-    assert (out["tol_rms"], out["tol_max"]) == reference.tol(8) == (0.02, 0.12)
-    assert 0 < out["rms_rel_err"] < 0.015 and out["rms_rel_err"] < out["max_rel_err"] < 0.08
-    # the rows' stored states carry float32's low bits (about 2^-8 of them read coarse)
-    coarse = block.state_readings()
-    assert len(coarse) == 4 and 0 < max(coarse) < 0.01 < block.STATE_COARSE
-    assert reference.tol(28) == pytest.approx((0.02646, 0.15875), rel=1e-3)  # the cell's depth
-
-
-@pytest.mark.parametrize("control", [
-    dict(state_moves_by_the_window=True), dict(pending_commit_twice=True), dict(state_in_bfloat16=True),
-    dict(control="int8-weights"),
-])
-def test_a_step_that_is_wrong_fails_the_comparison(block, reference, control):
-    """A rejected slot's token left in ``h`` (the state moved by the window,
-    not by what the row kept); the pending commit applied twice; ``h`` through
-    bfloat16 where the configuration states float32 (the logits cannot see it:
-    the stored values' low bits do); a step on weights of 256 levels: the
-    comparison that passes the sound step does not pass these."""
-    out, _, _ = _compare(block, reference, **control)
-    assert not out["ok"], out
-
-
-def test_the_mixers_operands_through_bfloat16_are_seen(block, reference):
-    """``mixer_in_bfloat16``, the precision below the one the configuration
-    states between the mixer's matrices: its four products read their operand
-    rounded once, everything else as it was. At this size (8 layers, 6 of them
-    mixers) it moves the reading from 0.012 to 0.019-0.022, about the limit;
-    at the cell's 28 layers the chip judges it (``benchmarks/chip/tests/
-    test_jamba_readings.py``, PERF.md section 6, PR 58)."""
-    sound, _, _ = _compare(block, reference)
-    low, _, _ = _compare(block, reference, mixer_in_bfloat16=True)
-    assert low["rms_rel_err"] > 1.4 * sound["rms_rel_err"] and low["rms_rel_err"] > 0.9 * low["tol_rms"], (sound, low)
-    assert not block.CONTROLS["mixer_in_bfloat16"]
 
 
 # ------------------------------------------- the served path, at every length
@@ -737,7 +648,7 @@ def _published(block, replicated):
     parameter tree, a leaf's shape there, an int32 shape there)."""
     with open(os.path.join(CHIP_DIR, "configs", "jamba2-3b.json")) as f:
         config = json.load(f)
-    spec = _by_path("chip_harness_spec_jamba_t", os.path.join(CHIP_DIR, "spec.py"))
+    spec = by_path("chip_harness_spec_jamba_t", os.path.join(CHIP_DIR, "spec.py"))
     cfg = block.model_config(spec.model_keys(config), 3072)
     sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated)
     params = jax.tree.map(sd, jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))

@@ -4,52 +4,36 @@ absorbed against the pages), beside a sigmoid router of which this device
 holds a share, a shared expert and a dense lead. CPU, small sizes; the plain
 reference is the benchmark's block module (``benchmarks/chip/models/mla.py``),
 imported by path, and the comparison is the one that decides a benchmark
-run's ``correct`` (``benchmarks/chip/reference.py``)."""
+run's ``correct`` (``benchmarks/chip/reference.py``),
+run with its controls in ``tests/test_mla_rehearsal.py`` beside the rehearsal child."""
 
 import dataclasses
-import importlib.util
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import mcpx.engine.paged_decode as paged
-import mcpx.models.gemma.model as model
 from mcpx.core.config import MCPXConfig
 from mcpx.core.errors import ConfigError
 from mcpx.engine.kv_cache import commit_prefill_to_pages, init_paged_kv
-from mcpx.engine.paged_decode import _kv_window, _write_kv_window, decode_chunk_paged
+from mcpx.engine.paged_decode import _kv_window, _write_kv_window
 from mcpx.models.gemma import moe
 from mcpx.models.gemma.config import GemmaConfig
-from mcpx.models.gemma.model import (
-    feed_forward_residual, gated_mlp, init_kv_cache, init_params, prefill,
-)
+from mcpx.models.gemma.model import feed_forward_residual, gated_mlp, init_kv_cache
 from mcpx.parallel.mesh import kv_cache_pspecs, make_mesh, param_pspecs
 from mcpx.telemetry.costs import model_cost
+from tests.helpers import by_path, compiled, one_device, params_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
-
-
-def _by_path(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = module
-    spec.loader.exec_module(module)
-    return module
+jit_prefill, jit_chunk = compiled()  # one executable a (configuration, route, shapes): tests/helpers.py
 
 
 @pytest.fixture(scope="module")
 def block():
-    return _by_path("chip_block_mla_t", os.path.join(CHIP_DIR, "models", "mla.py"))
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return _by_path("chip_harness_reference_mla_t", os.path.join(CHIP_DIR, "reference.py"))
+    return by_path("chip_block_mla_t", os.path.join(CHIP_DIR, "models", "mla.py"))
 
 
 def small(**kw):
@@ -117,7 +101,7 @@ def test_published_counts_of_a_x_k1():
 
 def test_the_tree_is_the_counts_and_weight_bytes_are_the_leaves_a_forward_reads():
     cfg = small(dtype="bfloat16")
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     assert set(params["layers"]) >= {"w_dq", "q_lora_norm", "w_uq", "w_dkv", "kv_lora_norm", "w_ukv", "wo"}
     assert not {"wq", "wk", "wv"} & set(params["layers"])
     assert params["layers"]["w_uq"].shape == (3, 24, 4, 24) and params["layers"]["w_dkv"].shape == (3, 64, 40)
@@ -180,7 +164,7 @@ def test_every_leaf_and_the_pools_have_a_spec(mesh_shape):
     assert specs["dense_layers"]["w_uq"] == specs["layers"]["w_uq"]
     # a latent has no head axis: the cache is whole over model, rows over data
     assert all(spec[3] is None for spec in kv_cache_pspecs(cfg, mesh, 4).values())
-    alone = init_params(cfg, jax.random.PRNGKey(0))
+    alone = params_of(cfg)
     for a, b in zip(jax.tree.leaves(alone), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -190,7 +174,7 @@ def _prefilled(cfg, params, seq, lens, pages=4):
     B, T = seq.shape
     table = jnp.asarray(1 + np.arange(B * pages, dtype=np.int32).reshape(B, pages))
     padded = jnp.where(jnp.arange(T)[None] < lens[:, None], seq, 0)
-    last, dense = prefill(params, cfg, padded, lens, init_kv_cache(cfg, B, T), last_only=True)
+    last, dense = jit_prefill(params, cfg, padded, lens, init_kv_cache(cfg, B, T), last_only=True)
     pools = commit_prefill_to_pages(init_paged_kv(cfg, 1 + B * pages, 16), dense, table, lens, 16)
     return last, dense, pools, table
 
@@ -202,24 +186,24 @@ def test_absorbed_against_pages_is_expanded_against_the_dense_cache(path):
     pages followed by ABSORBED paged windows (one token, then a ragged window
     of up to five) through the kernel and through its jnp reference."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     rng = np.random.default_rng(42)
     B, T = 3, 48
     seq = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T)), jnp.int32)
     lens = jnp.asarray([20, 9, 33])
-    whole, _ = prefill(params, cfg, seq, jnp.full((B,), T), init_kv_cache(cfg, B, T))
+    whole, _ = jit_prefill(params, cfg, seq, jnp.full((B,), T), init_kv_cache(cfg, B, T))
     last, _, pools, table = _prefilled(cfg, params, seq, lens)
     rows = jnp.arange(B)
     np.testing.assert_allclose(last, whole[rows, lens - 1], rtol=1e-4, atol=1e-4)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
     step = dict(use_pallas=path == "kernel", interpret=True, mesh=mesh)
-    logits, pools = decode_chunk_paged(
+    logits, pools = jit_chunk(
         params, cfg, seq[rows, lens][:, None], lens, table, pools,
         q_lens=jnp.ones((B,), jnp.int32), **step)
     np.testing.assert_allclose(logits[:, 0], whole[rows, lens], rtol=1e-4, atol=1e-4)
     q_lens = jnp.asarray([5, 0, 3])
     window = jnp.stack([seq[b, int(lens[b]) + 1 : int(lens[b]) + 6] for b in range(B)])
-    logits, _ = decode_chunk_paged(params, cfg, window, lens + 1, table, pools, q_lens=q_lens, **step)
+    logits, _ = jit_chunk(params, cfg, window, lens + 1, table, pools, q_lens=q_lens, **step)
     for b, n in enumerate([5, 0, 3]):
         start = int(lens[b]) + 1
         np.testing.assert_allclose(logits[b, :n], whole[b, start : start + n], rtol=1e-4, atol=1e-4)
@@ -229,7 +213,7 @@ def test_latent_pages_written_both_ways_read_back_equal():
     """A token's cache row is written once, by the prefill's commit or by the
     paged window's write: the same prompt through each leaves the same pages."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     rng = np.random.default_rng(3)
     B, T = 2, 32
     seq = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T)), jnp.int32)
@@ -245,9 +229,9 @@ def test_latent_pages_written_both_ways_read_back_equal():
             pool = _write_kv_window(pool, jnp.int32(layer), dense[name][layer], window)
         np.testing.assert_array_equal(np.asarray(pool[:, :, 1:5]), np.asarray(committed[name][:, :, 1:5]))
     # and the paged forward's own write: a window forward over empty pages
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    _, written = decode_chunk_paged(params, cfg, seq, jnp.zeros((B,), jnp.int32), table, empty,
-                                    q_lens=lens, use_pallas=False, mesh=mesh)
+    mesh = one_device()
+    _, written = jit_chunk(params, cfg, seq, jnp.zeros((B,), jnp.int32), table, empty,
+                           q_lens=lens, use_pallas=False, mesh=mesh)
     for name in ("k", "v"):
         np.testing.assert_allclose(np.asarray(written[name][:, :, 1:5]),
                                    np.asarray(committed[name][:, :, 1:5]), rtol=2e-4, atol=2e-5)
@@ -259,16 +243,16 @@ def test_the_suffix_prefill_route_over_latent_pages_equals_a_whole_prefill():
     window against them through the kernel: the last logits and the pages
     are a whole prefill's."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     rng = np.random.default_rng(9)
     B, T = 2, 48
     seq = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T)), jnp.int32)
     lens = jnp.asarray([41, 30])
     want, _, whole_pools, table = _prefilled(cfg, params, seq, lens)
     _, _, pools, _ = _prefilled(cfg, params, seq, jnp.asarray([16, 16]))
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
     suffix = jnp.where(jnp.arange(32)[None] < (lens - 16)[:, None], seq[:, 16:], 0)
-    got, pools = decode_chunk_paged(
+    got, pools = jit_chunk(
         params, cfg, suffix, jnp.full((B,), 16), table, pools, use_pallas=True, interpret=True,
         mesh=mesh, logits_at=lens - 17, q_lens=lens - 16)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
@@ -292,7 +276,7 @@ def test_four_shares_of_sixteen_experts_add_up_with_the_shared_expert_counted_on
     all 16 and each computing the shared expert: the four partial results,
     less the shared expert's three times, sum to the uncut layer's."""
     cfg = small(expert_first=0, experts_held=0)
-    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    layers = params_of(cfg)["layers"]
     lp = {k: v[1] for k, v in layers.items() if k not in moe.EXPERT_LEAVES}
     experts = {k: layers[k] for k in moe.EXPERT_LEAVES}
     h = jax.random.normal(jax.random.PRNGKey(5), (3, 5, 64), jnp.float32)
@@ -319,12 +303,12 @@ def test_four_shares_of_sixteen_experts_add_up_with_the_shared_expert_counted_on
 def test_a_forward_counts_what_it_routed_and_what_its_attention_read():
     """The forward's own counters after the layers' (``moe.add_forward_stats``)."""
     cfg = small()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     rng = np.random.default_rng(1)
     B, T = 2, 32
     seq = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T)), jnp.int32)
     lens = jnp.asarray([20, 9])
-    _, _, stats = prefill(params, cfg, seq, lens, init_kv_cache(cfg, B, T), last_only=True, moe_stats=True)
+    _, _, stats = jit_prefill(params, cfg, seq, lens, init_kv_cache(cfg, B, T), last_only=True, moe_stats=True)
     held = cfg.n_experts_held
     own = held + moe.LAYER_STATS
     assert stats.shape == (own + moe.FORWARD_STATS + moe.LATENT_STATS,)
@@ -332,107 +316,13 @@ def test_a_forward_counts_what_it_routed_and_what_its_attention_read():
     assert stats[own:].tolist() == [29 * 2 * 3, 29 * 4, 2 * 4, 0, 0, 0]
     assert 0 < int(stats[:held].sum()) < 29 * 2 * 3  # this share's part of the routing
     _, _, pools, table = _prefilled(cfg, params, seq, lens)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    _, _, stats = decode_chunk_paged(
+    mesh = one_device()
+    _, _, stats = jit_chunk(
         params, cfg, seq[:, :8], lens, table, pools, use_pallas=False, mesh=mesh,
         q_lens=jnp.asarray([3, 0]), moe_stats=True)
     # the idle row reads and routes nothing; the live row's 3 queries take the rung of 4 slots
     # and fetch ONE key block a layer, a part of one: no run
     assert stats[own:].tolist() == [3 * 2 * 3, 23 * 4, 1 * 4, 4 * 4, 1 * 4, 0]
-
-
-# ------------------------------------------------ the comparison, and controls
-def _compare(block, reference, prog=None, control=""):
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    cfg = block.rehearsal_config(3072)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    return reference.compare_with_engine_step(
-        block, params, prog or cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 42, interpret=True,
-        page_size=16, rows=4, pages_per_row=4, prefill_len=48, n_decode=3, control=control,
-    ), cfg, params
-
-
-@pytest.mark.parametrize("path", ["kernel", "jnp"])
-def test_prefill_then_paged_decode_matches_the_reference(block, reference, path, monkeypatch):
-    """Expanded prefill committed to latent pages, then absorbed paged decode
-    one token at a time (the interpreted kernel; the jnp route beside it):
-    logits against the block's plain float32 reference (expanded, no cache,
-    the same share of the experts), through the comparison that decides
-    ``correct``, under the step's routing."""
-    if path == "jnp":
-        monkeypatch.setattr(
-            paged, "decode_chunk_paged",
-            lambda *a, **kw: decode_chunk_paged(*a, **{**kw, "use_pallas": False}),
-        )
-    out, cfg, params = _compare(block, reference)
-    assert out["ok"] and out["positions"] == 16, out
-    assert (out["tol_rms"], out["tol_max"]) == reference.tol(2) == (0.02, 0.12)
-    assert min(out["prompt_lens"]) >= 9 and 0 < out["rms_rel_err"] < out["max_rel_err"]
-    read = block.routing_readings(params, dataclasses.asdict(cfg))
-    assert len(read) == 4 and max(r["distance"] for r in read) < block.MARGIN
-    # every position the step ran, in the SPARSE layer behind the dense lead
-    assert sum(r["checked"] for r in read) == sum(out["prompt_lens"]) + 4 * 3
-
-
-def _skip_norm_of(width):
-    rms_norm = model.rms_norm
-
-    def norm(x, scale, *args, **kw):
-        return x.astype(args[2] if len(args) > 2 and args[2] else x.dtype) if scale.shape[-1] == width \
-            else rms_norm(x, scale, *args, **kw)
-    return norm
-
-
-def _shared_key_unrotated(x, positions, theta, kind=None, rope=model.apply_rope):
-    return x if x.shape[-2] == 1 else rope(x, positions, theta, kind)
-
-
-def _absorbed_through_the_values(q, lp, cfg, *args, attend=paged._latent_attend, **kw):
-    """The mistake the absorption's control makes: the query taken into the
-    latent's space through W_uv, the output brought back through W_uk."""
-    hd = cfg.head_dim
-    swapped = jnp.concatenate([lp["w_ukv"][..., hd:], lp["w_ukv"][..., :hd]], axis=-1)
-    return attend(q, {**lp, "w_ukv": swapped}, cfg, *args, **kw)
-
-
-def _dense_lead_without_its_feed_forward(width, mlp=model.gated_mlp):
-    def gated_mlp(h, w_gate, *args, **kw):
-        out = mlp(h, w_gate, *args, **kw)
-        return jnp.zeros_like(out) if w_gate.shape[-1] == width else out
-    return gated_mlp
-
-
-CONTROLS = {
-    "no_q_norm": ("rms_norm", lambda cfg: _skip_norm_of(cfg.q_lora_rank)),
-    "no_kv_norm": ("rms_norm", lambda cfg: _skip_norm_of(cfg.kv_lora_rank)),
-    "shared_key_unrotated": ("apply_rope", lambda cfg: _shared_key_unrotated),
-    "no_m2_in_the_scale": dict(attn_score_factor=1.0),
-    "absorbed_on_the_wrong_side": ("_latent_attend", lambda cfg: _absorbed_through_the_values),
-    "shared_expert_dropped": dict(d_shared_expert=0),
-    "dense_lead_dropped": ("gated_mlp", lambda cfg: _dense_lead_without_its_feed_forward(cfg.d_ff)),
-    "route_scale_1": dict(router_scale=1.0),
-    "int8_weights": "int8-weights",
-}
-
-
-@pytest.mark.parametrize("control", list(CONTROLS))
-def test_a_step_that_leaves_a_part_out_fails_the_comparison(block, reference, control, monkeypatch):
-    """Each part of the block taken out of (or put wrongly into) the
-    PROGRAM's step alone: the reference keeps it, and the comparison that
-    passes the sound step does not pass this one."""
-    what = CONTROLS[control]
-    cfg = block.rehearsal_config(3072)
-    if isinstance(what, tuple):
-        name, make = what
-        for module in (model, paged):
-            if hasattr(module, name):
-                monkeypatch.setattr(module, name, make(cfg))
-        out, _, _ = _compare(block, reference)
-    elif isinstance(what, str):
-        out, _, _ = _compare(block, reference, control=what)
-    else:
-        out, _, _ = _compare(block, reference, prog=dataclasses.replace(cfg, **what))
-    assert not out["ok"], out
 
 
 def test_the_block_module_reads_the_published_keys(block):
@@ -588,14 +478,14 @@ def test_the_segments_count_the_key_blocks_fetched_and_those_fetched_as_runs():
 
     # one forward over whole blocks: every block a run, or none
     B, S, pages = 2, 8, 32
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = params_of(cfg)
     pools = init_paged_kv(cfg, B * pages + 1, 16)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
     ids = 1 + np.arange(B * pages, dtype=np.int32).reshape(B, pages)
     shuffled = np.random.default_rng(0).permuted(ids, axis=1)
     first = cfg.n_experts_held + moe.LAYER_STATS + moe.FORWARD_STATS
     for table, share in ((ids, 1.0), (shuffled, 0.0)):
-        out = decode_chunk_paged(
+        out = jit_chunk(
             params, cfg, jnp.ones((B, S), jnp.int32), jnp.full((B,), pages * 16 - S, jnp.int32),
             jnp.asarray(table), pools, use_pallas=True, interpret=True, mesh=mesh,
             q_lens=jnp.asarray([S, 1], jnp.int32), moe_stats=True,

@@ -4,11 +4,9 @@ gain. CPU, small sizes; the plain reference is the benchmark's block module
 (``benchmarks/chip/models/mellum.py``), imported by path."""
 
 import dataclasses
-import importlib.util
 import json
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,24 +23,16 @@ from mcpx.models.gemma.model import init_kv_cache, init_params, prefill
 from mcpx.models.gemma import moe
 from mcpx.models.gemma.moe import moe_forward, route
 from mcpx.parallel.mesh import make_mesh, param_pspecs
-from tests.helpers import grouped_against_loop
+from tests.helpers import by_path, grouped_against_loop, one_device
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
 PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
 
 
-def _by_path(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = module
-    spec.loader.exec_module(module)
-    return module
-
-
 @pytest.fixture(scope="module")
 def block():
-    return _by_path("chip_block_mellum_t", os.path.join(CHIP_DIR, "models", "mellum.py"))
+    return by_path("chip_block_mellum_t", os.path.join(CHIP_DIR, "models", "mellum.py"))
 
 
 def small(**kw):
@@ -485,7 +475,7 @@ def test_pads_and_idle_rows_change_no_live_rows_logits(use_pallas):
     toks = jnp.asarray(rng.integers(0, 384, (B, T)), jnp.int32)
     _, dense = prefill(params, cfg, toks, lens, init_kv_cache(cfg, B, T), last_only=True)
     pools = commit_prefill_to_pages(init_paged_kv(cfg, n_pages, 16), dense, table, lens, 16)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    mesh = one_device()
     q_lens = jnp.asarray([3, 0, 8])
 
     def run(window_toks):
@@ -512,7 +502,7 @@ def test_prefill_then_paged_decode_through_one_period_matches_the_reference(bloc
     pattern (3 window layers of 8 + 1 full with YaRN), contexts 9-47: logits
     against the block's plain float32 reference under the step's routing. On
     the 2 x 2 mesh the expert leaves stay whole on every device."""
-    reference = _by_path("chip_harness_reference_t", os.path.join(CHIP_DIR, "reference.py"))
+    reference = by_path("chip_harness_reference_t", os.path.join(CHIP_DIR, "reference.py"))
     from mcpx.models.gemma.params import load_or_init
 
     data, model = mesh_shape
@@ -535,8 +525,8 @@ def test_prefill_then_paged_decode_through_one_period_matches_the_reference(bloc
 def test_the_window_is_really_applied_in_prefill_and_decode(block):
     """The same step with the window taken out of the program's config alone
     (the reference keeps it) fails the comparison: contexts pass 8."""
-    reference = _by_path("chip_harness_reference_t2", os.path.join(CHIP_DIR, "reference.py"))
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
+    reference = by_path("chip_harness_reference_t2", os.path.join(CHIP_DIR, "reference.py"))
+    mesh = one_device()
     cfg = block.rehearsal_config(3072)
     params = init_params(cfg, jax.random.PRNGKey(0))
     no_window = dataclasses.replace(cfg, layer_types=(), sliding_window=0)

@@ -5,15 +5,14 @@ of two matrices in a latent beside a shared expert. CPU, small sizes, the
 state pool's kernel interpreted AND the jnp form in lockstep; the plain
 reference is the benchmark's block module (``benchmarks/chip/models/
 nemotron_h.py``), imported by path, and the comparison is the one that decides
-a benchmark run's ``correct`` (``benchmarks/chip/reference.py``)."""
+a benchmark run's ``correct`` (``benchmarks/chip/reference.py``),
+run with its controls in ``tests/test_ssm_rehearsal.py`` beside the rehearsal child."""
 
 import asyncio
 import dataclasses
 import functools
-import importlib.util
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,35 +24,24 @@ from mcpx.core.errors import ConfigError
 from mcpx.engine.kv_cache import (
     commit_prefill_to_pages, init_paged_kv, init_state_pool, write_prefill_state,
 )
-from mcpx.engine.paged_decode import decode_chunk_paged, keep_window
+from mcpx.engine.paged_decode import keep_window
 from mcpx.models.gemma import moe
 from mcpx.models.gemma.config import GemmaConfig
 from mcpx.models.gemma.model import (
-    hybrid_feed_forward, init_kv_cache, init_params, prefill, rms_norm, stack_row,
+    hybrid_feed_forward, init_kv_cache, init_params, rms_norm, stack_row,
 )
 from mcpx.parallel.mesh import make_mesh, param_pspecs
+from tests.helpers import by_path, compiled, one_device, params_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP_DIR = os.path.join(REPO, "benchmarks", "chip")
 W = 8  # the decode window's slots
-
-
-def _by_path(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = module
-    spec.loader.exec_module(module)
-    return module
+jit_prefill, jit_chunk = compiled()  # one executable a (configuration, route, shapes): tests/helpers.py
 
 
 @pytest.fixture(scope="module")
 def block():
-    return _by_path("chip_block_nemotron_t", os.path.join(CHIP_DIR, "models", "nemotron_h.py"))
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return _by_path("chip_harness_reference_nemotron_t", os.path.join(CHIP_DIR, "reference.py"))
+    return by_path("chip_block_nemotron_t", os.path.join(CHIP_DIR, "models", "nemotron_h.py"))
 
 
 def small(**kw):
@@ -70,10 +58,9 @@ def small(**kw):
     return GemmaConfig(**{**base, **kw})
 
 
-@functools.lru_cache(maxsize=None)
 def _small_params():
     cfg = small()
-    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params_of(cfg)
 
 
 # ------------------------------------------------------------ configuration
@@ -237,7 +224,7 @@ def test_past_the_ridge_the_grouped_form_computes_what_the_loop_does(monkeypatch
 # ------------------------------------------ the state, at the model's level
 def _prefilled(cfg, params, toks, lens, T, n_slots):
     B = toks.shape[0]
-    last, dense = prefill(params, cfg, toks[:, :T], lens, init_kv_cache(cfg, B, T), last_only=True)
+    last, dense = jit_prefill(params, cfg, toks[:, :T], lens, init_kv_cache(cfg, B, T), last_only=True)
     table = jnp.asarray(1 + np.arange(B * 4, dtype=np.int32).reshape(B, 4))
     pools = commit_prefill_to_pages(init_paged_kv(cfg, 1 + B * 4, 16), dense, table, lens, 16)
     pools["state"] = write_prefill_state(init_state_pool(cfg, n_slots, W), jnp.arange(B), dense["ssm"])
@@ -252,10 +239,10 @@ def test_a_padded_prefills_state_is_the_unpadded_ones():
     rng = np.random.default_rng(1)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, 48)), jnp.int32)
     lens = [20, 16, 37]
-    _, padded = prefill(params, cfg, toks, jnp.asarray(lens), init_kv_cache(cfg, 3, 48), last_only=True)
+    _, padded = jit_prefill(params, cfg, toks, jnp.asarray(lens), init_kv_cache(cfg, 3, 48), last_only=True)
     for b, n in enumerate(lens):
-        _, alone = prefill(params, cfg, toks[b : b + 1, :n], jnp.asarray([n]), init_kv_cache(cfg, 1, n),
-                           last_only=True)
+        _, alone = jit_prefill(params, cfg, toks[b : b + 1, :n], jnp.asarray([n]), init_kv_cache(cfg, 1, n),
+                               last_only=True)
         for (h, tail), (h1, tail1) in zip(padded["ssm"], alone["ssm"]):
             np.testing.assert_allclose(np.asarray(h[b]), np.asarray(h1[0]), atol=1e-5)
             np.testing.assert_allclose(np.asarray(tail[b]), np.asarray(tail1[0]), atol=1e-5)
@@ -275,14 +262,12 @@ def test_windows_with_rejected_proposals_equal_token_by_token_decode(path):
     B, T = 3, 32
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 64)), jnp.int32)
     lens = jnp.asarray([20, 9, 14])
-    full, _ = prefill(params, cfg, toks, jnp.asarray([64] * B), init_kv_cache(cfg, B, 64))
+    full, _ = jit_prefill(params, cfg, toks, jnp.asarray([64] * B), init_kv_cache(cfg, B, 64))
     last, pools, table, _ = _prefilled(cfg, params, toks, lens, T, B + 2)
     for b in range(B):
         np.testing.assert_allclose(np.asarray(last[b]), np.asarray(full[b, lens[b] - 1]), atol=2e-4)
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    step = jax.jit(functools.partial(
-        decode_chunk_paged, use_pallas=path == "kernel", interpret=True, mesh=mesh,
-    ), static_argnums=(1,))
+    mesh = one_device()
+    step = functools.partial(jit_chunk, use_pallas=path == "kernel", interpret=True, mesh=mesh)
     pos = lens
     plan = [([3, 0, 8], [1, 0, 5]), ([8, 4, 1], [8, 2, 1]), ([5, 5, 5], [1, 1, 1]), ([0, 8, 2], [0, 3, 2])]
     for q, keep in plan:
@@ -348,8 +333,8 @@ def test_a_window_wider_than_the_pending_width_is_refused():
     lens = jnp.asarray([16, 11])
     _, pools, table, _ = _prefilled(cfg, params, toks, lens, 16, 2)
     with pytest.raises(ValueError, match="the state pool keeps 8 pending"):
-        decode_chunk_paged(params, cfg, toks[:, :32], lens, table, pools, use_pallas=False,
-                           q_lens=jnp.asarray([24, 19]))
+        jit_chunk(params, cfg, toks[:, :32], lens, table, pools, use_pallas=False,
+                  q_lens=jnp.asarray([24, 19]))
 
 
 def test_the_kernels_blocks_are_a_groups_lanes_under_the_budget():
@@ -362,65 +347,6 @@ def test_the_kernels_blocks_are_a_groups_lanes_under_the_budget():
     assert _blocking(1024, 1024) == 512 and 4 * 1024 * 512 * 4 <= VMEM_BUDGET
     assert _blocking(1024, 8192) == 128  # a lane width is the least a step takes
     assert _blocking(32, 32) == 32  # narrower than a lane width: the tests' sizes
-
-
-# ------------------------------------------------ the comparison, and controls
-def _compare(block, reference, control="", **switches):
-    mesh = make_mesh(data=1, model=1, devices=jax.devices()[:1])
-    cfg = block.rehearsal_config(3072)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    saved = dict(block.CONTROLS)
-    block.CONTROLS.update(switches)
-    try:
-        out = reference.compare_with_engine_step(
-            block, params, cfg, dataclasses.asdict(cfg), mesh, seed=2**31 + 48, interpret=True,
-            page_size=16, rows=4, pages_per_row=4, prefill_len=48, n_decode=3, control=control,
-        )
-    finally:
-        block.CONTROLS.update(saved)
-    return out, cfg, params
-
-
-@pytest.mark.parametrize("path", ["kernel", "jnp"])
-def test_prefill_then_decode_windows_match_the_reference(block, reference, path, monkeypatch):
-    """The dense prefill into pages and state slots, then decode windows of
-    uneven live widths of which every row keeps one token (the interpreted
-    kernels; the jnp route beside them), over the pattern's first 11 layers:
-    logits against the block's plain float32 reference, whose recurrence runs
-    token by token, through the comparison that decides ``correct``, under the
-    step's routing."""
-    if path == "jnp":
-        import mcpx.engine.paged_decode as paged
-
-        monkeypatch.setattr(
-            paged, "decode_chunk_paged",
-            lambda *a, **kw: decode_chunk_paged(*a, **{**kw, "use_pallas": False}),
-        )
-    out, cfg, params = _compare(block, reference)
-    assert out["ok"] and out["positions"] == 16, out
-    assert (out["tol_rms"], out["tol_max"]) == reference.tol(11) == (0.02, 0.12)
-    assert 0 < out["rms_rel_err"] < out["max_rel_err"]
-    read = block.routing_readings(params, dataclasses.asdict(cfg))
-    assert len(read) == 4 and max(r["distance"] for r in read) < block.MARGIN
-    # every position the step compared, in each of the 5 expert layers
-    assert sum(r["checked"] for r in read) == 5 * (sum(out["prompt_lens"]) + 4 * 3)
-    # the rows' stored states carry float32's low bits (about 2^-8 of them read coarse)
-    coarse = block.state_readings()
-    assert len(coarse) == 4 and 0 < max(coarse) < 0.01 < block.STATE_COARSE
-
-
-@pytest.mark.parametrize("control", [
-    dict(state_moves_by_the_window=True), dict(state_in_bfloat16=True), dict(follow_step_routing=False),
-    dict(control="int8-weights"),
-])
-def test_a_step_that_is_wrong_fails_the_comparison(block, reference, control):
-    """A state that moves by the window and not by what the row kept; a
-    state kept in bfloat16 where the configuration states float32 (the
-    logits cannot see it: the stored values' low bits do); a reference under
-    its own routing; a step on weights of 256 levels: the comparison that
-    passes the sound step does not pass these."""
-    out, _, _ = _compare(block, reference, **control)
-    assert not out["ok"], out
 
 
 # ------------------------------------------- the served path, at every length
@@ -562,7 +488,7 @@ def test_a_row_reused_after_another_plan_serves_what_a_fresh_engine_serves():
     plan's tokens are what an engine that has served nothing else gives it."""
     from mcpx.engine.engine import InferenceEngine
 
-    config = _engine_config(max_batch_size=2)
+    config = _engine_config(max_batch_size=2, warmup_compile=False)  # no compile count is read here
     prompts = [f"Reuse.\nintent {i}: route and merge. JSON:" for i in range(6)]
     budgets = [17, 5, 11, 23, 8, 14]
 
