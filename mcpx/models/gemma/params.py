@@ -4,6 +4,12 @@ The reference persists nothing anywhere (SURVEY.md §5 checkpoint/resume:
 "there are no writes at all"). Here model weights are Orbax checkpoints that
 restore *directly onto the mesh* — each host/device materialises only its
 shard, which is what makes 2B/7B loads fit HBM without a host-RAM spike.
+
+Orbax is imported where a checkpoint is written or restored (``_orbax``),
+not at module scope: a start on drawn weights or an ``.npz`` never reads it,
+and it brings ~780 modules (google.cloud.logging, grpc, tensorstore) into a
+process that imports this module for ``load_or_init`` alone. A start from an
+Orbax checkpoint pays that import once, inside ``startup.weights``.
 """
 
 from __future__ import annotations
@@ -12,7 +18,6 @@ import os
 from typing import Optional
 
 import jax
-import orbax.checkpoint as ocp
 from jax.sharding import Mesh, NamedSharding
 
 from mcpx.core.errors import EngineError
@@ -46,8 +51,21 @@ def _check_shapes(params: Params, cfg: GemmaConfig, path: str) -> None:
         raise EngineError(f"checkpoint {path} does not fit model config: {problems[:4]}")
 
 
+def _orbax(path: str):
+    """``orbax.checkpoint``, imported at first use; ``path`` is the
+    checkpoint that needed it, for the error of an install without it."""
+    try:
+        import orbax.checkpoint as ocp
+    except ImportError as e:
+        raise EngineError(
+            f"checkpoint {path} needs the package orbax-checkpoint, which cannot be imported: {e}"
+        ) from e
+    return ocp
+
+
 def save_checkpoint(path: str, params: Params) -> None:
     path = os.path.abspath(path)
+    ocp = _orbax(path)
     with ocp.PyTreeCheckpointer() as ckptr:
         ckptr.save(path, params)
 
@@ -72,6 +90,7 @@ def load_checkpoint(
 
             params = shard_pytree(params, param_pspecs(cfg, mesh), mesh)
         return params
+    ocp = _orbax(path)
     with ocp.PyTreeCheckpointer() as ckptr:
         if mesh is None:
             return ckptr.restore(path)

@@ -14,7 +14,9 @@ Top-level phases, which tile the wall from the process's start to
 ``started``: ``startup.import`` (the process's start as the OS gives it ->
 ``InferenceEngine.__init__`` entered; once a process, absent where the
 platform has no ``/proc`` and for an engine built later in a process's life:
-never guessed), ``startup.build`` (-> the worker's ``_setup`` entered),
+never guessed; its ``modules`` is ``len(sys.modules)`` where it closes, the
+import closure's size, which repeats exactly where its seconds do not),
+``startup.build`` (-> the worker's ``_setup`` entered),
 ``startup.backend``, ``startup.weights``, ``startup.pools``,
 ``startup.warmup`` (children ``warmup.grammar_tables``, ``warmup.prefill``
 and ``warmup.admit`` a bucket, ``warmup.segment``, ``warmup.merge``,
@@ -58,6 +60,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import weakref
@@ -201,7 +204,9 @@ class StartupTimeline:
         self.ready_s: Optional[float] = None
         self._cache: dict = {}
         if t_proc is not None:
-            self._close_attrs(root.child("startup.import", t0=root.t0, t1=now, **_ZEROS))
+            self._close_attrs(
+                root.child("startup.import", t0=root.t0, t1=now, modules=len(sys.modules), **_ZEROS)
+            )
         self._build: Optional[Span] = self.begin("startup.build", t0=now)
 
     # ----------------------------------------------------------------- phases
